@@ -14,9 +14,12 @@ carried as ControlMessage::{Stop,Kill}, lib/runtime/src/engine.rs:76-84).
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import logging
 import threading
 from typing import AsyncIterator
+
+import jax
 
 from dynamo_tpu.engine.core import EngineCore
 from dynamo_tpu.engine.request import EngineRequest
@@ -35,6 +38,10 @@ class AsyncLLMEngine(AsyncEngine):
         self._wake = threading.Event()
         self._shutdown = False
         self._thread: threading.Thread | None = None
+        # resolves (with the exception) when a step failed while building
+        # its program; the engine thread has then stopped for good.  The
+        # serving entrypoint awaits it and exits non-zero (cli.py run).
+        self.failed: concurrent.futures.Future = concurrent.futures.Future()
 
     # --------------------------------------------------------------- lifecycle
     def start(self) -> "AsyncLLMEngine":
@@ -54,16 +61,44 @@ class AsyncLLMEngine(AsyncEngine):
             self.core.close()  # stop the kv-offload thread, if any
 
     def _run(self) -> None:
-        while not self._shutdown:
-            try:
-                did_work = self.core.step()
-            except Exception:
-                log.exception("engine step failed; failing in-flight requests")
-                self.core.fail_all()
-                did_work = False
-            if not did_work:
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
+        # jax reports every trace/lower/compile stage it runs; one on this
+        # thread during a step means the step was building a program
+        # (first call of that shape) rather than running a built one
+        me = threading.get_ident()
+        builds = 0
+
+        def on_compile_stage(event: str, duration: float, **kw) -> None:
+            nonlocal builds
+            if "/compile/" in event and threading.get_ident() == me:
+                builds += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_compile_stage)
+        try:
+            while not self._shutdown:
+                before = builds
+                try:
+                    did_work = self.core.step()
+                except Exception as e:
+                    log.exception(
+                        "engine step failed; failing in-flight requests")
+                    self.core.fail_all()
+                    if builds != before:
+                        # a compile error (Mosaic refusing a kernel, a
+                        # missing lowering, XLA out of memory) is not a
+                        # per-request failure: every request of that shape
+                        # fails the same way, so the server must not stay up
+                        log.critical(
+                            "the failed step was building its program; "
+                            "stopping the engine")
+                        self.failed.set_result(e)
+                        return
+                    did_work = False
+                if not did_work:
+                    self._wake.wait(timeout=0.05)
+                    self._wake.clear()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(
+                on_compile_stage)
 
     async def run_on_engine(self, fn):
         """Run ``fn`` on the engine thread at a step boundary (cache/block
@@ -103,6 +138,10 @@ class AsyncLLMEngine(AsyncEngine):
         remote_decode: bool = False,
         on_allocated=None,
     ) -> AsyncIterator[LLMEngineOutput]:
+        if self.failed.done():
+            # the engine thread is gone: a queued request would never step
+            raise RuntimeError(
+                f"engine stopped after a failed build: {self.failed.result()!r}")
         inp = request.data
         loop = asyncio.get_running_loop()
         out_q: asyncio.Queue[LLMEngineOutput] = asyncio.Queue()

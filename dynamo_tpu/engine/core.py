@@ -33,6 +33,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import dataclasses
+import functools
 import logging
 import os
 import queue
@@ -58,7 +59,7 @@ from dynamo_tpu.llm.kv.block_manager import KvBlockManager, NoFreeBlocks
 from dynamo_tpu.llm.protocols import FinishReason, LLMEngineOutput
 from dynamo_tpu.models.llama import LlamaModel
 from dynamo_tpu.obs.perfmodel import perf_model
-from dynamo_tpu.utils.mesh import AXIS_DATA
+from dynamo_tpu.utils.mesh import AXIS_DATA, AXIS_MODEL
 from dynamo_tpu.obs.timeline import step_timeline
 from dynamo_tpu.tokens import TokenBlockSequence
 
@@ -582,26 +583,42 @@ class EngineCore:
         self.cache = cache
 
         self._rng = jax.random.PRNGKey(config.seed)
+
+        def under_mesh(impl):
+            """``impl`` traced with the engine's mesh in scope: the
+            attention dispatch (ops/paged_attention.py) reads the
+            tensor-parallel axis from it and runs its Pallas kernels per
+            kv-head shard under shard_map."""
+            if mesh is None:
+                return impl
+
+            @functools.wraps(impl)
+            def traced(*args, **kwargs):
+                with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                    return impl(*args, **kwargs)
+
+            return traced
+
         self._step_fn = jax.jit(
-            self._step_impl, donate_argnums=(1,),
+            under_mesh(self._step_impl), donate_argnums=(1,),
             static_argnames=("prefix_blocks", "k_cand", "exact"),
         )
         self._multi_fn = jax.jit(
-            self._multi_impl, donate_argnums=(1,),
+            under_mesh(self._multi_impl), donate_argnums=(1,),
             static_argnames=("num_steps", "k_cand", "exact", "use_penalties"),
         )
         self._spec_fn = jax.jit(
-            self._spec_impl, donate_argnums=(1,),
+            under_mesh(self._spec_impl), donate_argnums=(1,),
             static_argnames=("k_cand", "exact"),
         )
         self._ragged_fn = jax.jit(
-            self._ragged_impl, donate_argnums=(1,),
+            under_mesh(self._ragged_impl), donate_argnums=(1,),
             static_argnames=("prefix_blocks", "k_cand", "exact"),
         )
         # the fifth donated serving impl: unified mixed prefill+decode
         # dispatch (decode rows + prefill spans on one flat token axis)
         self._unified_fn = jax.jit(
-            self._unified_impl, donate_argnums=(1,),
+            under_mesh(self._unified_impl), donate_argnums=(1,),
             static_argnames=("row_tokens", "prefix_blocks", "k_cand",
                              "exact"),
         )
@@ -609,7 +626,7 @@ class EngineCore:
         # (turn 0 = unified mixed step, then a multi-step decode scan
         # over the unified row axis — ONE device_get per burst)
         self._burst_fn = jax.jit(
-            self._burst_impl, donate_argnums=(1,),
+            under_mesh(self._burst_impl), donate_argnums=(1,),
             static_argnames=("num_steps", "row_tokens", "prefix_blocks",
                              "k_cand", "exact", "use_penalties"),
         )
@@ -877,6 +894,28 @@ class EngineCore:
             self._cache_specs,
             is_leaf=lambda x: isinstance(x, PartitionSpec),
         )
+
+    def attention_impls(self) -> dict[str, tuple[str, str]]:
+        """phase -> ("pallas" | "xla", why), as the dispatch in
+        ops/paged_attention.py decides it for this engine's geometry and
+        mesh (the same static rule, asked up front for the start-up
+        line).  ``windowed`` is the worst case over a request's life."""
+        from dynamo_tpu.ops.paged_attention import (
+            ATTENTION_PHASES,
+            attention_impl,
+        )
+
+        window = getattr(self.model.config, "sliding_window", None)
+        tp = 1 if self.mesh is None else self.mesh.shape.get(AXIS_MODEL, 1)
+        return {
+            phase: attention_impl(
+                phase, num_kv_heads=self.model.config.num_kv_heads,
+                block_size=self.config.block_size, quant=self.cache_quant,
+                windowed=(window is not None
+                          and self.config.max_model_len > window),
+                tp=tp)
+            for phase in ATTENTION_PHASES
+        }
 
     # ------------------------------------------------------- JSON grammar
     def attach_grammar_tokenizer(self, tokenizer, eos_ids=None) -> None:

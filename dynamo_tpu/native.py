@@ -30,6 +30,7 @@ _NATIVE_DIR = os.path.join(_REPO, "native")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
+_built_here = False
 
 _u64p = ctypes.POINTER(ctypes.c_uint64)
 _u32p = ctypes.POINTER(ctypes.c_uint32)
@@ -81,6 +82,7 @@ def _declare(lib: ctypes.CDLL) -> None:
 
 
 def _try_build() -> bool:
+    global _built_here
     if not os.path.isdir(_NATIVE_DIR):
         return False
     try:
@@ -88,7 +90,8 @@ def _try_build() -> bool:
             ["make", "-C", _NATIVE_DIR],
             check=True, capture_output=True, timeout=120,
         )
-        return os.path.exists(_LIB_PATH)
+        _built_here = os.path.exists(_LIB_PATH)
+        return _built_here
     except (OSError, subprocess.SubprocessError) as e:
         log.debug("native build failed: %s", e)
         return False
@@ -117,6 +120,16 @@ def load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return load() is not None
+
+
+def describe() -> str:
+    """Which implementation is live, for a server's start-up line — the
+    fall to the Python implementations is otherwise silent."""
+    lib = load()
+    if lib is None:
+        return "python (native library unavailable)"
+    how = "built by make -C native" if _built_here else "found prebuilt"
+    return f"native v{lib.dyn_native_version().decode()} ({how})"
 
 
 def _as_u64(arr: Sequence[int] | np.ndarray) -> np.ndarray:

@@ -20,16 +20,21 @@ TPU rebuild owns natively (SURVEY.md §7 stage 4).
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.ops.kv_quant import (
     QuantKvCache, dequant_layer_slice, is_quant, quantize_kv_rows,
 )
+from dynamo_tpu.utils.mesh import AXIS_MODEL
 
 __all__ = [
+    "ATTENTION_PHASES",
+    "attention_impl",
     "softcap",
     "write_kv_cache",
     "write_kv_cache_layer",
@@ -45,36 +50,80 @@ def softcap(x: jax.Array, cap: float) -> jax.Array:
     return jnp.tanh(x / cap) * cap
 
 
-def _pallas_decode_enabled() -> bool:
-    """Use the Pallas flash-decoding kernel for S=1 steps on TPU."""
-    if os.environ.get("DYNAMO_DISABLE_PALLAS"):
-        return False
-    if os.environ.get("DYNAMO_DISABLE_PALLAS_DECODE"):
-        return False
-    return jax.default_backend() == "tpu"
-
-
-def _pallas_prefill_enabled() -> bool:
-    """Use the Pallas flash-prefill kernel for S>1 steps on TPU."""
-    if os.environ.get("DYNAMO_DISABLE_PALLAS"):
-        return False
-    if os.environ.get("DYNAMO_DISABLE_PALLAS_PREFILL"):
-        return False
-    return jax.default_backend() == "tpu"
-
-
 MQ_MAX_S = 8  # multi-query decode kernel: trailing-query count it serves
 
+# phase -> the env knob that pins it to the XLA oracle (tests and A/B runs;
+# DYNAMO_DISABLE_PALLAS pins all four)
+ATTENTION_PHASES = {
+    "decode": "DYNAMO_DISABLE_PALLAS_DECODE",
+    "mq": "DYNAMO_DISABLE_PALLAS_MQ",
+    "prefill": "DYNAMO_DISABLE_PALLAS_PREFILL",
+    "ragged": "DYNAMO_DISABLE_PALLAS_PREFILL",
+}
 
-def _pallas_mq_enabled() -> bool:
-    """Use the multi-query flash-decode kernel for small S>1 steps on TPU
-    (the speculative-verify shape; positions must be contiguous per row,
-    which every in-repo caller guarantees)."""
-    if os.environ.get("DYNAMO_DISABLE_PALLAS"):
-        return False
-    if os.environ.get("DYNAMO_DISABLE_PALLAS_MQ"):
-        return False
-    return jax.default_backend() == "tpu"
+
+def attention_impl(
+    phase: str, *, num_kv_heads: int, block_size: int, quant: bool = False,
+    windowed: bool = False, tp: int = 1,
+) -> tuple[str, str]:
+    """``("pallas" | "xla", why)`` for one attention phase — the ONE
+    place the choice is made, before tracing, as a static function of
+    the environment, the backend and the geometry.  The dispatch sites
+    below and the server's start-up line both read it; nothing on the
+    serving path catches a kernel error and falls back, so a kernel
+    this says ``pallas`` for either compiles or takes the server down.
+
+    ``windowed``: the static attended span can exceed a sliding window
+    (the kernels are full-attention).  ``tp``: size of the mesh's
+    tensor-parallel axis the call is traced under; the kernels then run
+    per kv-head shard under ``shard_map``.
+    """
+    for var in ("DYNAMO_DISABLE_PALLAS", ATTENTION_PHASES[phase]):
+        if os.environ.get(var):
+            return "xla", f"{var} is set"
+    backend = jax.default_backend()
+    if backend != "tpu":
+        return "xla", f"backend is {backend}"
+    if windowed:
+        return "xla", "sliding window shorter than the attended span"
+    if quant and block_size % 32:
+        # int8 payload tiles are (32, 128): Bs % 32 != 0 pads the block's
+        # sublane dim and the kernels' per-block DMA cannot slice a
+        # partial tile
+        return "xla", "int8 KV needs block_size % 32 == 0"
+    if tp > 1 and num_kv_heads % tp:
+        return "xla", f"{num_kv_heads} kv heads do not split over tp={tp}"
+    if tp > 1 and quant:
+        # the scale pool's head axis is tile-padded, so an even split of
+        # it does not follow the data's head-major lane split
+        return "xla", "int8 KV scale pool is not sharded per kv head"
+    return "pallas", "tpu" if tp == 1 else f"tpu, shard_map over tp={tp}"
+
+
+def _tp_size() -> int:
+    """Size of the tensor-parallel axis of the mesh the caller traces
+    under (the engine wraps its jitted steps in
+    ``jax.sharding.use_abstract_mesh``); 1 with no mesh in scope."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return 1 if mesh.empty else mesh.shape.get(AXIS_MODEL, 1)
+
+
+def _per_kv_head(kernel, tp: int, in_specs: tuple, out_spec):
+    """Run a Pallas kernel once per tensor-parallel shard.  Mosaic calls
+    cannot be partitioned by GSPMD; attention is independent per kv
+    head, so each device runs the kernel over its own H/tp query heads
+    and Hk/tp kv heads of the cache, tables and lengths replicated."""
+    if tp == 1:
+        return kernel
+    return jax.shard_map(
+        kernel, in_specs=in_specs, out_specs=out_spec, check_vma=False)
+
+
+# operand specs under _per_kv_head: arrays split on their head axis
+_HEADS3 = P(None, AXIS_MODEL, None)              # [B, H, D]
+_HEADS4 = P(None, None, AXIS_MODEL, None)        # [B, S, H|Hk, D]
+_CACHE = P(None, None, None, None, AXIS_MODEL)   # [L, N, 2, Bs, Hk*D]
+_REPL = P()
 
 
 def paged_attention_layer(
@@ -113,34 +162,39 @@ def paged_attention_layer(
     windowed = window is not None and block_tables.shape[1] * bs > window
     if not windowed:
         window = None  # static no-op: full attention is exact here
-    # int8 payload tiles are (32, 128): a quant cache with Bs % 32 != 0
-    # pads the block's sublane dim, and the kernels' manual per-block DMA
-    # cannot slice a partial tile — take the XLA dequant path instead
-    kernel_ok = (not quant or bs % 32 == 0) and not windowed
-    if s == 1 and kernel_ok and _pallas_decode_enabled():
-        from dynamo_tpu.ops.pallas.decode_attention import paged_decode_attention
-
-        # tuning knobs for on-chip sweeps (benchmarks/profile_decode.py):
-        # group size trades per-grid-step fixed cost against VMEM; the
-        # defaults fit 8B bf16 KV, int8 KV has headroom for larger groups
-        spg = int(os.environ.get("DYNAMO_DECODE_SEQS_PER_GROUP", "8"))
-        bpc = int(os.environ.get("DYNAMO_DECODE_BLOCKS_PER_CHUNK", "4"))
-        out = paged_decode_attention(
-            q[:, 0], cache, layer, block_tables, seq_lens, sm_scale=sm_scale,
-            logit_cap=logit_cap, seqs_per_group=spg, blocks_per_chunk=bpc,
-        )
-        return out[:, None]
-    if 1 < s <= MQ_MAX_S and kernel_ok and _pallas_mq_enabled():
-        # speculative-verify shape: a few trailing queries per row — stream
-        # only the owned blocks instead of gathering the padded table
+    tp = _tp_size()
+    phase = "decode" if s == 1 else "mq" if s <= MQ_MAX_S else None
+    if phase and attention_impl(
+            phase, num_kv_heads=hk, block_size=bs, quant=quant,
+            windowed=windowed, tp=tp)[0] == "pallas":
         from dynamo_tpu.ops.pallas.decode_attention import (
+            paged_decode_attention,
             paged_decode_attention_mq,
         )
 
-        return paged_decode_attention_mq(
-            q, cache, layer, block_tables, seq_lens, positions[:, 0],
-            sm_scale=sm_scale, logit_cap=logit_cap,
-        )
+        if s == 1:
+            # tuning knobs for on-chip sweeps (benchmarks/profile_decode.py):
+            # group size trades per-grid-step fixed cost against VMEM; the
+            # defaults fit 8B bf16 KV, int8 KV has headroom for larger groups
+            spg = int(os.environ.get("DYNAMO_DECODE_SEQS_PER_GROUP", "8"))
+            bpc = int(os.environ.get("DYNAMO_DECODE_BLOCKS_PER_CHUNK", "4"))
+            kernel = functools.partial(
+                paged_decode_attention, sm_scale=sm_scale,
+                logit_cap=logit_cap, seqs_per_group=spg,
+                blocks_per_chunk=bpc)
+            out = _per_kv_head(
+                kernel, tp, (_HEADS3, _CACHE, _REPL, _REPL, _REPL), _HEADS3,
+            )(q[:, 0], cache, layer, block_tables, seq_lens)
+            return out[:, None]
+        # speculative-verify shape: a few trailing queries per row — stream
+        # only the owned blocks instead of gathering the padded table
+        kernel = functools.partial(
+            paged_decode_attention_mq, sm_scale=sm_scale,
+            logit_cap=logit_cap)
+        return _per_kv_head(
+            kernel, tp, (_HEADS4, _CACHE, _REPL, _REPL, _REPL, _REPL),
+            _HEADS4,
+        )(q, cache, layer, block_tables, seq_lens, positions[:, 0])
 
     layer_kv = jax.lax.dynamic_index_in_dim(data, layer, axis=0, keepdims=False)
     if quant:
@@ -196,9 +250,10 @@ def prefill_attention(
     windowed = window is not None and prefix_blocks * bs_ + s > window
     if not windowed:
         window = None
-    # same (32, 128) int8 tile constraint as the decode dispatch
-    kernel_ok = (not quant or bs_ % 32 == 0) and not windowed
-    if s > 1 and kernel_ok and _pallas_prefill_enabled():
+    tp = _tp_size()
+    if s > 1 and attention_impl(
+            "prefill", num_kv_heads=hk, block_size=bs_, quant=quant,
+            windowed=windowed, tp=tp)[0] == "pallas":
         # flash path: online softmax, scores never leave VMEM; the cached
         # prefix streams from HBM by its TRUE length (start), so the
         # static prefix_blocks bucket doesn't even force recompiles here
@@ -206,10 +261,13 @@ def prefill_attention(
             paged_prefill_attention,
         )
 
-        return paged_prefill_attention(
-            q, k_new, v_new, cache, layer, block_tables, seq_lens, start,
-            sm_scale=sm_scale, logit_cap=logit_cap,
-        )
+        kernel = functools.partial(
+            paged_prefill_attention, sm_scale=sm_scale, logit_cap=logit_cap)
+        return _per_kv_head(
+            kernel, tp,
+            (_HEADS4, _HEADS4, _HEADS4, _CACHE) + (_REPL,) * 4, _HEADS4,
+        )(q, k_new, v_new, cache, layer, block_tables, seq_lens, start)
+
     qg = q.reshape(b, s, hk, g, d).astype(jnp.float32)
     fresh = (seq_lens - start)[:, None, None]  # valid fresh tokens per row
 
@@ -315,16 +373,22 @@ def ragged_prefill_attention(
     windowed = window is not None and prefix_blocks * bs + t > window
     if not windowed:
         window = None
-    kernel_ok = (not quant or bs % 32 == 0) and not windowed
-    if t > 1 and kernel_ok and _pallas_prefill_enabled():
+    tp = _tp_size()
+    if t > 1 and attention_impl(
+            "ragged", num_kv_heads=hk, block_size=bs, quant=quant,
+            windowed=windowed, tp=tp)[0] == "pallas":
         from dynamo_tpu.ops.pallas.prefill_attention import (
             ragged_paged_prefill_attention,
         )
 
-        return ragged_paged_prefill_attention(
-            q, k_new, v_new, cache, layer, block_tables, seq_lens, starts,
-            row_offsets, sm_scale=sm_scale, logit_cap=logit_cap,
-        )
+        kernel = functools.partial(
+            ragged_paged_prefill_attention, sm_scale=sm_scale,
+            logit_cap=logit_cap)
+        return _per_kv_head(
+            kernel, tp,
+            (_HEADS4, _HEADS4, _HEADS4, _CACHE) + (_REPL,) * 5, _HEADS4,
+        )(q, k_new, v_new, cache, layer, block_tables, seq_lens, starts,
+          row_offsets)
 
     qg = q[0].reshape(t, hk, g, d).astype(jnp.float32)
     sid = seq_ids[0]                              # [T]

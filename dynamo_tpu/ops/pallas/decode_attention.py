@@ -361,12 +361,11 @@ def paged_decode_attention_mq(
     )
 
     # Honest scheduling hint: seq_lens are dynamic, so price the static
-    # worst case (every row at full-table context).  None on older jax.
+    # worst case (every row at full-table context).
     cost = decode_cost_estimate(
         b, s_q, h, hk, d, bs, m, cache_bytes=data.dtype.itemsize,
         quant=quant, blocks_per_chunk=blocks_per_chunk,
         seqs_per_group=seqs_per_group)
-    cost_kw = {} if cost is None else {"cost_estimate": cost}
 
     out = pl.pallas_call(
         functools.partial(_kernel_quant if quant else _kernel, c=c, g=g,
@@ -374,7 +373,7 @@ def paged_decode_attention_mq(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, hkd), q.dtype),
         interpret=interpret,
-        **cost_kw,
+        cost_estimate=cost,
     )(*operands)
 
     # Collapse the block-diagonal layout back to [B, S, H, D].

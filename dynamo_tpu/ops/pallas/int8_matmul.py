@@ -1,6 +1,5 @@
-"""Dequant-in-kernel int8 matmul — the standby fix for perf hypothesis #2.
-
-docs/perf_analysis_r3.md: if the profiler shows XLA materializing
+"""Dequant-in-kernel int8 matmul — the standby fix for a decode-ITL
+hypothesis (ROADMAP S3): if the profiler shows XLA materializing
 bf16-converted weight tiles to HBM (instead of fusing the convert into
 the matmul operand load), int8 weight-only serving loses its entire
 bandwidth win. This kernel guarantees the int8->bf16 convert happens in

@@ -15,9 +15,12 @@ Semantics match ops.paged_attention.prefill_attention:
   * fresh-prefix attention is full over slots [0, start),
   * query padding rows (index >= seq_len - start) yield 0.
 
-Grid: (B, S/TQ).  GQA is handled per kv-head: q rows fold the G query
-heads into the row axis ([TQ, G*D] -> [TQ*G, D]), so scores and PV are
-plain MXU matmuls.  SURVEY.md §7 hard part 3; VERDICT r2 ask #4.
+Grid: (B, S/TQ).  GQA is handled per kv-head: q arrives head-group-major
+([Hk, G, TQ, D]) and the G query heads fold into the row axis by a
+leading-dim merge ([G, TQ, D] -> [G*TQ, D]), so scores and PV are plain
+MXU matmuls at any head_dim — a [TQ, G*D] -> [TQ*G, D] lane regroup only
+compiles when D is a whole 128-lane tile, which Llama-3.2-1B (D=64) is
+not.  SURVEY.md §7 hard part 3.
 """
 
 from __future__ import annotations
@@ -73,22 +76,22 @@ def _kernel_impl(
     bt_ref,      # [B, M] int32
     layer_ref,   # [1] int32
     # inputs
-    q_ref,       # [1, Hk, TQ, G*D] VMEM — this grid step's query rows.
-    #              The kv-head axis LEADS (outside the tiled minor-2 dims):
-    #              per-head reads are then plain leading-index loads —
-    #              `[1, TQ, Hk, G*D]` with h in the sublane slot made
-    #              Mosaic reject the kernel (sublane slices of extent 1
-    #              aren't tile-aligned).
+    q_ref,       # [1, Hk, G, TQ, D] VMEM — this grid step's query rows.
+    #              The kv-head and group axes LEAD (outside the tiled
+    #              minor-2 dims): per-head reads are then plain
+    #              leading-index loads — `[1, TQ, Hk, G*D]` with h in the
+    #              sublane slot made Mosaic reject the kernel (sublane
+    #              slices of extent 1 aren't tile-aligned).
     k_ref,       # [1, S, Hk*D] VMEM — whole fresh K (chunk-resident)
     v_ref,       # [1, S, Hk*D] VMEM
     cache_ref,   # [L, N, 2, Bs, Hk*D] HBM (manual DMA)
     scale_ref,   # [L, N, 2, Hp, Sp] HBM f32 (tile-padded), or None (bf16)
     # outputs
-    out_ref,     # [1, Hk, TQ, G*D] VMEM (head-leading, as q_ref)
+    out_ref,     # [1, Hk, G, TQ, D] VMEM (head-leading, as q_ref)
     # scratch
-    acc_ref,     # [Hk, TQ*G, D] f32
-    m_ref,       # [Hk, TQ*G, 128] f32
-    l_ref,       # [Hk, TQ*G, 128] f32
+    acc_ref,     # [Hk, G*TQ, D] f32
+    m_ref,       # [Hk, G*TQ, 128] f32
+    l_ref,       # [Hk, G*TQ, 128] f32
     kvbuf,       # [2, C, 2, Bs, Hk*D] cache-dtype (double buffer)
     sems,        # [2, C] DMA semaphores
     scbuf,       # [2, C, 2, Hp, Sp] f32, or None
@@ -116,10 +119,12 @@ def _kernel_impl(
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
 
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tq * g, 1), 0) // g  # query row
+    # rows are (group, query)-major: row r is query r % TQ of group r // TQ
+    rows = jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (g * tq, 1), 0), tq)
 
     def flash_update(h, s_scores, v_cols, p_scale=None):
-        """Online-softmax fold of one [TQ*G, TKV] score tile (masked).
+        """Online-softmax fold of one [G*TQ, TKV] score tile (masked).
         ``p_scale`` [1, TKV] rescales P before the PV product (int8 V
         dequant folded per column; softmax stats use the true probs)."""
         m_prev = m_ref[h, :, :1]
@@ -133,8 +138,8 @@ def _kernel_impl(
         acc_ref[h] = acc_ref[h] * alpha + pv
 
     def q_head(h):
-        # [TQ, G*D] -> [TQ*G, D], pre-scaled f32
-        return q_ref[0, h].reshape(tq * g, d).astype(jnp.float32) * sm_scale
+        # [G, TQ, D] -> [G*TQ, D], pre-scaled f32
+        return q_ref[0, h].reshape(g * tq, d).astype(jnp.float32) * sm_scale
 
     # ---------------------------------------------------- prefix phase (DMA)
     def block_dmas(ci, slot):
@@ -193,7 +198,7 @@ def _kernel_impl(
             s_ = jax.lax.dot_general(
                 q_head(h), kc[:, h * d:(h + 1) * d],
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            )  # [TQ*G, T]
+            )  # [G*TQ, T]
             if quant:
                 # K's per-token scale multiplies score columns; V's folds
                 # into P inside flash_update's PV product via p_scale
@@ -214,7 +219,7 @@ def _kernel_impl(
         vc = v_ref[0, pl.ds(col0, tq)].astype(jnp.float32)
         col = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, tq), 1)
         # causal by fresh index + clip padding columns
-        allow = (col <= ri * tq + rows) & (col < fresh)      # [TQ*G, TQ]
+        allow = (col <= ri * tq + rows) & (col < fresh)      # [G*TQ, TQ]
         # fresh padding tokens may be non-finite — zero their V rows
         vc = jnp.where(col0 + jax.lax.broadcasted_iota(
             jnp.int32, (tq, 1), 0) < fresh, vc, 0.0)
@@ -234,7 +239,7 @@ def _kernel_impl(
     for h in range(hk):
         denom = jnp.maximum(l_ref[h, :, :1], 1e-9)  # padding rows → 0
         out_ref[0, h] = (
-            (acc_ref[h] / denom).reshape(tq, g * d).astype(out_ref.dtype)
+            (acc_ref[h] / denom).reshape(g, tq, d).astype(out_ref.dtype)
         )
 
 
@@ -280,13 +285,15 @@ def paged_prefill_attention(
         tq //= 2
     c = min(blocks_per_chunk, m)
 
-    # head-leading query layout (see kernel docstring): [B, Hk, S, G*D]
-    q_in = q.reshape(b, s, hk, g * d).transpose(0, 2, 1, 3)
+    # head-group-leading query layout (see kernel docstring):
+    # [B, Hk, G, S, D]
+    q_in = q.reshape(b, s, hk, g, d).transpose(0, 2, 3, 1, 4)
     k_in = k_new.reshape(b, s, hkd)
     v_in = v_new.reshape(b, s, hkd)
 
     in_specs = [
-        pl.BlockSpec((1, hk, tq, g * d), lambda bi, ri, *_: (bi, 0, ri, 0)),
+        pl.BlockSpec((1, hk, g, tq, d),
+                     lambda bi, ri, *_: (bi, 0, 0, ri, 0)),
         pl.BlockSpec((1, s, hkd), lambda bi, ri, *_: (bi, 0, 0)),
         pl.BlockSpec((1, s, hkd), lambda bi, ri, *_: (bi, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),  # cache stays in HBM
@@ -322,18 +329,17 @@ def paged_prefill_attention(
         grid=(b, s // tq),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, hk, tq, g * d), lambda bi, ri, *_: (bi, 0, ri, 0)
+            (1, hk, g, tq, d), lambda bi, ri, *_: (bi, 0, 0, ri, 0)
         ),
         scratch_shapes=scratch,
     )
 
     # Honest scheduling hint at the static worst case (full-table
-    # prefixes) — seq_lens/start are dynamic.  None on older jax.
+    # prefixes) — seq_lens/start are dynamic.
     cost = prefill_cost_estimate(
         b, s, h, hk, d, bs, m, cache_bytes=data.dtype.itemsize,
         quant=quant, rows_per_chunk=rows_per_chunk,
         blocks_per_chunk=blocks_per_chunk)
-    cost_kw = {} if cost is None else {"cost_estimate": cost}
 
     out = pl.pallas_call(
         functools.partial(
@@ -342,12 +348,12 @@ def paged_prefill_attention(
             logit_cap=logit_cap,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, s, g * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hk, g, s, d), q.dtype),
         interpret=interpret,
-        **cost_kw,
+        cost_estimate=cost,
     )(*operands)
-    # [B, Hk, S, G*D] -> [B, S, H, D]
-    return out.transpose(0, 2, 1, 3).reshape(b, s, h, d)
+    # [B, Hk, G, S, D] -> [B, S, H, D]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)
 
 
 # --------------------------------------------------------- ragged prefill
@@ -403,17 +409,17 @@ def _ragged_kernel_impl(
     bt_ref,      # [R, M] int32
     layer_ref,   # [1] int32
     # inputs
-    q_ref,       # [1, Hk, TQ, G*D] VMEM — this grid step's query rows
+    q_ref,       # [1, Hk, G, TQ, D] VMEM — this grid step's query rows
     k_ref,       # [1, T, Hk*D] VMEM — whole packed fresh K
     v_ref,       # [1, T, Hk*D] VMEM
     cache_ref,   # [L, N, 2, Bs, Hk*D] HBM (manual DMA)
     scale_ref,   # [L, N, 2, Hp, Sp] HBM f32, or None (bf16 cache)
     # outputs
-    out_ref,     # [1, Hk, TQ, G*D] VMEM
+    out_ref,     # [1, Hk, G, TQ, D] VMEM
     # scratch
-    acc_ref,     # [Hk, TQ*G, D] f32
-    m_ref,       # [Hk, TQ*G, 128] f32
-    l_ref,       # [Hk, TQ*G, 128] f32
+    acc_ref,     # [Hk, G*TQ, D] f32
+    m_ref,       # [Hk, G*TQ, 128] f32
+    l_ref,       # [Hk, G*TQ, 128] f32
     kvbuf,       # [2, C, 2, Bs, Hk*D] cache-dtype (double buffer)
     sems,        # [2, C] DMA semaphores
     scbuf,       # [2, C, 2, Hp, Sp] f32, or None
@@ -439,8 +445,9 @@ def _ragged_kernel_impl(
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
     l_ref[:] = jnp.zeros_like(l_ref)
 
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tq * g, 1), 0) // g
-    qflat = q0 + rows                      # [TQ*G, 1] flat query index
+    rows = jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (g * tq, 1), 0), tq)
+    qflat = q0 + rows                      # [G*TQ, 1] flat query index
 
     def sid_at(x):
         """Row id per flat index in ``x`` (-1 = padding), from the span
@@ -451,7 +458,7 @@ def _ragged_kernel_impl(
         return jax.lax.fori_loop(
             0, r_rows, body, jnp.full(x.shape, -1, jnp.int32))
 
-    sid_q = sid_at(qflat)                  # [TQ*G, 1]
+    sid_q = sid_at(qflat)                  # [G*TQ, 1]
 
     def flash_update(h, s_scores, v_cols, p_scale=None):
         m_prev = m_ref[h, :, :1]
@@ -465,7 +472,7 @@ def _ragged_kernel_impl(
         acc_ref[h] = acc_ref[h] * alpha + pv
 
     def q_head(h):
-        return q_ref[0, h].reshape(tq * g, d).astype(jnp.float32) * sm_scale
+        return q_ref[0, h].reshape(g * tq, d).astype(jnp.float32) * sm_scale
 
     # ------------------------------------------------ prefix phase (per row)
     def block_dmas(r, ci, slot):
@@ -581,7 +588,7 @@ def _ragged_kernel_impl(
     for h in range(hk):
         denom = jnp.maximum(l_ref[h, :, :1], 1e-9)  # keep padding finite
         out_ref[0, h] = (
-            (acc_ref[h] / denom).reshape(tq, g * d).astype(out_ref.dtype)
+            (acc_ref[h] / denom).reshape(g, tq, d).astype(out_ref.dtype)
         )
 
 
@@ -628,13 +635,13 @@ def ragged_paged_prefill_attention(
         tq //= 2
     c = min(blocks_per_chunk, m)
 
-    q_in = q.reshape(1, t, hk, g * d).transpose(0, 2, 1, 3)
+    q_in = q.reshape(1, t, hk, g, d).transpose(0, 2, 3, 1, 4)
     k_in = k_new.reshape(1, t, hkd)
     v_in = v_new.reshape(1, t, hkd)
     row_ends = row_offsets + (seq_lens - starts)  # one past last real token
 
     in_specs = [
-        pl.BlockSpec((1, hk, tq, g * d), lambda ri, *_: (0, 0, ri, 0)),
+        pl.BlockSpec((1, hk, g, tq, d), lambda ri, *_: (0, 0, 0, ri, 0)),
         pl.BlockSpec((1, t, hkd), lambda ri, *_: (0, 0, 0)),
         pl.BlockSpec((1, t, hkd), lambda ri, *_: (0, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),  # cache stays in HBM
@@ -671,7 +678,7 @@ def ragged_paged_prefill_attention(
         grid=(t // tq,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, hk, tq, g * d), lambda ri, *_: (0, 0, ri, 0)
+            (1, hk, g, tq, d), lambda ri, *_: (0, 0, 0, ri, 0)
         ),
         scratch_shapes=scratch,
     )
@@ -680,7 +687,6 @@ def ragged_paged_prefill_attention(
         t, r_rows, h, hk, d, bs, m, cache_bytes=data.dtype.itemsize,
         quant=quant, rows_per_chunk=rows_per_chunk,
         blocks_per_chunk=blocks_per_chunk)
-    cost_kw = {} if cost is None else {"cost_estimate": cost}
 
     out = pl.pallas_call(
         functools.partial(
@@ -689,9 +695,9 @@ def ragged_paged_prefill_attention(
             sm_scale=float(sm_scale), logit_cap=logit_cap,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, hk, t, g * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((1, hk, g, t, d), q.dtype),
         interpret=interpret,
-        **cost_kw,
+        cost_estimate=cost,
     )(*operands)
-    # [1, Hk, T, G*D] -> [1, T, H, D]
-    return out.transpose(0, 2, 1, 3).reshape(1, t, h, d)
+    # [1, Hk, G, T, D] -> [1, T, H, D]
+    return out.transpose(0, 3, 1, 2, 4).reshape(1, t, h, d)

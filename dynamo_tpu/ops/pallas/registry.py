@@ -341,13 +341,10 @@ def _cost_dict(dma: int, flops: int, trans: int) -> dict:
 
 
 def _cost_estimate(cost: dict):
-    """dict -> pl.CostEstimate (None when this jax predates it)."""
+    """dict -> pl.CostEstimate."""
     from jax.experimental import pallas as pl
 
-    ce = getattr(pl, "CostEstimate", None)
-    if ce is None:  # pragma: no cover - older jax
-        return None
-    return ce(
+    return pl.CostEstimate(
         flops=cost["flops"],
         transcendentals=cost["transcendentals"],
         bytes_accessed=cost["hbm_bytes"],
@@ -395,8 +392,8 @@ def fallback_census() -> dict:
     (KN006) — retiring a kernel, or landing the unified kernel, must
     update BOTH planes deliberately."""
     return {
-        "probe.llama.decode[tiny-llama]": {"all-gather": 6},
-        "probe.deepseek.decode[tiny-mla]": {"all-gather": 7},
+        "probe.llama.decode[tiny-llama]": {"all-gather": 4, "all-to-all": 1},
+        "probe.deepseek.decode[tiny-mla]": {"all-gather": 10},
     }
 
 

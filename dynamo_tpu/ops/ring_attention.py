@@ -140,24 +140,11 @@ def ring_attention(
     )
     seq = P(None, axis, None, None)
     pos = P(None, axis)
-    if hasattr(jax, "shard_map"):
-        wrapped = jax.shard_map(
-            inner,
-            mesh=mesh,
-            in_specs=(seq, seq, seq, pos, pos),
-            out_specs=seq,
-            check_vma=False,
-        )
-    else:
-        # jax < 0.6: shard_map lives in jax.experimental and the
-        # replication-check kwarg is check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        wrapped = _shard_map(
-            inner,
-            mesh=mesh,
-            in_specs=(seq, seq, seq, pos, pos),
-            out_specs=seq,
-            check_rep=False,
-        )
+    wrapped = jax.shard_map(
+        inner,
+        mesh=mesh,
+        in_specs=(seq, seq, seq, pos, pos),
+        out_specs=seq,
+        check_vma=False,
+    )
     return wrapped(q, k, v, q_pos, kv_pos)

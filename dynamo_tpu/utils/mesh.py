@@ -92,4 +92,4 @@ def abstract_mesh(topology, axes: Sequence[str] = MESH_AXES):
             f"mesh topology {shape} has {len(shape)} axes but "
             f"{len(axes)} names {axes}"
         )
-    return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.sharding.AbstractMesh(shape, axes)

@@ -2,7 +2,6 @@
 
 Tests run on a virtual 8-device CPU mesh so multi-chip sharding logic is
 exercised without TPU hardware (the driver's dryrun does the same).
-See dynamo_tpu/utils/platform.py for why env vars alone are too late.
 """
 
 import os
@@ -21,17 +20,11 @@ force_cpu_devices(8)
 # closure, so jax's in-memory cache never hits across tests.  The disk
 # cache is keyed by serialized HLO and dedupes those compiles within one
 # run (and warm-starts repeat runs) — it shaves minutes off the tier-1
-# wall clock without changing what executes.  DYNAMO_TEST_XLA_CACHE_DIR
-# overrides the location; "0" disables.
-import tempfile  # noqa: E402
-
+# wall clock without changing what executes.  Same placement as the
+# serving entrypoints: JAX_COMPILATION_CACHE_DIR, else <checkout>/.cache/xla.
 from dynamo_tpu.utils.compilation_cache import enable_persistent_cache  # noqa: E402
 
-_xla_cache_dir = os.environ.get("DYNAMO_TEST_XLA_CACHE_DIR")
-if _xla_cache_dir != "0":
-    enable_persistent_cache(
-        _xla_cache_dir
-        or os.path.join(tempfile.gettempdir(), "dynamo-tpu-test-xla-cache"))
+enable_persistent_cache()
 
 # dtsan runtime sanitizer (docs/static_analysis.md#runtime-sanitizer):
 # task-LEAK checking is on by default in tier-1; DYNAMO_SANITIZE=1

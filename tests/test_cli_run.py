@@ -91,3 +91,78 @@ def test_worker_config_kv_quant_and_sp_reach_engine(model_dir):
         assert core.config.sp_prefill_threshold == 64
     finally:
         engine.shutdown()
+
+
+def test_run_out_tpu_without_a_chip_exits_nonzero(model_dir):
+    """out=tpu means the chip: with JAX_PLATFORMS unset and no TPU, JAX
+    would fall back to the CPU quietly — the CLI must refuse instead.
+    (Every other test here sets JAX_PLATFORMS=cpu, which is the caller
+    asking for the CPU on purpose.)"""
+    env = dict(os.environ, PYTHONPATH=str(REPO))
+    env.pop("JAX_PLATFORMS", None)
+    out = subprocess.run(
+        [sys.executable, "-m", "dynamo_tpu.cli", "run", "in=text:hello",
+         "out=tpu", "--model-path", str(model_dir), "--max-tokens", "2"],
+        capture_output=True, text=True, timeout=300, cwd=str(REPO), env=env)
+    assert out.returncode != 0
+    assert "out=tpu found no TPU" in out.stderr
+    assert "startup" not in out.stderr  # refused before loading anything
+
+
+def test_step_that_cannot_compile_brings_the_server_down(model_dir, tmp_path):
+    """A step that raises while building its program is not a
+    per-request failure: the requests fail, the engine stops, and the
+    server exits non-zero instead of answering every request with an
+    error.  The failure is injected the way it happens on the chip — the
+    static rule says "pallas" for a kernel the backend cannot compile
+    (steered here by telling the dispatch the backend is a TPU while the
+    kernels can only lower for one)."""
+    import socket
+    import time
+    import urllib.error
+    import urllib.request
+
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    shim = (
+        "import jax, sys; jax.default_backend = lambda: 'tpu'; "
+        "from dynamo_tpu.cli import main; main(sys.argv[1:])")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO))
+    log_path = tmp_path / "server.log"  # not a pipe: nobody drains it
+    with open(log_path, "w") as log_file:
+        proc = subprocess.Popen(
+            [sys.executable, "-c", shim, "run", "in=http", "out=tpu",
+             "--model-path", str(model_dir), "--max-model-len", "64",
+             "--num-blocks", "16", "--max-batch-size", "2",
+             "--http-port", str(port)],
+            stdout=log_file, stderr=subprocess.STDOUT, cwd=str(REPO), env=env)
+    try:
+        url = f"http://127.0.0.1:{port}"
+        deadline = time.monotonic() + 120
+        while True:
+            assert proc.poll() is None, "server died before any request"
+            assert time.monotonic() < deadline, "server never came up"
+            try:
+                urllib.request.urlopen(url + "/health", timeout=2).read()
+                break
+            except (urllib.error.URLError, OSError):
+                time.sleep(0.3)
+        req = urllib.request.Request(
+            url + "/v1/completions",
+            data=json.dumps({"model": model_dir.name, "prompt": "hello world",
+                             "max_tokens": 4}).encode(),
+            headers={"content-type": "application/json"})
+        try:
+            urllib.request.urlopen(req, timeout=60).read()
+        except (urllib.error.URLError, OSError):
+            pass  # an error answer or a dropped connection: both fine
+        rc = proc.wait(timeout=60)
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+    log = log_path.read_text()
+    assert rc not in (0, None), log[-2000:]
+    assert "engine step failed" in log
+    assert "engine stopped" in log

@@ -373,7 +373,6 @@ def test_seeded_burst_compiles_once():
     (t, r, pb, num_steps) bucket, and an identical second run triggers
     ZERO further compile events — the speculative prebuild path must not
     smuggle in a retrace."""
-    import jax._src.monitoring as monitoring
 
     model, params = _runtime_model()
 
@@ -416,7 +415,7 @@ def test_seeded_burst_compiles_once():
     try:
         drive(core)  # identical seeded workload, fresh requests
     finally:
-        monitoring._unregister_event_listener_by_callback(listener)
+        jax.monitoring.unregister_event_listener(listener)
     assert compile_events == [], (
         f"second identical run recompiled: {compile_events}"
     )

@@ -565,3 +565,77 @@ def test_fuzz_case_deterministic_and_canary_clean():
     a, b = fuzz_case(1234), fuzz_case(1234)
     assert a["name"] == b["name"] == "fuzz[ragged-1234]"
     assert_canary_clean(a)
+
+
+# ------------------------------------------- kernels per tensor-parallel shard
+
+
+@pytest.mark.parametrize("phase", ["decode", "mq", "prefill", "ragged"])
+def test_dispatch_under_tp_mesh_runs_kernels_per_kv_head(phase, monkeypatch):
+    """--tp > 1: GSPMD cannot partition a Mosaic call, so the dispatch in
+    ops/paged_attention.py runs each kernel per kv-head shard under
+    shard_map when it is traced with a model-axis mesh in scope.  On four
+    virtual CPU devices (kernels in interpret mode, the backend gate
+    steered to "tpu") the sharded result must equal the unsharded XLA
+    oracle — a wrong head split shows up as wrong numbers, not a crash."""
+    import functools
+    import importlib
+
+    import jax
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from dynamo_tpu.ops.pallas import decode_attention, prefill_attention
+    from dynamo_tpu.ops.pallas.registry import (
+        probe_decode_inputs, probe_prefill_inputs, probe_ragged_inputs,
+    )
+
+    pa = importlib.import_module("dynamo_tpu.ops.paged_attention")
+    h, hk, d, bs, n, m, b = 8, 4, 32, 16, 16, 4, 2
+    f32 = jnp.float32
+    if phase in ("decode", "mq"):
+        s_q = 1 if phase == "decode" else 3
+        lens = np.asarray([s_q, m * bs - 5], np.int32)
+        q, cache, layer, bt, sl, q0 = probe_decode_inputs(
+            b, h, hk, d, bs, n, m, lens, dtype=f32, s_q=s_q)
+        pos = q0[:, None] + jnp.arange(s_q, dtype=jnp.int32)[None]
+        fn, args, cache_at = pa.paged_attention_layer, \
+            [q, cache, layer, bt, sl, pos], 1
+    elif phase == "prefill":
+        a = probe_prefill_inputs(b, 32, h, hk, d, bs, n, m, dtype=f32)
+        fn = functools.partial(pa.prefill_attention, prefix_blocks=1)
+        args, cache_at = list(a), 3
+    else:
+        a = probe_ragged_inputs(32, 2, h, hk, d, bs, n, m, dtype=f32)
+        sid = jnp.repeat(jnp.arange(2, dtype=jnp.int32), 16)[None]
+        fn = functools.partial(pa.ragged_prefill_attention, prefix_blocks=1)
+        args, cache_at = list(a) + [sid], 3
+    ref = fn(*args)  # backend is the CPU: the XLA oracle
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for mod, names in ((decode_attention, ("paged_decode_attention",
+                                           "paged_decode_attention_mq")),
+                       (prefill_attention, ("paged_prefill_attention",
+                                            "ragged_paged_prefill_attention"))):
+        for name in names:
+            monkeypatch.setattr(mod, name, functools.partial(
+                getattr(mod, name), interpret=True))
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 4), ("data", "model"))
+    heads = NamedSharding(mesh, P(None, None, "model", None))
+    args[0] = jax.device_put(args[0], heads)
+    if cache_at == 3:
+        args[1] = jax.device_put(args[1], heads)
+        args[2] = jax.device_put(args[2], heads)
+    args[cache_at] = jax.device_put(args[cache_at], NamedSharding(
+        mesh, P(None, None, None, None, "model")))
+
+    def under_mesh(*a):  # what EngineCore's jit does around its impls
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            assert pa.attention_impl(
+                phase, num_kv_heads=hk, block_size=bs,
+                tp=pa._tp_size()) == ("pallas", "tpu, shard_map over tp=4")
+            return fn(*a)
+
+    got = jax.jit(under_mesh)(*args)
+    assert got.sharding.spec == P(None, None, "model", None)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=3e-5)

@@ -250,17 +250,13 @@ def test_shard_map_collective_census_and_cost():
     """A psum inside shard_map over an abstract 4-way mesh produces a
     census entry with the right axis size and a nonzero analytic ring
     cost; the same code over a 1-way axis costs zero."""
-    try:
-        mesh = jax.sharding.AbstractMesh((("dp", 4),))
-    except Exception:
-        pytest.skip("no AbstractMesh in this jax build")
+    mesh = jax.sharding.AbstractMesh((4,), ("dp",))
     import functools
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    f = shard_map(lambda x: jax.lax.psum(x, "dp"), mesh=mesh,
-                  in_specs=P("dp"), out_specs=P(), check_rep=False)
+    f = jax.shard_map(lambda x: jax.lax.psum(x, "dp"), mesh=mesh,
+                      in_specs=P("dp"), out_specs=P(), check_vma=False)
     est = _est(f, _sds((64,)))
     (ckey, c), = est["collectives"].items()
     assert ckey == "psum:dp"
@@ -388,16 +384,12 @@ def fake_registry(monkeypatch):
     multi-second fact collection."""
     import functools
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
-    try:
-        mesh = jax.sharding.AbstractMesh((("dp", 2),))
-    except Exception:
-        pytest.skip("no AbstractMesh in this jax build")
-    f = shard_map(lambda x, w: jax.lax.psum(x @ w, "dp"), mesh=mesh,
-                  in_specs=(P(None, "dp"), P("dp", None)), out_specs=P(),
-                  check_rep=False)
+    mesh = jax.sharding.AbstractMesh((2,), ("dp",))
+    f = jax.shard_map(lambda x, w: jax.lax.psum(x @ w, "dp"), mesh=mesh,
+                      in_specs=(P(None, "dp"), P("dp", None)), out_specs=P(),
+                      check_vma=False)
 
     def build(n):
         return Signature(f"n={n}", (_sds((n, 2 * n)), _sds((2 * n, n))),
