@@ -221,8 +221,8 @@ def _build_local_engine(args) -> tuple[object, object]:
         # prebuild overlapped with device compute (implies unified)
         lookahead_dispatch=bool(
             getattr(args, "lookahead_dispatch", False)),
-        # dtspan profile hook: one jax.profiler capture over the first
-        # profile_steps device steps
+        # where POST /debug/profile writes (profile_steps: inert, see
+        # EngineConfig)
         profile_dir=(getattr(args, "profile_dir", None) or None),
         profile_steps=int(getattr(args, "profile_steps", 8) or 8),
     )
@@ -320,7 +320,8 @@ async def _cmd_run(args) -> None:
     elif args.inp == "http":
         from dynamo_tpu.llm.http.service import HttpService
 
-        svc = HttpService(host=args.host, port=args.http_port)
+        svc = HttpService(host=args.host, port=args.http_port,
+                          profile_dir=getattr(args, "profile_dir", None))
         svc.manager.add_model(model_name, engine, card)
         await svc.start()
         log.info("OpenAI server on %s:%s — ctrl-c to stop", svc.host, svc.port)
@@ -1050,10 +1051,11 @@ def _parser() -> argparse.ArgumentParser:
                      "DYNAMO_TRACE=1): per-request spans, exported as "
                      "Chrome trace JSON at /debug/traces/{request_id}")
     run.add_argument("--profile-dir", default=None,
-                     help="wrap the first --profile-steps engine device "
-                     "steps in ONE jax.profiler capture written under "
-                     "this directory (keyed by first step id)")
-    run.add_argument("--profile-steps", type=int, default=8)
+                     help="directory for the jax.profiler captures an "
+                     "operator asks for with POST /debug/profile?seconds=N "
+                     "(in=http; refused when unset)")
+    run.add_argument("--profile-steps", type=int, default=8,
+                     help="inert (captures are asked for in seconds)")
     common(run)
 
     serve = sub.add_parser("serve", help="serve a graph of @service components")

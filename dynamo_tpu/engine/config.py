@@ -125,10 +125,11 @@ class EngineConfig:
     prefill_buckets: list[int] = field(default_factory=list)
     # sharding: data/model axis sizes; 1,1 = single chip
     mesh_shape: tuple[int, int] = (1, 1)
-    # dtspan profile hook: when profile_dir is set, the engine wraps the
-    # first profile_steps device steps in ONE jax.profiler capture
-    # written under profile_dir/steps-<first step id>/ (CLI:
-    # --profile-dir / --profile-steps on serve/http)
+    # where ``POST /debug/profile`` on the HTTP service writes its
+    # jax.profiler captures (CLI: --profile-dir); the engine itself starts
+    # no capture.  profile_steps is inert since PR 25 (captures are asked
+    # for in seconds, when the server is warm): the field and its flag stay
+    # only because cellbench/server.py still passes them.
     profile_dir: Optional[str] = None
     profile_steps: int = 8
     # rng

@@ -35,7 +35,6 @@ import concurrent.futures
 import dataclasses
 import functools
 import logging
-import os
 import queue
 import threading
 import time
@@ -47,7 +46,7 @@ import numpy as np
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.counters import counters as prefill_counters
-from dynamo_tpu.engine.counters import lookahead_counters
+from dynamo_tpu.engine.counters import lookahead_counters, request_counters
 from dynamo_tpu.engine.grammar import (
     INIT_STATE, JsonGrammar, compile_choice_vocab, compile_regex_vocab,
     compose_tables, device_tables, grammar_advance, grammar_mask,
@@ -710,6 +709,13 @@ class EngineCore:
         self.lookahead_flushes = 0       # speculative prebuilds discarded
         self.lookahead_depth = 0         # device turns per device_get (last)
         self.device_gets = 0             # step-loop jax.device_get calls
+        # counted where the work happens (cellbench reads them as core.*)
+        self.decode_dispatches = 0       # pure-decode dispatches (burst/spec)
+        self.decode_rows_dispatched = 0  # RUNNING rows packed over them
+        self.requests_finished = 0       # any finish reason
+        self.requests_cut_short = 0      # LENGTH because block space ran out
+        self.first_tokens = 0            # requests that emitted a first token
+        self.first_token_s = 0.0         # sum of (first emit - submitted_at)
         # speculative next-turn dispatch operands, built during the
         # overlap window while the device computes (committed next turn
         # if the predicted plan held, flushed otherwise)
@@ -718,11 +724,6 @@ class EngineCore:
         # admission/finish; incremental append between turns)
         self._pen_cache: Optional[dict] = None
         self._last_was_prefill = False
-        # --profile-dir hook: one jax.profiler capture over the first
-        # config.profile_steps device steps, keyed by starting step id
-        self._profile_active = False
-        self._profile_done = False
-        self._profile_from_step = 0
 
     # ----------------------------------------------------------- step kernel
     def _step_impl(self, params, cache, *args, prefix_blocks=None,
@@ -1164,11 +1165,11 @@ class EngineCore:
         self._rng, rng = jax.random.split(self._rng)
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
-        step_timeline.mark("host_build")
+        step_timeline.enter("upload")
         up, gkw = self._upload_dispatch(
             (tokens, positions, block_tables, seq_lens, slot_idx, last_idx,
              temp, top_k, top_p), gkw)
-        step_timeline.mark("upload")
+        step_timeline.enter("dispatch", kind="step")
         if perf_model.wants("step"):
             perf_model.offer(
                 "step", self._step_fn,
@@ -1180,11 +1181,11 @@ class EngineCore:
             *up[:6], rng, *up[6:],
             prefix_blocks=prefix_blocks, k_cand=k_cand, exact=exact, **gkw,
         )
-        step_timeline.mark("dispatch", kind="step")
+        step_timeline.enter("readback")
         self.steps += 1
         out = tuple(jax.device_get(out))
         self.device_gets += 1
-        step_timeline.mark("readback")
+        step_timeline.enter("host_post")
         return out
 
     def _run_multi_decode_step(self, tokens, positions, block_tables, seq_lens,
@@ -1199,9 +1200,9 @@ class EngineCore:
                 temp, top_k, top_p] + (list(pen) if use_pen else [])
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
-        step_timeline.mark("host_build")
+        step_timeline.enter("upload")
         up, gkw = self._upload_dispatch(host, gkw)
-        step_timeline.mark("upload")
+        step_timeline.enter("dispatch", kind="decode_multi")
         up = list(up)
         args = up[:5] + [rng] + up[5:]
         if perf_model.wants("decode_multi"):
@@ -1215,19 +1216,19 @@ class EngineCore:
             num_steps=num_steps, k_cand=k_cand, exact=exact,
             use_penalties=use_pen, **gkw,
         )
-        step_timeline.mark("dispatch", kind="decode_multi")
         self.steps += 1
         if self._lookahead_enabled():
             # overlap window: absorb arrivals while the device runs the
             # decode burst (admission next turn starts from a warm list)
+            step_timeline.enter("overlap")
             self._drain_waiting()
-            step_timeline.mark("overlap")
+        step_timeline.enter("readback")
         # ONE batched transfer: per-array np.asarray would issue a
         # device->host round trip per output (per-array latency is the
         # cost that matters on a remote-attached chip)
         out = tuple(jax.device_get(out))
         self.device_gets += 1
-        step_timeline.mark("readback")
+        step_timeline.enter("host_post")
         return out
 
     # ------------------------------------------------------- cross-thread API
@@ -1309,6 +1310,12 @@ class EngineCore:
             "lookahead_flushes_total": self.lookahead_flushes,
             "lookahead_dispatch_depth": self.lookahead_depth,
             "device_gets_total": self.device_gets,
+            "decode_dispatches_total": self.decode_dispatches,
+            "decode_rows_dispatched_total": self.decode_rows_dispatched,
+            "requests_finished_total": self.requests_finished,
+            "requests_cut_short_total": self.requests_cut_short,
+            "first_tokens_total": self.first_tokens,
+            "first_token_seconds_total": self.first_token_s,
         }
         if self.host_pool is not None:
             out.update(self.host_pool.stats())
@@ -1323,18 +1330,16 @@ class EngineCore:
         """Run one scheduling iteration.  Returns False when idle.
 
         The body is wrapped in the dtspan step timeline (obs/timeline.py):
-        ``begin()`` opens the step, the scheduler and every dispatch
-        helper ``mark()`` their phase boundaries, ``end()`` attributes
-        the residue — so per-phase wall time sums to step wall time by
-        construction (the host-bubble before-number ROADMAP item 3
-        needs)."""
-        self._maybe_profile_start()
-        step_timeline.begin()
+        ``begin()`` (first thing in ``_step_inner``) opens the step and its
+        first phase, the scheduler and every dispatch helper ``enter()``
+        the phase that starts, ``end()`` closes the last — so per-phase
+        wall time sums to step wall time by construction, and under a
+        profiler session each phase is one
+        ``dyn.<phase>`` event on this thread."""
         try:
             return self._step_inner()
         finally:
             step_timeline.end(trace=self._active_trace())
-            self._maybe_profile_stop()
 
     def _active_trace(self):
         """(trace_id, span_id) of any traced request currently in a
@@ -1351,33 +1356,19 @@ class EngineCore:
                 return trace
         return None
 
-    def _maybe_profile_start(self) -> None:
-        cfg = self.config
-        if not cfg.profile_dir or self._profile_done or self._profile_active:
-            return
-        path = os.path.join(cfg.profile_dir, f"steps-{self.steps:06d}")
-        os.makedirs(path, exist_ok=True)
-        jax.profiler.start_trace(path)
-        self._profile_active = True
-        self._profile_from_step = self.steps
-
-    def _maybe_profile_stop(self) -> None:
-        if not self._profile_active:
-            return
-        if (self.steps - self._profile_from_step
-                >= max(1, self.config.profile_steps)):
-            jax.profiler.stop_trace()
-            self._profile_active = False
-            self._profile_done = True
-
     def _step_inner(self) -> bool:
+        # Opened here, not in step(): a span that is open when this frame
+        # starts and closes inside it hides the whole step from a reader
+        # that rebuilds the host's call tree by time (cellbench's
+        # breakdown.idle_gaps under the profiler's Python tracer).
+        step_timeline.begin()  # opens kv_spill_restore
         self._drain_offload()  # evictions from the previous step's tail
-        step_timeline.mark("kv_spill_restore")
+        step_timeline.enter("host_ops")
         self._process_ops()
         self._process_aborts()
-        step_timeline.mark("host_ops")
+        step_timeline.enter("admission")
         self._admit()
-        step_timeline.mark("admission")
+        step_timeline.enter("host_build")
         # slots not yet decoding (waiting on external KV, or mid-chunked-
         # prefill): honour aborts here — _append_token never runs for them,
         # so without this a cancelled long prompt would keep prefilling
@@ -1856,11 +1847,11 @@ class EngineCore:
         self._rng, rng = jax.random.split(self._rng)
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
-        step_timeline.mark("host_build")
+        step_timeline.enter("upload")
         up, gkw = self._upload_dispatch(
             (tokens, positions, bt, seq_lens, slot_idx, seq_ids, starts,
              roff, last_idx, temp, top_k, top_p), gkw)
-        step_timeline.mark("upload")
+        step_timeline.enter("dispatch", kind="prefill_ragged")
         if perf_model.wants("prefill_ragged"):
             perf_model.offer(
                 "prefill_ragged", self._ragged_fn,
@@ -1871,13 +1862,13 @@ class EngineCore:
             self.params, self.cache, *up[:9], rng, *up[9:],
             prefix_blocks=pb, k_cand=k_cand, exact=exact, **gkw,
         )
-        step_timeline.mark("dispatch", kind="prefill_ragged")
         if self._lookahead_enabled():
+            step_timeline.enter("overlap")
             self._drain_waiting()  # overlap: absorb arrivals under compute
-            step_timeline.mark("overlap")
+        step_timeline.enter("readback")
         sampled, lps, cids, clps = jax.device_get(out)  # one batched pull
         self.device_gets += 1
-        step_timeline.mark("readback")
+        step_timeline.enter("host_post")
         self.steps += 1
         self.prefill_steps += 1
         take_sum = sum(take for _, take, _ in sel)
@@ -2090,17 +2081,18 @@ class EngineCore:
 
         # growth allocations above may have evicted registered blocks
         # that this very dispatch writes into — offload them first
-        step_timeline.mark("host_build")
+        step_timeline.enter("kv_spill_restore")
         self._drain_offload()
-        step_timeline.mark("kv_spill_restore")
+        step_timeline.enter("host_build")
         self._rng, rng = jax.random.split(self._rng)
         gkw = self._gram_kwargs(gram)
         gkw.update(extras)
+        step_timeline.enter("upload")
         if burst:
             up, gkw = self._upload_dispatch(
                 (tokens, positions, bt, seq_lens, slot_idx, seq_ids,
                  starts, roff, last_idx, limits, temp, top_k, top_p), gkw)
-            step_timeline.mark("upload")
+            step_timeline.enter("dispatch", kind="unified_burst")
             if perf_model.wants("unified_burst"):
                 perf_model.offer(
                     "unified_burst", self._burst_fn,
@@ -2114,12 +2106,11 @@ class EngineCore:
                 num_steps=k_steps, row_tokens=d_region, prefix_blocks=pb,
                 k_cand=k_cand, exact=exact, use_penalties=use_pen, **gkw,
             )
-            step_timeline.mark("dispatch", kind="unified_burst")
         else:
             up, gkw = self._upload_dispatch(
                 (tokens, positions, bt, seq_lens, slot_idx, seq_ids,
                  starts, roff, last_idx, temp, top_k, top_p), gkw)
-            step_timeline.mark("upload")
+            step_timeline.enter("dispatch", kind="unified")
             if perf_model.wants("unified"):
                 perf_model.offer(
                     "unified", self._unified_fn,
@@ -2132,7 +2123,6 @@ class EngineCore:
                 row_tokens=d_region, prefix_blocks=pb, k_cand=k_cand,
                 exact=exact, **gkw,
             )
-            step_timeline.mark("dispatch", kind="unified")
         if lookahead:
             # overlap window: the dispatch above is in flight — drain
             # arrivals and speculatively prebuild the NEXT turn's
@@ -2140,10 +2130,11 @@ class EngineCore:
             # device_get below is the synchronization point, so this
             # host work is hidden under device time (attributed to the
             # "overlap" phase, excluded from the host gap).
+            step_timeline.enter("overlap")
             self._drain_waiting()
             self._spec_next = self._prebuild_next(
                 ready, sel, dec, d_region, budget)
-            step_timeline.mark("overlap")
+        step_timeline.enter("readback")
         if burst:
             # ONE pull for the whole burst: turn-0 samples (named as in
             # the single-turn path — the sel completion below is shared)
@@ -2153,7 +2144,7 @@ class EngineCore:
         else:
             sampled, lps, cids, clps = jax.device_get(out)
         self.device_gets += 1
-        step_timeline.mark("readback")
+        step_timeline.enter("host_post")
         self.steps += 1
         self.prefill_steps += 1
         self.decode_steps += k_steps
@@ -2201,7 +2192,7 @@ class EngineCore:
             if req.state is RequestState.RUNNING and allowed < k_steps - 1:
                 # ran out of block-table room mid-burst — same LENGTH
                 # semantics as the pure-decode burst
-                self._finish_slot(req, FinishReason.LENGTH)
+                self._cut_short(req)
             if consumed < allowed:
                 mis += 1  # a stop fired: predicted tail discarded
             else:
@@ -2464,14 +2455,14 @@ class EngineCore:
         last_idx = np.asarray([req.prompt_len - 1], np.int32)
         self._rng, rng = jax.random.split(self._rng)
         k_cand, exact = self._sampling_mode([req])
-        step_timeline.mark("host_build")
+        step_timeline.enter("upload")
         up, _ = self._upload_dispatch((
             tokens, positions, last_idx,
             np.asarray([req.sampling.temperature], np.float32),
             np.asarray([req.sampling.top_k], np.int32),
             np.asarray([req.sampling.top_p], np.float32),
         ))
-        step_timeline.mark("upload")
+        step_timeline.enter("dispatch", kind="sp_prefill")
         if perf_model.wants("sp_prefill"):
             perf_model.offer(
                 "sp_prefill", self._sp_fn,
@@ -2482,11 +2473,11 @@ class EngineCore:
             self.params, up[0], up[1], up[2], rng, up[3], up[4], up[5],
             nb=nb_pad, k_cand=k_cand, exact=exact,
         )
-        step_timeline.mark("dispatch", kind="sp_prefill")
+        step_timeline.enter("readback")
         sampled, lps, cids, clps = jax.device_get(
             (sampled, lps, cids, clps))  # one batched transfer
         self.device_gets += 1
-        step_timeline.mark("readback")
+        step_timeline.enter("host_post")
         nb = -(-req.prompt_len // bs)
         self.cache = scatter_blocks_inplace(
             self.cache, req.block_ids[:nb],
@@ -2546,7 +2537,7 @@ class EngineCore:
                 )
             except NoFreeBlocks:
                 if len(req.block_ids) * cfg.block_size <= p:
-                    self._finish_slot(req, FinishReason.LENGTH)
+                    self._cut_short(req)
                     return None
         return min(len(req.block_ids) * cfg.block_size, cfg.max_model_len)
 
@@ -2642,15 +2633,16 @@ class EngineCore:
         blocks_used = max(1, -(-int(seq_lens.max()) // cfg.block_size))
         m_used = min(m, 1 << (blocks_used - 1).bit_length())
 
-        step_timeline.mark("host_build")
+        step_timeline.enter("kv_spill_restore")
         self._drain_offload()
-        step_timeline.mark("kv_spill_restore")
+        step_timeline.enter("host_build")
         self._rng, rng = jax.random.split(self._rng)
         k_cand, exact = self._sampling_mode(rows)
+        step_timeline.enter("upload")
         up, _ = self._upload_dispatch(
             (tokens, positions, bt[:, :m_used], seq_lens, slot_idx,
              temp, top_k, top_p, min_p, seeds, seed_rows))
-        step_timeline.mark("upload")
+        step_timeline.enter("dispatch", kind="spec_verify")
         if perf_model.wants("spec_verify"):
             perf_model.offer(
                 "spec_verify", self._spec_fn,
@@ -2661,13 +2653,16 @@ class EngineCore:
             *up[:5], rng, *up[5:],
             k_cand=k_cand, exact=exact,
         )
-        step_timeline.mark("dispatch", kind="spec_verify")
+        step_timeline.enter("readback")
         verified = jax.device_get(verified)
         self.device_gets += 1
-        step_timeline.mark("readback")
+        step_timeline.enter("host_post")
         self.steps += 1
         self.decode_steps += 1
         self.spec_steps += 1
+        self.decode_dispatches += 1
+        self.decode_rows_dispatched += len(rows)
+        request_counters.record_decode(len(rows))
         for req in rows:
             i = req.slot
             prop = props.get(i, [])
@@ -2686,7 +2681,7 @@ class EngineCore:
                     break  # EOS/stop/max_tokens mid-acceptance
                 self._append_token(req, t)
             if req.state is RequestState.RUNNING and allowed < len(emit):
-                self._finish_slot(req, FinishReason.LENGTH)
+                self._cut_short(req)
         return True
 
     def _run_decode(self) -> None:
@@ -2759,9 +2754,9 @@ class EngineCore:
             return
         # growth allocations above may have evicted registered blocks that
         # this very dispatch writes into — offload them first
-        step_timeline.mark("host_build")
+        step_timeline.enter("kv_spill_restore")
         self._drain_offload()
-        step_timeline.mark("kv_spill_restore")
+        step_timeline.enter("host_build")
         k_cand, exact = self._sampling_mode(active)
         pen = self._penalty_buffers(active, k_steps)
         gram = None
@@ -2789,6 +2784,9 @@ class EngineCore:
             num_steps=k_steps, k_cand=k_cand, exact=exact,
         )  # [K, B], [K, B], [K, B, C], [K, B, C]
         self.decode_steps += sampled.shape[0]
+        self.decode_dispatches += 1
+        self.decode_rows_dispatched += len(active)
+        request_counters.record_decode(len(active))
         for req in active:
             slot = req.slot
             want_lp = req.sampling.logprobs or req.sampling.top_logprobs > 0
@@ -2804,7 +2802,7 @@ class EngineCore:
                 )
             if req.state is RequestState.RUNNING and allowed < sampled.shape[0]:
                 # block space exhausted before the burst ended
-                self._finish_slot(req, FinishReason.LENGTH)
+                self._cut_short(req)
 
     def _penalty_buffers(self, active, k_steps: int):
         """Build the generated-token penalty buffers for this dispatch, or
@@ -2899,8 +2897,21 @@ class EngineCore:
                     [(int(i), float(l)) for i, l in zip(ids[:n], lps[:n])]
                 ]
         req.emit(out)
+        if req.generated == 1 and req.submitted_at:
+            ttft = time.perf_counter() - req.submitted_at
+            self.first_tokens += 1
+            self.first_token_s += ttft
+            request_counters.record_first_token(ttft)
         if finish is not None:
             self._finish_slot(req, finish, emitted=True)
+
+    def _cut_short(self, req: EngineRequest) -> None:
+        """End a running request because its block space ran out: the
+        client sees ``finish_reason: "length"`` like a ``max_tokens`` stop,
+        so this counter is the only place the two can be told apart."""
+        self.requests_cut_short += 1
+        request_counters.record_cut_short()
+        self._finish_slot(req, FinishReason.LENGTH)
 
     def _finish_slot(self, req: EngineRequest, reason: FinishReason, emitted: bool = False) -> None:
         if req.slot >= 0 and self.slots[req.slot] is req:
@@ -2918,6 +2929,8 @@ class EngineCore:
         self._by_id.pop(req.request_id, None)
         req.state = RequestState.FINISHED
         req.finish_reason = reason
+        self.requests_finished += 1
+        request_counters.record_finish()
         if not emitted:
             req.emit(LLMEngineOutput(token_ids=[], finish_reason=reason,
                                      cached_tokens=req.cached_tokens))
@@ -2926,6 +2939,8 @@ class EngineCore:
         """Finish a request that never got a slot."""
         req.state = RequestState.FINISHED
         req.finish_reason = reason
+        self.requests_finished += 1
+        request_counters.record_finish()
         req.emit(LLMEngineOutput(token_ids=[], finish_reason=reason))
 
     # ------------------------------------------------- disaggregation support

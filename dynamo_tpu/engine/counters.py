@@ -31,7 +31,8 @@ from __future__ import annotations
 __all__ = ["PrefillCounters", "counters", "PersistCounters", "persist_counters",
            "KvStreamCounters", "kv_stream_counters",
            "KvShardCounters", "kv_shard_counters",
-           "LookaheadCounters", "lookahead_counters"]
+           "LookaheadCounters", "lookahead_counters",
+           "RequestCounters", "request_counters"]
 
 
 class PrefillCounters:
@@ -330,3 +331,55 @@ class LookaheadCounters:
 
 
 lookahead_counters = LookaheadCounters()
+
+
+class RequestCounters:
+    """What an operator asks of the decode path and of request endings,
+    counted on the engine thread where it happens.
+
+        dynamo_tpu_engine_decode_dispatches_total      counter (pure-decode
+                                                       dispatches: burst or
+                                                       speculative verify)
+        dynamo_tpu_engine_decode_rows_dispatched_total counter (running rows
+                                                       packed over them)
+        dynamo_tpu_engine_requests_finished_total      counter (any reason)
+        dynamo_tpu_engine_requests_cut_short_total     counter (ended with
+                                                       ``length`` because
+                                                       KV block space ran
+                                                       out — not max_tokens,
+                                                       not max_model_len)
+        dynamo_tpu_engine_first_tokens_total           counter (requests that
+                                                       emitted a token)
+        dynamo_tpu_engine_first_token_seconds_total    counter (sum of first
+                                                       emit - submit: TTFT
+                                                       as the engine sees it)
+    """
+
+    def __init__(self) -> None:
+        self.reset()
+
+    def record_decode(self, rows: int) -> None:
+        self.decode_dispatches_total += 1
+        self.decode_rows_dispatched_total += rows
+
+    def record_finish(self) -> None:
+        self.requests_finished_total += 1
+
+    def record_cut_short(self) -> None:
+        self.requests_cut_short_total += 1
+
+    def record_first_token(self, seconds: float) -> None:
+        self.first_tokens_total += 1
+        self.first_token_seconds_total += seconds
+
+    def reset(self) -> None:
+        """Test isolation hook — the counters are process-global."""
+        self.decode_dispatches_total = 0
+        self.decode_rows_dispatched_total = 0
+        self.requests_finished_total = 0
+        self.requests_cut_short_total = 0
+        self.first_tokens_total = 0
+        self.first_token_seconds_total = 0.0
+
+
+request_counters = RequestCounters()

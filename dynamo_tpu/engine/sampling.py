@@ -88,6 +88,7 @@ def _exact_top_k_tiled(logits: jax.Array, k: int) -> tuple[jax.Array, jax.Array]
     return vals, idx
 
 
+@jax.named_scope("sample")
 def sample_full(
     logits: jax.Array,        # [B, V] f32
     rng: jax.Array,           # PRNGKey

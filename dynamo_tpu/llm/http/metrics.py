@@ -21,7 +21,8 @@ from typing import Iterator
 
 from dynamo_tpu.engine.counters import counters as prefill_counters
 from dynamo_tpu.engine.counters import (kv_shard_counters, kv_stream_counters,
-                                        lookahead_counters, persist_counters)
+                                        lookahead_counters, persist_counters,
+                                        request_counters)
 from dynamo_tpu.fault.counters import counters as fault_counters
 from dynamo_tpu.obs.costs import transfer_costs
 from dynamo_tpu.obs.metric_names import EngineMetric as EM
@@ -32,7 +33,7 @@ from dynamo_tpu.obs.metric_names import KvStreamMetric as STM
 from dynamo_tpu.obs.metric_names import KvTransferMetric as KM
 from dynamo_tpu.obs.metric_names import PerfMetric as PM
 from dynamo_tpu.obs.perfmodel import perf_model
-from dynamo_tpu.obs.timeline import PHASES, step_timeline
+from dynamo_tpu.obs.timeline import CLASSES, PHASES, step_timeline
 
 # seconds; TTFT and whole-request durations share one ladder
 _BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0)
@@ -269,6 +270,37 @@ class Metrics:
             lines.append(
                 f'{EM.STEP_PHASE_SECONDS_TOTAL}{{phase="{p}"}} '
                 f"{round(tl['phases'][p], 6)}")
+        # busy steps by what they dispatched: prompt processing, token
+        # generation, or both in one step — wall, and its device-facing
+        # part (dispatch -> readback returned)
+        for name, key in (
+                (EM.STEP_CLASS_STEPS_TOTAL, "steps_total"),
+                (EM.STEP_CLASS_WALL_SECONDS_TOTAL, "wall_seconds_total"),
+                (EM.STEP_CLASS_DEVICE_SECONDS_TOTAL,
+                 "device_seconds_total")):
+            lines.append(f"# TYPE {name} counter")
+            for c in CLASSES:
+                lines.append(f'{name}{{class="{c}"}} '
+                             f"{round(tl[f'{c}_{key}'], 6)}")
+        # decode occupancy, request endings, engine-side TTFT
+        rc = request_counters
+        lines.append(f"# TYPE {EM.DECODE_DISPATCHES_TOTAL} counter")
+        lines.append(f"{EM.DECODE_DISPATCHES_TOTAL} "
+                     f"{rc.decode_dispatches_total}")
+        lines.append(f"# TYPE {EM.DECODE_ROWS_DISPATCHED_TOTAL} counter")
+        lines.append(f"{EM.DECODE_ROWS_DISPATCHED_TOTAL} "
+                     f"{rc.decode_rows_dispatched_total}")
+        lines.append(f"# TYPE {EM.REQUESTS_FINISHED_TOTAL} counter")
+        lines.append(f"{EM.REQUESTS_FINISHED_TOTAL} "
+                     f"{rc.requests_finished_total}")
+        lines.append(f"# TYPE {EM.REQUESTS_CUT_SHORT_TOTAL} counter")
+        lines.append(f"{EM.REQUESTS_CUT_SHORT_TOTAL} "
+                     f"{rc.requests_cut_short_total}")
+        lines.append(f"# TYPE {EM.FIRST_TOKENS_TOTAL} counter")
+        lines.append(f"{EM.FIRST_TOKENS_TOTAL} {rc.first_tokens_total}")
+        lines.append(f"# TYPE {EM.FIRST_TOKEN_SECONDS_TOTAL} counter")
+        lines.append(f"{EM.FIRST_TOKEN_SECONDS_TOTAL} "
+                     f"{round(rc.first_token_seconds_total, 6)}")
         lines.append(f"# TYPE {EM.HOST_GAP_MS_PER_TURN} gauge")
         lines.append(f"{EM.HOST_GAP_MS_PER_TURN} "
                      f"{round(tl['host_gap_ms_per_turn'], 6)}")

@@ -7,6 +7,8 @@ service_v2.rs):
   POST /v1/completions        — streaming (SSE) and unary
   GET  /v1/models
   GET  /metrics               — Prometheus text format
+  GET  /debug/traces/{id}     — dtspan Chrome trace of one request
+  POST /debug/profile         — one jax.profiler capture of ?seconds=N
   GET  /health, /live, /ready
 
 Models are served through a ModelManager registry; entries can be added and
@@ -20,6 +22,7 @@ from __future__ import annotations
 import asyncio
 import json
 import logging
+import os
 import time
 from dataclasses import dataclass
 from typing import AsyncIterator, Optional
@@ -92,8 +95,12 @@ class ModelManager:
 
 class HttpService:
     def __init__(self, manager: Optional[ModelManager] = None, host: str = "127.0.0.1", port: int = 8080,
-                 admission=None, affinity: Optional[SessionAffinity] = None):
+                 admission=None, affinity: Optional[SessionAffinity] = None,
+                 profile_dir: Optional[str] = None):
         self.manager = manager or ModelManager()
+        # where POST /debug/profile writes; None = the route refuses
+        self.profile_dir = profile_dir
+        self._profiling = False
         self.metrics = Metrics()
         # consistent-hash session affinity (llm/http/affinity.py): with N
         # stateless frontends, route a multi-turn session to the replica
@@ -114,6 +121,7 @@ class HttpService:
         self.app.router.add_get("/v1/models", self._models)
         self.app.router.add_get("/metrics", self._metrics)
         self.app.router.add_get("/debug/traces/{request_id}", self._debug_trace)
+        self.app.router.add_post("/debug/profile", self._debug_profile)
         for p in ("/health", "/live", "/ready"):
             self.app.router.add_get(p, self._health)
 
@@ -164,6 +172,43 @@ class HttpService:
                           " (is DYNAMO_TRACE=1 set?)"},
                 status=404)
         return web.json_response(doc)
+
+    async def _debug_profile(self, request: web.Request) -> web.Response:
+        """One ``jax.profiler`` capture of ``?seconds=N`` (default 2, at
+        most 60) of whatever the server is doing now, written under
+        ``--profile-dir``.  Only the process that holds the chip can trace
+        it, so this works where the engine runs in this process
+        (``run in=http``).  The engine's phases appear in the capture as
+        ``dyn.<phase>`` events on the engine thread (obs/timeline.py)."""
+        if not self.profile_dir:
+            return web.json_response(
+                {"error": "profiling is off: start the server with "
+                          "--profile-dir"}, status=409)
+        try:
+            seconds = float(request.query.get("seconds", "2"))
+        except ValueError:
+            seconds = 0.0
+        if not 0.0 < seconds <= 60.0:
+            return web.json_response(
+                {"error": "seconds must be a number in (0, 60]"}, status=400)
+        if self._profiling:
+            return web.json_response(
+                {"error": "a capture is already running"}, status=409)
+        import jax
+
+        self._profiling = True
+        path = os.path.join(self.profile_dir,
+                            time.strftime("capture-%Y%m%d-%H%M%S"))
+        try:
+            os.makedirs(path, exist_ok=True)
+            await asyncio.to_thread(jax.profiler.start_trace, path)
+            try:
+                await asyncio.sleep(seconds)
+            finally:
+                await asyncio.to_thread(jax.profiler.stop_trace)
+        finally:
+            self._profiling = False
+        return web.json_response({"path": path, "seconds": seconds})
 
     async def _chat(self, request: web.Request) -> web.StreamResponse:
         return await self._serve(request, chat=True)

@@ -399,11 +399,17 @@ class LlamaModel:
             prefix_blocks is not None and s > 1 and not ragged_prefill
         )
 
-        hidden = take_rows(params["embed"], tokens, cfg.jax_dtype)
-        if cfg.scale_embeddings:  # Gemma multiplies by sqrt(hidden_size)
-            hidden = hidden * jnp.asarray(
-                math.sqrt(cfg.hidden_size), cfg.jax_dtype
-            )
+        # Named scopes (embed, attn_proj, attn, attn_out, mlp with moe_router
+        # / moe_experts, logits, sample) put a device operation's place in
+        # the model into its profiler metadata; cellbench's device.*_pct
+        # read them.  The layer scan carries none, so what XLA adds around
+        # it (per-layer weight slices, layout copies) stays unscoped.
+        with jax.named_scope("embed"):
+            hidden = take_rows(params["embed"], tokens, cfg.jax_dtype)
+            if cfg.scale_embeddings:  # Gemma multiplies by sqrt(hidden_size)
+                hidden = hidden * jnp.asarray(
+                    math.sqrt(cfg.hidden_size), cfg.jax_dtype
+                )
 
         # The cache rides the scan as CARRY, updated by scatter: XLA keeps
         # one buffer and updates it in place.  (Passing it as xs/ys instead
@@ -414,50 +420,58 @@ class LlamaModel:
         def layer_step(carry, layer_in):
             h, cache = carry
             lp, li = layer_in
-            x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps, uo)
-            q, k, v = _qkv_proj(cfg, lp, x, b, s)
-            q = apply_rope(q, positions, cfg.rope_theta, self.inv_freq)
-            k = apply_rope(k, positions, cfg.rope_theta, self.inv_freq)
-            # fast_prefill/ragged imply the engine's block-aligned
-            # contiguous span layout — unlocks the block-granular write
-            cache = write_kv_cache_layer(
-                cache, li, k, v, slot_idx,
-                block_aligned=fast_prefill or ragged_prefill,
-                row_tokens=ragged_row_tokens if ragged_prefill else 0,
-            )
-            if ragged_prefill:
-                seq_ids, seq_starts, row_offsets = ragged
-                attn = ragged_prefill_attention(
-                    q, k, v, cache, li, block_tables, seq_lens,
-                    seq_starts, row_offsets, seq_ids, prefix_blocks,
-                    sm_scale=self.sm_scale, logit_cap=cfg.attn_logit_softcap,
-                    window=cfg.sliding_window,
+            with jax.named_scope("attn_proj"):
+                x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps, uo)
+                q, k, v = _qkv_proj(cfg, lp, x, b, s)
+                q = apply_rope(q, positions, cfg.rope_theta, self.inv_freq)
+                k = apply_rope(k, positions, cfg.rope_theta, self.inv_freq)
+            with jax.named_scope("attn"):
+                # fast_prefill/ragged imply the engine's block-aligned
+                # contiguous span layout — unlocks the block-granular write
+                cache = write_kv_cache_layer(
+                    cache, li, k, v, slot_idx,
+                    block_aligned=fast_prefill or ragged_prefill,
+                    row_tokens=ragged_row_tokens if ragged_prefill else 0,
                 )
-            elif fast_prefill:
-                attn = prefill_attention(
-                    q, k, v, cache, li, block_tables, seq_lens,
-                    positions[:, 0], prefix_blocks,
-                    sm_scale=self.sm_scale, logit_cap=cfg.attn_logit_softcap,
-                    window=cfg.sliding_window,
-                )
-            else:
-                attn = paged_attention_layer(
-                    q, cache, li, block_tables, seq_lens, positions,
-                    sm_scale=self.sm_scale, logit_cap=cfg.attn_logit_softcap,
-                    window=cfg.sliding_window,
-                )
-            attn_out = matmul(attn.reshape(b, s, hq * dh), lp["wo"])
-            if cfg.post_norms:  # Gemma2 sandwich: norm the residual branch
-                attn_out = rms_norm(attn_out, lp["post_attn_norm"],
-                                    cfg.rms_norm_eps, uo)
-            h = h + attn_out
+                if ragged_prefill:
+                    seq_ids, seq_starts, row_offsets = ragged
+                    attn = ragged_prefill_attention(
+                        q, k, v, cache, li, block_tables, seq_lens,
+                        seq_starts, row_offsets, seq_ids, prefix_blocks,
+                        sm_scale=self.sm_scale,
+                        logit_cap=cfg.attn_logit_softcap,
+                        window=cfg.sliding_window,
+                    )
+                elif fast_prefill:
+                    attn = prefill_attention(
+                        q, k, v, cache, li, block_tables, seq_lens,
+                        positions[:, 0], prefix_blocks,
+                        sm_scale=self.sm_scale,
+                        logit_cap=cfg.attn_logit_softcap,
+                        window=cfg.sliding_window,
+                    )
+                else:
+                    attn = paged_attention_layer(
+                        q, cache, li, block_tables, seq_lens, positions,
+                        sm_scale=self.sm_scale,
+                        logit_cap=cfg.attn_logit_softcap,
+                        window=cfg.sliding_window,
+                    )
+            with jax.named_scope("attn_out"):
+                attn_out = matmul(attn.reshape(b, s, hq * dh), lp["wo"])
+                if cfg.post_norms:  # Gemma2 sandwich: norm the residual branch
+                    attn_out = rms_norm(attn_out, lp["post_attn_norm"],
+                                        cfg.rms_norm_eps, uo)
+                h = h + attn_out
 
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps, uo)
-            mlp_out = _moe_mlp(cfg, lp, x) if cfg.is_moe else _dense_mlp(cfg, lp, x)
-            if cfg.post_norms:
-                mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
-                                   cfg.rms_norm_eps, uo)
-            h = h + mlp_out
+            with jax.named_scope("mlp"):
+                x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps, uo)
+                mlp_out = (_moe_mlp(cfg, lp, x) if cfg.is_moe
+                           else _dense_mlp(cfg, lp, x))
+                if cfg.post_norms:
+                    mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
+                                       cfg.rms_norm_eps, uo)
+                h = h + mlp_out
             return (h, cache), None
 
         (hidden, new_cache), _ = jax.lax.scan(
@@ -465,8 +479,9 @@ class LlamaModel:
             (hidden, kv_cache),
             (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)),
         )
-        hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps,
-                          cfg.rmsnorm_unit_offset)
+        with jax.named_scope("logits"):
+            hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps,
+                              cfg.rmsnorm_unit_offset)
         return hidden, new_cache
 
     def forward_seq_parallel(
@@ -534,6 +549,7 @@ class LlamaModel:
                           cfg.rmsnorm_unit_offset)
         return hidden, kv  # kv: [L, 2, B, S, Hk*D]
 
+    @jax.named_scope("logits")
     def compute_logits(self, params: Params, hidden: jax.Array) -> jax.Array:
         """hidden [..., Dm] -> logits [..., V] in f32.
 
@@ -653,16 +669,18 @@ def _moe_mlp_grouped(cfg: ModelConfig, lp: dict, x: jax.Array) -> jax.Array:
     equivalent."""
     b, s, d = x.shape
     xf = x.reshape(b * s, d)
-    weights, topi = _moe_router(cfg, lp, xf)
-    out = grouped_expert_dispatch(
-        xf, weights, topi, cfg.num_experts,
-        # quantized experts dequant at the operand: convert fuses into
-        # the grouped dot's operand load, HBM reads stay int8
-        dequantize(lp["w_gate"], x.dtype),
-        dequantize(lp["w_up"], x.dtype),
-        dequantize(lp["w_down"], x.dtype),
-        lambda g: _act(cfg, g),
-    )
+    with jax.named_scope("moe_router"):
+        weights, topi = _moe_router(cfg, lp, xf)
+    with jax.named_scope("moe_experts"):
+        out = grouped_expert_dispatch(
+            xf, weights, topi, cfg.num_experts,
+            # quantized experts dequant at the operand: convert fuses into
+            # the grouped dot's operand load, HBM reads stay int8
+            dequantize(lp["w_gate"], x.dtype),
+            dequantize(lp["w_up"], x.dtype),
+            dequantize(lp["w_down"], x.dtype),
+            lambda g: _act(cfg, g),
+        )
     return out.reshape(b, s, d)
 
 

@@ -8,8 +8,11 @@ Zero-dependency observability for the five-process serving path:
   allocation on the token path).
 - ``obs.timeline``: the engine step timeline — per-phase wall-time
   attribution for ``EngineCore.step`` (host scheduling, upload, jitted
-  dispatch, readback, post-processing).  Always on; a handful of
-  ``perf_counter`` calls per step.
+  dispatch, readback, post-processing), split into prefill, decode and
+  mixed steps.  The counters are always on (about twenty clock reads and
+  two small dicts per busy step); under a ``jax.profiler`` session every
+  phase is also one ``dyn.<phase>`` event on the engine thread, and with
+  no session open that costs one flag test per phase.
 - ``obs.costs``: measured KV-transfer cost tables (EWMA per
   (src, dst, path)) fed by spans around ICI/DCN transfers and persist
   restores — the routing input NetKV-style transfer-aware disagg

@@ -99,6 +99,21 @@ class EngineMetric:
     HOST_GAP_MS_PER_TURN = "dynamo_tpu_engine_host_gap_ms_per_turn"
     STEP_WALL_MS_EWMA = "dynamo_tpu_engine_step_wall_ms_ewma"
     HOST_GAP_MS_EWMA = "dynamo_tpu_engine_host_gap_ms_ewma"
+    # busy steps by what they dispatched (class: prefill, decode, mixed)
+    STEP_CLASS_STEPS_TOTAL = "dynamo_tpu_engine_step_class_steps_total"
+    STEP_CLASS_WALL_SECONDS_TOTAL = (
+        "dynamo_tpu_engine_step_class_wall_seconds_total")
+    STEP_CLASS_DEVICE_SECONDS_TOTAL = (
+        "dynamo_tpu_engine_step_class_device_seconds_total")
+    # engine/counters.py RequestCounters
+    DECODE_DISPATCHES_TOTAL = "dynamo_tpu_engine_decode_dispatches_total"
+    DECODE_ROWS_DISPATCHED_TOTAL = (
+        "dynamo_tpu_engine_decode_rows_dispatched_total")
+    REQUESTS_FINISHED_TOTAL = "dynamo_tpu_engine_requests_finished_total"
+    REQUESTS_CUT_SHORT_TOTAL = "dynamo_tpu_engine_requests_cut_short_total"
+    FIRST_TOKENS_TOTAL = "dynamo_tpu_engine_first_tokens_total"
+    FIRST_TOKEN_SECONDS_TOTAL = (
+        "dynamo_tpu_engine_first_token_seconds_total")
 
 
 class KvTransferMetric:
@@ -199,6 +214,15 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.HOST_GAP_MS_PER_TURN: ("gauge", ()),
     EngineMetric.STEP_WALL_MS_EWMA: ("gauge", ()),
     EngineMetric.HOST_GAP_MS_EWMA: ("gauge", ()),
+    EngineMetric.STEP_CLASS_STEPS_TOTAL: ("counter", ("class",)),
+    EngineMetric.STEP_CLASS_WALL_SECONDS_TOTAL: ("counter", ("class",)),
+    EngineMetric.STEP_CLASS_DEVICE_SECONDS_TOTAL: ("counter", ("class",)),
+    EngineMetric.DECODE_DISPATCHES_TOTAL: ("counter", ()),
+    EngineMetric.DECODE_ROWS_DISPATCHED_TOTAL: ("counter", ()),
+    EngineMetric.REQUESTS_FINISHED_TOTAL: ("counter", ()),
+    EngineMetric.REQUESTS_CUT_SHORT_TOTAL: ("counter", ()),
+    EngineMetric.FIRST_TOKENS_TOTAL: ("counter", ()),
+    EngineMetric.FIRST_TOKEN_SECONDS_TOTAL: ("counter", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.BYTES_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.SECONDS_TOTAL: ("counter", ("src", "dst", "path")),

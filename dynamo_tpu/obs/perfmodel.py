@@ -12,9 +12,14 @@ path, by tracing the offered signature through
 ``perfcheck.estimate_callable``.
 
 ``reconcile()`` joins the predictions against the per-kind measured
-dispatch seconds the step timeline accumulates
-(``step_phase_seconds{phase="dispatch"}`` split by kind) into the
-model-error rows that ``/metrics`` exports as
+seconds the step timeline accumulates.  **"Measured" is device-facing
+time: from the jitted call to the return of its ``device_get``**
+(the dispatch phase plus the overlap and readback phases that follow it,
+``dispatch_kinds[kind].seconds`` of ``step_timeline.snapshot()``), so it
+holds the program's run time plus launch and readback latency.  Until
+PR 25 it was the dispatch phase alone — on an asynchronous backend the
+*enqueue*, a few hundred microseconds whatever the program costs — and
+the ratio below was wrong on the chip.  The rows go to ``/metrics`` as
 
     dynamo_tpu_perf_predicted_dispatch_ms{kind}
     dynamo_tpu_perf_measured_dispatch_ms{kind}
@@ -143,7 +148,7 @@ class PerfModel:
     def reconcile(self) -> list[dict]:
         """Predicted-vs-measured rows per dispatch kind, joining the
         lazy roofline predictions with the step timeline's per-kind
-        measured dispatch seconds."""
+        device-facing seconds (dispatch -> readback returned)."""
         from dynamo_tpu.obs.timeline import step_timeline
 
         snap = step_timeline.snapshot()

@@ -1,20 +1,19 @@
 """Engine step timeline: per-phase wall-time attribution for
-``EngineCore.step``.
+``EngineCore.step``, on the host's counters and in the profiler's trace.
 
-The model is mark-based: :meth:`StepTimeline.begin` opens a step,
-``mark(phase)`` attributes *all elapsed time since the previous mark*
-to ``phase``, and :meth:`end` attributes the residue to ``host_post``
-— so the phase sum equals the step wall time **by construction** (the
->= 95 % acceptance bound holds with slack; the only loss is float
-rounding).
+The model is enter-based: :meth:`StepTimeline.begin` opens a step and its
+first phase, ``enter(phase)`` closes the open phase and opens the next,
+and :meth:`end` closes the last — every instant of a step lies in exactly
+one phase, so the phase sum equals the step wall time **by construction**
+(the only loss is float rounding).
 
-Phases (what the marks mean, in step order):
+Phases (in step order; the profiler span of each is ``dyn.<phase>``):
 
     kv_spill_restore  host<->device KV block traffic (_drain_offload)
     host_ops          cross-thread op/abort queues
     admission         _admit: block allocation, grammar budget, slots
     host_build        numpy dispatch-operand builds (tokens, block
-                      tables, penalty buffers, grammar rows)
+                      tables, penalty buffers, grammar rows), rng split
     upload            the ONE batched jax.device_put per dispatch
     dispatch          the jitted call itself (trace/en-queue; on CPU
                       backends this includes compute)
@@ -28,34 +27,53 @@ Phases (what the marks mean, in step order):
                       work shows up here
     host_post         sampled-token append, stop conditions, emit
 
+**One call, two sinks.**  The same clock reads feed the ``perf_counter``
+aggregates and, while a ``jax.profiler`` session is open, one
+``TraceAnnotation("dyn.<phase>", step=, kind=, t_mono_ns=)`` per phase on
+the engine thread (one open at a time, never one around the whole step:
+phases stay leaves at the depth of their call site).  ``step`` is the
+busy-step index, ``kind`` the dispatch kind on dispatch/overlap/readback,
+and ``t_mono_ns`` is ``time.monotonic_ns()`` at the open: trace time −
+``t_mono_ns`` is the offset that puts anything stamped with
+``time.monotonic`` on the device trace's axis.  With no session open a
+phase costs a flag test (``TraceAnnotation.is_enabled()``) and no object.
+
 The headline derived number is **host_gap_ms_per_turn** — wall time
 per dispatching step spent *outside* dispatch+overlap+readback, i.e.
 the host bubble ROADMAP item 3 (double-buffered dispatch) must close.
 Overlapped host work is not a bubble: the device is busy underneath
 it, so the phase-sum==wall invariant holds while the gap shrinks.  The
-aggregates are always on (a handful of ``perf_counter`` calls per
-step, no allocation); full per-step records are kept only in a small
-ring buffer, and per-step *spans* are emitted only when the tracing
-plane is enabled.
+aggregates are always on: per busy step about twenty clock reads, two
+small dicts and a handful of float adds; per-step *spans* of the dtspan
+plane are emitted only when that plane is enabled.
 
-The dispatch mark additionally takes the **dispatch kind** (``step``,
-``decode_multi``, ``prefill_ragged``, ``unified``, ``sp_prefill``,
-``spec_verify``) so measured dispatch seconds split per jitted
-entrypoint — the denominator of the dtperf predicted-vs-measured
-model-error gauge (``obs/perfmodel.py``).  When tracing is enabled,
-``end`` also emits one ``engine.step`` span per busy step carrying the
-phase breakdown and the roofline-predicted dispatch envelope, which
-the Chrome export renders as a predicted-vs-measured counter track.
+``enter("dispatch", kind=...)`` names the **dispatch kind** (``step``,
+``decode_multi``, ``prefill_ragged``, ``unified``, ``unified_burst``,
+``sp_prefill``, ``spec_verify``).  ``dispatch_kinds[kind].seconds`` is
+**device-facing** time: the dispatch phase plus the overlap and readback
+phases that follow it, i.e. enqueue → readback returned — not the enqueue
+alone, which on an asynchronous backend is a few hundred microseconds
+whatever the program costs.  It is the denominator of the dtperf
+predicted-vs-measured gauge (``obs/perfmodel.py``).  At ``end`` a busy
+step's wall, and its device-facing part (wall − host gap), are added to a
+**class** — ``prefill`` (``step``, ``prefill_ragged``, ``sp_prefill``),
+``decode`` (``decode_multi``, ``spec_verify``) or ``mixed`` (``unified``,
+``unified_burst``, several classes in one step, or no kind at all) — so
+the class walls add up to ``wall_seconds_total``.  When the dtspan plane
+is enabled, ``end`` also emits one ``engine.step`` span per busy step
+carrying the phase breakdown and the roofline-predicted dispatch
+envelope, which the Chrome export renders as a predicted-vs-measured
+counter track.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from collections import deque
 from typing import Callable, Optional
 
-__all__ = ["StepTimeline", "step_timeline", "PHASES"]
+__all__ = ["StepTimeline", "step_timeline", "PHASES", "CLASSES",
+           "KIND_CLASS"]
 
 PHASES = (
     "kv_spill_restore",
@@ -70,6 +88,29 @@ PHASES = (
 )
 
 _DISPATCH_PHASES = ("upload", "dispatch", "readback")
+# enqueue -> readback returned: what a dispatch kind's seconds cover, and
+# what the host gap leaves out
+_DEVICE_FACING = ("dispatch", "overlap", "readback")
+_SPAN_NAMES = {p: f"dyn.{p}" for p in PHASES}
+
+CLASSES = ("prefill", "decode", "mixed")
+KIND_CLASS = {
+    "step": "prefill",
+    "prefill_ragged": "prefill",
+    "sp_prefill": "prefill",
+    "decode_multi": "decode",
+    "spec_verify": "decode",
+    "unified": "mixed",
+    "unified_burst": "mixed",
+}
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported at a timeline's first
+    ``begin``: the HTTP front end reads this module without an engine."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
 
 
 class StepTimeline:
@@ -77,13 +118,13 @@ class StepTimeline:
     torn reads of monotonically-increasing floats are acceptable for
     monitoring)."""
 
-    def __init__(self, keep_steps: int = 256,
+    def __init__(self,
                  clock: Callable[[], float] = time.perf_counter) -> None:
         self._lock = threading.Lock()
         # injectable so simulated engines (load plane) can stamp steps
         # at virtual time; the default stays the high-resolution counter
         self._clock = clock
-        self.recent: deque = deque(maxlen=keep_steps)
+        self._annotation = None
         self.reset()
 
     def reset(self) -> None:
@@ -95,47 +136,80 @@ class StepTimeline:
         self.host_gap_s_total = 0.0   # busy wall - dispatch-overlap-readback
         self.ewma_wall_s = 0.0
         self.ewma_host_gap_s = 0.0
-        # measured dispatch time split by jitted-entrypoint kind — the
-        # denominator of the dtperf model-error gauge
+        # device-facing seconds (dispatch -> readback returned) split by
+        # jitted-entrypoint kind — the denominator of the dtperf gauge
         self.dispatch_kind_s: dict[str, float] = {}
         self.dispatch_kind_n: dict[str, int] = {}
+        # busy steps by what they dispatched: count, wall, device-facing
+        self.class_steps = {c: 0 for c in CLASSES}
+        self.class_wall_s = {c: 0.0 for c in CLASSES}
+        self.class_device_s = {c: 0.0 for c in CLASSES}
         self._alpha = 0.05
         self._t0: Optional[float] = None
         self._t0_ns = 0
         self._last = 0.0
+        self._phase = PHASES[0]
+        self._kind: Optional[str] = None
+        self._span = None
         self._phases: dict = {}
         self._step_kinds: dict = {}
 
     # ------------------------------------------------------------ hot path
-    def begin(self) -> None:
+    def begin(self, phase: str = PHASES[0]) -> None:
+        """Open a step and its first phase."""
+        if self._annotation is None:
+            self._annotation = _trace_annotation()
         now = self._clock()
         self._t0 = now
         self._last = now
         self._phases = {}
         self._step_kinds = {}
+        self._kind = None
         self._t0_ns = time.monotonic_ns()
+        self._open(phase)
 
-    def mark(self, phase: str, kind: Optional[str] = None) -> None:
+    def enter(self, phase: str, kind: Optional[str] = None) -> None:
+        """Close the open phase and open ``phase``.  ``kind`` (on
+        ``dispatch``) names the jitted entrypoint; the overlap and
+        readback that follow are booked to it too."""
         if self._t0 is None:
             return  # dispatch helper invoked outside step() (tests)
-        now = self._clock()
-        delta = now - self._last
-        self._phases[phase] = self._phases.get(phase, 0.0) + delta
+        self._close(self._clock())
         if kind is not None:
+            self._kind = kind
+            self.dispatch_kind_n[kind] = self.dispatch_kind_n.get(kind, 0) + 1
+        self._open(phase)
+
+    def _open(self, phase: str) -> None:
+        self._phase = phase
+        if self._annotation.is_enabled():
+            kind = self._kind if phase in _DEVICE_FACING else None
+            span = self._annotation(
+                _SPAN_NAMES[phase], step=self.busy_steps_total,
+                kind=kind or "", t_mono_ns=time.monotonic_ns())
+            span.__enter__()
+            self._span = span
+
+    def _close(self, now: float) -> None:
+        delta = now - self._last
+        self._last = now
+        phase = self._phase
+        self._phases[phase] = self._phases.get(phase, 0.0) + delta
+        kind = self._kind
+        if kind is not None and phase in _DEVICE_FACING:
             self.dispatch_kind_s[kind] = \
                 self.dispatch_kind_s.get(kind, 0.0) + delta
-            self.dispatch_kind_n[kind] = \
-                self.dispatch_kind_n.get(kind, 0) + 1
-            self._step_kinds[kind] = \
-                self._step_kinds.get(kind, 0.0) + delta
-        self._last = now
+            self._step_kinds[kind] = self._step_kinds.get(kind, 0.0) + delta
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
     def end(self, trace: Optional[tuple] = None) -> None:
         if self._t0 is None:
             return
         now = self._clock()
+        self._close(now)
         phases = self._phases
-        phases["host_post"] = phases.get("host_post", 0.0) + (now - self._last)
         wall = now - self._t0
         t0_ns = self._t0_ns
         self._t0 = None
@@ -143,20 +217,23 @@ class StepTimeline:
         self.steps_total += 1
         if not busy:
             return  # idle polls would drown the per-turn numbers
-        gap = (wall - phases.get("dispatch", 0.0)
-               - phases.get("overlap", 0.0)
-               - phases.get("readback", 0.0))
+        facing = sum(phases.get(p, 0.0) for p in _DEVICE_FACING)
+        gap = wall - facing
         self.busy_steps_total += 1
         self.wall_s_total += wall
         self.host_gap_s_total += gap
         for p, v in phases.items():
             self.phase_s_total[p] = self.phase_s_total.get(p, 0.0) + v
+        classes = {KIND_CLASS.get(k, "mixed") for k in self._step_kinds}
+        cls = classes.pop() if len(classes) == 1 else "mixed"
+        self.class_steps[cls] += 1
+        self.class_wall_s[cls] += wall
+        self.class_device_s[cls] += facing
         a = self._alpha
         self.ewma_wall_s = wall if self.busy_steps_total == 1 else (
             (1 - a) * self.ewma_wall_s + a * wall)
         self.ewma_host_gap_s = gap if self.busy_steps_total == 1 else (
             (1 - a) * self.ewma_host_gap_s + a * gap)
-        self.recent.append({"wall_s": wall, "phases": dict(phases)})
         self._emit_step_span(trace, t0_ns, wall, phases)
 
     # ----------------------------------------------------------- trace emit
@@ -221,6 +298,12 @@ class StepTimeline:
             "ewma_wall_ms": self.ewma_wall_s * 1e3,
             "ewma_host_gap_ms": self.ewma_host_gap_s * 1e3,
             "phases": {p: self.phase_s_total.get(p, 0.0) for p in PHASES},
+            # flat, so that a reader of top-level numbers gets them
+            **{f"{c}_steps_total": self.class_steps[c] for c in CLASSES},
+            **{f"{c}_wall_seconds_total": self.class_wall_s[c]
+               for c in CLASSES},
+            **{f"{c}_device_seconds_total": self.class_device_s[c]
+               for c in CLASSES},
             "dispatch_kinds": {
                 k: {
                     "seconds": self.dispatch_kind_s[k],

@@ -374,6 +374,9 @@ def paged_decode_attention_mq(
         out_shape=jax.ShapeDtypeStruct((b, rows, hkd), q.dtype),
         interpret=interpret,
         cost_estimate=cost,
+        # the name a profile shows; cellbench's kernel.decode_attn_roofline
+        # matches the prefix paged_decode_attention
+        name="paged_decode_attention_mq" + ("_int8" if quant else ""),
     )(*operands)
 
     # Collapse the block-diagonal layout back to [B, S, H, D].

@@ -102,4 +102,5 @@ def int8_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="int8_matmul",
     )(x, wq, scale.reshape(1, n))

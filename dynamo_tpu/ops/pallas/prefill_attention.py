@@ -351,6 +351,9 @@ def paged_prefill_attention(
         out_shape=jax.ShapeDtypeStruct((b, hk, g, s, d), q.dtype),
         interpret=interpret,
         cost_estimate=cost,
+        # the name a profile shows; cellbench's kernel.prefill_attn_roofline
+        # matches the prefix paged_prefill_attention
+        name="paged_prefill_attention" + ("_int8" if quant else ""),
     )(*operands)
     # [B, Hk, G, S, D] -> [B, S, H, D]
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)
@@ -698,6 +701,7 @@ def ragged_paged_prefill_attention(
         out_shape=jax.ShapeDtypeStruct((1, hk, g, t, d), q.dtype),
         interpret=interpret,
         cost_estimate=cost,
+        name="paged_prefill_attention_ragged" + ("_int8" if quant else ""),
     )(*operands)
     # [1, Hk, G, T, D] -> [1, T, H, D]
     return out.transpose(0, 3, 1, 2, 4).reshape(1, t, h, d)

@@ -58,15 +58,16 @@ def reset_producers() -> None:
     from dynamo_tpu.engine.counters import (counters, kv_shard_counters,
                                             kv_stream_counters,
                                             lookahead_counters,
-                                            persist_counters)
+                                            persist_counters,
+                                            request_counters)
     from dynamo_tpu.fault.counters import counters as fault_counters
     from dynamo_tpu.obs.costs import transfer_costs
     from dynamo_tpu.obs.perfmodel import perf_model
     from dynamo_tpu.obs.timeline import step_timeline
 
     for c in (counters, persist_counters, kv_stream_counters,
-              kv_shard_counters, lookahead_counters, fault_counters,
-              transfer_costs, perf_model):
+              kv_shard_counters, lookahead_counters, request_counters,
+              fault_counters, transfer_costs, perf_model):
         c.reset()
     step_timeline.reset()
     step_timeline._clock = time.perf_counter
@@ -78,7 +79,8 @@ def seed_http_metrics():
     from dynamo_tpu.engine.counters import (counters, kv_shard_counters,
                                             kv_stream_counters,
                                             lookahead_counters,
-                                            persist_counters)
+                                            persist_counters,
+                                            request_counters)
     from dynamo_tpu.fault.counters import counters as fault_counters
     from dynamo_tpu.llm.http.metrics import Metrics
     from dynamo_tpu.obs.costs import transfer_costs
@@ -112,6 +114,12 @@ def seed_http_metrics():
     lookahead_counters.record_commit()
     lookahead_counters.record_commit()
     lookahead_counters.record_flush()
+    request_counters.record_decode(12)
+    request_counters.record_decode(11)
+    request_counters.record_finish()
+    request_counters.record_finish()
+    request_counters.record_cut_short()
+    request_counters.record_first_token(0.125)
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()
     persist_counters.record_spill(4096)
@@ -129,18 +137,18 @@ def seed_http_metrics():
     transfer_costs.record("prefill-0", "decode-0", "dcn", 5_000_000, 0.025)
     transfer_costs.record("decode-0", "decode-0", "ici", 1_000_000, 0.001)
 
-    # two busy steps at virtual time: 10 ms dispatch, 2 ms host_build,
-    # 1 ms readback, 0.5 ms host_post each
+    # two busy steps at virtual time, one prefill ("step") and one decode:
+    # 2 ms host_build, 10 ms dispatch, 1 ms readback, 0.5 ms host_post each
     clock = _Clock()
     step_timeline._clock = clock
-    for _ in range(2):
-        step_timeline.begin()
+    for kind in ("step", "decode_multi"):
+        step_timeline.begin("host_build")
         clock.advance(0.002)
-        step_timeline.mark("host_build")
+        step_timeline.enter("dispatch", kind=kind)
         clock.advance(0.010)
-        step_timeline.mark("dispatch", kind="step")
+        step_timeline.enter("readback")
         clock.advance(0.001)
-        step_timeline.mark("readback")
+        step_timeline.enter("host_post")
         clock.advance(0.0005)
         step_timeline.end()
 

@@ -289,9 +289,9 @@ def test_step_timeline_clock_injection():
 
     t = [0.0]
     tl = StepTimeline(clock=lambda: t[0])
-    tl.begin()
+    tl.begin("dispatch")
     t[0] = 0.010
-    tl.mark("dispatch", kind="step")
+    tl.enter("host_post")
     t[0] = 0.015
     tl.end()
     assert tl.busy_steps_total == 1

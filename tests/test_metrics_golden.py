@@ -121,12 +121,19 @@ def test_perf_model_stats_round_trip():
 
     rows = perf_model_stats_from_text(
         (GOLDEN / "render_http.txt").read_text())
-    assert rows == {"step": {
-        "predicted_dispatch_ms": 1.25,
-        "measured_dispatch_ms": 10.0,
-        "dispatches_total": 2.0,
-        "model_error_ratio": 0.125,
-    }}
+    # measured = dispatch -> readback returned (10 + 1 ms), per kind
+    assert rows == {
+        "step": {
+            "predicted_dispatch_ms": 1.25,
+            "measured_dispatch_ms": 11.0,
+            "dispatches_total": 1.0,
+            "model_error_ratio": 0.1136,
+        },
+        "decode_multi": {
+            "measured_dispatch_ms": 11.0,
+            "dispatches_total": 1.0,
+        },
+    }
 
 
 def test_snapshot_parses_labeled_series():

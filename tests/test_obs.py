@@ -118,24 +118,24 @@ def test_collector_bounded_and_request_binding(traced):
 
 
 def test_timeline_phase_sum_accounts_wall():
-    """The mark model attributes every elapsed interval to some phase,
-    so sum(phases) == wall to float rounding — well past the >=95 %
+    """Every instant of a step lies in exactly one open phase, so
+    sum(phases) == wall to float rounding — well past the >=95 %
     acceptance bound."""
     import time
 
     tl = StepTimeline()
     t_start = time.perf_counter()
-    tl.begin()
+    tl.begin("admission")
     time.sleep(0.002)
-    tl.mark("admission")
+    tl.enter("host_build")
     time.sleep(0.001)
-    tl.mark("host_build")
+    tl.enter("dispatch")
     time.sleep(0.003)
-    tl.mark("dispatch")
+    tl.enter("readback")
     time.sleep(0.002)
-    tl.mark("readback")
+    tl.enter("host_post")
     time.sleep(0.001)
-    tl.end()  # residue -> host_post
+    tl.end()
     wall_ub = time.perf_counter() - t_start
 
     snap = tl.snapshot()
@@ -144,7 +144,8 @@ def test_timeline_phase_sum_accounts_wall():
     assert 0.009 <= wall <= wall_ub
     phase_sum = sum(snap["phases"].values())
     assert phase_sum >= 0.95 * wall
-    assert snap["phases"]["host_post"] > 0  # residue attribution
+    assert phase_sum == pytest.approx(wall, rel=1e-9)
+    assert snap["phases"]["host_post"] > 0
     # host gap = wall - dispatch - readback
     gap_ms = (wall - snap["phases"]["dispatch"]
               - snap["phases"]["readback"]) * 1e3
@@ -154,14 +155,15 @@ def test_timeline_phase_sum_accounts_wall():
 def test_timeline_idle_steps_excluded():
     tl = StepTimeline()
     tl.begin()
-    tl.mark("host_ops")
+    tl.enter("host_ops")
     tl.end()  # no upload/dispatch/readback -> idle poll
     snap = tl.snapshot()
     assert snap["steps_total"] == 1
     assert snap["busy_steps_total"] == 0
     assert snap["wall_seconds_total"] == 0.0  # idle wall not banked
-    # a mark outside begin/end (helper called from a unit test) is a no-op
-    tl.mark("dispatch")
+    # an enter outside begin/end (helper called from a unit test) is a
+    # no-op
+    tl.enter("dispatch", kind="step")
     assert tl.snapshot() == snap
 
 

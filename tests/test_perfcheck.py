@@ -574,13 +574,13 @@ def test_metrics_render_exports_perf_gauges():
         x = jnp.ones((32, 32), jnp.float32)
         # the timeline is process-global with engine-thread writers; a
         # straggling engine thread from an earlier test calling
-        # begin()/end() between our marks silently swallows the
+        # begin()/end() between our calls silently swallows the
         # dispatch sample — retry until our mark lands
         for _ in range(5):
-            step_timeline.begin()
+            step_timeline.begin("upload")
+            step_timeline.enter("dispatch", kind="step")
             perf_model.offer("step", f, (x,))
             f(x)
-            step_timeline.mark("dispatch", kind="step")
             step_timeline.end()
             if step_timeline.dispatch_kind_n.get("step"):
                 break
