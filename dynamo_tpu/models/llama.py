@@ -6,8 +6,22 @@ Design (TPU-first, not a port):
     run paged attention over their full context (ops/paged_attention.py).
   * ``lax.scan`` over layers: per-layer weights are stacked on a leading L
     axis so the whole stack compiles once — fast XLA compiles even at 80
-    layers.  The KV cache is scan CARRY updated in place by scatter (never
-    sliced per layer), so decode traffic is O(tokens), not O(cache).
+    layers.  What the loop body reads decides how a stack reaches it:
+      - norms, attention projections and the dense FFN ride the scan as
+        ``xs``; their consumers are XLA dot fusions, which read the
+        layer's slice in place (the chip's trace shows the FFN matrices
+        at the bandwidth roofline; ``wq``/``wk`` are re-laid-out per
+        head, a layout choice of the dot, ROADMAP S1b);
+      - the KV cache is scan CARRY updated in place by scatter and indexed
+        by the layer number (never sliced per layer), so decode traffic
+        is O(tokens), not O(cache);
+      - bf16 MoE expert stacks are CLOSED OVER and indexed by the layer
+        number too: their consumer, ``lax.ragged_dot``, is a Mosaic
+        custom call on the TPU, a custom call takes whole buffers, and a
+        slice riding ``xs`` was materialised — three copies of E·Dm·F
+        weights a layer, 62% of Qwen3-30B-A3B's device time on the v5e
+        (PERF.md §6, PR 26).  int8 (QTensor) experts still ride ``xs``
+        (``experts_in_place``).
   * Static shapes everywhere; bf16 weights/activations on the MXU, f32
     norms/softmax/logits.
   * Tensor parallelism is declarative: :meth:`partition_specs` returns a
@@ -56,6 +70,7 @@ from dynamo_tpu.ops.paged_attention import (
     prefill_attention,
     ragged_prefill_attention,
     softcap,
+    tp_size,
     write_kv_cache_layer,
 )
 
@@ -417,6 +432,14 @@ class LlamaModel:
         # that copy, not attention, dominated decode ITL.)
         uo = cfg.rmsnorm_unit_offset
 
+        # bf16 expert stacks stay out of the pytree the scan slices:
+        # layer_step closes over them and ragged_dot reads layer li's
+        # experts where they lie (grouped_expert_dispatch)
+        layers, experts = params["layers"], None
+        if cfg.is_moe and experts_in_place(layers, tp_size()):
+            experts = {k: layers[k] for k in _EXPERT_KEYS}
+            layers = {k: w for k, w in layers.items() if k not in experts}
+
         def layer_step(carry, layer_in):
             h, cache = carry
             lp, li = layer_in
@@ -466,8 +489,11 @@ class LlamaModel:
 
             with jax.named_scope("mlp"):
                 x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps, uo)
-                mlp_out = (_moe_mlp(cfg, lp, x) if cfg.is_moe
-                           else _dense_mlp(cfg, lp, x))
+                if experts:   # the whole stacks; li picks the layer
+                    mlp_out = _moe_mlp(cfg, {**lp, **experts}, x, layer=li)
+                else:
+                    mlp_out = (_moe_mlp(cfg, lp, x) if cfg.is_moe
+                               else _dense_mlp(cfg, lp, x))
                 if cfg.post_norms:
                     mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"],
                                        cfg.rms_norm_eps, uo)
@@ -477,7 +503,7 @@ class LlamaModel:
         (hidden, new_cache), _ = jax.lax.scan(
             layer_step,
             (hidden, kv_cache),
-            (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)),
+            (layers, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
         )
         with jax.named_scope("logits"):
             hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps,
@@ -620,22 +646,59 @@ def _moe_router(cfg: ModelConfig, lp: dict, xf: jax.Array):
     return weights, topi
 
 
-def _moe_mlp(cfg: ModelConfig, lp: dict, x: jax.Array) -> jax.Array:
+_EXPERT_KEYS = ("w_gate", "w_up", "w_down")
+_LANES = 128  # minor-dimension tile of a TPU array
+
+
+def experts_in_place(layers: dict, tp: int) -> bool:
+    """Whether ``forward`` closes over the stacked expert arrays of
+    ``layers`` (the layer-indexed ``grouped_expert_dispatch``) or lets
+    the scan slice them — a static function of the leaves' type and the
+    mesh, made before tracing, like ``attention_impl``'s.
+
+    QTensor leaves are sliced: dequantising a closed-over stack would
+    materialise every layer.  Under ``tp`` > 1 a device holds F/tp of
+    each expert; where that is not a multiple of the 128 lanes (Qwen3's
+    768 over tp=4) the TPU keeps ``w_gate``/``w_up`` with Dm minor, and
+    the custom call's row-major operand then costs a re-layout of the
+    WHOLE stack every step (compiled for a described v5e: temp 538 MB
+    at depth 2, growing with L) where the sliced form re-lays one layer
+    at a time."""
+    if any(isinstance(layers[k], QTensor) for k in _EXPERT_KEYS):
+        return False
+    ffn = layers["w_gate"].shape[-1]
+    return tp == 1 or ffn % tp == 0 and (ffn // tp) % _LANES == 0
+
+
+def _moe_mlp(cfg: ModelConfig, lp: dict, x: jax.Array,
+             layer=None) -> jax.Array:
+    """``lp`` holds this layer's expert arrays — or, with ``layer``, the
+    stacked [L, E, ...] ones, of which ``layer`` is this one's index."""
     import os
 
     if os.environ.get("DYNAMO_MOE_DENSE"):
+        if layer is not None:
+            lp = {**lp, **{k: lp[k][layer] for k in _EXPERT_KEYS}}
         return _moe_mlp_dense(cfg, lp, x)
-    return _moe_mlp_grouped(cfg, lp, x)
+    return _moe_mlp_grouped(cfg, lp, x, layer)
 
 
 def grouped_expert_dispatch(xf, weights, topi, num_experts,
-                            w_gate, w_up, w_down, act):
+                            w_gate, w_up, w_down, act, layer=None):
     """The grouped-MoE core, shared across model families (Llama-family
     MoE here, DeepSeekMoE in models/deepseek.py): sort token→expert
     assignments by expert, run each projection as ONE ``lax.ragged_dot``
     (XLA's grouped matmul), then weighted unsort-sum back per token.
     ``xf`` [T,Dm]; ``weights``/``topi`` [T,k]; ``w_*`` dense [E,Dm,F] /
-    [E,F,Dm]; ``act`` maps the gate activation."""
+    [E,F,Dm]; ``act`` maps the gate activation.
+
+    With ``layer`` (a traced int32 scalar) the ``w_*`` are the whole
+    stacked [L,E,Dm,F] / [L,E,F,Dm] arrays, viewed as L·E groups of which
+    only ``[layer·E, (layer+1)·E)`` have rows.  On the TPU ``ragged_dot``
+    is a Mosaic custom call that takes whole buffers, so a layer sliced
+    out of the stack first is a copy of E·Dm·F weights per projection;
+    this form reads them where they lie, and the kernel's grid visits
+    only (group, row-tile) pairs that have rows."""
     t, d = xf.shape
     k = topi.shape[1]
     flat_e = topi.reshape(t * k)
@@ -643,6 +706,12 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
     token_idx = order // k               # source token of each sorted row
     xs = xf[token_idx]                   # [T*k, Dm] gather
     group_sizes = jnp.bincount(flat_e, length=num_experts).astype(jnp.int32)
+    if layer is not None:
+        groups = w_gate.shape[0] * num_experts
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros(groups, jnp.int32), group_sizes, (layer * num_experts,))
+        w_gate, w_up, w_down = (
+            w.reshape(groups, *w.shape[2:]) for w in (w_gate, w_up, w_down))
     gate = jax.lax.ragged_dot(xs, w_gate, group_sizes)
     up = jax.lax.ragged_dot(xs, w_up, group_sizes)
     out = jax.lax.ragged_dot(act(gate) * up, w_down, group_sizes)  # [T*k, Dm]
@@ -652,7 +721,8 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
     return out[jnp.argsort(order)].reshape(t, k, d).sum(axis=1)
 
 
-def _moe_mlp_grouped(cfg: ModelConfig, lp: dict, x: jax.Array) -> jax.Array:
+def _moe_mlp_grouped(cfg: ModelConfig, lp: dict, x: jax.Array,
+                     layer=None) -> jax.Array:
     """Grouped MoE dispatch: sort token→expert assignments by expert, run
     ONE ragged (grouped) matmul per projection, unsort, weighted-sum per
     token.  Intermediates are [T·k, F] — E/k× smaller than the dense
@@ -674,12 +744,15 @@ def _moe_mlp_grouped(cfg: ModelConfig, lp: dict, x: jax.Array) -> jax.Array:
     with jax.named_scope("moe_experts"):
         out = grouped_expert_dispatch(
             xf, weights, topi, cfg.num_experts,
-            # quantized experts dequant at the operand: convert fuses into
-            # the grouped dot's operand load, HBM reads stay int8
+            # quantized experts (always this layer's slice) dequantise
+            # here.  Whether the convert fuses into the grouped dot's
+            # operand load, HBM reads staying int8, is not measured; on
+            # the TPU the dot is a custom call, whose operands are whole
+            # buffers.
             dequantize(lp["w_gate"], x.dtype),
             dequantize(lp["w_up"], x.dtype),
             dequantize(lp["w_down"], x.dtype),
-            lambda g: _act(cfg, g),
+            lambda g: _act(cfg, g), layer=layer,
         )
     return out.reshape(b, s, d)
 

@@ -36,6 +36,7 @@ __all__ = [
     "ATTENTION_PHASES",
     "attention_impl",
     "softcap",
+    "tp_size",
     "write_kv_cache",
     "write_kv_cache_layer",
     "paged_attention",
@@ -100,7 +101,7 @@ def attention_impl(
     return "pallas", "tpu" if tp == 1 else f"tpu, shard_map over tp={tp}"
 
 
-def _tp_size() -> int:
+def tp_size() -> int:
     """Size of the tensor-parallel axis of the mesh the caller traces
     under (the engine wraps its jitted steps in
     ``jax.sharding.use_abstract_mesh``); 1 with no mesh in scope."""
@@ -162,7 +163,7 @@ def paged_attention_layer(
     windowed = window is not None and block_tables.shape[1] * bs > window
     if not windowed:
         window = None  # static no-op: full attention is exact here
-    tp = _tp_size()
+    tp = tp_size()
     phase = "decode" if s == 1 else "mq" if s <= MQ_MAX_S else None
     if phase and attention_impl(
             phase, num_kv_heads=hk, block_size=bs, quant=quant,
@@ -250,7 +251,7 @@ def prefill_attention(
     windowed = window is not None and prefix_blocks * bs_ + s > window
     if not windowed:
         window = None
-    tp = _tp_size()
+    tp = tp_size()
     if s > 1 and attention_impl(
             "prefill", num_kv_heads=hk, block_size=bs_, quant=quant,
             windowed=windowed, tp=tp)[0] == "pallas":
@@ -373,7 +374,7 @@ def ragged_prefill_attention(
     windowed = window is not None and prefix_blocks * bs + t > window
     if not windowed:
         window = None
-    tp = _tp_size()
+    tp = tp_size()
     if t > 1 and attention_impl(
             "ragged", num_kv_heads=hk, block_size=bs, quant=quant,
             windowed=windowed, tp=tp)[0] == "pallas":

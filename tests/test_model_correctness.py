@@ -259,6 +259,122 @@ def test_moe_grouped_quantized_matches_dense():
     )
 
 
+@pytest.mark.parametrize("routing", ["empty-experts", "one-expert"])
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+def test_layer_indexed_dispatch_equals_sliced(layer, routing):
+    """grouped_expert_dispatch over the whole stacked [L, E, ...] arrays and
+    a layer index is the dispatch over that layer's slice, bit for bit in
+    the serving dtype — the other layers' groups are empty and the grouped
+    matmul skips them.  (In float32 the CPU's expansion of ragged_dot sums
+    over group and row jointly, so there the two differ in the last ulp.)"""
+    import jax
+
+    from dynamo_tpu.models.llama import grouped_expert_dispatch
+
+    n_layers, e, d, f, t, k = 5, 8, 32, 48, 3, 2   # 6 rows: experts stay empty
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    bf16 = lambda a: a.astype(jnp.bfloat16)
+    w_gate = bf16(jax.random.normal(keys[0], (n_layers, e, d, f)) / d ** 0.5)
+    w_up = bf16(jax.random.normal(keys[1], (n_layers, e, d, f)) / d ** 0.5)
+    w_down = bf16(jax.random.normal(keys[2], (n_layers, e, f, d)) / f ** 0.5)
+    x = bf16(jax.random.normal(keys[3], (t, d)))
+    weights = jax.nn.softmax(jax.random.normal(keys[4], (t, k)), axis=-1)
+    if routing == "one-expert":
+        topi = jnp.full((t, k), e - 1, jnp.int32)
+    else:
+        topi = jax.random.randint(keys[5], (t, k), 0, e)
+
+    li = jnp.int32(layer)   # traced in both, as the layer scan has it
+    want = jax.jit(lambda li: grouped_expert_dispatch(
+        x, weights, topi, e, w_gate[li], w_up[li], w_down[li], jax.nn.silu)
+    )(li)
+    got = jax.jit(lambda li: grouped_expert_dispatch(
+        x, weights, topi, e, w_gate, w_up, w_down, jax.nn.silu, layer=li)
+    )(li)
+    want, got = (np.asarray(a, np.float32) for a in (want, got))
+    assert np.abs(want).max() > 0
+    np.testing.assert_array_equal(got, want)
+
+
+def _dispatch_layers_seen(monkeypatch) -> list:
+    """The ``layer`` argument of every grouped_expert_dispatch call traced
+    from here on: an index where ``forward`` closed over the expert stacks,
+    None where the scan sliced them."""
+    import dynamo_tpu.models.llama as llama
+
+    seen = []
+    dispatch = llama.grouped_expert_dispatch
+
+    def spy(*a, layer=None):
+        seen.append(layer)
+        return dispatch(*a, layer=layer)
+
+    monkeypatch.setattr(llama, "grouped_expert_dispatch", spy)
+    return seen
+
+
+def _tiny_qwen3_moe_forward(model, params, b, s):
+    """Logits of one ``forward`` over fresh sequences: S > 1 takes the
+    prefill fast path, S == 1 the decode path."""
+    import jax
+
+    toks = jax.random.randint(
+        jax.random.PRNGKey(8), (b, s), 0, model.config.vocab_size)
+    positions = jnp.tile(jnp.arange(s, dtype=jnp.int32), (b, 1))
+    tables = jnp.arange(b * 2, dtype=jnp.int32).reshape(b, 2)
+    hidden, _ = model.forward(
+        params, toks, positions, model.init_kv_cache(b * 2, BLOCK), tables,
+        jnp.full((b,), s, jnp.int32), tables[:, :1] * BLOCK + positions,
+        prefix_blocks=1 if s > 1 else None,
+    )
+    return np.asarray(model.compute_logits(params, hidden))
+
+
+@pytest.mark.parametrize("shape", [(2, 5), (3, 1)], ids=["prefill", "decode"])
+def test_qwen3_moe_forward_in_place_equals_sliced_scan(shape, monkeypatch):
+    """A Qwen3-MoE ``forward`` that closes over the expert stacks gives the
+    logits of the scan that slices them (the parent's), exactly, in bf16."""
+    import jax
+
+    import dynamo_tpu.models.llama as llama
+
+    cfg = ModelConfig.tiny(
+        num_layers=3, num_experts=8, num_experts_per_tok=2, qk_norm=True,
+        norm_topk_prob=False, intermediate_size=48, dtype="bfloat16")
+    model = LlamaModel(cfg)
+    params = model.init_params(jax.random.PRNGKey(7))
+    layers_seen = _dispatch_layers_seen(monkeypatch)
+    got = _tiny_qwen3_moe_forward(model, params, *shape)
+    assert layers_seen and all(li is not None for li in layers_seen)
+    # the stored pytree is what it was: [L, E, Dm, F]
+    assert params["layers"]["w_gate"].shape == (3, 8, cfg.hidden_size, 48)
+
+    del layers_seen[:]
+    monkeypatch.setattr(llama, "experts_in_place", lambda layers, tp: False)
+    want = _tiny_qwen3_moe_forward(model, params, *shape)
+    assert layers_seen and all(li is None for li in layers_seen)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_qtensor_experts_keep_the_sliced_form(monkeypatch):
+    """int8 experts ride the scan's xs and dequantise after the slice (the
+    choice is made on the leaf's type), and match the dense oracle."""
+    import jax
+
+    from dynamo_tpu.models.quant import QTensor
+
+    cfg = ModelConfig.tiny(num_experts=4, num_experts_per_tok=2, qk_norm=True)
+    model = LlamaModel(cfg)
+    params = model.init_params(jax.random.PRNGKey(5), quantized=True)
+    assert isinstance(params["layers"]["w_gate"], QTensor)
+    layers_seen = _dispatch_layers_seen(monkeypatch)
+    got = _tiny_qwen3_moe_forward(model, params, 2, 5)
+    assert layers_seen and all(li is None for li in layers_seen)
+    monkeypatch.setenv("DYNAMO_MOE_DENSE", "1")
+    want = _tiny_qwen3_moe_forward(model, params, 2, 5)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+
+
 def test_gemma2_matches_hf():
     """Gemma2 = GeGLU + (1+w) RMSNorm + embed scaling + sandwich norms +
     query_pre_attn_scalar + attn/final logit softcaps, all through the

@@ -633,7 +633,7 @@ def test_dispatch_under_tp_mesh_runs_kernels_per_kv_head(phase, monkeypatch):
         with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             assert pa.attention_impl(
                 phase, num_kv_heads=hk, block_size=bs,
-                tp=pa._tp_size()) == ("pallas", "tpu, shard_map over tp=4")
+                tp=pa.tp_size()) == ("pallas", "tpu, shard_map over tp=4")
             return fn(*a)
 
     got = jax.jit(under_mesh)(*args)

@@ -10,9 +10,12 @@ phase the static rule calls ``pallas`` is shown to compile as one.
 Results and times come only from ``chip_smoke.py`` on the chip.
 """
 
+import contextlib
 import functools
 import importlib
+import json
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
 
@@ -176,6 +179,111 @@ def test_attention_phase_compiles_under_tp4(topo, tpu_gate, phase):
     per_device = compiled.memory_analysis().argument_size_in_bytes
     assert cache_bytes / 4 <= per_device < cache_bytes / 4 * 1.25, (
         per_device, cache_bytes)
+
+
+# ---------------------------------------------------------------------------
+# Qwen3-30B-A3B, the benchmark's MoE configuration, at depth 2: the expert
+# weights are read where they lie.  ``lax.ragged_dot`` is a Mosaic custom
+# call on the TPU and takes whole buffers, so a layer the scan slices out of
+# the stacked [L, E, Dm, F] arrays is materialised: three copies of
+# E·Dm·F·2 = 403 MB a layer, 62% of the cell's device time (ledger, PR 25).
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(")
+_PASS_THROUGH = ("parameter", "get-tuple-element", "bitcast")
+
+
+def _largest_produced(hlo: str) -> tuple[int, str]:
+    """(bytes, instruction) of the largest array an instruction of the
+    optimised HLO writes to memory: not one that hands a buffer on, and
+    not one inside a fused computation, whose values live in registers."""
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    worst, inside = (0, ""), ""
+    for line in hlo.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            inside = head.group(1)
+        m = _HLO_INSTR.match(line)
+        if not m or inside in fused or m.group(4) in _PASS_THROUGH:
+            continue
+        bits = re.search(r"\d+", m.group(2))      # bf16, f32, s8; pred: none
+        size = max(int(bits.group()) // 8, 1) if bits else 1
+        for d in filter(None, m.group(3).split(",")):
+            size *= int(d)
+        worst = max(worst, (size, f"{m.group(1)} = {m.group(4)}"))
+    return worst
+
+
+@pytest.mark.parametrize(
+    "case", ["decode", "prefill", "engine-decode", "decode-tp2", "decode-tp4"])
+def test_qwen3_moe_reads_expert_weights_in_place(topo, tpu_gate, case):
+    from dynamo_tpu.engine.core import multi_decode_step
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.models.llama import LlamaModel, experts_in_place
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench/configs/qwen3-30b-a3b.json")) as f:
+        hf = dict(json.load(f), num_hidden_layers=2)
+    cfg = ModelConfig.from_hf_config(hf, dtype="bfloat16")
+    model = LlamaModel(cfg)
+    expert_bytes = (cfg.num_experts * cfg.hidden_size
+                    * cfg.intermediate_size * 2)
+
+    tp = {"decode-tp2": 2, "decode-tp4": 4}.get(case, 1)
+    if tp > 1:   # --tp 4 is the 2x2 host as one "model" axis
+        mesh = Mesh(np.array(topo.devices[:tp]).reshape(1, tp),
+                    ("data", "model"))
+        place = lambda spec: NamedSharding(mesh, spec)
+    else:
+        mesh = None
+        place = lambda spec: SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype=jnp.int32, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=place(spec))
+
+    params = jax.tree.map(
+        lambda a, spec: sds(a.shape, a.dtype, spec),
+        jax.eval_shape(lambda: model.init_params(jax.random.key(0))),
+        model.partition_specs())
+    cache = sds((cfg.num_layers, N_BLOCKS, 2, BS,
+                 cfg.num_kv_heads * cfg.head_dim), jnp.bfloat16,
+                model.cache_spec())
+    b, s = (1, PREFILL_S) if case == "prefill" else (32, 1)
+
+    if case == "engine-decode":   # the nested scan the served path runs
+        def fn(params, cache, *a):
+            return multi_decode_step(model, params, cache, *a,
+                                     num_steps=1, block_size=BS)
+        args = (sds((b,)), sds((b,)), sds((b, M)), sds((b,)), sds((b,)),
+                sds((2,), jnp.uint32), sds((b,), jnp.float32), sds((b,)),
+                sds((b,), jnp.float32))
+    else:
+        def fn(params, cache, tokens, positions, tables, lens, slots):
+            kw = dict(prefix_blocks=1) if case == "prefill" else {}
+            with (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+                  if mesh else contextlib.nullcontext()):
+                return model.forward(params, tokens, positions, cache,
+                                     tables, lens, slots, **kw)
+        args = (sds((b, s)), sds((b, s)), sds((b, M)), sds((b,)),
+                sds((b, s)))
+
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("ragged-dot-none") >= 3 and "tpu_custom_call" in hlo
+    # Per device, a layer's experts are expert_bytes / tp.  At tp 4 a shard
+    # is F/4 = 192 wide, not a multiple of the 128 lanes, and the static
+    # rule keeps the sliced form (copies of a layer's shard, 101 MB): the
+    # in-place form there re-lays out both whole stacks every step (temp
+    # 538 MB at this depth, growing with it), which the whole-layer bound
+    # catches.
+    in_place = experts_in_place(params["layers"], tp)
+    assert in_place == (tp != 4)
+    bound = expert_bytes // tp if in_place else expert_bytes
+    size, instr = _largest_produced(hlo)
+    assert size < bound, (instr, size)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < bound, temp
 
 
 def test_tp_rules_that_keep_the_xla_path(tpu_gate):
