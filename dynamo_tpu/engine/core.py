@@ -46,7 +46,11 @@ import numpy as np
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.counters import counters as prefill_counters
-from dynamo_tpu.engine.counters import lookahead_counters, request_counters
+from dynamo_tpu.engine.counters import (
+    lookahead_counters,
+    mesh_shape,
+    request_counters,
+)
 from dynamo_tpu.engine.grammar import (
     INIT_STATE, JsonGrammar, compile_choice_vocab, compile_regex_vocab,
     compose_tables, device_tables, grammar_advance, grammar_mask,
@@ -556,9 +560,14 @@ class EngineCore:
                     self._persist_events.append(
                         KvStoredEvent(block_hashes=resident, tier=TIER_PERSIST))
 
-        cache = model.init_kv_cache(config.num_blocks, config.block_size, cache_dtype)
+        def make_cache():
+            return model.init_kv_cache(
+                config.num_blocks, config.block_size, cache_dtype)
+
         self._cache_specs = None
-        if mesh is not None:
+        if mesh is None:
+            cache = make_cache()
+        else:
             from jax.sharding import NamedSharding
 
             from dynamo_tpu.models.quant import align_specs, prune_specs
@@ -575,11 +584,20 @@ class EngineCore:
             # cache sharding pruned the same way (a kv-head axis the mesh
             # doesn't divide replicates rather than failing device_put)
             self._cache_specs = prune_specs(
-                cache, model.cache_spec(quant=self.cache_quant), mesh
+                jax.eval_shape(make_cache),
+                model.cache_spec(quant=self.cache_quant), mesh
             )
-            cache = jax.device_put(cache, self._cache_sharding())
+            # made in its shards: the whole cache of a sharded model need
+            # not fit one device (Mistral-7B at tp 4: 16 GiB, 4 a device)
+            # (jitted once, here at init: the jit is what shards the zeros)
+            cache = jax.jit(  # dt: noqa[DT101]
+                make_cache, out_shardings=self._cache_sharding())()
         self.params = params
         self.cache = cache
+        # what this engine is spread over: metrics() and /metrics say it
+        self.mesh_tp = 1 if mesh is None else mesh.shape.get(AXIS_MODEL, 1)
+        self.mesh_devices = 1 if mesh is None else mesh.size
+        mesh_shape.update(tp=self.mesh_tp, devices=self.mesh_devices)
 
         self._rng = jax.random.PRNGKey(config.seed)
 
@@ -724,6 +742,7 @@ class EngineCore:
         # admission/finish; incremental append between turns)
         self._pen_cache: Optional[dict] = None
         self._last_was_prefill = False
+        self._admit_seq = 0              # order of admission: prefill is served in it
 
     # ----------------------------------------------------------- step kernel
     def _step_impl(self, params, cache, *args, prefix_blocks=None,
@@ -907,14 +926,13 @@ class EngineCore:
         )
 
         window = getattr(self.model.config, "sliding_window", None)
-        tp = 1 if self.mesh is None else self.mesh.shape.get(AXIS_MODEL, 1)
         return {
             phase: attention_impl(
                 phase, num_kv_heads=self.model.config.num_kv_heads,
                 block_size=self.config.block_size, quant=self.cache_quant,
                 windowed=(window is not None
                           and self.config.max_model_len > window),
-                tp=tp)
+                tp=self.mesh_tp)
             for phase in ATTENTION_PHASES
         }
 
@@ -1316,6 +1334,8 @@ class EngineCore:
             "requests_cut_short_total": self.requests_cut_short,
             "first_tokens_total": self.first_tokens,
             "first_token_seconds_total": self.first_token_s,
+            "mesh_tp": self.mesh_tp,
+            "mesh_devices": self.mesh_devices,
         }
         if self.host_pool is not None:
             out.update(self.host_pool.stats())
@@ -1379,13 +1399,21 @@ class EngineCore:
                 and req.abort_requested
             ):
                 self._finish_slot(req, FinishReason.CANCELLED)
-        ready = [
-            r
-            for r in self.slots
-            if r is not None
-            and r.state is RequestState.PREFILL
-            and self._prefill_ready(r)
-        ]
+        # in order of admission, not of slot: a new request takes the
+        # lowest free slot, and served by slot it would cut in ahead of a
+        # long prompt mid-prefill in a higher one, chunk after chunk (at a
+        # full batch of 64 the 95th percentile of TTFT was 7x the median,
+        # and timing chose which request starved: PERF.md, PR 27)
+        ready = sorted(
+            (
+                r
+                for r in self.slots
+                if r is not None
+                and r.state is RequestState.PREFILL
+                and self._prefill_ready(r)
+            ),
+            key=lambda r: r.admit_seq,
+        )
         decoding = any(
             r is not None and r.state is RequestState.RUNNING for r in self.slots
         )
@@ -1587,6 +1615,8 @@ class EngineCore:
             req.wait_upto = req.cached_tokens + alloc.joined_tokens
             self._reserve_own(req)
             req.slot = slot
+            req.admit_seq = self._admit_seq
+            self._admit_seq += 1
             if req.submitted_at:
                 req.queue_wait_s = time.perf_counter() - req.submitted_at
             req.state = (
@@ -1606,7 +1636,7 @@ class EngineCore:
                     req.abort_requested = True
 
     def _dispatch_prefill(self, ready: list[EngineRequest]) -> None:
-        """One prefill turn over the READY requests (slot order): the
+        """One prefill turn over the READY requests (admission order): the
         head request keeps its historical routing (seq-parallel long
         prompts dispatch alone), otherwise the token-budget ragged batch
         packs every non-SP ready request — or, with batching disabled

@@ -383,3 +383,15 @@ class RequestCounters:
 
 
 request_counters = RequestCounters()
+
+
+# The mesh the engine of this process runs on, written when an ``EngineCore``
+# is built (the last one built wins; its own ``metrics()`` says the same):
+#
+#     dynamo_tpu_engine_mesh_tp       gauge (size of the tensor-parallel axis
+#                                     "model"; 1 with no mesh)
+#     dynamo_tpu_engine_mesh_devices  gauge (devices of the mesh; 1 with no mesh)
+#
+# A number of a sharded server (a step time, a collective's share) means
+# something else than one chip's: a scrape says which it is looking at.
+mesh_shape = {"tp": 1, "devices": 1}

@@ -68,6 +68,7 @@ class EngineRequest:
     # advanced host-side per appended token, mirrored on device in-scan
     gstate: tuple = (INIT_STATE, 0, 0)
     slot: int = -1
+    admit_seq: int = -1        # position in the order of admission
     finish_reason: Optional[FinishReason] = None
     abort_requested: bool = False
     # dtspan trace context (trace_id, span_id) — the engine thread has

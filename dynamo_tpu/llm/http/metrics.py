@@ -21,8 +21,8 @@ from typing import Iterator
 
 from dynamo_tpu.engine.counters import counters as prefill_counters
 from dynamo_tpu.engine.counters import (kv_shard_counters, kv_stream_counters,
-                                        lookahead_counters, persist_counters,
-                                        request_counters)
+                                        lookahead_counters, mesh_shape,
+                                        persist_counters, request_counters)
 from dynamo_tpu.fault.counters import counters as fault_counters
 from dynamo_tpu.obs.costs import transfer_costs
 from dynamo_tpu.obs.metric_names import EngineMetric as EM
@@ -301,6 +301,11 @@ class Metrics:
         lines.append(f"# TYPE {EM.FIRST_TOKEN_SECONDS_TOTAL} counter")
         lines.append(f"{EM.FIRST_TOKEN_SECONDS_TOTAL} "
                      f"{round(rc.first_token_seconds_total, 6)}")
+        # the mesh this engine runs on (1 and 1 with no mesh)
+        lines.append(f"# TYPE {EM.MESH_TP} gauge")
+        lines.append(f"{EM.MESH_TP} {mesh_shape['tp']}")
+        lines.append(f"# TYPE {EM.MESH_DEVICES} gauge")
+        lines.append(f"{EM.MESH_DEVICES} {mesh_shape['devices']}")
         lines.append(f"# TYPE {EM.HOST_GAP_MS_PER_TURN} gauge")
         lines.append(f"{EM.HOST_GAP_MS_PER_TURN} "
                      f"{round(tl['host_gap_ms_per_turn'], 6)}")

@@ -114,6 +114,9 @@ class EngineMetric:
     FIRST_TOKENS_TOTAL = "dynamo_tpu_engine_first_tokens_total"
     FIRST_TOKEN_SECONDS_TOTAL = (
         "dynamo_tpu_engine_first_token_seconds_total")
+    # engine/counters.py mesh_shape
+    MESH_TP = "dynamo_tpu_engine_mesh_tp"
+    MESH_DEVICES = "dynamo_tpu_engine_mesh_devices"
 
 
 class KvTransferMetric:
@@ -223,6 +226,8 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.REQUESTS_CUT_SHORT_TOTAL: ("counter", ()),
     EngineMetric.FIRST_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.FIRST_TOKEN_SECONDS_TOTAL: ("counter", ()),
+    EngineMetric.MESH_TP: ("gauge", ()),
+    EngineMetric.MESH_DEVICES: ("gauge", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.BYTES_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.SECONDS_TOTAL: ("counter", ("src", "dst", "path")),

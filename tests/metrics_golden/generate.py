@@ -58,6 +58,7 @@ def reset_producers() -> None:
     from dynamo_tpu.engine.counters import (counters, kv_shard_counters,
                                             kv_stream_counters,
                                             lookahead_counters,
+                                            mesh_shape,
                                             persist_counters,
                                             request_counters)
     from dynamo_tpu.fault.counters import counters as fault_counters
@@ -69,6 +70,7 @@ def reset_producers() -> None:
               kv_shard_counters, lookahead_counters, request_counters,
               fault_counters, transfer_costs, perf_model):
         c.reset()
+    mesh_shape.update(tp=1, devices=1)
     step_timeline.reset()
     step_timeline._clock = time.perf_counter
 
@@ -79,6 +81,7 @@ def seed_http_metrics():
     from dynamo_tpu.engine.counters import (counters, kv_shard_counters,
                                             kv_stream_counters,
                                             lookahead_counters,
+                                            mesh_shape,
                                             persist_counters,
                                             request_counters)
     from dynamo_tpu.fault.counters import counters as fault_counters
@@ -120,6 +123,7 @@ def seed_http_metrics():
     request_counters.record_finish()
     request_counters.record_cut_short()
     request_counters.record_first_token(0.125)
+    mesh_shape.update(tp=4, devices=4)
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()
     persist_counters.record_spill(4096)

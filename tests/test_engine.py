@@ -256,6 +256,35 @@ def test_chunked_prefill_interleaves_decode(setup):
             f"no decode progress between prefill chunks at iters {a}..{b}"
 
 
+def test_prefill_is_served_in_order_of_admission(setup):
+    """A request admitted later into a LOWER slot does not cut in ahead of
+    a long prompt that is mid-prefill in a higher one: chunks go to the
+    request admitted first until its prompt is done."""
+    hf, model, params = setup
+    rng = np.random.RandomState(9)
+    first = []
+
+    def req(rid, n, max_tokens):
+        return EngineRequest(
+            rid, list(rng.randint(1, 128, size=n)),
+            SamplingOptions(temperature=0.0),
+            StopConditions(max_tokens=max_tokens),
+            lambda out, rid=rid: first.append(rid) if rid not in first else None)
+
+    core = make_core(model, params, prefill_chunk_tokens=16)
+    a, long = req("a", 5, 1), req("long", 64, 2)
+    core.submit(a)
+    core.submit(long)
+    core.step()                 # admits both; prefills a, which then ends
+    assert first == ["a"] and core.slots[0] is None and long.slot == 1
+    b = req("b", 32, 2)
+    core.submit(b)
+    while core.step():
+        pass
+    assert b.slot == 0 < long.slot          # the premise: b sits lower
+    assert first == ["a", "long", "b"]
+
+
 def test_logprobs_and_penalties_through_engine(setup):
     """Engine emits per-token logprobs + top_logprobs when requested, and
     frequency penalties actually change what gets sampled (previously dead

@@ -66,7 +66,11 @@ def _soak_model(family: str):
     # host-offload tier ON: the tight device pool evicts constantly, so
     # the async kv-offload thread's reserve/write/publish races against
     # the engine thread's drain/restore the whole run — bf16 and int8
-    (5, None, False, True, "llama"), (13, "int8", False, True, "llama"),
+    # (seeds at which the pool does evict: "offload tier never engaged"
+    # is this test's own premise, and about a third of seeds miss it
+    # whatever order prefill is served in; 13 did once prefill went by
+    # admission and not by slot, PR 27)
+    (5, None, False, True, "llama"), (21, "int8", False, True, "llama"),
     # MLA latent cache under the same churn, bf16 and int8+host-offload
     (17, None, False, False, "mla"), (19, "int8", False, True, "mla"),
 ])

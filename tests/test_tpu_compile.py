@@ -214,20 +214,38 @@ def _largest_produced(hlo: str) -> tuple[int, str]:
     return worst
 
 
+def _abstract_model(config_file: str, place, num_blocks=None, **overrides):
+    """(file, cfg, model, params, cache, sds) of a benchmark configuration
+    as shapes with shardings: ``place(spec)`` turns a PartitionSpec of
+    models/llama.py into the sharding of the described device(s); the cache
+    has the configuration's own ``num_blocks`` unless one is given."""
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.models.llama import LlamaModel
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench/configs", config_file)) as f:
+        hf = dict(json.load(f), **overrides)
+    cfg = ModelConfig.from_hf_config(hf, dtype=hf["dtype"])
+    model = LlamaModel(cfg)
+
+    def sds(shape, dtype=jnp.int32, spec=P()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=place(spec))
+
+    params = jax.tree.map(
+        lambda a, spec: sds(a.shape, a.dtype, spec),
+        jax.eval_shape(lambda: model.init_params(jax.random.key(0))),
+        model.partition_specs())
+    cache = sds((cfg.num_layers, num_blocks or hf["serve"]["num_blocks"], 2, BS,
+                 cfg.num_kv_heads * cfg.head_dim), jnp.bfloat16,
+                model.cache_spec())
+    return hf, cfg, model, params, cache, sds
+
+
 @pytest.mark.parametrize(
     "case", ["decode", "prefill", "engine-decode", "decode-tp2", "decode-tp4"])
 def test_qwen3_moe_reads_expert_weights_in_place(topo, tpu_gate, case):
     from dynamo_tpu.engine.core import multi_decode_step
-    from dynamo_tpu.models.config import ModelConfig
-    from dynamo_tpu.models.llama import LlamaModel, experts_in_place
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "cellbench/configs/qwen3-30b-a3b.json")) as f:
-        hf = dict(json.load(f), num_hidden_layers=2)
-    cfg = ModelConfig.from_hf_config(hf, dtype="bfloat16")
-    model = LlamaModel(cfg)
-    expert_bytes = (cfg.num_experts * cfg.hidden_size
-                    * cfg.intermediate_size * 2)
+    from dynamo_tpu.models.llama import experts_in_place
 
     tp = {"decode-tp2": 2, "decode-tp4": 4}.get(case, 1)
     if tp > 1:   # --tp 4 is the 2x2 host as one "model" axis
@@ -237,17 +255,10 @@ def test_qwen3_moe_reads_expert_weights_in_place(topo, tpu_gate, case):
     else:
         mesh = None
         place = lambda spec: SingleDeviceSharding(topo.devices[0])
-
-    def sds(shape, dtype=jnp.int32, spec=P()):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=place(spec))
-
-    params = jax.tree.map(
-        lambda a, spec: sds(a.shape, a.dtype, spec),
-        jax.eval_shape(lambda: model.init_params(jax.random.key(0))),
-        model.partition_specs())
-    cache = sds((cfg.num_layers, N_BLOCKS, 2, BS,
-                 cfg.num_kv_heads * cfg.head_dim), jnp.bfloat16,
-                model.cache_spec())
+    _, cfg, model, params, cache, sds = _abstract_model(
+        "qwen3-30b-a3b.json", place, N_BLOCKS, num_hidden_layers=2)
+    expert_bytes = (cfg.num_experts * cfg.hidden_size
+                    * cfg.intermediate_size * 2)
     b, s = (1, PREFILL_S) if case == "prefill" else (32, 1)
 
     if case == "engine-decode":   # the nested scan the served path runs
@@ -284,6 +295,92 @@ def test_qwen3_moe_reads_expert_weights_in_place(topo, tpu_gate, case):
     assert size < bound, (instr, size)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < bound, temp
+
+
+# ---------------------------------------------------------------------------
+# Mistral-7B-v0.3 whole under --tp 4, the benchmark's four-chip configuration
+# (PR 27): the two programs a step of `mistral-7b-tp4.chat-closed` runs, at
+# the configuration's own batch and cache, for the 2x2 host.
+_COLLECTIVE = re.compile(
+    r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(?:-start)?\(")
+V5E_HBM = 15.75 * 2**30
+
+
+def _collective_census(hlo: str) -> tuple[dict, dict]:
+    """Collectives by kind: (inside the layer scan's body, outside it).  The
+    scan is the one ``while`` of these programs; its body computation is the
+    one that holds the attention kernel's custom call."""
+    by_comp: dict[str, dict] = {}
+    kernel_in, comp = set(), ""
+    for line in hlo.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            comp = head.group(1)
+        if "tpu_custom_call" in line:
+            kernel_in.add(comp)
+        m = _COLLECTIVE.search(line)
+        if m:
+            kinds = by_comp.setdefault(comp, {})
+            kinds[m.group(1)] = kinds.get(m.group(1), 0) + 1
+    assert len(kernel_in) == 1, kernel_in
+    body = kernel_in.pop()
+    outside: dict[str, int] = {}
+    for c, kinds in by_comp.items():
+        if c != body:
+            for k, n in kinds.items():
+                outside[k] = outside.get(k, 0) + n
+    return by_comp.get(body, {}), outside
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_mistral_7b_whole_compiles_under_tp4(topo, tpu_gate, program):
+    from dynamo_tpu.engine.core import multi_decode_step, unified_step
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "mistral-7b-tp4.json", lambda spec: NamedSharding(mesh, spec))
+    serve = hf["serve"]
+    assert hf["num_hidden_layers"] == 32 and hf["reduced"] == [] and serve["tp"] == 4
+    bs, rows = serve["block_size"], serve["max_batch_size"]
+    assert bs == BS
+    m = serve["max_model_len"] // bs
+    f32, key = jnp.float32, sds((2,), jnp.uint32)
+    if program == "decode":
+        b = rows
+        args = (sds((b,)), sds((b,)), sds((b, m)), sds((b,)), sds((b,)), key,
+                sds((b,), f32), sds((b,)), sds((b,), f32))
+
+        def fn(params, cache, *a):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return multi_decode_step(model, params, cache, *a,
+                                         num_steps=1, block_size=bs)
+    else:       # one chunk of one prompt, sixteen blocks of it already cached
+        s = serve["prefill_chunk_tokens"]
+        args = (sds((1, s)), sds((1, s)), sds((1, m)), sds((1,)), sds((1, s)),
+                sds((1,)), key, sds((1,), f32), sds((1,)), sds((1,), f32))
+
+        def fn(params, cache, *a):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return unified_step(model, params, cache, *a, prefix_blocks=16)
+
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    mem = compiled.memory_analysis()
+    per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                  + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    # 3.56 GiB of weights and the 4 GiB cache shard, donated: and, unlike the
+    # one-chip decode program (PERF.md section 6, finding 1), no second copy
+    # of the cache among the temporaries
+    assert 7.5 * 2**30 < per_device < V5E_HBM, per_device
+    assert mem.temp_size_in_bytes < 2**30, mem.temp_size_in_bytes
+    # a layer all-reduces twice (after wo and after w_down) and does nothing
+    # else across chips; the vocabulary-sharded head gathers its candidates
+    # and their ids and all-reduces the log-sum-exp.  One more collective a
+    # layer is 32 more a step: a test failure here, not a slow cell there.
+    in_layer, outside = _collective_census(compiled.as_text())
+    assert in_layer == {"all-reduce": 2}, in_layer
+    assert outside == {"all-gather": 2, "all-reduce": 2}, outside
 
 
 def test_tp_rules_that_keep_the_xla_path(tpu_gate):
