@@ -111,7 +111,7 @@ def multi_decode_step(
     pen_tokens=None, pen_first=None, pen_cursor=None, freq_pen=None,
     pres_pen=None, grammar=None, jrows=None, jstate=None, jdepth=None,
     jstack=None, min_p=None, bias_tokens=None, bias_vals=None,
-    seeds=None, seed_rows=None,
+    seeds=None, seed_rows=None, carry_tokens=None, carry_rows=None,
     *, num_steps: int, block_size: int,
     k_cand: int = K_MAX, exact: bool = False, use_penalties: bool = False,
 ):
@@ -132,11 +132,21 @@ def multi_decode_step(
     sampled token is appended on device so mid-burst repeats are penalised
     without a host round-trip.
 
+    ``carry_tokens`` [K',B] is the ``sampled`` output of the decode
+    dispatch issued just before this one, still on the device, and
+    ``carry_rows`` [B] marks the rows that were in it: those start from
+    its last sample instead of ``last_tokens``, which the host has not
+    read yet (dispatch-ahead, ``EngineCore._settle``).  The engine always
+    passes both (no row marked when nothing is carried), so a shape has
+    one executable.
+
     Returns ((sampled [K,B], logprob [K,B], cand_ids [K,B,C],
     cand_lps [K,B,C]), cache).
     """
     m = block_tables.shape[1]
     use_grammar = grammar is not None
+    if carry_tokens is not None:
+        last_tokens = jnp.where(carry_rows, carry_tokens[-1], last_tokens)
 
     def one(carry, rng_k):
         gs = gd = gk = None
@@ -433,6 +443,24 @@ def unified_burst_step(
     carry, outs = jax.lax.scan(
         one, init, jax.random.split(rng_scan, num_steps - 1))
     return (out0, outs), carry[0]
+
+
+@dataclasses.dataclass
+class _Inflight:
+    """A dispatch that has been issued and not read back: its outputs
+    are still on the device, and ``finish`` is the host work that waits
+    for them (append, stop checks, block commits, emits)."""
+
+    kind: str                       # the timeline's dispatch kind
+    out: tuple                      # (sampled, logprob, cand_ids, cand_lps)
+    finish: Callable[[tuple], None]
+    rows: dict                      # slot -> request the program writes KV for
+    # may it stay un-read past the turn that issued it?  (the next
+    # dispatch then has nothing to ask of the host: EngineCore._settle)
+    deferrable: bool = True
+    # requests that ended while this dispatch was already issued: their
+    # slot and blocks go back once it has been read back
+    ended: list = dataclasses.field(default_factory=list)
 
 
 class EngineCore:
@@ -743,6 +771,15 @@ class EngineCore:
         self._pen_cache: Optional[dict] = None
         self._last_was_prefill = False
         self._admit_seq = 0              # order of admission: prefill is served in it
+        # dispatch-ahead (``_settle``): at most one dispatch un-read-back
+        self._inflight: Optional[_Inflight] = None
+        self.ahead_dispatches = 0        # issued with their predecessor un-read
+        self.ahead_discards = 0          # rows whose ahead-sample a late stop threw away
+        self.pipeline_drains = 0         # turns that had to read back first
+        # what a decode dispatch carries when there is nothing to carry:
+        # made once, so every decode call has the same operands
+        self._no_carry = self._carry_operand(
+            np.zeros((1, config.max_batch_size), np.int32))
 
     # ----------------------------------------------------------- step kernel
     def _step_impl(self, params, cache, *args, prefix_blocks=None,
@@ -893,12 +930,14 @@ class EngineCore:
                     exact=False, use_penalties=False, grammar=None,
                     jrows=None, jstate=None, jdepth=None, jstack=None,
                     min_p=None, bias_tokens=None, bias_vals=None,
-                    seeds=None, seed_rows=None):
+                    seeds=None, seed_rows=None, carry_tokens=None,
+                    carry_rows=None):
         return multi_decode_step(
             self.model, params, cache, *args,
             grammar=grammar, jrows=jrows, jstate=jstate, jdepth=jdepth,
             jstack=jstack, min_p=min_p, bias_tokens=bias_tokens,
             bias_vals=bias_vals, seeds=seeds, seed_rows=seed_rows,
+            carry_tokens=carry_tokens, carry_rows=carry_rows,
             num_steps=num_steps,
             block_size=self.config.block_size,
             k_cand=k_cand, exact=exact, use_penalties=use_penalties,
@@ -1179,7 +1218,11 @@ class EngineCore:
     def _run_step(self, tokens, positions, block_tables, seq_lens, slot_idx,
                   last_idx, temp, top_k, top_p, prefix_blocks=None,
                   k_cand=K_MAX, exact=False, gram=None, extras=None):
-        """Returns (sampled [B], logprob [B], cand_ids [B,C], cand_lps [B,C])."""
+        """Upload and issue one prefill dispatch (``unified_step``);
+        returns its outputs (sampled [B], logprob [B], cand_ids [B,C],
+        cand_lps [B,C]) **still on the device**.  Nothing is read back
+        here: the caller hands them to :meth:`_settle`, which finishes the
+        dispatch issued before this one first."""
         self._rng, rng = jax.random.split(self._rng)
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
@@ -1199,25 +1242,27 @@ class EngineCore:
             *up[:6], rng, *up[6:],
             prefix_blocks=prefix_blocks, k_cand=k_cand, exact=exact, **gkw,
         )
-        step_timeline.enter("readback")
         self.steps += 1
-        out = tuple(jax.device_get(out))
-        self.device_gets += 1
-        step_timeline.enter("host_post")
         return out
 
     def _run_multi_decode_step(self, tokens, positions, block_tables, seq_lens,
                                limits, temp, top_k, top_p, pen=None, gram=None,
                                extras=None, num_steps=1, k_cand=K_MAX,
-                               exact=False):
-        """Dispatch one multi-step decode; returns (sampled [K,B],
-        logprob [K,B], cand_ids [K,B,C], cand_lps [K,B,C])."""
+                               exact=False, *, carry_rows):
+        """Upload and issue one multi-step decode; returns (sampled [K,B],
+        logprob [K,B], cand_ids [K,B,C], cand_lps [K,B,C]) still on the
+        device.  Rows marked in ``carry_rows`` start from the last sample
+        of the decode in flight (``multi_decode_step``)."""
         self._rng, rng = jax.random.split(self._rng)
         use_pen = pen is not None
         host = [tokens, positions, block_tables, seq_lens, limits,
                 temp, top_k, top_p] + (list(pen) if use_pen else [])
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
+        gkw["carry_rows"] = carry_rows
+        gkw["carry_tokens"] = (
+            self._carry_operand(self._inflight.out[0]) if carry_rows.any()
+            else self._no_carry)
         step_timeline.enter("upload")
         up, gkw = self._upload_dispatch(host, gkw)
         step_timeline.enter("dispatch", kind="decode_multi")
@@ -1240,14 +1285,109 @@ class EngineCore:
             # decode burst (admission next turn starts from a warm list)
             step_timeline.enter("overlap")
             self._drain_waiting()
-        step_timeline.enter("readback")
+        return out
+
+    # ------------------------------------------------------- dispatch-ahead
+    def _carry_operand(self, arr):
+        """``arr`` placed as a decode's ``sampled`` output is handed to
+        the next decode: replicated over the mesh, so that the operand has
+        one layout whether it is a real carry or ``_no_carry``."""
+        if self.mesh is None:
+            return arr if isinstance(arr, jax.Array) else jax.device_put(arr)
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(arr, NamedSharding(self.mesh, PartitionSpec()))
+
+    def _may_stay_in_flight(self, rec: _Inflight) -> bool:
+        """THE rule of dispatch-ahead, asked once a dispatch is issued:
+        may the turn end with it un-read, so that the next one is built,
+        uploaded and issued while the device runs it?  Yes when the next
+        dispatch can need nothing of it that only the host could compute:
+        a one-step decode of plain sampling rows (its sample is carried on
+        the device, lengths are predictable), or a prefill that the
+        alternation follows with a decode (rows are running, chunking is
+        on): that decode holds none of the prefill's rows, and the first
+        token is read right behind it.  With no row running the prefill is
+        read back at once, as ever: nothing is there to issue behind it.
+        The engine-wide paths that build from the host's tokens every turn
+        (unified / lookahead dispatch, speculation) or deliver tokens in
+        bursts (``decode_steps`` > 1) never leave one: they are
+        alternatives to this overlap, and keep the serial step."""
+        cfg = self.config
+        if (not rec.deferrable or self._unified_enabled()
+                or cfg.spec_tokens > 0 or cfg.decode_steps != 1):
+            return False
+        return rec.kind == "decode_multi" or self._decode_follows()
+
+    def _decode_follows(self) -> bool:
+        """With a prefill just issued: is the next dispatch a decode?"""
+        return bool(self.config.prefill_chunk_tokens) and any(
+            r is not None and r.state is RequestState.RUNNING
+            for r in self.slots)
+
+    def _issue_behind(self, reqs, carry_ok: bool = False
+                      ) -> Optional[_Inflight]:
+        """Called before a decode over ``reqs`` is built.  If it can go
+        behind the dispatch in flight, return that one; else read that one
+        back first (a pipeline drain) and return None.  It can when no
+        request is in both (a prefill in flight holds no running row), or
+        when the one in flight is a decode (plain and one-step, or it would
+        not have stayed: its sample is carried over on the device) and the
+        new one is such a decode too (``carry_ok``) — else the rows' tokens,
+        grammar states or penalty buffers are the host's to compute from
+        the readback.  A prefill is never built with a prefill in flight
+        (``_schedule`` reads it back first)."""
+        fl = self._inflight
+        if fl is None:
+            return None
+        if any(fl.rows.get(r.slot) is r for r in reqs) and not (
+                carry_ok and fl.kind == "decode_multi"):
+            self._drain_pipeline()
+            return None
+        return fl
+
+    def _settle(self, rec: _Inflight) -> None:
+        """A dispatch has just been issued: now finish the one issued
+        before it (readback, appends, stop checks, commits, emits — host
+        work the device no longer waits for), then ``rec`` itself unless
+        it may stay in flight for the next turn."""
+        prev, self._inflight = self._inflight, rec
+        if prev is not None:
+            if rec.kind == "decode_multi":
+                # counted like decode_dispatches_total, at the dispatch:
+                # their ratio is how often a decode hid its round trip
+                self.ahead_dispatches += 1
+                request_counters.record_ahead()
+            self._finish_dispatch(prev)
+        if not self._may_stay_in_flight(rec):
+            self._inflight = None
+            self._finish_dispatch(rec)
+
+    def _finish_dispatch(self, rec: _Inflight) -> None:
+        """Read ``rec`` back and do its host work.  Requests that ended
+        while it was in flight gave up nothing yet: the program wrote one
+        position past their stop into blocks they still owned (never
+        committed); slot and blocks go back now that it has run."""
+        step_timeline.enter("readback", kind=rec.kind, issued=False)
         # ONE batched transfer: per-array np.asarray would issue a
         # device->host round trip per output (per-array latency is the
         # cost that matters on a remote-attached chip)
-        out = tuple(jax.device_get(out))
+        out = tuple(jax.device_get(rec.out))
         self.device_gets += 1
         step_timeline.enter("host_post")
-        return out
+        rec.finish(out)
+        for req in rec.ended:
+            self._release_slot(req)
+
+    def _drain_pipeline(self) -> None:
+        """Finish the dispatch in flight, if any, before going on: what
+        comes next needs the host's view of it (see ``_issue_behind``), or
+        there is nothing to issue behind it."""
+        rec, self._inflight = self._inflight, None
+        if rec is not None:
+            self.pipeline_drains += 1
+            request_counters.record_drain()
+            self._finish_dispatch(rec)
 
     # ------------------------------------------------------- cross-thread API
     def submit(self, request: EngineRequest) -> None:
@@ -1272,11 +1412,16 @@ class EngineCore:
             or bool(self._admitted)
             or not self._ops.empty()
             or any(s is not None for s in self.slots)
+            or self._inflight is not None
         )
 
     def fail_all(self) -> None:
         """Fail every in-flight and queued request (engine step blew up) so
-        callers get an error finish instead of a hung stream."""
+        callers get an error finish instead of a hung stream.  A dispatch
+        in flight is dropped unread: its samples may be the failed step's."""
+        fl, self._inflight = self._inflight, None
+        for req in (fl.ended if fl is not None else ()):
+            self._release_slot(req)   # ended already: no second finish
         for req in [r for r in self.slots if r is not None]:
             self._finish_slot(req, FinishReason.ERROR)
         for req in self._admitted:
@@ -1334,6 +1479,12 @@ class EngineCore:
             "requests_cut_short_total": self.requests_cut_short,
             "first_tokens_total": self.first_tokens,
             "first_token_seconds_total": self.first_token_s,
+            # dispatch-ahead: ahead / decode_dispatches_total = how
+            # often a decode hid its round trip; discards = late stops;
+            # drains = turns that read back before they could issue
+            "ahead_dispatches_total": self.ahead_dispatches,
+            "ahead_discards_total": self.ahead_discards,
+            "pipeline_drains_total": self.pipeline_drains,
             "mesh_tp": self.mesh_tp,
             "mesh_devices": self.mesh_devices,
         }
@@ -1348,6 +1499,23 @@ class EngineCore:
     # -------------------------------------------------------------- main loop
     def step(self) -> bool:
         """Run one scheduling iteration.  Returns False when idle.
+
+        **A step issues dispatch N+1 and then finishes dispatch N**
+        (dispatch-ahead, docs/engine_scheduling.md).  At most one dispatch
+        is un-read-back (``_inflight``), and never a decode ahead of a
+        ready prefill: the turn admits, picks its dispatch by the
+        alternation below, builds it from state the host can predict
+        (lengths, not tokens), uploads and issues it behind the one in
+        flight, and only then reads that one back and does its host work
+        (appends, stop checks, block commits, emits) — while the device
+        already runs N+1.  What may go ahead is decided per turn from the
+        rows at hand (``_issue_behind``, ``_may_stay_in_flight``);
+        everything else reads back first and runs as the serial step,
+        which is the same code with nothing in flight.  A stop the host
+        cannot foresee (EOS, a stop token) is found one dispatch late: the
+        row's ahead-sample is thrown away (``ahead_discards_total``).
+        Idle is idle: with nothing to issue the dispatch in flight is read
+        back and the next call returns False.
 
         The body is wrapped in the dtspan step timeline (obs/timeline.py):
         ``begin()`` (first thing in ``_step_inner``) opens the step and its
@@ -1382,6 +1550,14 @@ class EngineCore:
         # that rebuilds the host's call tree by time (cellbench's
         # breakdown.idle_gaps under the profiler's Python tracer).
         step_timeline.begin()  # opens kv_spill_restore
+        if self._inflight is not None and (
+                self._pending_offload or not self._ops.empty()
+                or not self._abort_q.empty()):
+            # evicted blocks to snapshot, an operation of another thread
+            # on the cache / block manager (KV scatter and gather,
+            # remote-prefill completion), an abort: they see a quiescent
+            # engine, and a cancelled row is not issued once more
+            self._drain_pipeline()
         self._drain_offload()  # evictions from the previous step's tail
         step_timeline.enter("host_ops")
         self._process_ops()
@@ -1389,6 +1565,25 @@ class EngineCore:
         step_timeline.enter("admission")
         self._admit()
         step_timeline.enter("host_build")
+        fl = self._inflight
+        worked = self._schedule()
+        if fl is not None and self._inflight is fl:
+            # nothing was issued behind it: the last tokens of an engine
+            # going quiet are emitted now, not at the next arrival
+            self._drain_pipeline()
+            return True
+        return worked
+
+    def _schedule(self) -> bool:
+        """Choose this turn's dispatch and run it; False with none."""
+        fl = self._inflight
+        if (fl is not None and fl.kind != "decode_multi"
+                and not (self._last_was_prefill and self._decode_follows())):
+            # a prefill stays in flight only for the decode the alternation
+            # issues behind it; the rows that were to decode are gone (cut
+            # short, cancelled), so it is read back before this turn looks
+            # at what is ready: its requests are about to change state
+            self._drain_pipeline()
         # slots not yet decoding (waiting on external KV, or mid-chunked-
         # prefill): honour aborts here — _append_token never runs for them,
         # so without this a cancelled long prompt would keep prefilling
@@ -1609,6 +1804,7 @@ class EngineCore:
                 # (With joined in-flight blocks, restore would scatter host
                 # content into blocks the owner is writing — skip; the
                 # owner's compute is arriving anyway.)
+                self._drain_pipeline()  # restore commits what it scatters
                 self._drain_offload()
                 self._restore_from_host(req)
             req.computed_tokens = req.cached_tokens
@@ -1734,7 +1930,7 @@ class EngineCore:
             gram = (keys, np.asarray([True]),
                     np.asarray([gs + off if gs > 0 else gs], np.int32),
                     np.asarray([gd], np.int32), np.asarray([gk], np.int32))
-        sampled, lps, cids, clps = self._run_step(
+        out = self._run_step(
             tokens, positions, bt, seq_lens, slot_idx, last_idx,
             np.asarray([req.sampling.temperature], np.float32),
             np.asarray([req.sampling.top_k], np.int32),
@@ -1746,12 +1942,17 @@ class EngineCore:
         self.prefill_dispatches += 1
         self.prefill_rows_dispatched += 1
         prefill_counters.record(rows=1, tokens=take)
-        self.prompt_tokens_computed += take
-        req.computed_tokens = end
-        self._commit_prefill_blocks(req)
-        if not final:
-            return  # more chunks to go; sample discarded (no logits needed)
-        self._complete_prefill(req, sampled, lps, cids, clps)
+
+        def finish(out):
+            if req.state is not RequestState.PREFILL:
+                return  # cancelled while the chunk was in flight
+            self.prompt_tokens_computed += take
+            req.computed_tokens = end
+            self._commit_prefill_blocks(req)
+            if final:  # else more chunks to go; the sample is discarded
+                self._complete_prefill(req, *out)
+
+        self._settle(_Inflight("step", out, finish, {req.slot: req}))
 
     def _commit_prefill_blocks(self, req: EngineRequest) -> None:
         """Offer newly completed prompt blocks to the block manager.  The
@@ -1895,27 +2096,28 @@ class EngineCore:
         if self._lookahead_enabled():
             step_timeline.enter("overlap")
             self._drain_waiting()  # overlap: absorb arrivals under compute
-        step_timeline.enter("readback")
-        sampled, lps, cids, clps = jax.device_get(out)  # one batched pull
-        self.device_gets += 1
-        step_timeline.enter("host_post")
         self.steps += 1
         self.prefill_steps += 1
         take_sum = sum(take for _, take, _ in sel)
-        self.prompt_tokens_computed += take_sum
         self.prefill_dispatches += 1
         self.prefill_rows_dispatched += r_real
         self.prefill_budget_offered += budget
         self.prefill_budget_used += take_sum
         prefill_counters.record(rows=r_real, tokens=take_sum, budget=budget)
-        for r, (req, take, final) in enumerate(sel):
-            req.computed_tokens += take
-            self._commit_prefill_blocks(req)
-            if final:
-                self._complete_prefill(
-                    req, sampled[r:r + 1], lps[r:r + 1],
-                    cids[r:r + 1], clps[r:r + 1],
-                )
+
+        def finish(out):
+            for r, (req, take, final) in enumerate(sel):
+                if req.state is not RequestState.PREFILL:
+                    continue  # cancelled while the chunk was in flight
+                self.prompt_tokens_computed += take
+                req.computed_tokens += take
+                self._commit_prefill_blocks(req)
+                if final:
+                    self._complete_prefill(req, *(a[r:r + 1] for a in out))
+
+        self._settle(_Inflight(
+            "prefill_ragged", out, finish,
+            {req.slot: req for req, _, _ in sel}))
 
     def _complete_prefill(self, req, sampled, lps, cids, clps) -> None:
         """Shared tail of chunked and sequence-parallel prefill: state
@@ -2467,6 +2669,7 @@ class EngineCore:
         long-context path where even a single prompt's activations/KV
         exceed one chip's comfort.  KV comes back already block-shaped and
         pool-sharded; a donated scatter drops it into the paged cache."""
+        self._drain_pipeline()  # reads back in its own turn, as ever
         cfg = self.config
         bs = cfg.block_size
         unit = bs * self._sp_size
@@ -2549,15 +2752,18 @@ class EngineCore:
             for r in reqs
         )
 
-    def _grow_blocks(self, req: EngineRequest, extra_tokens: int
-                     ) -> Optional[int]:
+    def _grow_blocks(self, req: EngineRequest, extra_tokens: int,
+                     ahead: int = 0) -> Optional[int]:
         """Extend ``req``'s block table to cover ``extra_tokens`` more
         positions beyond its uncomputed tail; returns the row's token
         limit, or None when not even the current token has a slot (the
         request was finished at LENGTH).  Shared by the burst and
-        speculative dispatch builders."""
+        speculative dispatch builders.  ``ahead`` = 1 for a row whose
+        token of the decode in flight the host has not appended yet: its
+        tail is one further, and with no slot for it the row is only left
+        out (the turn that knows the token decides)."""
         cfg = self.config
-        p = req.seq.total_tokens - 1
+        p = req.seq.total_tokens - 1 + ahead
         want_tokens = min(p + extra_tokens, cfg.max_model_len)
         needed = (want_tokens - 1) // cfg.block_size + 1
         if len(req.block_ids) < needed:
@@ -2567,7 +2773,8 @@ class EngineCore:
                 )
             except NoFreeBlocks:
                 if len(req.block_ids) * cfg.block_size <= p:
-                    self._cut_short(req)
+                    if not ahead:
+                        self._cut_short(req)
                     return None
         return min(len(req.block_ids) * cfg.block_size, cfg.max_model_len)
 
@@ -2726,7 +2933,16 @@ class EngineCore:
         mid-prefill slot, or requests waiting for admission) the burst
         shrinks to ``interactive_decode_steps`` so a fresh prompt waits
         ~8 ITLs, not a whole 64-step burst, before its first prefill chunk
-        — the dominant term in chunked-prefill TTFT (VERDICT r2 weak #3)."""
+        — the dominant term in chunked-prefill TTFT (VERDICT r2 weak #3).
+
+        This builds and issues the dispatch; its readback and host work
+        are ``finish`` below, run by :meth:`_settle` — in this turn, or in
+        the next one behind that turn's dispatch.  Issued behind a decode
+        still in flight, a row of that decode takes its token on the
+        device (``carry_rows``) and is built for length + 1; a row that
+        ends in the one in flight by ``max_tokens`` or ``max_model_len``
+        is left out.  ``lookahead_dispatch`` and ``decode_steps`` > 1 are
+        other mechanisms (see :meth:`step`)."""
         cfg = self.config
         if cfg.spec_tokens > 0 and self._try_spec_decode():
             return
@@ -2752,6 +2968,19 @@ class EngineCore:
             1,
             cfg.interactive_decode_steps if prefill_pending else cfg.decode_steps,
         )
+        running = [r for r in self.slots
+                   if r is not None and r.state is RequestState.RUNNING]
+        # plain rows, one step: what the device can carry from the decode
+        # in flight (a grammar state or a penalty buffer is built by the
+        # host from the token, so such a batch reads back first)
+        plain = k_steps == 1 and not any(
+            self._grammar_key(r) or r.sampling.frequency_penalty
+            or r.sampling.presence_penalty for r in running)
+        fl = self._issue_behind(running, carry_ok=plain)
+        if fl is None:
+            # read back first: the rows that ended there are gone
+            running = [r for r in running
+                       if r.state is RequestState.RUNNING]
         tokens = np.zeros(b, np.int32)
         positions = np.zeros(b, np.int32)
         bt = np.zeros((b, m), np.int32)
@@ -2760,21 +2989,36 @@ class EngineCore:
         temp = np.ones(b, np.float32)
         top_k = np.zeros(b, np.int32)
         top_p = np.ones(b, np.float32)
+        carry_rows = np.zeros(b, bool)
 
         active: list[EngineRequest] = []
-        for i, req in enumerate(self.slots):
-            if req is None or req.state is not RequestState.RUNNING:
-                continue
-            p = req.seq.total_tokens - 1  # position of the not-yet-computed last token
+        for req in running:
+            i = req.slot
+            # a row of the decode in flight: its token is on the device,
+            # its length is one more than the host has appended
+            ahead = int(fl is not None and fl.rows.get(i) is req)
+            total = req.seq.total_tokens + ahead
+            if ahead:
+                st = req.stops
+                if (
+                    req.abort_requested
+                    or (st.max_tokens is not None
+                        and req.generated + 1 >= st.max_tokens)
+                    or total >= cfg.max_model_len
+                ):
+                    continue  # ends in the dispatch in flight: left out
+            p = total - 1  # position of the not-yet-computed last token
             # cover the whole burst: positions p .. p+k-1, clamped to model len
-            limit = self._grow_blocks(req, k_steps)
+            limit = self._grow_blocks(req, k_steps, ahead)
             if limit is None:
                 continue  # not even the current token has a slot
             active.append(req)
-            tokens[i] = req.seq.tokens[-1]
+            carry_rows[i] = bool(ahead)
+            if not ahead:
+                tokens[i] = req.seq.tokens[-1]
             positions[i] = p
             bt[i, : len(req.block_ids)] = req.block_ids
-            seq_lens[i] = req.seq.total_tokens
+            seq_lens[i] = total
             limits[i] = limit
             temp[i] = req.sampling.temperature
             top_k[i] = req.sampling.top_k
@@ -2807,32 +3051,48 @@ class EngineCore:
                     jstate[r.slot] = gs + offs[k] if gs > 0 else gs
                     jdepth[r.slot], jstack[r.slot] = gd, gk
             gram = (keys, jrows, jstate, jdepth, jstack)
-        sampled, lps, cids, clps = self._run_multi_decode_step(
+        out = self._run_multi_decode_step(
             tokens, positions, bt, seq_lens, limits, temp, top_k, top_p,
             pen=pen, gram=gram,
             extras=self._sampling_extras(active, rows=[r.slot for r in active]),
             num_steps=k_steps, k_cand=k_cand, exact=exact,
+            carry_rows=carry_rows,
         )  # [K, B], [K, B], [K, B, C], [K, B, C]
-        self.decode_steps += sampled.shape[0]
         self.decode_dispatches += 1
         self.decode_rows_dispatched += len(active)
         request_counters.record_decode(len(active))
-        for req in active:
-            slot = req.slot
-            want_lp = req.sampling.logprobs or req.sampling.top_logprobs > 0
-            # samples at/past the limit wrote no KV — not appendable
-            allowed = min(sampled.shape[0], int(limits[slot] - positions[slot]))
-            for k in range(allowed):
+
+        def finish(out):
+            sampled, lps, cids, clps = out
+            self.decode_steps += sampled.shape[0]
+            for req in active:
                 if req.state is not RequestState.RUNNING:
-                    break  # EOS/stop/max_tokens hit mid-burst
-                self._append_token(
-                    req, int(sampled[k, slot]),
-                    logprob=float(lps[k, slot]) if want_lp else None,
-                    cand=(cids[k, slot], clps[k, slot]) if want_lp else None,
-                )
-            if req.state is RequestState.RUNNING and allowed < sampled.shape[0]:
-                # block space exhausted before the burst ended
-                self._cut_short(req)
+                    # stopped on a token, or aborted, while this dispatch
+                    # was already issued behind the one that told: its
+                    # sample is past the stop and is thrown away
+                    self.ahead_discards += 1
+                    request_counters.record_ahead_discard()
+                    continue
+                slot = req.slot
+                want_lp = req.sampling.logprobs or req.sampling.top_logprobs > 0
+                # samples at/past the limit wrote no KV — not appendable
+                allowed = min(sampled.shape[0],
+                              int(limits[slot] - positions[slot]))
+                for k in range(allowed):
+                    if req.state is not RequestState.RUNNING:
+                        break  # EOS/stop/max_tokens hit mid-burst
+                    self._append_token(
+                        req, int(sampled[k, slot]),
+                        logprob=float(lps[k, slot]) if want_lp else None,
+                        cand=(cids[k, slot], clps[k, slot]) if want_lp else None,
+                    )
+                if req.state is RequestState.RUNNING and allowed < sampled.shape[0]:
+                    # block space exhausted before the burst ended
+                    self._cut_short(req)
+
+        self._settle(_Inflight(
+            "decode_multi", out, finish, {r.slot: r for r in active},
+            deferrable=plain))
 
     def _penalty_buffers(self, active, k_steps: int):
         """Build the generated-token penalty buffers for this dispatch, or
@@ -2943,7 +3203,8 @@ class EngineCore:
         request_counters.record_cut_short()
         self._finish_slot(req, FinishReason.LENGTH)
 
-    def _finish_slot(self, req: EngineRequest, reason: FinishReason, emitted: bool = False) -> None:
+    def _release_slot(self, req: EngineRequest) -> None:
+        """Give back a finished request's slot, reservations and blocks."""
         if req.slot >= 0 and self.slots[req.slot] is req:
             self.slots[req.slot] = None
             if self.draft is not None:
@@ -2956,6 +3217,16 @@ class EngineCore:
         req.reserved_pairs = []
         self.block_manager.release(req.block_ids)
         req.block_ids = []
+
+    def _finish_slot(self, req: EngineRequest, reason: FinishReason, emitted: bool = False) -> None:
+        fl = self._inflight
+        if fl is not None and fl.rows.get(req.slot) is req:
+            # a dispatch issued ahead still writes this request's blocks:
+            # the client hears of the end now, slot and blocks go back
+            # once that dispatch has been read back (_finish_dispatch)
+            fl.ended.append(req)
+        else:
+            self._release_slot(req)
         self._by_id.pop(req.request_id, None)
         req.state = RequestState.FINISHED
         req.finish_reason = reason
@@ -3199,7 +3470,14 @@ class EngineCore:
     def close(self) -> None:
         """Stop the kv-offload thread (idempotent).  Without this an
         abandoned engine's daemon thread would pin the whole instance —
-        params, cache, host pool — for process lifetime."""
+        params, cache, host pool — for process lifetime.  A dispatch
+        still in flight is finished first: nothing stays un-emitted."""
+        if getattr(self, "_inflight", None) is not None:
+            try:
+                self._drain_pipeline()
+            except Exception:
+                log.exception("finishing the dispatch in flight failed")
+                self.fail_all()
         t = getattr(self, "_offload_thread", None)
         if t is not None and t.is_alive():
             # flag first (under the lock _drain_offload enqueues under):

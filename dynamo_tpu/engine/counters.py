@@ -353,6 +353,22 @@ class RequestCounters:
         dynamo_tpu_engine_first_token_seconds_total    counter (sum of first
                                                        emit - submit: TTFT
                                                        as the engine sees it)
+        dynamo_tpu_engine_ahead_dispatches_total       counter (decode
+                                                       dispatches issued
+                                                       with a dispatch in
+                                                       flight: over decode
+                                                       dispatches, how often
+                                                       the host's round trip
+                                                       is hidden)
+        dynamo_tpu_engine_ahead_discards_total         counter (rows whose
+                                                       ahead-sample a stop
+                                                       found one dispatch
+                                                       late threw away: the
+                                                       mechanism's waste)
+        dynamo_tpu_engine_pipeline_drains_total        counter (turns that
+                                                       read back before they
+                                                       could issue: why the
+                                                       engagement is not 1)
     """
 
     def __init__(self) -> None:
@@ -372,6 +388,15 @@ class RequestCounters:
         self.first_tokens_total += 1
         self.first_token_seconds_total += seconds
 
+    def record_ahead(self) -> None:
+        self.ahead_dispatches_total += 1
+
+    def record_ahead_discard(self) -> None:
+        self.ahead_discards_total += 1
+
+    def record_drain(self) -> None:
+        self.pipeline_drains_total += 1
+
     def reset(self) -> None:
         """Test isolation hook — the counters are process-global."""
         self.decode_dispatches_total = 0
@@ -380,6 +405,9 @@ class RequestCounters:
         self.requests_cut_short_total = 0
         self.first_tokens_total = 0
         self.first_token_seconds_total = 0.0
+        self.ahead_dispatches_total = 0
+        self.ahead_discards_total = 0
+        self.pipeline_drains_total = 0
 
 
 request_counters = RequestCounters()

@@ -301,6 +301,15 @@ class Metrics:
         lines.append(f"# TYPE {EM.FIRST_TOKEN_SECONDS_TOTAL} counter")
         lines.append(f"{EM.FIRST_TOKEN_SECONDS_TOTAL} "
                      f"{round(rc.first_token_seconds_total, 6)}")
+        # dispatch-ahead: how often the host's round trip ran under the
+        # device program, what late stops wasted, what broke the chain
+        lines.append(f"# TYPE {EM.AHEAD_DISPATCHES_TOTAL} counter")
+        lines.append(f"{EM.AHEAD_DISPATCHES_TOTAL} "
+                     f"{rc.ahead_dispatches_total}")
+        lines.append(f"# TYPE {EM.AHEAD_DISCARDS_TOTAL} counter")
+        lines.append(f"{EM.AHEAD_DISCARDS_TOTAL} {rc.ahead_discards_total}")
+        lines.append(f"# TYPE {EM.PIPELINE_DRAINS_TOTAL} counter")
+        lines.append(f"{EM.PIPELINE_DRAINS_TOTAL} {rc.pipeline_drains_total}")
         # the mesh this engine runs on (1 and 1 with no mesh)
         lines.append(f"# TYPE {EM.MESH_TP} gauge")
         lines.append(f"{EM.MESH_TP} {mesh_shape['tp']}")

@@ -114,6 +114,10 @@ class EngineMetric:
     FIRST_TOKENS_TOTAL = "dynamo_tpu_engine_first_tokens_total"
     FIRST_TOKEN_SECONDS_TOTAL = (
         "dynamo_tpu_engine_first_token_seconds_total")
+    # dispatch-ahead (EngineCore._settle)
+    AHEAD_DISPATCHES_TOTAL = "dynamo_tpu_engine_ahead_dispatches_total"
+    AHEAD_DISCARDS_TOTAL = "dynamo_tpu_engine_ahead_discards_total"
+    PIPELINE_DRAINS_TOTAL = "dynamo_tpu_engine_pipeline_drains_total"
     # engine/counters.py mesh_shape
     MESH_TP = "dynamo_tpu_engine_mesh_tp"
     MESH_DEVICES = "dynamo_tpu_engine_mesh_devices"
@@ -226,6 +230,9 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.REQUESTS_CUT_SHORT_TOTAL: ("counter", ()),
     EngineMetric.FIRST_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.FIRST_TOKEN_SECONDS_TOTAL: ("counter", ()),
+    EngineMetric.AHEAD_DISPATCHES_TOTAL: ("counter", ()),
+    EngineMetric.AHEAD_DISCARDS_TOTAL: ("counter", ()),
+    EngineMetric.PIPELINE_DRAINS_TOTAL: ("counter", ()),
     EngineMetric.MESH_TP: ("gauge", ()),
     EngineMetric.MESH_DEVICES: ("gauge", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),

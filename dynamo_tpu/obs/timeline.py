@@ -7,6 +7,18 @@ and :meth:`end` closes the last — every instant of a step lies in exactly
 one phase, so the phase sum equals the step wall time **by construction**
 (the only loss is float rounding).
 
+**What a step is.**  The engine keeps at most one dispatch un-read-back
+(``EngineCore._settle``): a step builds, uploads and issues dispatch N+1
+and *then* reads back and finishes dispatch N.  So the ``readback`` and
+``host_post`` of a step are, as a rule, those of the dispatch the step
+before issued, and they run while the device executes the one this step
+issued.  A step that cannot issue ahead reads back first and then issues
+(the old order, all of one dispatch); one with nothing to issue only
+finishes.  Phase names, their order within one dispatch's life and the
+phase-sum invariant are unchanged.  This is not ``lookahead_dispatch``,
+which fuses k unified turns into one program and fills the ``overlap``
+phase.
+
 Phases (in step order; the profiler span of each is ``dyn.<phase>``):
 
     kv_spill_restore  host<->device KV block traffic (_drain_offload)
@@ -24,7 +36,8 @@ Phases (in step order; the profiler span of each is ``dyn.<phase>``):
                       is excluded from the host gap
     readback          jax.device_get — blocks until device compute
                       lands, so device time not overlapped with host
-                      work shows up here
+                      work shows up here (of the dispatch issued the step
+                      before, when one was in flight)
     host_post         sampled-token append, stop conditions, emit
 
 **One call, two sinks.**  The same clock reads feed the ``perf_counter``
@@ -39,10 +52,11 @@ and ``t_mono_ns`` is ``time.monotonic_ns()`` at the open: trace time −
 phase costs a flag test (``TraceAnnotation.is_enabled()``) and no object.
 
 The headline derived number is **host_gap_ms_per_turn** — wall time
-per dispatching step spent *outside* dispatch+overlap+readback, i.e.
-the host bubble ROADMAP item 3 (double-buffered dispatch) must close.
-Overlapped host work is not a bubble: the device is busy underneath
-it, so the phase-sum==wall invariant holds while the gap shrinks.  The
+per dispatching step spent *outside* dispatch+overlap+readback: the
+host's own work per step.  Its definition has not changed, its meaning
+has: while a dispatch is in flight that time runs under the device
+program (hidden), and it is device time lost only in the steps that read
+back first.  The
 aggregates are always on: per busy step about twenty clock reads, two
 small dicts and a handful of float adds; per-step *spans* of the dtspan
 plane are emitted only when that plane is enabled.
@@ -53,13 +67,17 @@ plane are emitted only when that plane is enabled.
 **device-facing** time: the dispatch phase plus the overlap and readback
 phases that follow it, i.e. enqueue → readback returned — not the enqueue
 alone, which on an asynchronous backend is a few hundred microseconds
-whatever the program costs.  It is the denominator of the dtperf
+whatever the program costs.  ``enter("readback", kind=..., issued=False)``
+books the readback of a dispatch an earlier step issued to that
+dispatch's kind and counts no dispatch.  It is the denominator of the dtperf
 predicted-vs-measured gauge (``obs/perfmodel.py``).  At ``end`` a busy
 step's wall, and its device-facing part (wall − host gap), are added to a
 **class** — ``prefill`` (``step``, ``prefill_ragged``, ``sp_prefill``),
 ``decode`` (``decode_multi``, ``spec_verify``) or ``mixed`` (``unified``,
-``unified_burst``, several classes in one step, or no kind at all) — so
-the class walls add up to ``wall_seconds_total``.  When the dtspan plane
+``unified_burst``, several classes in one step, or no kind at all) — the
+class of the dispatch the step **issued**, or, when it issued none, of
+what it finished; so the class walls add up to ``wall_seconds_total`` and
+a class's steps count its dispatches.  When the dtspan plane
 is enabled, ``end`` also emits one ``engine.step`` span per busy step
 carrying the phase breakdown and the roofline-predicted dispatch
 envelope, which the Chrome export renders as a predicted-vs-measured
@@ -153,6 +171,7 @@ class StepTimeline:
         self._span = None
         self._phases: dict = {}
         self._step_kinds: dict = {}
+        self._issued: set = set()
 
     # ------------------------------------------------------------ hot path
     def begin(self, phase: str = PHASES[0]) -> None:
@@ -164,20 +183,27 @@ class StepTimeline:
         self._last = now
         self._phases = {}
         self._step_kinds = {}
+        self._issued = set()
         self._kind = None
         self._t0_ns = time.monotonic_ns()
         self._open(phase)
 
-    def enter(self, phase: str, kind: Optional[str] = None) -> None:
+    def enter(self, phase: str, kind: Optional[str] = None,
+              issued: bool = True) -> None:
         """Close the open phase and open ``phase``.  ``kind`` (on
         ``dispatch``) names the jitted entrypoint; the overlap and
-        readback that follow are booked to it too."""
+        readback that follow are booked to it too.  ``issued=False`` (on
+        the ``readback`` of a dispatch that may be an earlier step's)
+        books to ``kind`` and counts no dispatch."""
         if self._t0 is None:
             return  # dispatch helper invoked outside step() (tests)
         self._close(self._clock())
         if kind is not None:
             self._kind = kind
-            self.dispatch_kind_n[kind] = self.dispatch_kind_n.get(kind, 0) + 1
+            if issued:
+                self._issued.add(kind)
+                self.dispatch_kind_n[kind] = \
+                    self.dispatch_kind_n.get(kind, 0) + 1
         self._open(phase)
 
     def _open(self, phase: str) -> None:
@@ -224,7 +250,8 @@ class StepTimeline:
         self.host_gap_s_total += gap
         for p, v in phases.items():
             self.phase_s_total[p] = self.phase_s_total.get(p, 0.0) + v
-        classes = {KIND_CLASS.get(k, "mixed") for k in self._step_kinds}
+        classes = {KIND_CLASS.get(k, "mixed")
+                   for k in (self._issued or self._step_kinds)}
         cls = classes.pop() if len(classes) == 1 else "mixed"
         self.class_steps[cls] += 1
         self.class_wall_s[cls] += wall

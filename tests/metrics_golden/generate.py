@@ -123,6 +123,11 @@ def seed_http_metrics():
     request_counters.record_finish()
     request_counters.record_cut_short()
     request_counters.record_first_token(0.125)
+    for _ in range(5):
+        request_counters.record_ahead()
+    request_counters.record_ahead_discard()
+    request_counters.record_drain()
+    request_counters.record_drain()
     mesh_shape.update(tp=4, devices=4)
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()

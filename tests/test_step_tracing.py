@@ -153,6 +153,41 @@ def test_class_totals_add_up_to_wall():
                          "device_seconds_total"))
 
 
+def test_a_step_that_issues_ahead_is_booked_to_what_it_issues():
+    """PR 28: a step issues dispatch N+1 and then reads N back.  The
+    readback goes to N's kind and counts no dispatch; the step's class is
+    the one it issued, or, with none issued, the one it finished."""
+    clock = Clock()
+    tl = StepTimeline(clock=clock)
+
+    def step(script):
+        tl.begin("host_build")
+        for phase, kw in script:
+            clock.t += 0.001
+            tl.enter(phase, **kw)
+        clock.t += 0.001
+        tl.end()
+
+    ahead = [("upload", {}), ("dispatch", dict(kind="decode_multi")),
+             ("readback", dict(kind="step", issued=False)), ("host_post", {})]
+    step(ahead)                         # a decode behind a prefill chunk
+    step([("upload", {}), ("dispatch", dict(kind="step"))])   # stays un-read
+    step([("readback", dict(kind="step", issued=False)),
+          ("host_post", {})])           # nothing to issue: only finishes
+    snap = tl.snapshot()
+    assert {k: v["count"] for k, v in snap["dispatch_kinds"].items()} \
+        == {"decode_multi": 1, "step": 1}
+    assert snap["dispatch_kinds"]["step"]["seconds"] == pytest.approx(0.003)
+    assert snap["dispatch_kinds"]["decode_multi"]["seconds"] \
+        == pytest.approx(0.001)
+    assert [snap[f"{c}_steps_total"] for c in CLASSES] == [2, 1, 0]
+    assert snap["busy_steps_total"] == 3
+    assert sum(snap["phases"].values()) == pytest.approx(
+        snap["wall_seconds_total"], rel=1e-12)
+    assert sum(snap[f"{c}_wall_seconds_total"] for c in CLASSES) \
+        == pytest.approx(snap["wall_seconds_total"], rel=1e-12)
+
+
 def test_without_a_profiler_session_a_phase_makes_no_span():
     clock = Clock()
     tl = StepTimeline(clock=clock)
@@ -236,10 +271,14 @@ def test_profiled_engine_run_yields_dyn_events_on_one_host_line(tiny, tmp_path):
     events.sort(key=lambda e: e.start_ns)
     for a, b in zip(events, events[1:]):
         assert a.start_ns + a.duration_ns <= b.start_ns + 1000
-    # the busy-step index on the spans is the timeline's own
+    # the busy-step index on the spans is the timeline's own; every
+    # busy step issues a dispatch or reads one back, and every dispatch is
+    # read back once (in its own step, or in the step that issued the next)
     steps = {int(dict(e.stats)["step"]) for e in events
-             if e.name == "dyn.readback"}
+             if e.name in ("dyn.dispatch", "dyn.readback")}
     assert steps == set(range(step_timeline.busy_steps_total))
+    assert sum(e.name == "dyn.dispatch" for e in events) \
+        == sum(e.name == "dyn.readback" for e in events)
     snap = step_timeline.snapshot()
     assert snap["prefill_steps_total"] == 1 and snap["decode_steps_total"] >= 3
     assert snap["mixed_steps_total"] == 0
@@ -282,6 +321,41 @@ def test_counters_cut_short_is_not_max_tokens(tiny):
     assert c[-1].finish_reason == FinishReason.LENGTH
     assert core.metrics()["requests_cut_short_total"] == 0
     assert core.metrics()["requests_finished_total"] == 1
+
+
+def test_dispatch_ahead_keeps_the_accounting(tiny):
+    """PR 29: with a dispatch in flight the per-phase wall time still sums
+    to the step wall time, a class's steps still count its dispatches, and
+    the rows and the prefill dispatches are those of the serial engine (a
+    row joins the decode behind its final chunk's, so a decode dispatch
+    may be added: no row is dispatched twice for one token)."""
+    got = {}
+    for sync in (True, False):
+        core = make_core(*tiny, prefill_chunk_tokens=16)
+        if sync:
+            core._may_stay_in_flight = lambda rec: False
+        step_timeline.reset()
+        outs = [submit(core, f"r{i}", 9 + 12 * i, 5 + 4 * i, seed=i)
+                for i in range(3)]
+        run_dry(core)
+        m, snap = core.metrics(), step_timeline.snapshot()
+        assert [sum(len(o.token_ids) for o in out) for out in outs] == [5, 9, 13]
+        assert sum(snap["phases"].values()) == pytest.approx(
+            snap["wall_seconds_total"], rel=1e-9)
+        assert sum(snap[f"{c}_wall_seconds_total"] for c in CLASSES) \
+            == pytest.approx(snap["wall_seconds_total"], rel=1e-9)
+        assert snap["mixed_steps_total"] == 0
+        assert snap["dispatch_kinds"]["decode_multi"]["count"] \
+            == m["decode_dispatches_total"]
+        assert snap["dispatch_kinds"]["step"]["count"] \
+            == m["prefill_dispatches_total"]
+        # a step that only finishes adds one to its class, never takes one
+        assert snap["decode_steps_total"] >= m["decode_dispatches_total"]
+        assert snap["prefill_steps_total"] == m["prefill_dispatches_total"]
+        assert (m["ahead_dispatches_total"] > 0) is (not sync)
+        got[sync] = (m["decode_rows_dispatched_total"],
+                     m["prefill_dispatches_total"])
+    assert got[False] == got[True]
 
 
 MODULE_NAMES = {"step": ("_step_fn", "jit__step_impl"),

@@ -348,13 +348,17 @@ def test_mistral_7b_whole_compiles_under_tp4(topo, tpu_gate, program):
     f32, key = jnp.float32, sds((2,), jnp.uint32)
     if program == "decode":
         b = rows
+        # as the engine issues it: the last decode's samples and the mask
+        # of the rows that take their token from them (dispatch-ahead)
         args = (sds((b,)), sds((b,)), sds((b, m)), sds((b,)), sds((b,)), key,
-                sds((b,), f32), sds((b,)), sds((b,), f32))
+                sds((b,), f32), sds((b,)), sds((b,), f32),
+                sds((1, b)), sds((b,), jnp.bool_))
 
         def fn(params, cache, *a):
             with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-                return multi_decode_step(model, params, cache, *a,
-                                         num_steps=1, block_size=bs)
+                return multi_decode_step(
+                    model, params, cache, *a[:-2], carry_tokens=a[-2],
+                    carry_rows=a[-1], num_steps=1, block_size=bs)
     else:       # one chunk of one prompt, sixteen blocks of it already cached
         s = serve["prefill_chunk_tokens"]
         args = (sds((1, s)), sds((1, s)), sds((1, m)), sds((1,)), sds((1, s)),
