@@ -24,9 +24,6 @@ DYNAMO_BENCH_STEPS, DYNAMO_BENCH_ISL, DYNAMO_BENCH_MAX_LEN,
 DYNAMO_BENCH_BLOCK_SIZE, DYNAMO_BENCH_DECODE_STEPS,
 DYNAMO_BENCH_PREFILL_CHUNK, DYNAMO_BENCH_PREFILL_BUDGET,
 DYNAMO_BENCH_UNIFIED (1 = unified mixed prefill+decode dispatch),
-DYNAMO_BENCH_LOOKAHEAD (1 = double-buffered lookahead dispatch on the
-primary engine + an on/off ITL A/B phase;
-DYNAMO_BENCH_LOOKAHEAD_MODEL / _ISL size the A/B),
 DYNAMO_BENCH_PERSIST (1 = persistent prefix-cache tier cold-vs-warm
 restart TTFT phase; DYNAMO_BENCH_PERSIST_MODEL / _ISL size it),
 DYNAMO_BENCH_STREAM (1 = streamed-vs-blocking disagg handoff TTFT
@@ -1003,115 +1000,6 @@ def _stream_phase(on_accel: bool, block_size: int):
     }
 
 
-def _lookahead_phase(on_accel: bool, block_size: int):
-    """Double-buffered dispatch on/off ITL A/B (engine/core.py
-    ``_run_unified`` lookahead path): same model, same seeded workload,
-    one engine with unified dispatch only and one with lookahead bursts
-    on top.  Lookahead folds up to ``interactive_decode_steps`` decode
-    turns into one donated dispatch with a single trailing device_get,
-    so the per-TOKEN latency ratio is the measured host-gap recovery;
-    the counters confirm the burst path actually ran and the token
-    streams must match exactly (greedy).  Returns the ``lookahead``
-    sub-dict for the bench JSON.  The caller must free the primary
-    model's HBM first."""
-    import gc
-
-    import jax
-
-    from dynamo_tpu.engine.config import EngineConfig
-    from dynamo_tpu.engine.core import EngineCore
-    from dynamo_tpu.engine.request import EngineRequest
-    from dynamo_tpu.llm.protocols import SamplingOptions, StopConditions
-    from dynamo_tpu.models.config import ModelConfig
-    from dynamo_tpu.models.llama import LlamaModel
-    from dynamo_tpu.obs.timeline import step_timeline
-
-    name = os.environ.get("DYNAMO_BENCH_LOOKAHEAD_MODEL",
-                          "1b" if on_accel else "tiny")
-    mcfg = MODELS[name]
-    isl = int(os.environ.get("DYNAMO_BENCH_LOOKAHEAD_ISL",
-                             "256" if on_accel else "24"))
-    batch = 8
-    gen = 64 if on_accel else 16
-    max_len = ((isl + gen) // block_size + 2) * block_size
-    cfg = ModelConfig(**mcfg, dtype="bfloat16" if on_accel else "float32")
-    model = LlamaModel(cfg)
-    params = model.init_params(jax.random.PRNGKey(13))
-    jax.block_until_ready(params)
-
-    def run(lookahead: bool):
-        """One engine lifecycle: warmup pass (compiles), measured pass.
-        Returns (ms_per_token, token_streams, metrics, host_gap_ms)."""
-        ecfg = EngineConfig(
-            max_batch_size=batch, max_model_len=max_len,
-            block_size=block_size,
-            num_blocks=batch * (max_len // block_size) + 8,
-            decode_steps=8,
-            prefill_token_budget=4 * block_size,
-            unified_token_dispatch=True,
-            lookahead_dispatch=lookahead,
-            enable_prefix_reuse=False,
-        )
-        engine = EngineCore(model, params, ecfg, eos_token_ids=[])
-        rng = np.random.default_rng(7)
-        prompts = [rng.integers(1, cfg.vocab_size - 1, size=isl).tolist()
-                   for _ in range(batch)]
-
-        def pass_once(tag: str):
-            streams = {}
-
-            def mk_emit(rid):
-                def emit(out):
-                    streams.setdefault(rid, []).extend(out.token_ids)
-                return emit
-
-            for i, prompt in enumerate(prompts):
-                rid = f"la-{tag}-{i}"
-                engine.submit(EngineRequest(
-                    request_id=rid, prompt=list(prompt),
-                    sampling=SamplingOptions(temperature=0.0),
-                    stops=StopConditions(max_tokens=gen, ignore_eos=True),
-                    emit=mk_emit(rid),
-                ))
-            tok0 = engine.tokens_generated
-            t0 = time.perf_counter()
-            guard = time.monotonic() + 600
-            while engine.has_work() and time.monotonic() < guard:
-                engine.step()
-            dt = time.perf_counter() - t0
-            toks = engine.tokens_generated - tok0
-            return dt / max(toks, 1) * 1000, [streams[k] for k in
-                                              sorted(streams)]
-
-        try:
-            pass_once("warm")  # compiles every bucket outside the window
-            step_timeline.reset()
-            ms_per_tok, streams = pass_once("meas")
-            gap = step_timeline.host_gap_ms_per_turn
-            return ms_per_tok, streams, engine.metrics(), gap
-        finally:
-            engine = None
-            gc.collect()
-
-    off_ms, off_toks, _, off_gap = run(lookahead=False)
-    on_ms, on_toks, stats, on_gap = run(lookahead=True)
-    hits = int(stats.get("lookahead_hits_total", 0))
-    mis = int(stats.get("lookahead_mispredicts_total", 0))
-    return {
-        "model": name, "isl": isl, "batch": batch, "gen": gen,
-        "itl_off_ms_per_tok": round(off_ms, 3),
-        "itl_on_ms_per_tok": round(on_ms, 3),
-        "off_over_on": round(off_ms / on_ms, 3) if on_ms else None,
-        "token_parity": off_toks == on_toks,
-        "bursts": int(stats.get("lookahead_bursts_total", 0)),
-        "hit_rate": round(hits / (hits + mis), 4) if hits + mis else None,
-        "commits": int(stats.get("lookahead_commits_total", 0)),
-        "flushes": int(stats.get("lookahead_flushes_total", 0)),
-        "host_gap_off_ms": off_gap and round(off_gap, 3),
-        "host_gap_on_ms": on_gap and round(on_gap, 3),
-    }
-
-
 def main() -> None:
     cpu_mode = os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
     if cpu_mode:
@@ -1196,10 +1084,6 @@ def main() -> None:
     # step per mixed turn (engine/core.py _run_unified); default off
     # until the on-chip numbers are re-landed (ROADMAP standing note)
     unified = bool(int(os.environ.get("DYNAMO_BENCH_UNIFIED", "0")))
-    # double-buffered dispatch: fused decode bursts + speculative host
-    # prebuild on the unified path (engine/core.py _run_unified); implies
-    # unified dispatch.  Also enables the on/off ITL A/B phase below.
-    lookahead = bool(int(os.environ.get("DYNAMO_BENCH_LOOKAHEAD", "0")))
     # int8 weight-only quantization (models/quant.py): halves weight HBM
     # footprint AND per-decode-step weight traffic — this is what fits the
     # north-star 8B model on a single 16GiB v5e chip (the reference's
@@ -1283,7 +1167,6 @@ def main() -> None:
         prefill_chunk_tokens=min(prefill_chunk, max_len) if prefill_chunk else 0,
         prefill_token_budget=prefill_budget,
         unified_token_dispatch=unified,
-        lookahead_dispatch=lookahead,
         enable_prefix_reuse=False,  # distinct prompts; measure raw decode
         cache_dtype="int8" if kv_quant == "int8" else None,
     )
@@ -1293,7 +1176,7 @@ def main() -> None:
             and kv_quant == "none":
         _probe_pallas_prefill(mcfg, max_len, block_size, prefill_chunk,
                               prefill_budget)
-    if (unified or lookahead) and pallas_on \
+    if unified and pallas_on \
             and not env("DYNAMO_DISABLE_PALLAS_PREFILL"):
         # the mixed dispatch exercises the ragged kernel at a geometry
         # the single-phase probes never touch (non-aligned decode starts)
@@ -1531,26 +1414,6 @@ def main() -> None:
             res["kv_stream"] = stream
             _emit(res)
 
-    # double-buffered dispatch on/off ITL A/B (rides the same opt-in as
-    # the primary engine's lookahead mode: two extra engine lifecycles
-    # on a small model).  Failure can't lose the round — the primary
-    # numbers, including the lookahead perf_model reconcile, are banked.
-    if lookahead:
-        import gc
-
-        engine = model = params = None
-        gc.collect()
-        try:
-            la = _lookahead_phase(on_accel, block_size)
-        except Exception:
-            import traceback
-
-            traceback.print_exc(file=sys.stderr)
-            la = None
-        if la:
-            print(f"# lookahead: {json.dumps(la)}", file=sys.stderr)
-            res["lookahead"] = la
-            _emit(res)
     run_cancel()
 
 

@@ -133,7 +133,7 @@ class MetricsSnapshot:
 
 def prefill_dispatch_stats_from_text(text: str) -> Optional[dict]:
     """Engine-side dispatch summary from one /metrics body: prefill
-    batching, unified dispatch, lookahead, persist tier, step-timeline
+    batching, unified dispatch, persist tier, step-timeline
     headline, DCN transfer bandwidth and streamed KV handoff.  Returns
     None when no prefill work was recorded (non-dynamo endpoint)."""
     snap = MetricsSnapshot.parse(text)
@@ -162,22 +162,6 @@ def prefill_dispatch_stats_from_text(text: str) -> Optional[dict]:
             "unified_prefill_tokens_per_dispatch": round(
                 g(EM.UNIFIED_PREFILL_TOKENS_TOTAL) / unified, 1),
             "unified_budget_utilization": g(EM.UNIFIED_BUDGET_UTILIZATION),
-        })
-    bursts = g(EM.LOOKAHEAD_BURSTS_TOTAL)
-    if bursts:
-        # double-buffered dispatch engaged: fused device turns per
-        # readback, the per-row prediction hit rate, and how often the
-        # speculative next-turn prebuild survived to commit
-        rows = g(EM.LOOKAHEAD_HITS_TOTAL) + g(EM.LOOKAHEAD_MISPREDICTS_TOTAL)
-        plans = g(EM.LOOKAHEAD_COMMITS_TOTAL) + g(EM.LOOKAHEAD_FLUSHES_TOTAL)
-        out.update({
-            "lookahead_bursts": int(bursts),
-            "lookahead_dispatch_depth": int(
-                g(EM.LOOKAHEAD_DISPATCH_DEPTH)),
-            "lookahead_hit_rate": round(
-                g(EM.LOOKAHEAD_HITS_TOTAL) / rows, 4) if rows else 0.0,
-            "lookahead_commit_rate": round(
-                g(EM.LOOKAHEAD_COMMITS_TOTAL) / plans, 4) if plans else 0.0,
         })
     phits = g(EM.PERSIST_HITS_TOTAL)
     pmiss = g(EM.PERSIST_MISSES_TOTAL)

@@ -284,10 +284,6 @@ async def run_with_native(args):
         # mixed turn); DYNAMO_UNIFIED_DISPATCH=1 to enable for a sweep
         unified_token_dispatch=bool(int(os.environ.get(
             "DYNAMO_UNIFIED_DISPATCH", "0"))),
-        # double-buffered dispatch (fused bursts + speculative next-turn
-        # prebuild, implies unified); DYNAMO_LOOKAHEAD=1 for a sweep
-        lookahead_dispatch=bool(int(os.environ.get(
-            "DYNAMO_LOOKAHEAD", "0"))),
         enable_prefix_reuse=False,
         cache_dtype="int8" if quant else None,
     )

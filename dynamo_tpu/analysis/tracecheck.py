@@ -527,47 +527,6 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
             upcast_min_elems=min_elems,
         ))
 
-        if cfg.lookahead_dispatch and cfg.interactive_decode_steps >= 2:
-            # double-buffered dispatch: the fused burst shares the
-            # unified step's axes plus the per-row limits operand and a
-            # static burst depth (one value — the interactive burst
-            # length, the only depth _run_unified ever dispatches)
-            k_burst = cfg.interactive_decode_steps
-
-            def build_burst(t_bucket, r_pad, prefix_blocks, num_steps):
-                min_rows = r_pad // 2 + 1 if r_pad > 1 else 1
-                if min_rows > b or (t_bucket - d_region) // bs < 1:
-                    return None
-                args = (params, cache,
-                        _sds((1, t_bucket), i32), _sds((1, t_bucket), i32),
-                        _sds((r_pad, m), i32), _sds((r_pad,), i32),
-                        _sds((1, t_bucket), i32), _sds((1, t_bucket), i32),
-                        _sds((r_pad,), i32), _sds((r_pad,), i32),
-                        _sds((r_pad,), i32), _sds((r_pad,), i32), rng,
-                        _sds((r_pad,), f32), _sds((r_pad,), i32),
-                        _sds((r_pad,), f32))
-                return Signature(
-                    f"t={t_bucket},r={r_pad},pb={prefix_blocks},"
-                    f"k={num_steps}", args,
-                    dict(num_steps=num_steps, row_tokens=d_region,
-                         prefix_blocks=prefix_blocks, k_cand=K_MAX,
-                         exact=False, use_penalties=False),
-                )
-
-            eps.append(Entrypoint(
-                name=f"engine.unified_burst[{tag}]",
-                axes={"t_bucket": tu_axis, "r_pad": ru_axis,
-                      "prefix_blocks": pb_axis, "num_steps": [k_burst]},
-                build=build_burst,
-                jit_fn=core._burst_fn, raw_fn=core._burst_impl,
-                donate_argnums=(1,),
-                representatives=[
-                    dict(t_bucket=tu_axis[-1], r_pad=ru_axis[-1],
-                         prefix_blocks=0, num_steps=k_burst),
-                ],
-                upcast_min_elems=min_elems,
-            ))
-
     if cfg.spec_tokens > 0:
         # the sixth donated serving dispatch: the draft proposer's
         # ingest+draft step owns its own paged cache (engine/draft.py)
@@ -794,12 +753,9 @@ def build_registry() -> list[Entrypoint]:
     eps: list[Entrypoint] = []
     eps += _engine_entrypoints(
         "tiny-llama", tiny,
-        # lookahead on: the fused unified burst (double-buffered
-        # dispatch) joins the census alongside the single-turn unified
-        # impl it falls back to
         _tiny_engine_config(decode_steps=16, spec_tokens=2,
                             prefill_token_budget=64,
-                            lookahead_dispatch=True),
+                            unified_token_dispatch=True),
     )
     eps += _engine_entrypoints(
         "tiny-llama-int8", tiny,

@@ -217,8 +217,7 @@ def _build_local_engine(args) -> tuple[object, object]:
         # step per turn when both phases have work
         unified_token_dispatch=bool(
             getattr(args, "unified_token_dispatch", False)),
-        # double-buffered dispatch: fused bursts + speculative next-turn
-        # prebuild overlapped with device compute (implies unified)
+        # refused by EngineConfig when set
         lookahead_dispatch=bool(
             getattr(args, "lookahead_dispatch", False)),
         # where POST /debug/profile writes (profile_steps: inert, see
@@ -1012,14 +1011,9 @@ def _parser() -> argparse.ArgumentParser:
                      "--prefill-token-budget, which defaults to 1024 "
                      "when unset); see docs/engine_scheduling.md")
     run.add_argument("--lookahead-dispatch", action="store_true",
-                     default=bool(int(os.environ.get(
-                         "DYNAMO_LOOKAHEAD", "0") or "0")),
-                     help="double-buffered dispatch: fuse mixed "
-                     "prefill+decode turns into multi-step bursts with "
-                     "ONE device readback, and prebuild the next turn's "
-                     "dispatch on the host while the device computes "
-                     "(implies --unified-token-dispatch; also "
-                     "DYNAMO_LOOKAHEAD=1); see docs/engine_scheduling.md")
+                     help="refused: dispatch-ahead hides the host round "
+                     "trip by default and has no option; see "
+                     "docs/engine_scheduling.md")
     run.add_argument("--nnodes", type=int, default=1,
                      help="worker processes forming ONE mesh (multi-host)")
     run.add_argument("--node-rank", type=int, default=0)

@@ -54,22 +54,16 @@ class EngineConfig:
     # Default off until parity-gated (tests/test_unified_dispatch.py
     # pins seeded-stream parity vs the legacy paths).
     unified_token_dispatch: bool = False
-    # double-buffered dispatch (lookahead scheduler): overlap next-turn
-    # host scheduling with device compute.  Mixed prefill+decode turns
-    # fuse interactive_decode_steps unified turns into ONE dispatch with
-    # on-device stop/append (a burst needs a single trailing device_get),
-    # and while the device computes, the host speculatively prebuilds
-    # the NEXT turn's dispatch operands from predicted token counts
-    # (every active decode row yields exactly 1 token/turn unless a stop
-    # fires) — committed if the prediction held, flushed on mismatch.
-    # Implies unified_token_dispatch.  Default off until parity-gated
-    # (tests/test_lookahead_dispatch.py pins seeded-stream parity).
+    # refused (ValueError below): dispatch-ahead (EngineCore._settle) is
+    # the engine's one overlap of host and device and has no option.  The
+    # name stays while cellbench/server.py passes it (ROADMAP D12).
     lookahead_dispatch: bool = False
     # decode burst length while prefill work is pending (admitted/waiting
-    # requests or a mid-prefill slot).  Long bursts amortise dispatch
-    # overhead but make a freshly-arrived prompt wait a whole burst
-    # (decode_steps * ITL ≈ 760ms at 64 steps) before its first chunk —
-    # the dominant term in VERDICT r2's TTFT miss.  0 = min(8, decode_steps).
+    # requests or a mid-prefill slot): a long burst amortises the host
+    # round trip, and a freshly-arrived prompt waits a whole burst before
+    # its first chunk.  0 = min(8, decode_steps); never above decode_steps.
+    # Like decode_steps it has no CLI flag: `run` and `serve` decode one
+    # step a dispatch, under dispatch-ahead (ROADMAP D14).
     interactive_decode_steps: int = 0
     # prompt-lookup speculative decoding (engine/spec.py): propose up to
     # spec_tokens continuation tokens by n-gram match against the sequence
@@ -151,11 +145,11 @@ class EngineConfig:
                 self.block_size,
                 self.prefill_chunk_tokens // self.block_size * self.block_size,
             )
-        if self.lookahead_dispatch and not self.unified_token_dispatch:
-            # the lookahead scheduler is a layer over unified dispatch:
-            # the fused burst generalizes the unified mixed step, so the
-            # flag implies it (and inherits its budget defaulting below)
-            self.unified_token_dispatch = True
+        if self.lookahead_dispatch:
+            raise ValueError(
+                "lookahead_dispatch is refused: dispatch-ahead hides the host "
+                "round trip under the next device program by default and "
+                "has no option (docs/engine_scheduling.md)")
         if self.unified_token_dispatch and not self.prefill_token_budget:
             # the unified scheduler packs under prefill_token_budget; a
             # bare --unified-token-dispatch gets a sensible default

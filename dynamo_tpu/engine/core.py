@@ -46,11 +46,7 @@ import numpy as np
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.counters import counters as prefill_counters
-from dynamo_tpu.engine.counters import (
-    lookahead_counters,
-    mesh_shape,
-    request_counters,
-)
+from dynamo_tpu.engine.counters import mesh_shape, request_counters
 from dynamo_tpu.engine.grammar import (
     INIT_STATE, JsonGrammar, compile_choice_vocab, compile_regex_vocab,
     compose_tables, device_tables, grammar_advance, grammar_mask,
@@ -69,8 +65,7 @@ from dynamo_tpu.tokens import TokenBlockSequence
 log = logging.getLogger("dynamo_tpu.engine")
 
 __all__ = ["EngineCore", "unified_step", "multi_decode_step",
-           "ragged_prefill_step", "unified_token_step",
-           "unified_burst_step"]
+           "ragged_prefill_step", "unified_token_step"]
 
 
 def unified_step(
@@ -292,157 +287,6 @@ def unified_token_step(
                       seed_steps=(seq_lens if seeds is not None else None),
                       k_cand=k_cand, exact=exact)
     return out, cache
-
-
-def unified_burst_step(
-    model, params, cache, tokens, positions, block_tables, seq_lens,
-    slot_idx, seq_ids, seq_starts, row_offsets, last_idx, limits, rng,
-    temp, top_k, top_p, pen_tokens=None, pen_first=None, pen_cursor=None,
-    freq_pen=None, pres_pen=None,
-    *, num_steps: int, block_size: int, row_tokens: int = 0,
-    prefix_blocks: int = 0, k_cand: int = K_MAX, exact: bool = False,
-    use_penalties: bool = False, grammar=None, jrows=None, jstate=None,
-    jdepth=None, jstack=None, min_p=None, bias_tokens=None, bias_vals=None,
-    seeds=None, seed_rows=None,
-):
-    """Fused multi-turn unified dispatch (double-buffered dispatch): turn
-    0 is exactly :func:`unified_token_step` (decode rows + prefill spans
-    on one flat axis), then ``num_steps - 1`` further decode turns run
-    on device under one ``lax.scan`` — :func:`multi_decode_step`'s body
-    over the unified ROW axis, with turn 0's sampled tokens fed back.
-    A burst of ``num_steps`` device turns therefore needs ONE
-    ``jax.device_get`` at the end, generalising the pure-decode
-    multi-step burst to mixed prefill+decode turns.
-
-    Stop-condition handling stays host-side but is *deferred*: the scan
-    keeps generating past a stop (the prediction is that no row stops
-    mid-burst); the host discards the tail samples of a row whose stop
-    fired (a lookahead mispredict).  KV written past a stop lands only
-    in blocks the request still owns and never commits — released on
-    finish, the same discard semantics ``multi_decode_step`` already
-    has.  Prefill and padding rows are inert in the scan: ``limits`` is
-    0 for them, so they write no KV, attend over zero context, and
-    sample garbage the host discards.
-
-    Sampled-token append runs on device too: grammar states advance and
-    the penalty buffers (``pen_cursor`` is each row's next write index)
-    absorb each turn's sample inside the dispatch, so grammar masks and
-    repetition penalties see mid-burst tokens without a host round
-    trip.  Seeded rows fold on the absolute position (turn 0:
-    ``seq_lens``; scan: ``pos + 1``), so their streams are bit-identical
-    to the single-turn dispatches the burst replaces
-    (tests/test_lookahead_dispatch.py pins this).
-
-    Returns ``((out0, outs), cache)`` — ``out0`` is turn 0's
-    (sampled [R], logprob [R], cand_ids [R,C], cand_lps [R,C]) and
-    ``outs`` stacks the scan turns' ([K-1,R], ...).
-    """
-    use_grammar = grammar is not None
-    m = block_tables.shape[1]
-    rng0, rng_scan = jax.random.split(rng)
-
-    # ---- turn 0: the unified mixed step
-    hidden, cache = model.forward(
-        params, tokens, positions, cache, block_tables, seq_lens, slot_idx,
-        prefix_blocks=prefix_blocks,
-        ragged=(seq_ids, seq_starts, row_offsets),
-        ragged_row_tokens=row_tokens,
-    )
-    last_h = hidden[0, last_idx]  # [R, Dm] — flat-axis gather per row
-    logits = model.compute_logits(params, last_h)  # [R, V] f32
-    if use_grammar:
-        logits = grammar_mask(logits, grammar, jrows, jstate, jdepth, jstack)
-    out0 = sample_full(
-        logits, rng0, temp, top_k, top_p,
-        pen_tokens if use_penalties else None,
-        pen_first if use_penalties else None,
-        freq_pen if use_penalties else None,
-        pres_pen if use_penalties else None,
-        bias_tokens=bias_tokens, bias_vals=bias_vals, min_p=min_p,
-        seeds=seeds, seed_rows=seed_rows,
-        seed_steps=(seq_lens if seeds is not None else None),
-        k_cand=k_cand, exact=exact)
-    sampled0 = out0[0]
-
-    # ---- on-device append of turn 0's samples into the carried state
-    gs = gd = gk = None
-    if use_grammar:
-        gs, gd, gk = grammar_advance(
-            grammar, jrows, jstate, jdepth, jstack, sampled0)
-    ptoks, pfirst, cur = pen_tokens, pen_first, pen_cursor
-    if use_penalties:
-        rows = jnp.arange(sampled0.shape[0], dtype=jnp.int32)
-        seen = jnp.any(ptoks == sampled0[:, None], axis=-1)
-        t_cap = ptoks.shape[1]
-        at = jnp.minimum(cur, t_cap - 1)
-        ptoks = ptoks.at[rows, at].set(sampled0)
-        pfirst = pfirst.at[rows, at].set(~seen)
-        cur = jnp.minimum(cur + 1, t_cap - 1)
-
-    # ---- turns 1..num_steps-1: multi_decode_step's scan body over the
-    # unified row axis (decode rows live, prefill/pad rows inert)
-    def one(carry, rng_k):
-        gs = gd = gk = None
-        if use_penalties and use_grammar:
-            cache, toks, pos, lens, ptoks, pfirst, cur, gs, gd, gk = carry
-        elif use_penalties:
-            cache, toks, pos, lens, ptoks, pfirst, cur = carry
-        elif use_grammar:
-            cache, toks, pos, lens, gs, gd, gk = carry
-        else:
-            cache, toks, pos, lens = carry
-        blk = jnp.minimum(pos // block_size, m - 1)
-        base = jnp.take_along_axis(block_tables, blk[:, None], axis=1)[:, 0]
-        slot = base * block_size + pos % block_size
-        slot = jnp.where(pos < limits, slot, -1)
-        hidden, cache = model.forward(
-            params, toks[:, None], pos[:, None], cache, block_tables, lens,
-            slot[:, None],
-        )
-        logits = model.compute_logits(params, hidden[:, 0])
-        if use_grammar:
-            logits = grammar_mask(logits, grammar, jrows, gs, gd, gk)
-        sampled, lp, cids, clps = sample_full(
-            logits, rng_k, temp, top_k, top_p,
-            ptoks if use_penalties else None,
-            pfirst if use_penalties else None,
-            freq_pen if use_penalties else None,
-            pres_pen if use_penalties else None,
-            bias_tokens=bias_tokens, bias_vals=bias_vals, min_p=min_p,
-            seeds=seeds, seed_rows=seed_rows,
-            seed_steps=(pos + 1 if seeds is not None else None),
-            k_cand=k_cand, exact=exact,
-        )
-        new_lens = jnp.minimum(lens + 1, limits)
-        ys = (sampled, lp, cids, clps)
-        if use_grammar:
-            gs, gd, gk = grammar_advance(grammar, jrows, gs, gd, gk, sampled)
-        if use_penalties:
-            rows = jnp.arange(sampled.shape[0], dtype=jnp.int32)
-            seen = jnp.any(ptoks == sampled[:, None], axis=-1)
-            t_cap = ptoks.shape[1]
-            at = jnp.minimum(cur, t_cap - 1)
-            ptoks = ptoks.at[rows, at].set(sampled)
-            pfirst = pfirst.at[rows, at].set(~seen)
-            cur = jnp.minimum(cur + 1, t_cap - 1)
-        nxt = (cache, sampled, pos + 1, new_lens)
-        if use_penalties:
-            nxt = nxt + (ptoks, pfirst, cur)
-        if use_grammar:
-            nxt = nxt + (gs, gd, gk)
-        return nxt, ys
-
-    # init mirrors the follow-up decode turn the scan replaces: turn 0's
-    # token sits at position seq_lens, the context now includes it
-    # (clamped at the block limit — past it no KV was written)
-    init = (cache, sampled0, seq_lens, jnp.minimum(seq_lens + 1, limits))
-    if use_penalties:
-        init = init + (ptoks, pfirst, cur)
-    if use_grammar:
-        init = init + (gs, gd, gk)
-    carry, outs = jax.lax.scan(
-        one, init, jax.random.split(rng_scan, num_steps - 1))
-    return (out0, outs), carry[0]
 
 
 @dataclasses.dataclass
@@ -667,14 +511,6 @@ class EngineCore:
             static_argnames=("row_tokens", "prefix_blocks", "k_cand",
                              "exact"),
         )
-        # double-buffered dispatch: the fused multi-turn unified burst
-        # (turn 0 = unified mixed step, then a multi-step decode scan
-        # over the unified row axis — ONE device_get per burst)
-        self._burst_fn = jax.jit(
-            under_mesh(self._burst_impl), donate_argnums=(1,),
-            static_argnames=("num_steps", "row_tokens", "prefix_blocks",
-                             "k_cand", "exact", "use_penalties"),
-        )
         # sequence-parallel long-prefill (ring attention over the "data"
         # axis): one dispatch computes the whole prompt with the sequence
         # sharded across the mesh — SURVEY §5 long-context path
@@ -745,15 +581,6 @@ class EngineCore:
         self.unified_prefill_tokens = 0  # prefill tokens packed over them
         self.unified_budget_offered = 0  # flat-axis budget offered
         self.unified_budget_used = 0     # decode rows + prefill tokens
-        # double-buffered dispatch (lookahead_dispatch): fused bursts,
-        # per-row prediction outcomes, and the speculative next-turn
-        # prebuild commit/flush protocol
-        self.lookahead_bursts = 0        # fused multi-turn dispatches
-        self.lookahead_hits = 0          # rows that consumed every sample
-        self.lookahead_mispredicts = 0   # rows whose stop fired mid-burst
-        self.lookahead_commits = 0       # speculative prebuilds committed
-        self.lookahead_flushes = 0       # speculative prebuilds discarded
-        self.lookahead_depth = 0         # device turns per device_get (last)
         self.device_gets = 0             # step-loop jax.device_get calls
         # counted where the work happens (cellbench reads them as core.*)
         self.decode_dispatches = 0       # pure-decode dispatches (burst/spec)
@@ -762,10 +589,6 @@ class EngineCore:
         self.requests_cut_short = 0      # LENGTH because block space ran out
         self.first_tokens = 0            # requests that emitted a first token
         self.first_token_s = 0.0         # sum of (first emit - submitted_at)
-        # speculative next-turn dispatch operands, built during the
-        # overlap window while the device computes (committed next turn
-        # if the predicted plan held, flushed otherwise)
-        self._spec_next: Optional[dict] = None
         # cached _unified_penalties host buffers (invalidated on
         # admission/finish; incremental append between turns)
         self._pen_cache: Optional[dict] = None
@@ -826,28 +649,6 @@ class EngineCore:
             rng, temp, top_k, top_p, pen_tokens, pen_first, freq_pen,
             pres_pen, row_tokens=row_tokens, prefix_blocks=prefix_blocks,
             k_cand=k_cand, exact=exact, grammar=grammar, jrows=jrows,
-            jstate=jstate, jdepth=jdepth, jstack=jstack, min_p=min_p,
-            bias_tokens=bias_tokens, bias_vals=bias_vals, seeds=seeds,
-            seed_rows=seed_rows)
-
-    def _burst_impl(self, params, cache, tokens, positions, block_tables,
-                    seq_lens, slot_idx, seq_ids, seq_starts, row_offsets,
-                    last_idx, limits, rng, temp, top_k, top_p, *,
-                    num_steps=2, row_tokens=0, prefix_blocks=0,
-                    k_cand=K_MAX, exact=False, use_penalties=False,
-                    grammar=None, jrows=None, jstate=None, jdepth=None,
-                    jstack=None, min_p=None, bias_tokens=None,
-                    bias_vals=None, seeds=None, seed_rows=None,
-                    pen_tokens=None, pen_first=None, pen_cursor=None,
-                    freq_pen=None, pres_pen=None):
-        return unified_burst_step(
-            self.model, params, cache, tokens, positions, block_tables,
-            seq_lens, slot_idx, seq_ids, seq_starts, row_offsets, last_idx,
-            limits, rng, temp, top_k, top_p, pen_tokens, pen_first,
-            pen_cursor, freq_pen, pres_pen, num_steps=num_steps,
-            block_size=self.config.block_size, row_tokens=row_tokens,
-            prefix_blocks=prefix_blocks, k_cand=k_cand, exact=exact,
-            use_penalties=use_penalties, grammar=grammar, jrows=jrows,
             jstate=jstate, jdepth=jdepth, jstack=jstack, min_p=min_p,
             bias_tokens=bias_tokens, bias_vals=bias_vals, seeds=seeds,
             seed_rows=seed_rows)
@@ -1280,11 +1081,6 @@ class EngineCore:
             use_penalties=use_pen, **gkw,
         )
         self.steps += 1
-        if self._lookahead_enabled():
-            # overlap window: absorb arrivals while the device runs the
-            # decode burst (admission next turn starts from a warm list)
-            step_timeline.enter("overlap")
-            self._drain_waiting()
         return out
 
     # ------------------------------------------------------- dispatch-ahead
@@ -1310,7 +1106,7 @@ class EngineCore:
         token is read right behind it.  With no row running the prefill is
         read back at once, as ever: nothing is there to issue behind it.
         The engine-wide paths that build from the host's tokens every turn
-        (unified / lookahead dispatch, speculation) or deliver tokens in
+        (unified dispatch, speculation) or deliver tokens in
         bursts (``decode_steps`` > 1) never leave one: they are
         alternatives to this overlap, and keep the serial step."""
         cfg = self.config
@@ -1465,13 +1261,6 @@ class EngineCore:
                 self.unified_budget_used / self.unified_budget_offered
                 if self.unified_budget_offered else 0.0
             ),
-            # double-buffered dispatch (lookahead scheduler)
-            "lookahead_bursts_total": self.lookahead_bursts,
-            "lookahead_hits_total": self.lookahead_hits,
-            "lookahead_mispredicts_total": self.lookahead_mispredicts,
-            "lookahead_commits_total": self.lookahead_commits,
-            "lookahead_flushes_total": self.lookahead_flushes,
-            "lookahead_dispatch_depth": self.lookahead_depth,
             "device_gets_total": self.device_gets,
             "decode_dispatches_total": self.decode_dispatches,
             "decode_rows_dispatched_total": self.decode_rows_dispatched,
@@ -1646,12 +1435,6 @@ class EngineCore:
             and getattr(self.model, "supports_unified_dispatch", False)
         )
 
-    def _lookahead_enabled(self) -> bool:
-        """Double-buffered dispatch: a layer over unified dispatch (the
-        fused burst generalizes the unified mixed step), so it engages
-        only where unified dispatch would."""
-        return self.config.lookahead_dispatch and self._unified_enabled()
-
     def _step_unified(self, ready: list[EngineRequest], decoding: bool
                       ) -> bool:
         """One turn of the unified token-budget scheduler: mixed work
@@ -1709,12 +1492,8 @@ class EngineCore:
             # and it ran to completion)
             self._pending_aborts.add(rid)
 
-    def _drain_waiting(self) -> None:
-        """Pull the cross-thread waiting queue into ``_admitted``,
-        applying pending aborts.  Factored from :meth:`_admit` so the
-        lookahead overlap window can absorb arrivals while the device
-        computes (the next turn's admission then starts from a warm
-        list instead of paying the queue drain in the host gap)."""
+    def _admit(self) -> None:
+        # drain the cross-thread queue
         while True:
             try:
                 req = self.waiting.get_nowait()
@@ -1724,9 +1503,6 @@ class EngineCore:
                 self._pending_aborts.discard(req.request_id)
                 req.abort_requested = True
             self._admitted.append(req)
-
-    def _admit(self) -> None:
-        self._drain_waiting()
         # pending aborts unmatched after a full queue drain can never match:
         # a caller that submitted before aborting had its request visible in
         # this drain (_process_aborts runs before _admit each step), so the
@@ -2093,9 +1869,6 @@ class EngineCore:
             self.params, self.cache, *up[:9], rng, *up[9:],
             prefix_blocks=pb, k_cand=k_cand, exact=exact, **gkw,
         )
-        if self._lookahead_enabled():
-            step_timeline.enter("overlap")
-            self._drain_waiting()  # overlap: absorb arrivals under compute
         self.steps += 1
         self.prefill_steps += 1
         take_sum = sum(take for _, take, _ in sel)
@@ -2183,22 +1956,13 @@ class EngineCore:
         if budget < bs:
             return False  # flat axis cannot fit a span past the region
 
-        lookahead = self._lookahead_enabled()
-        # fused burst depth: mixed turns always have prefill pending, so
-        # the interactive burst length applies (cf. _run_decode); 1 when
-        # lookahead is off keeps the single-turn dispatch bit-for-bit
-        k_steps = max(1, cfg.interactive_decode_steps) if lookahead else 1
-
         dec: list[EngineRequest] = []
-        dec_limits: list[int] = []
         for req in self.slots:
             if req is None or req.state is not RequestState.RUNNING:
                 continue
-            limit = self._grow_blocks(req, k_steps)
-            if limit is None:
+            if self._grow_blocks(req, 1) is None:
                 continue  # no slot for even the current token: LENGTH
             dec.append(req)
-            dec_limits.append(limit)
         if not dec:
             return False
 
@@ -2226,40 +1990,19 @@ class EngineCore:
         r_real = n_dec + len(sel)
         r_pad = 1 << max(0, (r_real - 1).bit_length())
         t_pad = cfg.bucket_for(d_region + used)
-
-        # speculative-dispatch commit protocol: if last turn's overlap
-        # window prebuilt exactly this plan, reuse its prefill-span
-        # arrays (the O(t_pad) host work) — decode-row scalars advance
-        # every turn and are always refilled below.  Any divergence
-        # (a stop fired, an admission/finish changed the slot map, a
-        # prefill chunk resized) mismatches the key: flush and rebuild.
-        arrays = None
-        pf_max_pb = 0
-        if lookahead:
-            spec, self._spec_next = self._spec_next, None
-            if spec is not None:
-                key = (tuple(r.request_id for r in dec),
-                       tuple((rq.request_id, rq.computed_tokens, take, fin)
-                             for rq, take, fin in sel),
-                       d_region, r_pad, t_pad)
-                if spec["key"] == key:
-                    arrays = spec["arrays"]
-                    pf_max_pb = spec["max_pb"]
-                    self.lookahead_commits += 1
-                    lookahead_counters.record_commit()
-                else:
-                    self.lookahead_flushes += 1
-                    lookahead_counters.record_flush()
-        if arrays is None:
-            arrays = self._alloc_unified_arrays(r_pad, t_pad)
-            off = d_region
-            for j, (req, take, _final) in enumerate(sel):
-                off = self._fill_prefill_span(
-                    arrays, n_dec + j, off, req, req.computed_tokens, take)
-                pf_max_pb = max(pf_max_pb, req.computed_tokens // bs)
-        (tokens, positions, slot_idx, seq_ids, bt, seq_lens, starts, roff,
-         last_idx, temp, top_k, top_p, limits) = arrays
-        max_pb = pf_max_pb
+        tokens = np.zeros((1, t_pad), np.int32)
+        positions = np.zeros((1, t_pad), np.int32)
+        slot_idx = np.full((1, t_pad), -1, np.int32)
+        seq_ids = np.full((1, t_pad), -1, np.int32)
+        bt = np.zeros((r_pad, m), np.int32)
+        seq_lens = np.zeros(r_pad, np.int32)
+        starts = np.zeros(r_pad, np.int32)
+        roff = np.zeros(r_pad, np.int32)
+        last_idx = np.zeros(r_pad, np.int32)
+        temp = np.zeros(r_pad, np.float32)
+        top_k = np.zeros(r_pad, np.int32)
+        top_p = np.ones(r_pad, np.float32)
+        max_pb = 0
         for r, req in enumerate(dec):
             p = req.seq.total_tokens - 1  # uncomputed tail position
             tokens[0, r] = req.seq.tokens[-1]
@@ -2274,8 +2017,27 @@ class EngineCore:
             temp[r] = req.sampling.temperature
             top_k[r] = req.sampling.top_k
             top_p[r] = req.sampling.top_p
-            limits[r] = dec_limits[r]
             max_pb = max(max_pb, -(-p // bs))
+        off = d_region
+        for j, (req, take, _final) in enumerate(sel):
+            r = n_dec + j
+            begin = req.computed_tokens
+            end = begin + take
+            tokens[0, off:off + take] = req.prompt[begin:end]
+            pos = np.arange(begin, end, dtype=np.int32)
+            positions[0, off:off + take] = pos
+            bt[r, : len(req.block_ids)] = req.block_ids
+            slot_idx[0, off:off + take] = bt[r, pos // bs] * bs + pos % bs
+            seq_ids[0, off:off + take] = r
+            seq_lens[r] = end
+            starts[r] = begin
+            roff[r] = off
+            last_idx[r] = off + take - 1
+            temp[r] = req.sampling.temperature
+            top_k[r] = req.sampling.top_k
+            top_p[r] = req.sampling.top_p
+            max_pb = max(max_pb, begin // bs)
+            off += -(-take // bs) * bs
         pb = 0 if max_pb == 0 else 1 << (max_pb - 1).bit_length()
         pb = min(pb, m)
 
@@ -2306,10 +2068,7 @@ class EngineCore:
             gram = (keys, jrows, jstate, jdepth, jstack)
         extras = self._sampling_extras(
             samp_reqs, rows=[r for r, _ in samp], b=r_pad)
-        burst = lookahead and k_steps >= 2
-        extras.update(self._unified_penalties(
-            samp, r_pad, horizon=k_steps if burst else 1))
-        use_pen = "pen_tokens" in extras
+        extras.update(self._unified_penalties(samp, r_pad))
 
         # growth allocations above may have evicted registered blocks
         # that this very dispatch writes into — offload them first
@@ -2320,66 +2079,28 @@ class EngineCore:
         gkw = self._gram_kwargs(gram)
         gkw.update(extras)
         step_timeline.enter("upload")
-        if burst:
-            up, gkw = self._upload_dispatch(
-                (tokens, positions, bt, seq_lens, slot_idx, seq_ids,
-                 starts, roff, last_idx, limits, temp, top_k, top_p), gkw)
-            step_timeline.enter("dispatch", kind="unified_burst")
-            if perf_model.wants("unified_burst"):
-                perf_model.offer(
-                    "unified_burst", self._burst_fn,
-                    (self.params, self.cache, *up[:10], rng, *up[10:]),
-                    kw=gkw,
-                    statics=dict(num_steps=k_steps, row_tokens=d_region,
-                                 prefix_blocks=pb, k_cand=k_cand,
-                                 exact=exact, use_penalties=use_pen))
-            out, self.cache = self._burst_fn(
-                self.params, self.cache, *up[:10], rng, *up[10:],
-                num_steps=k_steps, row_tokens=d_region, prefix_blocks=pb,
-                k_cand=k_cand, exact=exact, use_penalties=use_pen, **gkw,
-            )
-        else:
-            up, gkw = self._upload_dispatch(
-                (tokens, positions, bt, seq_lens, slot_idx, seq_ids,
-                 starts, roff, last_idx, temp, top_k, top_p), gkw)
-            step_timeline.enter("dispatch", kind="unified")
-            if perf_model.wants("unified"):
-                perf_model.offer(
-                    "unified", self._unified_fn,
-                    (self.params, self.cache, *up[:9], rng, *up[9:]),
-                    kw=gkw,
-                    statics=dict(row_tokens=d_region, prefix_blocks=pb,
-                                 k_cand=k_cand, exact=exact))
-            out, self.cache = self._unified_fn(
-                self.params, self.cache, *up[:9], rng, *up[9:],
-                row_tokens=d_region, prefix_blocks=pb, k_cand=k_cand,
-                exact=exact, **gkw,
-            )
-        if lookahead:
-            # overlap window: the dispatch above is in flight — drain
-            # arrivals and speculatively prebuild the NEXT turn's
-            # prefill-span operands while the device computes.  The
-            # device_get below is the synchronization point, so this
-            # host work is hidden under device time (attributed to the
-            # "overlap" phase, excluded from the host gap).
-            step_timeline.enter("overlap")
-            self._drain_waiting()
-            self._spec_next = self._prebuild_next(
-                ready, sel, dec, d_region, budget)
+        up, gkw = self._upload_dispatch(
+            (tokens, positions, bt, seq_lens, slot_idx, seq_ids, starts,
+             roff, last_idx, temp, top_k, top_p), gkw)
+        step_timeline.enter("dispatch", kind="unified")
+        if perf_model.wants("unified"):
+            perf_model.offer(
+                "unified", self._unified_fn,
+                (self.params, self.cache, *up[:9], rng, *up[9:]), kw=gkw,
+                statics=dict(row_tokens=d_region, prefix_blocks=pb,
+                             k_cand=k_cand, exact=exact))
+        out, self.cache = self._unified_fn(
+            self.params, self.cache, *up[:9], rng, *up[9:],
+            row_tokens=d_region, prefix_blocks=pb, k_cand=k_cand,
+            exact=exact, **gkw,
+        )
         step_timeline.enter("readback")
-        if burst:
-            # ONE pull for the whole burst: turn-0 samples (named as in
-            # the single-turn path — the sel completion below is shared)
-            # plus the on-device-appended scan turns
-            (sampled, lps, cids, clps), (ss, ls, css, cls) = \
-                jax.device_get(out)
-        else:
-            sampled, lps, cids, clps = jax.device_get(out)
+        sampled, lps, cids, clps = jax.device_get(out)  # one batched pull
         self.device_gets += 1
         step_timeline.enter("host_post")
         self.steps += 1
         self.prefill_steps += 1
-        self.decode_steps += k_steps
+        self.decode_steps += 1
         take_sum = sum(take for _, take, _ in sel)
         self.prompt_tokens_computed += take_sum
         self.prefill_dispatches += 1
@@ -2397,44 +2118,13 @@ class EngineCore:
             decode_rows=n_dec, prefill_tokens=take_sum,
             budget=cfg.prefill_token_budget)
 
-        hits = mis = 0
         for r, req in enumerate(dec):
             want_lp = req.sampling.logprobs or req.sampling.top_logprobs > 0
-            row_len = int(seq_lens[r])  # pre-dispatch total (p + 1)
             self._append_token(
                 req, int(sampled[r]),
                 logprob=float(lps[r]) if want_lp else None,
                 cand=(cids[r], clps[r]) if want_lp else None,
             )
-            if not burst:
-                continue
-            # scan turns: positions at/past the row's block limit wrote
-            # no KV on device, so only `allowed` samples are real
-            allowed = max(0, min(k_steps - 1, dec_limits[r] - row_len))
-            consumed = 0
-            for j in range(allowed):
-                if req.state is not RequestState.RUNNING:
-                    break  # stop fired mid-burst: discard the tail
-                self._append_token(
-                    req, int(ss[j, r]),
-                    logprob=float(ls[j, r]) if want_lp else None,
-                    cand=(css[j, r], cls[j, r]) if want_lp else None,
-                )
-                consumed += 1
-            if req.state is RequestState.RUNNING and allowed < k_steps - 1:
-                # ran out of block-table room mid-burst — same LENGTH
-                # semantics as the pure-decode burst
-                self._cut_short(req)
-            if consumed < allowed:
-                mis += 1  # a stop fired: predicted tail discarded
-            else:
-                hits += 1
-        if burst:
-            self.lookahead_bursts += 1
-            self.lookahead_hits += hits
-            self.lookahead_mispredicts += mis
-            self.lookahead_depth = k_steps
-            lookahead_counters.record_burst(k_steps, hits, mis)
         for j, (req, take, final) in enumerate(sel):
             r = n_dec + j
             req.computed_tokens += take
@@ -2446,135 +2136,12 @@ class EngineCore:
                 )
         return True
 
-    def _alloc_unified_arrays(self, r_pad: int, t_pad: int):
-        """Zero/pad-initialised dispatch operands for one unified turn —
-        shared by the live build and :meth:`_prebuild_next` so a
-        committed speculative build is bit-identical to a fresh one."""
-        m = self.config.max_blocks_per_seq
-        tokens = np.zeros((1, t_pad), np.int32)
-        positions = np.zeros((1, t_pad), np.int32)
-        slot_idx = np.full((1, t_pad), -1, np.int32)
-        seq_ids = np.full((1, t_pad), -1, np.int32)
-        bt = np.zeros((r_pad, m), np.int32)
-        seq_lens = np.zeros(r_pad, np.int32)
-        starts = np.zeros(r_pad, np.int32)
-        roff = np.zeros(r_pad, np.int32)
-        last_idx = np.zeros(r_pad, np.int32)
-        temp = np.zeros(r_pad, np.float32)
-        top_k = np.zeros(r_pad, np.int32)
-        top_p = np.ones(r_pad, np.float32)
-        limits = np.zeros(r_pad, np.int32)
-        return (tokens, positions, slot_idx, seq_ids, bt, seq_lens,
-                starts, roff, last_idx, temp, top_k, top_p, limits)
-
-    def _fill_prefill_span(self, arrays, r: int, off: int,
-                           rq: EngineRequest, begin: int, take: int) -> int:
-        """Fill dispatch row ``r`` with ``rq``'s prefill chunk
-        ``[begin, begin+take)`` starting at flat-axis offset ``off``;
-        returns the next (block-rounded) span offset.  Safe to run
-        speculatively: it reads only ``rq.prompt`` and ``rq.block_ids``,
-        which are immutable while the request sits in PREFILL."""
-        bs = self.config.block_size
-        (tokens, positions, slot_idx, seq_ids, bt, seq_lens, starts,
-         roff, last_idx, temp, top_k, top_p, _limits) = arrays
-        end = begin + take
-        tokens[0, off:off + take] = rq.prompt[begin:end]
-        pos = np.arange(begin, end, dtype=np.int32)
-        positions[0, off:off + take] = pos
-        bt[r, : len(rq.block_ids)] = rq.block_ids
-        slot_idx[0, off:off + take] = bt[r, pos // bs] * bs + pos % bs
-        seq_ids[0, off:off + take] = r
-        seq_lens[r] = end
-        starts[r] = begin
-        roff[r] = off
-        last_idx[r] = off + take - 1
-        temp[r] = rq.sampling.temperature
-        top_k[r] = rq.sampling.top_k
-        top_p[r] = rq.sampling.top_p
-        return off + -(-take // bs) * bs
-
-    def _prebuild_next(self, ready, sel, dec, d_region: int,
-                       budget: int) -> Optional[dict]:
-        """Speculatively build the NEXT unified turn's prefill-span
-        operands while the device computes the current one (the overlap
-        window between the dispatch call and its device_get).
-
-        Prediction model: this turn's selected chunks land (their
-        effects are deterministic — ``computed_tokens`` advances by
-        ``take``), every decode row survives the turn (exactly one
-        token, no stop fires), finals join the decode set, and no
-        admission or finish changes the slot map.  The returned dict's
-        ``key`` pins that prediction; the next :meth:`_run_unified`
-        commits the arrays when its actual plan matches and flushes
-        them otherwise.  Only the O(t_pad) prefill-span work is
-        prebuilt — decode-row scalars advance every turn and are always
-        refilled at commit time, so a committed build needs no
-        patching."""
-        cfg = self.config
-        bs = cfg.block_size
-        sel_map = {rq.request_id: (take, fin) for rq, take, fin in sel}
-        nxt = []  # (req, predicted next begin) — ready order preserved
-        for rq in ready:
-            take, fin = sel_map.get(rq.request_id, (0, False))
-            if fin:
-                continue  # completes this turn: joins the decode set
-            nxt.append((rq, rq.computed_tokens + take))
-        if not nxt:
-            return None  # no prefill survives: next turn isn't mixed
-        # predicted packing — same selection loop as the live build,
-        # over the predicted begins
-        plan = []
-        used = 0
-        for rq, begin in nxt:
-            avail = budget - used
-            if avail < bs:
-                break
-            remaining = rq.prompt_len - begin
-            chunk = cfg.prefill_chunk_tokens or remaining
-            take = min(remaining, chunk, avail)
-            if take < remaining:
-                take = take // bs * bs
-                if take == 0:
-                    break
-            plan.append((rq, begin, take, take == remaining))
-            used += -(-take // bs) * bs
-        if not plan:
-            return None
-        dec_ids = {r.request_id for r in dec}
-        fin_ids = {rq.request_id for rq, _, fin in sel if fin}
-        pred_dec = [
-            r.request_id for r in self.slots
-            if r is not None
-            and (r.request_id in dec_ids or r.request_id in fin_ids)
-        ]
-        n_dec = len(pred_dec)
-        r_real = n_dec + len(plan)
-        r_pad = 1 << max(0, (r_real - 1).bit_length())
-        t_pad = cfg.bucket_for(d_region + used)
-        arrays = self._alloc_unified_arrays(r_pad, t_pad)
-        off = d_region
-        max_pb = 0
-        for j, (rq, begin, take, _fin) in enumerate(plan):
-            off = self._fill_prefill_span(arrays, n_dec + j, off, rq,
-                                          begin, take)
-            max_pb = max(max_pb, begin // bs)
-        key = (tuple(pred_dec),
-               tuple((rq.request_id, begin, take, fin)
-                     for rq, begin, take, fin in plan),
-               d_region, r_pad, t_pad)
-        return dict(key=key, arrays=arrays, max_pb=max_pb)
-
-    def _unified_penalties(self, samp, r_pad: int, horizon: int = 1) -> dict:
+    def _unified_penalties(self, samp, r_pad: int) -> dict:
         """Penalty buffers for one unified dispatch, keyed by DISPATCH
         row (cf. :meth:`_penalty_buffers`, which keys by slot): a
         [R_pad, T] generated-token buffer + first-occurrence mask +
         per-row strengths.  {} when no sampling row uses penalties, so
         the common case compiles no extra executables.
-
-        ``horizon`` > 1 sizes the buffer for a fused burst (the scan
-        appends up to ``horizon`` tokens per row on device) and adds
-        the per-row ``pen_cursor`` write index; ``horizon`` == 1 keeps
-        the single-turn buffer shape (and its trace keys) unchanged.
 
         The host build is cached on (rows, shapes, live request set +
         penalty strengths): while the plan is stable, only the tokens
@@ -2589,8 +2156,7 @@ class EngineCore:
             return {}
         longest = max(rq.seq.total_tokens - rq.prompt_len
                       for _, rq in users)
-        need = longest if horizon <= 1 else longest + horizon
-        t_cap = max(16, 1 << max(0, need - 1).bit_length())
+        t_cap = max(16, 1 << max(0, longest - 1).bit_length())
         t_cap = min(t_cap, max(
             16, 1 << (self.config.max_model_len - 1).bit_length()))
         key = (r_pad, t_cap, tuple(
@@ -2636,12 +2202,6 @@ class EngineCore:
             self._pen_cache = dict(key=key, out=dict(out), ptoks=ptoks,
                                    pfirst=pfirst, seen=seen_map,
                                    count=count_map)
-        if horizon > 1:
-            # fused burst: the device appends past this cursor per turn
-            cur = np.zeros(r_pad, np.int32)
-            for r, rq in users:
-                cur[r] = min(rq.seq.total_tokens - rq.prompt_len, t_cap)
-            out["pen_cursor"] = cur
         return out
 
     # ------------------------------------------------ seq-parallel prefill
@@ -2941,8 +2501,8 @@ class EngineCore:
         still in flight, a row of that decode takes its token on the
         device (``carry_rows``) and is built for length + 1; a row that
         ends in the one in flight by ``max_tokens`` or ``max_model_len``
-        is left out.  ``lookahead_dispatch`` and ``decode_steps`` > 1 are
-        other mechanisms (see :meth:`step`)."""
+        is left out.  ``decode_steps`` > 1 is another mechanism (see
+        :meth:`step`)."""
         cfg = self.config
         if cfg.spec_tokens > 0 and self._try_spec_decode():
             return

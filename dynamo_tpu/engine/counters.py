@@ -12,12 +12,6 @@ benchmarks read — no import cycles.  The HTTP metrics endpoint exposes:
     dynamo_tpu_engine_unified_decode_rows_total    counter
     dynamo_tpu_engine_unified_prefill_tokens_total counter
     dynamo_tpu_engine_unified_budget_utilization   gauge (used/offered)
-    dynamo_tpu_engine_lookahead_bursts_total       counter
-    dynamo_tpu_engine_lookahead_hits_total         counter
-    dynamo_tpu_engine_lookahead_mispredicts_total  counter
-    dynamo_tpu_engine_lookahead_commits_total      counter
-    dynamo_tpu_engine_lookahead_flushes_total      counter
-    dynamo_tpu_engine_lookahead_dispatch_depth     gauge (turns/device_get)
 
 The ``unified_*`` family counts the mixed prefill+decode dispatches of
 the unified token-budget scheduler (engine/core.py ``_run_unified``):
@@ -31,7 +25,6 @@ from __future__ import annotations
 __all__ = ["PrefillCounters", "counters", "PersistCounters", "persist_counters",
            "KvStreamCounters", "kv_stream_counters",
            "KvShardCounters", "kv_shard_counters",
-           "LookaheadCounters", "lookahead_counters",
            "RequestCounters", "request_counters"]
 
 
@@ -268,69 +261,6 @@ class KvShardCounters:
 
 
 kv_shard_counters = KvShardCounters()
-
-
-class LookaheadCounters:
-    """Double-buffered dispatch (engine/core.py lookahead scheduler)
-    counters.
-
-        dynamo_tpu_engine_lookahead_bursts_total       counter (fused
-                                                       multi-turn dispatches)
-        dynamo_tpu_engine_lookahead_hits_total         counter (burst rows
-                                                       whose predicted token
-                                                       count held to the end)
-        dynamo_tpu_engine_lookahead_mispredicts_total  counter (rows where a
-                                                       stop fired mid-burst
-                                                       and the tail was
-                                                       discarded)
-        dynamo_tpu_engine_lookahead_commits_total      counter (speculative
-                                                       next-turn builds
-                                                       committed as-is)
-        dynamo_tpu_engine_lookahead_flushes_total      counter (speculative
-                                                       builds discarded —
-                                                       admission/finish
-                                                       changed the plan)
-        dynamo_tpu_engine_lookahead_dispatch_depth     gauge (device turns
-                                                       folded per device_get,
-                                                       last burst)
-
-    A *burst* is one fused dispatch that runs ``depth`` unified turns
-    on-device with a single trailing ``jax.device_get`` — the
-    prediction being that every active decode row yields exactly one
-    token per turn unless a stop fires.  ``hits``/``mispredicts``
-    count rows, ``commits``/``flushes`` count speculative host-side
-    prebuilds of the *next* turn's dispatch operands.
-    """
-
-    def __init__(self) -> None:
-        self.reset()
-
-    def record_burst(self, depth: int, hits: int, mispredicts: int) -> None:
-        """One fused burst landed: ``depth`` device turns folded into
-        one device_get; ``hits`` rows consumed every predicted token,
-        ``mispredicts`` rows stopped mid-burst (tail discarded)."""
-        self.bursts_total += 1
-        self.hits_total += hits
-        self.mispredicts_total += mispredicts
-        self.dispatch_depth = depth
-
-    def record_commit(self) -> None:
-        self.commits_total += 1
-
-    def record_flush(self) -> None:
-        self.flushes_total += 1
-
-    def reset(self) -> None:
-        """Test isolation hook — the counters are process-global."""
-        self.bursts_total = 0
-        self.hits_total = 0
-        self.mispredicts_total = 0
-        self.commits_total = 0
-        self.flushes_total = 0
-        self.dispatch_depth = 0
-
-
-lookahead_counters = LookaheadCounters()
 
 
 class RequestCounters:

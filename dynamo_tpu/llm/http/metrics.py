@@ -21,8 +21,8 @@ from typing import Iterator
 
 from dynamo_tpu.engine.counters import counters as prefill_counters
 from dynamo_tpu.engine.counters import (kv_shard_counters, kv_stream_counters,
-                                        lookahead_counters, mesh_shape,
-                                        persist_counters, request_counters)
+                                        mesh_shape, persist_counters,
+                                        request_counters)
 from dynamo_tpu.fault.counters import counters as fault_counters
 from dynamo_tpu.obs.costs import transfer_costs
 from dynamo_tpu.obs.metric_names import EngineMetric as EM
@@ -169,23 +169,6 @@ class Metrics:
         lines.append(f"# TYPE {EM.UNIFIED_BUDGET_UTILIZATION} gauge")
         lines.append(f"{EM.UNIFIED_BUDGET_UTILIZATION} "
                      f"{round(prefill_counters.unified_budget_utilization, 6)}")
-        # double-buffered dispatch (lookahead scheduler): fused bursts,
-        # per-row prediction hit/mispredict split, speculative next-turn
-        # prebuild commits/flushes, and the depth of the last burst
-        lc = lookahead_counters
-        lines.append(f"# TYPE {EM.LOOKAHEAD_BURSTS_TOTAL} counter")
-        lines.append(f"{EM.LOOKAHEAD_BURSTS_TOTAL} {lc.bursts_total}")
-        lines.append(f"# TYPE {EM.LOOKAHEAD_HITS_TOTAL} counter")
-        lines.append(f"{EM.LOOKAHEAD_HITS_TOTAL} {lc.hits_total}")
-        lines.append(f"# TYPE {EM.LOOKAHEAD_MISPREDICTS_TOTAL} counter")
-        lines.append(f"{EM.LOOKAHEAD_MISPREDICTS_TOTAL} "
-                     f"{lc.mispredicts_total}")
-        lines.append(f"# TYPE {EM.LOOKAHEAD_COMMITS_TOTAL} counter")
-        lines.append(f"{EM.LOOKAHEAD_COMMITS_TOTAL} {lc.commits_total}")
-        lines.append(f"# TYPE {EM.LOOKAHEAD_FLUSHES_TOTAL} counter")
-        lines.append(f"{EM.LOOKAHEAD_FLUSHES_TOTAL} {lc.flushes_total}")
-        lines.append(f"# TYPE {EM.LOOKAHEAD_DISPATCH_DEPTH} gauge")
-        lines.append(f"{EM.LOOKAHEAD_DISPATCH_DEPTH} {lc.dispatch_depth}")
         # persistent prefix-cache tier (llm/kv/persist.py): blocks/tokens
         # restored from disk instead of re-prefilled, spill volume, and
         # the store's current footprint

@@ -64,8 +64,8 @@ class FaultMetric:
 
 
 class EngineMetric:
-    """Engine plane: prefill batching, unified dispatch, lookahead,
-    persist tier (``engine/counters.py``) and the step timeline
+    """Engine plane: prefill batching, unified dispatch, persist tier
+    (``engine/counters.py``) and the step timeline
     (``obs/timeline.py``)."""
 
     PREFILL_DISPATCHES_TOTAL = "dynamo_tpu_engine_prefill_dispatches_total"
@@ -79,13 +79,6 @@ class EngineMetric:
         "dynamo_tpu_engine_unified_prefill_tokens_total")
     UNIFIED_BUDGET_UTILIZATION = (
         "dynamo_tpu_engine_unified_budget_utilization")
-    LOOKAHEAD_BURSTS_TOTAL = "dynamo_tpu_engine_lookahead_bursts_total"
-    LOOKAHEAD_HITS_TOTAL = "dynamo_tpu_engine_lookahead_hits_total"
-    LOOKAHEAD_MISPREDICTS_TOTAL = (
-        "dynamo_tpu_engine_lookahead_mispredicts_total")
-    LOOKAHEAD_COMMITS_TOTAL = "dynamo_tpu_engine_lookahead_commits_total"
-    LOOKAHEAD_FLUSHES_TOTAL = "dynamo_tpu_engine_lookahead_flushes_total"
-    LOOKAHEAD_DISPATCH_DEPTH = "dynamo_tpu_engine_lookahead_dispatch_depth"
     PERSIST_HITS_TOTAL = "dynamo_tpu_engine_persist_hits_total"
     PERSIST_MISSES_TOTAL = "dynamo_tpu_engine_persist_misses_total"
     PERSIST_RESTORED_TOKENS_TOTAL = (
@@ -203,12 +196,6 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.UNIFIED_DECODE_ROWS_TOTAL: ("counter", ()),
     EngineMetric.UNIFIED_PREFILL_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.UNIFIED_BUDGET_UTILIZATION: ("gauge", ()),
-    EngineMetric.LOOKAHEAD_BURSTS_TOTAL: ("counter", ()),
-    EngineMetric.LOOKAHEAD_HITS_TOTAL: ("counter", ()),
-    EngineMetric.LOOKAHEAD_MISPREDICTS_TOTAL: ("counter", ()),
-    EngineMetric.LOOKAHEAD_COMMITS_TOTAL: ("counter", ()),
-    EngineMetric.LOOKAHEAD_FLUSHES_TOTAL: ("counter", ()),
-    EngineMetric.LOOKAHEAD_DISPATCH_DEPTH: ("gauge", ()),
     EngineMetric.PERSIST_HITS_TOTAL: ("counter", ()),
     EngineMetric.PERSIST_MISSES_TOTAL: ("counter", ()),
     EngineMetric.PERSIST_RESTORED_TOKENS_TOTAL: ("counter", ()),

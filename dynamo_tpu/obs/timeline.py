@@ -14,10 +14,7 @@ and *then* reads back and finishes dispatch N.  So the ``readback`` and
 before issued, and they run while the device executes the one this step
 issued.  A step that cannot issue ahead reads back first and then issues
 (the old order, all of one dispatch); one with nothing to issue only
-finishes.  Phase names, their order within one dispatch's life and the
-phase-sum invariant are unchanged.  This is not ``lookahead_dispatch``,
-which fuses k unified turns into one program and fills the ``overlap``
-phase.
+finishes.  That is the one overlap of host and device the engine has.
 
 Phases (in step order; the profiler span of each is ``dyn.<phase>``):
 
@@ -29,11 +26,6 @@ Phases (in step order; the profiler span of each is ``dyn.<phase>``):
     upload            the ONE batched jax.device_put per dispatch
     dispatch          the jitted call itself (trace/en-queue; on CPU
                       backends this includes compute)
-    overlap           host work performed *while the device computes*
-                      (lookahead dispatch: next-turn speculative build
-                      + waiting-queue drain between dispatch and
-                      readback) — concurrent with device time, so it
-                      is excluded from the host gap
     readback          jax.device_get — blocks until device compute
                       lands, so device time not overlapped with host
                       work shows up here (of the dispatch issued the step
@@ -45,42 +37,41 @@ aggregates and, while a ``jax.profiler`` session is open, one
 ``TraceAnnotation("dyn.<phase>", step=, kind=, t_mono_ns=)`` per phase on
 the engine thread (one open at a time, never one around the whole step:
 phases stay leaves at the depth of their call site).  ``step`` is the
-busy-step index, ``kind`` the dispatch kind on dispatch/overlap/readback,
+busy-step index, ``kind`` the dispatch kind on dispatch/readback,
 and ``t_mono_ns`` is ``time.monotonic_ns()`` at the open: trace time −
 ``t_mono_ns`` is the offset that puts anything stamped with
 ``time.monotonic`` on the device trace's axis.  With no session open a
 phase costs a flag test (``TraceAnnotation.is_enabled()``) and no object.
 
 The headline derived number is **host_gap_ms_per_turn** — wall time
-per dispatching step spent *outside* dispatch+overlap+readback: the
-host's own work per step.  Its definition has not changed, its meaning
-has: while a dispatch is in flight that time runs under the device
-program (hidden), and it is device time lost only in the steps that read
-back first.  The
-aggregates are always on: per busy step about twenty clock reads, two
-small dicts and a handful of float adds; per-step *spans* of the dtspan
-plane are emitted only when that plane is enabled.
+per dispatching step spent *outside* dispatch+readback: the host's own
+work per step.  While a dispatch is in flight that time runs under the
+device program (hidden), and it is device time lost only in the steps
+that read back first.  The aggregates are always on: per busy step about
+twenty clock reads, two small dicts and a handful of float adds;
+per-step *spans* of the dtspan plane are emitted only when that plane is
+enabled.
 
 ``enter("dispatch", kind=...)`` names the **dispatch kind** (``step``,
-``decode_multi``, ``prefill_ragged``, ``unified``, ``unified_burst``,
-``sp_prefill``, ``spec_verify``).  ``dispatch_kinds[kind].seconds`` is
-**device-facing** time: the dispatch phase plus the overlap and readback
-phases that follow it, i.e. enqueue → readback returned — not the enqueue
-alone, which on an asynchronous backend is a few hundred microseconds
-whatever the program costs.  ``enter("readback", kind=..., issued=False)``
-books the readback of a dispatch an earlier step issued to that
-dispatch's kind and counts no dispatch.  It is the denominator of the dtperf
-predicted-vs-measured gauge (``obs/perfmodel.py``).  At ``end`` a busy
-step's wall, and its device-facing part (wall − host gap), are added to a
-**class** — ``prefill`` (``step``, ``prefill_ragged``, ``sp_prefill``),
-``decode`` (``decode_multi``, ``spec_verify``) or ``mixed`` (``unified``,
-``unified_burst``, several classes in one step, or no kind at all) — the
-class of the dispatch the step **issued**, or, when it issued none, of
-what it finished; so the class walls add up to ``wall_seconds_total`` and
-a class's steps count its dispatches.  When the dtspan plane
-is enabled, ``end`` also emits one ``engine.step`` span per busy step
-carrying the phase breakdown and the roofline-predicted dispatch
-envelope, which the Chrome export renders as a predicted-vs-measured
+``decode_multi``, ``prefill_ragged``, ``unified``, ``sp_prefill``,
+``spec_verify``).  ``dispatch_kinds[kind].seconds`` is **device-facing**
+time: the dispatch phase plus the readback phase that follows it, i.e.
+enqueue → readback returned — not the enqueue alone, which on an
+asynchronous backend is a few hundred microseconds whatever the program
+costs.  ``enter("readback", kind=..., issued=False)`` books the readback
+of a dispatch an earlier step issued to that dispatch's kind and counts
+no dispatch.  It is the denominator of the dtperf predicted-vs-measured
+gauge (``obs/perfmodel.py``).  At ``end`` a busy step's wall, and its
+device-facing part (wall − host gap), are added to a **class** —
+``prefill`` (``step``, ``prefill_ragged``, ``sp_prefill``), ``decode``
+(``decode_multi``, ``spec_verify``) or ``mixed`` (``unified``, several
+classes in one step, or no kind at all) — the class of the dispatch the
+step **issued**, or, when it issued none, of what it finished; so the
+class walls add up to ``wall_seconds_total`` and a class's steps count
+its dispatches.  When the dtspan plane is enabled, ``end`` also emits one
+``engine.step`` span per busy step carrying the phase breakdown and the
+roofline-predicted dispatch envelope, which the Chrome export renders as
+a predicted-vs-measured
 counter track.
 """
 
@@ -100,7 +91,6 @@ PHASES = (
     "host_build",
     "upload",
     "dispatch",
-    "overlap",
     "readback",
     "host_post",
 )
@@ -108,7 +98,7 @@ PHASES = (
 _DISPATCH_PHASES = ("upload", "dispatch", "readback")
 # enqueue -> readback returned: what a dispatch kind's seconds cover, and
 # what the host gap leaves out
-_DEVICE_FACING = ("dispatch", "overlap", "readback")
+_DEVICE_FACING = ("dispatch", "readback")
 _SPAN_NAMES = {p: f"dyn.{p}" for p in PHASES}
 
 CLASSES = ("prefill", "decode", "mixed")
@@ -119,7 +109,6 @@ KIND_CLASS = {
     "decode_multi": "decode",
     "spec_verify": "decode",
     "unified": "mixed",
-    "unified_burst": "mixed",
 }
 
 
@@ -151,7 +140,7 @@ class StepTimeline:
         self.busy_steps_total = 0     # steps that ran >= 1 device dispatch
         self.wall_s_total = 0.0       # busy-step wall time
         self.phase_s_total = {p: 0.0 for p in PHASES}
-        self.host_gap_s_total = 0.0   # busy wall - dispatch-overlap-readback
+        self.host_gap_s_total = 0.0   # busy wall - dispatch - readback
         self.ewma_wall_s = 0.0
         self.ewma_host_gap_s = 0.0
         # device-facing seconds (dispatch -> readback returned) split by
@@ -191,8 +180,8 @@ class StepTimeline:
     def enter(self, phase: str, kind: Optional[str] = None,
               issued: bool = True) -> None:
         """Close the open phase and open ``phase``.  ``kind`` (on
-        ``dispatch``) names the jitted entrypoint; the overlap and
-        readback that follow are booked to it too.  ``issued=False`` (on
+        ``dispatch``) names the jitted entrypoint; the readback that
+        follows is booked to it too.  ``issued=False`` (on
         the ``readback`` of a dispatch that may be an earlier step's)
         books to ``kind`` and counts no dispatch."""
         if self._t0 is None:

@@ -26,7 +26,7 @@ def describe_batch(stats):
 
 
 def burst_decode(step_fn, state, rng_keys):
-    # the fused-burst idiom (engine/core.py unified_burst_step): k
+    # the fused-burst idiom (engine/core.py multi_decode_step): k
     # device turns accumulate under one scan, the host sees ONE
     # trailing pull for the whole burst
     state, samples = jax.lax.scan(step_fn, state, rng_keys)

@@ -57,7 +57,6 @@ def reset_producers() -> None:
     same singletons the tier-1 tests isolate against)."""
     from dynamo_tpu.engine.counters import (counters, kv_shard_counters,
                                             kv_stream_counters,
-                                            lookahead_counters,
                                             mesh_shape,
                                             persist_counters,
                                             request_counters)
@@ -67,7 +66,7 @@ def reset_producers() -> None:
     from dynamo_tpu.obs.timeline import step_timeline
 
     for c in (counters, persist_counters, kv_stream_counters,
-              kv_shard_counters, lookahead_counters, request_counters,
+              kv_shard_counters, request_counters,
               fault_counters, transfer_costs, perf_model):
         c.reset()
     mesh_shape.update(tp=1, devices=1)
@@ -80,7 +79,6 @@ def seed_http_metrics():
     seeded ``Metrics`` instance (render via ``render_http``)."""
     from dynamo_tpu.engine.counters import (counters, kv_shard_counters,
                                             kv_stream_counters,
-                                            lookahead_counters,
                                             mesh_shape,
                                             persist_counters,
                                             request_counters)
@@ -113,10 +111,6 @@ def seed_http_metrics():
     counters.record(4, 96, budget=128)
     counters.record(2, 64, budget=128)
     counters.record_unified(6, 90, 128)
-    lookahead_counters.record_burst(depth=4, hits=6, mispredicts=2)
-    lookahead_counters.record_commit()
-    lookahead_counters.record_commit()
-    lookahead_counters.record_flush()
     request_counters.record_decode(12)
     request_counters.record_decode(11)
     request_counters.record_finish()

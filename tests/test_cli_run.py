@@ -71,6 +71,23 @@ def test_run_text_tpu_engine(model_dir):
     assert out.stdout.strip(), "no generated text on stdout"
 
 
+def test_the_lookahead_flag_is_refused(model_dir):
+    """The lookahead scheduler is gone and its name is still accepted
+    (the benchmark passes it): a server must not start in silence on a
+    flag that does nothing, so the config refuses it and says what hides
+    the host now, and ``run`` exits non-zero."""
+    from dynamo_tpu.engine import EngineConfig
+
+    assert EngineConfig().lookahead_dispatch is False
+    with pytest.raises(ValueError, match="dispatch-ahead"):
+        EngineConfig(lookahead_dispatch=True)
+    out = _run(["run", "in=text:hello", "out=tpu", "--model-path",
+                str(model_dir), "--max-tokens", "2", "--lookahead-dispatch"])
+    assert out.returncode != 0
+    assert "dispatch-ahead" in out.stderr
+    assert not out.stdout.strip()
+
+
 def test_worker_config_kv_quant_and_sp_reach_engine(model_dir):
     """The example-graph worker config keys `kv-quant` and
     `sp-prefill-threshold` (multinode-70b/moe.yaml) flow through

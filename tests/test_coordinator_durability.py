@@ -383,10 +383,17 @@ def test_disagg_queued_prefill_survives_restart(tmp_path):
             await worker.start()
             expected = await drain(reference_engine, prompt, 6)
 
-            # request stalls in REMOTE_PREFILL (no prefill worker yet);
-            # its queue push is in the WAL
+            # request stalls in REMOTE_PREFILL (no prefill worker yet).
+            # Wait for its queue push to reach the coordinator, and so the
+            # WAL, not for a fixed time: on a loaded machine the push can
+            # take longer than any sleep, and a push into the stopped
+            # coordinator is a ConnectionError, not a redelivery
             task = asyncio.ensure_future(drain(worker, prompt, 6))
-            await asyncio.sleep(0.5)
+            for _ in range(1200):
+                if any(srv._queues.values()) or task.done():
+                    break
+                await asyncio.sleep(0.05)
+            assert any(srv._queues.values()), "prefill was never queued"
             assert not task.done()
 
             # coordinator crashes and restarts

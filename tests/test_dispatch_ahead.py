@@ -101,17 +101,25 @@ SAMPLINGS = {
     "greedy": dict(temperature=0.0),
     "seeded": dict(temperature=0.8, top_p=0.9, seed=1234),
 }
+# the cache as the model's dtype and as int8 K/V with per-block scales
+# (``--kv-cache-dtype int8``): a carried row reads what the decode in
+# flight wrote, quantised or not
+CACHES = pytest.mark.parametrize("cache_dtype", [None, "int8"],
+                                 ids=["native", "int8"])
 
 
 # ----------------------------- (a) a full batch running to max_tokens
 @pytest.mark.parametrize("budget", [0, 64], ids=["legacy", "ragged"])
 @pytest.mark.parametrize("rows", [1, 4])
 @pytest.mark.parametrize("kind", sorted(SAMPLINGS))
-def test_streams_equal_the_serial_engine(tiny, kind, rows, budget):
+@CACHES
+def test_streams_equal_the_serial_engine(tiny, cache_dtype, kind, rows,
+                                         budget):
     got = {}
     for serial in (True, False):
         core = make_core(tiny, serial=serial, prefill_chunk_tokens=16,
-                         prefill_token_budget=budget)
+                         prefill_token_budget=budget,
+                         cache_dtype=cache_dtype)
         outs = [submit(core, f"r{i}", prompt(9 + 11 * i, i),
                        SamplingOptions(logprobs=True, top_logprobs=2,
                                        **SAMPLINGS[kind]),
@@ -148,11 +156,13 @@ def test_unseeded_sampling_keeps_its_key_sequence(tiny):
 # --------------- (b) rows joining from prefill mid-stream, chunked prompts too
 @pytest.mark.parametrize("budget", [0, 64], ids=["legacy", "ragged"])
 @pytest.mark.parametrize("kind", sorted(SAMPLINGS))
-def test_rows_that_join_mid_stream(tiny, kind, budget):
+@CACHES
+def test_rows_that_join_mid_stream(tiny, cache_dtype, kind, budget):
     got = {}
     for serial in (True, False):
         core = make_core(tiny, serial=serial, prefill_chunk_tokens=16,
-                         prefill_token_budget=budget)
+                         prefill_token_budget=budget,
+                         cache_dtype=cache_dtype)
         sampling = SamplingOptions(logprobs=True, top_logprobs=2,
                                    **SAMPLINGS[kind])
         outs = [submit(core, "a", prompt(9, 1), sampling, max_tokens=30),

@@ -97,10 +97,6 @@ def test_prefill_dispatch_stats_round_trip():
         "unified_decode_rows_per_dispatch": 6.0,
         "unified_prefill_tokens_per_dispatch": 90.0,
         "unified_budget_utilization": 0.75,
-        "lookahead_bursts": 1,
-        "lookahead_dispatch_depth": 4,
-        "lookahead_hit_rate": 0.75,
-        "lookahead_commit_rate": 0.6667,
         "persist_hits": 2,
         "persist_hit_rate": 0.6667,
         "persist_restored_tokens": 32,

@@ -11,6 +11,7 @@ tests/lint_fixtures/proto/.
 import argparse
 import io
 import json
+import sys
 import time
 from pathlib import Path
 
@@ -243,10 +244,15 @@ def test_fixture_inventory():
                          ids=lambda p: p.stem)
 def test_golden_fixture_replays(path):
     """Each committed replay token still reproduces its recorded
-    outcome and violation set against today's protocol code."""
+    outcome and violation set against today's protocol code.  A token
+    indexes DetLoop's ready list, whose content is asyncio's: it holds
+    for the minor version of Python it was recorded under
+    (tests/lint_fixtures/proto/record.py records them anew)."""
     doc = json.loads(path.read_text())
     r = replay_token(doc["token"])
-    assert r.outcome == doc["expect"]["outcome"], doc["name"]
+    assert r.outcome == doc["expect"]["outcome"], (
+        f"{doc['name']}: {r.error} (recorded under Python "
+        f"{doc['recorded_with']}, run under {sys.version.split()[0]})")
     assert sorted({v for v, _ in r.violations}) == \
         doc["expect"]["violations"], doc["name"]
 

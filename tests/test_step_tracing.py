@@ -57,7 +57,7 @@ class MarkTimeline:
         self.busy += 1
         self.wall += wall
         self.gap += wall - sum(self.cur.get(p, 0.0) for p in
-                               ("dispatch", "overlap", "readback"))
+                               ("dispatch", "readback"))
         for p, v in self.cur.items():
             self.phases[p] += v
 
@@ -66,8 +66,8 @@ class MarkTimeline:
 STEP = [("kv_spill_restore", 0.0001), ("host_ops", 0.0002),
         ("admission", 0.0003), ("host_build", 0.002),
         ("kv_spill_restore", 0.0004), ("host_build", 0.001),
-        ("upload", 0.0007), ("dispatch", 0.0005), ("overlap", 0.003),
-        ("readback", 0.021), ("host_post", 0.0015)]
+        ("upload", 0.0007), ("dispatch", 0.0005), ("readback", 0.021),
+        ("host_post", 0.0015)]
 IDLE = [("kv_spill_restore", 0.0001), ("host_ops", 0.0001),
         ("admission", 0.0001), ("host_build", 0.0002)]
 
@@ -110,7 +110,7 @@ def test_readback_lands_on_the_preceding_dispatch_kind(kind):
     tl = StepTimeline(clock=clock)
     play(tl, clock, STEP, kind=kind)
     snap = tl.snapshot()
-    facing = 0.0005 + 0.003 + 0.021      # dispatch + overlap + readback
+    facing = 0.0005 + 0.021      # dispatch + readback
     assert snap["dispatch_kinds"] == {
         kind: {"seconds": pytest.approx(facing), "count": 1}}
     cls = KIND_CLASS[kind]
@@ -362,8 +362,7 @@ MODULE_NAMES = {"step": ("_step_fn", "jit__step_impl"),
                 "decode_multi": ("_multi_fn", "jit__multi_impl"),
                 "spec_verify": ("_spec_fn", "jit__spec_impl"),
                 "prefill_ragged": ("_ragged_fn", "jit__ragged_impl"),
-                "unified": ("_unified_fn", "jit__unified_impl"),
-                "unified_burst": ("_burst_fn", "jit__burst_impl")}
+                "unified": ("_unified_fn", "jit__unified_impl")}
 
 
 @pytest.mark.parametrize("kind", sorted(MODULE_NAMES))
