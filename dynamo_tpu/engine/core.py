@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dynamo_tpu.engine import operands
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.counters import counters as prefill_counters
 from dynamo_tpu.engine.counters import mesh_shape, request_counters
@@ -289,6 +290,17 @@ def unified_token_step(
     return out, cache
 
 
+@functools.partial(jax.jit, static_argnames=("layout",))
+def operand_prologue(key, bufs, *, layout):
+    """Under a mesh, ahead of every serving program: the next key of the
+    chain, this dispatch's key, and the dispatch's small operands out of
+    their two buffers (``EngineCore._upload_dispatch``) — one small program
+    a dispatch where ``jax.random.split`` and the unpacking of its result
+    were two."""
+    key, dispatch_key = jax.random.split(key)
+    return key, dispatch_key, operands.unpack(bufs, layout)
+
+
 @dataclasses.dataclass
 class _Inflight:
     """A dispatch that has been issued and not read back: its outputs
@@ -440,14 +452,12 @@ class EngineCore:
         if mesh is None:
             cache = make_cache()
         else:
-            from jax.sharding import NamedSharding
-
             from dynamo_tpu.models.quant import align_specs, prune_specs
 
             params = jax.device_put(
                 params,
                 jax.tree.map(
-                    lambda s: NamedSharding(mesh, s),
+                    lambda s: jax.sharding.NamedSharding(mesh, s),
                     align_specs(params, prune_specs(
                         params, model.partition_specs(), mesh)),
                     is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec),
@@ -471,7 +481,18 @@ class EngineCore:
         self.mesh_devices = 1 if mesh is None else mesh.size
         mesh_shape.update(tp=self.mesh_tp, devices=self.mesh_devices)
 
-        self._rng = jax.random.PRNGKey(config.seed)
+        # where a dispatch's small operands go under a mesh
+        # (``_upload_dispatch``): replicated over it, the layout the jitted
+        # serving calls are compiled for, so that a call re-lays nothing
+        # out; None with no mesh (the default device, uncommitted)
+        self._operand_sharding = None if mesh is None else (
+            jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
+        self.operand_buffers = 0         # buffers put x devices put to
+
+        # an operand like the others: under a mesh it lives where they go,
+        # and is split there by the program that takes the two buffers apart
+        self._rng = jax.device_put(
+            jax.random.PRNGKey(config.seed), self._operand_sharding)
 
         def under_mesh(impl):
             """``impl`` traced with the engine's mesh in scope: the
@@ -912,7 +933,9 @@ class EngineCore:
             if len(self._gdev_cache) >= 8:
                 self._gdev_cache.clear()
             self._gdev_cache[keys] = (
-                device_tables(comp, self.model.config.vocab_size),
+                jax.device_put(
+                    device_tables(comp, self.model.config.vocab_size),
+                    self._operand_sharding),
                 dict(zip(keys, offs)),
             )
         return self._gdev_cache[keys]
@@ -1002,19 +1025,44 @@ class EngineCore:
             exact = True
         return k_cand, exact
 
-    @staticmethod
-    def _upload_dispatch(host_args, gkw=None):
+    def _upload_dispatch(self, host_args, gkw=None):
         """ONE batched host->device upload for a dispatch's small
         operands — positional AND grammar/extras rows (per-array
         jnp.asarray would issue a transfer round trip each; per-transfer
-        latency is the cost that matters on a remote-attached chip).
-        Returns (device_args tuple, gkw with its host arrays replaced)."""
+        latency is the cost that matters on a remote-attached chip) — and
+        the dispatch's key, the next of ``jax.random.split``'s chain.
+
+        Under a mesh each array is one transfer *a device*, and an
+        uncommitted array on device 0 is re-laid out over the mesh inside
+        every jitted serving call, argument by argument, on pjit's slow
+        path (on four chips 3 ms of a 25 ms turn with the devices idle).
+        So there the operands travel as two buffers (``operands.pack``: the
+        int32/bool arrays as one, the float32 ones as another), put once,
+        from the host, replicated; ``operand_prologue`` takes them apart on
+        the devices and draws the key, and every operand of the serving
+        call arrives committed in the layout its executable was compiled
+        for.  With no mesh two transfers for nine gain nothing end to end
+        (PERF.md, PRs 32 and 33) and the tree goes up as it is.
+
+        Returns (device_args tuple, the dispatch's key, gkw with its host
+        arrays replaced)."""
         gkw = dict(gkw or {})
         host_kw = {k: v for k, v in gkw.items() if isinstance(v, np.ndarray)}
-        up, up_kw = jax.device_put(
-            (tuple(np.asarray(a) for a in host_args), host_kw))
+        tree = (tuple(np.asarray(a) for a in host_args), host_kw)
+        if self.mesh is None:
+            self._rng, rng = jax.random.split(self._rng)
+            up, up_kw = jax.device_put(tree)
+            put = len(tree[0]) + len(host_kw)
+        else:
+            bufs, layout = operands.pack(tree)
+            bufs = jax.device_put(bufs, self._operand_sharding)
+            self._rng, rng, (up, up_kw) = operand_prologue(
+                self._rng, bufs, layout=layout)
+            put = len(bufs) * self.mesh_devices
+        self.operand_buffers += put
+        request_counters.record_operands(put)
         gkw.update(up_kw)
-        return up, gkw
+        return up, rng, gkw
 
     def _run_step(self, tokens, positions, block_tables, seq_lens, slot_idx,
                   last_idx, temp, top_k, top_p, prefix_blocks=None,
@@ -1024,11 +1072,10 @@ class EngineCore:
         cand_lps [B,C]) **still on the device**.  Nothing is read back
         here: the caller hands them to :meth:`_settle`, which finishes the
         dispatch issued before this one first."""
-        self._rng, rng = jax.random.split(self._rng)
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
         step_timeline.enter("upload")
-        up, gkw = self._upload_dispatch(
+        up, rng, gkw = self._upload_dispatch(
             (tokens, positions, block_tables, seq_lens, slot_idx, last_idx,
              temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="step")
@@ -1054,7 +1101,6 @@ class EngineCore:
         logprob [K,B], cand_ids [K,B,C], cand_lps [K,B,C]) still on the
         device.  Rows marked in ``carry_rows`` start from the last sample
         of the decode in flight (``multi_decode_step``)."""
-        self._rng, rng = jax.random.split(self._rng)
         use_pen = pen is not None
         host = [tokens, positions, block_tables, seq_lens, limits,
                 temp, top_k, top_p] + (list(pen) if use_pen else [])
@@ -1065,7 +1111,7 @@ class EngineCore:
             self._carry_operand(self._inflight.out[0]) if carry_rows.any()
             else self._no_carry)
         step_timeline.enter("upload")
-        up, gkw = self._upload_dispatch(host, gkw)
+        up, rng, gkw = self._upload_dispatch(host, gkw)
         step_timeline.enter("dispatch", kind="decode_multi")
         up = list(up)
         args = up[:5] + [rng] + up[5:]
@@ -1088,11 +1134,9 @@ class EngineCore:
         """``arr`` placed as a decode's ``sampled`` output is handed to
         the next decode: replicated over the mesh, so that the operand has
         one layout whether it is a real carry or ``_no_carry``."""
-        if self.mesh is None:
-            return arr if isinstance(arr, jax.Array) else jax.device_put(arr)
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        return jax.device_put(arr, NamedSharding(self.mesh, PartitionSpec()))
+        if self.mesh is None and isinstance(arr, jax.Array):
+            return arr
+        return jax.device_put(arr, self._operand_sharding)
 
     def _may_stay_in_flight(self, rec: _Inflight) -> bool:
         """THE rule of dispatch-ahead, asked once a dispatch is issued:
@@ -1274,6 +1318,9 @@ class EngineCore:
             "ahead_dispatches_total": self.ahead_dispatches,
             "ahead_discards_total": self.ahead_discards,
             "pipeline_drains_total": self.pipeline_drains,
+            # host->device buffers the dispatches' operands took: over
+            # prefill + decode dispatches, buffers per dispatch
+            "operand_buffers_total": self.operand_buffers,
             "mesh_tp": self.mesh_tp,
             "mesh_devices": self.mesh_devices,
         }
@@ -1851,11 +1898,10 @@ class EngineCore:
                 final_reqs, rows=[r for r, _ in finals], b=r_pad
             )
 
-        self._rng, rng = jax.random.split(self._rng)
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
         step_timeline.enter("upload")
-        up, gkw = self._upload_dispatch(
+        up, rng, gkw = self._upload_dispatch(
             (tokens, positions, bt, seq_lens, slot_idx, seq_ids, starts,
              roff, last_idx, temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="prefill_ragged")
@@ -2075,11 +2121,10 @@ class EngineCore:
         step_timeline.enter("kv_spill_restore")
         self._drain_offload()
         step_timeline.enter("host_build")
-        self._rng, rng = jax.random.split(self._rng)
         gkw = self._gram_kwargs(gram)
         gkw.update(extras)
         step_timeline.enter("upload")
-        up, gkw = self._upload_dispatch(
+        up, rng, gkw = self._upload_dispatch(
             (tokens, positions, bt, seq_lens, slot_idx, seq_ids, starts,
              roff, last_idx, temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="unified")
@@ -2246,10 +2291,9 @@ class EngineCore:
         # invisible; padding queries produce discarded (finite) rows
         positions = np.arange(s_pad, dtype=np.int32)[None, :]
         last_idx = np.asarray([req.prompt_len - 1], np.int32)
-        self._rng, rng = jax.random.split(self._rng)
         k_cand, exact = self._sampling_mode([req])
         step_timeline.enter("upload")
-        up, _ = self._upload_dispatch((
+        up, rng, _ = self._upload_dispatch((
             tokens, positions, last_idx,
             np.asarray([req.sampling.temperature], np.float32),
             np.asarray([req.sampling.top_k], np.int32),
@@ -2433,10 +2477,9 @@ class EngineCore:
         step_timeline.enter("kv_spill_restore")
         self._drain_offload()
         step_timeline.enter("host_build")
-        self._rng, rng = jax.random.split(self._rng)
         k_cand, exact = self._sampling_mode(rows)
         step_timeline.enter("upload")
-        up, _ = self._upload_dispatch(
+        up, rng, _ = self._upload_dispatch(
             (tokens, positions, bt[:, :m_used], seq_lens, slot_idx,
              temp, top_k, top_p, min_p, seeds, seed_rows))
         step_timeline.enter("dispatch", kind="spec_verify")

@@ -299,6 +299,16 @@ class RequestCounters:
                                                        read back before they
                                                        could issue: why the
                                                        engagement is not 1)
+        dynamo_tpu_engine_operand_buffers_total        counter (host->device
+                                                       buffers the operands
+                                                       of the dispatches
+                                                       took: buffers put x
+                                                       devices put to; over
+                                                       prefill + decode
+                                                       dispatches 2 x devices
+                                                       under a mesh, the
+                                                       number of arrays with
+                                                       none)
     """
 
     def __init__(self) -> None:
@@ -327,6 +337,9 @@ class RequestCounters:
     def record_drain(self) -> None:
         self.pipeline_drains_total += 1
 
+    def record_operands(self, buffers: int) -> None:
+        self.operand_buffers_total += buffers
+
     def reset(self) -> None:
         """Test isolation hook — the counters are process-global."""
         self.decode_dispatches_total = 0
@@ -338,6 +351,7 @@ class RequestCounters:
         self.ahead_dispatches_total = 0
         self.ahead_discards_total = 0
         self.pipeline_drains_total = 0
+        self.operand_buffers_total = 0
 
 
 request_counters = RequestCounters()

@@ -159,7 +159,9 @@ class DraftProposer:
         # ONE batched host->device upload (engine/core.py:_upload_dispatch
         # convention): per-array jnp.asarray would issue seven transfer
         # round trips, and per-transfer latency is the cost that matters
-        # on a remote-attached chip
+        # on a remote-attached chip.  They go where the program runs: the
+        # draft's params and cache are on the default device, not on the
+        # engine's mesh, so the put names no sharding
         up = jax.device_put(
             (tokens, positions, bt, seq_lens, slot_idx, last_idx, active)
         )

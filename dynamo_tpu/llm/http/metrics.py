@@ -293,6 +293,10 @@ class Metrics:
         lines.append(f"{EM.AHEAD_DISCARDS_TOTAL} {rc.ahead_discards_total}")
         lines.append(f"# TYPE {EM.PIPELINE_DRAINS_TOTAL} counter")
         lines.append(f"{EM.PIPELINE_DRAINS_TOTAL} {rc.pipeline_drains_total}")
+        # host->device buffers the dispatches' operands took (buffers put
+        # x devices put to): over the dispatches, buffers per dispatch
+        lines.append(f"# TYPE {EM.OPERAND_BUFFERS_TOTAL} counter")
+        lines.append(f"{EM.OPERAND_BUFFERS_TOTAL} {rc.operand_buffers_total}")
         # the mesh this engine runs on (1 and 1 with no mesh)
         lines.append(f"# TYPE {EM.MESH_TP} gauge")
         lines.append(f"{EM.MESH_TP} {mesh_shape['tp']}")

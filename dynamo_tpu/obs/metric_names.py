@@ -111,6 +111,8 @@ class EngineMetric:
     AHEAD_DISPATCHES_TOTAL = "dynamo_tpu_engine_ahead_dispatches_total"
     AHEAD_DISCARDS_TOTAL = "dynamo_tpu_engine_ahead_discards_total"
     PIPELINE_DRAINS_TOTAL = "dynamo_tpu_engine_pipeline_drains_total"
+    # operand upload (EngineCore._upload_dispatch)
+    OPERAND_BUFFERS_TOTAL = "dynamo_tpu_engine_operand_buffers_total"
     # engine/counters.py mesh_shape
     MESH_TP = "dynamo_tpu_engine_mesh_tp"
     MESH_DEVICES = "dynamo_tpu_engine_mesh_devices"
@@ -220,6 +222,7 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.AHEAD_DISPATCHES_TOTAL: ("counter", ()),
     EngineMetric.AHEAD_DISCARDS_TOTAL: ("counter", ()),
     EngineMetric.PIPELINE_DRAINS_TOTAL: ("counter", ()),
+    EngineMetric.OPERAND_BUFFERS_TOTAL: ("counter", ()),
     EngineMetric.MESH_TP: ("gauge", ()),
     EngineMetric.MESH_DEVICES: ("gauge", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),

@@ -122,6 +122,7 @@ def seed_http_metrics():
     request_counters.record_ahead_discard()
     request_counters.record_drain()
     request_counters.record_drain()
+    request_counters.record_operands(440)
     mesh_shape.update(tp=4, devices=4)
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()
