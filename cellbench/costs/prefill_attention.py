@@ -1,6 +1,8 @@
 """Operations and bytes of (chunked) causal prefill attention, from shapes.
 
 One call = one chunk of ``take`` new tokens after ``prefix`` cached ones.
+Layers are those that attend: ``attention_layers`` of the configuration, all
+``num_hidden_layers`` where it has no such key (costs/decode_attention.py).
 Per layer the causal scores the algorithm needs are take·prefix +
 take·(take+1)/2 query-key pairs, each 2·D operations for QK^T and 2·D for
 PV, in each of Hq heads.  Bytes: queries and outputs of the chunk
@@ -29,7 +31,7 @@ def calls(records: list, interval: tuple, config: dict) -> list[tuple[int, int]]
 
 
 def cost(config: dict, calls_: list[tuple[int, int]]) -> tuple[float, float]:
-    layers = config["num_hidden_layers"]
+    layers = config.get("attention_layers", config["num_hidden_layers"])
     hq, hk, d = (config["num_attention_heads"], config["num_key_value_heads"],
                  config["head_dim"])
     el = BYTES[config.get("dtype", "bfloat16")]

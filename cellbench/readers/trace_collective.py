@@ -20,11 +20,10 @@ what the step pays.
                  programs over their executions, mean over the devices
 
 A program that is not sharded has no collective and reads 0: its devices
-spend none of their time in one.  (No metric of ``BENCHMARK.json`` lists
-cells, and one that lists none is owed by every cell that reports the
-end-to-end metric it moves, so the one-chip cells report this zero.)  None
-when the run made no profile or the profile holds no device operation, and
-for ``per_program`` when no matching program ran in the slice, as
+spend none of their time in one.  (``BENCHMARK.json`` lists the four-chip
+cell on the three metrics, so the one-chip cells do not report that zero.)
+None when the run made no profile or the profile holds no device operation,
+and for ``per_program`` when no matching program ran in the slice, as
 ``trace_module_ms`` has it.
 """
 

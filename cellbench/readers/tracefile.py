@@ -210,7 +210,6 @@ def parse(path: str, device: dict) -> dict:
         if plane_re.search(plane["name"]):
             where: dict[int, tuple[str, str, str]] = {}    # name, scope, program
             programs: dict[str, str] = {}
-            keys: set[str] = set()
             for mid, (mname, mstats) in plane["events"].items():
                 stats = dict(_stat(s, names) for s in mstats)
                 tf_op = str(stats.get("tf_op") or "")
@@ -224,10 +223,9 @@ def parse(path: str, device: dict) -> dict:
                 is_ops = bool(line_re.search(lname))
                 if not is_ops and not MODULE_LINE.search(lname):
                     continue
-                key = (f"{plane['name']}/{lname}" if device.get("per_line")
-                       else plane["name"])
-                dev = devices.setdefault(key, {"ops": [], "modules": []})
-                keys.add(key)
+                # the matching lines of one plane are one device (the CPU's
+                # client threads, in rehearsals: trace_reduce.load)
+                dev = devices.setdefault(plane["name"], {"ops": [], "modules": []})
                 for ev in events:
                     mid, off, dur, _ = _event(ev)
                     if dur <= 0:
@@ -241,8 +239,8 @@ def parse(path: str, device: dict) -> dict:
                         m = re.search(r"\((\d+)\)$", name)
                         if m:
                             programs[m.group(1)] = name[:m.start()]
-            for key in keys:                 # program id -> module name
-                for row in devices[key]["ops"]:
+            if plane["name"] in devices:     # program id -> module name
+                for row in devices[plane["name"]]["ops"]:
                     row[4] = programs.get(row[4], row[4])
         if plane["name"].startswith("/host:"):
             ids = {mid for mid, (mname, _) in plane["events"].items()

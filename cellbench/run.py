@@ -243,14 +243,31 @@ def device_report(devs, chips: int) -> dict:
             "count": chips, "memory_peak_bytes": max(peaks)}
 
 
+def metric_args(desc: dict, config: dict) -> dict:
+    """The arguments a metric's reader gets: the metric file's ``args``, and
+    over them what the configuration's ``kernels`` block names for this
+    metric (the operations to time, the cost module to load), so that one
+    name stays one quantity whatever kernel does the work."""
+    return {**desc.get("args", {}),
+            **config.get("kernels", {}).get(desc["name"], {})}
+
+
 def layer_metrics(root: Path, cell, ctx: dict) -> dict:
     out = {}
+    on_chip = ctx["device"]["platform"] == "tpu"
     for m in spec.metrics_for(root, cell.name, "per_layer"):
         desc = spec.load_layer_metric(root, m["name"])
         reader = spec.load_module(root, "readers", desc["reader"])
-        value = reader.read(ctx, desc.get("args", {}))
-        if value is not None:       # nothing to read: left out of the line
+        args = metric_args(desc, cell.config)
+        value = reader.read(ctx, args)
+        if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
+        elif on_chip:
+            # nothing to read: left out of the line, and this cell owes the
+            # metric, so the log says what was missing
+            why = (reader.missing(ctx, args) if hasattr(reader, "missing")
+                   else "it found nothing to read")
+            note(f"not reported: {m['name']} (reader {desc['reader']}): {why}")
     return out
 
 

@@ -56,11 +56,10 @@ def load(path: str, device: dict, host_event: str | None = None) -> dict:
                 if not line_re.search(line.name):
                     continue
                 # a backend without device planes (the CPU, in rehearsals)
-                # runs its programs on threads of the host plane: there
-                # ``per_line`` makes each matching thread a "device"
-                key = (f"{plane.name}/{line.name}" if device.get("per_line")
-                       else plane.name)
-                devices.setdefault(key, []).extend(
+                # runs its programs on threads of the host plane, several at
+                # once with a dispatch in flight: the matching lines of one
+                # plane are one device, busy while any of them is
+                devices.setdefault(plane.name, []).extend(
                     [op_name(e.name), e.start_ns, e.duration_ns]
                     for e in line.events if e.duration_ns > 0)
         if host_re is not None and plane.name.startswith("/host:"):

@@ -51,11 +51,21 @@ def test_names_units_and_keys():
 
 
 def test_a_new_cell_needs_no_edit_to_an_entry_that_is_there():
-    """Every metric of the first benchmark is reported by every cell, so a
-    cell added later as one more ``workloads`` entry gets all of them; a
-    list of cells on a metric is for one that exists only across chips."""
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+    """Every end-to-end metric is reported by every cell, and so is a
+    per-layer metric without a ``workloads`` list: a cell added later as one
+    more ``workloads`` entry gets all of them.  A per-layer metric of what
+    only some configurations have (collectives across chips, a kernel of one
+    kind of layer) lists its cells: a list is not empty, names accepted
+    cells once each, and every one of them reports the metric it moves."""
+    for m in BENCH["end_to_end"]:
         assert "workloads" not in m, m["name"]
+    for m in BENCH["per_layer"]:
+        if "workloads" not in m:
+            continue
+        listed = m["workloads"]
+        assert isinstance(listed, list) and listed, m["name"]
+        assert len(set(listed)) == len(listed) and set(listed) <= set(CELLS), m
+        assert set(listed) <= set(cells_of(E2E[m["moves"]])), m["name"]
 
 
 def test_configs_and_workloads():

@@ -115,11 +115,12 @@ def added(tmp_path_factory):
         "moves": "tok_s_chip", "workloads": ["added.cell"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     run = run_cell(root, "added.cell", 1, seconds=1, extra=["--rehearse"])
-    return root, before, bench_before, run
+    other = run_cell(root, "tiny-dense.open", 1, seconds=1, extra=["--rehearse"])
+    return root, before, bench_before, run, other
 
 
 def test_a_cell_is_added_with_new_files_only(added):
-    root, before, bench_before, (p, lines) = added
+    root, before, bench_before, (p, lines), _ = added
     assert p.returncode == 0, p.stderr[-2000:]
     out = json.loads(lines[-1])
     assert out["correct"] is True and out["metrics"]["added.tokens"]["value"] > 0
@@ -140,3 +141,20 @@ def test_a_cell_is_added_with_new_files_only(added):
     # a cell of the first benchmark does not get the newcomer's metric
     assert "added.tokens" not in {
         m["name"] for m in spec.metrics_for(root, "tiny-dense.open", "per_layer")}
+
+
+def test_a_metric_that_lists_one_cell_is_on_that_cell_s_line_alone(added):
+    """``added.tokens`` was appended after the last entry of ``per_layer``
+    with ``"workloads": ["added.cell"]``: the traced line of a cell that was
+    there before holds what it held and not the newcomer's metric."""
+    root, _, bench_before, (_, mine), (p, lines) = added
+    assert p.returncode == 0, p.stderr[-2000:]
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    assert bench["per_layer"][-1]["name"] == "added.tokens"
+    assert bench["per_layer"][-1]["workloads"] == ["added.cell"]
+    other, own = json.loads(lines[-1])["metrics"], json.loads(mine[-1])["metrics"]
+    assert "added.tokens" in own and "added.tokens" not in other
+    assert set(other) <= {m["name"] for m in bench_before["per_layer"]}
+    assert {"step.wall_ms", "sched.ahead_dispatch_pct"} <= set(other)
+    # nor the metrics the real benchmark keeps for its four-chip cell
+    assert not any(n.startswith("coll.") for n in set(own) | set(other))

@@ -3,12 +3,10 @@ a made-up trace, the closed loop whose order the seed does not choose, and a
 ``--rehearse`` run of a tiny ``"tp": 4`` configuration on four virtual
 devices of the CPU, which reports the contract's line.
 
-The three ``coll.*`` metrics have their files under ``layer_metrics/`` but no
-entry in the real ``BENCHMARK.json`` yet (``test_trace_readers.py`` pins the
-tail of ``per_layer``, and a file that is there may not be edited by the PR
-that adds a cell); the rehearsal declares them in its copy, as the next
-``benchmark`` PR will in the real file, and so shows that nothing else is
-missing."""
+The three ``coll.*`` metrics are declared in the real ``BENCHMARK.json`` with
+``"workloads": ["mistral-7b-tp4.chat-closed"]`` (PR 35): collectives exist
+only across chips, so only that cell owes them.  The rehearsal's copy lists
+its own four-device cell instead."""
 
 import json
 import shutil
@@ -98,13 +96,21 @@ def test_one_chip_reads_zero_and_no_trace_reads_nothing():
         assert reader().read(ctx, spec.load_layer_metric(roots.REPO, n)["args"]) is None
 
 
-def test_metric_files_are_ready_to_be_declared():
+def test_metric_files_are_declared_for_the_four_chip_cell():
     bench = spec.load_benchmark(roots.REPO)
     e2e = {m["name"] for m in bench["end_to_end"]}
+    declared = {m["name"]: m for m in bench["per_layer"]}
     for n in COLL:
         d = spec.load_layer_metric(roots.REPO, n)
         assert d["name"] == n and d["layer"] == "device" and d["moves"] in e2e
         assert d["source"] == "device_trace" and "workloads" not in d
+        assert declared[n]["workloads"] == ["mistral-7b-tp4.chat-closed"]
+        assert {k: declared[n][k] for k in declared[n] if k != "workloads"} == {
+            k: d[k] for k in ("name", "unit", "better", "source", "layer", "moves")}
+    for cell in (w["name"] for w in bench["workloads"]):
+        got = {m["name"] for m in spec.metrics_for(roots.REPO, cell, "per_layer")}
+        owes = cell == "mistral-7b-tp4.chat-closed"
+        assert (set(COLL) <= got) if owes else not (set(COLL) & got), cell
 
 
 # ------------------------------------------- the closed loop's fixed order
@@ -147,10 +153,9 @@ def tp4(tmp_path_factory):
     bench["workloads"].append({"name": "tiny-tp4.closed", "config": "tiny-tp4",
                                "traffic": "tiny-fixed-closed", "chips": 4,
                                "why": "closed loop, toy, four devices"})
-    for n in COLL:
-        d = spec.load_layer_metric(roots.REPO, n)
-        bench["per_layer"].append({k: d[k] for k in (
-            "name", "unit", "better", "source", "layer", "moves")})
+    for m in bench["per_layer"]:    # across chips only: this copy's such cell
+        if m["name"] in COLL:
+            m["workloads"] = ["tiny-tp4.closed"]
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     # conftest.py gave this process eight virtual devices through XLA_FLAGS,
     # and the run inherits them: the mesh takes the first four

@@ -58,8 +58,10 @@ def recorded():
 def test_new_metrics_are_all_declared():
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW):] == NEW         # appended, in the issue's order
-    assert not any("workloads" in m for m in bench["per_layer"])
+    i = names.index(NEW[0])
+    assert names[i:i + len(NEW)] == NEW     # one run, in the issue's order
+    # every cell reports them: none lists cells (later entries may)
+    assert not any("workloads" in m for m in bench["per_layer"][i:i + len(NEW)])
 
 
 def test_the_wire_walk_agrees_with_profiledata(explore_ctx):
@@ -217,12 +219,18 @@ def test_step_classes_add_up_to_the_wall_exactly():
 def test_counter_metrics_read_the_engine_counters():
     before = {"core.decode_rows_dispatched_total": 100, "core.decode_dispatches_total": 10,
               "core.first_token_seconds_total": 1.0, "core.first_tokens_total": 4,
-              "core.requests_cut_short_total": 0, "core.requests_finished_total": 3}
+              "core.requests_cut_short_total": 0, "core.requests_finished_total": 3,
+              "core.ahead_dispatches_total": 9, "core.ahead_discards_total": 2}
     after = {"core.decode_rows_dispatched_total": 424, "core.decode_dispatches_total": 37,
              "core.first_token_seconds_total": 2.5, "core.first_tokens_total": 16,
-             "core.requests_cut_short_total": 1, "core.requests_finished_total": 13}
+             "core.requests_cut_short_total": 1, "core.requests_finished_total": 13,
+             "core.ahead_dispatches_total": 33, "core.ahead_discards_total": 11}
     ctx = {"edges": (before, after), "root": REPO}
     assert read("sched.decode_rows_per_dispatch", ctx) == pytest.approx(12.0)
+    # dispatch-ahead (PR 29): 24 of 27 decode dispatches went out with one in
+    # flight; a late stop threw away the ahead-sample of 9 of 324 rows
+    assert read("sched.ahead_dispatch_pct", ctx) == pytest.approx(100 * 24 / 27)
+    assert read("sched.ahead_discard_pct", ctx) == pytest.approx(100 * 9 / 324)
     assert read("engine.ttft_ms", ctx) == pytest.approx(125.0)
     assert read("kv.cut_short_pct", ctx) == pytest.approx(10.0)
     # a program without the counters: nothing, not an error
@@ -258,6 +266,9 @@ def test_traced_rehearsal_reports_the_counter_based_metrics(traced_rehearsal):
         assert name in m, name
     assert m["kv.cut_short_pct"] == 0.0 and m["engine.ttft_ms"] > 0
     assert 1.0 <= m["sched.decode_rows_per_dispatch"] <= 8.0
+    assert 0.0 < m["sched.ahead_dispatch_pct"] <= 100.0
+    assert 0.0 <= m["sched.ahead_discard_pct"] < 100.0
+    assert not any(n.startswith("coll.") for n in m)    # the four-chip cell's
     assert 0 < m["step.prefill_share_pct"] < 100
     assert sum(m[n] for n in IDLE) == pytest.approx(m["device.idle_pct"], abs=1.0)
     # the CPU's profile has no module line and no operation metadata
