@@ -154,6 +154,33 @@ def main() -> None:
         return jax.lax.ragged_dot(xs, w, sizes)
 
     variants.append(("moe/ragged_dot", moe_ragged))
+    # sparse latent attention at GLM-5.2's widths (64 heads, rows of 576)
+    # and the DMA movers of its cache; the dispatch picks the kernel
+    from dynamo_tpu.ops.pallas.latent_cache_dma import write_rows
+    from dynamo_tpu.ops.pallas.registry import (
+        probe_latent_dma_inputs, probe_mla_sparse_inputs,
+    )
+    from dynamo_tpu.ops.paged_attention import sparse_latent_attention
+
+    for phase, nq in (("decode", batch), ("prefill", 256)):
+        variants.append((
+            f"mla_sparse/{phase}",
+            lambda phase=phase, nq=nq: sparse_latent_attention(
+                *probe_mla_sparse_inputs(
+                    nq, 64, 576, 2048, 1 << 16,
+                    np.linspace(1, 4096, nq).astype(np.int32)),
+                sm_scale=1 / 16, phase=phase)))
+    from dynamo_tpu.ops.pallas.mla_masked_prefill import mla_masked_prefill
+    from dynamo_tpu.ops.pallas.registry import probe_mla_masked_inputs
+
+    variants.append((
+        "mla_masked/prefill",
+        lambda: mla_masked_prefill(
+            *probe_mla_masked_inputs(512, 4096, 64, 640), heads=64, dv=512,
+            sm_scale=1 / 16)))
+    variants.append((
+        "latent_cache/write_rows",
+        lambda: write_rows(*probe_latent_dma_inputs(1 << 16, 576, 2048))))
     ok = all([probe(lbl, fn) for lbl, fn in variants])
     sys.exit(0 if ok else 1)
 

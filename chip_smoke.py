@@ -279,6 +279,55 @@ def _child_kernels(arg: dict) -> None:
                 wq.astype(jnp.float32) * scale[None, :])
         return got, ref
 
+    def sparse(_quant):
+        a = reg.probe_mla_sparse_inputs(
+            g["batch"], h, 72, 96, n * 32, lens)
+        got = pa.sparse_latent_attention(*a, sm_scale=0.2, phase="decode") \
+            if not interpret else _sparse_interpret(a)
+        return got, oracle(pa.sparse_latent_attention, *a, sm_scale=0.2,
+                           phase="decode")
+
+    def _sparse_interpret(a):
+        from dynamo_tpu.ops import latent_cache
+        from dynamo_tpu.ops.pallas.mla_sparse_attention import (
+            mla_sparse_attention,
+        )
+
+        q, latent, _, slots, nvalid = a
+        q_lo, q_hi = latent_cache.split_query(q)
+        return jnp.concatenate(mla_sparse_attention(
+            q_lo, q_hi, slots, nvalid,
+            latent.reshape(-1, 1, latent.shape[-1]), sm_scale=0.2,
+            rows_per_tile=32, interpret=True), axis=-1)
+
+    def masked(_quant):
+        from dynamo_tpu.ops.pallas.mla_masked_prefill import (
+            mla_masked_prefill,
+        )
+
+        q, ctx, bias = reg.probe_mla_masked_inputs(32, 256, h, 128)
+        got = mla_masked_prefill(q, ctx, bias, heads=h, dv=128, sm_scale=0.2,
+                                 tokens_per_tile=8, keys_per_tile=128,
+                                 interpret=interpret)
+        with jax.default_matmul_precision("highest"):
+            sc = jnp.einsum("shd,cd->shc", q.astype(jnp.float32).reshape(
+                32, h, 128), ctx.astype(jnp.float32)) * 0.2 + bias[:, None, :]
+            ref = jnp.einsum("shc,cd->shd", jax.nn.softmax(sc, axis=-1),
+                             ctx.astype(jnp.float32))
+        return got, ref.reshape(32 * h, 128)
+
+    def latent_dma(_quant):
+        from dynamo_tpu.ops.pallas.latent_cache_dma import write_rows
+
+        cache, rows, slots = reg.probe_latent_dma_inputs(n * 32, 72, 64)
+        ref = np.asarray(cache).copy()
+        for r, s_ in zip(np.asarray(rows), np.asarray(slots)):
+            if s_ >= 0:
+                ref[s_] = r
+        # compared as floats below: keep the words exactly representable
+        got = write_rows(cache, rows, slots, interpret=interpret)
+        return (np.asarray(got) >> 8), (ref >> 8)
+
     # tolerances of tests/test_pallas_kernels.py: bf16 operands 3e-2;
     # the int8 matmul rtol 5e-2 / atol 0.5
     cases = {
@@ -286,13 +335,18 @@ def _child_kernels(arg: dict) -> None:
         "paged_prefill_attention": [("prefill", prefill)],
         "ragged_paged_prefill_attention": [("ragged", ragged)],
         "int8_matmul": [("int8_matmul", matmul)],
+        "mla_sparse_attention": [("sparse_latent", sparse)],
+        "mla_masked_prefill": [("masked_latent", masked)],
+        "latent_cache_dma": [("latent_write_rows", latent_dma)],
     }
     live = [k for k, meta in reg.KERNELS.items() if not meta["placeholder"]]
     assert sorted(live) == sorted(cases), (live, sorted(cases))
     ok = True
     for kernel in live:
         for label, fn in cases[kernel]:
-            for quant in ([False] if kernel == "int8_matmul"
+            for quant in ([False] if kernel in (
+                    "int8_matmul", "mla_sparse_attention",
+                    "mla_masked_prefill", "latent_cache_dma")
                           else [False, True]):
                 t0 = time.monotonic()
                 got, ref = (np.asarray(x, np.float32) for x in fn(quant))

@@ -372,6 +372,23 @@ class EngineCore:
         )
         cache_dtype = config.cache_dtype or model.config.dtype
         self.cache_quant = str(cache_dtype) == "int8"
+        # a cache of two arrays (latent rows and indexer keys): what moves
+        # blocks was written for one, so refuse it here, at start-up,
+        # rather than move half a block
+        self._two_part_cache = bool(getattr(model, "two_part_cache", False))
+        if self._two_part_cache:
+            asked = [name for name, on in (
+                ("num_host_blocks", config.num_host_blocks > 0),
+                ("kv_persist_dir", bool(config.kv_persist_dir)),
+                ("cache_dtype=int8", self.cache_quant),
+                ("spec_tokens", config.spec_tokens > 0),
+                ("sp_prefill_threshold", config.sp_prefill_threshold > 0),
+                ("a mesh", mesh is not None)) if on]
+            if asked:
+                raise ValueError(
+                    f"{type(model).__name__} keeps a two-part cache (latent "
+                    "rows and indexer keys under one block table); not "
+                    f"supported with it: {', '.join(asked)}")
         # host-RAM offload tier: device-evicted blocks stay restorable
         # (ref kv/reuse.rs + layer.rs copy streams; SURVEY §5 checkpoint row)
         self.host_pool = None
@@ -609,6 +626,15 @@ class EngineCore:
         self.requests_finished = 0       # any finish reason
         self.requests_cut_short = 0      # LENGTH because block space ran out
         self.first_tokens = 0            # requests that emitted a first token
+        # prefix reuse: prompt tokens of completed prefills, and of those
+        # the tokens served from reused blocks
+        self.prompt_tokens_admitted = 0
+        self.prompt_tokens_cached = 0
+        # a model with a sparse-attention indexer: positions its decode
+        # rows could see, and positions they attended to
+        self._index_topk = int(getattr(model.config, "index_topk", 0) or 0)
+        self.attn_context_tokens = 0
+        self.attn_selected_tokens = 0
         self.first_token_s = 0.0         # sum of (first emit - submitted_at)
         # cached _unified_penalties host buffers (invalidated on
         # admission/finish; incremental append between turns)
@@ -786,6 +812,9 @@ class EngineCore:
             attention_impl,
         )
 
+        if hasattr(self.model, "attention_impls"):
+            # a model with attention kernels of its own names them
+            return self.model.attention_impls()
         window = getattr(self.model.config, "sliding_window", None)
         return {
             phase: attention_impl(
@@ -1311,6 +1340,10 @@ class EngineCore:
             "requests_finished_total": self.requests_finished,
             "requests_cut_short_total": self.requests_cut_short,
             "first_tokens_total": self.first_tokens,
+            "prompt_tokens_admitted_total": self.prompt_tokens_admitted,
+            "prompt_tokens_cached_total": self.prompt_tokens_cached,
+            "attn_context_tokens_total": self.attn_context_tokens,
+            "attn_selected_tokens_total": self.attn_selected_tokens,
             "first_token_seconds_total": self.first_token_s,
             # dispatch-ahead: ahead / decode_dispatches_total = how
             # often a decode hid its round trip; discards = late stops;
@@ -1952,6 +1985,9 @@ class EngineCore:
         ):
             self._last_was_prefill = False
         req.state = RequestState.RUNNING
+        self.prompt_tokens_admitted += req.prompt_len
+        self.prompt_tokens_cached += req.cached_tokens
+        request_counters.record_prompt(req.prompt_len, req.cached_tokens)
         if req.remote_decode:
             # prefill-only request: emit the first sampled token, hold the
             # blocks for transfer-out, free the slot (ref prefill_worker.py:148
@@ -2051,7 +2087,7 @@ class EngineCore:
         max_pb = 0
         for r, req in enumerate(dec):
             p = req.seq.total_tokens - 1  # uncomputed tail position
-            tokens[0, r] = req.seq.tokens[-1]
+            tokens[0, r] = req.seq.last_token
             positions[0, r] = p
             slot_idx[0, r] = req.block_ids[p // bs] * bs + p % bs
             seq_ids[0, r] = r
@@ -2446,7 +2482,7 @@ class EngineCore:
             props[i] = prop
             any_prop = any_prop or bool(prop)
             rows.append(req)
-            row_tokens = [req.seq.tokens[-1]] + prop
+            row_tokens = [req.seq.last_token] + prop
             n = len(row_tokens)
             tokens[i, :n] = row_tokens
             positions[i, :n] = np.arange(p, p + n, dtype=np.int32)
@@ -2618,7 +2654,7 @@ class EngineCore:
             active.append(req)
             carry_rows[i] = bool(ahead)
             if not ahead:
-                tokens[i] = req.seq.tokens[-1]
+                tokens[i] = req.seq.last_token
             positions[i] = p
             bt[i, : len(req.block_ids)] = req.block_ids
             seq_lens[i] = total
@@ -2664,6 +2700,12 @@ class EngineCore:
         self.decode_dispatches += 1
         self.decode_rows_dispatched += len(active)
         request_counters.record_decode(len(active))
+        if self._index_topk:
+            ctx = int(seq_lens.sum())
+            picked = int(np.minimum(seq_lens, self._index_topk).sum())
+            self.attn_context_tokens += ctx
+            self.attn_selected_tokens += picked
+            request_counters.record_sparse_decode(ctx, picked)
 
         def finish(out):
             sampled, lps, cids, clps = out
@@ -3144,12 +3186,21 @@ class EngineCore:
             )
         req.cached_tokens += len(hit) * bs
 
+    def _refuse_block_move(self, what: str) -> None:
+        """Transfer, streaming and remote prefill speak of one cache array;
+        a model with a two-part cache has none of them."""
+        if self._two_part_cache:
+            raise NotImplementedError(
+                f"{what}: {type(self.model).__name__} keeps a two-part "
+                "cache, which the block movers do not carry")
+
     def gather_blocks_device(self, block_ids: list[int]) -> jax.Array:
         """Gather blocks WITHOUT leaving the device: returns a jax.Array
         [L, n, 2, Bs, HkD].  The colocated transfer fast path hands this
         straight to the target engine's scatter — the copy rides ICI (or
         stays on-chip), never touching host RAM (ref: NIXL device WRITE,
         vllm patch nixl.py +394; VERDICT r2 ask #8)."""
+        self._refuse_block_move("gather_blocks_device")
         return gather_blocks_padded(self.cache, block_ids)
 
     def gather_blocks_np(self, block_ids: list[int]):
@@ -3158,6 +3209,7 @@ class EngineCore:
         sharded mesh this all-gathers KV heads — which is exactly the
         TP-resharding the reference needs a Triton kernel for
         (kv_rearrange.py); here the host staging buffer is layout-neutral."""
+        self._refuse_block_move("gather_blocks_np")
         out = gather_blocks_padded(self.cache, block_ids)
         return jax.device_get(out)  # one batched transfer, numpy leaves
 
@@ -3174,6 +3226,7 @@ class EngineCore:
         request was aborted meanwhile its blocks may already belong to
         someone else, and a late write must be dropped, not applied.
         """
+        self._refuse_block_move("scatter_external")
         if request_id is not None:
             req = self._by_id.get(request_id)
             if (

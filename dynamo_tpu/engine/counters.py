@@ -309,6 +309,27 @@ class RequestCounters:
                                                        under a mesh, the
                                                        number of arrays with
                                                        none)
+        dynamo_tpu_engine_prompt_tokens_admitted_total counter (prompt tokens
+                                                       of requests whose
+                                                       prefill completed)
+        dynamo_tpu_engine_prompt_tokens_cached_total   counter (of those, the
+                                                       tokens served from
+                                                       reused blocks: over
+                                                       admitted, the prefix
+                                                       cache's hit share)
+        dynamo_tpu_engine_attn_context_tokens_total    counter (models with
+                                                       a sparse-attention
+                                                       indexer: cached
+                                                       positions the decode
+                                                       rows dispatched could
+                                                       see, summed)
+        dynamo_tpu_engine_attn_selected_tokens_total   counter (of those, the
+                                                       positions attended to:
+                                                       min(context,
+                                                       index_topk) a row;
+                                                       over context, how
+                                                       sparse attention was)
+    All four are counted on the host from lengths it already has.
     """
 
     def __init__(self) -> None:
@@ -340,6 +361,14 @@ class RequestCounters:
     def record_operands(self, buffers: int) -> None:
         self.operand_buffers_total += buffers
 
+    def record_prompt(self, tokens: int, cached: int) -> None:
+        self.prompt_tokens_admitted_total += tokens
+        self.prompt_tokens_cached_total += cached
+
+    def record_sparse_decode(self, context: int, selected: int) -> None:
+        self.attn_context_tokens_total += context
+        self.attn_selected_tokens_total += selected
+
     def reset(self) -> None:
         """Test isolation hook — the counters are process-global."""
         self.decode_dispatches_total = 0
@@ -352,6 +381,10 @@ class RequestCounters:
         self.ahead_discards_total = 0
         self.pipeline_drains_total = 0
         self.operand_buffers_total = 0
+        self.prompt_tokens_admitted_total = 0
+        self.prompt_tokens_cached_total = 0
+        self.attn_context_tokens_total = 0
+        self.attn_selected_tokens_total = 0
 
 
 request_counters = RequestCounters()

@@ -297,6 +297,20 @@ class Metrics:
         # x devices put to): over the dispatches, buffers per dispatch
         lines.append(f"# TYPE {EM.OPERAND_BUFFERS_TOTAL} counter")
         lines.append(f"{EM.OPERAND_BUFFERS_TOTAL} {rc.operand_buffers_total}")
+        # prefix reuse (cached / admitted) and, for a model with a
+        # sparse-attention indexer, how sparse decode attention was
+        lines.append(f"# TYPE {EM.PROMPT_TOKENS_ADMITTED_TOTAL} counter")
+        lines.append(f"{EM.PROMPT_TOKENS_ADMITTED_TOTAL} "
+                     f"{rc.prompt_tokens_admitted_total}")
+        lines.append(f"# TYPE {EM.PROMPT_TOKENS_CACHED_TOTAL} counter")
+        lines.append(f"{EM.PROMPT_TOKENS_CACHED_TOTAL} "
+                     f"{rc.prompt_tokens_cached_total}")
+        lines.append(f"# TYPE {EM.ATTN_CONTEXT_TOKENS_TOTAL} counter")
+        lines.append(f"{EM.ATTN_CONTEXT_TOKENS_TOTAL} "
+                     f"{rc.attn_context_tokens_total}")
+        lines.append(f"# TYPE {EM.ATTN_SELECTED_TOKENS_TOTAL} counter")
+        lines.append(f"{EM.ATTN_SELECTED_TOKENS_TOTAL} "
+                     f"{rc.attn_selected_tokens_total}")
         # the mesh this engine runs on (1 and 1 with no mesh)
         lines.append(f"# TYPE {EM.MESH_TP} gauge")
         lines.append(f"{EM.MESH_TP} {mesh_shape['tp']}")

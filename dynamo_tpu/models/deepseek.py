@@ -1,23 +1,25 @@
-"""DeepSeek-V2 family (MLA + DeepSeekMoE) for paged serving.
+"""DeepSeek-V2/V3 family (MLA + DeepSeekMoE) for paged serving.
 
 Multi-head Latent Attention projects hidden states through low-rank
 latents (``kv_a`` → norm → ``kv_b``) and splits queries/keys into a
 no-position part and a small rotary part shared across heads; the MoE
-layers combine softmax-routed experts (optionally group-limited routing)
-scaled by ``routed_scaling_factor`` with always-on shared experts, and
-the first ``first_k_dense_replace`` layers use a plain dense MLP.
+layers combine routed experts (``moe_route``: softmax or sigmoid scores,
+optionally group-limited, or chosen under V3's correction bias) scaled by
+``routed_scaling_factor`` with always-on shared experts, and the first
+``first_k_dense_replace`` layers use a plain dense MLP.  The variant with a
+learned sparse-attention indexer is models/glm_dsa.py, which holds the
+latent once in a cache of its own (ops/latent_cache.py).
 
 TPU mapping:
   * Default ``attn_impl="absorbed"`` — the MLA deployment shape: the
     paged cache stores ONE shared latent row per token (c_hat ‖ roped
     k_pe, width kv_lora_rank+rope), queries absorb kv_b's K-half into
     latent space, attention runs as GQA with a single KV head, and the
-    attended latent expands per head through kv_b's V-half.  This is the
-    MLA memory win — the generic pool's K/V axis still holds the row
-    twice, so the per-token cost is 2·(kv_lora+rope) (1,152 for
-    DeepSeek-V2 vs 49,152 expanded at 128 heads; collapsing the
-    duplicate plane is a follow-up) — and is logit-exact vs
-    transformers.
+    attended latent expands per head through kv_b's V-half.  This class
+    keeps the generic K/V pool, whose two planes both hold the row, so
+    a token costs 2·(kv_lora+rope) elements a layer (1,152 for
+    DeepSeek-V2 vs 49,152 expanded at 128 heads); models/glm_dsa.py
+    holds it once.  Logit-exact vs transformers.
   * ``attn_impl="expanded"`` keeps the per-head K/V oracle (V padded to
     qk_head_dim) — parity baseline and debugging aid.
   * Two ``lax.scan`` stacks — dense-MLP layers then MoE layers — because
@@ -28,9 +30,10 @@ TPU mapping:
     TP-within-experts.
   * RoPE is DeepSeek's INTERLEAVED complex-pair form (adjacent element
     pairs rotate together), unlike the Llama rotate-half layout.
-  * The Pallas attention kernels currently assume lane-friendly head
-    dims; serve this family with DYNAMO_DISABLE_PALLAS=1 until an MLA
-    kernel lands (the pure-JAX paged path is used in tests).
+  * Attention goes through the generic dispatch
+    (ops/paged_attention.py) as one kv head of width kv_lora+rope; the
+    dispatch decides kernel or XLA as for any other model, and no
+    environment variable is needed to serve this family.
 
 Reference parity: the reference serves DeepSeek through vLLM (its patch
 carries a DeepSeek MoE tweak, container/deps/vllm patch:4074); here the
@@ -82,7 +85,9 @@ class DeepseekConfig:
     num_experts_per_tok: int = 0
     n_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
-    topk_method: str = "greedy"            # or "group_limited_greedy"
+    topk_method: str = "greedy"   # "group_limited_greedy" | "noaux_tc"
+    scoring_func: str = "softmax"          # or "sigmoid"
+    norm_topk_prob: bool = False
     n_group: int = 1
     topk_group: int = 1
     first_k_dense_replace: int = 0
@@ -129,17 +134,19 @@ class DeepseekConfig:
                 "is not implemented yet — loading this checkpoint would "
                 "produce silently wrong logits at every position"
             )
-        if g("topk_method", "greedy") not in ("greedy",
-                                              "group_limited_greedy"):
-            raise NotImplementedError(
-                f"topk_method {g('topk_method')!r} (e.g. V3's noaux_tc) "
-                "is not implemented"
-            )
-        if bool(g("norm_topk_prob", False)):
-            raise NotImplementedError("norm_topk_prob=True routing")
-        if g("scoring_func", "softmax") != "softmax":
+        method = g("topk_method", "greedy")
+        if method not in ("greedy", "group_limited_greedy", "noaux_tc"):
+            raise NotImplementedError(f"topk_method {method!r}")
+        if g("scoring_func", "softmax") not in ("softmax", "sigmoid"):
             raise NotImplementedError(
                 f"scoring_func {g('scoring_func')!r}"
+            )
+        if method == "noaux_tc" and int(g("n_group", 1) or 1) != 1:
+            # noaux_tc with groups ranks a group by the sum of its two
+            # best biased scores: not computed here
+            raise NotImplementedError(
+                "topk_method 'noaux_tc' with n_group > 1 (group-limited "
+                "choice over biased scores)"
             )
         if bool(g("attention_bias", False)):
             raise NotImplementedError(
@@ -161,7 +168,9 @@ class DeepseekConfig:
             num_experts_per_tok=g("num_experts_per_tok", 0) or 0,
             n_shared_experts=g("n_shared_experts", 0) or 0,
             routed_scaling_factor=float(g("routed_scaling_factor", 1.0)),
-            topk_method=g("topk_method", "greedy"),
+            topk_method=method,
+            scoring_func=g("scoring_func", "softmax"),
+            norm_topk_prob=bool(g("norm_topk_prob", False)),
             n_group=g("n_group", 1) or 1,
             topk_group=g("topk_group", 1) or 1,
             first_k_dense_replace=g("first_k_dense_replace", 0) or 0,
@@ -185,6 +194,42 @@ def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
     x0, x1 = xr[..., 0], xr[..., 1]
     out = jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos], axis=-1)
     return out.reshape(b, s, h, d).astype(x.dtype)
+
+
+def moe_route(cfg, router: jax.Array, xf: jax.Array, bias=None):
+    """The DeepSeek family's routing.  ``xf`` [T, Dm] -> (weights [T, k]
+    f32, expert ids [T, k]).  Scores are a softmax or, per expert, a
+    sigmoid of the router's logits, computed in f32 (inputs AND weights
+    cast before the matmul, as HF does: near-tie logits must resolve to the
+    same experts).  The k experts are those of largest score — within the
+    ``topk_group`` best groups for ``group_limited_greedy``; of largest
+    score + ``bias`` for ``noaux_tc`` (the correction bias steers the
+    choice only, the weight is the unbiased score).  ``norm_topk_prob``
+    divides the chosen weights by their sum; all are scaled by
+    ``routed_scaling_factor``."""
+    t = xf.shape[0]
+    logits = xf.astype(jnp.float32) @ router.astype(jnp.float32)  # [T,E]
+    if cfg.scoring_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores
+    if cfg.topk_method == "group_limited_greedy":
+        e = scores.shape[-1]
+        gs = scores.reshape(t, cfg.n_group, -1).max(axis=-1)  # [T,G]
+        _, gidx = jax.lax.top_k(gs, cfg.topk_group)
+        gmask = jnp.zeros_like(gs).at[
+            jnp.arange(t)[:, None], gidx
+        ].set(1.0)
+        choice = scores = scores * jnp.repeat(
+            gmask, e // cfg.n_group, axis=-1)
+    elif cfg.topk_method == "noaux_tc" and bias is not None:
+        choice = scores + bias.astype(jnp.float32)
+    _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)  # [T,k]
+    weights = jnp.take_along_axis(scores, topi, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return weights * cfg.routed_scaling_factor, topi
 
 
 class DeepseekModel:
@@ -259,6 +304,11 @@ class DeepseekModel:
             shared_up=dense(next(keys), (lm, dm, fs), dm),
             shared_down=dense(next(keys), (lm, fs, dm), fs),
         )
+        if cfg.topk_method == "noaux_tc":
+            # e_score_correction_bias: non-zero so that seeded weights
+            # exercise the choice-only bias
+            moe_layers["router_bias"] = 0.1 * jax.random.normal(
+                next(keys), (lm, e), jnp.float32)
         return {
             "embed": dense(next(keys), (cfg.vocab_size, dm), dm),
             "dense_layers": dense_layers,
@@ -305,6 +355,8 @@ class DeepseekModel:
             shared_up=P(None, None, _TP),
             shared_down=P(None, _TP, None),
         )
+        if cfg.topk_method == "noaux_tc":
+            moe_layers["router_bias"] = P(None, None)
         return {
             "embed": P(None, None),
             "dense_layers": dense_layers,
@@ -477,32 +529,16 @@ class DeepseekModel:
         return self._absorbed_out(lp, h_in, attn, w_v), cache
 
     def _moe_mlp(self, lp, x):
-        """DeepSeekMoE: softmax routing (optionally group-limited) ×
-        routed_scaling_factor through the grouped ragged_dot dispatch,
-        plus the always-on shared experts."""
+        """DeepSeekMoE: ``moe_route``'s choice and weights through the
+        grouped ragged_dot dispatch, plus the always-on shared experts."""
         cfg = self.config
         b, s, d = x.shape
         t = b * s
-        e, k = cfg.n_routed_experts, cfg.num_experts_per_tok
         xf = x.reshape(t, d)
-        # HF gates fully in f32 (inputs AND weights cast before the
-        # matmul): near-tie logits must resolve to the same experts
-        scores = jax.nn.softmax(
-            xf.astype(jnp.float32) @ lp["router"].astype(jnp.float32),
-            axis=-1,
-        )  # [T,E]
-        if cfg.topk_method == "group_limited_greedy":
-            gs = scores.reshape(t, cfg.n_group, -1).max(axis=-1)  # [T,G]
-            _, gidx = jax.lax.top_k(gs, cfg.topk_group)
-            gmask = jnp.zeros_like(gs).at[
-                jnp.arange(t)[:, None], gidx
-            ].set(1.0)
-            scores = scores * jnp.repeat(gmask, e // cfg.n_group, axis=-1)
-        weights, topi = jax.lax.top_k(scores, k)  # [T,k]
-        weights = weights * cfg.routed_scaling_factor
-
+        weights, topi = moe_route(cfg, lp["router"], xf,
+                                  lp.get("router_bias"))
         routed = grouped_expert_dispatch(
-            xf, weights, topi, e,
+            xf, weights, topi, cfg.n_routed_experts,
             lp["w_gate"], lp["w_up"], lp["w_down"], jax.nn.silu,
         )
 
@@ -696,6 +732,10 @@ def convert_hf_state_dict(sd: dict, cfg: DeepseekConfig) -> Params:
         shared_down=stack(
             "model.layers.{}.mlp.shared_experts.down_proj.weight", moe_idx, lin),
     )
+    if cfg.topk_method == "noaux_tc":
+        moe_layers["router_bias"] = jnp.asarray(_np.stack([
+            w(f"model.layers.{i}.mlp.gate.e_score_correction_bias")
+            for i in moe_idx]), jnp.float32)
     return {
         "embed": jnp.asarray(w("model.embed_tokens.weight"), dt),
         "dense_layers": dense_layers,

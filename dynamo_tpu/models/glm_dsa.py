@@ -1,0 +1,654 @@
+"""GLM-5.2-style decoder (``glm_moe_dsa``): latent attention, a learned
+sparse-attention indexer shared between layers, sigmoid-routed experts of
+which this chip holds a share.
+
+Per layer (pre-norm residual blocks, docs/glm_dsa.md has the equations):
+
+  * **Latent attention**, absorbed form: the cache holds one row a token
+    (c_kv ‖ roped k_pe) *once* (ops/latent_cache.py), queries are taken into
+    latent space through kv_b's K half, the attended latent comes back
+    through its V half.
+  * **Indexer**, in layers whose ``indexer_types`` entry is ``full``: a small
+    multi-head scorer over a cache of its own keys picks, for every query
+    token, the ``index_topk`` positions it attends to.  A ``shared`` layer
+    has no indexer and attends to the selection of the nearest ``full``
+    layer before it.
+  * Attention over a selection takes one of two forms, chosen statically by
+    the number of query tokens in the call.  Up to ``SPARSE_MAX_QUERIES``
+    (decode steps, the short tail of a prompt after a prefix hit) each query
+    *gathers* its rows: ``sparse_latent_attention``, a Pallas kernel on the
+    TPU.  A longer prefill chunk scores every key of the context once for
+    all its queries and masks what was not selected
+    (``dense_masked_attention``): the same result, by the matrix unit,
+    where a gather would fetch 2,048 rows for each of 2,048 queries.
+  * **Experts**: the router scores all ``router_experts``; this chip
+    computes the part of the sum its own ``n_routed_experts`` give
+    (``grouped_expert_dispatch(held=...)``), plus the shared expert.  What
+    the other chips of the expert-parallel group would add is left out: no
+    code stands in for them.
+
+The stack has up to four kinds of layer (dense or expert MLP × ``full`` or
+``shared`` indexer).  Parameters are stacked per kind, and each run of
+consecutive layers of one kind is one ``lax.scan``; expert weights are read
+where they lie (the layer-indexed form of ``grouped_expert_dispatch``).
+
+One chip only: no partition specs.  Block movers (host pool, persistent
+store, streamed or remote prefill) do not know the two-part cache, and the
+engine refuses them for this model at start-up (``two_part_cache``).
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from dynamo_tpu.models.deepseek import apply_rope_interleaved, moe_route
+from dynamo_tpu.models.llama import (
+    grouped_expert_dispatch,
+    rms_norm,
+    rope_inv_freq,
+)
+from dynamo_tpu.ops import latent_cache
+from dynamo_tpu.ops.paged_attention import (
+    sparse_attention_impl,
+    sparse_latent_attention,
+)
+
+Params = Any
+
+__all__ = ["GlmDsaConfig", "GlmDsaModel", "SPARSE_MAX_QUERIES",
+           "ROUTER_BIAS_STD",
+           "kth_largest", "index_scores", "select_mask", "selected_positions"]
+
+# query tokens in a call up to which each query gathers its own rows
+SPARSE_MAX_QUERIES = 256
+# queries scored at a time by the indexer ([tile, heads, context] in f32)
+INDEX_QUERY_TILE = 64
+INDEX_NORM_EPS = 1e-6
+# standard deviation of the seeded e_score_correction_bias.  The eight
+# largest of 256 sigmoid scores lie ~0.02 apart, so a bias of 0.1 chose the
+# same few experts for every token (the busiest took 10x its share, and the
+# experts held here 0.4-2x theirs by the seed: decode time moved with it);
+# at 0.01 the bias still changes about one choice in eleven and the load
+# stays as even as the trained bias is there to keep it
+ROUTER_BIAS_STD = 0.01
+NEG_INF = -jnp.inf
+
+
+@dataclass
+class GlmDsaConfig:
+    vocab_size: int
+    hidden_size: int
+    num_layers: int
+    num_heads: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    kv_lora_rank: int
+    q_lora_rank: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int          # experts held HERE
+    router_experts: int            # experts the router chooses among
+    expert_first: int              # index of the first expert held here
+    num_experts_per_tok: int
+    n_shared_experts: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    index_n_heads: int
+    index_head_dim: int
+    index_topk: int
+    indexer_types: tuple           # per layer: "full" | "shared"
+    mlp_layer_types: tuple         # per layer: "dense" | "sparse"
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 10000.0
+    max_position_embeddings: int = 4096
+    dtype: str = "bfloat16"
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    # ---- engine-facing surface (duck-typed like ModelConfig) ----
+    @property
+    def num_kv_heads(self) -> int:
+        return 1
+
+    @property
+    def head_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def jax_dtype(self):
+        return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[self.dtype]
+
+    @property
+    def full_layers(self) -> int:
+        return sum(t == "full" for t in self.indexer_types)
+
+    @classmethod
+    def from_hf_config(cls, cfg: dict, dtype: str = "bfloat16"
+                       ) -> "GlmDsaConfig":
+        """The published ``glm_moe_dsa`` keys -> GlmDsaConfig.  Raises on
+        what this port does not compute, rather than computing something
+        else in silence.  ``expert_parallel`` (not a published key) says
+        which share of a layer's experts this chip holds:
+        ``{"router_experts": 256, "first_expert": 0}`` beside
+        ``n_routed_experts`` = the number held."""
+        g = cfg.get
+        n = int(g("num_hidden_layers"))
+        types = tuple(g("indexer_types") or ())
+        mlps = tuple(g("mlp_layer_types") or (
+            ["dense"] * int(g("first_k_dense_replace", 0))
+            + ["sparse"] * (n - int(g("first_k_dense_replace", 0)))))
+        if len(types) != n or len(mlps) != n:
+            raise ValueError(
+                f"indexer_types ({len(types)}) and mlp_layer_types "
+                f"({len(mlps)}) must name each of the {n} layers")
+        if set(types) - {"full", "shared"} or set(mlps) - {"dense", "sparse"}:
+            raise NotImplementedError(
+                f"layer types {sorted(set(types) | set(mlps))}")
+        if types[0] != "full":
+            raise ValueError("the first layer has no index to share")
+        if g("scoring_func", "sigmoid") not in ("sigmoid", "softmax"):
+            raise NotImplementedError(f"scoring_func {g('scoring_func')!r}")
+        if g("topk_method", "noaux_tc") not in ("noaux_tc", "greedy"):
+            raise NotImplementedError(f"topk_method {g('topk_method')!r}")
+        if int(g("n_group", 1) or 1) != 1 or int(g("topk_group", 1) or 1) != 1:
+            raise NotImplementedError("group-limited expert choice")
+        if bool(g("attention_bias", False)):
+            raise NotImplementedError("attention_bias=True")
+        if g("hidden_act", "silu") != "silu":
+            raise NotImplementedError(f"hidden_act {g('hidden_act')!r}")
+        if int(g("moe_layer_freq", 1)) != 1:
+            raise NotImplementedError("moe_layer_freq != 1")
+        if not g("rope_interleave", True) or not g(
+                "indexer_rope_interleave", True):
+            raise NotImplementedError("rotate-half RoPE for this family")
+        if g("index_topk_pattern") is not None:
+            raise NotImplementedError("index_topk_pattern")
+        if bool(g("tie_word_embeddings", False)):
+            raise NotImplementedError("tie_word_embeddings=True")
+        rope = g("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default" or g("rope_scaling"):
+            raise NotImplementedError("RoPE scaling for this family")
+        ep = g("expert_parallel") or {}
+        held = int(g("n_routed_experts"))
+        total = int(ep.get("router_experts", held))
+        first = int(ep.get("first_expert", 0))
+        if not 0 <= first <= total - held:
+            raise ValueError(
+                f"experts {first}..{first + held - 1} are not among the "
+                f"router's {total}")
+        return cls(
+            vocab_size=int(g("vocab_size")), hidden_size=int(g("hidden_size")),
+            num_layers=n, num_heads=int(g("num_attention_heads")),
+            qk_nope_head_dim=int(g("qk_nope_head_dim")),
+            qk_rope_head_dim=int(g("qk_rope_head_dim")),
+            v_head_dim=int(g("v_head_dim")),
+            kv_lora_rank=int(g("kv_lora_rank")),
+            q_lora_rank=int(g("q_lora_rank")),
+            intermediate_size=int(g("intermediate_size")),
+            moe_intermediate_size=int(g("moe_intermediate_size")),
+            n_routed_experts=held, router_experts=total, expert_first=first,
+            num_experts_per_tok=int(g("num_experts_per_tok")),
+            n_shared_experts=int(g("n_shared_experts", 1)),
+            routed_scaling_factor=float(g("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(g("norm_topk_prob", True)),
+            index_n_heads=int(g("index_n_heads")),
+            index_head_dim=int(g("index_head_dim")),
+            index_topk=int(g("index_topk")),
+            indexer_types=types, mlp_layer_types=mlps,
+            scoring_func=g("scoring_func", "sigmoid"),
+            topk_method=g("topk_method", "noaux_tc"),
+            rms_norm_eps=float(g("rms_norm_eps", 1e-5)),
+            rope_theta=float(rope.get("rope_theta", g("rope_theta", 10000.0))),
+            max_position_embeddings=int(g("max_position_embeddings", 4096)),
+            dtype=dtype,
+        )
+
+
+def kth_largest(x: jax.Array, k) -> jax.Array:
+    """The k-th largest value of each row of ``x`` [..., C] (f32; ``k`` an
+    int or an int array [..., 1], one k a row), exactly,
+    as [..., 1]; the smallest value where a row has fewer than k.  A radix
+    select on the order-preserving integer image of the floats: 32 passes
+    of compare-and-count, no sort.  (−0.0 orders below +0.0 here.)"""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    keys = jax.lax.bitcast_convert_type(
+        bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF)), jnp.uint32
+    ) ^ jnp.uint32(0x80000000)
+
+    def one(i, best):
+        cand = best | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(keys >= cand, axis=-1, keepdims=True) >= k
+        return jnp.where(enough, cand, best)
+
+    best = jax.lax.fori_loop(
+        0, 32, one, jnp.zeros((*x.shape[:-1], 1), jnp.uint32))
+    signed = jax.lax.bitcast_convert_type(
+        best ^ jnp.uint32(0x80000000), jnp.int32)
+    return jax.lax.bitcast_convert_type(
+        signed ^ ((signed >> 31) & jnp.int32(0x7FFFFFFF)), jnp.float32)
+
+
+def select_mask(scores: jax.Array, seen: jax.Array, k: int) -> jax.Array:
+    """bool [..., C]: exactly the positions ``lax.top_k(scores, k)`` would
+    pick among the ``seen`` ones — those above the k-th score and, of those
+    equal to it, the earliest — all of them where fewer than k are seen.
+    ``scores`` is -inf where not seen.  Two radix selects, no sort."""
+    at = jnp.arange(scores.shape[-1], dtype=jnp.float32)
+    kth = kth_largest(scores, k)
+    above, tie = scores > kth, scores == kth
+    room = k - above.sum(axis=-1, keepdims=True)
+    # the ``room`` earliest of the tied: the room-th smallest position among
+    # them is minus the room-th largest of the negated positions
+    last = -kth_largest(jnp.where(tie, -at, NEG_INF), room)
+    return seen & (above | (tie & (at <= last)))
+
+
+def selected_positions(sel: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """The positions a mask [N, C] selects (at most k a row), in ascending
+    order, as (positions [N, k] int32, count [N]); slots past a row's count
+    hold 0.  A compaction without a sort or a scatter: count the selected in
+    lanes of 128, find each output slot's lane group by its running count,
+    and its place within the group by a prefix sum done as a matmul."""
+    n, c = sel.shape
+    lanes = 128
+    sel = jnp.pad(sel, ((0, 0), (0, -c % lanes)))
+    g = sel.shape[1] // lanes
+    groups = sel.reshape(n, g, lanes)
+    upto = jnp.cumsum(groups.sum(axis=-1, dtype=jnp.int32), axis=-1)  # [N, G]
+    count = upto[:, -1]
+    slot = jnp.arange(k, dtype=jnp.int32)
+    group = (upto[:, None, :] <= slot[None, :, None]).sum(
+        axis=-1, dtype=jnp.int32)                                     # [N, k]
+    group = jnp.minimum(group, g - 1)
+    before = jnp.take_along_axis(
+        jnp.pad(upto, ((0, 0), (1, 0))), group, axis=1)               # [N, k]
+    rows = jnp.take_along_axis(groups, group[:, :, None], axis=1)     # [N,k,128]
+    ones = jnp.tril(jnp.ones((lanes, lanes), jnp.bfloat16)).T
+    running = jnp.dot(rows.astype(jnp.bfloat16), ones,
+                      preferred_element_type=jnp.float32)             # prefix
+    want = (slot[None, :] - before + 1).astype(jnp.float32)
+    lane = jnp.argmax(rows & (running == want[:, :, None]), axis=-1)
+    pos = group * lanes + lane.astype(jnp.int32)
+    return jnp.where(slot[None, :] < count[:, None], pos, 0), count
+
+
+def index_scores(q: jax.Array, w: jax.Array, keys: jax.Array) -> jax.Array:
+    """I[b, s, c] = Hi^-1/2 · Di^-1/2 · Σ_h w[b,s,h] · relu(q[b,s,h] · k[b,c]).
+    q [B, S, Hi, Di], w [B, S, Hi], keys [B, C, Di] -> f32 [B, S, C],
+    ``INDEX_QUERY_TILE`` queries at a time."""
+    b, s, hi, di = q.shape
+    scale = (hi * di) ** -0.5
+
+    def tile(args):
+        qt, wt = args                       # [B, t, Hi, Di], [B, t, Hi]
+        dots = jnp.einsum("bthd,bcd->bthc", qt, keys,
+                          preferred_element_type=jnp.float32)
+        return jnp.einsum("bthc,bth->btc", jax.nn.relu(dots),
+                          wt.astype(jnp.float32)) * scale
+
+    t = INDEX_QUERY_TILE
+    if s <= t or s % t:
+        return tile((q, w))
+    qs = jnp.moveaxis(q.reshape(b, s // t, t, hi, di), 1, 0)
+    ws = jnp.moveaxis(w.reshape(b, s // t, t, hi), 1, 0)
+    out = jax.lax.map(tile, (qs, ws))       # [S/t, B, t, C]
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, -1)
+
+
+def _layer_norm(x, weight, bias, eps):
+    xf = x.astype(jnp.float32)
+    mu = xf.mean(axis=-1, keepdims=True)
+    var = ((xf - mu) ** 2).mean(axis=-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
+    return (y * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def _rope_head(x, positions, inv_freq, rope_dim):
+    """Interleaved RoPE on the first ``rope_dim`` of the last axis."""
+    return jnp.concatenate(
+        [apply_rope_interleaved(x[..., :rope_dim], positions, inv_freq),
+         x[..., rope_dim:]], axis=-1)
+
+
+@dataclass(frozen=True)
+class _Run:
+    """Consecutive layers of one kind: a scan."""
+    kind: str        # params group: "<mlp>_<indexer>"
+    start: int       # first index within the group's stacks
+    count: int
+    layer0: int      # first layer index (row of the latent cache)
+    full0: int       # first row of the indexer-key cache (full kinds)
+
+
+class GlmDsaModel:
+    """Engine-facing functional model (same protocol as LlamaModel)."""
+
+    # the cache is a pytree of two arrays: EngineCore refuses what would
+    # move blocks without knowing that
+    two_part_cache = True
+    supports_ragged_prefill = False
+    supports_unified_dispatch = False
+    supports_seq_parallel = False
+
+    def __init__(self, config: GlmDsaConfig):
+        self.config = config
+        self.sm_scale = float(config.qk_head_dim ** -0.5)
+        self.inv_freq = rope_inv_freq(config.qk_rope_head_dim,
+                                      config.rope_theta)
+        kinds = [f"{m}_{i}" for m, i in zip(config.mlp_layer_types,
+                                            config.indexer_types)]
+        runs, seen, fulls = [], {}, 0
+        for li, kind in enumerate(kinds):
+            at = seen.get(kind, 0)
+            if runs and runs[-1].kind == kind:
+                last = runs[-1]
+                runs[-1] = _Run(kind, last.start, last.count + 1,
+                                last.layer0, last.full0)
+            else:
+                runs.append(_Run(kind, at, 1, li, fulls))
+            seen[kind] = at + 1
+            fulls += kind.endswith("_full")
+        self.runs = tuple(runs)
+        self.group_sizes = seen
+
+    # ------------------------------------------------------------------ init
+    def init_params(self, rng: jax.Array) -> Params:
+        cfg = self.config
+        dt = cfg.jax_dtype
+        dm, h = cfg.hidden_size, cfg.num_heads
+        qk, rope, vd = cfg.qk_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        r, ql = cfg.kv_lora_rank, cfg.q_lora_rank
+        hi, di = cfg.index_n_heads, cfg.index_head_dim
+        keys = iter(jax.random.split(rng, 128))
+
+        def dense(shape, fan_in):
+            return (jax.random.normal(next(keys), shape, jnp.float32)
+                    / math.sqrt(fan_in)).astype(dt)
+
+        def group(kind: str, n: int) -> dict:
+            mlp, idx = kind.split("_")
+            p = {
+                "attn_norm": jnp.ones((n, dm), dt),
+                "mlp_norm": jnp.ones((n, dm), dt),
+                "q_a": dense((n, dm, ql), dm),
+                "q_a_norm": jnp.ones((n, ql), dt),
+                "q_b": dense((n, ql, h * qk), ql),
+                "kv_a": dense((n, dm, r + rope), dm),
+                "kv_a_norm": jnp.ones((n, r), dt),
+                "kv_b": dense((n, r, h * (cfg.qk_nope_head_dim + vd)), r),
+                "wo": dense((n, h * vd, dm), h * vd),
+            }
+            if idx == "full":
+                p.update(
+                    idx_wq_b=dense((n, ql, hi * di), ql),
+                    idx_wk=dense((n, dm, di), dm),
+                    idx_k_norm_w=jnp.ones((n, di), dt),
+                    idx_k_norm_b=(0.1 * jax.random.normal(
+                        next(keys), (n, di), jnp.float32)).astype(dt),
+                    idx_weights=dense((n, dm, hi), dm),
+                )
+            if mlp == "dense":
+                f = cfg.intermediate_size
+                p.update(w_gate=dense((n, dm, f), dm),
+                         w_up=dense((n, dm, f), dm),
+                         w_down=dense((n, f, dm), f))
+            else:
+                e, f = cfg.n_routed_experts, cfg.moe_intermediate_size
+                fs = f * cfg.n_shared_experts
+                p.update(
+                    router=dense((n, dm, cfg.router_experts), dm),
+                    # e_score_correction_bias: non-zero, so that seeded
+                    # weights exercise the choice-only bias
+                    router_bias=ROUTER_BIAS_STD * jax.random.normal(
+                        next(keys), (n, cfg.router_experts), jnp.float32),
+                    w_gate=dense((n, e, dm, f), dm),
+                    w_up=dense((n, e, dm, f), dm),
+                    w_down=dense((n, e, f, dm), f),
+                    shared_gate=dense((n, dm, fs), dm),
+                    shared_up=dense((n, dm, fs), dm),
+                    shared_down=dense((n, fs, dm), fs),
+                )
+            return p
+
+        return {
+            "embed": dense((cfg.vocab_size, dm), dm),
+            "groups": {kind: group(kind, n)
+                       for kind, n in sorted(self.group_sizes.items())},
+            "final_norm": jnp.ones((dm,), dt),
+            "lm_head": dense((dm, cfg.vocab_size), dm),
+        }
+
+    # -------------------------------------------------------------- sharding
+    def partition_specs(self) -> Params:
+        raise NotImplementedError(
+            "GlmDsaModel serves one chip's share of an expert-parallel "
+            "deployment on one chip; it has no partition specs (the "
+            "exchange of an expert-parallel layer across chips is not built)")
+
+    def cache_spec(self, quant: bool = False):
+        if quant:
+            raise NotImplementedError("int8 latent cache")
+        return {"latent": P(None, None, None, None, None),
+                "index_k": P(None, None, None, None)}
+
+    # --------------------------------------------------------------- kv cache
+    def init_kv_cache(self, num_blocks: int, block_size: int, dtype=None):
+        """The two-part cache of ops/latent_cache.py: the latent row once a
+        token and layer, the indexer's key beside it in ``full`` layers."""
+        cfg = self.config
+        if dtype is not None and jnp.dtype(dtype) != jnp.dtype(cfg.jax_dtype):
+            raise NotImplementedError(f"latent cache dtype {dtype!r}")
+        return latent_cache.init_latent_cache(
+            cfg.num_layers, cfg.full_layers, num_blocks, block_size,
+            cfg.head_dim, cfg.index_head_dim, cfg.jax_dtype)
+
+    def attention_impls(self) -> dict[str, tuple[str, str]]:
+        """phase -> ("pallas" | "xla", why) for the engine's start-up line."""
+        out = {p: sparse_attention_impl(p) for p in ("decode", "prefill")}
+        impl, why = out["prefill"]
+        out["prefill_chunk"] = (impl, f"{why}; chunks over "
+                                f"{SPARSE_MAX_QUERIES} tokens score the whole "
+                                "context and mask the selection")
+        return out
+
+    # ---------------------------------------------------------------- forward
+    def _select(self, lp, fi, x, c_q, positions, cache, block_tables,
+                seq_lens, slot_idx, ctx_blocks, sparse: bool):
+        """The indexer of a ``full`` layer: writes this call's keys, scores
+        every cached position and returns the selection — (slots [N, K],
+        nvalid [N]) for the gather, or a mask [B, S, C] for a dense chunk —
+        with the updated cache."""
+        cfg = self.config
+        b, s, _ = x.shape
+        hi, di, rope = (cfg.index_n_heads, cfg.index_head_dim,
+                        cfg.qk_rope_head_dim)
+        bs = cache["index_k"].shape[2]
+        q = (c_q @ lp["idx_wq_b"]).reshape(b, s, hi, di)
+        q = _rope_head(q, positions, self.inv_freq, rope)
+        k = _layer_norm(x @ lp["idx_wk"], lp["idx_k_norm_w"],
+                        lp["idx_k_norm_b"], INDEX_NORM_EPS)
+        k = _rope_head(k[:, :, None, :], positions, self.inv_freq,
+                       rope)[:, :, 0]
+        w = x @ lp["idx_weights"]                        # [B, S, Hi]
+        index_k = latent_cache.write_rows(
+            cache["index_k"], fi, k.reshape(b * s, di),
+            slot_idx.reshape(b * s))
+        cache = {**cache, "index_k": index_k}
+        keys = index_k[fi, block_tables[:, :ctx_blocks]].reshape(
+            b, ctx_blocks * bs, di)
+        scores = index_scores(q, w, keys)                # [B, S, C] f32
+        at = jnp.arange(ctx_blocks * bs, dtype=jnp.int32)
+        seen = ((at[None, None, :] <= positions[:, :, None])
+                & (at[None, None, :] < seq_lens[:, None, None]))
+        scores = jnp.where(seen, scores, NEG_INF)
+        k_sel = min(cfg.index_topk, ctx_blocks * bs)
+        # the same positions in both forms (``lax.top_k``'s, without its
+        # sort: two 8.6 ms sorts a decode step on the chip, 40% of it)
+        sel = select_mask(scores, seen, k_sel)
+        if not sparse:
+            return sel, cache
+        c = scores.shape[-1]
+        picked, nvalid = selected_positions(sel.reshape(b * s, c), k_sel)
+        vals = jnp.take_along_axis(scores.reshape(b * s, c), picked, axis=1)
+        slots = latent_cache.flat_slots(
+            block_tables, picked.reshape(b, s * k_sel), bs)
+        # (slots, nvalid) is what attention reads; positions and scores ride
+        # along for ``forward(probe=True)`` and cost nothing otherwise
+        return (slots.reshape(b * s, k_sel), nvalid.reshape(b * s),
+                picked.reshape(b * s, k_sel), vals.reshape(b * s, k_sel)
+                ), cache
+
+    def _attention(self, lp, li, fi, h_in, positions, cache, block_tables,
+                   seq_lens, slot_idx, sel, ctx_blocks, sparse, full):
+        cfg = self.config
+        b, s, _ = h_in.shape
+        nh, nope, vd, r = (cfg.num_heads, cfg.qk_nope_head_dim,
+                           cfg.v_head_dim, cfg.kv_lora_rank)
+        with jax.named_scope("attn_proj"):
+            x = rms_norm(h_in, lp["attn_norm"], cfg.rms_norm_eps)
+            c_q = rms_norm(x @ lp["q_a"], lp["q_a_norm"], cfg.rms_norm_eps)
+            q = (c_q @ lp["q_b"]).reshape(b, s, nh, cfg.qk_head_dim)
+            q_pe = apply_rope_interleaved(q[..., nope:], positions,
+                                          self.inv_freq)
+            ckv = x @ lp["kv_a"]
+            c_hat = rms_norm(ckv[..., :r], lp["kv_a_norm"], cfg.rms_norm_eps)
+            k_pe = apply_rope_interleaved(
+                ckv[:, :, None, r:], positions, self.inv_freq)[:, :, 0]
+            kv_b = lp["kv_b"].reshape(r, nh, nope + vd)
+            # absorption: q_nope[h]·(Wk[h]ᵀ c) = (Wk[h] q_nope[h])·c
+            q_lat = jnp.concatenate(
+                [jnp.einsum("bshn,rhn->bshr", q[..., :nope], kv_b[..., :nope]),
+                 q_pe], axis=-1)
+            row = jnp.concatenate([c_hat, k_pe], axis=-1)
+            latent = latent_cache.write_latent(
+                cache["latent"], li,
+                latent_cache.pack_rows(row.reshape(b * s, -1)),
+                slot_idx.reshape(b * s))
+            cache = {**cache, "latent": latent}
+        with jax.named_scope("attn"):
+            if full:
+                with jax.named_scope("indexer"):
+                    sel, cache = self._select(
+                        lp, fi, x, c_q, positions, cache, block_tables,
+                        seq_lens, slot_idx, ctx_blocks, sparse)
+            if sparse:
+                slots, nvalid = sel[:2]
+                out = sparse_latent_attention(
+                    q_lat.reshape(b * s, nh, -1), cache["latent"], li, slots,
+                    nvalid, sm_scale=self.sm_scale,
+                    phase="decode" if s == 1 else "prefill",
+                ).reshape(b, s, nh, -1)
+            else:
+                out = latent_cache.masked_attention(
+                    q_lat, cache["latent"], li,
+                    block_tables[:, :ctx_blocks], sel, self.sm_scale, dv=r)
+        with jax.named_scope("attn_out"):
+            o = jnp.einsum("bshr,rhv->bshv", out[..., :r].astype(h_in.dtype),
+                           kv_b[..., nope:])
+            h = h_in + o.reshape(b, s, nh * vd) @ lp["wo"]
+        return h, cache, sel
+
+    def _mlp(self, group: dict, lp: dict, i, x, dense: bool):
+        cfg = self.config
+        if dense:
+            return (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) \
+                @ lp["w_down"]
+        b, s, d = x.shape
+        xf = x.reshape(b * s, d)
+        with jax.named_scope("moe_router"):
+            weights, topi = moe_route(cfg, lp["router"], xf,
+                                      lp["router_bias"])
+        with jax.named_scope("moe_experts"):
+            # the group's whole expert stacks, read where they lie
+            routed = grouped_expert_dispatch(
+                xf, weights, topi, cfg.router_experts,
+                group["w_gate"], group["w_up"], group["w_down"],
+                jax.nn.silu, layer=i,
+                held=(cfg.expert_first, cfg.n_routed_experts))
+        shared = (jax.nn.silu(xf @ lp["shared_gate"])
+                  * (xf @ lp["shared_up"])) @ lp["shared_down"]
+        return (routed + shared).reshape(b, s, d)
+
+    def forward(self, params, tokens, positions, cache, block_tables,
+                seq_lens, slot_idx, prefix_blocks=None, probe=False):
+        """(hidden [B,S,Dm], cache).  ``prefix_blocks`` (static) bounds the
+        context a prefill chunk reads: that many cached blocks plus its
+        own; None reads the whole block table.  ``probe`` (static; gather
+        form only) also returns, for every ``full`` layer in order, what its
+        indexer selected: (positions [N, K], scores [N, K], nvalid [N]) —
+        for scripts/glm_longctx_check.py, not for serving."""
+        cfg = self.config
+        b, s = tokens.shape
+        bs = cache["latent"].shape[2]
+        m = block_tables.shape[1]
+        ctx_blocks = m if prefix_blocks is None else min(
+            m, prefix_blocks + -(-s // bs))
+        sparse = b * s <= SPARSE_MAX_QUERIES
+        with jax.named_scope("embed"):
+            hidden = params["embed"][tokens].astype(cfg.jax_dtype)
+        if probe and not sparse:
+            raise ValueError(f"probe needs at most {SPARSE_MAX_QUERIES} "
+                             "query tokens (the gather form)")
+        probes = []
+        if sparse:
+            k_sel = min(cfg.index_topk, ctx_blocks * bs)
+            sel = (jnp.zeros((b * s, k_sel), jnp.int32),
+                   jnp.zeros((b * s,), jnp.int32),
+                   jnp.zeros((b * s, k_sel), jnp.int32),
+                   jnp.zeros((b * s, k_sel), jnp.float32))
+        else:
+            sel = jnp.zeros((b, s, ctx_blocks * bs), bool)
+
+        expert_keys = ("w_gate", "w_up", "w_down")
+        for run in self.runs:
+            group = params["groups"][run.kind]
+            dense = run.kind.startswith("dense")
+            full = run.kind.endswith("_full")
+            sliced = {k: v for k, v in group.items()
+                      if dense or k not in expert_keys}
+
+            def step(carry, at, group=group, sliced=sliced, dense=dense,
+                     full=full, shared_sel=sel):
+                h, cache, sel = carry if full else (*carry, shared_sel)
+                i, li, fi = at
+                lp = jax.tree.map(lambda a: a[i], sliced)
+                h, cache, sel = self._attention(
+                    lp, li, fi, h, positions, cache, block_tables, seq_lens,
+                    slot_idx, sel, ctx_blocks, sparse, full)
+                with jax.named_scope("mlp"):
+                    x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
+                    h = h + self._mlp(group, lp, i, x, dense)
+                seen = (sel[2], sel[3], sel[1]) if probe and full else None
+                return ((h, cache, sel) if full else (h, cache)), seen
+
+            n = jnp.arange(run.count, dtype=jnp.int32)
+            xs = (run.start + n, run.layer0 + n, run.full0 + n)
+            init = (hidden, cache, sel) if full else (hidden, cache)
+            out, seen = jax.lax.scan(step, init, xs)
+            if full:
+                hidden, cache, sel = out
+            else:
+                hidden, cache = out
+            if seen is not None:
+                probes += [jax.tree.map(lambda a, i=i: a[i], seen)
+                           for i in range(run.count)]
+        hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps)
+        return (hidden, cache, probes) if probe else (hidden, cache)
+
+    def compute_logits(self, params, hidden):
+        with jax.named_scope("logits"):
+            w = params["lm_head"]
+            return jnp.matmul(hidden.astype(w.dtype), w,
+                              preferred_element_type=jnp.float32)

@@ -684,7 +684,8 @@ def _moe_mlp(cfg: ModelConfig, lp: dict, x: jax.Array,
 
 
 def grouped_expert_dispatch(xf, weights, topi, num_experts,
-                            w_gate, w_up, w_down, act, layer=None):
+                            w_gate, w_up, w_down, act, layer=None,
+                            held=None):
     """The grouped-MoE core, shared across model families (Llama-family
     MoE here, DeepSeekMoE in models/deepseek.py): sort token→expert
     assignments by expert, run each projection as ONE ``lax.ragged_dot``
@@ -698,14 +699,29 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
     is a Mosaic custom call that takes whole buffers, so a layer sliced
     out of the stack first is a copy of E·Dm·F weights per projection;
     this form reads them where they lie, and the kernel's grid visits
-    only (group, row-tile) pairs that have rows."""
+    only (group, row-tile) pairs that have rows.
+
+    With ``held`` = (first, count) the ``w_*`` hold only experts
+    ``first .. first+count-1`` of the ``num_experts`` the router chose
+    among (one chip's share of an expert-parallel layer).  Assignments to
+    the others sort last, belong to no group, and add nothing: the result
+    is the part of the layer's sum that the held experts give."""
     t, d = xf.shape
     k = topi.shape[1]
     flat_e = topi.reshape(t * k)
+    here = None
+    if held is not None:
+        first, num_experts = held
+        here = (flat_e >= first) & (flat_e < first + num_experts)
+        flat_e = jnp.where(here, flat_e - first, num_experts)
     order = jnp.argsort(flat_e)          # stable: ties keep token order
     token_idx = order // k               # source token of each sorted row
     xs = xf[token_idx]                   # [T*k, Dm] gather
     group_sizes = jnp.bincount(flat_e, length=num_experts).astype(jnp.int32)
+    if here is not None:
+        # an id of ``num_experts`` (held elsewhere) is counted in no group
+        group_sizes = jnp.bincount(
+            flat_e, length=num_experts + 1)[:num_experts].astype(jnp.int32)
     if layer is not None:
         groups = w_gate.shape[0] * num_experts
         group_sizes = jax.lax.dynamic_update_slice(
@@ -716,6 +732,9 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
     up = jax.lax.ragged_dot(xs, w_up, group_sizes)
     out = jax.lax.ragged_dot(act(gate) * up, w_down, group_sizes)  # [T*k, Dm]
     out = out * weights.reshape(t * k)[order, None].astype(out.dtype)
+    if here is not None:
+        # rows past the last group are whatever the grouped dot left there
+        out = jnp.where(here[order, None], out, 0)
     # unsort (inverse permutation) then reduce the k slots of each token;
     # gather+reshape-sum keeps the combine deterministic (no scatter-add)
     return out[jnp.argsort(order)].reshape(t, k, d).sum(axis=1)

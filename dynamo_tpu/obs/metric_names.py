@@ -113,6 +113,12 @@ class EngineMetric:
     PIPELINE_DRAINS_TOTAL = "dynamo_tpu_engine_pipeline_drains_total"
     # operand upload (EngineCore._upload_dispatch)
     OPERAND_BUFFERS_TOTAL = "dynamo_tpu_engine_operand_buffers_total"
+    # prefix reuse and sparse attention, from lengths the host has
+    PROMPT_TOKENS_ADMITTED_TOTAL = (
+        "dynamo_tpu_engine_prompt_tokens_admitted_total")
+    PROMPT_TOKENS_CACHED_TOTAL = "dynamo_tpu_engine_prompt_tokens_cached_total"
+    ATTN_CONTEXT_TOKENS_TOTAL = "dynamo_tpu_engine_attn_context_tokens_total"
+    ATTN_SELECTED_TOKENS_TOTAL = "dynamo_tpu_engine_attn_selected_tokens_total"
     # engine/counters.py mesh_shape
     MESH_TP = "dynamo_tpu_engine_mesh_tp"
     MESH_DEVICES = "dynamo_tpu_engine_mesh_devices"
@@ -223,6 +229,10 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.AHEAD_DISCARDS_TOTAL: ("counter", ()),
     EngineMetric.PIPELINE_DRAINS_TOTAL: ("counter", ()),
     EngineMetric.OPERAND_BUFFERS_TOTAL: ("counter", ()),
+    EngineMetric.PROMPT_TOKENS_ADMITTED_TOTAL: ("counter", ()),
+    EngineMetric.PROMPT_TOKENS_CACHED_TOTAL: ("counter", ()),
+    EngineMetric.ATTN_CONTEXT_TOKENS_TOTAL: ("counter", ()),
+    EngineMetric.ATTN_SELECTED_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.MESH_TP: ("gauge", ()),
     EngineMetric.MESH_DEVICES: ("gauge", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),

@@ -43,6 +43,9 @@ __all__ = [
     "paged_attention_layer",
     "prefill_attention",
     "ragged_prefill_attention",
+    "SPARSE_PHASES",
+    "sparse_attention_impl",
+    "sparse_latent_attention",
 ]
 
 
@@ -99,6 +102,60 @@ def attention_impl(
         # it does not follow the data's head-major lane split
         return "xla", "int8 KV scale pool is not sharded per kv head"
     return "pallas", "tpu" if tp == 1 else f"tpu, shard_map over tp={tp}"
+
+
+# sparse latent attention (ops/pallas/mla_sparse_attention.py): the phase is
+# the kernel's name in a profile, the knob pins it to the XLA gather
+SPARSE_PHASES = {
+    "decode": "DYNAMO_DISABLE_PALLAS_DECODE",
+    "prefill": "DYNAMO_DISABLE_PALLAS_PREFILL",
+}
+
+
+def sparse_attention_impl(phase: str) -> tuple[str, str]:
+    """``attention_impl`` for the sparse latent-attention kernel: one
+    shared latent head and a cache that is not sharded, so only the
+    environment and the backend decide."""
+    for var in ("DYNAMO_DISABLE_PALLAS", SPARSE_PHASES[phase]):
+        if os.environ.get(var):
+            return "xla", f"{var} is set"
+    backend = jax.default_backend()
+    if backend != "tpu":
+        return "xla", f"backend is {backend}"
+    return "pallas", "tpu"
+
+
+def sparse_latent_attention(
+    q: jax.Array,        # [N, H, width] latent-space queries
+    latent: jax.Array,   # uint32 [L, N_blocks, Bs, 1, W] (ops/latent_cache.py)
+    layer: jax.Array,    # scalar int32
+    slots: jax.Array,    # [N, K] flat token slots of the layer each query reads
+    nvalid: jax.Array,   # [N] how many of them count
+    *, sm_scale: float, phase: str,
+) -> jax.Array:
+    """Each query's softmax-weighted sum of its own list of cache rows,
+    f32 [N, H, 2·W] (the row's elements, zero padded)."""
+    from dynamo_tpu.ops import latent_cache
+
+    if sparse_attention_impl(phase)[0] != "pallas":
+        return latent_cache.sparse_attention_xla(
+            q, latent, layer, slots, nvalid, sm_scale)
+    from dynamo_tpu.ops.pallas.mla_sparse_attention import (
+        mla_sparse_attention,
+    )
+    from dynamo_tpu.ops.pallas.registry import MLA_SPARSE_LIST_ALIGN
+
+    l, n, bs, _, w = latent.shape
+    q_lo, q_hi = latent_cache.split_query(q)
+    # a list is sliced out of a flat int32 array, whose tiling is 1,024
+    # (a short list, 32 rows under a 32-token prompt, was refused by the
+    # chip's compiler); the padding lies past ``nvalid`` and is never read
+    pad = -slots.shape[1] % MLA_SPARSE_LIST_ALIGN
+    slots = jnp.pad(slots, ((0, 0), (0, pad)))
+    o_lo, o_hi = mla_sparse_attention(
+        q_lo, q_hi, slots + layer * (n * bs), nvalid,
+        latent.reshape(l * n * bs, 1, w), sm_scale=sm_scale, phase=phase)
+    return jnp.concatenate([o_lo, o_hi], axis=-1)
 
 
 def tp_size() -> int:

@@ -40,6 +40,10 @@ __all__ = [
     "INT8_MATMUL_BM",
     "INT8_MATMUL_BN",
     "INT8_MATMUL_BK",
+    "MLA_SPARSE_ROWS_PER_TILE",
+    "MLA_SPARSE_LIST_ALIGN",
+    "MLA_MASKED_TOKENS_PER_TILE",
+    "MLA_MASKED_KEYS_PER_TILE",
     "V5E_VMEM_BYTES",
     "VMEM_BUDGET_BYTES",
     "KERNELS",
@@ -50,6 +54,9 @@ __all__ = [
     "prefill_kernel_cost",
     "ragged_kernel_cost",
     "int8_matmul_cost",
+    "mla_sparse_cost",
+    "mla_masked_cost",
+    "latent_dma_cost",
     "decode_cost_estimate",
     "prefill_cost_estimate",
     "ragged_cost_estimate",
@@ -70,6 +77,16 @@ PREFILL_BLOCKS_PER_CHUNK = 8
 INT8_MATMUL_BM = 128
 INT8_MATMUL_BN = 512
 INT8_MATMUL_BK = 512
+# sparse latent attention: cache rows gathered per double-buffered tile
+# (one DMA a row; 256 rows x 1536 B = 384 KiB a buffer)
+MLA_SPARSE_ROWS_PER_TILE = 256
+# ... and the multiple a query's row list is padded to (the tiling of a
+# flat int32 array, out of which the kernel slices one list)
+MLA_SPARSE_LIST_ALIGN = 1024
+# masked latent prefill: 16 tokens x 64 heads = 1,024 query rows meet 512
+# keys a grid step (scores 2 MiB, accumulator 2 MiB in f32)
+MLA_MASKED_TOKENS_PER_TILE = 16
+MLA_MASKED_KEYS_PER_TILE = 512
 
 # v5e VMEM is 128 MiB per core (accelerator guide); budget 75% of it —
 # the compiler needs headroom for spills and the double-buffer pipeline.
@@ -101,6 +118,21 @@ KERNELS = {
     },
     "int8_matmul": {
         "module": "dynamo_tpu.ops.pallas.int8_matmul",
+        "placeholder": False,
+    },
+    # sparse latent attention (one kernel; a profile shows it as
+    # mla_sparse_decode / mla_sparse_prefill) and the DMA-only movers
+    # that keep the latent cache in the layout it needs
+    "mla_sparse_attention": {
+        "module": "dynamo_tpu.ops.pallas.mla_sparse_attention",
+        "placeholder": False,
+    },
+    "latent_cache_dma": {
+        "module": "dynamo_tpu.ops.pallas.latent_cache_dma",
+        "placeholder": False,
+    },
+    "mla_masked_prefill": {
+        "module": "dynamo_tpu.ops.pallas.mla_masked_prefill",
         "placeholder": False,
     },
     "unified_ragged_attention": {
@@ -329,6 +361,31 @@ def int8_matmul_cost(
         + gm * gn * (bm * bn * out_bytes + bn * 4)        # out + scale
     )
     return _cost_dict(dma, 2 * m * n * k, 0)
+
+
+def mla_sparse_cost(n: int, h: int, w: int, k: int) -> dict:
+    """Sparse latent attention over N queries of K listed rows of W words,
+    every list full: each row is one 4·W-byte DMA; both halves of a row are
+    scored (2 · 2·H·W·K) and summed (2 · 2·H·W·K); one exp a (head, row)."""
+    rows = n * k
+    return _cost_dict(
+        dma=rows * 4 * w + n * k * 4 + 2 * n * h * w * 2 + 2 * n * h * w * 4,
+        flops=8 * h * w * rows, trans=h * rows)
+
+
+def mla_masked_cost(s: int, c: int, h: int, dq: int, dv: int,
+                    tq: int = MLA_MASKED_TOKENS_PER_TILE) -> dict:
+    """Masked latent prefill: every (query row, key) pair is scored over
+    Dq and summed over Dv; the context is read once a query tile."""
+    pairs = s * h * c
+    return _cost_dict(
+        dma=_cdiv(s, tq) * c * dq * 2 + s * c * 4 + s * h * (dq * 2 + dv * 4),
+        flops=2 * pairs * (dq + dv), trans=pairs)
+
+
+def latent_dma_cost(rows: int, row_bytes: int) -> dict:
+    """The latent cache's movers read and write every moved row once."""
+    return _cost_dict(dma=2 * rows * row_bytes, flops=0, trans=0)
 
 
 def _cost_dict(dma: int, flops: int, trans: int) -> dict:
@@ -815,6 +872,187 @@ def _int8_matmul_case() -> dict:
     }
 
 
+def _mla_sparse_case() -> dict:
+    """Five queries of four heads over 48 listed rows, 16 a tile: a full
+    list, lists ending inside a tile, one row, and an empty list.  The
+    poisoned run points the padding past each list at NaN rows."""
+    import jax.numpy as jnp
+
+    np = _np()
+    n, h, width, k, rows_total = 5, 4, 40, 48, 400
+    nvalid = [48, 17, 1, 0, 33]
+
+    def build():
+        from dynamo_tpu.ops import latent_cache
+
+        rng = np.random.default_rng(500)
+        rows = rng.normal(size=(rows_total, width)).astype(np.float32)
+        rows[-1] = np.nan                      # where poisoned padding points
+        latent = latent_cache.pack_rows(jnp.asarray(rows, jnp.bfloat16))
+        q = jnp.asarray(rng.normal(size=(n, h, width)) * 0.3, jnp.bfloat16)
+        slots = rng.integers(0, rows_total - 1, size=(n, k)).astype(np.int32)
+        return {"latent": latent[:, None, :], "q": q, "slots": slots,
+                "nvalid": jnp.asarray(nvalid, jnp.int32)}
+
+    def _lists(inp, poisoned):
+        slots = inp["slots"].copy()
+        for i, v in enumerate(nvalid):
+            slots[i, v:] = rows_total - 1 if poisoned else slots[i, 0]
+        return jnp.asarray(slots)
+
+    def run(inp, poisoned: bool):
+        from dynamo_tpu.ops import latent_cache
+        from dynamo_tpu.ops.pallas.mla_sparse_attention import (
+            mla_sparse_attention,
+        )
+
+        q_lo, q_hi = latent_cache.split_query(inp["q"])
+        o_lo, o_hi = mla_sparse_attention.__wrapped__(
+            q_lo, q_hi, _lists(inp, poisoned), inp["nvalid"], inp["latent"],
+            sm_scale=0.2, rows_per_tile=16, interpret=True)
+        return jnp.concatenate([o_lo, o_hi], axis=-1)
+
+    def oracle(inp):
+        from dynamo_tpu.ops import latent_cache
+
+        w = inp["latent"].shape[-1]
+        ref = np.asarray(latent_cache.sparse_attention_xla(
+            inp["q"], inp["latent"].reshape(1, rows_total, 1, 1, w), 0,
+            _lists(inp, False), inp["nvalid"], 0.2), np.float32)
+        live = np.ones(ref.shape, bool)
+        zero = np.zeros(ref.shape, bool)
+        zero[3] = True                         # the empty list
+        return ref, live, zero
+
+    def pricing():
+        return mla_sparse_cost(n, h, 128, k)
+
+    return {
+        "name": "sparse-latent", "kernel": "mla_sparse_attention",
+        "mode": "interpret", "atol": 2e-2,
+        "build": build, "run": run, "oracle": oracle, "pricing": pricing,
+    }
+
+
+def _mla_masked_case() -> dict:
+    """32 tokens of four heads over 256 keys in tiles of (8, 128): causal
+    masks with a few more holes, one query with nothing selected in its
+    first key tile, one with nothing at all.  The poisoned run makes the
+    keys no query selects huge: a masked key weighs exactly nothing, but a
+    cache row is an activation and never NaN, and 0 x NaN is what no
+    matrix unit can mask."""
+    import jax.numpy as jnp
+
+    np = _np()
+    s_, c, h, dq, dv = 32, 256, 4, 128, 128
+
+    def build():
+        rng = np.random.default_rng(700)
+        mask = np.tril(np.ones((s_, c), bool), k=c - s_)
+        mask &= rng.random((s_, c)) < 0.6
+        mask[5, :128] = False
+        mask[9] = False
+        mask[:, 200:208] = False                 # keys nobody selects
+        return {"q": jnp.asarray(rng.normal(size=(s_ * h, dq)) * 0.3,
+                                 jnp.bfloat16),
+                "ctx": rng.normal(size=(c, dq)).astype(np.float32),
+                "mask": mask}
+
+    def _ctx(inp, poisoned):
+        ctx = inp["ctx"].copy()
+        if poisoned:
+            ctx[200:208] = 1e6
+        return jnp.asarray(ctx, jnp.bfloat16)
+
+    def run(inp, poisoned: bool):
+        from dynamo_tpu.ops.pallas.mla_masked_prefill import (
+            mla_masked_prefill,
+        )
+
+        return mla_masked_prefill.__wrapped__(
+            inp["q"], _ctx(inp, poisoned),
+            jnp.where(jnp.asarray(inp["mask"]), 0.0, -1e30).astype(
+                jnp.float32),
+            heads=h, dv=dv, sm_scale=0.2, tokens_per_tile=8,
+            keys_per_tile=128, interpret=True)
+
+    def oracle(inp):
+        q = np.asarray(inp["q"], np.float32).reshape(s_, h, dq)
+        ctx = np.asarray(_ctx(inp, False), np.float32)
+        sc = np.einsum("shd,cd->shc", q, ctx) * 0.2
+        sc = np.where(inp["mask"][:, None, :], sc, -np.inf)
+        with np.errstate(invalid="ignore"):
+            p = np.exp(sc - sc.max(axis=-1, keepdims=True))
+            p = np.nan_to_num(p / p.sum(axis=-1, keepdims=True))
+        ref = np.einsum("shc,cd->shd", p, ctx[:, :dv]).reshape(s_ * h, dv)
+        live = np.ones(ref.shape, bool)
+        zero = np.zeros(ref.shape, bool)
+        zero[9 * h:10 * h] = True
+        return ref.astype(np.float32), live, zero
+
+    def pricing():
+        return mla_masked_cost(s_, c, h, dq, dv, tq=8)
+
+    return {
+        "name": "masked-latent", "kernel": "mla_masked_prefill",
+        "mode": "interpret", "atol": 2e-2,
+        "build": build, "run": run, "oracle": oracle, "pricing": pricing,
+    }
+
+
+def _latent_dma_case(kind: str) -> dict:
+    """``write``: seven rows of which two have no slot; ``gather``: five
+    blocks, one twice.  Pure copies: the oracle is exact."""
+    import jax.numpy as jnp
+
+    np = _np()
+    w, bs, blocks = 128, 4, 12
+
+    def build():
+        rng = np.random.default_rng(600)
+        cache = rng.integers(0, 1 << 20, size=(blocks * bs, 1, w))
+        rows = rng.integers(0, 1 << 20, size=(7, 1, w))
+        return {"cache": jnp.asarray(cache, jnp.uint32),
+                "rows": jnp.asarray(rows, jnp.uint32),
+                "slots": jnp.asarray([5, -1, 0, 47, 9, -1, 30], jnp.int32),
+                "ids": jnp.asarray([7, 2, 2, 11, 0], jnp.int32)}
+
+    def run(inp, poisoned: bool):
+        from dynamo_tpu.ops.pallas import latent_cache_dma as dma
+
+        if kind == "gather":
+            return dma.gather_blocks.__wrapped__(
+                inp["cache"].reshape(blocks, bs, 1, w), inp["ids"],
+                interpret=True)
+        rows = inp["rows"]
+        if poisoned:                           # rows without a slot: junk
+            rows = jnp.where((inp["slots"] < 0)[:, None, None],
+                             jnp.uint32(0xFFFFFFFF), rows)
+        return dma.write_rows.__wrapped__(
+            jnp.array(inp["cache"]), rows, inp["slots"], interpret=True)
+
+    def oracle(inp):
+        cache = np.asarray(inp["cache"]).astype(np.float32)
+        if kind == "gather":
+            ref = cache.reshape(blocks, bs, 1, w)[np.asarray(inp["ids"])]
+        else:
+            ref = cache.copy()
+            for r, s_ in zip(np.asarray(inp["rows"]), np.asarray(inp["slots"])):
+                if s_ >= 0:
+                    ref[s_] = r
+        live = np.ones(ref.shape, bool)
+        return ref, live, np.zeros_like(live)
+
+    def pricing():
+        return latent_dma_cost(5 * bs if kind == "gather" else 5, 4 * w)
+
+    return {
+        "name": f"latent-{kind}", "kernel": "latent_cache_dma",
+        "mode": "interpret", "atol": 0.0,
+        "build": build, "run": run, "oracle": oracle, "pricing": pricing,
+    }
+
+
 # ---------------------------------------------- serving-scale (spec) ----
 
 
@@ -923,6 +1161,10 @@ def audit_cases() -> list[dict]:
         _ragged_case("ragged-bf16", quant=False),
         _ragged_case("ragged-int8", quant=True),
         _int8_matmul_case(),
+        _mla_sparse_case(),
+        _mla_masked_case(),
+        _latent_dma_case("write"),
+        _latent_dma_case("gather"),
         _spec_decode_8b(),
         _spec_prefill_8b(),
     ]
@@ -1056,7 +1298,58 @@ def probe_int8_matmul_inputs(m, k, n):
     return x, wq, scale
 
 
+def probe_mla_sparse_inputs(n, h, width, k, rows_total, lens):
+    """q [N,H,width], slots [N,K], nvalid [N], latent [1,R/Bs,Bs,1,W]: the
+    operands of ``paged_attention.sparse_latent_attention`` (layer 0)."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import latent_cache
+
+    np = _np()
+    rng = np.random.default_rng(0)
+    bs = 32
+    latent = latent_cache.pack_rows(jnp.asarray(
+        rng.normal(size=(rows_total, width)), jnp.bfloat16))
+    q = jnp.asarray(rng.normal(size=(n, h, width)) * 0.1, jnp.bfloat16)
+    slots = jnp.asarray(rng.integers(0, rows_total, size=(n, k)), jnp.int32)
+    nvalid = jnp.asarray(np.minimum(np.asarray(lens), k), jnp.int32)
+    return (q, latent.reshape(1, rows_total // bs, bs, 1, -1), jnp.int32(0),
+            slots, nvalid)
+
+
+def probe_latent_dma_inputs(rows_total, width, t):
+    """cache [R,1,W], rows [T,1,W], slots [T] (every third without one)."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import latent_cache
+
+    np = _np()
+    rng = np.random.default_rng(0)
+    w = latent_cache.latent_words(width)
+    cache = jnp.zeros((rows_total, 1, w), jnp.uint32)
+    rows = jnp.asarray(rng.integers(0, 1 << 30, size=(t, 1, w)), jnp.uint32)
+    slots = rng.permutation(rows_total)[:t].astype(np.int32)
+    slots[::3] = -1
+    return cache, rows, jnp.asarray(slots)
+
+
+def probe_mla_masked_inputs(s, c, h, dq):
+    """q [S·H, Dq], ctx [C, Dq], bias [S, C] (causal, every other key)."""
+    import jax.numpy as jnp
+
+    np = _np()
+    rng = np.random.default_rng(0)
+    mask = np.tril(np.ones((s, c), bool), k=c - s)
+    mask[:, 1::2] &= rng.random((s, c // 2)) < 0.5
+    return (jnp.asarray(rng.normal(size=(s * h, dq)) * 0.1, jnp.bfloat16),
+            jnp.asarray(rng.normal(size=(c, dq)), jnp.bfloat16),
+            jnp.where(jnp.asarray(mask), 0.0, -1e30).astype(jnp.float32))
+
+
 _PROBE_BUILDERS = {
+    "mla_masked_prefill": probe_mla_masked_inputs,
+    "mla_sparse_attention": probe_mla_sparse_inputs,
+    "latent_cache_dma": probe_latent_dma_inputs,
     "paged_decode_attention_mq": probe_decode_inputs,
     "paged_prefill_attention": probe_prefill_inputs,
     "ragged_paged_prefill_attention": probe_ragged_inputs,

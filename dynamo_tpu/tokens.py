@@ -191,13 +191,48 @@ class TokenBlockSequence:
             return block
         return None
 
+    @property
+    def last_token(self) -> int:
+        """The newest token, without rebuilding the whole list (``tokens``
+        copies every block: a decode step over a 30 k-token context asks
+        for one)."""
+        if self.partial.tokens:
+            return self.partial.tokens[-1]
+        return self.blocks[-1].tokens[-1]
+
     def extend(self, tokens: Iterable[int]) -> list[TokenBlock]:
-        """Append many tokens; returns all blocks completed by this call."""
+        """Append many tokens; returns all blocks completed by this call.
+        Whole blocks are hashed from one buffer of the tokens' bytes (as
+        ``sequence_hashes`` does), not pushed token by token: admitting a
+        30 k-token prompt is a thousand hashes, not thirty thousand Python
+        calls on the engine's thread.  Same hashes as ``append``.  A few
+        tokens (a decode step's one) take ``append``: nothing to batch."""
+        if not isinstance(tokens, (list, tuple)):
+            tokens = list(tokens)
+        bs = self.block_size
+        if len(tokens) < bs:
+            return [b for b in map(self.append, tokens) if b is not None]
+        buf = self.partial.tokens + [int(t) for t in tokens]
+        n_full = len(buf) // bs
         completed: list[TokenBlock] = []
-        for t in tokens:
-            b = self.append(t)
-            if b is not None:
-                completed.append(b)
+        if n_full:
+            raw = np.asarray(buf[: n_full * bs], dtype=np.uint32).tobytes()
+            parent = self.blocks[-1].sequence_hash if self.blocks else None
+            for i in range(n_full):
+                chunk = raw[i * bs * 4 : (i + 1) * bs * 4]
+                prefix = np.uint64(
+                    self.salt if parent is None else parent).tobytes()
+                block = TokenBlock(
+                    tokens=tuple(buf[i * bs : (i + 1) * bs]),
+                    block_hash=compute_hash(chunk),
+                    sequence_hash=compute_hash(prefix + chunk),
+                    parent_sequence_hash=parent,
+                    position=len(self.blocks),
+                )
+                self.blocks.append(block)
+                completed.append(block)
+                parent = block.sequence_hash
+        self.partial = PartialTokenBlock(bs, buf[n_full * bs :])
         return completed
 
     def truncate(self, n_tokens: int) -> None:

@@ -123,6 +123,8 @@ def seed_http_metrics():
     request_counters.record_drain()
     request_counters.record_drain()
     request_counters.record_operands(440)
+    request_counters.record_prompt(1000, 768)
+    request_counters.record_sparse_decode(48000, 4096)
     mesh_shape.update(tp=4, devices=4)
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()

@@ -62,8 +62,13 @@ def test_tiny_reports_what_the_server_ran(tiny):
     warm = [l for l in tiny.lines if l.startswith("  serve-warm: compile")]
     assert warm and " 0 hits" not in warm[0]
     kernels = [l for l in tiny.lines if l.startswith("  kernel ")]
-    assert len(kernels) == 9 and all(
+    # nine lines of the GQA kernels and the int8 matmul, then the sparse
+    # and the masked latent-attention kernels and the latent cache's writes
+    assert len(kernels) == 12 and all(
         l.endswith("PASS") and "interpret=True" in l for l in kernels)
+    assert any("sparse_latent" in l for l in kernels)
+    assert any("masked_latent" in l for l in kernels)
+    assert any("latent_write_rows" in l for l in kernels)
 
 
 def test_device_check_fails_on_a_cpu_machine():

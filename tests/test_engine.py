@@ -189,6 +189,50 @@ def test_async_engine_and_cancellation(setup):
     asyncio.new_event_loop().run_until_complete(go())
 
 
+def test_engine_thread_freezes_what_a_build_leaves(setup):
+    """A step that built its program moves the heap out of the collector's
+    sight (ROADMAP S11); a step that ran a built one does not, and a
+    stopped engine gives it back."""
+    import gc
+
+    from dynamo_tpu.engine import async_engine
+
+    _, model, params = setup
+    calls = []
+    real = async_engine.settle_heap
+
+    def counted():
+        real()
+        calls.append(gc.get_freeze_count())
+
+    async def ask(eng, n):
+        ctx = Context(BackendInput(token_ids=list(range(3, 3 + n)),
+                                   sampling=SamplingOptions(temperature=0.0),
+                                   stops=StopConditions(max_tokens=4)))
+        return [o async for o in eng.generate(ctx)]
+
+    async def go():
+        # a batch width no other test of this process has built
+        cfg = EngineConfig(max_batch_size=3, max_model_len=128, block_size=8,
+                           num_blocks=64, prefill_buckets=[16, 32, 64, 128])
+        eng = AsyncLLMEngine(EngineCore(model, params, cfg)).start()
+        try:
+            await ask(eng, 5)
+            built = len(calls)
+            assert built >= 1 and calls[-1] > 0
+            await ask(eng, 5)  # the same shapes: nothing to build
+            assert len(calls) == built
+        finally:
+            eng.shutdown()
+
+    async_engine.settle_heap = counted
+    try:
+        asyncio.new_event_loop().run_until_complete(go())
+    finally:
+        async_engine.settle_heap = real
+    assert gc.get_freeze_count() == 0
+
+
 def test_sampling_with_temperature_runs(setup):
     _, model, params = setup
     core = make_core(model, params)

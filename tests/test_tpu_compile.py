@@ -395,3 +395,72 @@ def test_tp_rules_that_keep_the_xla_path(tpu_gate):
     assert pa.attention_impl("prefill", windowed=True, **kw)[0] == "xla"
     assert pa.attention_impl(
         "decode", num_kv_heads=8, block_size=16, quant=True)[0] == "xla"
+
+
+# ---------------------------------------------------------------------------
+# GLM-5.2 (cellbench/configs/glm-5.2-ep16.json): the kernels of a latent
+# cache held once, at the published widths (64 heads, rows of 576 elements =
+# 384 words, index_topk 2,048) and the cell's pool (14,400 blocks of 32).
+# What interpret mode could not say, and the chip's compiler did: a one-row
+# slice of an (8, 128)-tiled array is refused (hence the unit axis), a row
+# list shorter than the 1,024-word tiling of a flat int32 array is refused
+# (hence the padding), and XLA's own scatter re-lays the whole cache out
+# (hence the DMA movers: the whole-cache copy shows as temp bytes).
+GLM = dict(h=64, width=576, words=384, topk=2048, layers=5, blocks=14400)
+
+
+@pytest.fixture
+def glm_sds(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("phase,n,k", [
+    ("decode", 32, 2048), ("prefill", 256, 2048), ("prefill", 32, 32)])
+def test_sparse_latent_attention_compiles_on_one_chip(
+        glm_sds, tpu_gate, phase, n, k):
+    g = GLM
+    latent = glm_sds((g["layers"], g["blocks"], BS, 1, g["words"]), jnp.uint32)
+    fn = functools.partial(pa.sparse_latent_attention, sm_scale=1 / 16,
+                           phase=phase)
+    compiled = jax.jit(fn).lower(
+        glm_sds((n, g["h"], g["width"]), jnp.bfloat16), latent,
+        glm_sds((), jnp.int32), glm_sds((n, k), jnp.int32),
+        glm_sds((n,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and f"mla_sparse_{phase}" in text
+    # the cache is read where it lies: no copy of it among the temporaries
+    cache_bytes = g["layers"] * g["blocks"] * BS * g["words"] * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 10
+
+
+def test_masked_latent_prefill_compiles_on_one_chip(glm_sds):
+    from dynamo_tpu.ops.pallas.mla_masked_prefill import mla_masked_prefill
+
+    s, c, h = 2048, 34816, GLM["h"]
+    compiled = jax.jit(functools.partial(
+        mla_masked_prefill, heads=h, dv=512, sm_scale=1 / 16)).lower(
+            glm_sds((s * h, 640), jnp.bfloat16),
+            glm_sds((c, 640), jnp.bfloat16),
+            glm_sds((s, c), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_cache_movers_compile_and_copy_no_cache(glm_sds, tpu_gate):
+    from dynamo_tpu.ops import latent_cache
+
+    g = GLM
+    latent = glm_sds((g["layers"], g["blocks"], BS, 1, g["words"]), jnp.uint32)
+    cache_bytes = g["layers"] * g["blocks"] * BS * g["words"] * 4
+    write = jax.jit(latent_cache.write_latent, donate_argnums=(0,)).lower(
+        latent, glm_sds((), jnp.int32), glm_sds((2048, g["words"]), jnp.uint32),
+        glm_sds((2048,), jnp.int32)).compile()
+    assert "latent_cache_write_rows" in write.as_text()
+    stats = write.memory_analysis()
+    assert stats.alias_size_in_bytes >= cache_bytes       # written in place
+    assert stats.temp_size_in_bytes < cache_bytes / 10
+    read = jax.jit(latent_cache.context_rows).lower(
+        latent, glm_sds((), jnp.int32), glm_sds((1, 1088), jnp.int32)).compile()
+    assert "latent_cache_gather_blocks" in read.as_text()
+    assert read.memory_analysis().temp_size_in_bytes < cache_bytes / 10
