@@ -52,6 +52,7 @@ from dynamo_tpu.models.llama import (
     grouped_expert_dispatch,
     rms_norm,
     rope_inv_freq,
+    split_heads,
 )
 from dynamo_tpu.ops import latent_cache
 from dynamo_tpu.ops.paged_attention import (
@@ -475,7 +476,7 @@ class GlmDsaModel:
         hi, di, rope = (cfg.index_n_heads, cfg.index_head_dim,
                         cfg.qk_rope_head_dim)
         bs = cache["index_k"].shape[2]
-        q = (c_q @ lp["idx_wq_b"]).reshape(b, s, hi, di)
+        q = split_heads(c_q @ lp["idx_wq_b"], hi)
         q = _rope_head(q, positions, self.inv_freq, rope)
         k = _layer_norm(x @ lp["idx_wk"], lp["idx_k_norm_w"],
                         lp["idx_k_norm_b"], INDEX_NORM_EPS)
@@ -519,7 +520,7 @@ class GlmDsaModel:
         with jax.named_scope("attn_proj"):
             x = rms_norm(h_in, lp["attn_norm"], cfg.rms_norm_eps)
             c_q = rms_norm(x @ lp["q_a"], lp["q_a_norm"], cfg.rms_norm_eps)
-            q = (c_q @ lp["q_b"]).reshape(b, s, nh, cfg.qk_head_dim)
+            q = split_heads(c_q @ lp["q_b"], nh)
             q_pe = apply_rope_interleaved(q[..., nope:], positions,
                                           self.inv_freq)
             ckv = x @ lp["kv_a"]

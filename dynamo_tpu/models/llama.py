@@ -600,6 +600,20 @@ class LlamaModel:
         return logits
 
 
+def split_heads(y: jax.Array, heads: int) -> jax.Array:
+    """[..., H·D] -> [..., H, D] of a projection's result, with the dot
+    that made it left a plain 2-D one.
+
+    Given the chance, XLA folds this reshape into the dot, and a dot with
+    two free dimensions on the weight side wants its weight as [H, D, Dm]:
+    on the TPU the layer scan then transposed all of ``wq`` and ``wk``
+    (GLM: ``q_b``, 67 MB) every layer of every step, prefill and decode.
+    Behind the barrier the reshape, the q/k norm and RoPE see only the
+    (small) result, and the dot reads the stacked weight as it is stored."""
+    y = jax.lax.optimization_barrier(y)
+    return y.reshape(*y.shape[:-1], heads, y.shape[-1] // heads)
+
+
 def _qkv_proj(
     cfg: ModelConfig, lp: dict, x: jax.Array, b: int, s: int
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
@@ -608,8 +622,7 @@ def _qkv_proj(
     q, k, v = matmul(x, lp["wq"]), matmul(x, lp["wk"]), matmul(x, lp["wv"])
     if cfg.attention_bias:
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(b, s, hq, dh)
-    k = k.reshape(b, s, hk, dh)
+    q, k = split_heads(q, hq), split_heads(k, hk)
     if cfg.qk_norm:  # Qwen3: RMSNorm over head_dim, pre-RoPE
         q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)

@@ -194,6 +194,33 @@ def test_decode_and_prefix_hit_alone_and_batched():
         _logp(model, params, alone[:1, 0]), got[:1], atol=1e-4)
 
 
+@pytest.mark.parametrize("chunks", [[(0, 40)], [(0, 32), (32, 33)]],
+                         ids=["prefill", "prefill-then-one-token"])
+def test_projection_kept_out_of_the_head_reshape_is_bit_identical(
+        chunks, monkeypatch):
+    """``split_heads`` on ``q_b`` and the indexer's ``idx_wq_b`` is a change
+    of how the dot is expressed: log-probabilities, both parts of the cache
+    and the selection equal those of the old expression — the reshape
+    straight on the dot — to the bit, in bf16."""
+    model = GlmDsaModel(GlmDsaConfig.from_hf_config(TINY, dtype="bfloat16"))
+    params = model.init_params(jax.random.PRNGKey(5))
+    tokens, table = _tokens(40, seed=2), _table(1, 48)
+
+    def run():
+        logp, cache = _prefill(model, params, model.init_kv_cache(NB, BS),
+                               tokens, table, chunks)
+        return [logp] + [np.asarray(c) for c in jax.tree.leaves(cache)]
+
+    got = run()
+    monkeypatch.setattr(
+        glm, "split_heads",
+        lambda y, heads: y.reshape(*y.shape[:-1], heads, -1))
+    want = run()
+    assert len(got) == 3 and np.isfinite(want[0]).all()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
 def test_engine_serves_it_with_chunks_decode_and_a_prefix_hit():
     """Through EngineCore: chunked prefill, the decode batch with a dispatch
     in flight, prefix reuse — and the counters that say so."""

@@ -356,6 +356,68 @@ def test_qwen3_moe_forward_in_place_equals_sliced_scan(shape, monkeypatch):
     np.testing.assert_array_equal(got, want)
 
 
+def _qkv_proj_folded(cfg, lp, x, b, s):
+    """``_qkv_proj`` as it was until PR 38: the head reshape straight on
+    the dot, which the TPU compiler folds into it (and then re-lays
+    ``wq``/``wk`` out every layer).  The oracle of the test below."""
+    from dynamo_tpu.models.llama import matmul, rms_norm
+
+    dh, hq, hk = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    q, k, v = matmul(x, lp["wq"]), matmul(x, lp["wk"]), matmul(x, lp["wv"])
+    if cfg.attention_bias:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    q = q.reshape(b, s, hq, dh)
+    k = k.reshape(b, s, hk, dh)
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+    return q, k, v.reshape(b, s, hk, dh)
+
+
+@pytest.mark.parametrize("shape", [(2, 5), (3, 1)], ids=["prefill", "decode"])
+@pytest.mark.parametrize("extra", [{}, {"qk_norm": True},
+                                   {"attention_bias": True}],
+                         ids=["plain", "qk_norm", "attention_bias"])
+def test_projection_kept_out_of_the_head_reshape_is_bit_identical(
+        extra, shape, monkeypatch):
+    """``split_heads`` changes how the q/k dots are expressed, not what
+    they compute: hidden states and the written cache equal the old
+    expression's to the bit, in bf16."""
+    import jax
+
+    import dynamo_tpu.models.llama as llama
+
+    model = LlamaModel(ModelConfig.tiny(num_layers=3, dtype="bfloat16",
+                                        **extra))
+    params = model.init_params(jax.random.PRNGKey(11))
+    if extra.get("attention_bias"):   # init draws them zero
+        keys = jax.random.split(jax.random.PRNGKey(12), 3)
+        for name, key in zip(("bq", "bk", "bv"), keys):
+            bias = params["layers"][name]
+            params["layers"][name] = jax.random.normal(
+                key, bias.shape, jnp.float32).astype(bias.dtype)
+
+    def run():
+        b, s = shape
+        toks = jax.random.randint(
+            jax.random.PRNGKey(8), (b, s), 0, model.config.vocab_size)
+        positions = jnp.tile(jnp.arange(s, dtype=jnp.int32), (b, 1))
+        tables = jnp.arange(b * 2, dtype=jnp.int32).reshape(b, 2)
+        hidden, cache = model.forward(
+            params, toks, positions, model.init_kv_cache(b * 2, BLOCK),
+            tables, jnp.full((b,), s, jnp.int32),
+            tables[:, :1] * BLOCK + positions,
+            prefix_blocks=1 if s > 1 else None)
+        return np.asarray(hidden, np.float32), np.asarray(cache, np.float32)
+
+    got = run()
+    monkeypatch.setattr(llama, "_qkv_proj", _qkv_proj_folded)
+    want = run()
+    assert np.abs(want[0]).max() > 0 and np.abs(want[1]).max() > 0
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+
+
 def test_qtensor_experts_keep_the_sliced_form(monkeypatch):
     """int8 experts ride the scan's xs and dequantise after the slice (the
     choice is made on the leaf's type), and match the dense oracle."""

@@ -14,6 +14,7 @@ import contextlib
 import functools
 import importlib
 import json
+import math
 import os
 import re
 
@@ -193,24 +194,32 @@ _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(")
 _PASS_THROUGH = ("parameter", "get-tuple-element", "bitcast")
 
 
-def _largest_produced(hlo: str) -> tuple[int, str]:
-    """(bytes, instruction) of the largest array an instruction of the
-    optimised HLO writes to memory: not one that hands a buffer on, and
-    not one inside a fused computation, whose values live in registers."""
+def _unfused_instructions(hlo: str):
+    """(name, dtype, dims, opcode) of each instruction of the optimised HLO
+    that is not inside a fused computation, whose values live in registers."""
     fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
-    worst, inside = (0, ""), ""
+    inside = ""
     for line in hlo.splitlines():
         head = _HLO_COMPUTATION.match(line)
         if head:
             inside = head.group(1)
         m = _HLO_INSTR.match(line)
-        if not m or inside in fused or m.group(4) in _PASS_THROUGH:
+        if m and inside not in fused:
+            dims = tuple(int(d) for d in m.group(3).split(",") if d)
+            yield m.group(1), m.group(2), dims, m.group(4)
+
+
+def _largest_produced(hlo: str) -> tuple[int, str]:
+    """(bytes, instruction) of the largest array an instruction of the
+    optimised HLO writes to memory: not one that hands a buffer on, and
+    not one inside a fused computation."""
+    worst = (0, "")
+    for name, dtype, dims, op in _unfused_instructions(hlo):
+        if op in _PASS_THROUGH:
             continue
-        bits = re.search(r"\d+", m.group(2))      # bf16, f32, s8; pred: none
+        bits = re.search(r"\d+", dtype)          # bf16, f32, s8; pred: none
         size = max(int(bits.group()) // 8, 1) if bits else 1
-        for d in filter(None, m.group(3).split(",")):
-            size *= int(d)
-        worst = max(worst, (size, f"{m.group(1)} = {m.group(4)}"))
+        worst = max(worst, (size * math.prod(dims), f"{name} = {op}"))
     return worst
 
 
@@ -219,25 +228,33 @@ def _abstract_model(config_file: str, place, num_blocks=None, **overrides):
     as shapes with shardings: ``place(spec)`` turns a PartitionSpec of
     models/llama.py into the sharding of the described device(s); the cache
     has the configuration's own ``num_blocks`` unless one is given."""
-    from dynamo_tpu.models.config import ModelConfig
-    from dynamo_tpu.models.llama import LlamaModel
-
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "cellbench/configs", config_file)) as f:
         hf = dict(json.load(f), **overrides)
-    cfg = ModelConfig.from_hf_config(hf, dtype=hf["dtype"])
-    model = LlamaModel(cfg)
+
+    def resolve(key, default):   # "module:Class", as cellbench/server.py
+        module, name = hf.get(key, default).split(":")
+        return getattr(importlib.import_module(module), name)
+
+    cfg = resolve("config_class", "dynamo_tpu.models.config:ModelConfig"
+                  ).from_hf_config(hf, dtype=hf["dtype"])
+    model = resolve("model_class", "dynamo_tpu.models.llama:LlamaModel")(cfg)
+    num_blocks = num_blocks or hf["serve"]["num_blocks"]
 
     def sds(shape, dtype=jnp.int32, spec=P()):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=place(spec))
 
-    params = jax.tree.map(
-        lambda a, spec: sds(a.shape, a.dtype, spec),
-        jax.eval_shape(lambda: model.init_params(jax.random.key(0))),
-        model.partition_specs())
-    cache = sds((cfg.num_layers, num_blocks or hf["serve"]["num_blocks"], 2, BS,
-                 cfg.num_kv_heads * cfg.head_dim), jnp.bfloat16,
-                model.cache_spec())
+    def placed(shapes, specs):
+        return jax.tree.map(lambda a, spec: sds(a.shape, a.dtype, spec),
+                            shapes, specs)
+
+    shapes = jax.eval_shape(lambda: model.init_params(jax.random.key(0)))
+    specs = (jax.tree.map(lambda a: P(), shapes)      # GLM: one chip, no specs
+             if getattr(model, "two_part_cache", False)
+             else model.partition_specs())
+    params = placed(shapes, specs)
+    cache = placed(jax.eval_shape(lambda: model.init_kv_cache(num_blocks, BS)),
+                   model.cache_spec())
     return hf, cfg, model, params, cache, sds
 
 
@@ -333,41 +350,56 @@ def _collective_census(hlo: str) -> tuple[dict, dict]:
     return by_comp.get(body, {}), outside
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_mistral_7b_whole_compiles_under_tp4(topo, tpu_gate, program):
+def _step_program(program, model, serve, sds, mesh=None, prefix_blocks=1):
+    """(fn, args) of a step as the engine issues it at the configuration's
+    own ``serve`` geometry — ``decode``: the whole batch, one token a row;
+    ``prefill``: one chunk of one prompt with ``prefix_blocks`` blocks of
+    it cached — for ``jax.jit(fn, donate_argnums=(1,)).lower(params,
+    cache, *args)``."""
     from dynamo_tpu.engine.core import multi_decode_step, unified_step
 
-    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
-    hf, cfg, model, params, cache, sds = _abstract_model(
-        "mistral-7b-tp4.json", lambda spec: NamedSharding(mesh, spec))
-    serve = hf["serve"]
-    assert hf["num_hidden_layers"] == 32 and hf["reduced"] == [] and serve["tp"] == 4
-    bs, rows = serve["block_size"], serve["max_batch_size"]
+    bs = serve["block_size"]
     assert bs == BS
     m = serve["max_model_len"] // bs
     f32, key = jnp.float32, sds((2,), jnp.uint32)
+    under_mesh = (
+        (lambda: jax.sharding.use_abstract_mesh(mesh.abstract_mesh))
+        if mesh else contextlib.nullcontext)
     if program == "decode":
-        b = rows
-        # as the engine issues it: the last decode's samples and the mask
-        # of the rows that take their token from them (dispatch-ahead)
+        b = serve["max_batch_size"]
+        # the last decode's samples and the mask of the rows that take
+        # their token from them (dispatch-ahead)
         args = (sds((b,)), sds((b,)), sds((b, m)), sds((b,)), sds((b,)), key,
                 sds((b,), f32), sds((b,)), sds((b,), f32),
                 sds((1, b)), sds((b,), jnp.bool_))
 
         def fn(params, cache, *a):
-            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            with under_mesh():
                 return multi_decode_step(
                     model, params, cache, *a[:-2], carry_tokens=a[-2],
                     carry_rows=a[-1], num_steps=1, block_size=bs)
-    else:       # one chunk of one prompt, sixteen blocks of it already cached
+    else:
         s = serve["prefill_chunk_tokens"]
         args = (sds((1, s)), sds((1, s)), sds((1, m)), sds((1,)), sds((1, s)),
                 sds((1,)), key, sds((1,), f32), sds((1,)), sds((1,), f32))
 
         def fn(params, cache, *a):
-            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-                return unified_step(model, params, cache, *a, prefix_blocks=16)
+            with under_mesh():
+                return unified_step(model, params, cache, *a,
+                                    prefix_blocks=prefix_blocks)
+    return fn, args
 
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_mistral_7b_whole_compiles_under_tp4(topo, tpu_gate, program):
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "mistral-7b-tp4.json", lambda spec: NamedSharding(mesh, spec))
+    serve = hf["serve"]
+    assert hf["num_hidden_layers"] == 32 and hf["reduced"] == [] and serve["tp"] == 4
+    # prefill: sixteen blocks of the prompt already cached
+    fn, args = _step_program(program, model, serve, sds, mesh,
+                             prefix_blocks=16)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *args).compile()
     mem = compiled.memory_analysis()
@@ -385,6 +417,78 @@ def test_mistral_7b_whole_compiles_under_tp4(topo, tpu_gate, program):
     in_layer, outside = _collective_census(compiled.as_text())
     assert in_layer == {"all-reduce": 2}, in_layer
     assert outside == {"all-gather": 2, "all-reduce": 2}, outside
+
+
+# ---------------------------------------------------------------------------
+# The layer scan re-lays no projection weight (PR 38).  XLA folds a reshape
+# that follows a dot into the dot; with the head reshape of q and k folded in,
+# the TPU compiler asked for ``wq``/``wk`` as [H, D, Dm] and the scan's body
+# transposed all of both every layer of every step (`copy.21` + `copy.23`,
+# 40 MB a Mistral layer; GLM's ``q_b`` 67 MB, its indexer's ``idx_wq_b``
+# 16 MB).  ``models/llama.py::split_heads`` keeps the reshape out of the dot.
+# Two layers show what every layer does; GLM's own five are three kinds.
+_TWO_LAYERS = {"num_hidden_layers": 2}
+_SCAN_PROGRAMS = {       # configuration file, program, chips, overrides
+    "mistral-7b-decode": ("mistral-7b.json", "decode", 1, _TWO_LAYERS),
+    "mistral-7b-prefill": ("mistral-7b.json", "prefill", 1, _TWO_LAYERS),
+    "qwen3-30b-a3b-decode": ("qwen3-30b-a3b.json", "decode", 1, _TWO_LAYERS),
+    "mistral-7b-tp4-decode": ("mistral-7b-tp4.json", "decode", 4, _TWO_LAYERS),
+    "glm-5.2-ep16-decode": ("glm-5.2-ep16.json", "decode", 1, {}),
+}
+# GLM's ``kv_b`` is still transposed once a layer ([1,512,28672], 29 MB):
+# its two einsums are batched over the head, which lies in the middle of
+# the stored [r, H·(nope+v)], and a batched dot wants the batch dimension
+# outermost — only another stored layout would spare it (PERF.md, PR 38).
+_STILL_RELAID = {"kv_b"}
+_HLO_DTYPES = {"bf16": "bfloat16", "f32": "float32"}
+
+
+def _layer_slices(params) -> dict[tuple, set[str]]:
+    """{(dtype, sorted dims): names} of one layer's slice, on one device, of
+    every stacked weight of 1 MiB and more."""
+    out: dict[tuple, set[str]] = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        name = jax.tree_util.keystr(path[-1:]).strip("[]'")
+        if leaf.ndim < 3 or name in _STILL_RELAID:
+            continue
+        dims = leaf.sharding.shard_shape(leaf.shape)[1:]
+        if math.prod(dims) * leaf.dtype.itemsize >= 2**20:
+            out.setdefault((str(leaf.dtype), tuple(sorted(dims))),
+                           set()).add(name)
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(_SCAN_PROGRAMS))
+def test_layer_scan_copies_no_projection_weight(topo, tpu_gate, case):
+    config_file, program, chips, overrides = _SCAN_PROGRAMS[case]
+    if chips > 1:
+        mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                    ("data", "model"))
+        place = lambda spec: NamedSharding(mesh, spec)
+    else:
+        mesh = None
+        place = lambda spec: SingleDeviceSharding(topo.devices[0])
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        config_file, place, N_BLOCKS, **overrides)
+    fn, args = _step_program(program, model, hf["serve"], sds, mesh)
+    hlo = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile().as_text()
+    assert "tpu_custom_call" in hlo and " while(" in hlo
+
+    weights = _layer_slices(params)
+    assert len(weights) >= 3, weights
+    relaid = []
+    for name, dtype, dims, op in _unfused_instructions(hlo):
+        if op not in ("copy", "transpose"):
+            continue
+        # by shape, not by size: a 512-row prefill copies 4 MB of
+        # activations, more than Qwen3's whole wk
+        shape = tuple(sorted(d for d in dims if d != 1))
+        names = weights.get((_HLO_DTYPES.get(dtype), shape))
+        if names:
+            relaid.append(f"{name} = {dtype}{list(dims)} {op}: a layer of "
+                          + "/".join(sorted(names)))
+    assert not relaid, relaid
 
 
 def test_tp_rules_that_keep_the_xla_path(tpu_gate):
