@@ -713,7 +713,7 @@ def _ops_entrypoints(model_cfg, engine_cfg) -> list[Entrypoint]:
     ))
 
     def build_scatter(n):
-        l_ = model_cfg.num_layers
+        l_ = cache.shape[0]   # the cache's own layers (a looped model: T x L)
         blocks = _sds((l_, n, 2, cfg.block_size, hk * d), dt)
         args = (cache, _sds((n,), i32), blocks)
         return Signature(f"n={n}", args, {})

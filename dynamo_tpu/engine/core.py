@@ -47,7 +47,8 @@ import numpy as np
 from dynamo_tpu.engine import operands
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.counters import counters as prefill_counters
-from dynamo_tpu.engine.counters import mesh_shape, request_counters
+from dynamo_tpu.engine.counters import (cache_shape, mesh_shape,
+                                        request_counters)
 from dynamo_tpu.engine.grammar import (
     INIT_STATE, JsonGrammar, compile_choice_vocab, compile_regex_vocab,
     compose_tables, device_tables, grammar_advance, grammar_mask,
@@ -497,6 +498,13 @@ class EngineCore:
         self.mesh_tp = 1 if mesh is None else mesh.shape.get(AXIS_MODEL, 1)
         self.mesh_devices = 1 if mesh is None else mesh.size
         mesh_shape.update(tp=self.mesh_tp, devices=self.mesh_devices)
+        # what the cache is made of: its layers (a looped decoder keeps one
+        # per pass of every layer) and what one token costs across them all
+        self.cache_layers = int(jax.tree.leaves(cache)[0].shape[0])
+        self.kv_bytes_per_token = (
+            self.kv_bytes_per_block() // config.block_size)
+        cache_shape.update(layers=self.cache_layers,
+                           bytes_per_token=self.kv_bytes_per_token)
 
         # where a dispatch's small operands go under a mesh
         # (``_upload_dispatch``): replicated over it, the layout the jitted
@@ -635,6 +643,11 @@ class EngineCore:
         self._index_topk = int(getattr(model.config, "index_topk", 0) or 0)
         self.attn_context_tokens = 0
         self.attn_selected_tokens = 0
+        # tokens dispatched (prefill and decode) and, over them, the passes
+        # of the layer stack run: ut_steps a token for a looped decoder
+        self._ut_steps = int(getattr(model.config, "ut_steps", 1) or 1)
+        self.loop_tokens = 0
+        self.loop_passes = 0
         self.first_token_s = 0.0         # sum of (first emit - submitted_at)
         # cached _unified_penalties host buffers (invalidated on
         # admission/finish; incremental append between turns)
@@ -1302,6 +1315,15 @@ class EngineCore:
             except queue.Empty:
                 break
 
+    def _count_tokens(self, tokens: int) -> None:
+        """``tokens`` went out in a dispatch: each runs the layer stack
+        ``ut_steps`` times (passes / tokens = cellbench's
+        loop.passes_per_token)."""
+        passes = tokens * self._ut_steps
+        self.loop_tokens += tokens
+        self.loop_passes += passes
+        request_counters.record_loop(tokens, passes)
+
     def metrics(self) -> dict:
         """ForwardPassMetrics equivalent (ref kv_router/protocols.rs:30-47)."""
         active = sum(1 for s in self.slots if s is not None)
@@ -1344,6 +1366,10 @@ class EngineCore:
             "prompt_tokens_cached_total": self.prompt_tokens_cached,
             "attn_context_tokens_total": self.attn_context_tokens,
             "attn_selected_tokens_total": self.attn_selected_tokens,
+            "loop_tokens_total": self.loop_tokens,
+            "loop_passes_total": self.loop_passes,
+            "cache_layers": self.cache_layers,
+            "kv_bytes_per_token": self.kv_bytes_per_token,
             "first_token_seconds_total": self.first_token_s,
             # dispatch-ahead: ahead / decode_dispatches_total = how
             # often a decode hid its round trip; discards = late stops;
@@ -1798,6 +1824,7 @@ class EngineCore:
         self.prefill_dispatches += 1
         self.prefill_rows_dispatched += 1
         prefill_counters.record(rows=1, tokens=take)
+        self._count_tokens(take)
 
         def finish(out):
             if req.state is not RequestState.PREFILL:
@@ -1956,6 +1983,7 @@ class EngineCore:
         self.prefill_budget_offered += budget
         self.prefill_budget_used += take_sum
         prefill_counters.record(rows=r_real, tokens=take_sum, budget=budget)
+        self._count_tokens(take_sum)
 
         def finish(out):
             for r, (req, take, final) in enumerate(sel):
@@ -2193,6 +2221,7 @@ class EngineCore:
         self.unified_prefill_tokens += take_sum
         self.unified_budget_offered += cfg.prefill_token_budget
         self.unified_budget_used += n_dec + take_sum
+        self._count_tokens(n_dec + take_sum)
         prefill_counters.record(rows=len(sel), tokens=take_sum,
                                 budget=budget)
         prefill_counters.record_unified(
@@ -2362,6 +2391,7 @@ class EngineCore:
         self.prefill_dispatches += 1
         self.prefill_rows_dispatched += 1
         prefill_counters.record(rows=1, tokens=req.prompt_len)
+        self._count_tokens(req.prompt_len)
         self.prompt_tokens_computed += req.prompt_len
         req.computed_tokens = req.prompt_len
         self._commit_prefill_blocks(req)
@@ -2539,6 +2569,7 @@ class EngineCore:
         self.decode_dispatches += 1
         self.decode_rows_dispatched += len(rows)
         request_counters.record_decode(len(rows))
+        self._count_tokens(len(rows) * tokens.shape[1])
         for req in rows:
             i = req.slot
             prop = props.get(i, [])
@@ -2700,6 +2731,7 @@ class EngineCore:
         self.decode_dispatches += 1
         self.decode_rows_dispatched += len(active)
         request_counters.record_decode(len(active))
+        self._count_tokens(len(active) * k_steps)
         if self._index_topk:
             ctx = int(seq_lens.sum())
             picked = int(np.minimum(seq_lens, self._index_topk).sum())

@@ -329,7 +329,16 @@ class RequestCounters:
                                                        index_topk) a row;
                                                        over context, how
                                                        sparse attention was)
-    All four are counted on the host from lengths it already has.
+        dynamo_tpu_engine_loop_tokens_total            counter (tokens that
+                                                       went out in a prefill
+                                                       or decode dispatch)
+        dynamo_tpu_engine_loop_passes_total            counter (passes of the
+                                                       layer stack run for
+                                                       them: ut_steps a token
+                                                       for a looped decoder,
+                                                       1 otherwise; over
+                                                       tokens, passes a token)
+    All six are counted on the host from lengths it already has.
     """
 
     def __init__(self) -> None:
@@ -369,6 +378,10 @@ class RequestCounters:
         self.attn_context_tokens_total += context
         self.attn_selected_tokens_total += selected
 
+    def record_loop(self, tokens: int, passes: int) -> None:
+        self.loop_tokens_total += tokens
+        self.loop_passes_total += passes
+
     def reset(self) -> None:
         """Test isolation hook — the counters are process-global."""
         self.decode_dispatches_total = 0
@@ -385,6 +398,8 @@ class RequestCounters:
         self.prompt_tokens_cached_total = 0
         self.attn_context_tokens_total = 0
         self.attn_selected_tokens_total = 0
+        self.loop_tokens_total = 0
+        self.loop_passes_total = 0
 
 
 request_counters = RequestCounters()
@@ -400,3 +415,13 @@ request_counters = RequestCounters()
 # A number of a sharded server (a step time, a collective's share) means
 # something else than one chip's: a scrape says which it is looking at.
 mesh_shape = {"tp": 1, "devices": 1}
+
+# The KV cache of that engine, written beside it:
+#
+#     dynamo_tpu_engine_cache_layers        gauge (layers of the cache: the
+#                                           model's layers, times its passes
+#                                           for a looped decoder)
+#     dynamo_tpu_engine_kv_bytes_per_token  gauge (bytes one token holds
+#                                           across all of them: what sizes
+#                                           num_blocks and a block transfer)
+cache_shape = {"layers": 0, "bytes_per_token": 0}

@@ -20,9 +20,9 @@ from collections import defaultdict
 from typing import Iterator
 
 from dynamo_tpu.engine.counters import counters as prefill_counters
-from dynamo_tpu.engine.counters import (kv_shard_counters, kv_stream_counters,
-                                        mesh_shape, persist_counters,
-                                        request_counters)
+from dynamo_tpu.engine.counters import (cache_shape, kv_shard_counters,
+                                        kv_stream_counters, mesh_shape,
+                                        persist_counters, request_counters)
 from dynamo_tpu.fault.counters import counters as fault_counters
 from dynamo_tpu.obs.costs import transfer_costs
 from dynamo_tpu.obs.metric_names import EngineMetric as EM
@@ -316,6 +316,18 @@ class Metrics:
         lines.append(f"{EM.MESH_TP} {mesh_shape['tp']}")
         lines.append(f"# TYPE {EM.MESH_DEVICES} gauge")
         lines.append(f"{EM.MESH_DEVICES} {mesh_shape['devices']}")
+        # tokens dispatched and the passes of the layer stack run for them
+        # (a looped decoder: ut_steps a token), and what the cache is made
+        # of: its layers, and the bytes a token holds across them
+        lines.append(f"# TYPE {EM.LOOP_TOKENS_TOTAL} counter")
+        lines.append(f"{EM.LOOP_TOKENS_TOTAL} {rc.loop_tokens_total}")
+        lines.append(f"# TYPE {EM.LOOP_PASSES_TOTAL} counter")
+        lines.append(f"{EM.LOOP_PASSES_TOTAL} {rc.loop_passes_total}")
+        lines.append(f"# TYPE {EM.CACHE_LAYERS} gauge")
+        lines.append(f"{EM.CACHE_LAYERS} {cache_shape['layers']}")
+        lines.append(f"# TYPE {EM.KV_BYTES_PER_TOKEN} gauge")
+        lines.append(f"{EM.KV_BYTES_PER_TOKEN} "
+                     f"{cache_shape['bytes_per_token']}")
         lines.append(f"# TYPE {EM.HOST_GAP_MS_PER_TURN} gauge")
         lines.append(f"{EM.HOST_GAP_MS_PER_TURN} "
                      f"{round(tl['host_gap_ms_per_turn'], 6)}")

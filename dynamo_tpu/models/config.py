@@ -27,6 +27,7 @@ SUPPORTED_ARCHITECTURES = {
     "Phi3ForCausalLM",
     "GemmaForCausalLM",
     "Gemma2ForCausalLM",
+    "OuroForCausalLM",
 }
 
 
@@ -79,6 +80,15 @@ class ModelConfig:
     # tanh softcaps: scores (Gemma2 attn_logit_softcapping) and final logits
     attn_logit_softcap: Optional[float] = None
     final_logit_softcap: Optional[float] = None
+    # --- looped decoder (Ouro: HF total_ut_steps / early_exit_threshold) ---
+    # The layer stack runs ``ut_steps`` times over the same weights; pass t
+    # keeps a K/V cache of its own (cache layer t * num_layers + l), the
+    # final norm closes every pass, and an exit gate chooses, per token,
+    # which pass's state goes to the output head: the first pass at which
+    # the cumulated exit probability reaches ``early_exit_threshold``,
+    # else the last (at 1.0 always the last).  1 = an ordinary decoder.
+    ut_steps: int = 1
+    early_exit_threshold: float = 1.0
     # runtime
     dtype: str = "bfloat16"
 
@@ -128,6 +138,7 @@ class ModelConfig:
                 f"{sorted(SUPPORTED_ARCHITECTURES)}"
             )
         gemma = arch in ("GemmaForCausalLM", "Gemma2ForCausalLM")
+        ouro = arch == "OuroForCausalLM"
         qwen3_moe = arch == "Qwen3MoeForCausalLM"
         if qwen3_moe and (
             cfg.get("decoder_sparse_step", 1) != 1 or cfg.get("mlp_only_layers")
@@ -234,9 +245,13 @@ class ModelConfig:
             hidden_activation=act_map[act],
             rmsnorm_unit_offset=gemma,
             scale_embeddings=gemma,
-            post_norms=arch == "Gemma2ForCausalLM",
+            # Ouro's input_layernorm_2 / post_attention_layernorm_2 norm
+            # the two residual branches, as Gemma2's sandwich norms do
+            post_norms=arch == "Gemma2ForCausalLM" or ouro,
             query_pre_attn_scalar=cfg.get("query_pre_attn_scalar"),
             attn_logit_softcap=cfg.get("attn_logit_softcapping"),
             final_logit_softcap=cfg.get("final_logit_softcapping"),
+            ut_steps=int(cfg.get("total_ut_steps", 1)) if ouro else 1,
+            early_exit_threshold=float(cfg.get("early_exit_threshold", 1.0)),
             dtype=dtype,
         )

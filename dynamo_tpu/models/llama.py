@@ -24,6 +24,12 @@ Design (TPU-first, not a port):
         (``experts_in_place``).
   * Static shapes everywhere; bf16 weights/activations on the MXU, f32
     norms/softmax/logits.
+  * A looped decoder (``ModelConfig.ut_steps`` > 1) runs the layer scan
+    that many times over the same weights inside one more ``lax.scan``
+    with ``(hidden, cache)`` as its carry; pass t reads and writes cache
+    layer ``t * num_layers + l``, so the cache has ``cache_layers`` =
+    ut_steps x num_layers layers under the one block table
+    (docs/looped_layers.md).  With ``ut_steps`` 1 there is no outer loop.
   * Tensor parallelism is declarative: :meth:`partition_specs` returns a
     PartitionSpec pytree over mesh axes ("data", "model") and GSPMD inserts
     the collectives (all-gather/psum over ICI) — no NCCL-style plumbing.
@@ -150,6 +156,8 @@ class LlamaModel:
     supports_unified_dispatch = True
 
     def __init__(self, config: ModelConfig):
+        if config.ut_steps < 1:
+            raise ValueError(f"ut_steps must be >= 1, got {config.ut_steps}")
         self.config = config
         # Gemma2 scales scores by query_pre_attn_scalar**-0.5, not head_dim
         self.sm_scale = float(
@@ -159,6 +167,19 @@ class LlamaModel:
         self.inv_freq = rope_inv_freq(
             config.head_dim, config.rope_theta, config.rope_scaling
         )
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of K/V cache: one per layer *application* that attends.
+        A looped decoder keeps a cache of its own for every pass, so this
+        is ``ut_steps`` x the layers of weights."""
+        return self.config.num_layers * self.config.ut_steps
+
+    @property
+    def supports_seq_parallel(self) -> bool:
+        """``forward_seq_parallel`` walks the stack once: the engine
+        refuses ``sp_prefill_threshold`` for a looped decoder at start-up."""
+        return self.config.ut_steps == 1
 
     # ------------------------------------------------------------------ init
     def init_params(self, rng: jax.Array, quantized: bool = False) -> Params:
@@ -205,11 +226,22 @@ class LlamaModel:
             "wo": dense(next(keys), (L, hq * dh, dm), hq * dh),
             "mlp_norm": norm_init((L, dm), dt),
         }
-        if cfg.post_norms:  # Gemma2 sandwich norms
-            layers.update(
-                post_attn_norm=norm_init((L, dm), dt),
-                post_mlp_norm=norm_init((L, dm), dt),
-            )
+        if cfg.post_norms:  # Gemma2 / Ouro sandwich norms
+            post = norm_init((L, dm), dt)
+            if cfg.ut_steps > 1:
+                # A looped stack re-enters itself from a state of unit RMS
+                # (the final norm closes every pass).  With the sandwich
+                # norms seeded at 1 every branch adds a unit-RMS vector to
+                # it, and the random model amplifies a rounding error ~2.7x
+                # a pass: bf16 against float32 read a median |d log p| of
+                # 0.014 after one pass and 0.23 after four (0.20-0.22 on
+                # the chip at Ouro-2.6B's widths, PERF.md section 6), which
+                # no trained looped model does.  Seeded at 1/sqrt(2L), the
+                # 2L branches of a pass add the state's own variance once
+                # (GPT-2's scaling of its residual projections): 0.022
+                # after four.
+                post = post / math.sqrt(2 * L)
+            layers.update(post_attn_norm=post, post_mlp_norm=post)
         if cfg.attention_bias:  # Qwen2-style QKV bias
             layers.update(
                 bq=jnp.zeros((L, hq * dh), dt),
@@ -249,6 +281,13 @@ class LlamaModel:
         }
         if not cfg.tie_word_embeddings:
             params["lm_head"] = dense(next(keys), (dm, cfg.vocab_size), dm)
+        if cfg.ut_steps > 1:
+            # the exit gate, Linear(Dm -> 1): tiny, and its logit picks a
+            # pass, so it stays dense under quantization like the router
+            params["exit_gate_w"] = (
+                jax.random.normal(next(keys), (dm,), jnp.float32)
+                / math.sqrt(dm)).astype(dt)
+            params["exit_gate_b"] = jnp.zeros((), dt)
         return params
 
     def quantize_params(self, params: Params) -> Params:
@@ -309,10 +348,12 @@ class LlamaModel:
         }
         if not cfg.tie_word_embeddings:
             specs["lm_head"] = P(None, _TP)
+        if cfg.ut_steps > 1:
+            specs.update(exit_gate_w=P(None), exit_gate_b=P())
         return specs
 
     def cache_spec(self, quant: bool = False):
-        """KV cache [L,N,2,Bs,Hk*D]: the trailing axis is kv-head-major, so
+        """KV cache [cache_layers,N,2,Bs,Hk*D]: the trailing axis is kv-head-major, so
         sharding it over "model" splits whole kv heads across the mesh.
         For a quantized cache, the scale pool [L,N,2,Hp,Sp] shards its
         head axis the same way — but only when Hk is tile-exact (Hk % 8 ==
@@ -330,7 +371,10 @@ class LlamaModel:
 
     # --------------------------------------------------------------- kv cache
     def init_kv_cache(self, num_blocks: int, block_size: int, dtype=None) -> jax.Array:
-        """One array for the whole model: [L, N, 2, Bs, Hk*D].
+        """One array for the whole model: [cache_layers, N, 2, Bs, Hk*D]
+        (``cache_layers`` = L, or ut_steps x L for a looped decoder: pass t
+        of layer l is cache layer t*L + l, and one block id is one block
+        in every one of them).
 
         A single multi-layer array (rather than per-layer leaves) is what
         lets (a) the decode kernel index layers with a scalar instead of
@@ -346,7 +390,7 @@ class LlamaModel:
         """
         cfg = self.config
         shape = (
-            cfg.num_layers,
+            self.cache_layers,
             num_blocks,
             2,
             block_size,
@@ -360,7 +404,7 @@ class LlamaModel:
             return QuantKvCache(
                 jnp.zeros(shape, jnp.int8),
                 jnp.ones(
-                    (cfg.num_layers, num_blocks, 2, hp, sp), jnp.float32,
+                    (self.cache_layers, num_blocks, 2, hp, sp), jnp.float32,
                 ),
             )
         return jnp.zeros(shape, dt)
@@ -417,8 +461,10 @@ class LlamaModel:
         # Named scopes (embed, attn_proj, attn, attn_out, mlp with moe_router
         # / moe_experts, logits, sample) put a device operation's place in
         # the model into its profiler metadata; cellbench's device.*_pct
-        # read them.  The layer scan carries none, so what XLA adds around
-        # it (per-layer weight slices, layout copies) stays unscoped.
+        # read them.  The layer scan carries none (nor does the loop over
+        # passes), so what XLA adds around them (per-layer weight slices,
+        # layout copies) stays unscoped.  The norm that closes a pass, the
+        # exit gate and its selection are the head's (``logits``).
         with jax.named_scope("embed"):
             hidden = take_rows(params["embed"], tokens, cfg.jax_dtype)
             if cfg.scale_embeddings:  # Gemma multiplies by sqrt(hidden_size)
@@ -440,9 +486,12 @@ class LlamaModel:
             experts = {k: layers[k] for k in _EXPERT_KEYS}
             layers = {k: w for k, w in layers.items() if k not in experts}
 
-        def layer_step(carry, layer_in):
+        def layer_step(carry, layer_in, first=None):
+            """``first``: the cache layer of this pass's layer 0 (None: 0,
+            the only pass)."""
             h, cache = carry
-            lp, li = layer_in
+            lp, li = layer_in   # this layer's weights, its index in L
+            ci = li if first is None else first + li   # its cache layer
             with jax.named_scope("attn_proj"):
                 x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps, uo)
                 q, k, v = _qkv_proj(cfg, lp, x, b, s)
@@ -452,14 +501,14 @@ class LlamaModel:
                 # fast_prefill/ragged imply the engine's block-aligned
                 # contiguous span layout — unlocks the block-granular write
                 cache = write_kv_cache_layer(
-                    cache, li, k, v, slot_idx,
+                    cache, ci, k, v, slot_idx,
                     block_aligned=fast_prefill or ragged_prefill,
                     row_tokens=ragged_row_tokens if ragged_prefill else 0,
                 )
                 if ragged_prefill:
                     seq_ids, seq_starts, row_offsets = ragged
                     attn = ragged_prefill_attention(
-                        q, k, v, cache, li, block_tables, seq_lens,
+                        q, k, v, cache, ci, block_tables, seq_lens,
                         seq_starts, row_offsets, seq_ids, prefix_blocks,
                         sm_scale=self.sm_scale,
                         logit_cap=cfg.attn_logit_softcap,
@@ -467,7 +516,7 @@ class LlamaModel:
                     )
                 elif fast_prefill:
                     attn = prefill_attention(
-                        q, k, v, cache, li, block_tables, seq_lens,
+                        q, k, v, cache, ci, block_tables, seq_lens,
                         positions[:, 0], prefix_blocks,
                         sm_scale=self.sm_scale,
                         logit_cap=cfg.attn_logit_softcap,
@@ -475,14 +524,14 @@ class LlamaModel:
                     )
                 else:
                     attn = paged_attention_layer(
-                        q, cache, li, block_tables, seq_lens, positions,
+                        q, cache, ci, block_tables, seq_lens, positions,
                         sm_scale=self.sm_scale,
                         logit_cap=cfg.attn_logit_softcap,
                         window=cfg.sliding_window,
                     )
             with jax.named_scope("attn_out"):
                 attn_out = matmul(attn.reshape(b, s, hq * dh), lp["wo"])
-                if cfg.post_norms:  # Gemma2 sandwich: norm the residual branch
+                if cfg.post_norms:  # sandwich: norm the residual branch
                     attn_out = rms_norm(attn_out, lp["post_attn_norm"],
                                         cfg.rms_norm_eps, uo)
                 h = h + attn_out
@@ -500,14 +549,53 @@ class LlamaModel:
                 h = h + mlp_out
             return (h, cache), None
 
-        (hidden, new_cache), _ = jax.lax.scan(
-            layer_step,
-            (hidden, kv_cache),
-            (layers, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
+        def run_pass(hidden, cache, first=None):
+            """The layer scan once, then the final norm."""
+            (hidden, cache), _ = jax.lax.scan(
+                lambda carry, layer_in: layer_step(carry, layer_in, first),
+                (hidden, cache),
+                (layers, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
+            )
+            with jax.named_scope("logits"):
+                hidden = rms_norm(hidden, params["final_norm"],
+                                  cfg.rms_norm_eps, cfg.rmsnorm_unit_offset)
+            return hidden, cache
+
+        if cfg.ut_steps == 1:
+            return run_pass(hidden, kv_cache)
+
+        # Looped decoder: the same scan ``ut_steps`` times, the weights read
+        # where they lie in every pass, (hidden, cache) carried through.  The
+        # final norm closes every pass and feeds the next; the exit gate
+        # reads it.  Every pass always runs (a later token attends to every
+        # pass's K/V): the gate only chooses which pass's state goes to the
+        # head — the first at which the cumulated exit probability reaches
+        # the threshold, else the last.
+        last = cfg.ut_steps - 1
+        threshold = jnp.float32(cfg.early_exit_threshold)
+
+        def pass_step(carry, t):
+            h, cache, out, alive, cum = carry
+            h, cache = run_pass(h, cache, t * cfg.num_layers)
+            with jax.named_scope("logits"):
+                gate = jnp.einsum(
+                    "bsd,d->bs", h.astype(jnp.float32),
+                    params["exit_gate_w"].astype(jnp.float32),
+                ) + params["exit_gate_b"].astype(jnp.float32)
+                lam = jax.nn.sigmoid(gate)
+                # p_t = lam_t * prod_{j<t}(1 - lam_j); the last pass takes
+                # what is left
+                reached = cum + jnp.where(t == last, alive, lam * alive)
+                take = (cum < threshold) & ((reached >= threshold) | (t == last))
+                out = jnp.where(take[..., None], h, out)
+            return (h, cache, out, alive * (1.0 - lam), reached), None
+
+        zeros = jnp.zeros((b, s), jnp.float32)
+        (_, new_cache, hidden, _, _), _ = jax.lax.scan(
+            pass_step,
+            (hidden, kv_cache, jnp.zeros_like(hidden), zeros + 1.0, zeros),
+            jnp.arange(cfg.ut_steps, dtype=jnp.int32),
         )
-        with jax.named_scope("logits"):
-            hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps,
-                              cfg.rmsnorm_unit_offset)
         return hidden, new_cache
 
     def forward_seq_parallel(
@@ -533,6 +621,11 @@ class LlamaModel:
         from dynamo_tpu.ops.ring_attention import ring_attention
 
         cfg = self.config
+        if not self.supports_seq_parallel:
+            raise NotImplementedError(
+                "seq-parallel prefill walks the layer stack once; a looped "
+                f"decoder (ut_steps={cfg.ut_steps}) is served by forward() "
+                "only: disable sp_prefill_threshold")
         b, s = tokens.shape
         dh, hq, hk = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
 

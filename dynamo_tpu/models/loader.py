@@ -50,6 +50,8 @@ def load_params_from_state_dict(
     # Phi3 fuses qkv_proj and gate_up_proj into single matrices
     fused_qkv = "model.layers.0.self_attn.qkv_proj.weight" in state
     fused_gate_up = "model.layers.0.mlp.gate_up_proj.weight" in state
+    # Ouro names its two extra (sandwich) norms after the norm they follow
+    ouro_norms = "model.layers.0.input_layernorm_2.weight" in state
 
     def stack_fused(fmt: str, sizes: list[int]) -> list[jnp.ndarray]:
         """One read of each layer's fused [sum(sizes), in] matrix, split
@@ -82,15 +84,27 @@ def load_params_from_state_dict(
         "wv": wv,
         "wo": stack("model.layers.{i}.self_attn.o_proj.weight"),
         # Gemma2 renames the pre-MLP norm and adds sandwich norms; in the
-        # Llama family post_attention_layernorm IS the pre-MLP norm
+        # Llama family (and Ouro) post_attention_layernorm IS the pre-MLP
+        # norm
         "mlp_norm": stack(
             "model.layers.{i}.pre_feedforward_layernorm.weight"
-            if cfg.post_norms
+            if cfg.post_norms and not ouro_norms
             else "model.layers.{i}.post_attention_layernorm.weight",
             transpose=False,
         ),
     }
-    if cfg.post_norms:
+    if ouro_norms:
+        # Ouro's sandwich: input_layernorm_2 norms the attention branch,
+        # post_attention_layernorm_2 the FFN branch (names assumed from the
+        # layer's equations: no checkpoint was at hand to read)
+        layers.update(
+            post_attn_norm=stack(
+                "model.layers.{i}.input_layernorm_2.weight", transpose=False),
+            post_mlp_norm=stack(
+                "model.layers.{i}.post_attention_layernorm_2.weight",
+                transpose=False),
+        )
+    elif cfg.post_norms:
         layers.update(
             post_attn_norm=stack(
                 "model.layers.{i}.post_attention_layernorm.weight",
@@ -164,6 +178,11 @@ def load_params_from_state_dict(
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = jnp.asarray(get("lm_head.weight").T, dtype=dt)
+    if cfg.ut_steps > 1:  # looped decoder: the exit gate, Linear(Dm -> 1)
+        params["exit_gate_w"] = jnp.asarray(
+            get("model.early_exit_gate.weight").reshape(-1), dtype=dt)
+        params["exit_gate_b"] = jnp.asarray(
+            get("model.early_exit_gate.bias").reshape(()), dtype=dt)
     return params
 
 

@@ -119,9 +119,15 @@ class EngineMetric:
     PROMPT_TOKENS_CACHED_TOTAL = "dynamo_tpu_engine_prompt_tokens_cached_total"
     ATTN_CONTEXT_TOKENS_TOTAL = "dynamo_tpu_engine_attn_context_tokens_total"
     ATTN_SELECTED_TOKENS_TOTAL = "dynamo_tpu_engine_attn_selected_tokens_total"
+    # tokens dispatched and the passes of the layer stack run for them
+    LOOP_TOKENS_TOTAL = "dynamo_tpu_engine_loop_tokens_total"
+    LOOP_PASSES_TOTAL = "dynamo_tpu_engine_loop_passes_total"
     # engine/counters.py mesh_shape
     MESH_TP = "dynamo_tpu_engine_mesh_tp"
     MESH_DEVICES = "dynamo_tpu_engine_mesh_devices"
+    # engine/counters.py cache_shape
+    CACHE_LAYERS = "dynamo_tpu_engine_cache_layers"
+    KV_BYTES_PER_TOKEN = "dynamo_tpu_engine_kv_bytes_per_token"
 
 
 class KvTransferMetric:
@@ -235,6 +241,10 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.ATTN_SELECTED_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.MESH_TP: ("gauge", ()),
     EngineMetric.MESH_DEVICES: ("gauge", ()),
+    EngineMetric.LOOP_TOKENS_TOTAL: ("counter", ()),
+    EngineMetric.LOOP_PASSES_TOTAL: ("counter", ()),
+    EngineMetric.CACHE_LAYERS: ("gauge", ()),
+    EngineMetric.KV_BYTES_PER_TOKEN: ("gauge", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.BYTES_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.SECONDS_TOTAL: ("counter", ("src", "dst", "path")),

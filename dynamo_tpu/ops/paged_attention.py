@@ -232,10 +232,11 @@ def paged_attention_layer(
 
         if s == 1:
             # tuning knobs for on-chip sweeps (benchmarks/profile_decode.py):
-            # group size trades per-grid-step fixed cost against VMEM; the
-            # defaults fit 8B bf16 KV, int8 KV has headroom for larger groups
-            spg = int(os.environ.get("DYNAMO_DECODE_SEQS_PER_GROUP", "8"))
-            bpc = int(os.environ.get("DYNAMO_DECODE_BLOCKS_PER_CHUNK", "4"))
+            # group size trades per-grid-step fixed cost against VMEM.
+            # Unset, the kernel takes the tiling its geometry allows
+            # (registry.decode_tiling: 8 and 4 where the scratch fits)
+            spg = int(os.environ.get("DYNAMO_DECODE_SEQS_PER_GROUP", 0)) or None
+            bpc = int(os.environ.get("DYNAMO_DECODE_BLOCKS_PER_CHUNK", 0)) or None
             kernel = functools.partial(
                 paged_decode_attention, sm_scale=sm_scale,
                 logit_cap=logit_cap, seqs_per_group=spg,
@@ -610,6 +611,23 @@ def _write_layer_rows(
         valid = (slot_idx >= 0).reshape(b * nb, bs, 1)
         gk = rows_k.reshape(b * nb, bs, r)
         gv = rows_v.reshape(b * nb, bs, r)
+        if b * nb == 1:
+            # One block (a chunk of exactly Bs tokens: every short prompt).
+            # XLA turns a scatter of ONE update with an out-of-bounds
+            # "drop" into select(in bounds, updated, original) over the
+            # whole operand: a second copy of the cache as a temporary of
+            # the prefill program (the copy(bitcast) of [L*N*2, Bs, R] that
+            # PR 24 met as "the decode program's twin").  K and V of a block
+            # are adjacent rows, so the same read-modify-write is one
+            # dynamic-update-slice of two rows at a clamped index; a dropped
+            # block (first slot -1: no row of it is valid) rewrites what is
+            # there.
+            at = (jnp.minimum(base[0], size - 2), 0, 0)
+            cur = jax.lax.dynamic_slice(flat, at, (2, bs, r))
+            new = jnp.where(valid & (base[0] < size),
+                            jnp.concatenate([gk, gv]), cur)
+            return jax.lax.dynamic_update_slice(flat, new, at).reshape(
+                cache.shape)
         # read-modify-write: padding rows inside a partial block preserve
         # the existing cache bytes instead of clobbering them with K/V of
         # padding tokens

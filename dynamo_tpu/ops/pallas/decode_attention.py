@@ -49,9 +49,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dynamo_tpu.ops.paged_attention import softcap
 from dynamo_tpu.ops.pallas.registry import (
-    DECODE_BLOCKS_PER_CHUNK,
-    DECODE_SEQS_PER_GROUP,
     decode_cost_estimate,
+    decode_tiling,
 )
 
 __all__ = ["paged_decode_attention", "paged_decode_attention_mq"]
@@ -259,8 +258,8 @@ def paged_decode_attention(
     seq_lens: jax.Array,      # [B] int32
     sm_scale: float | None = None,
     logit_cap: float | None = None,
-    blocks_per_chunk: int = DECODE_BLOCKS_PER_CHUNK,
-    seqs_per_group: int = DECODE_SEQS_PER_GROUP,
+    blocks_per_chunk: int | None = None,
+    seqs_per_group: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """One decode step of attention for B sequences.  Returns [B, H, D]."""
@@ -287,8 +286,8 @@ def paged_decode_attention_mq(
     q0_pos: jax.Array,        # [B] int32 — absolute position of q[:, 0]
     sm_scale: float | None = None,
     logit_cap: float | None = None,
-    blocks_per_chunk: int = DECODE_BLOCKS_PER_CHUNK,
-    seqs_per_group: int = DECODE_SEQS_PER_GROUP,
+    blocks_per_chunk: int | None = None,
+    seqs_per_group: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Multi-query flash decode: S queries per row (query j at position
@@ -296,7 +295,11 @@ def paged_decode_attention_mq(
     verify pass and other short non-block-aligned S>1 steps stream only
     live KV instead of gathering the padded table.  Returns [B, S, H, D].
     Rows whose real query count is < S put padding at the tail; their
-    outputs are finite garbage the caller discards."""
+    outputs are finite garbage the caller discards.
+
+    ``seqs_per_group`` / ``blocks_per_chunk`` left None follow the
+    geometry (``registry.decode_tiling``): 8 and 4 wherever the kernel's
+    scratch fits, less where Hk*D is wide (plain multi-head attention)."""
     from dynamo_tpu.ops.kv_quant import is_quant
 
     quant = is_quant(cache)
@@ -309,6 +312,10 @@ def paged_decode_attention_mq(
     rows = s_q * h
     if sm_scale is None:
         sm_scale = 1.0 / (d**0.5)
+    spg, bpc = decode_tiling(h, hkd, bs, data.dtype.itemsize,
+                             q.dtype.itemsize)
+    seqs_per_group = seqs_per_group or spg
+    blocks_per_chunk = blocks_per_chunk or bpc
     c = min(blocks_per_chunk, m)
     # VMEM scratch scales with S*H rows: shrink the group accordingly
     g = max(1, seqs_per_group // s_q)

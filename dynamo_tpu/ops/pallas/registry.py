@@ -46,6 +46,9 @@ __all__ = [
     "MLA_MASKED_KEYS_PER_TILE",
     "V5E_VMEM_BYTES",
     "VMEM_BUDGET_BYTES",
+    "SCOPED_VMEM_BYTES",
+    "decode_vmem_bytes",
+    "decode_tiling",
     "KERNELS",
     "audit_cases",
     "fuzz_case",
@@ -92,6 +95,12 @@ MLA_MASKED_KEYS_PER_TILE = 512
 # the compiler needs headroom for spills and the double-buffer pipeline.
 V5E_VMEM_BYTES = 128 * 1024 * 1024
 VMEM_BUDGET_BYTES = int(V5E_VMEM_BYTES * 0.75)
+
+# What one kernel may allocate unless it asks for more: the TPU compiler's
+# scoped-VMEM limit on the v5e.  A decode kernel whose scratch passes it is
+# refused at compile time ("exceeded scoped vmem limit"), and the dispatch
+# would then have to drop to the XLA path.
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 
 # Pallas allocates two buffers per blocked operand (pipeline double
 # buffering); manual kvbuf scratch already carries its own factor 2.
@@ -222,6 +231,39 @@ def _record_call(kernel, kw: dict, operands) -> dict:
 
 def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def decode_vmem_bytes(g: int, c: int, rows: int, hkd: int, bs: int,
+                      cache_bytes: int = 2, q_bytes: int = 2) -> int:
+    """VMEM one grid step of the flash-decode kernel holds at G sequences
+    a group and C blocks a chunk: the double-buffered K/V scratch
+    ``(2, G, C, 2, Bs, Hk*D)``, the f32 accumulator and the m / l
+    statistics, the pipelined q (f32) and output blocks, and one chunk's
+    keys and values upcast to f32 for the two matmuls."""
+    kvbuf = 2 * g * c * 2 * bs * hkd * cache_bytes
+    acc_ml = g * rows * (hkd + 2 * 128) * 4
+    io = DOUBLE_BUFFER * g * rows * hkd * (4 + q_bytes)
+    upcast = 2 * c * bs * hkd * 4
+    return kvbuf + acc_ml + io + upcast
+
+
+def decode_tiling(rows: int, hkd: int, bs: int, cache_bytes: int = 2,
+                  q_bytes: int = 2) -> tuple[int, int]:
+    """(seqs_per_group, blocks_per_chunk) of the flash-decode kernel for a
+    geometry: the serving defaults (8, 4) wherever their scratch fits the
+    scoped VMEM of one kernel, else halved until it does — the blocks of a
+    chunk first (a block of 2,048 lanes is a 256 KiB DMA already, and a
+    shorter chunk re-fetches less of a short row's last block), then the
+    group.  ``rows`` = query rows a sequence (heads, one query each),
+    ``hkd`` = Hk*D lanes of a cache row."""
+    g, c = DECODE_SEQS_PER_GROUP, DECODE_BLOCKS_PER_CHUNK
+    while (decode_vmem_bytes(g, c, rows, hkd, bs, cache_bytes, q_bytes)
+           > SCOPED_VMEM_BYTES and (g > 1 or c > 1)):
+        if c > 1 and c * 2 >= g:
+            c //= 2
+        else:
+            g //= 2
+    return g, c
 
 
 def decode_kernel_cost(

@@ -55,7 +55,8 @@ class _Clock:
 def reset_producers() -> None:
     """Reset every process-global producer the HTTP render reads (the
     same singletons the tier-1 tests isolate against)."""
-    from dynamo_tpu.engine.counters import (counters, kv_shard_counters,
+    from dynamo_tpu.engine.counters import (cache_shape, counters,
+                                            kv_shard_counters,
                                             kv_stream_counters,
                                             mesh_shape,
                                             persist_counters,
@@ -70,6 +71,7 @@ def reset_producers() -> None:
               fault_counters, transfer_costs, perf_model):
         c.reset()
     mesh_shape.update(tp=1, devices=1)
+    cache_shape.update(layers=0, bytes_per_token=0)
     step_timeline.reset()
     step_timeline._clock = time.perf_counter
 
@@ -77,7 +79,8 @@ def reset_producers() -> None:
 def seed_http_metrics():
     """Fixed recording across every producer family; returns the
     seeded ``Metrics`` instance (render via ``render_http``)."""
-    from dynamo_tpu.engine.counters import (counters, kv_shard_counters,
+    from dynamo_tpu.engine.counters import (cache_shape, counters,
+                                            kv_shard_counters,
                                             kv_stream_counters,
                                             mesh_shape,
                                             persist_counters,
@@ -126,6 +129,8 @@ def seed_http_metrics():
     request_counters.record_prompt(1000, 768)
     request_counters.record_sparse_decode(48000, 4096)
     mesh_shape.update(tp=4, devices=4)
+    request_counters.record_loop(300, 1200)
+    cache_shape.update(layers=192, bytes_per_token=1572864)
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()
     persist_counters.record_spill(4096)

@@ -37,6 +37,8 @@ pa = importlib.import_module("dynamo_tpu.ops.paged_attention")
 GEOMETRIES = {
     "llama-3.2-1b": dict(h=32, hk=8, d=64, matmul=(64, 2048, 8192)),
     "llama-3-8b": dict(h=32, hk=8, d=128, matmul=(64, 4096, 14336)),
+    # plain multi-head attention: Hk*D = 2,048 lanes, twice any other's
+    "ouro-2.6b": dict(h=16, hk=16, d=128, matmul=(64, 2048, 5632)),
 }
 BS, N_BLOCKS, M, B = 32, 512, 64, 64
 PREFILL_S, RAGGED_T, RAGGED_ROWS, MQ_S = 512, 1024, 8, 4
@@ -405,9 +407,9 @@ def test_mistral_7b_whole_compiles_under_tp4(topo, tpu_gate, program):
     mem = compiled.memory_analysis()
     per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    # 3.56 GiB of weights and the 4 GiB cache shard, donated: and, unlike the
-    # one-chip decode program (PERF.md section 6, finding 1), no second copy
-    # of the cache among the temporaries
+    # 3.56 GiB of weights and the 4 GiB cache shard, donated, and no second
+    # copy of the cache among the temporaries (nor on one chip:
+    # test_one_chip_programs_keep_one_cache, ROADMAP S7)
     assert 7.5 * 2**30 < per_device < V5E_HBM, per_device
     assert mem.temp_size_in_bytes < 2**30, mem.temp_size_in_bytes
     # a layer all-reduces twice (after wo and after w_down) and does nothing
@@ -434,6 +436,10 @@ _SCAN_PROGRAMS = {       # configuration file, program, chips, overrides
     "qwen3-30b-a3b-decode": ("qwen3-30b-a3b.json", "decode", 1, _TWO_LAYERS),
     "mistral-7b-tp4-decode": ("mistral-7b-tp4.json", "decode", 4, _TWO_LAYERS),
     "glm-5.2-ep16-decode": ("glm-5.2-ep16.json", "decode", 1, {}),
+    "ouro-2.6b-decode": ("ouro-2.6b.json", "decode", 1, _TWO_LAYERS),
+    "ouro-2.6b-prefill": ("ouro-2.6b.json", "prefill", 1, _TWO_LAYERS),
+    # not a cell: the looped model sharded as any LlamaModel (docs/looped_layers.md)
+    "ouro-2.6b-tp4-decode": ("ouro-2.6b.json", "decode", 4, _TWO_LAYERS),
 }
 # GLM's ``kv_b`` is still transposed once a layer ([1,512,28672], 29 MB):
 # its two einsums are batched over the head, which lies in the middle of
@@ -476,7 +482,7 @@ def test_layer_scan_copies_no_projection_weight(topo, tpu_gate, case):
     assert "tpu_custom_call" in hlo and " while(" in hlo
 
     weights = _layer_slices(params)
-    assert len(weights) >= 3, weights
+    assert sum(map(len, weights.values())) >= 3, weights
     relaid = []
     for name, dtype, dims, op in _unfused_instructions(hlo):
         if op not in ("copy", "transpose"):
@@ -489,6 +495,63 @@ def test_layer_scan_copies_no_projection_weight(topo, tpu_gate, case):
             relaid.append(f"{name} = {dtype}{list(dims)} {op}: a layer of "
                           + "/".join(sorted(names)))
     assert not relaid, relaid
+
+
+# ---------------------------------------------------------------------------
+# A looped decoder whole on one chip (PR 39): 48 layers run 4 times, 192
+# cache layers of 2,048 lanes.  The decode kernel's tiling follows the
+# geometry (at G 8, C 4 its K/V scratch alone is 16 MiB, the compiler's
+# scoped limit), and neither program keeps a second copy of the cache:
+# 4.97 GiB of weights and the configuration's own cache, donated, are all.
+def test_decode_tiling_follows_the_geometry():
+    from dynamo_tpu.ops.pallas import registry
+
+    accepted = {   # rows a sequence, Hk*D lanes: the benchmark's decode kernels
+        "mistral-7b": (32, 1024), "qwen3-30b-a3b": (32, 512),
+        "mistral-7b-tp4 (a shard)": (8, 256), "llama-3-8b": (32, 1024)}
+    for name, (rows, hkd) in accepted.items():
+        assert registry.decode_tiling(rows, hkd, BS) == (8, 4), name
+    g, c = registry.decode_tiling(16, 2048, BS)
+    assert (g, c) != (8, 4) and g * c >= 8
+    assert registry.decode_vmem_bytes(g, c, 16, 2048, BS) \
+        <= registry.SCOPED_VMEM_BYTES < registry.decode_vmem_bytes(
+            8, 4, 16, 2048, BS)
+    # the K/V scratch the issue counted: (2, G, C, 2, Bs, Hk*D) in bf16
+    assert 2 * 8 * 4 * 2 * BS * 2048 * 2 == registry.SCOPED_VMEM_BYTES
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "prefill-one-block"])
+@pytest.mark.parametrize("config", ["ouro-2.6b", "mistral-7b"])
+def test_one_chip_programs_keep_one_cache(topo, tpu_gate, config, program):
+    """ROADMAP S7: "the one-chip decode program reserves a second copy of
+    the whole cache" was the *prefill* program of a chunk of exactly one
+    block — every short prompt — whose one-update scatter XLA lowered to a
+    select over the whole cache.  No program of either configuration holds
+    more temporaries than a fraction of its cache, at the cache the file
+    asks for."""
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        config + ".json", lambda spec: SingleDeviceSharding(topo.devices[0]))
+    serve = dict(hf["serve"])
+    if program == "prefill-one-block":
+        program, serve["prefill_chunk_tokens"] = "prefill", serve["block_size"]
+    fn, args = _step_program(program, model, serve, sds)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    assert ("paged_decode_attention" if program == "decode"
+            else "paged_prefill_attention") in hlo
+    # the layer scan, and round it the loop of passes where there is one
+    assert hlo.count(" while(") == (2 if cfg.ut_steps > 1 else 1)
+    mem = compiled.memory_analysis()
+    cache_bytes = cache.size * cache.dtype.itemsize
+    assert mem.alias_size_in_bytes >= cache_bytes           # donated
+    assert mem.temp_size_in_bytes < cache_bytes // 8, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < V5E_HBM, total
+    if config == "ouro-2.6b":       # whole, and the chip full
+        assert hf["reduced"] == [] and model.cache_layers == 192
+        assert cache_bytes >= 9 * 2**30 and total > 14 * 2**30
 
 
 def test_tp_rules_that_keep_the_xla_path(tpu_gate):
