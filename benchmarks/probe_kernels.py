@@ -13,6 +13,7 @@ by construction one the registry knows (``dynamo-tpu lint --kern``'s
 KN006 census flags any registered kernel that loses probe coverage).
 
 Usage:  python benchmarks/probe_kernels.py [bf16|int8|all] [8b|1b|probe]
+        python benchmarks/probe_kernels.py lengths [out.json]   # decode sweep
 """
 
 from __future__ import annotations
@@ -60,10 +61,127 @@ def time_topk() -> None:
         print(f"topk/{name}: {(time.perf_counter() - t0) / 20 * 1e3:.3f} ms")
 
 
+# The decode kernel alone at the benchmark cells' geometries (rows, query
+# heads, kv heads; head_dim 128, block 32, a table of 128 blocks) with the
+# tiling ``decode_tiling`` gives each, and two more to read (G, C) by.
+DECODE_GEOMS = {
+    "mistral-7b 32x32x1024": (32, 32, 8),
+    "mistral-7b-tp4 shard 64x8x256": (64, 8, 2),
+    "ouro-2.6b 16x16x2048": (16, 16, 16),
+    "qwen3-30b-a3b 32x32x512": (32, 32, 4),
+}
+DECODE_TILINGS = {"ouro-2.6b 16x16x2048": [(4, 2)],
+                  "mistral-7b 32x32x1024": [(4, 4)]}
+
+
+def decode_length_mixes(rows: int, rng) -> dict:
+    """Context lengths by slot: equal, uniform, and chat's (cellbench's chat
+    cells: prompt lognormal median 512 sigma 0.8 in [32, 3072] plus a
+    uniform part of an answer lognormal median 128 sigma 0.6 in [16, 512])
+    with every slot live and with a quarter of them."""
+    import numpy as np
+
+    prompt = np.clip(rng.lognormal(np.log(512), 0.8, rows), 32, 3072)
+    answer = np.clip(rng.lognormal(np.log(128), 0.6, rows), 16, 512)
+    chat = (prompt + rng.uniform(0, 1, rows) * answer).astype(np.int32)
+    live = np.zeros(rows, bool)
+    live[rng.permutation(rows)[:rows // 4]] = True
+    return {
+        "equal 224": np.full(rows, 224, np.int32),
+        "uniform 64-384": rng.integers(64, 385, rows).astype(np.int32),
+        "chat, all slots live": chat,
+        "chat, 25% live": np.where(live, chat, 0).astype(np.int32),
+    }
+
+
+def time_decode_lengths(out_path: str | None) -> None:
+    """µs a call of ``paged_decode_attention_mq`` (the custom call's own
+    device time, from a profile of 20 calls) and GB/s of the bytes the
+    contexts hold, rows in slot order and grouped by length.  (Run with
+    this file copied over the parent of PR 40, the same table reads the
+    kernel that fetched every row up to its group's longest.)"""
+    import glob
+    import json
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.profiler import ProfileData
+
+    from dynamo_tpu.ops.pallas import decode_attention as da
+    from dynamo_tpu.ops.pallas.registry import decode_tiling
+
+    d, bs, m, calls = 128, 32, 128, 20
+    print(f"# device {jax.devices()[0].device_kind}")
+
+    def kernel_us(fn, args) -> float:
+        jax.block_until_ready(fn(*args))
+        with tempfile.TemporaryDirectory() as tmp:
+            with jax.profiler.trace(tmp):
+                for _ in range(calls):
+                    out = fn(*args)
+                jax.block_until_ready(out)
+            path = glob.glob(f"{tmp}/plugins/profile/*/*.xplane.pb")[0]
+            data = ProfileData.from_file(path)
+        # an event's name is its HLO instruction: the custom call's begins
+        # with the kernel's name (its users only mention it)
+        took = [e.duration_ns for p in data.planes if p.name == "/device:TPU:0"
+                for line in p.lines if line.name == "XLA Ops"
+                for e in line.events
+                if e.name.startswith("%paged_decode_attention")]
+        assert len(took) == calls, (len(took), calls)
+        return float(np.median(took)) / 1e3
+
+    table = []
+    for geom, (rows, h, hk) in DECODE_GEOMS.items():
+        hkd = hk * d
+        rng = np.random.default_rng(40)
+        mixes = decode_length_mixes(rows, rng)
+        tilings = [decode_tiling(h, hkd, bs)] + DECODE_TILINGS.get(geom, [])
+        n = max(int((-(-x // bs)).sum()) for x in mixes.values()) + 1
+        kq, kc = jax.random.split(jax.random.key(40))
+        cache = jax.random.normal(kc, (1, n, 2, bs, hkd), jnp.bfloat16)
+        q = jax.random.normal(kq, (rows, 1, h, d), jnp.bfloat16)
+        for mix, lens in mixes.items():
+            # every row its own blocks, scattered over the pool; block 0
+            # (what an empty slot's table of zeros names) is no row's
+            need = -(-lens // bs)
+            pool = rng.permutation(n - 1)[:need.sum()] + 1
+            bt = np.zeros((rows, m), np.int32)
+            for r, at in enumerate(np.cumsum(need) - need):
+                bt[r, :need[r]] = pool[at:at + need[r]]
+            useful = 2 * (2 * hkd * int(lens.sum())
+                          + 2 * h * d * int((lens > 0).sum()))
+            grouped = np.argsort(-lens, kind="stable")
+            for g, c in tilings:
+                fn = jax.jit(lambda q, cache, bt, lens, g=g, c=c:
+                             da.paged_decode_attention_mq(
+                                 q, cache, jnp.int32(0), bt, lens, lens - 1,
+                                 seqs_per_group=g, blocks_per_chunk=c))
+                row = {"geometry": geom, "lengths": mix, "g": g, "c": c,
+                       "useful_bytes": useful}
+                for label, o in (("slot_order", np.arange(rows)),
+                                 ("by_length", grouped)):
+                    us = kernel_us(fn, (q[o], cache, jnp.asarray(bt[o]),
+                                        jnp.asarray(lens[o])))
+                    row[f"{label}_us"] = round(us, 2)
+                    row[f"{label}_gb_s"] = round(useful / us / 1e3, 1)
+                table.append(row)
+                print(json.dumps(row), flush=True)
+    if out_path:
+        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump(table, f, indent=1)
+
+
 def main() -> None:
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which == "topk":
         time_topk()
+        return
+    if which == "lengths":
+        time_decode_lengths(sys.argv[2] if len(sys.argv) > 2 else None)
         return
     geom = GEOMS[sys.argv[2] if len(sys.argv) > 2 else "8b"]
     import jax

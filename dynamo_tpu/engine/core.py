@@ -648,6 +648,12 @@ class EngineCore:
         self._ut_steps = int(getattr(model.config, "ut_steps", 1) or 1)
         self.loop_tokens = 0
         self.loop_passes = 0
+        # K/V blocks the rows of the decode dispatches own (what the
+        # flash-decode kernel fetches a layer), and what fetching every
+        # slot up to the longest row of its group fetched for them
+        self.decode_kv_blocks_walked = 0
+        self.decode_kv_blocks_group_bound = 0
+        self._decode_tiling = self._flash_decode_tiling()
         self.first_token_s = 0.0         # sum of (first emit - submitted_at)
         # cached _unified_penalties host buffers (invalidated on
         # admission/finish; incremental append between turns)
@@ -814,6 +820,41 @@ class EngineCore:
             self._cache_specs,
             is_leaf=lambda x: isinstance(x, PartitionSpec),
         )
+
+    def _flash_decode_tiling(self) -> Optional[tuple[int, int]]:
+        """(G, C) of the flash-decode kernel at this engine's geometry (a
+        shard's heads under a mesh); None for a model with attention
+        kernels of its own."""
+        from dynamo_tpu.ops.pallas.registry import decode_tiling
+
+        if hasattr(self.model, "attention_impls"):
+            return None
+        mc, tp = self.model.config, self.mesh_tp
+        q_bytes = jnp.dtype(mc.jax_dtype).itemsize
+        return decode_tiling(
+            max(1, mc.num_heads // tp),
+            max(1, mc.num_kv_heads * mc.head_dim // tp),
+            self.config.block_size, 1 if self.cache_quant else q_bytes,
+            q_bytes)
+
+    def _count_decode_blocks(self, seq_lens: np.ndarray, s_q: int = 1) -> None:
+        """Blocks the rows of a decode dispatch own, and what the kernel's
+        groups of G slots (in slot order, G shrunk by the queries a row as
+        the kernel shrinks it) fetched when every slot went up to its
+        group's longest row in chunks of C blocks."""
+        from dynamo_tpu.ops.pallas.registry import decode_group_and_chunk
+
+        if self._decode_tiling is None:
+            return
+        g, c = decode_group_and_chunk(
+            len(seq_lens), s_q, self.config.max_blocks_per_seq,
+            *self._decode_tiling)
+        blocks = -(-seq_lens // self.config.block_size)
+        walked = int(blocks.sum())
+        bound = int((-(-blocks.reshape(-1, g).max(axis=1) // c)).sum()) * g * c
+        self.decode_kv_blocks_walked += walked
+        self.decode_kv_blocks_group_bound += bound
+        request_counters.record_decode_blocks(walked, bound)
 
     def attention_impls(self) -> dict[str, tuple[str, str]]:
         """phase -> ("pallas" | "xla", why), as the dispatch in
@@ -1368,6 +1409,9 @@ class EngineCore:
             "attn_selected_tokens_total": self.attn_selected_tokens,
             "loop_tokens_total": self.loop_tokens,
             "loop_passes_total": self.loop_passes,
+            "decode_kv_blocks_walked_total": self.decode_kv_blocks_walked,
+            "decode_kv_blocks_group_bound_total":
+                self.decode_kv_blocks_group_bound,
             "cache_layers": self.cache_layers,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "first_token_seconds_total": self.first_token_s,
@@ -2569,6 +2613,7 @@ class EngineCore:
         self.decode_dispatches += 1
         self.decode_rows_dispatched += len(rows)
         request_counters.record_decode(len(rows))
+        self._count_decode_blocks(seq_lens, tokens.shape[1])
         self._count_tokens(len(rows) * tokens.shape[1])
         for req in rows:
             i = req.slot
@@ -2731,6 +2776,7 @@ class EngineCore:
         self.decode_dispatches += 1
         self.decode_rows_dispatched += len(active)
         request_counters.record_decode(len(active))
+        self._count_decode_blocks(seq_lens)
         self._count_tokens(len(active) * k_steps)
         if self._index_topk:
             ctx = int(seq_lens.sum())

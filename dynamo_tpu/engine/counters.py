@@ -338,6 +338,22 @@ class RequestCounters:
                                                        for a looped decoder,
                                                        1 otherwise; over
                                                        tokens, passes a token)
+        dynamo_tpu_engine_decode_kv_blocks_walked_total
+                                                       counter (K/V blocks the
+                                                       rows of the decode
+                                                       dispatches own,
+                                                       ceil(context / block)
+                                                       a row: what the decode
+                                                       kernel fetches a layer)
+        dynamo_tpu_engine_decode_kv_blocks_group_bound_total
+                                                       counter (what fetching
+                                                       every slot of a group
+                                                       up to the group's
+                                                       longest row took for
+                                                       the same dispatches;
+                                                       1 - walked / bound is
+                                                       the share of fetches a
+                                                       row's own walk spares)
     All six are counted on the host from lengths it already has.
     """
 
@@ -382,6 +398,10 @@ class RequestCounters:
         self.loop_tokens_total += tokens
         self.loop_passes_total += passes
 
+    def record_decode_blocks(self, walked: int, group_bound: int) -> None:
+        self.decode_kv_blocks_walked_total += walked
+        self.decode_kv_blocks_group_bound_total += group_bound
+
     def reset(self) -> None:
         """Test isolation hook — the counters are process-global."""
         self.decode_dispatches_total = 0
@@ -400,6 +420,8 @@ class RequestCounters:
         self.attn_selected_tokens_total = 0
         self.loop_tokens_total = 0
         self.loop_passes_total = 0
+        self.decode_kv_blocks_walked_total = 0
+        self.decode_kv_blocks_group_bound_total = 0
 
 
 request_counters = RequestCounters()

@@ -323,6 +323,14 @@ class Metrics:
         lines.append(f"{EM.LOOP_TOKENS_TOTAL} {rc.loop_tokens_total}")
         lines.append(f"# TYPE {EM.LOOP_PASSES_TOTAL} counter")
         lines.append(f"{EM.LOOP_PASSES_TOTAL} {rc.loop_passes_total}")
+        # K/V blocks the decode rows own (what the decode kernel fetches a
+        # layer) and what fetching up to each group's longest row took
+        lines.append(f"# TYPE {EM.DECODE_KV_BLOCKS_WALKED_TOTAL} counter")
+        lines.append(f"{EM.DECODE_KV_BLOCKS_WALKED_TOTAL} "
+                     f"{rc.decode_kv_blocks_walked_total}")
+        lines.append(f"# TYPE {EM.DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL} counter")
+        lines.append(f"{EM.DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL} "
+                     f"{rc.decode_kv_blocks_group_bound_total}")
         lines.append(f"# TYPE {EM.CACHE_LAYERS} gauge")
         lines.append(f"{EM.CACHE_LAYERS} {cache_shape['layers']}")
         lines.append(f"# TYPE {EM.KV_BYTES_PER_TOKEN} gauge")

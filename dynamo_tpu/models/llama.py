@@ -72,9 +72,11 @@ from dynamo_tpu.models.quant import (
     take_rows,
 )
 from dynamo_tpu.ops.paged_attention import (
+    MQ_MAX_S,
     paged_attention_layer,
     prefill_attention,
     ragged_prefill_attention,
+    rows_by_length,
     softcap,
     tp_size,
     write_kv_cache_layer,
@@ -458,6 +460,21 @@ class LlamaModel:
             prefix_blocks is not None and s > 1 and not ragged_prefill
         )
 
+        # A decode-shaped step (one token a row, or the few of a speculative
+        # verify) runs with its rows grouped by context length: every row is
+        # computed on its own, so the order moves no result, and the decode
+        # kernel, which walks G consecutive rows to the longest of them,
+        # then loops near every row's own length and not at all over a
+        # group of empty slots.  Ordered here, once, on the step's integer
+        # operands; the hidden state goes back to slot order at the end.
+        slot_order = lambda h: h
+        if not (ragged_prefill or fast_prefill) and s <= MQ_MAX_S:
+            order, inverse = rows_by_length(seq_lens)
+            tokens, positions, block_tables, seq_lens, slot_idx = (
+                a[order] for a in (tokens, positions, block_tables, seq_lens,
+                                   slot_idx))
+            slot_order = lambda h: h[inverse]
+
         # Named scopes (embed, attn_proj, attn, attn_out, mlp with moe_router
         # / moe_experts, logits, sample) put a device operation's place in
         # the model into its profiler metadata; cellbench's device.*_pct
@@ -562,7 +579,8 @@ class LlamaModel:
             return hidden, cache
 
         if cfg.ut_steps == 1:
-            return run_pass(hidden, kv_cache)
+            hidden, new_cache = run_pass(hidden, kv_cache)
+            return slot_order(hidden), new_cache
 
         # Looped decoder: the same scan ``ut_steps`` times, the weights read
         # where they lie in every pass, (hidden, cache) carried through.  The
@@ -596,7 +614,7 @@ class LlamaModel:
             (hidden, kv_cache, jnp.zeros_like(hidden), zeros + 1.0, zeros),
             jnp.arange(cfg.ut_steps, dtype=jnp.int32),
         )
-        return hidden, new_cache
+        return slot_order(hidden), new_cache
 
     def forward_seq_parallel(
         self,

@@ -122,6 +122,11 @@ class EngineMetric:
     # tokens dispatched and the passes of the layer stack run for them
     LOOP_TOKENS_TOTAL = "dynamo_tpu_engine_loop_tokens_total"
     LOOP_PASSES_TOTAL = "dynamo_tpu_engine_loop_passes_total"
+    # K/V blocks the decode rows own, and what a group-max fetch took
+    DECODE_KV_BLOCKS_WALKED_TOTAL = (
+        "dynamo_tpu_engine_decode_kv_blocks_walked_total")
+    DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL = (
+        "dynamo_tpu_engine_decode_kv_blocks_group_bound_total")
     # engine/counters.py mesh_shape
     MESH_TP = "dynamo_tpu_engine_mesh_tp"
     MESH_DEVICES = "dynamo_tpu_engine_mesh_devices"
@@ -243,6 +248,8 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.MESH_DEVICES: ("gauge", ()),
     EngineMetric.LOOP_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.LOOP_PASSES_TOTAL: ("counter", ()),
+    EngineMetric.DECODE_KV_BLOCKS_WALKED_TOTAL: ("counter", ()),
+    EngineMetric.DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL: ("counter", ()),
     EngineMetric.CACHE_LAYERS: ("gauge", ()),
     EngineMetric.KV_BYTES_PER_TOKEN: ("gauge", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),

@@ -41,6 +41,7 @@ __all__ = [
     "write_kv_cache_layer",
     "paged_attention",
     "paged_attention_layer",
+    "rows_by_length",
     "prefill_attention",
     "ragged_prefill_attention",
     "SPARSE_PHASES",
@@ -175,6 +176,18 @@ def _per_kv_head(kernel, tp: int, in_specs: tuple, out_spec):
         return kernel
     return jax.shard_map(
         kernel, in_specs=in_specs, out_specs=out_spec, check_vma=False)
+
+
+def rows_by_length(seq_lens: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(order, inverse): the rows of a decode step longest context first,
+    empty slots last, ties in slot order — ``x[order]`` groups them,
+    ``y[inverse]`` puts results back.  The flash-decode kernel takes G
+    consecutive rows a grid step and loops to the longest of them, so rows
+    of like length share a group and a group of empty slots does nothing.
+    Two sorts of B integers: made once a step, before the layer scan (XLA
+    does not move a sort out of a loop's body)."""
+    order = jnp.argsort(seq_lens, stable=True, descending=True)
+    return order, jnp.argsort(order)
 
 
 # operand specs under _per_kv_head: arrays split on their head axis

@@ -6,18 +6,26 @@ context that is GBs of HBM traffic per step and dominates ITL.  This kernel
 instead streams ONLY the blocks each sequence actually owns, directly from
 the full multi-layer cache in HBM.
 
-Design (one grid step per GROUP of G sequences, work ∝ actual context):
+Design (one grid step per GROUP of G sequences, work ∝ each row's context):
 
   * Grid is (B/G,).  TPU grid steps run sequentially on the core, so the
     per-step fixed cost (DMA issue, loop control, semaphore waits) is paid
     B times if the grid is (B,).  Grouping G sequences per step issues all
-    their block DMAs together — G×C copies in flight per chunk — and
+    their block DMAs together — up to G×C copies in flight per chunk — and
     amortises the fixed cost G-fold.  At batch 64 this took the 1B-model
     decode step from ~B sequential latency-bound walks to B/G.
   * Inside the kernel a `fori_loop` with a *data-dependent* bound
-    (ceil(max(seq_len in group) / chunk)) walks the group's chunks —
-    chunks past a sequence's end fetch its last block again (clamped id,
-    masked compute), chunks past the GROUP's max are never visited.
+    (ceil(max(seq_len in group) / chunk)) walks the group's chunks, and
+    every block copy is started (and waited for) only if the row owns that
+    block: a row fetches ceil(seq_len / Bs) blocks, an empty slot none.
+    The part of the K/V scratch no copy wrote holds stale VMEM; dead score
+    columns and dead V rows are *selected* away, never multiplied.
+  * The loop still runs to the group's longest row, so the caller hands
+    the rows over grouped by length (``rows_by_length`` in
+    ops/paged_attention.py; ``LlamaModel.forward`` orders a decode step's
+    rows once, before the layer scan): a group's bound is then near each of
+    its rows' own, and a group of empty slots runs no iteration.  A row's
+    output does not depend on which rows share its group.
   * K/V blocks are fetched with manual double-buffered `make_async_copy`
     from the cache in HBM (`pl.ANY`), chunk i+1 in flight while chunk i
     computes.  K and V of a block are adjacent in the cache layout
@@ -50,6 +58,7 @@ from jax.experimental.pallas import tpu as pltpu
 from dynamo_tpu.ops.paged_attention import softcap
 from dynamo_tpu.ops.pallas.registry import (
     decode_cost_estimate,
+    decode_group_and_chunk,
     decode_tiling,
 )
 
@@ -117,32 +126,38 @@ def _kernel_impl(
     lyr = layer_ref[0]
     quant = scale_ref is not None
 
+    seq = [seq_ref[gi * g + j] for j in range(g)]
     # group-wide chunk bound: max seq_len among the G sequences
-    max_len = seq_ref[gi * g]
-    for j in range(1, g):
-        max_len = jnp.maximum(max_len, seq_ref[gi * g + j])
+    max_len = functools.reduce(jnp.maximum, seq)
     num_chunks = pl.cdiv(max_len, t)  # data-dependent loop bound
+    # blocks a row owns (0 for an empty slot), clamped to the table width:
+    # a caller-side seq_len beyond the table must not index SMEM out of
+    # bounds
+    owned = [jnp.minimum(pl.cdiv(n, bs), bt_ref.shape[1]) for n in seq]
 
-    def block_dmas(ci, slot):
-        out = []
-        m = bt_ref.shape[1]
+    def block_dmas(ci, slot, wait=False):
+        """Start, or wait for, the copies of chunk ``ci``: each under the
+        one predicate "the row owns this block", the same at start and at
+        wait."""
         for j in range(g):          # static unroll over group
-            b = gi * g + j
-            # clamp to the table width: a caller-side seq_len beyond the
-            # table must not index SMEM out of bounds
-            last_block = jnp.minimum(jnp.maximum(seq_ref[b] - 1, 0) // bs, m - 1)
             for i in range(c):      # static unroll: C copies per seq per chunk
-                bid = bt_ref[b, jnp.minimum(ci * c + i, last_block)]
-                # K and V are adjacent in the [.., 2, Bs, HkD] block: ONE DMA
-                out.append(pltpu.make_async_copy(
-                    cache_ref.at[lyr, bid], kvbuf.at[slot, j, i], sems.at[slot, j, i]
-                ))
-                if quant:  # the block's scale tile rides a second small DMA
-                    out.append(pltpu.make_async_copy(
-                        scale_ref.at[lyr, bid], scbuf.at[slot, j, i],
-                        scsems.at[slot, j, i]
-                    ))
-        return out
+                @pl.when(ci * c + i < owned[j])
+                def _copy(j=j, i=i):
+                    bid = bt_ref[gi * g + j, ci * c + i]
+                    # K and V are adjacent in the [.., 2, Bs, HkD] block:
+                    # ONE DMA
+                    dmas = [pltpu.make_async_copy(
+                        cache_ref.at[lyr, bid], kvbuf.at[slot, j, i],
+                        sems.at[slot, j, i])]
+                    if quant:  # the block's scale tile rides a second small DMA
+                        dmas.append(pltpu.make_async_copy(
+                            scale_ref.at[lyr, bid], scbuf.at[slot, j, i],
+                            scsems.at[slot, j, i]))
+                    for dma in dmas:
+                        if wait:
+                            dma.wait()
+                        else:
+                            dma.start()
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -150,22 +165,19 @@ def _kernel_impl(
 
     @pl.when(num_chunks > 0)
     def _prologue():
-        for dma in block_dmas(0, 0):
-            dma.start()
+        block_dmas(0, 0)
 
     def body(ci, _):
         slot = jax.lax.rem(ci, 2)
 
         @pl.when(ci + 1 < num_chunks)
         def _prefetch():
-            for dma in block_dmas(ci + 1, jax.lax.rem(ci + 1, 2)):
-                dma.start()
+            block_dmas(ci + 1, jax.lax.rem(ci + 1, 2))
 
-        for dma in block_dmas(ci, slot):
-            dma.wait()
+        block_dmas(ci, slot, wait=True)
 
         for j in range(g):  # static unroll: one flash update per sequence
-            seq_len = seq_ref[gi * g + j]
+            seq_len = seq[j]
 
             # skip chunks past THIS sequence's end (and zero-length rows:
             # their acc/l stay 0 → output 0)
@@ -176,10 +188,13 @@ def _kernel_impl(
                 v = kvbuf[slot, j, :, 1].reshape(t, hkd).astype(jnp.float32)
 
                 # Slots at/past seq_len hold whatever the pool holds (pad
-                # lanes of a live block, or a clamped re-fetch).  The score
-                # mask zeroes their P columns, but 0 * garbage-V is still
-                # garbage when the pool holds non-finite values — zero V
-                # rows (and the V scales below) for dead slots outright.
+                # lanes of a live block) or whatever the scratch held (a
+                # block of the chunk the row does not own is not copied:
+                # stale VMEM, any bit pattern).  Both are SELECTED away,
+                # never multiplied: the score mask below picks NEG_INF for
+                # their columns of s, and because 0 * garbage-V is still
+                # garbage when V is non-finite, their V rows (and the V
+                # scales) are picked to 0 here.  Keep the two `jnp.where`s.
                 slot_pos = ci * t + jax.lax.broadcasted_iota(
                     jnp.int32, (t, 1), 0)
                 v = jnp.where(slot_pos < seq_len, v, 0.0)
@@ -316,11 +331,7 @@ def paged_decode_attention_mq(
                              q.dtype.itemsize)
     seqs_per_group = seqs_per_group or spg
     blocks_per_chunk = blocks_per_chunk or bpc
-    c = min(blocks_per_chunk, m)
-    # VMEM scratch scales with S*H rows: shrink the group accordingly
-    g = max(1, seqs_per_group // s_q)
-    while b % g:  # group size must divide the batch (terminates at g=1)
-        g -= 1
+    g, c = decode_group_and_chunk(b, s_q, m, seqs_per_group, blocks_per_chunk)
 
     # Block-diagonal q expansion: row for (query sq, head (k, gh)) lives in
     # kv-head k's D-wide column slot; zeros elsewhere.  [B, S, H, D] ->
@@ -371,8 +382,7 @@ def paged_decode_attention_mq(
     # worst case (every row at full-table context).
     cost = decode_cost_estimate(
         b, s_q, h, hk, d, bs, m, cache_bytes=data.dtype.itemsize,
-        quant=quant, blocks_per_chunk=blocks_per_chunk,
-        seqs_per_group=seqs_per_group)
+        quant=quant, blocks_per_chunk=blocks_per_chunk)
 
     out = pl.pallas_call(
         functools.partial(_kernel_quant if quant else _kernel, c=c, g=g,

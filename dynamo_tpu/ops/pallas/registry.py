@@ -49,6 +49,7 @@ __all__ = [
     "SCOPED_VMEM_BYTES",
     "decode_vmem_bytes",
     "decode_tiling",
+    "decode_group_and_chunk",
     "KERNELS",
     "audit_cases",
     "fuzz_case",
@@ -237,7 +238,8 @@ def decode_vmem_bytes(g: int, c: int, rows: int, hkd: int, bs: int,
                       cache_bytes: int = 2, q_bytes: int = 2) -> int:
     """VMEM one grid step of the flash-decode kernel holds at G sequences
     a group and C blocks a chunk: the double-buffered K/V scratch
-    ``(2, G, C, 2, Bs, Hk*D)``, the f32 accumulator and the m / l
+    ``(2, G, C, 2, Bs, Hk*D)`` (all of it reserved, though a row's copies
+    fill only the blocks it owns), the f32 accumulator and the m / l
     statistics, the pipelined q (f32) and output blocks, and one chunk's
     keys and values upcast to f32 for the two matmuls."""
     kvbuf = 2 * g * c * 2 * bs * hkd * cache_bytes
@@ -252,10 +254,12 @@ def decode_tiling(rows: int, hkd: int, bs: int, cache_bytes: int = 2,
     """(seqs_per_group, blocks_per_chunk) of the flash-decode kernel for a
     geometry: the serving defaults (8, 4) wherever their scratch fits the
     scoped VMEM of one kernel, else halved until it does — the blocks of a
-    chunk first (a block of 2,048 lanes is a 256 KiB DMA already, and a
-    shorter chunk re-fetches less of a short row's last block), then the
-    group.  ``rows`` = query rows a sequence (heads, one query each),
-    ``hkd`` = Hk*D lanes of a cache row."""
+    chunk first (a block of 2,048 lanes is a 256 KiB DMA already, and the
+    matmuls of a row's last chunk, which take the whole chunk, waste less
+    of a shorter one), then the group.  What is fetched does not depend on
+    either: a row copies its own ceil(len / Bs) blocks.  ``rows`` = query
+    rows a sequence (heads, one query each), ``hkd`` = Hk*D lanes of a
+    cache row."""
     g, c = DECODE_SEQS_PER_GROUP, DECODE_BLOCKS_PER_CHUNK
     while (decode_vmem_bytes(g, c, rows, hkd, bs, cache_bytes, q_bytes)
            > SCOPED_VMEM_BYTES and (g > 1 or c > 1)):
@@ -266,24 +270,33 @@ def decode_tiling(rows: int, hkd: int, bs: int, cache_bytes: int = 2,
     return g, c
 
 
+def decode_group_and_chunk(b: int, s_q: int, m: int, seqs_per_group: int,
+                           blocks_per_chunk: int) -> tuple[int, int]:
+    """(G, C) one call of the flash-decode kernel runs with at B rows of
+    S queries and a table of M blocks: the VMEM scratch scales with S*H
+    query rows, so the group shrinks by S; it must divide the batch
+    (terminates at 1); a chunk is no longer than the table."""
+    g = max(1, seqs_per_group // s_q)
+    while b % g:
+        g -= 1
+    return g, min(blocks_per_chunk, m)
+
+
 def decode_kernel_cost(
     b: int, s_q: int, h: int, hk: int, d: int, bs: int, m: int,
     lens, cache_bytes: int = 2, quant: bool = False, q_bytes: int = 4,
     blocks_per_chunk: int = DECODE_BLOCKS_PER_CHUNK,
-    seqs_per_group: int = DECODE_SEQS_PER_GROUP,
 ) -> dict:
-    """Analytic cost of one flash-decode dispatch: per-group chunk DMA
-    (work proportional to the group max context, the kernel's actual
-    loop bound), blocked q/out traffic, QK+PV FLOPs and softmax exps.
-    ``lens`` is the per-row context; pass ``[m * bs] * b`` for the
-    worst-case static bound (cost_estimate=)."""
+    """Analytic cost of one flash-decode dispatch: every row's own blocks
+    by DMA (ceil(len / Bs) of them: a block is copied only if the row owns
+    it, nothing for an empty slot), blocked q/out traffic, and QK+PV FLOPs
+    and softmax exps over the chunks a row computes (ceil(len / (C*Bs)):
+    the two matmuls take a whole chunk).  ``lens`` is the per-row context;
+    pass ``[m * bs] * b`` for the worst-case static bound
+    (cost_estimate=)."""
     hkd = hk * d
     rows = s_q * h
-    c = min(blocks_per_chunk, m)
-    g = max(1, seqs_per_group // s_q)
-    while b % g:
-        g -= 1
-    t = c * bs
+    t = min(blocks_per_chunk, m) * bs
     block_bytes = 2 * bs * hkd * cache_bytes
     if quant:
         from dynamo_tpu.ops.kv_quant import scale_tile
@@ -291,16 +304,12 @@ def decode_kernel_cost(
         hp, sp = scale_tile(hk, bs)
         block_bytes += 2 * hp * sp * 4
     lens = [int(x) for x in lens]
-    dma = flops = trans = 0
-    for gi in range(b // g):
-        grp_max = max(lens[gi * g:(gi + 1) * g])
-        chunks = _cdiv(grp_max, t) if grp_max > 0 else 0
-        dma += chunks * g * c * block_bytes
-        flops += chunks * g * 4 * rows * t * hkd  # QK + PV matmuls
-        trans += chunks * g * rows * t            # softmax exp
-    steps = b // g
-    dma += steps * g * rows * hkd * (4 + q_bytes)  # q (f32) in + out
-    return _cost_dict(dma, flops, trans)
+    blocks = sum(_cdiv(n, bs) for n in lens)
+    chunks = sum(_cdiv(n, t) for n in lens)
+    dma = blocks * block_bytes
+    dma += b * rows * hkd * (4 + q_bytes)  # q (f32) in + out
+    return _cost_dict(dma, chunks * 4 * rows * t * hkd,  # QK + PV matmuls
+                      chunks * rows * t)                 # softmax exp
 
 
 def prefill_kernel_cost(
@@ -451,14 +460,13 @@ def _cost_estimate(cost: dict):
 
 
 def decode_cost_estimate(b, s_q, h, hk, d, bs, m, cache_bytes, quant,
-                         blocks_per_chunk, seqs_per_group):
+                         blocks_per_chunk):
     """Worst-case (full-table context) CostEstimate for the decode
     pallas_call — seq_lens are dynamic at trace time, so the static
     bound is every row at M*Bs context."""
     return _cost_estimate(decode_kernel_cost(
         b, s_q, h, hk, d, bs, m, [m * bs] * b, cache_bytes=cache_bytes,
         quant=quant, blocks_per_chunk=blocks_per_chunk,
-        seqs_per_group=seqs_per_group,
     ))
 
 
@@ -663,7 +671,7 @@ def _decode_case(name: str, quant: bool, s_q: int = 1) -> dict:
     def pricing():
         return decode_kernel_cost(
             b, s_q, _H, _HK, _D, _BS, _M, lens, cache_bytes=1 if quant
-            else 4, quant=quant, blocks_per_chunk=2, seqs_per_group=4)
+            else 4, quant=quant, blocks_per_chunk=2)
 
     return {
         "name": name, "kernel": "paged_decode_attention_mq",

@@ -130,6 +130,7 @@ def seed_http_metrics():
     request_counters.record_sparse_decode(48000, 4096)
     mesh_shape.update(tp=4, devices=4)
     request_counters.record_loop(300, 1200)
+    request_counters.record_decode_blocks(2400, 4096)
     cache_shape.update(layers=192, bytes_per_token=1572864)
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()

@@ -201,6 +201,41 @@ def test_operand_buffers_are_counted_on_metrics_and_on_the_http_render(
             f"{per_dispatch * dispatches}\n" in Metrics().render() + "\n")
 
 
+@pytest.mark.parametrize("tp", [1, 4])
+def test_decode_kv_blocks_are_counted_on_metrics_and_on_the_http_render(
+        tiny, tp, request):
+    """PR 40: the K/V blocks the rows of every decode dispatch own (what
+    the flash-decode kernel fetches a layer) beside what the same dispatch
+    fetched when every slot went up to its group's longest row."""
+    where = request.getfixturevalue("mesh") if tp > 1 else None
+    request_counters.reset()
+    core = make_core(tiny, where)
+    calls = watch(core, "_multi_fn")
+    for i, n in enumerate((8, 40)):       # two rows of unlike length, 4 slots
+        core.submit(EngineRequest(
+            f"r{i}", prompt(n, i), SamplingOptions(temperature=0.0),
+            StopConditions(max_tokens=9, ignore_eos=True), lambda o: None))
+    while core.step():
+        pass
+    m = core.metrics()
+    assert len(calls) == m["decode_dispatches_total"] >= 8
+    bs, walked, bound = core.config.block_size, 0, 0
+    for args, _ in calls:
+        lens = np.asarray(args[3])        # seq_lens, by slot
+        assert lens.shape == (4,) and (lens > 0).sum() in (1, 2)
+        blocks = -(-lens // bs)
+        walked += int(blocks.sum())
+        # the tiny geometry's tiling is (8, 4): one group of the 4 slots,
+        # every slot fetched in chunks of 4 blocks up to the longest row
+        bound += 4 * 4 * -(-int(blocks.max()) // 4)
+    assert m["decode_kv_blocks_walked_total"] == walked > 0
+    assert m["decode_kv_blocks_group_bound_total"] == bound > 2 * walked
+    text = Metrics().render() + "\n"
+    assert f"dynamo_tpu_engine_decode_kv_blocks_walked_total {walked}\n" in text
+    assert (f"dynamo_tpu_engine_decode_kv_blocks_group_bound_total {bound}\n"
+            in text)
+
+
 # ------------------------------------------- the two buffers, taken apart
 TREES = {
     "a decode": ((np.arange(4, dtype=np.int32),

@@ -538,6 +538,155 @@ def test_ragged_prefill_kernel_quant_geometry():
 # ------------------------------------------------- registry audit matrix
 
 
+# ------------------------------ a row's K/V traffic follows its own context
+# (PR 40) A block is copied only if the row owns it; nothing is fetched for
+# an empty slot; the scratch no copy wrote is stale (NaN in interpret mode).
+# One kernel serves S = 1, S > 1 and the int8 cache: the same cases for each.
+_WALK = dict(b=8, h=4, hk=2, d=16, bs=8, m=8, g=4, c=2)   # table: 64 tokens
+_WALK_LENS = {
+    # one group holds a 1-token row and a whole-table row
+    "ragged-1-to-table": [1, 64, 9, 33, 8, 17, 40, 63],
+    # live rows among empty slots in one group, and a group of empty slots
+    "live-among-empty": [0, 17, 0, 64, 0, 0, 0, 0],
+}
+
+
+def _walk_inputs(variant, lens, seed=40):
+    """(q, cache, clean f32 cache of layer 1, bt, lens, q0) — ``variant`` is
+    s1 / mq (three trailing queries a row) / int8 (S = 1, int8 K/V)."""
+    from dynamo_tpu.ops.kv_quant import (
+        QuantKvCache, dequant_layer_slice, pad_scales,
+    )
+
+    w = _WALK
+    b, h, hk, d, bs, m = (w[k] for k in ("b", "h", "hk", "d", "bs", "m"))
+    s = 3 if variant == "mq" else 1
+    rng = np.random.default_rng(seed)
+    lens = np.asarray(lens, np.int32)
+    lens = np.where(lens > 0, np.maximum(lens, s), 0).astype(np.int32)
+    need = -(-lens // bs)
+    n = int(need.sum()) + 6                 # block 0 and five more: no row's
+    pool = rng.permutation(n - 1)[: need.sum()] + 1
+    bt = np.zeros((b, m), np.int32)         # an empty slot: a table of zeros
+    for r, at in enumerate(np.cumsum(need) - need):
+        bt[r, : need[r]] = pool[at: at + need[r]]
+    q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
+    if variant == "int8":
+        data = jnp.asarray(
+            rng.integers(-127, 127, size=(2, n, 2, bs, hk * d)), jnp.int8)
+        scale = pad_scales(jnp.asarray(
+            rng.random((2, n, 2, hk, bs)) * 0.05 + 0.01, jnp.float32))
+        cache = QuantKvCache(data, scale)
+        clean = dequant_layer_slice(data[1], scale[1], hk)
+    else:
+        cache = _mk_cache(rng, 2, n, bs, hk, d)
+        clean = cache[1]
+    return q, cache, clean, bt, lens, np.maximum(lens - s, 0)
+
+
+def _walk_oracle(q, clean, bt, lens, q0):
+    w = _WALK
+    n, s = clean.shape[0], q.shape[1]
+    kc = clean[:, 0].reshape(n, w["bs"], w["hk"], w["d"])
+    vc = clean[:, 1].reshape(n, w["bs"], w["hk"], w["d"])
+    positions = jnp.asarray(q0[:, None] + np.arange(s)[None, :], jnp.int32)
+    return np.asarray(paged_attention(
+        q, kc, vc, jnp.asarray(bt), jnp.asarray(lens), positions))
+
+
+def _walk_kernel(q, cache, bt, lens, q0):
+    from dynamo_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention_mq,
+    )
+
+    return np.asarray(paged_decode_attention_mq(
+        q, cache, jnp.int32(1), jnp.asarray(bt), jnp.asarray(lens),
+        jnp.asarray(q0), blocks_per_chunk=_WALK["c"],
+        seqs_per_group=_WALK["g"], interpret=True))
+
+
+def _poison_unowned(cache, bt, lens):
+    """Every pool block no row owns: NaN, +inf, -inf (an int8 cache cannot
+    hold them in its data: its scales do)."""
+    from dynamo_tpu.ops.kv_quant import QuantKvCache, is_quant
+
+    bs = _WALK["bs"]
+    owned = {int(x) for r, n in enumerate(lens)
+             for x in bt[r, : -(-int(n) // bs)]}
+    vals = [np.nan, np.inf, -np.inf]
+    arr = np.array(cache.scale if is_quant(cache) else cache)
+    dead = [blk for blk in range(arr.shape[1]) if blk not in owned]
+    assert 0 in dead and len(dead) >= 6
+    for blk in dead:
+        arr[:, blk] = vals[blk % 3]
+    arr = jnp.asarray(arr)
+    return QuantKvCache(cache.data, arr) if is_quant(cache) else arr
+
+
+@pytest.mark.parametrize("variant", ["s1", "mq", "int8"])
+@pytest.mark.parametrize("scenario", [
+    "ragged-1-to-table", "live-among-empty", "unowned-blocks-poisoned",
+    "rows-shuffled", "rows-grouped-by-length"])
+def test_decode_kernel_walks_each_rows_own_blocks(scenario, variant):
+    from dynamo_tpu.ops.paged_attention import rows_by_length
+
+    lens = _WALK_LENS.get(scenario, [5, 0, 64, 23, 0, 41, 8, 23])
+    q, cache, clean, bt, lens, q0 = _walk_inputs(variant, lens)
+    live = lens > 0
+    ref = _walk_oracle(q, clean, bt, lens, q0)
+    if scenario == "unowned-blocks-poisoned":
+        cache = _poison_unowned(cache, bt, lens)
+    out = _walk_kernel(q, cache, bt, lens, q0)
+    assert np.isfinite(out).all()
+    assert (out[~live] == 0).all()
+    np.testing.assert_allclose(out[live], ref[live], atol=3e-5)
+    if scenario in ("rows-shuffled", "rows-grouped-by-length"):
+        # a row's output is bit for bit the same whichever rows share its
+        # group: in another order of the slots, and in the order the model
+        # hands a decode step over (longest first, empty slots last)
+        if scenario == "rows-shuffled":
+            order = np.random.default_rng(7).permutation(len(lens))
+            inverse = np.argsort(order)
+        else:
+            order, inverse = (np.asarray(a) for a in
+                              rows_by_length(jnp.asarray(lens)))
+            assert (np.diff(lens[order]) <= 0).all()
+            assert order.tolist() == [2, 5, 3, 7, 6, 0, 1, 4]   # ties: by slot
+        moved = _walk_kernel(q[order], cache, bt[order], lens[order],
+                             q0[order])
+        assert (order[inverse] == np.arange(len(lens))).all()
+        np.testing.assert_array_equal(moved[inverse], out)
+
+
+@pytest.mark.parametrize("mix", ["equal", "ragged", "mostly-empty"])
+def test_decode_kernel_cost_counts_each_rows_own_blocks(mix):
+    """``decode_kernel_cost``'s bytes are the sum over the rows of
+    ceil(len / Bs) blocks, plus q in (f32) and the output; whatever the
+    grouping, nothing for an empty slot, no rounding up to a chunk."""
+    from dynamo_tpu.ops.pallas.registry import decode_kernel_cost
+
+    b, h, hk, d, bs, m, c = 16, 8, 2, 128, 32, 16, 4
+    lens = {"equal": [200] * b,
+            "ragged": [1, 512, 33, 64, 65, 127, 300, 7] * 2,
+            "mostly-empty": [0] * 13 + [257, 31, 512]}[mix]
+    block_bytes = 2 * bs * hk * d * 2
+    blocks = sum(-(-n // bs) for n in lens)
+    cost = decode_kernel_cost(b, 1, h, hk, d, bs, m, lens, cache_bytes=2,
+                              q_bytes=2, blocks_per_chunk=c)
+    assert cost["hbm_bytes"] == blocks * block_bytes + b * h * hk * d * (4 + 2)
+    # the matmuls take whole chunks of C blocks, a row's own
+    chunks = sum(-(-n // (c * bs)) for n in lens)
+    assert cost["flops"] == chunks * 4 * h * (c * bs) * hk * d
+    # the int8 cache: a block's scale tile rides with it
+    from dynamo_tpu.ops.kv_quant import scale_tile
+
+    hp, sp = scale_tile(hk, bs)
+    quant = decode_kernel_cost(b, 1, h, hk, d, bs, m, lens, cache_bytes=1,
+                               quant=True, q_bytes=2, blocks_per_chunk=c)
+    assert quant["hbm_bytes"] == (
+        blocks * (block_bytes // 2 + 2 * hp * sp * 4) + b * h * hk * d * 6)
+
+
 from kernel_oracles import assert_canary_clean, interpret_cases  # noqa: E402
 
 

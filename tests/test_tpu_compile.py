@@ -464,21 +464,33 @@ def _layer_slices(params) -> dict[tuple, set[str]]:
     return out
 
 
+_SCAN_HLO: dict[str, tuple] = {}
+
+
+def _scan_program(topo, case):
+    """(optimised HLO, params) of one of ``_SCAN_PROGRAMS`` compiled for the
+    described chip(s); compiled once a module."""
+    if case not in _SCAN_HLO:
+        config_file, program, chips, overrides = _SCAN_PROGRAMS[case]
+        if chips > 1:
+            mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                        ("data", "model"))
+            place = lambda spec: NamedSharding(mesh, spec)
+        else:
+            mesh = None
+            place = lambda spec: SingleDeviceSharding(topo.devices[0])
+        hf, cfg, model, params, cache, sds = _abstract_model(
+            config_file, place, N_BLOCKS, **overrides)
+        fn, args = _step_program(program, model, hf["serve"], sds, mesh)
+        hlo = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, *args).compile().as_text()
+        _SCAN_HLO[case] = (hlo, params)
+    return _SCAN_HLO[case]
+
+
 @pytest.mark.parametrize("case", sorted(_SCAN_PROGRAMS))
 def test_layer_scan_copies_no_projection_weight(topo, tpu_gate, case):
-    config_file, program, chips, overrides = _SCAN_PROGRAMS[case]
-    if chips > 1:
-        mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
-                    ("data", "model"))
-        place = lambda spec: NamedSharding(mesh, spec)
-    else:
-        mesh = None
-        place = lambda spec: SingleDeviceSharding(topo.devices[0])
-    hf, cfg, model, params, cache, sds = _abstract_model(
-        config_file, place, N_BLOCKS, **overrides)
-    fn, args = _step_program(program, model, hf["serve"], sds, mesh)
-    hlo = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, cache, *args).compile().as_text()
+    hlo, params = _scan_program(topo, case)
     assert "tpu_custom_call" in hlo and " while(" in hlo
 
     weights = _layer_slices(params)
@@ -495,6 +507,35 @@ def test_layer_scan_copies_no_projection_weight(topo, tpu_gate, case):
             relaid.append(f"{name} = {dtype}{list(dims)} {op}: a layer of "
                           + "/".join(sorted(names)))
     assert not relaid, relaid
+
+
+# ---------------------------------------------------------------------------
+# A decode step's rows go through the layers grouped by context length (PR 40:
+# the decode kernel loops to the longest row of each group of G).  The order
+# is two sorts of the batch's lengths, made once a step at the model's inputs:
+# XLA moves no sort out of a loop, so one made beside the kernel would run in
+# every layer (192 times a step in ouro-2.6b).
+_SORT = re.compile(r" sort\(")
+
+
+@pytest.mark.parametrize("case", [
+    "mistral-7b-decode", "ouro-2.6b-decode", "mistral-7b-tp4-decode"])
+def test_decode_rows_are_ordered_outside_the_layer_scan(topo, tpu_gate, case):
+    hlo, _ = _scan_program(topo, case)
+    sorts: dict[str, int] = {}
+    kernel_in, comp = set(), ""
+    for line in hlo.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            comp = head.group(1)
+        if "tpu_custom_call" in line:
+            kernel_in.add(comp)
+        if _SORT.search(line):
+            sorts[comp] = sorts.get(comp, 0) + 1
+    (body,) = kernel_in          # the layer scan's body holds the kernel
+    assert body not in sorts, sorts
+    # the order and its inverse (and the sampler's top-k) are in the program
+    assert sum(sorts.values()) >= 2, sorts
 
 
 # ---------------------------------------------------------------------------
