@@ -1182,7 +1182,8 @@ def _parser() -> argparse.ArgumentParser:
         "--trace / DYNAMO_TRACE=1)",
     )
     trace.add_argument("request_id",
-                       help="response id or the caller's x-request-id")
+                       help="response id or the caller's x-request-id; "
+                       "'engine' for the engine's steps")
     trace.add_argument("--url", default="http://127.0.0.1:8080",
                        help="frontend base URL")
     trace.add_argument("-o", "--out", default=None,

@@ -186,6 +186,8 @@ class AsyncLLMEngine(AsyncEngine):
             tracing.collector.bind_request(request.id, span.trace_id)
         self.core.submit(req)
         self._wake.set()
+        # for the front end's pre-submit histogram, as queue_wait_s below
+        request.annotations["submitted_at"] = req.submitted_at
 
         cancel_task = asyncio.ensure_future(request.stopped())
         get_task: asyncio.Future | None = None

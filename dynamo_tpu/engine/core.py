@@ -59,6 +59,7 @@ from dynamo_tpu.ops.block_copy import gather_blocks_padded, scatter_blocks_inpla
 from dynamo_tpu.llm.kv.block_manager import KvBlockManager, NoFreeBlocks
 from dynamo_tpu.llm.protocols import FinishReason, LLMEngineOutput
 from dynamo_tpu.models.llama import LlamaModel
+from dynamo_tpu.obs import tracing
 from dynamo_tpu.obs.perfmodel import perf_model
 from dynamo_tpu.utils.mesh import AXIS_DATA, AXIS_MODEL
 from dynamo_tpu.obs.timeline import step_timeline
@@ -318,6 +319,13 @@ class _Inflight:
     # requests that ended while this dispatch was already issued: their
     # slot and blocks go back once it has been read back
     ended: list = dataclasses.field(default_factory=list)
+    # rows / tokens / ctx for the profiler's dyn.readback event of this
+    # dispatch (EngineCore._carried); empty with no profiler session
+    carried: dict = dataclasses.field(default_factory=dict)
+
+
+# what a dispatch carried, when no profiler session is open to be told
+_NOT_PROFILED: dict = {}
 
 
 class EngineCore:
@@ -655,6 +663,18 @@ class EngineCore:
         self.decode_kv_blocks_group_bound = 0
         self._decode_tiling = self._flash_decode_tiling()
         self.first_token_s = 0.0         # sum of (first emit - submitted_at)
+        # ... and the two stages of it that follow the slot, summed over
+        # the same requests (EngineRequest's stamps; the third is the
+        # queue wait): in a slot with nothing issued for it yet, and from
+        # its first dispatch to its first token
+        self.turn_wait_s = 0.0
+        self.prefill_span_s = 0.0
+        # requests ready to prefill, summed at every prefill dispatch:
+        # over prefill_dispatches, how many stood ready when one was served
+        self.prefill_ready_rows = 0
+        # one clock read a finished dispatch, stamped on every output its
+        # host work emits (LLMEngineOutput.emitted_at)
+        self._emit_at = 0.0
         # cached _unified_penalties host buffers (invalidated on
         # admission/finish; incremental append between turns)
         self._pen_cache: Optional[dict] = None
@@ -1149,19 +1169,21 @@ class EngineCore:
 
     def _run_step(self, tokens, positions, block_tables, seq_lens, slot_idx,
                   last_idx, temp, top_k, top_p, prefix_blocks=None,
-                  k_cand=K_MAX, exact=False, gram=None, extras=None):
-        """Upload and issue one prefill dispatch (``unified_step``);
-        returns its outputs (sampled [B], logprob [B], cand_ids [B,C],
-        cand_lps [B,C]) **still on the device**.  Nothing is read back
-        here: the caller hands them to :meth:`_settle`, which finishes the
-        dispatch issued before this one first."""
+                  k_cand=K_MAX, exact=False, gram=None, extras=None,
+                  reqs=(), carried=None):
+        """Upload and issue one prefill dispatch (``unified_step``) for
+        ``reqs``; returns its outputs (sampled [B], logprob [B], cand_ids
+        [B,C], cand_lps [B,C]) **still on the device**.  Nothing is read
+        back here: the caller hands them to :meth:`_settle`, which finishes
+        the dispatch issued before this one first."""
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
-        step_timeline.enter("upload")
+        step_timeline.enter("upload", carried=carried)
         up, rng, gkw = self._upload_dispatch(
             (tokens, positions, block_tables, seq_lens, slot_idx, last_idx,
              temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="step")
+        self._note_issue(reqs)
         if perf_model.wants("step"):
             perf_model.offer(
                 "step", self._step_fn,
@@ -1179,7 +1201,7 @@ class EngineCore:
     def _run_multi_decode_step(self, tokens, positions, block_tables, seq_lens,
                                limits, temp, top_k, top_p, pen=None, gram=None,
                                extras=None, num_steps=1, k_cand=K_MAX,
-                               exact=False, *, carry_rows):
+                               exact=False, *, carry_rows, carried=None):
         """Upload and issue one multi-step decode; returns (sampled [K,B],
         logprob [K,B], cand_ids [K,B,C], cand_lps [K,B,C]) still on the
         device.  Rows marked in ``carry_rows`` start from the last sample
@@ -1193,7 +1215,7 @@ class EngineCore:
         gkw["carry_tokens"] = (
             self._carry_operand(self._inflight.out[0]) if carry_rows.any()
             else self._no_carry)
-        step_timeline.enter("upload")
+        step_timeline.enter("upload", carried=carried)
         up, rng, gkw = self._upload_dispatch(host, gkw)
         step_timeline.enter("dispatch", kind="decode_multi")
         up = list(up)
@@ -1211,6 +1233,34 @@ class EngineCore:
         )
         self.steps += 1
         return out
+
+    # ------------------------------------------- what a dispatch carried
+    def _carried(self, rows: int, tokens: int, seq_lens) -> dict:
+        """For the profiler's dyn.upload / dispatch / readback events of
+        the dispatch being built: its rows, its tokens (prompt tokens of a
+        prefill, rows of a decode) and the sum of its rows' context
+        lengths.  Built only while a profiler session is open."""
+        if not step_timeline.profiling():
+            return _NOT_PROFILED
+        return {"rows": rows, "tokens": tokens, "ctx": int(seq_lens.sum())}
+
+    def _note_issue(self, reqs) -> None:
+        """A prefill dispatch carrying ``reqs`` has just been issued (call
+        right after ``enter("dispatch")``): for those it is the first to
+        carry, the end of their turn wait."""
+        now = 0.0
+        for req in reqs:
+            req.prefill_chunks += 1
+            if not req.first_issue_at:
+                now = now or time.perf_counter()
+                req.first_issue_at = now
+                req.first_issue_step = step_timeline.busy_steps_total
+
+    def _host_post(self) -> None:
+        """A dispatch has been read back: its host work starts, and every
+        output that work emits carries this one clock read."""
+        step_timeline.enter("host_post")
+        self._emit_at = time.perf_counter()
 
     # ------------------------------------------------------- dispatch-ahead
     def _carry_operand(self, arr):
@@ -1291,13 +1341,14 @@ class EngineCore:
         while it was in flight gave up nothing yet: the program wrote one
         position past their stop into blocks they still owned (never
         committed); slot and blocks go back now that it has run."""
-        step_timeline.enter("readback", kind=rec.kind, issued=False)
+        step_timeline.enter("readback", kind=rec.kind, issued=False,
+                            carried=rec.carried)
         # ONE batched transfer: per-array np.asarray would issue a
         # device->host round trip per output (per-array latency is the
         # cost that matters on a remote-attached chip)
         out = tuple(jax.device_get(rec.out))
         self.device_gets += 1
-        step_timeline.enter("host_post")
+        self._host_post()
         rec.finish(out)
         for req in rec.ended:
             self._release_slot(req)
@@ -1415,6 +1466,11 @@ class EngineCore:
             "cache_layers": self.cache_layers,
             "kv_bytes_per_token": self.kv_bytes_per_token,
             "first_token_seconds_total": self.first_token_s,
+            # the stages of it behind the slot (over first_tokens_total),
+            # and the prefill backlog (over prefill_dispatches_total)
+            "turn_wait_seconds_total": self.turn_wait_s,
+            "prefill_span_seconds_total": self.prefill_span_s,
+            "prefill_ready_rows_total": self.prefill_ready_rows,
             # dispatch-ahead: ahead / decode_dispatches_total = how
             # often a decode hid its round trip; discards = late stops;
             # drains = turns that read back before they could issue
@@ -1466,22 +1522,7 @@ class EngineCore:
         try:
             return self._step_inner()
         finally:
-            step_timeline.end(trace=self._active_trace())
-
-    def _active_trace(self):
-        """(trace_id, span_id) of any traced request currently in a
-        slot — parents the per-step ``engine.step`` span (and its
-        dtperf counter track) under a live request trace.  None when
-        tracing is off or no slotted request carries a trace."""
-        from dynamo_tpu.obs import tracing
-
-        if not tracing.enabled():
-            return None
-        for req in self.slots:
-            trace = getattr(req, "trace", None)
-            if trace:
-                return trace
-        return None
+            step_timeline.end()
 
     def _step_inner(self) -> bool:
         # Opened here, not in step(): a span that is open when this frame
@@ -1594,10 +1635,12 @@ class EngineCore:
         only make sense with no prefill sharing the axis)."""
         if ready and self._sp_eligible(ready[0]):
             # seq-parallel long prompts keep their dedicated dispatch
+            self._count_ready(ready)
             self._run_sp_prefill(ready[0])
             return True
         ready = [r for r in ready if not self._sp_eligible(r)]
         if ready and decoding and self._run_unified(ready):
+            self._count_ready(ready)
             return True
         if ready:
             self._dispatch_prefill(ready)
@@ -1740,7 +1783,8 @@ class EngineCore:
             req.admit_seq = self._admit_seq
             self._admit_seq += 1
             if req.submitted_at:
-                req.queue_wait_s = time.perf_counter() - req.submitted_at
+                req.admitted_at = time.perf_counter()
+                req.queue_wait_s = req.admitted_at - req.submitted_at
             req.state = (
                 RequestState.REMOTE_PREFILL if req.remote_prefill else RequestState.PREFILL
             )
@@ -1764,6 +1808,7 @@ class EngineCore:
         packs every non-SP ready request — or, with batching disabled
         (prefill_token_budget=0) or a model without the ragged attention
         path, the legacy one-request dispatch."""
+        self._count_ready(ready)
         head = ready[0]
         if self._sp_eligible(head):
             self._run_sp_prefill(head)
@@ -1776,6 +1821,13 @@ class EngineCore:
             )
         else:
             self._run_prefill(head)
+
+    def _count_ready(self, ready: list[EngineRequest]) -> None:
+        """A prefill dispatch goes out with ``ready`` standing ready for
+        one: over the dispatches, the backlog a served request stood in
+        (1.0 = nobody ever waited behind another's chunk)."""
+        self.prefill_ready_rows += len(ready)
+        prefill_counters.record_ready(len(ready))
 
     # ---------------------------------------------------------------- prefill
     def _reserve_own(self, req: EngineRequest) -> None:
@@ -1856,6 +1908,7 @@ class EngineCore:
             gram = (keys, np.asarray([True]),
                     np.asarray([gs + off if gs > 0 else gs], np.int32),
                     np.asarray([gd], np.int32), np.asarray([gk], np.int32))
+        carried = self._carried(1, take, seq_lens)
         out = self._run_step(
             tokens, positions, bt, seq_lens, slot_idx, last_idx,
             np.asarray([req.sampling.temperature], np.float32),
@@ -1863,6 +1916,7 @@ class EngineCore:
             np.asarray([req.sampling.top_p], np.float32),
             prefix_blocks=pb, k_cand=k_cand, exact=exact, gram=gram,
             extras=self._sampling_extras([req]) if final else None,
+            reqs=(req,), carried=carried,
         )
         self.prefill_steps += 1
         self.prefill_dispatches += 1
@@ -1879,7 +1933,8 @@ class EngineCore:
             if final:  # else more chunks to go; the sample is discarded
                 self._complete_prefill(req, *out)
 
-        self._settle(_Inflight("step", out, finish, {req.slot: req}))
+        self._settle(_Inflight("step", out, finish, {req.slot: req},
+                               carried=carried))
 
     def _commit_prefill_blocks(self, req: EngineRequest) -> None:
         """Offer newly completed prompt blocks to the block manager.  The
@@ -2004,11 +2059,14 @@ class EngineCore:
 
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
-        step_timeline.enter("upload")
+        take_sum = sum(take for _, take, _ in sel)
+        carried = self._carried(r_real, take_sum, seq_lens)
+        step_timeline.enter("upload", carried=carried)
         up, rng, gkw = self._upload_dispatch(
             (tokens, positions, bt, seq_lens, slot_idx, seq_ids, starts,
              roff, last_idx, temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="prefill_ragged")
+        self._note_issue(req for req, _, _ in sel)
         if perf_model.wants("prefill_ragged"):
             perf_model.offer(
                 "prefill_ragged", self._ragged_fn,
@@ -2021,7 +2079,6 @@ class EngineCore:
         )
         self.steps += 1
         self.prefill_steps += 1
-        take_sum = sum(take for _, take, _ in sel)
         self.prefill_dispatches += 1
         self.prefill_rows_dispatched += r_real
         self.prefill_budget_offered += budget
@@ -2041,7 +2098,7 @@ class EngineCore:
 
         self._settle(_Inflight(
             "prefill_ragged", out, finish,
-            {req.slot: req for req, _, _ in sel}))
+            {req.slot: req for req, _, _ in sel}, carried=carried))
 
     def _complete_prefill(self, req, sampled, lps, cids, clps) -> None:
         """Shared tail of chunked and sequence-parallel prefill: state
@@ -2231,11 +2288,14 @@ class EngineCore:
         step_timeline.enter("host_build")
         gkw = self._gram_kwargs(gram)
         gkw.update(extras)
-        step_timeline.enter("upload")
+        take_sum = sum(take for _, take, _ in sel)
+        step_timeline.enter("upload", carried=self._carried(
+            r_real, n_dec + take_sum, seq_lens))
         up, rng, gkw = self._upload_dispatch(
             (tokens, positions, bt, seq_lens, slot_idx, seq_ids, starts,
              roff, last_idx, temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="unified")
+        self._note_issue(req for req, _, _ in sel)
         if perf_model.wants("unified"):
             perf_model.offer(
                 "unified", self._unified_fn,
@@ -2250,11 +2310,10 @@ class EngineCore:
         step_timeline.enter("readback")
         sampled, lps, cids, clps = jax.device_get(out)  # one batched pull
         self.device_gets += 1
-        step_timeline.enter("host_post")
+        self._host_post()
         self.steps += 1
         self.prefill_steps += 1
         self.decode_steps += 1
-        take_sum = sum(take for _, take, _ in sel)
         self.prompt_tokens_computed += take_sum
         self.prefill_dispatches += 1
         self.prefill_rows_dispatched += len(sel)
@@ -2401,7 +2460,8 @@ class EngineCore:
         positions = np.arange(s_pad, dtype=np.int32)[None, :]
         last_idx = np.asarray([req.prompt_len - 1], np.int32)
         k_cand, exact = self._sampling_mode([req])
-        step_timeline.enter("upload")
+        step_timeline.enter("upload", carried=self._carried(
+            1, req.prompt_len, last_idx + 1))
         up, rng, _ = self._upload_dispatch((
             tokens, positions, last_idx,
             np.asarray([req.sampling.temperature], np.float32),
@@ -2409,6 +2469,7 @@ class EngineCore:
             np.asarray([req.sampling.top_p], np.float32),
         ))
         step_timeline.enter("dispatch", kind="sp_prefill")
+        self._note_issue((req,))
         if perf_model.wants("sp_prefill"):
             perf_model.offer(
                 "sp_prefill", self._sp_fn,
@@ -2423,7 +2484,7 @@ class EngineCore:
         sampled, lps, cids, clps = jax.device_get(
             (sampled, lps, cids, clps))  # one batched transfer
         self.device_gets += 1
-        step_timeline.enter("host_post")
+        self._host_post()
         nb = -(-req.prompt_len // bs)
         self.cache = scatter_blocks_inplace(
             self.cache, req.block_ids[:nb],
@@ -2588,7 +2649,8 @@ class EngineCore:
         self._drain_offload()
         step_timeline.enter("host_build")
         k_cand, exact = self._sampling_mode(rows)
-        step_timeline.enter("upload")
+        step_timeline.enter("upload", carried=self._carried(
+            len(rows), len(rows) * s, seq_lens))
         up, rng, _ = self._upload_dispatch(
             (tokens, positions, bt[:, :m_used], seq_lens, slot_idx,
              temp, top_k, top_p, min_p, seeds, seed_rows))
@@ -2606,7 +2668,7 @@ class EngineCore:
         step_timeline.enter("readback")
         verified = jax.device_get(verified)
         self.device_gets += 1
-        step_timeline.enter("host_post")
+        self._host_post()
         self.steps += 1
         self.decode_steps += 1
         self.spec_steps += 1
@@ -2747,6 +2809,7 @@ class EngineCore:
         self._drain_offload()
         step_timeline.enter("host_build")
         k_cand, exact = self._sampling_mode(active)
+        carried = self._carried(len(active), len(active), seq_lens)
         pen = self._penalty_buffers(active, k_steps)
         gram = None
         if any(self._grammar_key(r) for r in active) \
@@ -2771,7 +2834,7 @@ class EngineCore:
             pen=pen, gram=gram,
             extras=self._sampling_extras(active, rows=[r.slot for r in active]),
             num_steps=k_steps, k_cand=k_cand, exact=exact,
-            carry_rows=carry_rows,
+            carry_rows=carry_rows, carried=carried,
         )  # [K, B], [K, B], [K, B, C], [K, B, C]
         self.decode_dispatches += 1
         self.decode_rows_dispatched += len(active)
@@ -2815,7 +2878,7 @@ class EngineCore:
 
         self._settle(_Inflight(
             "decode_multi", out, finish, {r.slot: r for r in active},
-            deferrable=plain))
+            deferrable=plain, carried=carried))
 
     def _penalty_buffers(self, active, k_steps: int):
         """Build the generated-token penalty buffers for this dispatch, or
@@ -2901,6 +2964,7 @@ class EngineCore:
         out = LLMEngineOutput(
             token_ids=[token], finish_reason=finish, cached_tokens=req.cached_tokens
         )
+        out.emitted_at = self._emit_at
         if logprob is not None and (req.sampling.logprobs or req.sampling.top_logprobs):
             out.logprobs = [logprob]
             n = req.sampling.top_logprobs
@@ -2911,12 +2975,46 @@ class EngineCore:
                 ]
         req.emit(out)
         if req.generated == 1 and req.submitted_at:
-            ttft = time.perf_counter() - req.submitted_at
-            self.first_tokens += 1
-            self.first_token_s += ttft
-            request_counters.record_first_token(ttft)
+            self._first_token(req, time.perf_counter())
         if finish is not None:
             self._finish_slot(req, finish, emitted=True)
+
+    def _first_token(self, req: EngineRequest, now: float) -> None:
+        """``req`` emitted its first token at ``now``: close its stages.
+        submit -> slot (``queue_wait_s``), slot -> first dispatch that
+        carried it (turn wait: behind other requests' chunks, the
+        alternation's decode turn, a joined prefix still being written),
+        first dispatch -> first token (its own chunks, the decode turns
+        between them, the program queued behind the one in flight,
+        readback, host_post): the three add up to its engine TTFT.  A
+        request no dispatch of this engine carried before its first token
+        (remote prefill: K/V and token come from a prefill worker) waited
+        all of it."""
+        req.first_token_at = now
+        req.first_token_step = step_timeline.busy_steps_total
+        if not req.first_issue_at:
+            req.first_issue_at = now
+            req.first_issue_step = req.first_token_step
+        ttft = now - req.submitted_at
+        turn_wait = req.first_issue_at - req.admitted_at
+        span = now - req.first_issue_at
+        self.first_tokens += 1
+        self.first_token_s += ttft
+        self.turn_wait_s += turn_wait
+        self.prefill_span_s += span
+        request_counters.record_first_token(ttft, turn_wait, span)
+        if tracing.enabled() and req.trace:
+            tracing.record_span("engine.queue", req.trace,
+                                req.submitted_at, req.admitted_at)
+            tracing.record_span("engine.turn_wait", req.trace,
+                                req.admitted_at, req.first_issue_at)
+            tracing.record_span(
+                "engine.prefill", req.trace, req.first_issue_at, now,
+                {"chunks": req.prefill_chunks,
+                 "prompt_tokens": req.prompt_len,
+                 "cached_tokens": req.cached_tokens,
+                 "first_step": req.first_issue_step,
+                 "last_step": req.first_token_step})
 
     def _cut_short(self, req: EngineRequest) -> None:
         """End a running request because its block space ran out: the
@@ -2955,6 +3053,15 @@ class EngineCore:
         req.finish_reason = reason
         self.requests_finished += 1
         request_counters.record_finish()
+        if req.first_token_at and tracing.enabled() and req.trace:
+            # ends at the stamp its last output carries (the consumer may
+            # have closed engine.generate by now), or now if none goes out
+            tracing.record_span(
+                "engine.decode", req.trace, req.first_token_at,
+                self._emit_at if emitted else time.perf_counter(),
+                {"tokens": req.generated,
+                 "first_step": req.first_token_step,
+                 "last_step": step_timeline.busy_steps_total})
         if not emitted:
             req.emit(LLMEngineOutput(token_ids=[], finish_reason=reason,
                                      cached_tokens=req.cached_tokens))
@@ -3354,6 +3461,7 @@ class EngineCore:
             self.block_manager.commit(
                 bid, blk.sequence_hash, blk.parent_sequence_hash, list(blk.tokens)
             )
+        self._emit_at = time.perf_counter()
         self._append_token(req, int(first_token), first=True)
 
     def prefix_hit_tokens(self, seq_hashes: list[int], prompt_len: int) -> int:

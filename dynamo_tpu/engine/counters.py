@@ -8,6 +8,12 @@ benchmarks read — no import cycles.  The HTTP metrics endpoint exposes:
     dynamo_tpu_engine_prefill_tokens_total         counter
     dynamo_tpu_engine_prefill_batch_occupancy      gauge (rows/dispatch)
     dynamo_tpu_engine_prefill_budget_utilization   gauge (used/offered)
+    dynamo_tpu_engine_prefill_ready_rows_total     counter (requests ready
+                                                   to prefill, summed at
+                                                   every prefill dispatch:
+                                                   over the dispatches, the
+                                                   backlog a served request
+                                                   stood in)
     dynamo_tpu_engine_unified_dispatches_total     counter
     dynamo_tpu_engine_unified_decode_rows_total    counter
     dynamo_tpu_engine_unified_prefill_tokens_total counter
@@ -43,6 +49,11 @@ class PrefillCounters:
         if budget > 0:
             self.budget_offered_total += budget
             self.budget_used_total += tokens
+
+    def record_ready(self, rows: int) -> None:
+        """A prefill dispatch went out with ``rows`` requests standing
+        ready for one (itself included)."""
+        self.ready_rows_total += rows
 
     def record_unified(self, decode_rows: int, prefill_tokens: int,
                        budget: int) -> None:
@@ -85,6 +96,7 @@ class PrefillCounters:
         self.tokens_total = 0
         self.budget_offered_total = 0
         self.budget_used_total = 0
+        self.ready_rows_total = 0
         self.unified_dispatches_total = 0
         self.unified_decode_rows_total = 0
         self.unified_prefill_tokens_total = 0
@@ -283,6 +295,19 @@ class RequestCounters:
         dynamo_tpu_engine_first_token_seconds_total    counter (sum of first
                                                        emit - submit: TTFT
                                                        as the engine sees it)
+        dynamo_tpu_engine_turn_wait_seconds_total      counter (of that, the
+                                                       sum of first dispatch
+                                                       that carried the
+                                                       request - slot: in a
+                                                       slot, nothing issued
+                                                       for it yet)
+        dynamo_tpu_engine_prefill_span_seconds_total   counter (and of first
+                                                       emit - that dispatch:
+                                                       its chunks, the turns
+                                                       between them, the
+                                                       readback; with the
+                                                       queue wait the three
+                                                       add up to the TTFT)
         dynamo_tpu_engine_ahead_dispatches_total       counter (decode
                                                        dispatches issued
                                                        with a dispatch in
@@ -370,9 +395,12 @@ class RequestCounters:
     def record_cut_short(self) -> None:
         self.requests_cut_short_total += 1
 
-    def record_first_token(self, seconds: float) -> None:
+    def record_first_token(self, seconds: float, turn_wait: float,
+                           prefill_span: float) -> None:
         self.first_tokens_total += 1
         self.first_token_seconds_total += seconds
+        self.turn_wait_seconds_total += turn_wait
+        self.prefill_span_seconds_total += prefill_span
 
     def record_ahead(self) -> None:
         self.ahead_dispatches_total += 1
@@ -410,6 +438,8 @@ class RequestCounters:
         self.requests_cut_short_total = 0
         self.first_tokens_total = 0
         self.first_token_seconds_total = 0.0
+        self.turn_wait_seconds_total = 0.0
+        self.prefill_span_seconds_total = 0.0
         self.ahead_dispatches_total = 0
         self.ahead_discards_total = 0
         self.pipeline_drains_total = 0

@@ -80,6 +80,20 @@ class EngineRequest:
     # and the async engine surfaces it to the HTTP histogram
     submitted_at: float = 0.0
     queue_wait_s: Optional[float] = None
+    # the boundaries a request crosses on its way to a first token, on
+    # submitted_at's clock (perf_counter: on Linux the clock of
+    # time.monotonic_ns, which dtspan and the profiler's t_mono_ns use).
+    # queue_wait_s + (first_issue_at - admitted_at) + (first_token_at -
+    # first_issue_at) is its engine TTFT; 0.0 = not crossed yet
+    admitted_at: float = 0.0       # took a slot (_admit)
+    first_issue_at: float = 0.0    # first dispatch that carried it went out
+    first_token_at: float = 0.0    # first token emitted
+    # step_timeline.busy_steps_total at those two moments: the busy steps
+    # [first_issue_step, first_token_step] prefilled it (the ``step`` of
+    # the profiler's dyn.<phase> events)
+    first_issue_step: int = -1
+    first_token_step: int = -1
+    prefill_chunks: int = 0        # prefill dispatches that carried it
 
     @property
     def prompt_len(self) -> int:

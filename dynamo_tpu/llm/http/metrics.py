@@ -84,6 +84,14 @@ class Metrics:
         # submit -> slot admission wait inside the engine (from
         # EngineRequest.queue_wait_s via Context annotations)
         self.queue_wait: dict[str, Histogram] = defaultdict(Histogram)
+        # the front end's two ends (docs/observability.md, "A request's
+        # stages"): handler entry -> engine submit (parse, template,
+        # tokenise, admission control, the hop), and an output's emit on
+        # the engine thread -> its chunk written to the socket: time work
+        # waited for the event loop, measured where it waits
+        self.pre_submit: dict[str, Histogram] = defaultdict(Histogram)
+        self.emit_lag: dict[str, Histogram] = defaultdict(
+            lambda: Histogram(_ITL_BUCKETS))
         # duration keyed by (model, status): near-zero error/disconnect
         # requests must not pull the success series' percentiles down
         self.duration: dict[tuple[str, str], Histogram] = defaultdict(Histogram)
@@ -125,6 +133,14 @@ class Metrics:
         for model, h in sorted(self.queue_wait.items()):
             lines.extend(h.render(HM.QUEUE_WAIT_SECONDS,
                                   f'model="{model}"'))
+        lines.append(f"# TYPE {HM.PRE_SUBMIT_SECONDS} histogram")
+        for model, h in sorted(self.pre_submit.items()):
+            lines.extend(h.render(HM.PRE_SUBMIT_SECONDS,
+                                  f'model="{model}"'))
+        lines.append(f"# TYPE {HM.EMIT_LAG_SECONDS} histogram")
+        for model, h in sorted(self.emit_lag.items()):
+            lines.extend(h.render(HM.EMIT_LAG_SECONDS,
+                                  f'model="{model}"'))
         lines.append(f"# TYPE {HM.REQUEST_SECONDS} histogram")
         for (model, status), h in sorted(self.duration.items()):
             lines.extend(h.render(
@@ -155,6 +171,10 @@ class Metrics:
         lines.append(f"# TYPE {EM.PREFILL_BUDGET_UTILIZATION} gauge")
         lines.append(f"{EM.PREFILL_BUDGET_UTILIZATION} "
                      f"{round(prefill_counters.budget_utilization, 6)}")
+        # requests ready to prefill, summed at every prefill dispatch
+        lines.append(f"# TYPE {EM.PREFILL_READY_ROWS_TOTAL} counter")
+        lines.append(f"{EM.PREFILL_READY_ROWS_TOTAL} "
+                     f"{prefill_counters.ready_rows_total}")
         # unified mixed prefill+decode dispatch: how many turns collapsed
         # the two-dispatch interleave into one, and what shared the axis
         lines.append(f"# TYPE {EM.UNIFIED_DISPATCHES_TOTAL} counter")
@@ -254,13 +274,19 @@ class Metrics:
                 f'{EM.STEP_PHASE_SECONDS_TOTAL}{{phase="{p}"}} '
                 f"{round(tl['phases'][p], 6)}")
         # busy steps by what they dispatched: prompt processing, token
-        # generation, or both in one step — wall, and its device-facing
-        # part (dispatch -> readback returned)
+        # generation, or both in one step — wall, its device-facing
+        # part (dispatch -> readback returned), and the two halves a
+        # turn's pace is read from: launch (upload + dispatch) and
+        # readback (the host blocked on the device: its slack)
         for name, key in (
                 (EM.STEP_CLASS_STEPS_TOTAL, "steps_total"),
                 (EM.STEP_CLASS_WALL_SECONDS_TOTAL, "wall_seconds_total"),
                 (EM.STEP_CLASS_DEVICE_SECONDS_TOTAL,
-                 "device_seconds_total")):
+                 "device_seconds_total"),
+                (EM.STEP_CLASS_LAUNCH_SECONDS_TOTAL,
+                 "launch_seconds_total"),
+                (EM.STEP_CLASS_READBACK_SECONDS_TOTAL,
+                 "readback_seconds_total")):
             lines.append(f"# TYPE {name} counter")
             for c in CLASSES:
                 lines.append(f'{name}{{class="{c}"}} '
@@ -284,6 +310,14 @@ class Metrics:
         lines.append(f"# TYPE {EM.FIRST_TOKEN_SECONDS_TOTAL} counter")
         lines.append(f"{EM.FIRST_TOKEN_SECONDS_TOTAL} "
                      f"{round(rc.first_token_seconds_total, 6)}")
+        # ... and its two stages behind the slot (the queue wait is the
+        # third): nothing issued for the request yet, then its prefill
+        lines.append(f"# TYPE {EM.TURN_WAIT_SECONDS_TOTAL} counter")
+        lines.append(f"{EM.TURN_WAIT_SECONDS_TOTAL} "
+                     f"{round(rc.turn_wait_seconds_total, 6)}")
+        lines.append(f"# TYPE {EM.PREFILL_SPAN_SECONDS_TOTAL} counter")
+        lines.append(f"{EM.PREFILL_SPAN_SECONDS_TOTAL} "
+                     f"{round(rc.prefill_span_seconds_total, 6)}")
         # dispatch-ahead: how often the host's round trip ran under the
         # device program, what late stops wasted, what broke the chain
         lines.append(f"# TYPE {EM.AHEAD_DISPATCHES_TOTAL} counter")

@@ -7,7 +7,7 @@ service_v2.rs):
   POST /v1/completions        — streaming (SSE) and unary
   GET  /v1/models
   GET  /metrics               — Prometheus text format
-  GET  /debug/traces/{id}     — dtspan Chrome trace of one request
+  GET  /debug/traces/{id}     — dtspan Chrome trace of one request (`engine`: the steps)
   POST /debug/profile         — one jax.profiler capture of ?seconds=N
   GET  /health, /live, /ready
 
@@ -162,7 +162,8 @@ class HttpService:
 
     async def _debug_trace(self, request: web.Request) -> web.Response:
         """Chrome trace-event JSON for one request id (the response id,
-        or the caller's ``x-request-id`` when it sent one).  Load the
+        or the caller's ``x-request-id`` when it sent one), or for
+        ``engine``: the engine's steps (``tracing.ENGINE_TRACE``).  Load the
         body in chrome://tracing or ui.perfetto.dev."""
         rid = request.match_info["request_id"]
         doc = trace_for_request(rid)
@@ -218,6 +219,8 @@ class HttpService:
 
     async def _serve(self, request: web.Request, chat: bool) -> web.StreamResponse:
         endpoint = "chat_completions" if chat else "completions"
+        # the engine's clock (EngineCore.submit stamps with it too)
+        entered_at = time.perf_counter()
         try:
             body = await request.json()
         except json.JSONDecodeError:
@@ -314,11 +317,11 @@ class HttpService:
             if parsed.stream:
                 resp = await self._stream_response(
                     request, ctxs, streams, rid, parsed, chat, guard,
-                    xrid=xrid, affinity=affinity)
+                    xrid=xrid, affinity=affinity, entered_at=entered_at)
             else:
                 resp = await self._unary_response(
                     ctxs, streams, rid, parsed, chat, guard, xrid=xrid,
-                    affinity=affinity)
+                    affinity=affinity, entered_at=entered_at)
             if self.affinity is not None and session:
                 # our persist tier is warm for this session now — record
                 # it so peers resolve future turns here on affinity miss
@@ -370,7 +373,7 @@ class HttpService:
         self, request: web.Request, ctxs: list[Context],
         streams: list[AsyncIterator[LLMEngineOutput]],
         rid: str, parsed, chat: bool, guard, xrid: str = "",
-        affinity=None,
+        affinity=None, entered_at: float = 0.0,
     ) -> web.StreamResponse:
         headers = {
             "Content-Type": "text/event-stream",
@@ -446,6 +449,7 @@ class HttpService:
                 for chunk in self._chunk(rid, parsed, chat, out, i,
                                          text_off[i], finish_override):
                     await resp.write(sse_encode(chunk))
+                self._observe_emit_lag(parsed.model, out)
                 text_off[i] += len(out.text or "")
             usage = usage_dict(ctxs[0].annotations.get("prompt_tokens", 0), n_out)
             if chat:
@@ -459,7 +463,7 @@ class HttpService:
             await resp.write(SSE_DONE)
             guard.ok()
             self.metrics.tokens_out[parsed.model] += n_out
-            self._observe_queue_wait(parsed.model, ctxs)
+            self._observe_queue_wait(parsed.model, ctxs, entered_at)
         except (ConnectionResetError, asyncio.CancelledError):
             # client went away — stop the engine (ref: disconnect detection)
             for ctx in ctxs:
@@ -472,16 +476,31 @@ class HttpService:
         await resp.write_eof()
         return resp
 
-    def _observe_queue_wait(self, model: str, ctxs: list[Context]) -> None:
+    def _observe_emit_lag(self, model: str, out: LLMEngineOutput) -> None:
+        """Emit on the engine thread -> here, on the event loop (the
+        streaming writer calls it after the write of ``out``'s chunk).  An
+        output of another process carries no stamp."""
+        if out.emitted_at:
+            self.metrics.emit_lag[model].observe(
+                time.perf_counter() - out.emitted_at)
+
+    def _observe_queue_wait(self, model: str, ctxs: list[Context],
+                            entered_at: float) -> None:
+        """What the engine left on a finished request's context: submit ->
+        slot, and its submit stamp (handler entry -> submit: parse,
+        template, tokenise, admission control, the hop to the engine)."""
         for c in ctxs:
             qw = c.annotations.get("queue_wait_s")
             if qw is not None:
                 self.metrics.queue_wait[model].observe(qw)
+            sub = c.annotations.get("submitted_at")
+            if sub is not None:
+                self.metrics.pre_submit[model].observe(sub - entered_at)
 
     async def _unary_response(
         self, ctxs: list[Context], streams: list[AsyncIterator[LLMEngineOutput]],
         rid: str, parsed, chat: bool, guard, xrid: str = "",
-        affinity=None,
+        affinity=None, entered_at: float = 0.0,
     ) -> web.Response:
         n = len(streams)
         texts: list[list[str]] = [[] for _ in range(n)]
@@ -493,6 +512,10 @@ class HttpService:
             async for out in s:
                 if out.token_ids:
                     guard.tokens(len(out.token_ids))
+                if not counts[i]:
+                    # nothing goes on the wire before the end: once, at
+                    # the first output
+                    self._observe_emit_lag(parsed.model, out)
                 counts[i] += len(out.token_ids)
                 if out.text:
                     texts[i].append(out.text)
@@ -545,7 +568,7 @@ class HttpService:
                 resp["choices"].extend(piece["choices"])
         guard.ok()
         self.metrics.tokens_out[parsed.model] += n_out
-        self._observe_queue_wait(parsed.model, ctxs)
+        self._observe_queue_wait(parsed.model, ctxs, entered_at)
         migrated = max((c.annotations.get("migrations", 0) for c in ctxs),
                        default=0)
         headers = {}

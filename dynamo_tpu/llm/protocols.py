@@ -102,6 +102,12 @@ class LLMEngineOutput:
     # display-form logprobs (token strings + bytes), filled by the Backend:
     # [{token, logprob, bytes, top_logprobs: [{token, logprob, bytes}]}]
     logprob_content: Optional[list[dict]] = None
+    # when the engine thread emitted this output (time.perf_counter of the
+    # emitting process; 0.0 = not stamped), so that the writer of its
+    # chunk can say how long it waited for the event loop.  Deliberately
+    # no dataclass field: it means nothing in another process, and stays
+    # off the wire (runtime/serde.py encodes fields) and out of equality
+    emitted_at = 0.0
 
     def __post_init__(self):
         # tolerate wire-decoded plain strings (runtime/serde.py)

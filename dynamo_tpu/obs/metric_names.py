@@ -52,6 +52,10 @@ class HttpMetric:
     TTFT_SECONDS = "dynamo_tpu_http_service_ttft_seconds"
     INTER_TOKEN_SECONDS = "dynamo_tpu_http_service_inter_token_seconds"
     QUEUE_WAIT_SECONDS = "dynamo_tpu_http_service_queue_wait_seconds"
+    # the front end's two ends: handler entry -> engine submit, and an
+    # output's emit on the engine thread -> its chunk written to the socket
+    PRE_SUBMIT_SECONDS = "dynamo_tpu_http_service_pre_submit_seconds"
+    EMIT_LAG_SECONDS = "dynamo_tpu_http_service_emit_lag_seconds"
     REQUEST_SECONDS = "dynamo_tpu_http_service_request_seconds"
 
 
@@ -73,6 +77,7 @@ class EngineMetric:
     PREFILL_BATCH_OCCUPANCY = "dynamo_tpu_engine_prefill_batch_occupancy"
     PREFILL_BUDGET_UTILIZATION = (
         "dynamo_tpu_engine_prefill_budget_utilization")
+    PREFILL_READY_ROWS_TOTAL = "dynamo_tpu_engine_prefill_ready_rows_total"
     UNIFIED_DISPATCHES_TOTAL = "dynamo_tpu_engine_unified_dispatches_total"
     UNIFIED_DECODE_ROWS_TOTAL = "dynamo_tpu_engine_unified_decode_rows_total"
     UNIFIED_PREFILL_TOKENS_TOTAL = (
@@ -98,6 +103,10 @@ class EngineMetric:
         "dynamo_tpu_engine_step_class_wall_seconds_total")
     STEP_CLASS_DEVICE_SECONDS_TOTAL = (
         "dynamo_tpu_engine_step_class_device_seconds_total")
+    STEP_CLASS_LAUNCH_SECONDS_TOTAL = (
+        "dynamo_tpu_engine_step_class_launch_seconds_total")
+    STEP_CLASS_READBACK_SECONDS_TOTAL = (
+        "dynamo_tpu_engine_step_class_readback_seconds_total")
     # engine/counters.py RequestCounters
     DECODE_DISPATCHES_TOTAL = "dynamo_tpu_engine_decode_dispatches_total"
     DECODE_ROWS_DISPATCHED_TOTAL = (
@@ -107,6 +116,10 @@ class EngineMetric:
     FIRST_TOKENS_TOTAL = "dynamo_tpu_engine_first_tokens_total"
     FIRST_TOKEN_SECONDS_TOTAL = (
         "dynamo_tpu_engine_first_token_seconds_total")
+    # a first token's stages behind the slot (EngineCore._first_token)
+    TURN_WAIT_SECONDS_TOTAL = "dynamo_tpu_engine_turn_wait_seconds_total"
+    PREFILL_SPAN_SECONDS_TOTAL = (
+        "dynamo_tpu_engine_prefill_span_seconds_total")
     # dispatch-ahead (EngineCore._settle)
     AHEAD_DISPATCHES_TOTAL = "dynamo_tpu_engine_ahead_dispatches_total"
     AHEAD_DISCARDS_TOTAL = "dynamo_tpu_engine_ahead_discards_total"
@@ -203,6 +216,8 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     HttpMetric.TTFT_SECONDS: ("histogram", ("model",)),
     HttpMetric.INTER_TOKEN_SECONDS: ("histogram", ("model",)),
     HttpMetric.QUEUE_WAIT_SECONDS: ("histogram", ("model",)),
+    HttpMetric.PRE_SUBMIT_SECONDS: ("histogram", ("model",)),
+    HttpMetric.EMIT_LAG_SECONDS: ("histogram", ("model",)),
     HttpMetric.REQUEST_SECONDS: ("histogram", ("model", "status")),
     FaultMetric.MIGRATIONS_TOTAL: ("counter", ()),
     FaultMetric.DRAINS_IN_PROGRESS: ("gauge", ()),
@@ -211,6 +226,7 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.PREFILL_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.PREFILL_BATCH_OCCUPANCY: ("gauge", ()),
     EngineMetric.PREFILL_BUDGET_UTILIZATION: ("gauge", ()),
+    EngineMetric.PREFILL_READY_ROWS_TOTAL: ("counter", ()),
     EngineMetric.UNIFIED_DISPATCHES_TOTAL: ("counter", ()),
     EngineMetric.UNIFIED_DECODE_ROWS_TOTAL: ("counter", ()),
     EngineMetric.UNIFIED_PREFILL_TOKENS_TOTAL: ("counter", ()),
@@ -230,12 +246,16 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.STEP_CLASS_STEPS_TOTAL: ("counter", ("class",)),
     EngineMetric.STEP_CLASS_WALL_SECONDS_TOTAL: ("counter", ("class",)),
     EngineMetric.STEP_CLASS_DEVICE_SECONDS_TOTAL: ("counter", ("class",)),
+    EngineMetric.STEP_CLASS_LAUNCH_SECONDS_TOTAL: ("counter", ("class",)),
+    EngineMetric.STEP_CLASS_READBACK_SECONDS_TOTAL: ("counter", ("class",)),
     EngineMetric.DECODE_DISPATCHES_TOTAL: ("counter", ()),
     EngineMetric.DECODE_ROWS_DISPATCHED_TOTAL: ("counter", ()),
     EngineMetric.REQUESTS_FINISHED_TOTAL: ("counter", ()),
     EngineMetric.REQUESTS_CUT_SHORT_TOTAL: ("counter", ()),
     EngineMetric.FIRST_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.FIRST_TOKEN_SECONDS_TOTAL: ("counter", ()),
+    EngineMetric.TURN_WAIT_SECONDS_TOTAL: ("counter", ()),
+    EngineMetric.PREFILL_SPAN_SECONDS_TOTAL: ("counter", ()),
     EngineMetric.AHEAD_DISPATCHES_TOTAL: ("counter", ()),
     EngineMetric.AHEAD_DISCARDS_TOTAL: ("counter", ()),
     EngineMetric.PIPELINE_DRAINS_TOTAL: ("counter", ()),

@@ -40,8 +40,13 @@ phases stay leaves at the depth of their call site).  ``step`` is the
 busy-step index, ``kind`` the dispatch kind on dispatch/readback,
 and ``t_mono_ns`` is ``time.monotonic_ns()`` at the open: trace time −
 ``t_mono_ns`` is the offset that puts anything stamped with
-``time.monotonic`` on the device trace's axis.  With no session open a
-phase costs a flag test (``TraceAnnotation.is_enabled()``) and no object.
+``time.monotonic`` on the device trace's axis.  The ``upload``,
+``dispatch`` and ``readback`` events also say what the dispatch carried
+(``enter(..., carried=)``): ``rows``, ``tokens`` (prompt tokens of a
+prefill dispatch, rows of a decode) and ``ctx`` (the sum of its rows'
+context lengths) — the engine builds them only while :meth:`profiling`.
+With no session open a phase costs a flag test
+(``TraceAnnotation.is_enabled()``) and no object.
 
 The headline derived number is **host_gap_ms_per_turn** — wall time
 per dispatching step spent *outside* dispatch+readback: the host's own
@@ -68,11 +73,21 @@ device-facing part (wall − host gap), are added to a **class** —
 classes in one step, or no kind at all) — the class of the dispatch the
 step **issued**, or, when it issued none, of what it finished; so the
 class walls add up to ``wall_seconds_total`` and a class's steps count
-its dispatches.  When the dtspan plane is enabled, ``end`` also emits one
-``engine.step`` span per busy step carrying the phase breakdown and the
-roofline-predicted dispatch envelope, which the Chrome export renders as
-a predicted-vs-measured
-counter track.
+its dispatches.  The class also gets the step's ``upload`` + ``dispatch``
+(**launch**: what it costs the host to hand the device its next program)
+and its ``readback`` (the host standing blocked on the device: **the
+host's slack in that turn** — near zero, the host sets the pace and the
+device waits); wall − launch − readback is the host's own work in a turn
+of that class.  The readback of a turn is as a rule of the dispatch the
+turn *before* issued, so a prefill turn's readback waits for a decode.
+When the dtspan plane is enabled, ``end`` also emits one ``engine.step``
+span per busy step carrying the phase breakdown and the roofline-predicted
+dispatch envelope, which the Chrome export renders as a
+predicted-vs-measured counter track.  Those spans share one trace of the
+engine's own (``tracing.ENGINE_TRACE``, served at ``/debug/traces/engine``):
+a request's trace holds the request's stages
+(``engine.queue`` … ``engine.decode``, engine/core.py), whose
+``first_step`` / ``last_step`` name the steps that served it.
 """
 
 from __future__ import annotations
@@ -151,6 +166,9 @@ class StepTimeline:
         self.class_steps = {c: 0 for c in CLASSES}
         self.class_wall_s = {c: 0.0 for c in CLASSES}
         self.class_device_s = {c: 0.0 for c in CLASSES}
+        # upload + dispatch, and readback, of those steps
+        self.class_launch_s = {c: 0.0 for c in CLASSES}
+        self.class_readback_s = {c: 0.0 for c in CLASSES}
         self._alpha = 0.05
         self._t0: Optional[float] = None
         self._t0_ns = 0
@@ -158,6 +176,7 @@ class StepTimeline:
         self._phase = PHASES[0]
         self._kind: Optional[str] = None
         self._span = None
+        self._carried: dict = {}
         self._phases: dict = {}
         self._step_kinds: dict = {}
         self._issued: set = set()
@@ -174,19 +193,30 @@ class StepTimeline:
         self._step_kinds = {}
         self._issued = set()
         self._kind = None
+        self._carried = {}
         self._t0_ns = time.monotonic_ns()
         self._open(phase)
 
+    def profiling(self) -> bool:
+        """Is a ``jax.profiler`` session open?  What a dispatch carried
+        (``enter(carried=)``) is worth building only then."""
+        return self._annotation is not None and self._annotation.is_enabled()
+
     def enter(self, phase: str, kind: Optional[str] = None,
-              issued: bool = True) -> None:
+              issued: bool = True, carried: Optional[dict] = None) -> None:
         """Close the open phase and open ``phase``.  ``kind`` (on
         ``dispatch``) names the jitted entrypoint; the readback that
         follows is booked to it too.  ``issued=False`` (on
         the ``readback`` of a dispatch that may be an earlier step's)
-        books to ``kind`` and counts no dispatch."""
+        books to ``kind`` and counts no dispatch.  ``carried`` (``rows``,
+        ``tokens``, ``ctx``) is what the dispatch of this and the
+        following upload / dispatch / readback events carried, until the
+        next ``carried``; an empty dict says "not known"."""
         if self._t0 is None:
             return  # dispatch helper invoked outside step() (tests)
         self._close(self._clock())
+        if carried is not None:
+            self._carried = carried
         if kind is not None:
             self._kind = kind
             if issued:
@@ -199,9 +229,10 @@ class StepTimeline:
         self._phase = phase
         if self._annotation.is_enabled():
             kind = self._kind if phase in _DEVICE_FACING else None
+            carried = self._carried if phase in _DISPATCH_PHASES else {}
             span = self._annotation(
                 _SPAN_NAMES[phase], step=self.busy_steps_total,
-                kind=kind or "", t_mono_ns=time.monotonic_ns())
+                kind=kind or "", t_mono_ns=time.monotonic_ns(), **carried)
             span.__enter__()
             self._span = span
 
@@ -219,7 +250,7 @@ class StepTimeline:
             self._span.__exit__(None, None, None)
             self._span = None
 
-    def end(self, trace: Optional[tuple] = None) -> None:
+    def end(self) -> None:
         if self._t0 is None:
             return
         now = self._clock()
@@ -245,26 +276,33 @@ class StepTimeline:
         self.class_steps[cls] += 1
         self.class_wall_s[cls] += wall
         self.class_device_s[cls] += facing
+        self.class_launch_s[cls] += (phases.get("upload", 0.0)
+                                     + phases.get("dispatch", 0.0))
+        self.class_readback_s[cls] += phases.get("readback", 0.0)
         a = self._alpha
         self.ewma_wall_s = wall if self.busy_steps_total == 1 else (
             (1 - a) * self.ewma_wall_s + a * wall)
         self.ewma_host_gap_s = gap if self.busy_steps_total == 1 else (
             (1 - a) * self.ewma_host_gap_s + a * gap)
-        self._emit_step_span(trace, t0_ns, wall, phases)
+        self._emit_step_span(t0_ns, wall, phases)
 
     # ----------------------------------------------------------- trace emit
-    def _emit_step_span(self, trace: Optional[tuple], t0_ns: int,
-                        wall: float, phases: dict) -> None:
+    def _emit_step_span(self, t0_ns: int, wall: float,
+                        phases: dict) -> None:
         """One ``engine.step`` span per busy step when the tracing
-        plane is on: phase breakdown, per-kind dispatch ms, and the
-        roofline-predicted dispatch envelope (the Chrome export turns
-        the predicted/measured pair into a counter track)."""
+        plane is on: the step's index, phase breakdown, per-kind dispatch
+        ms, and the roofline-predicted dispatch envelope (the Chrome
+        export turns the predicted/measured pair into a counter track).
+        All under ``tracing.ENGINE_TRACE``, which ``/debug/traces/engine``
+        fetches: a step serves every request in a slot, so it belongs
+        to no request's trace."""
         from dynamo_tpu.obs import tracing
 
         if not tracing.enabled():
             return
         kinds = dict(self._step_kinds)
         attrs: dict = {
+            "step": self.busy_steps_total - 1,
             "phases_ms": {
                 p: round(v * 1e3, 3) for p, v in sorted(phases.items())
             },
@@ -282,13 +320,11 @@ class StepTimeline:
                 attrs["predicted_dispatch_ms"] = round(sum(preds), 3)
         except Exception:
             pass  # monitoring must never break the step loop
-        trace_id, parent = (trace if trace else
-                            (tracing.new_trace_id(), None))
         tracing.collector.add({
             "name": "engine.step",
-            "trace": trace_id,
+            "trace": tracing.ENGINE_TRACE,
             "span": tracing._new_span_id(),
-            "parent": parent,
+            "parent": None,
             "ts": t0_ns,
             "dur": int(wall * 1e9),
             "proc": tracing.process_name(),
@@ -319,6 +355,10 @@ class StepTimeline:
             **{f"{c}_wall_seconds_total": self.class_wall_s[c]
                for c in CLASSES},
             **{f"{c}_device_seconds_total": self.class_device_s[c]
+               for c in CLASSES},
+            **{f"{c}_launch_seconds_total": self.class_launch_s[c]
+               for c in CLASSES},
+            **{f"{c}_readback_seconds_total": self.class_readback_s[c]
                for c in CLASSES},
             "dispatch_kinds": {
                 k: {

@@ -39,6 +39,7 @@ from collections import OrderedDict, deque
 from typing import Optional
 
 __all__ = [
+    "ENGINE_TRACE",
     "Span",
     "attach",
     "collector",
@@ -49,6 +50,7 @@ __all__ = [
     "extract",
     "inject",
     "new_trace_id",
+    "record_span",
     "set_process",
     "start_span",
 ]
@@ -95,6 +97,13 @@ def new_trace_id() -> str:
     return uuid.uuid4().hex
 
 
+#: The trace of every ``engine.step`` span (obs/timeline.py), and the name
+#: that fetches it: ``/debug/traces/engine``, ``dynamo-tpu trace engine``.
+#: A step serves every request in a slot and belongs to no request's trace;
+#: a fixed name cannot age out of the request map.
+ENGINE_TRACE = "engine"
+
+
 def _new_span_id() -> str:
     return uuid.uuid4().hex[:16]
 
@@ -125,6 +134,8 @@ class Collector:
                 self._rid_to_trace.popitem(last=False)
 
     def trace_for_request(self, request_id: str) -> Optional[str]:
+        if request_id == ENGINE_TRACE:
+            return ENGINE_TRACE
         with self._lock:
             return self._rid_to_trace.get(request_id)
 
@@ -244,6 +255,29 @@ def start_span(name: str, parent: Optional[tuple] = None,
     else:
         trace_id, parent_id = new_trace_id(), None
     return Span(name, trace_id, parent_id, attrs)
+
+
+def record_span(name: str, parent: tuple, start_s: float, end_s: float,
+                attrs: Optional[dict] = None) -> None:
+    """Add a finished span made from two stamps that were taken anyway:
+    ``parent`` is the ``(trace_id, span_id)`` it hangs under, the stamps
+    are seconds of ``time.perf_counter`` (on Linux one clock with
+    ``time.monotonic_ns``, which every other span reads:
+    tests/test_request_stages.py pins it).  For threads with no ambient
+    context and intervals whose ends no one code path brackets (a
+    request's stages on the engine thread).  Call it under
+    :func:`enabled` only."""
+    start_ns = int(start_s * 1e9)
+    collector.add({
+        "name": name,
+        "trace": parent[0],
+        "span": _new_span_id(),
+        "parent": parent[1],
+        "ts": start_ns,
+        "dur": max(0, int(end_s * 1e9) - start_ns),
+        "proc": _proc,
+        "attrs": attrs or {},
+    })
 
 
 def current() -> Optional[tuple]:

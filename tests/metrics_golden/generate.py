@@ -104,6 +104,9 @@ def seed_http_metrics():
     for v in (0.004, 0.008, 0.02):
         m.itl["m1"].observe(v)
     m.queue_wait["m1"].observe(0.03)
+    m.pre_submit["m1"].observe(0.002)
+    for v in (0.0004, 0.003):
+        m.emit_lag["m1"].observe(v)
     m.duration[("m1", "success")].observe(1.2)
     m.duration[("m1", "error")].observe(0.01)
 
@@ -114,12 +117,14 @@ def seed_http_metrics():
     counters.record(4, 96, budget=128)
     counters.record(2, 64, budget=128)
     counters.record_unified(6, 90, 128)
+    counters.record_ready(3)
+    counters.record_ready(1)
     request_counters.record_decode(12)
     request_counters.record_decode(11)
     request_counters.record_finish()
     request_counters.record_finish()
     request_counters.record_cut_short()
-    request_counters.record_first_token(0.125)
+    request_counters.record_first_token(0.125, 0.0625, 0.03125)
     for _ in range(5):
         request_counters.record_ahead()
     request_counters.record_ahead_discard()

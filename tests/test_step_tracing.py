@@ -284,6 +284,64 @@ def test_profiled_engine_run_yields_dyn_events_on_one_host_line(tiny, tmp_path):
     assert snap["mixed_steps_total"] == 0
 
 
+def test_profiled_dispatch_events_say_what_the_dispatch_carried(tiny, tmp_path):
+    """PR 43: ``dyn.upload`` / ``dyn.dispatch`` / ``dyn.readback`` carry
+    ``rows``, ``tokens`` (prompt tokens of a prefill dispatch, rows of a
+    decode) and ``ctx`` (the sum of the rows' context lengths) beside
+    ``step``, ``kind`` and ``t_mono_ns``; the readback of a dispatch says
+    what *that* dispatch carried, whichever step reads it back; no other
+    phase carries them, and with no session open nothing is built."""
+    from jax.profiler import ProfileData
+
+    core = make_core(*tiny, prefill_chunk_tokens=16)
+    submit(core, "warm-a", 20, 3)
+    submit(core, "warm-b", 12, 3, seed=3)
+    run_dry(core)                           # compile outside the capture
+    assert core._carried(1, 1, np.asarray([5])) == {}     # no session open
+    assert core._inflight is None
+    step_timeline.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert core._carried(2, 2, np.asarray([5, 6])) == {
+            "rows": 2, "tokens": 2, "ctx": 11}
+        submit(core, "a", 20, 5, seed=1)    # chunks of 16 and 4
+        submit(core, "b", 12, 5, seed=2)
+        run_dry(core)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(
+        tmp_path, "plugins", "profile", "*", "*.xplane.pb"))[-1]
+    events = [e for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for e in line.events
+              if e.name.startswith("dyn.")]
+    events.sort(key=lambda e: e.start_ns)
+    seen = {"dyn.upload": [], "dyn.dispatch": [], "dyn.readback": []}
+    for e in events:
+        stats = dict(e.stats)
+        if e.name in seen:
+            seen[e.name].append(
+                (stats.get("kind", ""),) + tuple(
+                    int(stats[k]) for k in ("rows", "tokens", "ctx")))
+        else:
+            assert not {"rows", "tokens", "ctx"} & set(stats), e.name
+    # the prefill chunks in order of admission: a's 16 and 4, then b's 12
+    prefills = [c for c in seen["dyn.dispatch"] if c[0] == "step"]
+    assert prefills == [("step", 1, 16, 16), ("step", 1, 4, 20),
+                        ("step", 1, 12, 12)]
+    decodes = [c for c in seen["dyn.dispatch"] if c[0] == "decode_multi"]
+    assert decodes and all(rows == tokens and rows in (1, 2)
+                           for _, rows, tokens, _ in decodes)
+    assert any(rows == 2 for _, rows, _, _ in decodes)
+    # a decodes at lengths 21..24 and b at 13..16: every position once
+    assert sum(ctx for _, _, _, ctx in decodes) \
+        == sum(range(21, 25)) + sum(range(13, 17))
+    # an upload carries what its dispatch does (kind is on the device-facing
+    # phases only), and every dispatch is read back once, as itself
+    assert [c[1:] for c in seen["dyn.upload"]] \
+        == [c[1:] for c in seen["dyn.dispatch"]]
+    assert sorted(seen["dyn.readback"]) == sorted(seen["dyn.dispatch"])
+
+
 def test_counters_cut_short_is_not_max_tokens(tiny):
     """A cache made to run out: the request that loses its block space is
     counted cut short; the one that stops at max_tokens is not."""
