@@ -64,12 +64,14 @@ Params = Any
 
 __all__ = ["GlmDsaConfig", "GlmDsaModel", "SPARSE_MAX_QUERIES",
            "ROUTER_BIAS_STD",
-           "kth_largest", "index_scores", "select_mask", "selected_positions"]
+           "kth_largest", "index_scores", "select_mask", "selected_slots"]
 
 # query tokens in a call up to which each query gathers its own rows
 SPARSE_MAX_QUERIES = 256
 # queries scored at a time by the indexer ([tile, heads, context] in f32)
 INDEX_QUERY_TILE = 64
+# queries whose selection is compacted at a time ([tile, topk, context/128])
+COMPACT_QUERY_TILE = 32
 INDEX_NORM_EPS = 1e-6
 # standard deviation of the seeded e_score_correction_bias.  The eight
 # largest of 256 sigmoid scores lie ~0.02 apart, so a bias of 0.1 chose the
@@ -255,33 +257,80 @@ def select_mask(scores: jax.Array, seen: jax.Array, k: int) -> jax.Array:
     return seen & (above | (tie & (at <= last)))
 
 
-def selected_positions(sel: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """The positions a mask [N, C] selects (at most k a row), in ascending
-    order, as (positions [N, k] int32, count [N]); slots past a row's count
-    hold 0.  A compaction without a sort or a scatter: count the selected in
-    lanes of 128, find each output slot's lane group by its running count,
-    and its place within the group by a prefix sum done as a matmul."""
-    n, c = sel.shape
-    lanes = 128
-    sel = jnp.pad(sel, ((0, 0), (0, -c % lanes)))
-    g = sel.shape[1] // lanes
-    groups = sel.reshape(n, g, lanes)
-    upto = jnp.cumsum(groups.sum(axis=-1, dtype=jnp.int32), axis=-1)  # [N, G]
-    count = upto[:, -1]
+def _bytes_of(x: jax.Array, n: int) -> jax.Array:
+    """int32 [..., C] (values in [0, 256**n)) -> bf16 [..., n * C]: the
+    values' n bytes, low first, each exact in bf16."""
+    return jnp.concatenate(
+        [((x >> (8 * i)) & 0xFF).astype(jnp.bfloat16) for i in range(n)],
+        axis=-1)
+
+
+def _compact(sel: jax.Array, k: int, payload: jax.Array):
+    """One tile of ``selected_slots``: sel bool [B, T, G, 128], payload bf16
+    [B, G, n·128] (``_bytes_of`` a value of every position, laid out like
+    ``sel``) -> (positions [B, T, k], the payload's value there [B, T, k],
+    count [B, T]; past a query's count the first two hold anything).
+    Output slot j belongs to the lane group whose running
+    count first passes j; a one-hot of that group, made by two comparisons,
+    picks the group's lanes out of ``sel`` and out of the payload on the
+    matrix unit, and the lane is the one whose rank within the group (a
+    prefix sum, also a matmul) is j's.  No gather, sort or scatter; every
+    number carried through a dot is an integer below 256, exact in bf16."""
+    b, t, g, lanes = sel.shape
+    ones = jnp.triu(jnp.ones((lanes, lanes), jnp.bfloat16))
+    rank = jnp.dot(sel.astype(jnp.bfloat16), ones,
+                   preferred_element_type=jnp.float32)    # 1-based, in group
+    marks = jnp.where(sel, rank, 0).astype(jnp.bfloat16)
+    held = rank[..., -1].astype(jnp.int32)                # [B, T, G]
+    upto = jnp.cumsum(held, axis=-1)
+    count = upto[..., -1]
+    below = (upto - held)[:, :, None, :]
     slot = jnp.arange(k, dtype=jnp.int32)
-    group = (upto[:, None, :] <= slot[None, :, None]).sum(
-        axis=-1, dtype=jnp.int32)                                     # [N, k]
-    group = jnp.minimum(group, g - 1)
-    before = jnp.take_along_axis(
-        jnp.pad(upto, ((0, 0), (1, 0))), group, axis=1)               # [N, k]
-    rows = jnp.take_along_axis(groups, group[:, :, None], axis=1)     # [N,k,128]
-    ones = jnp.tril(jnp.ones((lanes, lanes), jnp.bfloat16)).T
-    running = jnp.dot(rows.astype(jnp.bfloat16), ones,
-                      preferred_element_type=jnp.float32)             # prefix
-    want = (slot[None, :] - before + 1).astype(jnp.float32)
-    lane = jnp.argmax(rows & (running == want[:, :, None]), axis=-1)
-    pos = group * lanes + lane.astype(jnp.int32)
-    return jnp.where(slot[None, :] < count[:, None], pos, 0), count
+    mine = (below <= slot[:, None]) & (slot[:, None] < upto[:, :, None, :])
+    onehot = mine.astype(jnp.bfloat16)                    # [B, T, k, G]
+    before = jnp.sum(jnp.where(mine, below, 0), axis=-1)
+    picked = jnp.einsum("btkg,btgl->btkl", onehot, marks,
+                        preferred_element_type=jnp.bfloat16)
+    hit = picked.astype(jnp.int32) == (slot - before + 1)[..., None]
+    group = jnp.sum(jnp.where(mine, jnp.arange(g, dtype=jnp.int32), 0),
+                    axis=-1)
+    pos = group * lanes + jnp.argmax(hit, axis=-1).astype(jnp.int32)
+    there = jnp.einsum("btkg,bgl->btkl", onehot, payload,
+                       preferred_element_type=jnp.bfloat16)
+    there = there.astype(jnp.int32).reshape(b, t, k, -1, lanes)
+    whole = sum(there[..., i, :] << (8 * i) for i in range(there.shape[-2]))
+    return pos, jnp.sum(jnp.where(hit, whole, 0), axis=-1), count
+
+
+def selected_slots(sel: jax.Array, k: int, slot_of: jax.Array,
+                   slot_bound: int):
+    """What a mask [B, S, C] selects (at most k a query), in ascending order
+    of position: (positions [B, S, k] int32, slots [B, S, k], count [B, S]).
+    ``slot_of`` int32 [B, C] is the flat cache slot of every position of a
+    row's block table, below ``slot_bound`` (static); entries past a
+    query's count hold position 0 and its slot.  ``COMPACT_QUERY_TILE``
+    queries at a time, so that a 256-query call holds no [256, k, C/128]
+    array."""
+    b, s, c = sel.shape
+    lanes = latent_cache.LANES
+    pad = -c % lanes
+    groups = jnp.pad(sel, ((0, 0), (0, 0), (0, pad))).reshape(
+        b, s, -1, lanes)
+    n = max(1, -(-(slot_bound - 1).bit_length() // 8))
+    payload = _bytes_of(
+        jnp.pad(slot_of, ((0, 0), (0, pad))).reshape(b, -1, lanes), n)
+    t = COMPACT_QUERY_TILE
+    if b * s <= t or s % t:
+        pos, slots, count = _compact(groups, k, payload)
+    else:
+        tiles = jnp.moveaxis(groups.reshape(b, s // t, t, -1, lanes), 1, 0)
+        pos, slots, count = (
+            jnp.moveaxis(a, 0, 1).reshape(b, s, *a.shape[3:])
+            for a in jax.lax.map(lambda tile: _compact(tile, k, payload),
+                                 tiles))
+    valid = jnp.arange(k, dtype=jnp.int32) < count[..., None]
+    return (jnp.where(valid, pos, 0),
+            jnp.where(valid, slots, slot_of[:, None, :1]), count)
 
 
 def index_scores(q: jax.Array, w: jax.Array, keys: jax.Array) -> jax.Array:
@@ -501,15 +550,18 @@ class GlmDsaModel:
         if not sparse:
             return sel, cache
         c = scores.shape[-1]
-        picked, nvalid = selected_positions(sel.reshape(b * s, c), k_sel)
+        # the flat slot of every position of the table: a broadcast, and the
+        # compaction carries it along, so no position is looked up
+        slot_of = (block_tables[:, :ctx_blocks, None] * bs
+                   + jnp.arange(bs, dtype=jnp.int32)).reshape(b, c)
+        picked, slots, nvalid = selected_slots(
+            sel, k_sel, slot_of, index_k.shape[1] * bs)
+        picked = picked.reshape(b * s, k_sel)
         vals = jnp.take_along_axis(scores.reshape(b * s, c), picked, axis=1)
-        slots = latent_cache.flat_slots(
-            block_tables, picked.reshape(b, s * k_sel), bs)
         # (slots, nvalid) is what attention reads; positions and scores ride
         # along for ``forward(probe=True)`` and cost nothing otherwise
         return (slots.reshape(b * s, k_sel), nvalid.reshape(b * s),
-                picked.reshape(b * s, k_sel), vals.reshape(b * s, k_sel)
-                ), cache
+                picked, vals), cache
 
     def _attention(self, lp, li, fi, h_in, positions, cache, block_tables,
                    seq_lens, slot_idx, sel, ctx_blocks, sparse, full):

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 __all__ = [
     "latent_words", "init_latent_cache", "pack_rows", "unpack_rows",
     "split_query", "write_rows", "write_latent", "context_rows",
-    "flat_slots", "sparse_attention_xla", "dense_masked_attention",
+    "sparse_attention_xla", "dense_masked_attention",
     "masked_attention",
     "kernels_on",
 ]
@@ -130,13 +130,6 @@ def write_rows(part: jax.Array, layer, rows: jax.Array, slots: jax.Array):
         rows.reshape(rows.shape[0], *part.shape[3:]).astype(part.dtype),
         mode="drop")
     return flat.reshape(part.shape)
-
-
-def flat_slots(block_tables: jax.Array, positions: jax.Array,
-               block_size: int) -> jax.Array:
-    """Token positions [B, K] of each row -> flat slots [B, K] in a layer."""
-    blk = jnp.take_along_axis(block_tables, positions // block_size, axis=1)
-    return blk * block_size + positions % block_size
 
 
 def sparse_attention_xla(q: jax.Array, latent: jax.Array, layer,

@@ -306,12 +306,11 @@ def one_context(model, core, ref, config, length, seed, a) -> dict:
                 key = jax.random.fold_in(jax.random.PRNGKey(0), li)
                 pos = jax.random.randint(
                     key, slots.shape, 0, jnp.maximum(positions.reshape(-1, 1), 1))
-                from dynamo_tpu.ops import latent_cache
-
                 bs = cache["latent"].shape[2]
                 b, s = positions.shape
-                rnd = latent_cache.flat_slots(
-                    block_tables, pos.reshape(b, -1), bs).reshape(slots.shape)
+                pos = pos.reshape(b, -1)
+                rnd = (jnp.take_along_axis(block_tables, pos // bs, axis=1)
+                       * bs + pos % bs).reshape(slots.shape)
                 sel = (rnd, nvalid, *sel[2:])
             return super()._attention(
                 lp, li, fi, h_in, positions, cache, block_tables, seq_lens,

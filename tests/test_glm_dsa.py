@@ -414,30 +414,86 @@ def test_kth_largest_is_exact():
             np.sort(np.asarray(x), axis=-1)[:, -k])
 
 
-@pytest.mark.parametrize("k", [1, 16, 130, 300])
-def test_selection_without_a_sort_is_top_k(k):
-    """``select_mask`` + ``selected_positions`` pick what ``lax.top_k``
-    picks — ties to the earlier position, every seen position where fewer
-    than k are seen — and list them in ascending order."""
+# (context, k): the first four at a context that is no multiple of 128; then k
+# that is none either, the served shape's proportions (2,048 of the 36,864
+# positions of a full block table), and a context of less than one lane group
+@pytest.mark.parametrize("c,k", [
+    (300, 1), (300, 16), (300, 130), (300, 300), (1000, 200), (4133, 2048),
+    (36864, 2048), (100, 100)])
+def test_selection_without_a_sort_is_top_k(c, k):
+    """``select_mask`` + ``selected_slots`` pick what ``lax.top_k`` picks —
+    ties to the earlier position, every seen position where fewer than k
+    are seen — and list them in ascending order (with each position as its
+    own slot, the slots are the positions once more)."""
     rng = np.random.default_rng(k)
-    c = 300                                     # not a multiple of 128
-    scores = np.round(rng.standard_normal((7, c)), 1).astype(np.float32)
-    seen = np.ones((7, c), bool)
+    rows = 7
+    # + 0.0: no -0.0, which the radix select orders below +0.0 (its note)
+    scores = (np.round(rng.standard_normal((rows, c)), 1) + 0.0).astype(
+        np.float32)
+    seen = np.ones((rows, c), bool)
     seen[1, 40:] = False                        # fewer than k seen
     seen[2] = False                             # nothing seen
     seen[3, ::2] = False
     scores[4] = 0.5                             # all tied
+    scores[5, :c // 2] = 0.5                    # tied at the k-th score
+    seen[6] = False                             # all in one lane group
+    seen[6, 128 * (c // 256):128 * (c // 256) + 100] = True
     scores = np.where(seen, scores, -np.inf)
     sel = glm.select_mask(jnp.asarray(scores), jnp.asarray(seen), k)
-    pos, count = glm.selected_positions(sel, k)
+    at = jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), (rows, c))
+    pos, slots, count = (a[:, 0] for a in glm.selected_slots(
+        sel[:, None, :], k, at, c))
     _, want = jax.lax.top_k(jnp.asarray(scores), k)
-    for i in range(7):
+    for i in range(rows):
         n = min(k, int(seen[i].sum()))
         expect = sorted(np.asarray(want[i])[:n].tolist())
         assert int(count[i]) == n
         assert np.asarray(pos[i])[:n].tolist() == expect, i
+        assert np.asarray(slots[i])[:n].tolist() == expect, i
         assert not np.asarray(pos[i])[n:].any()
         assert sorted(np.flatnonzero(np.asarray(sel[i])).tolist()) == expect
+
+
+# (rows, queries a row, context, k, block size, blocks of the pool): the
+# decode program's proportions; a question's queries, more than one tile of
+# them, over one row's table; a context that is no multiple of 128 and a k
+# that is none; pools whose slots take three bytes, two to the last bit, one
+@pytest.mark.parametrize("b,s,c,k,bs,nb", [
+    (4, 1, 36864, 2048, 32, 14400), (1, 96, 4096, 2048, 32, 14400),
+    (2, 3, 300, 130, 4, 96), (3, 1, 1000, 200, 8, 70000),
+    (2, 1, 1000, 200, 8, 8192), (2, 2, 96, 96, 8, 32)])
+def test_slot_list_is_the_block_table_lookup(b, s, c, k, bs, nb):
+    """``selected_slots`` hands attention, for every query, the flat cache
+    slot ``block_tables[row, pos // bs] * bs + pos % bs`` of each selected
+    position in ascending order of position — every integer exact, block
+    ids up to the pool's last — and past the query's count the slot of
+    position 0, as a lookup of the padding would give."""
+    rng = np.random.default_rng(c + k)
+    sel = np.zeros((b, s, c), bool)
+    for i in range(b):
+        for j in range(s):
+            n = int(rng.integers(1, c + 1))
+            sel[i, j, rng.choice(n, size=min(k, n), replace=False)] = True
+    sel[0, 0] = False                           # nothing selected
+    sel[-1, -1] = False
+    sel[-1, -1, -k:] = True                     # the last k positions
+    tables = np.stack([rng.permutation(nb)[:c // bs] for _ in range(b)]
+                      ).astype(np.int32)
+    tables[0, :2] = nb - 1, 0                   # the pool's last block, and 0
+    slot_of = (tables[:, :, None] * bs + np.arange(bs)).reshape(b, c)
+    pos, slots, count = jax.jit(
+        glm.selected_slots, static_argnums=(1, 3))(
+            jnp.asarray(sel), k, jnp.asarray(slot_of), nb * bs)
+    assert slots.dtype == jnp.int32 and slots.shape == (b, s, k)
+    for i in range(b):
+        for j in range(s):
+            at = np.flatnonzero(sel[i, j])
+            want = np.full(k, tables[i, 0] * bs)
+            want[:len(at)] = tables[i, at // bs] * bs + at % bs
+            assert int(count[i, j]) == len(at)
+            np.testing.assert_array_equal(np.asarray(slots[i, j]), want)
+            np.testing.assert_array_equal(np.asarray(pos[i, j])[:len(at)], at)
+            assert not np.asarray(pos[i, j])[len(at):].any()
 
 
 # ------------------------------------------------------ (e) from_hf_config --

@@ -643,6 +643,55 @@ def test_sparse_latent_attention_compiles_on_one_chip(
     assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 10
 
 
+# temporaries of the 2,048-token chunk at 34,816 tokens of context, compiled
+# for this device as the question is below (1,284,287,488; the
+# configuration's ``serve_why`` counts on 1.28 GB) - 70 CPU-seconds to
+# compile, so held here as a number
+GLM_CHUNK_TEMP = 1.28e9
+# one ``full`` layer and one that shares its selection: what the five do
+_GLM_KINDS = {"num_hidden_layers": 2, "first_k_dense_replace": 1,
+              "indexer_types": ["full", "shared"],
+              "mlp_layer_types": ["dense", "sparse"]}
+_FROM_A_GATHER = re.compile(r'op_name="[^"]*/indexer/[^"]*gather"')
+
+
+@pytest.mark.parametrize("chunk", [None, 256], ids=["decode", "question-256"])
+def test_indexer_lists_its_selection_without_an_element_gather(
+        topo, tpu_gate, chunk):
+    """From the selection mask to the slot list attention reads, the indexer
+    gathers nothing by the element (three ``take_along_axis`` of 65,536 -
+    524,288 scalars a ``full`` layer were 12% of a decode program and a
+    quarter of a question program: PERF.md, PR 44).  What is left under the
+    scope is the gather of the keys, whole blocks of ``index_k``; and a
+    256-query question holds no more temporaries than a 2,048-token chunk,
+    the program that sizes the cell's memory (0.11 GB: 0.15 with the
+    gathers)."""
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "glm-5.2-ep16.json",
+        lambda spec: SingleDeviceSharding(topo.devices[0]), **_GLM_KINDS)
+    # the cell's decode program, or a question behind the longest prefix
+    fn, args = _step_program(
+        "prefill" if chunk else "decode", model,
+        dict(hf["serve"], prefill_chunk_tokens=chunk), sds,
+        prefix_blocks=1024)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    assert f"mla_sparse_{'prefill' if chunk else 'decode'}" in hlo
+    gathered = set()
+    for line in hlo.splitlines():
+        m = _HLO_INSTR.match(line)
+        # XLA lowers a gather to a custom fusion that keeps its name
+        if (m and m.group(4) in ("gather", "fusion")
+                and _FROM_A_GATHER.search(line)):
+            dims = tuple(int(d) for d in m.group(3).split(","))
+            gathered.add((m.group(2), dims[-2:]))
+    assert gathered == {("bf16", (BS, 128))}, gathered      # [.., Bs, Di]
+    if chunk:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp <= GLM_CHUNK_TEMP, temp
+
+
 def test_masked_latent_prefill_compiles_on_one_chip(glm_sds):
     from dynamo_tpu.ops.pallas.mla_masked_prefill import mla_masked_prefill
 
