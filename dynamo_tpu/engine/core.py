@@ -38,7 +38,7 @@ import logging
 import queue
 import threading
 import time
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -292,6 +292,15 @@ def unified_token_step(
     return out, cache
 
 
+@jax.jit
+def expert_totals(moe_counts):
+    """int32 [3] — router picks, picks on the experts held here, expert-layer
+    calls — summed over the layers of a cache's ``moe_counts`` [L, 1, 3].  A
+    buffer of its own: the cache is donated to the next dispatch, this is
+    read back with the dispatch's outputs."""
+    return moe_counts.sum(axis=(0, 1))
+
+
 @functools.partial(jax.jit, static_argnames=("layout",))
 def operand_prologue(key, bufs, *, layout):
     """Under a mesh, ahead of every serving program: the next key of the
@@ -322,6 +331,10 @@ class _Inflight:
     # rows / tokens / ctx for the profiler's dyn.readback event of this
     # dispatch (EngineCore._carried); empty with no profiler session
     carried: dict = dataclasses.field(default_factory=dict)
+    # what the model's expert layers had counted once this dispatch ran
+    # (``expert_totals`` of the cache's ``moe_counts``), on the device; None
+    # for a model that counts nothing
+    experts: Any = None
 
 
 # what a dispatch carried, when no profiler session is open to be told
@@ -381,11 +394,13 @@ class EngineCore:
         )
         cache_dtype = config.cache_dtype or model.config.dtype
         self.cache_quant = str(cache_dtype) == "int8"
-        # a cache of two arrays (latent rows and indexer keys): what moves
-        # blocks was written for one, so refuse it here, at start-up,
-        # rather than move half a block
-        self._two_part_cache = bool(getattr(model, "two_part_cache", False))
-        if self._two_part_cache:
+        # a cache in a layout of the model's own (ops/latent_cache.py: rows
+        # held once, an indexer's keys beside them or not): what moves
+        # blocks was written for the K/V pool, so refuse it here, at
+        # start-up, rather than move something else
+        self._private_cache_layout = bool(
+            getattr(model, "private_cache_layout", False))
+        if self._private_cache_layout:
             asked = [name for name, on in (
                 ("num_host_blocks", config.num_host_blocks > 0),
                 ("kv_persist_dir", bool(config.kv_persist_dir)),
@@ -395,9 +410,9 @@ class EngineCore:
                 ("a mesh", mesh is not None)) if on]
             if asked:
                 raise ValueError(
-                    f"{type(model).__name__} keeps a two-part cache (latent "
-                    "rows and indexer keys under one block table); not "
-                    f"supported with it: {', '.join(asked)}")
+                    f"{type(model).__name__} keeps its cache in a layout "
+                    "the block movers do not know (ops/latent_cache.py); "
+                    f"not supported with it: {', '.join(asked)}")
         # host-RAM offload tier: device-evicted blocks stay restorable
         # (ref kv/reuse.rs + layer.rs copy streams; SURVEY §5 checkpoint row)
         self.host_pool = None
@@ -646,11 +661,17 @@ class EngineCore:
         # the tokens served from reused blocks
         self.prompt_tokens_admitted = 0
         self.prompt_tokens_cached = 0
-        # a model with a sparse-attention indexer: positions its decode
-        # rows could see, and positions they attended to
+        # a latent-attention model: positions its decode rows could see,
+        # and positions they attended to (all of them without an indexer)
         self._index_topk = int(getattr(model.config, "index_topk", 0) or 0)
         self.attn_context_tokens = 0
         self.attn_selected_tokens = 0
+        # what a model's expert layers count on the device (the cache's
+        # ``moe_counts``): router picks, those that fell on the experts
+        # held here, expert-layer calls; read back with each dispatch
+        self.moe_router_picks = 0
+        self.moe_held_picks = 0
+        self.moe_expert_layer_calls = 0
         # tokens dispatched (prefill and decode) and, over them, the passes
         # of the layer stack run: ut_steps a token for a looped decoder
         self._ut_steps = int(getattr(model.config, "ut_steps", 1) or 1)
@@ -1325,6 +1346,8 @@ class EngineCore:
         work the device no longer waits for), then ``rec`` itself unless
         it may stay in flight for the next turn."""
         prev, self._inflight = self._inflight, rec
+        if isinstance(self.cache, dict) and "moe_counts" in self.cache:
+            rec.experts = expert_totals(self.cache["moe_counts"])
         if prev is not None:
             if rec.kind == "decode_multi":
                 # counted like decode_dispatches_total, at the dispatch:
@@ -1346,8 +1369,16 @@ class EngineCore:
         # ONE batched transfer: per-array np.asarray would issue a
         # device->host round trip per output (per-array latency is the
         # cost that matters on a remote-attached chip)
-        out = tuple(jax.device_get(rec.out))
+        out, experts = jax.device_get((tuple(rec.out), rec.experts))
         self.device_gets += 1
+        if experts is not None:
+            picks, held, calls = (int(n) for n in experts)
+            request_counters.record_experts(
+                picks - self.moe_router_picks, held - self.moe_held_picks,
+                calls - self.moe_expert_layer_calls)
+            self.moe_router_picks = picks
+            self.moe_held_picks = held
+            self.moe_expert_layer_calls = calls
         self._host_post()
         rec.finish(out)
         for req in rec.ended:
@@ -1458,6 +1489,9 @@ class EngineCore:
             "prompt_tokens_cached_total": self.prompt_tokens_cached,
             "attn_context_tokens_total": self.attn_context_tokens,
             "attn_selected_tokens_total": self.attn_selected_tokens,
+            "moe_router_picks_total": self.moe_router_picks,
+            "moe_held_picks_total": self.moe_held_picks,
+            "moe_expert_layer_calls_total": self.moe_expert_layer_calls,
             "loop_tokens_total": self.loop_tokens,
             "loop_passes_total": self.loop_passes,
             "decode_kv_blocks_walked_total": self.decode_kv_blocks_walked,
@@ -2841,9 +2875,10 @@ class EngineCore:
         request_counters.record_decode(len(active))
         self._count_decode_blocks(seq_lens)
         self._count_tokens(len(active) * k_steps)
-        if self._index_topk:
+        if self._private_cache_layout:
             ctx = int(seq_lens.sum())
-            picked = int(np.minimum(seq_lens, self._index_topk).sum())
+            picked = (int(np.minimum(seq_lens, self._index_topk).sum())
+                      if self._index_topk else ctx)
             self.attn_context_tokens += ctx
             self.attn_selected_tokens += picked
             request_counters.record_sparse_decode(ctx, picked)
@@ -3372,12 +3407,12 @@ class EngineCore:
         req.cached_tokens += len(hit) * bs
 
     def _refuse_block_move(self, what: str) -> None:
-        """Transfer, streaming and remote prefill speak of one cache array;
-        a model with a two-part cache has none of them."""
-        if self._two_part_cache:
+        """Transfer, streaming and remote prefill speak of the K/V pool; a
+        model with a cache layout of its own has none of them."""
+        if self._private_cache_layout:
             raise NotImplementedError(
-                f"{what}: {type(self.model).__name__} keeps a two-part "
-                "cache, which the block movers do not carry")
+                f"{what}: {type(self.model).__name__} keeps its cache in a "
+                "layout the block movers do not know")
 
     def gather_blocks_device(self, block_ids: list[int]) -> jax.Array:
         """Gather blocks WITHOUT leaving the device: returns a jax.Array
@@ -3494,5 +3529,7 @@ class EngineCore:
         quantization/dtype changes are automatically reflected."""
         leaves = jax.tree.leaves(self.cache)
         # cache leaves are [L, n_blocks, ...]: bytes per block = leaf
-        # bytes / n_blocks, summed over parts
-        return sum(int(l.nbytes) // max(1, int(l.shape[1])) for l in leaves)
+        # bytes / n_blocks, summed over parts (a leaf that is not per block,
+        # a model's ``moe_counts``, is not the cache's)
+        n = self.config.num_blocks
+        return sum(int(l.nbytes) // n for l in leaves if l.shape[1] == n)

@@ -342,18 +342,33 @@ class RequestCounters:
                                                        reused blocks: over
                                                        admitted, the prefix
                                                        cache's hit share)
-        dynamo_tpu_engine_attn_context_tokens_total    counter (models with
-                                                       a sparse-attention
-                                                       indexer: cached
-                                                       positions the decode
-                                                       rows dispatched could
-                                                       see, summed)
+        dynamo_tpu_engine_attn_context_tokens_total    counter (latent-
+                                                       attention models:
+                                                       cached positions the
+                                                       decode rows dispatched
+                                                       could see, summed)
         dynamo_tpu_engine_attn_selected_tokens_total   counter (of those, the
                                                        positions attended to:
                                                        min(context,
-                                                       index_topk) a row;
-                                                       over context, how
-                                                       sparse attention was)
+                                                       index_topk) a row, all
+                                                       of them without an
+                                                       indexer; over context,
+                                                       how sparse attention
+                                                       was)
+        dynamo_tpu_engine_moe_router_picks_total       counter (experts the
+                                                       router picked for the
+                                                       real tokens of every
+                                                       dispatch, counted on
+                                                       the device: top-k a
+                                                       token and expert layer)
+        dynamo_tpu_engine_moe_held_picks_total         counter (of those, the
+                                                       picks on the experts
+                                                       this chip holds: the
+                                                       rows its experts
+                                                       computed)
+        dynamo_tpu_engine_moe_expert_layer_calls_total counter (expert layers
+                                                       run, one a layer and
+                                                       dispatch)
         dynamo_tpu_engine_loop_tokens_total            counter (tokens that
                                                        went out in a prefill
                                                        or decode dispatch)
@@ -379,7 +394,9 @@ class RequestCounters:
                                                        1 - walked / bound is
                                                        the share of fetches a
                                                        row's own walk spares)
-    All six are counted on the host from lengths it already has.
+    The ``moe_*`` three are counted on the device, inside the model's expert
+    layers, and read back with each dispatch's outputs; the others on the
+    host from lengths it already has.
     """
 
     def __init__(self) -> None:
@@ -422,6 +439,11 @@ class RequestCounters:
         self.attn_context_tokens_total += context
         self.attn_selected_tokens_total += selected
 
+    def record_experts(self, picks: int, held: int, calls: int) -> None:
+        self.moe_router_picks_total += picks
+        self.moe_held_picks_total += held
+        self.moe_expert_layer_calls_total += calls
+
     def record_loop(self, tokens: int, passes: int) -> None:
         self.loop_tokens_total += tokens
         self.loop_passes_total += passes
@@ -448,6 +470,9 @@ class RequestCounters:
         self.prompt_tokens_cached_total = 0
         self.attn_context_tokens_total = 0
         self.attn_selected_tokens_total = 0
+        self.moe_router_picks_total = 0
+        self.moe_held_picks_total = 0
+        self.moe_expert_layer_calls_total = 0
         self.loop_tokens_total = 0
         self.loop_passes_total = 0
         self.decode_kv_blocks_walked_total = 0

@@ -345,6 +345,16 @@ class Metrics:
         lines.append(f"# TYPE {EM.ATTN_SELECTED_TOKENS_TOTAL} counter")
         lines.append(f"{EM.ATTN_SELECTED_TOKENS_TOTAL} "
                      f"{rc.attn_selected_tokens_total}")
+        # the expert layers' own counts, read back from the device: router
+        # picks, those on the experts held here, expert-layer calls
+        lines.append(f"# TYPE {EM.MOE_ROUTER_PICKS_TOTAL} counter")
+        lines.append(f"{EM.MOE_ROUTER_PICKS_TOTAL} "
+                     f"{rc.moe_router_picks_total}")
+        lines.append(f"# TYPE {EM.MOE_HELD_PICKS_TOTAL} counter")
+        lines.append(f"{EM.MOE_HELD_PICKS_TOTAL} {rc.moe_held_picks_total}")
+        lines.append(f"# TYPE {EM.MOE_EXPERT_LAYER_CALLS_TOTAL} counter")
+        lines.append(f"{EM.MOE_EXPERT_LAYER_CALLS_TOTAL} "
+                     f"{rc.moe_expert_layer_calls_total}")
         # the mesh this engine runs on (1 and 1 with no mesh)
         lines.append(f"# TYPE {EM.MESH_TP} gauge")
         lines.append(f"{EM.MESH_TP} {mesh_shape['tp']}")

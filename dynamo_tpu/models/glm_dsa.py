@@ -1,6 +1,9 @@
-"""GLM-5.2-style decoder (``glm_moe_dsa``): latent attention, a learned
-sparse-attention indexer shared between layers, sigmoid-routed experts of
-which this chip holds a share.
+"""The latent-attention decoder family with routed experts of which this chip
+holds a share: GLM-5.2 (``glm_moe_dsa``: a learned sparse-attention indexer
+shared between layers, sigmoid routing with a correction bias) and
+Mistral-Small-4 (``mistral4``: no indexer, every layer attends to every
+cached row, YaRN with a position-dependent query scale, softmax routing, no
+dense layer; docs/mistral4_mla.md).
 
 Per layer (pre-norm residual blocks, docs/glm_dsa.md has the equations):
 
@@ -12,7 +15,10 @@ Per layer (pre-norm residual blocks, docs/glm_dsa.md has the equations):
     multi-head scorer over a cache of its own keys picks, for every query
     token, the ``index_topk`` positions it attends to.  A ``shared`` layer
     has no indexer and attends to the selection of the nearest ``full``
-    layer before it.
+    layer before it.  A ``none`` layer has no indexer either and attends to
+    every cached row (``latent_cache.dense_attention``: two Pallas kernels
+    over whole blocks on the TPU); a stack is of ``none`` layers only or of
+    none of them, because the two read different cache layouts.
   * Attention over a selection takes one of two forms, chosen statically by
     the number of query tokens in the call.  Up to ``SPARSE_MAX_QUERIES``
     (decode steps, the short tail of a prompt after a prefix hit) each query
@@ -27,14 +33,15 @@ Per layer (pre-norm residual blocks, docs/glm_dsa.md has the equations):
     the other chips of the expert-parallel group would add is left out: no
     code stands in for them.
 
-The stack has up to four kinds of layer (dense or expert MLP × ``full`` or
-``shared`` indexer).  Parameters are stacked per kind, and each run of
+The stack has up to six kinds of layer (dense or expert MLP × ``full``,
+``shared`` or ``none`` indexer).  Parameters are stacked per kind, and each run of
 consecutive layers of one kind is one ``lax.scan``; expert weights are read
 where they lie (the layer-indexed form of ``grouped_expert_dispatch``).
 
 One chip only: no partition specs.  Block movers (host pool, persistent
-store, streamed or remote prefill) do not know the two-part cache, and the
-engine refuses them for this model at start-up (``two_part_cache``).
+store, streamed or remote prefill) know neither layout of the latent cache,
+and the engine refuses them for this model at start-up
+(``private_cache_layout``).
 """
 
 from __future__ import annotations
@@ -53,6 +60,8 @@ from dynamo_tpu.models.llama import (
     rms_norm,
     rope_inv_freq,
     split_heads,
+    yarn_inv_freq,
+    yarn_mscale,
 )
 from dynamo_tpu.ops import latent_cache
 from dynamo_tpu.ops.paged_attention import (
@@ -103,15 +112,20 @@ class GlmDsaConfig:
     n_shared_experts: int
     routed_scaling_factor: float
     norm_topk_prob: bool
-    index_n_heads: int
+    index_n_heads: int             # the three are 0 without an indexer
     index_head_dim: int
     index_topk: int
-    indexer_types: tuple           # per layer: "full" | "shared"
+    indexer_types: tuple           # per layer: "full" | "shared" | "none"
     mlp_layer_types: tuple         # per layer: "dense" | "sparse"
     scoring_func: str = "sigmoid"
     topk_method: str = "noaux_tc"
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
+    # YaRN: {"factor", "beta_fast", "beta_slow", "mscale_all_dim",
+    # "original_max_position_embeddings"}; None: plain RoPE
+    yarn: dict | None = None
+    # query scale 1 + beta·ln(1 + floor(position / original context))
+    query_scale_beta: float = 0.0
     max_position_embeddings: int = 4096
     dtype: str = "bfloat16"
 
@@ -136,18 +150,32 @@ class GlmDsaConfig:
     def full_layers(self) -> int:
         return sum(t == "full" for t in self.indexer_types)
 
+    @property
+    def indexed(self) -> bool:
+        """Whether attention reads a selection (False: every cached row)."""
+        return "none" not in self.indexer_types
+
     @classmethod
     def from_hf_config(cls, cfg: dict, dtype: str = "bfloat16"
                        ) -> "GlmDsaConfig":
-        """The published ``glm_moe_dsa`` keys -> GlmDsaConfig.  Raises on
-        what this port does not compute, rather than computing something
-        else in silence.  ``expert_parallel`` (not a published key) says
+        """The published ``glm_moe_dsa`` or ``mistral4`` keys ->
+        GlmDsaConfig.  Raises on what this port does not compute, rather
+        than computing something else in silence.  A config with no
+        ``indexer_types`` and no ``index_topk`` has no indexer: every
+        layer is ``none``.  ``mistral4`` states neither ``scoring_func`` nor
+        ``topk_method``: softmax over all experts and the k largest, the
+        Mistral family's convention.  ``expert_parallel`` (not a published key) says
         which share of a layer's experts this chip holds:
         ``{"router_experts": 256, "first_expert": 0}`` beside
         ``n_routed_experts`` = the number held."""
         g = cfg.get
         n = int(g("num_hidden_layers"))
         types = tuple(g("indexer_types") or ())
+        if not types and g("index_topk") is None:
+            types = ("none",) * n
+        mistral4 = g("model_type") == "mistral4"
+        scoring = g("scoring_func", "softmax" if mistral4 else "sigmoid")
+        method = g("topk_method", "greedy" if mistral4 else "noaux_tc")
         mlps = tuple(g("mlp_layer_types") or (
             ["dense"] * int(g("first_k_dense_replace", 0))
             + ["sparse"] * (n - int(g("first_k_dense_replace", 0)))))
@@ -155,19 +183,28 @@ class GlmDsaConfig:
             raise ValueError(
                 f"indexer_types ({len(types)}) and mlp_layer_types "
                 f"({len(mlps)}) must name each of the {n} layers")
-        if set(types) - {"full", "shared"} or set(mlps) - {"dense", "sparse"}:
+        if (set(types) - {"full", "shared", "none"}
+                or set(mlps) - {"dense", "sparse"}):
             raise NotImplementedError(
                 f"layer types {sorted(set(types) | set(mlps))}")
-        if types[0] != "full":
+        if "none" in types and set(types) != {"none"}:
+            raise NotImplementedError(
+                "layers with and without an indexer in one stack: they "
+                "read different layouts of the latent cache")
+        if types[0] == "shared":
             raise ValueError("the first layer has no index to share")
-        if g("scoring_func", "sigmoid") not in ("sigmoid", "softmax"):
-            raise NotImplementedError(f"scoring_func {g('scoring_func')!r}")
-        if g("topk_method", "noaux_tc") not in ("noaux_tc", "greedy"):
-            raise NotImplementedError(f"topk_method {g('topk_method')!r}")
+        if scoring not in ("sigmoid", "softmax"):
+            raise NotImplementedError(f"scoring_func {scoring!r}")
+        if method not in ("noaux_tc", "greedy"):
+            raise NotImplementedError(f"topk_method {method!r}")
         if int(g("n_group", 1) or 1) != 1 or int(g("topk_group", 1) or 1) != 1:
             raise NotImplementedError("group-limited expert choice")
         if bool(g("attention_bias", False)):
             raise NotImplementedError("attention_bias=True")
+        if bool(g("mlp_bias", False)):
+            raise NotImplementedError("mlp_bias=True")
+        if g("sliding_window") is not None:
+            raise NotImplementedError("sliding_window for this family")
         if g("hidden_act", "silu") != "silu":
             raise NotImplementedError(f"hidden_act {g('hidden_act')!r}")
         if int(g("moe_layer_freq", 1)) != 1:
@@ -180,8 +217,7 @@ class GlmDsaConfig:
         if bool(g("tie_word_embeddings", False)):
             raise NotImplementedError("tie_word_embeddings=True")
         rope = g("rope_parameters") or {}
-        if rope.get("rope_type", "default") != "default" or g("rope_scaling"):
-            raise NotImplementedError("RoPE scaling for this family")
+        yarn, beta = _read_rope_scaling(rope, g("rope_scaling"))
         ep = g("expert_parallel") or {}
         held = int(g("n_routed_experts"))
         total = int(ep.get("router_experts", held))
@@ -205,17 +241,59 @@ class GlmDsaConfig:
             n_shared_experts=int(g("n_shared_experts", 1)),
             routed_scaling_factor=float(g("routed_scaling_factor", 1.0)),
             norm_topk_prob=bool(g("norm_topk_prob", True)),
-            index_n_heads=int(g("index_n_heads")),
-            index_head_dim=int(g("index_head_dim")),
-            index_topk=int(g("index_topk")),
+            index_n_heads=int(g("index_n_heads", 0)),
+            index_head_dim=int(g("index_head_dim", 0)),
+            index_topk=int(g("index_topk", 0)),
             indexer_types=types, mlp_layer_types=mlps,
-            scoring_func=g("scoring_func", "sigmoid"),
-            topk_method=g("topk_method", "noaux_tc"),
+            scoring_func=scoring, topk_method=method,
             rms_norm_eps=float(g("rms_norm_eps", 1e-5)),
             rope_theta=float(rope.get("rope_theta", g("rope_theta", 10000.0))),
+            yarn=yarn, query_scale_beta=beta,
             max_position_embeddings=int(g("max_position_embeddings", 4096)),
             dtype=dtype,
         )
+
+
+_YARN_KEYS = {"rope_type", "type", "rope_theta", "factor", "beta_fast",
+              "beta_slow", "mscale", "mscale_all_dim",
+              "original_max_position_embeddings", "llama_4_scaling_beta"}
+
+
+def _read_rope_scaling(rope: dict, legacy) -> tuple[dict | None, float]:
+    """(the YaRN block, the query scale's beta) of ``rope_parameters``.
+    YaRN is read one way only — ``mscale`` equal to ``mscale_all_dim`` (so
+    cos and sin keep their size and the softmax scale takes m², the
+    DeepSeek-V3 convention whose keys these are), the trained context
+    stated, ``truncate`` left at true — and every other scaling raises."""
+    kind = rope.get("rope_type", rope.get("type", "default"))
+    if legacy or kind not in ("default", "yarn"):
+        raise NotImplementedError(
+            f"RoPE scaling {kind if not legacy else legacy!r} for this "
+            "family (YaRN under rope_parameters is read)")
+    if kind == "default":
+        if rope.get("llama_4_scaling_beta"):
+            raise NotImplementedError(
+                "llama_4_scaling_beta without YaRN's trained context")
+        return None, 0.0
+    if set(rope) - _YARN_KEYS:
+        raise NotImplementedError(
+            f"YaRN keys {sorted(set(rope) - _YARN_KEYS)}")
+    if "original_max_position_embeddings" not in rope or "factor" not in rope:
+        raise NotImplementedError(
+            "YaRN without factor and original_max_position_embeddings")
+    if rope.get("mscale", 1) != rope.get("mscale_all_dim", 0):
+        raise NotImplementedError(
+            f"YaRN with mscale {rope.get('mscale', 1)} != mscale_all_dim "
+            f"{rope.get('mscale_all_dim', 0)}")
+    yarn = {
+        "factor": float(rope["factor"]),
+        "beta_fast": float(rope.get("beta_fast", 32)),
+        "beta_slow": float(rope.get("beta_slow", 1)),
+        "mscale_all_dim": float(rope["mscale_all_dim"]),
+        "original_max_position_embeddings": int(
+            rope["original_max_position_embeddings"]),
+    }
+    return yarn, float(rope.get("llama_4_scaling_beta", 0.0))
 
 
 def kth_largest(x: jax.Array, k) -> jax.Array:
@@ -385,9 +463,10 @@ class _Run:
 class GlmDsaModel:
     """Engine-facing functional model (same protocol as LlamaModel)."""
 
-    # the cache is a pytree of two arrays: EngineCore refuses what would
-    # move blocks without knowing that
-    two_part_cache = True
+    # the cache is a pytree in a layout of this family's own (ops/
+    # latent_cache.py): EngineCore refuses what would move blocks without
+    # knowing it
+    private_cache_layout = True
     supports_ragged_prefill = False
     supports_unified_dispatch = False
     supports_seq_parallel = False
@@ -395,8 +474,19 @@ class GlmDsaModel:
     def __init__(self, config: GlmDsaConfig):
         self.config = config
         self.sm_scale = float(config.qk_head_dim ** -0.5)
-        self.inv_freq = rope_inv_freq(config.qk_rope_head_dim,
-                                      config.rope_theta)
+        yarn = config.yarn
+        if yarn is None:
+            self.inv_freq = rope_inv_freq(config.qk_rope_head_dim,
+                                          config.rope_theta)
+        else:
+            self.inv_freq = yarn_inv_freq(
+                config.qk_rope_head_dim, config.rope_theta, yarn["factor"],
+                yarn["original_max_position_embeddings"], yarn["beta_fast"],
+                yarn["beta_slow"])
+            # mscale = mscale_all_dim: cos and sin keep their size, and the
+            # softmax scale takes m² (the DeepSeek-V3 reading of these keys)
+            self.sm_scale *= yarn_mscale(
+                yarn["factor"], yarn["mscale_all_dim"]) ** 2
         kinds = [f"{m}_{i}" for m, i in zip(config.mlp_layer_types,
                                             config.indexer_types)]
         runs, seen, fulls = [], {}, 0
@@ -457,12 +547,13 @@ class GlmDsaModel:
             else:
                 e, f = cfg.n_routed_experts, cfg.moe_intermediate_size
                 fs = f * cfg.n_shared_experts
-                p.update(
-                    router=dense((n, dm, cfg.router_experts), dm),
+                p.update(router=dense((n, dm, cfg.router_experts), dm))
+                if cfg.topk_method == "noaux_tc":
                     # e_score_correction_bias: non-zero, so that seeded
                     # weights exercise the choice-only bias
-                    router_bias=ROUTER_BIAS_STD * jax.random.normal(
-                        next(keys), (n, cfg.router_experts), jnp.float32),
+                    p.update(router_bias=ROUTER_BIAS_STD * jax.random.normal(
+                        next(keys), (n, cfg.router_experts), jnp.float32))
+                p.update(
                     w_gate=dense((n, e, dm, f), dm),
                     w_up=dense((n, e, dm, f), dm),
                     w_down=dense((n, e, f, dm), f),
@@ -490,22 +581,41 @@ class GlmDsaModel:
     def cache_spec(self, quant: bool = False):
         if quant:
             raise NotImplementedError("int8 latent cache")
+        if not self.config.indexed:
+            return {"latent": P(None, None, None, None),
+                    "moe_counts": P(None, None, None)}
         return {"latent": P(None, None, None, None, None),
                 "index_k": P(None, None, None, None)}
 
     # --------------------------------------------------------------- kv cache
     def init_kv_cache(self, num_blocks: int, block_size: int, dtype=None):
-        """The two-part cache of ops/latent_cache.py: the latent row once a
-        token and layer, the indexer's key beside it in ``full`` layers."""
+        """The cache of ops/latent_cache.py: the latent row once a token
+        and layer, the indexer's key beside it in ``full`` layers; with no
+        indexer the dense layout and, beside it, what the expert layers
+        counted (``moe_counts`` int32 [L, 1, 3]: router picks, picks that
+        fell on the experts held here, calls — shaped like a part of the
+        cache, after ``latent`` in the pytree's order; ``EngineCore`` reads
+        its sums back with each dispatch)."""
         cfg = self.config
         if dtype is not None and jnp.dtype(dtype) != jnp.dtype(cfg.jax_dtype):
             raise NotImplementedError(f"latent cache dtype {dtype!r}")
+        if not cfg.indexed:
+            return {
+                **latent_cache.init_dense_cache(
+                    cfg.num_layers, num_blocks, block_size, cfg.head_dim,
+                    cfg.jax_dtype),
+                "moe_counts": jnp.zeros((cfg.num_layers, 1, 3), jnp.int32)}
         return latent_cache.init_latent_cache(
             cfg.num_layers, cfg.full_layers, num_blocks, block_size,
             cfg.head_dim, cfg.index_head_dim, cfg.jax_dtype)
 
     def attention_impls(self) -> dict[str, tuple[str, str]]:
         """phase -> ("pallas" | "xla", why) for the engine's start-up line."""
+        if not self.config.indexed:
+            # the dense kernels follow one rule (latent_cache.kernels_on)
+            impl, why = sparse_attention_impl("prefill")
+            return {"decode": (impl, f"{why}; mla_dense_decode"),
+                    "prefill": (impl, f"{why}; mla_dense_prefill")}
         out = {p: sparse_attention_impl(p) for p in ("decode", "prefill")}
         impl, why = out["prefill"]
         out["prefill_chunk"] = (impl, f"{why}; chunks over "
@@ -563,6 +673,18 @@ class GlmDsaModel:
         return (slots.reshape(b * s, k_sel), nvalid.reshape(b * s),
                 picked, vals), cache
 
+    def _query_scale(self, positions: jax.Array) -> jax.Array:
+        """f32 [B, S]: what multiplies a query before a dense kernel — the
+        softmax scale (YaRN's m² in it) times the position's factor
+        1 + beta·ln(1 + floor(p / trained context))."""
+        cfg = self.config
+        scale = jnp.full(positions.shape, self.sm_scale, jnp.float32)
+        if cfg.query_scale_beta:
+            trained = cfg.yarn["original_max_position_embeddings"]
+            scale = scale * (1.0 + cfg.query_scale_beta * jnp.log1p(
+                (positions // trained).astype(jnp.float32)))
+        return scale
+
     def _attention(self, lp, li, fi, h_in, positions, cache, block_tables,
                    seq_lens, slot_idx, sel, ctx_blocks, sparse, full):
         cfg = self.config
@@ -584,11 +706,18 @@ class GlmDsaModel:
             q_lat = jnp.concatenate(
                 [jnp.einsum("bshn,rhn->bshr", q[..., :nope], kv_b[..., :nope]),
                  q_pe], axis=-1)
-            row = jnp.concatenate([c_hat, k_pe], axis=-1)
-            latent = latent_cache.write_latent(
-                cache["latent"], li,
-                latent_cache.pack_rows(row.reshape(b * s, -1)),
-                slot_idx.reshape(b * s))
+            row = jnp.concatenate([c_hat, k_pe], axis=-1).reshape(b * s, -1)
+            if cfg.indexed:
+                latent = latent_cache.write_latent(
+                    cache["latent"], li, latent_cache.pack_rows(row),
+                    slot_idx.reshape(b * s))
+            else:
+                latent = latent_cache.write_dense(
+                    cache["latent"], li, row, slot_idx.reshape(b * s))
+                # the softmax scale and the position's factor ride on the
+                # query: the dense kernels know neither
+                q_lat = (q_lat.astype(jnp.float32) * self._query_scale(
+                    positions)[:, :, None, None]).astype(q_lat.dtype)
             cache = {**cache, "latent": latent}
         with jax.named_scope("attn"):
             if full:
@@ -596,7 +725,11 @@ class GlmDsaModel:
                     sel, cache = self._select(
                         lp, fi, x, c_q, positions, cache, block_tables,
                         seq_lens, slot_idx, ctx_blocks, sparse)
-            if sparse:
+            if not cfg.indexed:
+                out = latent_cache.dense_attention(
+                    q_lat, cache["latent"], li, block_tables[:, :ctx_blocks],
+                    positions, seq_lens, dv=r)
+            elif sparse:
                 slots, nvalid = sel[:2]
                 out = sparse_latent_attention(
                     q_lat.reshape(b * s, nh, -1), cache["latent"], li, slots,
@@ -613,16 +746,26 @@ class GlmDsaModel:
             h = h_in + o.reshape(b, s, nh * vd) @ lp["wo"]
         return h, cache, sel
 
-    def _mlp(self, group: dict, lp: dict, i, x, dense: bool):
+    def _mlp(self, group: dict, lp: dict, i, x, dense: bool, valid=None):
+        """(the layer's output, int32 [3] or None: what an expert layer
+        counts for the tokens of ``valid`` [B, S] — None: all — the router's
+        picks, those that fell on the experts held here, and this call)."""
         cfg = self.config
         if dense:
             return (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) \
-                @ lp["w_down"]
+                @ lp["w_down"], None
         b, s, d = x.shape
         xf = x.reshape(b * s, d)
         with jax.named_scope("moe_router"):
             weights, topi = moe_route(cfg, lp["router"], xf,
-                                      lp["router_bias"])
+                                      lp.get("router_bias"))
+            real = (jnp.ones((b * s, 1), bool) if valid is None
+                    else valid.reshape(b * s, 1))
+            here = ((topi >= cfg.expert_first)
+                    & (topi < cfg.expert_first + cfg.n_routed_experts) & real)
+            counted = jnp.stack([
+                real.sum(dtype=jnp.int32) * cfg.num_experts_per_tok,
+                here.sum(dtype=jnp.int32), jnp.int32(1)])
         with jax.named_scope("moe_experts"):
             # the group's whole expert stacks, read where they lie
             routed = grouped_expert_dispatch(
@@ -632,7 +775,7 @@ class GlmDsaModel:
                 held=(cfg.expert_first, cfg.n_routed_experts))
         shared = (jax.nn.silu(xf @ lp["shared_gate"])
                   * (xf @ lp["shared_up"])) @ lp["shared_down"]
-        return (routed + shared).reshape(b, s, d)
+        return (routed + shared).reshape(b, s, d), counted
 
     def forward(self, params, tokens, positions, cache, block_tables,
                 seq_lens, slot_idx, prefix_blocks=None, probe=False):
@@ -648,14 +791,17 @@ class GlmDsaModel:
         m = block_tables.shape[1]
         ctx_blocks = m if prefix_blocks is None else min(
             m, prefix_blocks + -(-s // bs))
-        sparse = b * s <= SPARSE_MAX_QUERIES
+        sparse = cfg.indexed and b * s <= SPARSE_MAX_QUERIES
+        valid = slot_idx >= 0       # a padding token writes no row
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(cfg.jax_dtype)
         if probe and not sparse:
             raise ValueError(f"probe needs at most {SPARSE_MAX_QUERIES} "
                              "query tokens (the gather form)")
         probes = []
-        if sparse:
+        if not cfg.indexed:
+            sel = None
+        elif sparse:
             k_sel = min(cfg.index_topk, ctx_blocks * bs)
             sel = (jnp.zeros((b * s, k_sel), jnp.int32),
                    jnp.zeros((b * s,), jnp.int32),
@@ -682,7 +828,11 @@ class GlmDsaModel:
                     slot_idx, sel, ctx_blocks, sparse, full)
                 with jax.named_scope("mlp"):
                     x = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps)
-                    h = h + self._mlp(group, lp, i, x, dense)
+                    y, counted = self._mlp(group, lp, i, x, dense, valid)
+                    h = h + y
+                    if counted is not None and "moe_counts" in cache:
+                        cache = {**cache, "moe_counts":
+                                 cache["moe_counts"].at[li, 0].add(counted)}
                 seen = (sel[2], sel[3], sel[1]) if probe and full else None
                 return ((h, cache, sel) if full else (h, cache)), seen
 

@@ -130,6 +130,38 @@ def rope_inv_freq(head_dim: int, theta: float,
     return jnp.asarray(inv, jnp.float32)
 
 
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> jax.Array:
+    """YaRN's inverse frequencies [D/2] (transformers'
+    ``_compute_yarn_parameters``, ``truncate`` true).  Pair j turns
+    ``original_max``·f_j / 2π times over the trained context: pairs that
+    turn more than ``beta_fast`` times keep f_j, pairs that turn less than
+    ``beta_slow`` times are divided by ``factor``, and the band between is
+    a ramp over the pair index, from floor(d(beta_fast)) to
+    ceil(d(beta_slow)) with d(r) = D·ln(original_max / 2πr) / (2 ln θ).
+    The latent-attention family's readers call it (models/glm_dsa.py);
+    ``ModelConfig`` still refuses YaRN for the Llama family."""
+    half = head_dim // 2
+    inv = 1.0 / (theta ** (np.arange(0, half, dtype=np.float64) * 2.0
+                           / head_dim))
+
+    def pair_of(turns: float) -> float:
+        return (head_dim * math.log(original_max / (turns * 2.0 * math.pi))
+                / (2.0 * math.log(theta)))
+
+    lo = max(math.floor(pair_of(beta_fast)), 0)
+    hi = min(math.ceil(pair_of(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - lo)
+                   / max(hi - lo, 0.001), 0.0, 1.0)
+    return jnp.asarray(inv * (1.0 - ramp) + inv / factor * ramp, jnp.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """m(s) = 0.1·s·ln(factor) + 1: YaRN's attention temperature."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
                inv_freq: Optional[jax.Array] = None) -> jax.Array:
     """HF-Llama rotate-half RoPE.  x: [B,S,H,D], positions: [B,S]."""

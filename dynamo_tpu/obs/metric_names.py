@@ -132,6 +132,11 @@ class EngineMetric:
     PROMPT_TOKENS_CACHED_TOTAL = "dynamo_tpu_engine_prompt_tokens_cached_total"
     ATTN_CONTEXT_TOKENS_TOTAL = "dynamo_tpu_engine_attn_context_tokens_total"
     ATTN_SELECTED_TOKENS_TOTAL = "dynamo_tpu_engine_attn_selected_tokens_total"
+    # what the expert layers counted on the device (a share of the experts)
+    MOE_ROUTER_PICKS_TOTAL = "dynamo_tpu_engine_moe_router_picks_total"
+    MOE_HELD_PICKS_TOTAL = "dynamo_tpu_engine_moe_held_picks_total"
+    MOE_EXPERT_LAYER_CALLS_TOTAL = (
+        "dynamo_tpu_engine_moe_expert_layer_calls_total")
     # tokens dispatched and the passes of the layer stack run for them
     LOOP_TOKENS_TOTAL = "dynamo_tpu_engine_loop_tokens_total"
     LOOP_PASSES_TOTAL = "dynamo_tpu_engine_loop_passes_total"
@@ -264,6 +269,9 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.PROMPT_TOKENS_CACHED_TOTAL: ("counter", ()),
     EngineMetric.ATTN_CONTEXT_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.ATTN_SELECTED_TOKENS_TOTAL: ("counter", ()),
+    EngineMetric.MOE_ROUTER_PICKS_TOTAL: ("counter", ()),
+    EngineMetric.MOE_HELD_PICKS_TOTAL: ("counter", ()),
+    EngineMetric.MOE_EXPERT_LAYER_CALLS_TOTAL: ("counter", ()),
     EngineMetric.MESH_TP: ("gauge", ()),
     EngineMetric.MESH_DEVICES: ("gauge", ()),
     EngineMetric.LOOP_TOKENS_TOTAL: ("counter", ()),

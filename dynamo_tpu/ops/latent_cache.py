@@ -1,5 +1,8 @@
-"""The two-part paged cache of a latent-attention model with a sparse-attention
-indexer (models/glm_dsa.py), and the XLA forms of attention over it.
+"""The paged cache of a latent-attention model (models/glm_dsa.py), held once,
+and the XLA forms of attention over it.  Two layouts, chosen by what reads the
+rows: a model with a sparse-attention indexer gathers single rows (the two
+parts below), a model that attends to every cached row fetches whole blocks
+(``init_dense_cache``, at the end of this docstring).
 
 ``latent``   uint32 [L, N, Bs, 1, W]: one row a token and layer, held once.
              A row is the token's latent (c_kv ‖ roped k_pe, ``width``
@@ -15,6 +18,17 @@ indexer (models/glm_dsa.py), and the XLA forms of attention over it.
 
 Both index blocks on axis 1 and share the engine's block table, so a block
 id is one block of every layer in both parts, and prefix reuse carries both.
+
+The dense layout (no indexer, so no ``index_k`` and no single-row DMA):
+
+``latent``   bf16 [L, N, Bs, Wd]: the row padded to whole 128-lane groups of
+             bf16 (``dense_row_width``), nothing packed.  A block of one
+             layer is one contiguous [Bs, Wd] tile-aligned DMA for the
+             dense kernels (ops/pallas/mla_dense_attention.py); rows are
+             written by XLA's own scatter, which keeps this plain layout
+             in place (as it does for ``index_k``).  Mistral-Small-4: width
+             320 -> Wd 384, 768 B a token and layer (640 of them data; the
+             word layout above would take 1,024).
 """
 
 from __future__ import annotations
@@ -29,6 +43,7 @@ __all__ = [
     "split_query", "write_rows", "write_latent", "context_rows",
     "sparse_attention_xla", "dense_masked_attention",
     "masked_attention",
+    "dense_row_width", "init_dense_cache", "write_dense", "dense_attention",
     "kernels_on",
 ]
 
@@ -50,6 +65,17 @@ def init_latent_cache(num_layers: int, index_layers: int, num_blocks: int,
         "index_k": jnp.zeros(
             (index_layers, num_blocks, block_size, index_dim), dtype),
     }
+
+
+def dense_row_width(width: int) -> int:
+    """Elements of a dense-layout cache row that holds ``width``."""
+    return LANES * math.ceil(width / LANES)
+
+
+def init_dense_cache(num_layers: int, num_blocks: int, block_size: int,
+                     width: int, dtype):
+    return {"latent": jnp.zeros(
+        (num_layers, num_blocks, block_size, dense_row_width(width)), dtype)}
 
 
 def _pad_to(x: jax.Array, n: int) -> jax.Array:
@@ -132,6 +158,13 @@ def write_rows(part: jax.Array, layer, rows: jax.Array, slots: jax.Array):
     return flat.reshape(part.shape)
 
 
+def write_dense(latent: jax.Array, layer, rows: jax.Array,
+                slots: jax.Array) -> jax.Array:
+    """Write ``rows`` [T, width] of one layer of the dense layout at flat
+    token slots [T] (a negative slot writes nothing)."""
+    return write_rows(latent, layer, _pad_to(rows, latent.shape[-1]), slots)
+
+
 def sparse_attention_xla(q: jax.Array, latent: jax.Array, layer,
                          slots: jax.Array, nvalid: jax.Array,
                          sm_scale: float) -> jax.Array:
@@ -178,26 +211,34 @@ def masked_attention(q: jax.Array, latent: jax.Array, layer,
 
 
 def dense_masked_attention(q: jax.Array, context: jax.Array,
-                           mask: jax.Array, sm_scale: float,
-                           tile_tokens: int = 256) -> jax.Array:
-    """Attention of q [B, S, H, width] over ``context`` [B, C, 2·W] (the
-    unpacked rows of each sequence's first C tokens, ``context_rows``),
-    restricted to ``mask`` [B, S, C] (the selected and causal positions).
-    The context is read a tile at a time with a running softmax, so no
-    [S, H, C] array exists.  Returns f32 [B, S, H, 2·W].  This is how a long
-    prefill chunk attends: every key is scored once for all the chunk's
-    queries on the matrix unit, where a gather would fetch each query's rows
-    separately."""
+                           mask: jax.Array | None, sm_scale: float,
+                           tile_tokens: int = 256, *, positions=None,
+                           seq_lens=None) -> jax.Array:
+    """Attention of q [B, S, H, width] over ``context`` [B, C, D] (the rows
+    of each sequence's first C tokens, zero padded to D), restricted to
+    ``mask`` [B, S, C] (the selected and causal positions) — or, with
+    ``mask`` None, to what is causal alone: key c for the query at
+    ``positions`` [B, S] if c <= its position and c < ``seq_lens`` [B],
+    worked out a tile at a time, so no [S, C] array is built for a layer
+    whose mask is only causality.  The context is read a tile at a time with
+    a running softmax, so no [S, H, C] array exists.  Returns f32
+    [B, S, H, D].  This is how a long prefill chunk attends: every key is
+    scored once for all the chunk's queries on the matrix unit, where a
+    gather would fetch each query's rows separately."""
     b, s, h, _ = q.shape
-    c, w2 = context.shape[1:]
-    w = w2 // 2
-    tk = max(d for d in range(1, min(tile_tokens, c) + 1) if c % d == 0)
-    q = _pad_to(q, 2 * w).astype(jnp.bfloat16)
+    c, d = context.shape[1:]
+    tk = max(n for n in range(1, min(tile_tokens, c) + 1) if c % n == 0)
+    q = _pad_to(q, d).astype(jnp.bfloat16)
 
     def tile(carry, t):
         m, l, acc = carry
         rows = jax.lax.dynamic_slice_in_dim(context, t * tk, tk, axis=1)
-        ok = jax.lax.dynamic_slice_in_dim(mask, t * tk, tk, axis=2)
+        if mask is not None:
+            ok = jax.lax.dynamic_slice_in_dim(mask, t * tk, tk, axis=2)
+        else:
+            at = t * tk + jnp.arange(tk, dtype=jnp.int32)
+            ok = ((at <= positions[:, :, None])
+                  & (at < seq_lens[:, None, None]))
         sc = jnp.einsum("bshd,bkd->bshk", q, rows,
                         preferred_element_type=jnp.float32) * sm_scale
         sc = jnp.where(ok[:, :, None, :], sc, NEG_INF)
@@ -212,6 +253,42 @@ def dense_masked_attention(q: jax.Array, context: jax.Array,
 
     init = (jnp.full((b, s, h, 1), NEG_INF, jnp.float32),
             jnp.zeros((b, s, h, 1), jnp.float32),
-            jnp.zeros((b, s, h, 2 * w), jnp.float32))
+            jnp.zeros((b, s, h, d), jnp.float32))
     (_, l, acc), _ = jax.lax.scan(tile, init, jnp.arange(c // tk))
     return acc / jnp.maximum(l, 1e-9)
+
+
+def dense_attention(q: jax.Array, latent: jax.Array, layer,
+                    block_tables: jax.Array, positions: jax.Array,
+                    seq_lens: jax.Array, dv: int) -> jax.Array:
+    """Causal attention of q [B, S, H, width] — already scaled — over every
+    cached row of each sequence (the dense layout, ``init_dense_cache``):
+    the query at ``positions`` [B, S] sees the rows c <= its position, c <
+    ``seq_lens`` [B], of the blocks ``block_tables`` [B, Mc].  f32
+    [B, S, H, dv'], dv' >= dv whole lane groups: the weighted sum of the
+    rows' first elements.  On the TPU the kernels of
+    ops/pallas/mla_dense_attention.py (``mla_dense_decode`` for one query a
+    row, ``mla_dense_prefill`` a sequence at a time otherwise); else the
+    tiled XLA form, which is also their oracle."""
+    l, n, bs, wd = latent.shape
+    b, s, h, _ = q.shape
+    dvp = -(-dv // LANES) * LANES
+    q = _pad_to(q, wd).astype(latent.dtype)
+    if not kernels_on():
+        context = latent[layer, block_tables].reshape(b, -1, wd)
+        return dense_masked_attention(
+            q, context, None, 1.0, positions=positions,
+            seq_lens=seq_lens)[..., :dvp]
+    from dynamo_tpu.ops.pallas import mla_dense_attention as dense
+
+    flat = latent.reshape(l * n, bs, wd)
+    if s == 1:
+        # a row past its limit has a length at or under its position
+        lens = jnp.minimum(seq_lens, positions[:, 0] + 1)
+        return dense.mla_dense_decode(
+            q[:, 0], flat, block_tables + layer * n, lens, dv=dvp)[:, None]
+    return jnp.stack([
+        dense.mla_dense_prefill(
+            q[i].reshape(s * h, wd), flat, block_tables[i] + layer * n,
+            jnp.stack([positions[i, 0], seq_lens[i]]), heads=h, dv=dvp,
+        ).reshape(s, h, dvp) for i in range(b)])

@@ -252,7 +252,7 @@ def _abstract_model(config_file: str, place, num_blocks=None, **overrides):
 
     shapes = jax.eval_shape(lambda: model.init_params(jax.random.key(0)))
     specs = (jax.tree.map(lambda a: P(), shapes)      # GLM: one chip, no specs
-             if getattr(model, "two_part_cache", False)
+             if getattr(model, "private_cache_layout", False)
              else model.partition_specs())
     params = placed(shapes, specs)
     cache = placed(jax.eval_shape(lambda: model.init_kv_cache(num_blocks, BS)),
@@ -436,6 +436,8 @@ _SCAN_PROGRAMS = {       # configuration file, program, chips, overrides
     "qwen3-30b-a3b-decode": ("qwen3-30b-a3b.json", "decode", 1, _TWO_LAYERS),
     "mistral-7b-tp4-decode": ("mistral-7b-tp4.json", "decode", 4, _TWO_LAYERS),
     "glm-5.2-ep16-decode": ("glm-5.2-ep16.json", "decode", 1, {}),
+    "mistral-small-4-ep8-decode": ("mistral-small-4-ep8.json", "decode", 1,
+                                   _TWO_LAYERS),
     "ouro-2.6b-decode": ("ouro-2.6b.json", "decode", 1, _TWO_LAYERS),
     "ouro-2.6b-prefill": ("ouro-2.6b.json", "prefill", 1, _TWO_LAYERS),
     # not a cell: the looped model sharded as any LlamaModel (docs/looped_layers.md)
@@ -721,3 +723,71 @@ def test_latent_cache_movers_compile_and_copy_no_cache(glm_sds, tpu_gate):
         latent, glm_sds((), jnp.int32), glm_sds((1, 1088), jnp.int32)).compile()
     assert "latent_cache_gather_blocks" in read.as_text()
     assert read.memory_analysis().temp_size_in_bytes < cache_bytes / 10
+
+
+# ---------------------------------------------------------------------------
+# Mistral-Small-4 (cellbench/configs/mistral-small-4-ep8.json): the dense
+# kernels over the latent cache held once, at the published widths (32 heads,
+# rows of 320 elements in 384 lanes) and the cell's pool (14,400 blocks of 32
+# in 9 layers), and the cell's whole programs at its own ``serve`` geometry.
+M4 = dict(h=32, wd=384, dv=256, layers=9, blocks=14400)
+
+
+def test_dense_latent_decode_compiles_on_one_chip(glm_sds):
+    from dynamo_tpu.ops.pallas.mla_dense_attention import mla_dense_decode
+
+    g = M4
+    compiled = jax.jit(functools.partial(mla_dense_decode, dv=g["dv"])).lower(
+        glm_sds((32, g["h"], g["wd"]), jnp.bfloat16),
+        glm_sds((g["layers"] * g["blocks"], BS, g["wd"]), jnp.bfloat16),
+        glm_sds((32, 1152), jnp.int32), glm_sds((32,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_dense_decode" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("s,blocks", [(2048, 1088), (256, 1032), (32, 1)],
+                         ids=["chunk", "question", "one-block"])
+def test_dense_latent_prefill_compiles_on_one_chip(glm_sds, s, blocks):
+    from dynamo_tpu.ops.pallas.mla_dense_attention import mla_dense_prefill
+
+    g = M4
+    compiled = jax.jit(functools.partial(
+        mla_dense_prefill, heads=g["h"], dv=g["dv"])).lower(
+            glm_sds((s * g["h"], g["wd"]), jnp.bfloat16),
+            glm_sds((g["layers"] * g["blocks"], BS, g["wd"]), jnp.bfloat16),
+            glm_sds((blocks,), jnp.int32), glm_sds((2,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "mla_dense_prefill" in text and "latent_cache_gather_blocks" in text
+
+
+@pytest.mark.parametrize("program,chunk,prefix", [
+    ("decode", None, 1), ("prefill", 256, 1024), ("prefill", 2048, 1024)],
+    ids=["decode", "question-256", "chunk-2048"])
+def test_mistral4_cell_programs_keep_one_cache_and_name_their_kernels(
+        topo, tpu_gate, program, chunk, prefix):
+    """The cell's decode program, a question behind the longest document and
+    a document's last chunk, whole (9 layers, 16 experts, the cell's cache):
+    the dense kernel by name, one layer scan, the cache donated and written
+    in place by XLA's own scatter (no second copy, no re-layout), and
+    weights + cache + temporaries inside the chip (11.7-11.8 GB)."""
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "mistral-small-4-ep8.json",
+        lambda spec: SingleDeviceSharding(topo.devices[0]))
+    serve = dict(hf["serve"])
+    if chunk:
+        serve["prefill_chunk_tokens"] = chunk
+    fn, args = _step_program(program, model, serve, sds, prefix_blocks=prefix)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    assert f"mla_dense_{program}" in hlo and hlo.count(" while(") == 1
+    assert "ragged-dot" in hlo
+    mem = compiled.memory_analysis()
+    cache_bytes = cache["latent"].size * 2
+    assert cache_bytes == 14400 * 32 * 9 * 768
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 16, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0.6 * V5E_HBM < total < 0.75 * V5E_HBM, total
