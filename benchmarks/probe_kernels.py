@@ -63,15 +63,21 @@ def time_topk() -> None:
 
 # The decode kernel alone at the benchmark cells' geometries (rows, query
 # heads, kv heads; head_dim 128, block 32, a table of 128 blocks) with the
-# tiling ``decode_tiling`` gives each, and two more to read (G, C) by.
+# tiling ``decode_tiling`` gives each, and others (G, C[, R]) to read it by:
+# the rule's chunk with one sequence an update (R 1: a branch a row, as
+# every kernel until PR 47 had it), at 256 and 512 lanes the (8, 4) every
+# geometry had until then, so one run prints the row-chunk of 4 blocks
+# beside the one sized by its bytes, and the batched update beside both.
 DECODE_GEOMS = {
     "mistral-7b 32x32x1024": (32, 32, 8),
     "mistral-7b-tp4 shard 64x8x256": (64, 8, 2),
     "ouro-2.6b 16x16x2048": (16, 16, 16),
     "qwen3-30b-a3b 32x32x512": (32, 32, 4),
 }
-DECODE_TILINGS = {"ouro-2.6b 16x16x2048": [(4, 2)],
-                  "mistral-7b 32x32x1024": [(4, 4)]}
+DECODE_TILINGS = {"ouro-2.6b 16x16x2048": [(8, 2, 1), (4, 2)],
+                  "mistral-7b 32x32x1024": [(8, 4, 1), (4, 4)],
+                  "mistral-7b-tp4 shard 64x8x256": [(8, 16, 1), (8, 4, 1)],
+                  "qwen3-30b-a3b 32x32x512": [(8, 8, 1), (8, 4, 1)]}
 
 
 def decode_length_mixes(rows: int, rng) -> dict:
@@ -101,6 +107,7 @@ def time_decode_lengths(out_path: str | None) -> None:
     this file copied over the parent of PR 40, the same table reads the
     kernel that fetched every row up to its group's longest.)"""
     import glob
+    import inspect
     import json
     import tempfile
 
@@ -113,6 +120,10 @@ def time_decode_lengths(out_path: str | None) -> None:
     from dynamo_tpu.ops.pallas.registry import decode_tiling
 
     d, bs, m, calls = 128, 32, 128, 20
+    # a kernel from before PR 47 (this file copied over its checkout) takes
+    # one sequence an update and has no word for it
+    batches = "seqs_per_update" in inspect.signature(
+        da.paged_decode_attention_mq.__wrapped__).parameters
     print(f"# device {jax.devices()[0].device_kind}")
 
     def kernel_us(fn, args) -> float:
@@ -138,7 +149,8 @@ def time_decode_lengths(out_path: str | None) -> None:
         hkd = hk * d
         rng = np.random.default_rng(40)
         mixes = decode_length_mixes(rows, rng)
-        tilings = [decode_tiling(h, hkd, bs)] + DECODE_TILINGS.get(geom, [])
+        tilings = list(dict.fromkeys(
+            [decode_tiling(h, hkd, bs)] + DECODE_TILINGS.get(geom, [])))
         n = max(int((-(-x // bs)).sum()) for x in mixes.values()) + 1
         kq, kc = jax.random.split(jax.random.key(40))
         cache = jax.random.normal(kc, (1, n, 2, bs, hkd), jnp.bfloat16)
@@ -154,13 +166,18 @@ def time_decode_lengths(out_path: str | None) -> None:
             useful = 2 * (2 * hkd * int(lens.sum())
                           + 2 * h * d * int((lens > 0).sum()))
             grouped = np.argsort(-lens, kind="stable")
-            for g, c in tilings:
-                fn = jax.jit(lambda q, cache, bt, lens, g=g, c=c:
+            for g, c, *r in tilings:
+                kw = dict(seqs_per_group=g, blocks_per_chunk=c)
+                if r and batches:  # sequences an update; else the kernel's
+                    kw["seqs_per_update"] = r[0]
+                elif r and r[0] != 1:
+                    continue
+                fn = jax.jit(lambda q, cache, bt, lens, kw=kw:
                              da.paged_decode_attention_mq(
                                  q, cache, jnp.int32(0), bt, lens, lens - 1,
-                                 seqs_per_group=g, blocks_per_chunk=c))
+                                 **kw))
                 row = {"geometry": geom, "lengths": mix, "g": g, "c": c,
-                       "useful_bytes": useful}
+                       "r": r[0] if r else None, "useful_bytes": useful}
                 for label, o in (("slot_order", np.arange(rows)),
                                  ("by_length", grouped)):
                     us = kernel_us(fn, (q[o], cache, jnp.asarray(bt[o]),

@@ -593,7 +593,8 @@ def _pallas_kernel_estimate(ep_name: str, sig: Signature) \
         bs, hkd = cache.shape[3], cache.shape[4]
         cost = kreg.decode_kernel_cost(
             b, s_q, h, hkd // d, d, bs, bt.shape[1],
-            [bt.shape[1] * bs] * b, cache_bytes=cache.dtype.itemsize)
+            [bt.shape[1] * bs] * b, cache_bytes=cache.dtype.itemsize,
+            q_bytes=q.dtype.itemsize)
     else:  # ops.ragged_prefill_attention
         q, _, _, cache, _, bt = sig.args[:6]
         _, t, h, d = q.shape

@@ -247,7 +247,7 @@ def paged_attention_layer(
             # tuning knobs for on-chip sweeps (benchmarks/profile_decode.py):
             # group size trades per-grid-step fixed cost against VMEM.
             # Unset, the kernel takes the tiling its geometry allows
-            # (registry.decode_tiling: 8 and 4 where the scratch fits)
+            # (registry.decode_tiling: 8 rows a group, ~512 KiB a row-chunk)
             spg = int(os.environ.get("DYNAMO_DECODE_SEQS_PER_GROUP", 0)) or None
             bpc = int(os.environ.get("DYNAMO_DECODE_BLOCKS_PER_CHUNK", 0)) or None
             kernel = functools.partial(

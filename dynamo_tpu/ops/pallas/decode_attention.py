@@ -33,10 +33,27 @@ Design (one grid step per GROUP of G sequences, work ∝ each row's context):
     come from the scalar-prefetched block table in SMEM; the layer is a
     scalar operand, so per-layer K/V is never sliced out.
   * GQA is handled by expanding q to a block-diagonal [H, Hk*D] layout
-    outside the kernel: scores and the PV product are then plain MXU
-    matmuls with no per-head lane slicing.  The extra zeros cost FLOPs the
-    decode step has to spare (it is bandwidth/latency-bound).
+    outside the kernel, in the query's own dtype and unscaled: scores and
+    the PV product are then plain MXU matmuls with no per-head lane
+    slicing.
+  * Both matmuls take their operands as the cache holds them (bf16 when
+    serving; an int8 block is widened to the query's dtype, which holds
+    every int8 value) and accumulate in float32: a bf16 x bf16 product is
+    exact in float32, ``sm_scale``, the int8 K scale, the soft cap and the
+    masks act on the float32 scores.  The float32 probabilities (times the
+    int8 V scale) go to the matrix unit as a bf16 head and a bf16
+    remainder stacked over the same V tile, so they keep ~16 bits; with a
+    float32 cache (tests) the operands are float32 and nothing is split.
+  * A row-chunk is ~512 KiB of K/V whatever the row's width
+    (``registry.decode_tiling``): what a row-chunk costs beside its DMA is
+    paid once a chunk.
   * Online softmax (flash) accumulation in VMEM scratch across chunks.
+    The update of a chunk takes R sequences of the group at once
+    (``registry.decode_seqs_per_update``): one batched pair of matmuls in
+    one basic block, so that R serial chains (matmul, max, exp, sum,
+    matmul, accumulator) fill one another's latencies - a branch a row
+    left the unit idle between them.  An update is skipped when all its R
+    sequences have ended; one that ended before the others runs masked.
 
 Semantics match `paged_attention` with S=1: each query row attends over
 slots [0, seq_len) of its own block table.  Rows with seq_len == 0 yield 0.
@@ -59,6 +76,7 @@ from dynamo_tpu.ops.paged_attention import softcap
 from dynamo_tpu.ops.pallas.registry import (
     decode_cost_estimate,
     decode_group_and_chunk,
+    decode_seqs_per_update,
     decode_tiling,
 )
 
@@ -74,7 +92,7 @@ def _kernel(
     bt_ref,      # [B, M] int32
     layer_ref,   # [1] int32
     # inputs
-    q_ref,       # [G, S*H, HkD] VMEM — block-diagonal expanded, pre-scaled f32
+    q_ref,       # [G, S*H, HkD] VMEM — block-diagonal expanded, q's dtype
     cache_ref,   # [L, N, 2, Bs, HkD] HBM (manual DMA)
     # (scale_ref [L, N, 2, Hp, Sp] HBM when quant — spliced via *rest)
     # outputs
@@ -86,27 +104,19 @@ def _kernel(
     kvbuf,       # [2, G, C, 2, Bs, HkD] cache-dtype (double buffer)
     sems,        # [2, G, C] DMA semaphores
     # (scbuf [2, G, C, 2, Hp, Sp] f32 + scsems when quant)
-    *,
-    c: int,
-    g: int,
-    s_q: int,
-    hk: int,
-    logit_cap=None,
+    **static,    # c, g, r, s_q, hk, sm_scale, logit_cap
 ):
     return _kernel_impl(seq_ref, q0_ref, bt_ref, layer_ref, q_ref, cache_ref,
                         None, out_ref, acc_ref, m_ref, l_ref, kvbuf, sems,
-                        None, None, c=c, g=g, s_q=s_q, hk=hk,
-                        logit_cap=logit_cap)
+                        None, None, **static)
 
 
 def _kernel_quant(seq_ref, q0_ref, bt_ref, layer_ref, q_ref, cache_ref,
                   scale_ref, out_ref, acc_ref, m_ref, l_ref, kvbuf, sems,
-                  scbuf, scsems, *, c: int, g: int, s_q: int, hk: int,
-                  logit_cap=None):
+                  scbuf, scsems, **static):
     return _kernel_impl(seq_ref, q0_ref, bt_ref, layer_ref, q_ref, cache_ref,
                         scale_ref, out_ref, acc_ref, m_ref, l_ref, kvbuf,
-                        sems, scbuf, scsems, c=c, g=g, s_q=s_q, hk=hk,
-                        logit_cap=logit_cap)
+                        sems, scbuf, scsems, **static)
 
 
 def _kernel_impl(
@@ -115,8 +125,10 @@ def _kernel_impl(
     *,
     c: int,
     g: int,
+    r: int,
     s_q: int,
     hk: int,
+    sm_scale: float,
     logit_cap=None,
 ):
     gi = pl.program_id(0)
@@ -125,6 +137,11 @@ def _kernel_impl(
     t = c * bs
     lyr = layer_ref[0]
     quant = scale_ref is not None
+    # what both matmuls take: the cache's dtype as it lies in VMEM (bf16
+    # when serving); int8 blocks are widened to the query's dtype
+    op_dt = q_ref.dtype if quant else jnp.promote_types(q_ref.dtype,
+                                                        kvbuf.dtype)
+    split_p = jnp.dtype(op_dt).itemsize < 4
 
     seq = [seq_ref[gi * g + j] for j in range(g)]
     # group-wide chunk bound: max seq_len among the G sequences
@@ -176,32 +193,39 @@ def _kernel_impl(
 
         block_dmas(ci, slot, wait=True)
 
-        for j in range(g):  # static unroll: one flash update per sequence
-            seq_len = seq[j]
+        rows = s_q * h
+        for j0 in range(0, g, r):  # static unroll: R sequences an update
+            js = range(j0, j0 + r)
+            live = functools.reduce(jnp.maximum, [seq[j] for j in js])
 
-            # skip chunks past THIS sequence's end (and zero-length rows:
-            # their acc/l stay 0 → output 0)
-            @pl.when(ci * t < seq_len)
-            def _update(j=j, seq_len=seq_len):
-                q = q_ref[j]  # [S*H, HkD]
-                k = kvbuf[slot, j, :, 0].reshape(t, hkd).astype(jnp.float32)
-                v = kvbuf[slot, j, :, 1].reshape(t, hkd).astype(jnp.float32)
+            # skip chunks past the end of all R sequences (and zero-length
+            # rows: their acc/l stay 0 -> output 0).  A sequence that ended
+            # before the others of its update runs it fully masked.
+            @pl.when(ci * t < live)
+            def _update(j0=j0, js=js):
+                jsl = slice(j0, j0 + r)
+                q = q_ref[jsl].astype(op_dt)  # [R, S*H, HkD]
+                k = kvbuf[slot, jsl, :, 0].reshape(r, t, hkd).astype(op_dt)
+                v = kvbuf[slot, jsl, :, 1].reshape(r, t, hkd).astype(op_dt)
 
                 # Slots at/past seq_len hold whatever the pool holds (pad
                 # lanes of a live block) or whatever the scratch held (a
                 # block of the chunk the row does not own is not copied:
                 # stale VMEM, any bit pattern).  Both are SELECTED away,
                 # never multiplied: the score mask below picks NEG_INF for
-                # their columns of s, and because 0 * garbage-V is still
-                # garbage when V is non-finite, their V rows (and the V
-                # scales) are picked to 0 here.  Keep the two `jnp.where`s.
+                # their columns of s and 0 for their columns of p, and
+                # because 0 * garbage-V is still garbage when V is
+                # non-finite, their V rows (and the V scales) are picked to
+                # 0 here.  Keep the `jnp.where`s.
                 slot_pos = ci * t + jax.lax.broadcasted_iota(
                     jnp.int32, (t, 1), 0)
-                v = jnp.where(slot_pos < seq_len, v, 0.0)
+                v = jnp.where(jnp.stack([slot_pos < seq[j] for j in js]),
+                              v, jnp.zeros_like(v))
 
                 s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-                )  # [H, T]
+                    q, k, (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32
+                ) * sm_scale  # [R, S*H, T] f32
                 if quant:
                     # int8 KV: k rows carry a per-(token, kv-head) scale.
                     # Column t of s uses k row t whose scale depends on the
@@ -212,45 +236,53 @@ def _kernel_impl(
                     # kv head's row for its G query heads (q rows are
                     # kv-head-major).  V's scale folds into P before the PV
                     # matmul (not into l: softmax stats use true probs).
-                    gq = h // hk
-                    sck = jnp.concatenate(
-                        [scbuf[slot, j, i, 0][:hk, :bs] for i in range(c)],
-                        axis=-1
-                    )  # [Hk, T]
-                    scv = jnp.concatenate(
-                        [scbuf[slot, j, i, 1][:hk, :bs] for i in range(c)],
-                        axis=-1
-                    )
-                    sck = jnp.repeat(sck, gq, axis=0)  # [H, T]
-                    scv = jnp.repeat(scv, gq, axis=0)
-                    if s_q > 1:  # row layout is (query, head)-major
-                        sck = jnp.concatenate([sck] * s_q, axis=0)
-                        scv = jnp.concatenate([scv] * s_q, axis=0)
-                    s = s * sck
+                    def scales(j, kv):
+                        sc = jnp.concatenate(
+                            [scbuf[slot, j, i, kv][:hk, :bs]
+                             for i in range(c)], axis=-1)     # [Hk, T]
+                        sc = jnp.repeat(sc, h // hk, axis=0)  # [H, T]
+                        # row layout is (query, head)-major
+                        return jnp.concatenate([sc] * s_q, axis=0)
+
+                    s = s * jnp.stack([scales(j, 0) for j in js])
+                    scv = jnp.stack([scales(j, 1) for j in js])
                 if logit_cap is not None:  # Gemma2 attention softcap
                     s = softcap(s, logit_cap)
-                rows = s_q * h
                 pos = ci * t + jax.lax.broadcasted_iota(jnp.int32, (rows, t), 1)
                 # causal per query: query sq (row sq*H + h) sits at absolute
                 # position q0 + sq and sees cache slots <= that position
-                q_pos = q0_ref[gi * g + j] + (
-                    jax.lax.broadcasted_iota(jnp.int32, (rows, t), 0) // h
-                )
-                s = jnp.where((pos <= q_pos) & (pos < seq_len), s, NEG_INF)
+                sq = jax.lax.broadcasted_iota(jnp.int32, (rows, t), 0) // h
+                owned_pos = jnp.stack([pos < seq[j] for j in js])
+                seen = owned_pos & jnp.stack(
+                    [pos <= q0_ref[gi * g + j] + sq for j in js])
+                s = jnp.where(seen, s, NEG_INF)
+
+                m_prev = m_ref[jsl, :, :1]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # a row with no column to see yet keeps m = NEG_INF, and
+                # exp(NEG_INF - NEG_INF) is 1: select, do not trust the exp
+                p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+                l_ref[jsl] = l_ref[jsl] * alpha + jnp.sum(
+                    p, axis=2, keepdims=True)
+                m_ref[jsl] = jnp.broadcast_to(m_new, (r,) + m_ref.shape[1:])
                 if quant:
                     # dead-slot V scales may be non-finite (pad lanes of
                     # the scale tile) — see the V zeroing above
-                    scv = jnp.where(pos < seq_len, scv, 0.0)
-
-                m_prev = m_ref[j, :, :1]
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-                alpha = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)
-                l_ref[j] = l_ref[j] * alpha + jnp.sum(p, axis=1, keepdims=True)
-                m_ref[j] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-                pv = jnp.dot(p * scv if quant else p, v,
-                             preferred_element_type=jnp.float32)
-                acc_ref[j] = acc_ref[j] * alpha + pv
+                    p = p * jnp.where(owned_pos, scv, 0.0)
+                if split_p:
+                    # a bf16 head and a bf16 remainder of the f32 weights,
+                    # stacked over the same V tile: ~16 bits of p reach
+                    # the accumulator (stacked in f32, where a row is a
+                    # whole sublane tile, then narrowed once)
+                    head = p.astype(op_dt).astype(jnp.float32)
+                    p = jnp.concatenate([head, p - head], axis=1)
+                pv = jax.lax.dot_general(
+                    p.astype(op_dt), v, (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)
+                if split_p:
+                    pv = pv[:, :rows] + pv[:, rows:]
+                acc_ref[jsl] = acc_ref[jsl] * alpha + pv
         return 0
 
     jax.lax.fori_loop(0, num_chunks, body, 0)
@@ -290,7 +322,7 @@ def paged_decode_attention(
 @functools.partial(
     jax.jit,
     static_argnames=("sm_scale", "logit_cap", "blocks_per_chunk",
-                     "seqs_per_group", "interpret"),
+                     "seqs_per_group", "seqs_per_update", "interpret"),
 )
 def paged_decode_attention_mq(
     q: jax.Array,             # [B, S, H, D] — S contiguous trailing queries
@@ -303,6 +335,7 @@ def paged_decode_attention_mq(
     logit_cap: float | None = None,
     blocks_per_chunk: int | None = None,
     seqs_per_group: int | None = None,
+    seqs_per_update: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Multi-query flash decode: S queries per row (query j at position
@@ -312,9 +345,10 @@ def paged_decode_attention_mq(
     Rows whose real query count is < S put padding at the tail; their
     outputs are finite garbage the caller discards.
 
-    ``seqs_per_group`` / ``blocks_per_chunk`` left None follow the
-    geometry (``registry.decode_tiling``): 8 and 4 wherever the kernel's
-    scratch fits, less where Hk*D is wide (plain multi-head attention)."""
+    ``seqs_per_group`` / ``blocks_per_chunk`` / ``seqs_per_update`` left
+    None follow the geometry (``registry.decode_tiling``: 8 rows a group
+    and ~512 KiB of K/V a row-chunk, less where the kernel's scratch would
+    not fit; ``registry.decode_seqs_per_update``)."""
     from dynamo_tpu.ops.kv_quant import is_quant
 
     quant = is_quant(cache)
@@ -332,14 +366,15 @@ def paged_decode_attention_mq(
     seqs_per_group = seqs_per_group or spg
     blocks_per_chunk = blocks_per_chunk or bpc
     g, c = decode_group_and_chunk(b, s_q, m, seqs_per_group, blocks_per_chunk)
+    r = decode_seqs_per_update(g, c, bs, seqs_per_update)
 
     # Block-diagonal q expansion: row for (query sq, head (k, gh)) lives in
     # kv-head k's D-wide column slot; zeros elsewhere.  [B, S, H, D] ->
-    # [B, S*H, Hk*D] f32, columns ordered (kv_head, d) to match the cache.
-    qf = q.astype(jnp.float32) * sm_scale
-    eye = jnp.eye(hk, dtype=jnp.float32)
+    # [B, S*H, Hk*D] in q's dtype, unscaled (the kernel scales the f32
+    # scores), columns ordered (kv_head, d) to match the cache.
     q_exp = jnp.einsum("bskgd,ke->bskged",
-                       qf.reshape(b, s_q, hk, g_heads, d), eye)
+                       q.reshape(b, s_q, hk, g_heads, d),
+                       jnp.eye(hk, dtype=q.dtype))
     q_exp = q_exp.reshape(b, rows, hkd)
 
     in_specs = [
@@ -382,11 +417,13 @@ def paged_decode_attention_mq(
     # worst case (every row at full-table context).
     cost = decode_cost_estimate(
         b, s_q, h, hk, d, bs, m, cache_bytes=data.dtype.itemsize,
-        quant=quant, blocks_per_chunk=blocks_per_chunk)
+        quant=quant, blocks_per_chunk=blocks_per_chunk,
+        q_bytes=q.dtype.itemsize)
 
     out = pl.pallas_call(
         functools.partial(_kernel_quant if quant else _kernel, c=c, g=g,
-                          s_q=s_q, hk=hk, logit_cap=logit_cap),
+                          r=r, s_q=s_q, hk=hk, sm_scale=sm_scale,
+                          logit_cap=logit_cap),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, hkd), q.dtype),
         interpret=interpret,

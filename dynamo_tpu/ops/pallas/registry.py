@@ -35,6 +35,11 @@ import math
 __all__ = [
     "DECODE_BLOCKS_PER_CHUNK",
     "DECODE_SEQS_PER_GROUP",
+    "DECODE_CHUNK_BYTES",
+    "DECODE_MAX_BLOCKS_PER_CHUNK",
+    "DECODE_MAX_DMA_SITES",
+    "DECODE_SEQS_PER_UPDATE",
+    "DECODE_KEYS_PER_UPDATE_SEQ",
     "PREFILL_ROWS_PER_CHUNK",
     "PREFILL_BLOCKS_PER_CHUNK",
     "INT8_MATMUL_BM",
@@ -50,6 +55,7 @@ __all__ = [
     "decode_vmem_bytes",
     "decode_tiling",
     "decode_group_and_chunk",
+    "decode_seqs_per_update",
     "KERNELS",
     "audit_cases",
     "fuzz_case",
@@ -70,12 +76,23 @@ __all__ = [
 ]
 
 # ------------------------------------------------------- tile constants ----
-# The serving tile sizes.  decode: 4 blocks per DMA chunk x 8 sequences
-# per grid step fits the 8B bf16 KV working set; prefill: 128 query rows
-# per grid step keeps acc/m/l scratch + the VMEM-resident fresh K/V well
-# inside VMEM at S=2048.  int8_matmul: MXU-shaped (128, 512, 512).
+# The serving tile sizes.  decode: 8 sequences per grid step, and a
+# row-chunk of ~512 KiB of K/V whatever the row's width (``decode_tiling``:
+# 4 blocks of 32 tokens at 1,024 bf16 lanes, 16 at 256, never more than 16
+# blocks, nor more than 128 unrolled DMA sites a chunk: the group gives
+# way); 4 blocks is what the cost model prices when it is not told.
+# prefill: 128 query rows per grid step keeps acc/m/l scratch + the
+# VMEM-resident fresh K/V well inside VMEM at S=2048.  int8_matmul:
+# MXU-shaped (128, 512, 512).
 DECODE_BLOCKS_PER_CHUNK = 4
 DECODE_SEQS_PER_GROUP = 8
+DECODE_CHUNK_BYTES = 512 * 1024
+DECODE_MAX_BLOCKS_PER_CHUNK = 16
+DECODE_MAX_DMA_SITES = 128
+# sequences one flash update takes at once (``decode_seqs_per_update``),
+# and the keys of a chunk each of them asks for
+DECODE_SEQS_PER_UPDATE = 4
+DECODE_KEYS_PER_UPDATE_SEQ = 32
 PREFILL_ROWS_PER_CHUNK = 128
 PREFILL_BLOCKS_PER_CHUNK = 8
 INT8_MATMUL_BM = 128
@@ -240,27 +257,43 @@ def decode_vmem_bytes(g: int, c: int, rows: int, hkd: int, bs: int,
     a group and C blocks a chunk: the double-buffered K/V scratch
     ``(2, G, C, 2, Bs, Hk*D)`` (all of it reserved, though a row's copies
     fill only the blocks it owns), the f32 accumulator and the m / l
-    statistics, the pipelined q (f32) and output blocks, and one chunk's
-    keys and values upcast to f32 for the two matmuls."""
+    statistics, the pipelined q and output blocks (both in the query's
+    dtype), and one chunk's keys and values of the sequences of one update
+    as the two matmuls take them (the cache's own dtype; an int8 block
+    widened to the query's)."""
     kvbuf = 2 * g * c * 2 * bs * hkd * cache_bytes
     acc_ml = g * rows * (hkd + 2 * 128) * 4
-    io = DOUBLE_BUFFER * g * rows * hkd * (4 + q_bytes)
-    upcast = 2 * c * bs * hkd * 4
-    return kvbuf + acc_ml + io + upcast
+    io = DOUBLE_BUFFER * g * rows * hkd * 2 * q_bytes
+    operands = (decode_seqs_per_update(g, c, bs)
+                * 2 * c * bs * hkd * max(cache_bytes, q_bytes))
+    return kvbuf + acc_ml + io + operands
 
 
 def decode_tiling(rows: int, hkd: int, bs: int, cache_bytes: int = 2,
                   q_bytes: int = 2) -> tuple[int, int]:
     """(seqs_per_group, blocks_per_chunk) of the flash-decode kernel for a
-    geometry: the serving defaults (8, 4) wherever their scratch fits the
-    scoped VMEM of one kernel, else halved until it does — the blocks of a
-    chunk first (a block of 2,048 lanes is a 256 KiB DMA already, and the
-    matmuls of a row's last chunk, which take the whole chunk, waste less
-    of a shorter one), then the group.  What is fetched does not depend on
-    either: a row copies its own ceil(len / Bs) blocks.  ``rows`` = query
-    rows a sequence (heads, one query each), ``hkd`` = Hk*D lanes of a
-    cache row."""
-    g, c = DECODE_SEQS_PER_GROUP, DECODE_BLOCKS_PER_CHUNK
+    geometry.  A row-chunk holds ``DECODE_CHUNK_BYTES`` of K/V: what a
+    row-chunk costs beside its DMA (two matmuls' latencies, max, exp, sum,
+    the m / l / accumulator read-modify-write, a branch) is paid once a
+    chunk, so a chunk is sized by its bytes and not by its blocks - 4
+    blocks of 32 tokens at 1,024 bf16 lanes, 8 at 512, 16 at 256, 2 at
+    2,048.  The unrolled DMA sites of a chunk (G x C, twice that for an
+    int8 cache, whose blocks bring a scale tile each; every site has a
+    semaphore in each buffer, of which a kernel may hold ~500) stay within
+    ``DECODE_MAX_DMA_SITES``, the group giving way, and the scratch within
+    the scoped VMEM of one kernel: halved until it fits - the blocks of a
+    chunk first (the matmuls of a row's last chunk, which take the whole
+    chunk, waste less of a shorter one), then the group.  What is fetched
+    does not depend on either: a row copies its own ceil(len / Bs) blocks.
+    ``rows`` = query rows a sequence (heads, one query each), ``hkd`` =
+    Hk*D lanes of a cache row."""
+    block_bytes = 2 * bs * hkd * cache_bytes
+    g = DECODE_SEQS_PER_GROUP
+    c = max(1, min(DECODE_CHUNK_BYTES // block_bytes,
+                   DECODE_MAX_BLOCKS_PER_CHUNK))
+    copies = 2 if cache_bytes == 1 else 1  # int8: a scale tile a block
+    while g * c * copies > DECODE_MAX_DMA_SITES and g > 1:
+        g //= 2
     while (decode_vmem_bytes(g, c, rows, hkd, bs, cache_bytes, q_bytes)
            > SCOPED_VMEM_BYTES and (g > 1 or c > 1)):
         if c > 1 and c * 2 >= g:
@@ -282,16 +315,37 @@ def decode_group_and_chunk(b: int, s_q: int, m: int, seqs_per_group: int,
     return g, min(blocks_per_chunk, m)
 
 
+def decode_seqs_per_update(g: int, c: int, bs: int,
+                           asked: int | None = None) -> int:
+    """R, the sequences of a group whose flash update of a chunk is ONE
+    batched pair of matmuls in one basic block: a row-chunk's update is a
+    serial chain (matmul, max, exp, sum, matmul, accumulator) whose
+    latencies only another sequence's chain can fill, so R chains run
+    side by side.  The R sequences run to the longest's last chunk, the
+    others masked, and a chunk of few keys drags a shorter sequence
+    through more of them: R is ``DECODE_SEQS_PER_UPDATE`` where a chunk
+    holds at least ``DECODE_KEYS_PER_UPDATE_SEQ`` keys for each, less
+    below (4 at 128 keys and over, 2 at the 64 of 2,048 lanes) - or what
+    a sweep ``asked`` for - and divides the group."""
+    r = asked or min(DECODE_SEQS_PER_UPDATE,
+                     c * bs // DECODE_KEYS_PER_UPDATE_SEQ)
+    r = max(1, min(r, g))
+    while g % r:
+        r -= 1
+    return r
+
+
 def decode_kernel_cost(
     b: int, s_q: int, h: int, hk: int, d: int, bs: int, m: int,
-    lens, cache_bytes: int = 2, quant: bool = False, q_bytes: int = 4,
+    lens, cache_bytes: int = 2, quant: bool = False, q_bytes: int = 2,
     blocks_per_chunk: int = DECODE_BLOCKS_PER_CHUNK,
 ) -> dict:
     """Analytic cost of one flash-decode dispatch: every row's own blocks
     by DMA (ceil(len / Bs) of them: a block is copied only if the row owns
-    it, nothing for an empty slot), blocked q/out traffic, and QK+PV FLOPs
-    and softmax exps over the chunks a row computes (ceil(len / (C*Bs)):
-    the two matmuls take a whole chunk).  ``lens`` is the per-row context;
+    it, nothing for an empty slot), the blocked block-diagonal q in and
+    the output back (both ``q_bytes`` an element), and QK+PV FLOPs and
+    softmax exps over the chunks a row computes (ceil(len / (C*Bs)): the
+    two matmuls take a whole chunk).  ``lens`` is the per-row context;
     pass ``[m * bs] * b`` for the worst-case static bound
     (cost_estimate=)."""
     hkd = hk * d
@@ -307,7 +361,7 @@ def decode_kernel_cost(
     blocks = sum(_cdiv(n, bs) for n in lens)
     chunks = sum(_cdiv(n, t) for n in lens)
     dma = blocks * block_bytes
-    dma += b * rows * hkd * (4 + q_bytes)  # q (f32) in + out
+    dma += b * rows * hkd * 2 * q_bytes  # q in + out
     return _cost_dict(dma, chunks * 4 * rows * t * hkd,  # QK + PV matmuls
                       chunks * rows * t)                 # softmax exp
 
@@ -460,13 +514,13 @@ def _cost_estimate(cost: dict):
 
 
 def decode_cost_estimate(b, s_q, h, hk, d, bs, m, cache_bytes, quant,
-                         blocks_per_chunk):
+                         blocks_per_chunk, q_bytes=2):
     """Worst-case (full-table context) CostEstimate for the decode
     pallas_call — seq_lens are dynamic at trace time, so the static
     bound is every row at M*Bs context."""
     return _cost_estimate(decode_kernel_cost(
         b, s_q, h, hk, d, bs, m, [m * bs] * b, cache_bytes=cache_bytes,
-        quant=quant, blocks_per_chunk=blocks_per_chunk,
+        quant=quant, q_bytes=q_bytes, blocks_per_chunk=blocks_per_chunk,
     ))
 
 
@@ -671,7 +725,7 @@ def _decode_case(name: str, quant: bool, s_q: int = 1) -> dict:
     def pricing():
         return decode_kernel_cost(
             b, s_q, _H, _HK, _D, _BS, _M, lens, cache_bytes=1 if quant
-            else 4, quant=quant, blocks_per_chunk=2)
+            else 4, quant=quant, q_bytes=4, blocks_per_chunk=2)
 
     return {
         "name": name, "kernel": "paged_decode_attention_mq",

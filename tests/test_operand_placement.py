@@ -220,14 +220,17 @@ def test_decode_kv_blocks_are_counted_on_metrics_and_on_the_http_render(
     m = core.metrics()
     assert len(calls) == m["decode_dispatches_total"] >= 8
     bs, walked, bound = core.config.block_size, 0, 0
+    # the kernel's tiling at the tiny geometry: one group of the 4 slots,
+    # a chunk as long as the rule's cap or the table
+    c = min(core._decode_tiling[1], core.config.max_blocks_per_seq)
+    assert core._decode_tiling[0] >= 4 and c > 1
     for args, _ in calls:
         lens = np.asarray(args[3])        # seq_lens, by slot
         assert lens.shape == (4,) and (lens > 0).sum() in (1, 2)
         blocks = -(-lens // bs)
         walked += int(blocks.sum())
-        # the tiny geometry's tiling is (8, 4): one group of the 4 slots,
-        # every slot fetched in chunks of 4 blocks up to the longest row
-        bound += 4 * 4 * -(-int(blocks.max()) // 4)
+        # every slot fetched in chunks of C blocks up to the longest row
+        bound += 4 * c * -(-int(blocks.max()) // c)
     assert m["decode_kv_blocks_walked_total"] == walked > 0
     assert m["decode_kv_blocks_group_bound_total"] == bound > 2 * walked
     text = Metrics().render() + "\n"

@@ -542,7 +542,9 @@ def test_ragged_prefill_kernel_quant_geometry():
 # (PR 40) A block is copied only if the row owns it; nothing is fetched for
 # an empty slot; the scratch no copy wrote is stale (NaN in interpret mode).
 # One kernel serves S = 1, S > 1 and the int8 cache: the same cases for each.
-_WALK = dict(b=8, h=4, hk=2, d=16, bs=8, m=8, g=4, c=2)   # table: 64 tokens
+# Two sequences an update (PR 47): one that has ended runs masked beside one
+# that has not.
+_WALK = dict(b=8, h=4, hk=2, d=16, bs=8, m=8, g=4, c=2, r=2)  # 64 tokens
 _WALK_LENS = {
     # one group holds a 1-token row and a whole-table row
     "ragged-1-to-table": [1, 64, 9, 33, 8, 17, 40, 63],
@@ -602,7 +604,8 @@ def _walk_kernel(q, cache, bt, lens, q0):
     return np.asarray(paged_decode_attention_mq(
         q, cache, jnp.int32(1), jnp.asarray(bt), jnp.asarray(lens),
         jnp.asarray(q0), blocks_per_chunk=_WALK["c"],
-        seqs_per_group=_WALK["g"], interpret=True))
+        seqs_per_group=_WALK["g"], seqs_per_update=_WALK["r"],
+        interpret=True))
 
 
 def _poison_unowned(cache, bt, lens):
@@ -661,8 +664,8 @@ def test_decode_kernel_walks_each_rows_own_blocks(scenario, variant):
 @pytest.mark.parametrize("mix", ["equal", "ragged", "mostly-empty"])
 def test_decode_kernel_cost_counts_each_rows_own_blocks(mix):
     """``decode_kernel_cost``'s bytes are the sum over the rows of
-    ceil(len / Bs) blocks, plus q in (f32) and the output; whatever the
-    grouping, nothing for an empty slot, no rounding up to a chunk."""
+    ceil(len / Bs) blocks, plus q in and the output back (both in the
+    query's dtype); whatever the grouping, nothing for an empty slot, no rounding up to a chunk."""
     from dynamo_tpu.ops.pallas.registry import decode_kernel_cost
 
     b, h, hk, d, bs, m, c = 16, 8, 2, 128, 32, 16, 4
@@ -673,7 +676,7 @@ def test_decode_kernel_cost_counts_each_rows_own_blocks(mix):
     blocks = sum(-(-n // bs) for n in lens)
     cost = decode_kernel_cost(b, 1, h, hk, d, bs, m, lens, cache_bytes=2,
                               q_bytes=2, blocks_per_chunk=c)
-    assert cost["hbm_bytes"] == blocks * block_bytes + b * h * hk * d * (4 + 2)
+    assert cost["hbm_bytes"] == blocks * block_bytes + b * h * hk * d * (2 + 2)
     # the matmuls take whole chunks of C blocks, a row's own
     chunks = sum(-(-n // (c * bs)) for n in lens)
     assert cost["flops"] == chunks * 4 * h * (c * bs) * hk * d
@@ -684,7 +687,161 @@ def test_decode_kernel_cost_counts_each_rows_own_blocks(mix):
     quant = decode_kernel_cost(b, 1, h, hk, d, bs, m, lens, cache_bytes=1,
                                quant=True, q_bytes=2, blocks_per_chunk=c)
     assert quant["hbm_bytes"] == (
-        blocks * (block_bytes // 2 + 2 * hp * sp * 4) + b * h * hk * d * 6)
+        blocks * (block_bytes // 2 + 2 * hp * sp * 4) + b * h * hk * d * 4)
+
+
+# ------------------------- bf16 operands, a row-chunk sized by its bytes
+# (PR 47) Both matmuls take bf16 as the cache holds it and accumulate in
+# float32; p reaches the matrix unit as a bf16 head and a bf16 remainder.
+# Serving dtypes (bf16 q, bf16 or int8 cache) at the four cell geometries'
+# lanes and the tiling ``decode_tiling`` gives each, against the float32
+# oracle on the same values.  ``_PARENT_WORST``: what the float32-operand
+# kernel of the parent commit (da567be, its own tiling) read on the same
+# inputs, interpret mode — the new kernel may be no further off.
+_PARITY_GEOMS = {256: (8, 2), 512: (32, 4), 1024: (32, 8), 2048: (16, 16)}
+_PARITY_LENS = {256: (4096, 1), 512: (1, 2917), 1024: (3333, 97),
+                2048: (640, 4096)}
+_PARENT_WORST = {
+    (256, "s1"): 0.0002147778868675232,
+    (256, "s3"): 0.007281303405761719,
+    (256, "int8"): 0.0037190914154052734,
+    (256, "softcap"): 0.00023202598094940186,
+    (512, "s1"): 0.00024375319480895996,
+    (512, "s3"): 0.007747173309326172,
+    (512, "int8"): 0.007512092590332031,
+    (512, "softcap"): 0.00024268031120300293,
+    (1024, "s1"): 0.0016565322875976562,
+    (1024, "s3"): 0.001893758773803711,
+    (1024, "int8"): 0.0019450783729553223,
+    (1024, "softcap"): 0.0019453167915344238,
+    (2048, "s1"): 0.000943988561630249,
+    (2048, "s3"): 0.0007906854152679443,
+    (2048, "int8"): 0.0009748935699462891,
+    (2048, "softcap"): 0.0008189678192138672,
+}
+
+
+def _parity_inputs(lanes, variant):
+    """(q, cache, clean f32 [N, 2, Bs, HkD], bt, lens, q0, logit_cap): 8
+    slots of which two are live, every unowned pool block NaN / +-inf."""
+    from dynamo_tpu.ops.kv_quant import (
+        QuantKvCache, dequant_layer_slice, pad_scales,
+    )
+
+    (h, hk), d, bs, m, b = _PARITY_GEOMS[lanes], 128, 32, 128, 8
+    s = 3 if variant == "s3" else 1
+    rng = np.random.default_rng(47 + lanes)
+    lens = np.zeros(b, np.int32)
+    lens[[5, 2]] = np.maximum(_PARITY_LENS[lanes], s)
+    need = -(-lens // bs)
+    n = int(need.sum()) + 6
+    pool = rng.permutation(n - 1)[: need.sum()] + 1
+    bt = np.zeros((b, m), np.int32)
+    for r, at in enumerate(np.cumsum(need) - need):
+        bt[r, : need[r]] = pool[at: at + need[r]]
+    dead = sorted(set(range(n)) - set(pool.tolist()))
+    assert 0 in dead and len(dead) == 6
+    poison = np.asarray([np.nan, np.inf, -np.inf], np.float32)
+    q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.bfloat16)
+    if variant == "int8":
+        data = jnp.asarray(
+            rng.integers(-127, 128, size=(1, n, 2, bs, hk * d)), jnp.int8)
+        scale = rng.random((1, n, 2, hk, bs)).astype(np.float32) * .02 + .002
+        clean = dequant_layer_slice(
+            data[0], pad_scales(jnp.asarray(scale))[0], hk)
+        for i, blk in enumerate(dead):
+            scale[:, blk] = poison[i % 3]
+        cache = QuantKvCache(data, pad_scales(jnp.asarray(scale)))
+    else:
+        cache = jnp.asarray(rng.normal(size=(1, n, 2, bs, hk * d)),
+                            jnp.bfloat16)
+        clean = cache[0].astype(jnp.float32)
+        for i, blk in enumerate(dead):
+            cache = cache.at[:, blk].set(jnp.bfloat16(poison[i % 3]))
+    cap = 30.0 if variant == "softcap" else None
+    return q, cache, clean, bt, lens, np.maximum(lens - s, 0), cap
+
+
+def _parity_worst(kernel, lanes, variant, interpret=True):
+    """Worst |kernel - float32 oracle| over the live rows' outputs."""
+    (h, hk), d, bs = _PARITY_GEOMS[lanes], 128, 32
+    q, cache, clean, bt, lens, q0, cap = _parity_inputs(lanes, variant)
+    n, s = clean.shape[0], q.shape[1]
+    positions = jnp.asarray(q0[:, None] + np.arange(s)[None, :], jnp.int32)
+    ref = np.asarray(paged_attention(
+        q.astype(jnp.float32), clean[:, 0].reshape(n, bs, hk, d),
+        clean[:, 1].reshape(n, bs, hk, d), jnp.asarray(bt),
+        jnp.asarray(lens), positions, logit_cap=cap))
+    out = np.asarray(kernel(
+        q, cache, jnp.int32(0), jnp.asarray(bt), jnp.asarray(lens),
+        jnp.asarray(q0), logit_cap=cap, interpret=interpret), np.float32)
+    live = lens > 0
+    assert np.isfinite(out).all() and (out[~live] == 0).all()
+    return float(np.abs(out[live] - ref[live]).max())
+
+
+@pytest.mark.parametrize("variant", ["s1", "s3", "int8", "softcap"])
+@pytest.mark.parametrize("lanes", sorted(_PARITY_GEOMS))
+def test_decode_kernel_bf16_operands_are_no_further_from_float32(lanes,
+                                                                 variant):
+    from dynamo_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention_mq,
+    )
+
+    worst = _parity_worst(paged_decode_attention_mq, lanes, variant)
+    assert worst <= _PARENT_WORST[lanes, variant], worst
+
+
+def _remainder_error(kernel, interpret=True):
+    """Two kinds of key a row: p = 1 for the first half and one x < 1 for
+    the second, V = +1 against -c over the lanes, c sweeping [1, 2): where
+    x * c is near 1 the output nearly cancels, and an x rounded to bf16
+    (relative error up to 2^-9, the same on every key) stands out of the
+    small output's own rounding by 10 to 100 times.  Worst |kernel -
+    float64| over the outputs under 2^-6."""
+    h, hk, d, bs, m, b = 8, 2, 128, 32, 16, 8
+    rng = np.random.default_rng(4747)
+    half = np.asarray([64, 0, 200, 0, 33, 256, 0, 7])
+    lens = (2 * half).astype(np.int32)
+    u = rng.choice([-1.0, 1.0], size=d)
+    gamma = 0.75 + np.arange(h) * 0.09375          # exact in bf16
+    q = np.broadcast_to((gamma[:, None] * u)[None, None], (b, 1, h, d))
+    c = 1 + np.arange(d) / 128.0                   # exact in bf16
+    need = -(-lens // bs)
+    n = int(need.sum()) + 1
+    bt = np.zeros((b, m), np.int32)
+    kv = np.zeros((1, n, 2, bs, hk * d), np.float32)
+    at = 1
+    for r in range(b):
+        bt[r, : need[r]] = np.arange(at, at + need[r])
+        first = (np.arange(need[r] * bs) < half[r])[:, None, None]
+        keys = np.where(first, u, u * 0.96875) * np.ones((1, hk, 1))
+        vals = np.where(first, 1.0, -c) * np.ones((1, hk, 1))
+        kv[0, at: at + need[r], 0] = keys.reshape(need[r], bs, hk * d)
+        kv[0, at: at + need[r], 1] = vals.reshape(need[r], bs, hk * d)
+        at += need[r]
+    out = np.asarray(kernel(
+        jnp.asarray(q, jnp.bfloat16), jnp.asarray(kv, jnp.bfloat16),
+        jnp.int32(0), jnp.asarray(bt), jnp.asarray(lens),
+        jnp.asarray(np.maximum(lens - 1, 0)), interpret=interpret),
+        np.float64)
+    # the same arithmetic in float64: s_first - s_second = 128 / sqrt(128)
+    # * gamma / 32
+    x = np.exp(-(d ** 0.5) * gamma / 32)
+    ref = (1 - x[:, None] * c[None, :]) / (1 + x[:, None])
+    small = np.abs(ref) < 2.0 ** -6
+    assert small.any(axis=1).all()                 # every head cancels somewhere
+    return float(np.abs(out[lens > 0][:, 0] - ref[None])[:, small].max())
+
+
+def test_decode_kernel_keeps_the_remainder_of_p():
+    """Fails if p goes to the matrix unit rounded to bf16 without its
+    remainder (1.4e-3 then; ~16 bits of p give under 1e-4)."""
+    from dynamo_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention_mq,
+    )
+
+    assert _remainder_error(paged_decode_attention_mq) < 1e-4
 
 
 from kernel_oracles import assert_canary_clean, interpret_cases  # noqa: E402

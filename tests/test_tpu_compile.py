@@ -549,18 +549,142 @@ def test_decode_rows_are_ordered_outside_the_layer_scan(topo, tpu_gate, case):
 def test_decode_tiling_follows_the_geometry():
     from dynamo_tpu.ops.pallas import registry
 
-    accepted = {   # rows a sequence, Hk*D lanes: the benchmark's decode kernels
-        "mistral-7b": (32, 1024), "qwen3-30b-a3b": (32, 512),
-        "mistral-7b-tp4 (a shard)": (8, 256), "llama-3-8b": (32, 1024)}
-    for name, (rows, hkd) in accepted.items():
-        assert registry.decode_tiling(rows, hkd, BS) == (8, 4), name
-    g, c = registry.decode_tiling(16, 2048, BS)
-    assert (g, c) != (8, 4) and g * c >= 8
-    assert registry.decode_vmem_bytes(g, c, 16, 2048, BS) \
-        <= registry.SCOPED_VMEM_BYTES < registry.decode_vmem_bytes(
-            8, 4, 16, 2048, BS)
-    # the K/V scratch the issue counted: (2, G, C, 2, Bs, Hk*D) in bf16
+    # rows a sequence, Hk*D lanes of the benchmark's decode kernels: a
+    # row-chunk of 512 KiB of K/V whatever the lanes (PR 47), and the
+    # sequences a batched update takes
+    accepted = {
+        "mistral-7b": (32, 1024, (8, 4), 4), "llama-3-8b": (32, 1024, (8, 4), 4),
+        "qwen3-30b-a3b": (32, 512, (8, 8), 4),
+        "mistral-7b-tp4 (a shard)": (8, 256, (8, 16), 4),
+        "ouro-2.6b": (16, 2048, (8, 2), 2)}
+    for name, (rows, hkd, tiling, r) in accepted.items():
+        g, c = registry.decode_tiling(rows, hkd, BS)
+        assert (g, c) == tiling, name
+        assert c * 2 * BS * hkd * 2 == registry.DECODE_CHUNK_BYTES, name
+        assert registry.decode_seqs_per_update(g, c, BS) == r, name
+        assert registry.decode_vmem_bytes(g, c, rows, hkd, BS) \
+            <= registry.SCOPED_VMEM_BYTES, name
+    # an int8 block brings a scale tile: two copies and two semaphores a site
+    g, c = registry.decode_tiling(8, 256, BS, cache_bytes=1)
+    assert 2 * g * c <= registry.DECODE_MAX_DMA_SITES and c == 16
+    # narrow toy rows: never more blocks a chunk than the cap, nor sites
+    g, c = registry.decode_tiling(4, 32, 8, cache_bytes=4, q_bytes=4)
+    assert c == registry.DECODE_MAX_BLOCKS_PER_CHUNK
+    assert g * c <= registry.DECODE_MAX_DMA_SITES
+    # at 2,048 lanes G 8, C 4 would not fit: the K/V scratch the issue of
+    # PR 39 counted, (2, G, C, 2, Bs, Hk*D) in bf16, is the scoped limit
     assert 2 * 8 * 4 * 2 * BS * 2048 * 2 == registry.SCOPED_VMEM_BYTES
+    assert registry.decode_vmem_bytes(8, 4, 16, 2048, BS) \
+        > registry.SCOPED_VMEM_BYTES
+
+
+def _probe_geoms():
+    from benchmarks.probe_kernels import DECODE_GEOMS
+
+    return sorted(DECODE_GEOMS)
+
+
+@pytest.mark.parametrize("geom", _probe_geoms())
+def test_decode_kernel_fits_scoped_vmem_at_every_probe_geometry(topo, geom):
+    """The kernel alone at the four cell geometries of the chip sweep
+    (``benchmarks/probe_kernels.py lengths``), with the tiling the rule
+    gives and a table of 128 blocks: the chip's compiler refuses a kernel
+    whose scratch and temporaries pass its scoped VMEM."""
+    from benchmarks.probe_kernels import DECODE_GEOMS
+    from dynamo_tpu.ops.pallas import registry
+    from dynamo_tpu.ops.pallas.decode_attention import (
+        paged_decode_attention_mq,
+    )
+
+    rows, h, hk = DECODE_GEOMS[geom]
+    d, m = 128, 128
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    g, c = registry.decode_tiling(h, hk * d, BS)
+    assert registry.decode_vmem_bytes(g, c, h, hk * d, BS) \
+        <= registry.SCOPED_VMEM_BYTES
+    hlo = jax.jit(lambda q, cache, bt, lens: paged_decode_attention_mq(
+        q, cache, jnp.int32(0), bt, lens, lens - 1)).lower(
+        sds((rows, 1, h, d), jnp.bfloat16),
+        sds((1, N_BLOCKS, 2, BS, hk * d), jnp.bfloat16),
+        sds((rows, m), jnp.int32), sds((rows,), jnp.int32)
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def _kernel_body_converts(fn, *args):
+    """(operand avals of the decode kernel's pallas_call, [(from dtype,
+    shape)] of every conversion to float32 inside its body, nested
+    branches and loops included)."""
+    def walk(jaxpr, found):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "convert_element_type"
+                    and eqn.params["new_dtype"] == jnp.float32):
+                aval = eqn.invars[0].aval
+                found.append((str(aval.dtype), tuple(aval.shape)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, found)
+        return found
+
+    def calls(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if (eqn.primitive.name == "pallas_call"
+                    and "paged_decode_attention" in eqn.params["name"]):
+                out.append(eqn)
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    calls(sub, out)
+        return out
+
+    (call,) = calls(jax.make_jaxpr(fn)(*args).jaxpr, [])
+    return ([v.aval for v in call.invars], walk(call.params["jaxpr"], []))
+
+
+@pytest.mark.parametrize("case", ["mistral-7b-decode", "mistral-7b-tp4-decode"])
+def test_decode_program_holds_no_float32_query_or_cache_block(
+        topo, tpu_gate, case):
+    """PR 47: the block-diagonal query goes to the kernel in the model's
+    dtype and K/V go to the matrix unit as the cache holds them.  Compiled
+    for the described v5e (one chip; four under ``--tp 4``, a shard's 8
+    query rows and 256 lanes) the decode program holds no float32
+    [B, S*H, Hk*D] array, and traced, the kernel's body converts no K/V
+    chunk to float32."""
+    hlo, _ = _scan_program(topo, case)
+    config_file, _, chips, overrides = _SCAN_PROGRAMS[case]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench/configs", config_file)) as f:
+        hf = json.load(f)
+    b = hf["serve"]["max_batch_size"]
+    h = hf["num_attention_heads"] // chips
+    hkd = hf["num_key_value_heads"] * hf["head_dim"] // chips
+    assert "tpu_custom_call" in hlo
+    assert f"bf16[{b},{h},{hkd}]" in hlo           # the kernel's q and output
+    assert f"f32[{b},{h},{hkd}]" not in hlo
+
+    # the kernel's body, traced at that geometry (a shard's under --tp 4)
+    sds = jax.ShapeDtypeStruct
+    fn = functools.partial(pa.paged_attention_layer, sm_scale=128 ** -0.5)
+    if chips > 1:
+        mesh = Mesh(np.array(topo.devices[:chips]).reshape(1, chips),
+                    ("data", "model"))
+        place = lambda spec: NamedSharding(mesh, spec)
+        inner = fn
+        def fn(*a):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return inner(*a)
+    else:
+        place = lambda spec: SingleDeviceSharding(topo.devices[0])
+    arg = lambda shape, dt, spec=P(): sds(shape, dt, sharding=place(spec))
+    operands, converts = _kernel_body_converts(
+        fn, arg((b, 1, h * chips, 128), jnp.bfloat16, _HEADS),
+        arg((1, N_BLOCKS, 2, BS, hkd * chips), jnp.bfloat16, _CACHE_SPEC),
+        arg((), jnp.int32), arg((b, M), jnp.int32), arg((b,), jnp.int32),
+        arg((b, 1), jnp.int32))
+    assert any(a.shape == (b, h, hkd) and a.dtype == jnp.bfloat16
+               for a in operands), operands
+    assert not any(a.dtype == jnp.float32 and a.ndim >= 3 for a in operands)
+    wide = [cv for cv in converts if cv[1][-1:] == (hkd,) and cv[0] != "float32"]
+    assert not wide, wide
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill", "prefill-one-block"])
