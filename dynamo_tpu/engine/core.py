@@ -76,17 +76,22 @@ def unified_step(
     slot_idx, last_idx, rng, temp, top_k, top_p, prefix_blocks=None,
     k_cand=K_MAX, exact=False, grammar=None, jrows=None, jstate=None,
     jdepth=None, jstack=None, min_p=None, bias_tokens=None, bias_vals=None,
-    seeds=None, seed_rows=None,
+    seeds=None, seed_rows=None, seq_slots=None,
 ):
     """THE jitted serving step: forward over the paged cache, gather each
     row's last hidden state, project to logits, sample.  Shared by the
     engine hot loop and the driver's compile checks (__graft_entry__.py).
+
+    ``seq_slots`` int32 [B] (a model with ``recurrent_state`` only): the
+    engine slot each row sits in, where that model keeps the row's state.
+    (``multi_decode_step`` passes none: its rows are the slot array.)
 
     Returns ((sampled [B], logprob [B], cand_ids [B,C], cand_lps [B,C]),
     cache) — candidate arrays feed OpenAI top_logprobs."""
     hidden, cache = model.forward(
         params, tokens, positions, cache, block_tables, seq_lens, slot_idx,
         prefix_blocks=prefix_blocks,
+        **({} if seq_slots is None else {"seq_slots": seq_slots}),
     )
     b = tokens.shape[0]
     last_h = hidden[jnp.arange(b), last_idx]  # [B, Dm]
@@ -387,10 +392,18 @@ class EngineCore:
         self._grammar_tok = None
         self._choice_tables: dict[tuple, object] = {}
         self._gdev_cache: dict[tuple, tuple] = {}
+        # a state of fixed size per slot beside the pool (models/
+        # hybrid_linear.py): a cached K/V block says nothing about the state
+        # at its end, so no block is ever reused for such a model
+        self._recurrent = bool(getattr(model, "recurrent_state", False))
+        self.prefix_reuse = config.enable_prefix_reuse and not self._recurrent
+        if self._recurrent and config.enable_prefix_reuse:
+            log.info("prefix reuse is off: %s keeps a recurrent state, "
+                     "which no cached block restores", type(model).__name__)
         self.block_manager = KvBlockManager(
             config.num_blocks,
             config.block_size,
-            enable_prefix_reuse=config.enable_prefix_reuse,
+            enable_prefix_reuse=self.prefix_reuse,
         )
         cache_dtype = config.cache_dtype or model.config.dtype
         self.cache_quant = str(cache_dtype) == "int8"
@@ -407,7 +420,12 @@ class EngineCore:
                 ("cache_dtype=int8", self.cache_quant),
                 ("spec_tokens", config.spec_tokens > 0),
                 ("sp_prefill_threshold", config.sp_prefill_threshold > 0),
-                ("a mesh", mesh is not None)) if on]
+                ("a mesh", mesh is not None),
+                # several sequences on one row axis: whose state?
+                ("prefill_token_budget",
+                 self._recurrent and config.prefill_token_budget > 0),
+                ("unified_token_dispatch",
+                 self._recurrent and config.unified_token_dispatch)) if on]
             if asked:
                 raise ValueError(
                     f"{type(model).__name__} keeps its cache in a layout "
@@ -487,7 +505,9 @@ class EngineCore:
 
         def make_cache():
             return model.init_kv_cache(
-                config.num_blocks, config.block_size, cache_dtype)
+                config.num_blocks, config.block_size, cache_dtype,
+                **({"slots": config.max_batch_size} if self._recurrent
+                   else {}))
 
         self._cache_specs = None
         if mesh is None:
@@ -523,11 +543,19 @@ class EngineCore:
         mesh_shape.update(tp=self.mesh_tp, devices=self.mesh_devices)
         # what the cache is made of: its layers (a looped decoder keeps one
         # per pass of every layer) and what one token costs across them all
-        self.cache_layers = int(jax.tree.leaves(cache)[0].shape[0])
+        self.cache_layers = int(jax.tree.leaves(self._pool())[0].shape[0])
         self.kv_bytes_per_token = (
             self.kv_bytes_per_block() // config.block_size)
+        # ... and what a slot's recurrent state costs, whatever its length
+        self.state_layers = (
+            int(cache["state"].shape[0]) if self._recurrent else 0)
+        self.state_bytes_per_slot = (
+            model.state_bytes_per_slot() if self._recurrent else 0)
         cache_shape.update(layers=self.cache_layers,
-                           bytes_per_token=self.kv_bytes_per_token)
+                           bytes_per_token=self.kv_bytes_per_token,
+                           state_layers=self.state_layers,
+                           state_bytes_per_slot=self.state_bytes_per_slot,
+                           prefix_reuse=int(self.prefix_reuse))
 
         # where a dispatch's small operands go under a mesh
         # (``_upload_dispatch``): replicated over it, the layout the jitted
@@ -672,6 +700,11 @@ class EngineCore:
         self.moe_router_picks = 0
         self.moe_held_picks = 0
         self.moe_expert_layer_calls = 0
+        # ... and its recurrent layers, in the same array: real tokens x
+        # layers advanced, sequences started from zeros, rows that went on
+        # at another position than their slot's state stood at (always 0:
+        # the slot contract, docs/linear_state.md)
+        self.state_counts = (0, 0, 0)
         # tokens dispatched (prefill and decode) and, over them, the passes
         # of the layer stack run: ut_steps a token for a looped decoder
         self._ut_steps = int(getattr(model.config, "ut_steps", 1) or 1)
@@ -716,14 +749,14 @@ class EngineCore:
                    k_cand=K_MAX, exact=False, grammar=None, jrows=None,
                    jstate=None, jdepth=None, jstack=None, min_p=None,
                    bias_tokens=None, bias_vals=None, seeds=None,
-                   seed_rows=None):
+                   seed_rows=None, seq_slots=None):
         return unified_step(self.model, params, cache, *args,
                             prefix_blocks=prefix_blocks, k_cand=k_cand,
                             exact=exact, grammar=grammar, jrows=jrows,
                             jstate=jstate, jdepth=jdepth, jstack=jstack,
                             min_p=min_p, bias_tokens=bias_tokens,
                             bias_vals=bias_vals, seeds=seeds,
-                            seed_rows=seed_rows)
+                            seed_rows=seed_rows, seq_slots=seq_slots)
 
     def _ragged_impl(self, params, cache, tokens, positions, block_tables,
                      seq_lens, slot_idx, seq_ids, seq_starts, row_offsets,
@@ -1372,7 +1405,11 @@ class EngineCore:
         out, experts = jax.device_get((tuple(rec.out), rec.experts))
         self.device_gets += 1
         if experts is not None:
-            picks, held, calls = (int(n) for n in experts)
+            picks, held, calls, *state = (int(n) for n in experts)
+            if state:
+                request_counters.record_state(*(
+                    n - had for n, had in zip(state, self.state_counts)))
+                self.state_counts = tuple(state)
             request_counters.record_experts(
                 picks - self.moe_router_picks, held - self.moe_held_picks,
                 calls - self.moe_expert_layer_calls)
@@ -1492,6 +1529,12 @@ class EngineCore:
             "moe_router_picks_total": self.moe_router_picks,
             "moe_held_picks_total": self.moe_held_picks,
             "moe_expert_layer_calls_total": self.moe_expert_layer_calls,
+            "state_tokens_total": self.state_counts[0],
+            "state_resets_total": self.state_counts[1],
+            "state_position_mismatches_total": self.state_counts[2],
+            "state_layers": self.state_layers,
+            "state_bytes_per_slot": self.state_bytes_per_slot,
+            "prefix_reuse": int(self.prefix_reuse),
             "loop_tokens_total": self.loop_tokens,
             "loop_passes_total": self.loop_passes,
             "decode_kv_blocks_walked_total": self.decode_kv_blocks_walked,
@@ -1949,7 +1992,9 @@ class EngineCore:
             np.asarray([req.sampling.top_k], np.int32),
             np.asarray([req.sampling.top_p], np.float32),
             prefix_blocks=pb, k_cand=k_cand, exact=exact, gram=gram,
-            extras=self._sampling_extras([req]) if final else None,
+            extras={**(self._sampling_extras([req]) if final else {}),
+                    **({"seq_slots": np.asarray([req.slot], np.int32)}
+                       if self._recurrent else {})},
             reqs=(req,), carried=carried,
         )
         self.prefill_steps += 1
@@ -3522,12 +3567,17 @@ class EngineCore:
         except Exception:  # pragma: no cover - probe must never raise
             return 0
 
+    def _pool(self):
+        """The part of the cache that is held by block: all of it, but for
+        a model that keeps a state per slot beside its ``kv``."""
+        return self.cache["kv"] if self._recurrent else self.cache
+
     def kv_bytes_per_block(self) -> int:
         """Host-staged wire bytes one KV block occupies (all layers, both
         K and V, all parts of a quantized pair) — the router's
         transfer-cost size input.  Derived from the live cache pytree so
         quantization/dtype changes are automatically reflected."""
-        leaves = jax.tree.leaves(self.cache)
+        leaves = jax.tree.leaves(self._pool())
         # cache leaves are [L, n_blocks, ...]: bytes per block = leaf
         # bytes / n_blocks, summed over parts (a leaf that is not per block,
         # a model's ``moe_counts``, is not the cache's)

@@ -369,6 +369,18 @@ class RequestCounters:
         dynamo_tpu_engine_moe_expert_layer_calls_total counter (expert layers
                                                        run, one a layer and
                                                        dispatch)
+        dynamo_tpu_engine_state_tokens_total           counter (a model
+                                                       with recurrent layers:
+                                                       real tokens x such
+                                                       layers advanced)
+        dynamo_tpu_engine_state_resets_total           counter (sequences
+                                                       started from a zero
+                                                       state: position 0)
+        dynamo_tpu_engine_state_position_mismatches_total  counter (rows that
+                                                       went on at another
+                                                       position than their
+                                                       slot's state stood at:
+                                                       0, the slot contract)
         dynamo_tpu_engine_loop_tokens_total            counter (tokens that
                                                        went out in a prefill
                                                        or decode dispatch)
@@ -394,9 +406,9 @@ class RequestCounters:
                                                        1 - walked / bound is
                                                        the share of fetches a
                                                        row's own walk spares)
-    The ``moe_*`` three are counted on the device, inside the model's expert
-    layers, and read back with each dispatch's outputs; the others on the
-    host from lengths it already has.
+    The ``moe_*`` and ``state_*`` three are counted on the device, inside the
+    model's forward, and read back with each dispatch's outputs; the others
+    on the host from lengths it already has.
     """
 
     def __init__(self) -> None:
@@ -444,6 +456,11 @@ class RequestCounters:
         self.moe_held_picks_total += held
         self.moe_expert_layer_calls_total += calls
 
+    def record_state(self, tokens: int, resets: int, mismatches: int) -> None:
+        self.state_tokens_total += tokens
+        self.state_resets_total += resets
+        self.state_position_mismatches_total += mismatches
+
     def record_loop(self, tokens: int, passes: int) -> None:
         self.loop_tokens_total += tokens
         self.loop_passes_total += passes
@@ -473,6 +490,9 @@ class RequestCounters:
         self.moe_router_picks_total = 0
         self.moe_held_picks_total = 0
         self.moe_expert_layer_calls_total = 0
+        self.state_tokens_total = 0
+        self.state_resets_total = 0
+        self.state_position_mismatches_total = 0
         self.loop_tokens_total = 0
         self.loop_passes_total = 0
         self.decode_kv_blocks_walked_total = 0
@@ -501,4 +521,14 @@ mesh_shape = {"tp": 1, "devices": 1}
 #     dynamo_tpu_engine_kv_bytes_per_token  gauge (bytes one token holds
 #                                           across all of them: what sizes
 #                                           num_blocks and a block transfer)
-cache_shape = {"layers": 0, "bytes_per_token": 0}
+#     dynamo_tpu_engine_state_layers        gauge (layers that keep a
+#                                           recurrent state per slot; 0 for
+#                                           a model without one)
+#     dynamo_tpu_engine_state_bytes_per_slot  gauge (what one slot's state
+#                                           holds across them, whatever the
+#                                           sequence's length)
+#     dynamo_tpu_engine_prefix_reuse        gauge (1: cached blocks are
+#                                           reused; 0: off, by configuration
+#                                           or because of such a state)
+cache_shape = {"layers": 0, "bytes_per_token": 0, "state_layers": 0,
+               "state_bytes_per_slot": 0, "prefix_reuse": 1}

@@ -355,6 +355,14 @@ class Metrics:
         lines.append(f"# TYPE {EM.MOE_EXPERT_LAYER_CALLS_TOTAL} counter")
         lines.append(f"{EM.MOE_EXPERT_LAYER_CALLS_TOTAL} "
                      f"{rc.moe_expert_layer_calls_total}")
+        # what a model's recurrent layers did, from the same read-back
+        lines.append(f"# TYPE {EM.STATE_TOKENS_TOTAL} counter")
+        lines.append(f"{EM.STATE_TOKENS_TOTAL} {rc.state_tokens_total}")
+        lines.append(f"# TYPE {EM.STATE_RESETS_TOTAL} counter")
+        lines.append(f"{EM.STATE_RESETS_TOTAL} {rc.state_resets_total}")
+        lines.append(f"# TYPE {EM.STATE_POSITION_MISMATCHES_TOTAL} counter")
+        lines.append(f"{EM.STATE_POSITION_MISMATCHES_TOTAL} "
+                     f"{rc.state_position_mismatches_total}")
         # the mesh this engine runs on (1 and 1 with no mesh)
         lines.append(f"# TYPE {EM.MESH_TP} gauge")
         lines.append(f"{EM.MESH_TP} {mesh_shape['tp']}")
@@ -380,6 +388,14 @@ class Metrics:
         lines.append(f"# TYPE {EM.KV_BYTES_PER_TOKEN} gauge")
         lines.append(f"{EM.KV_BYTES_PER_TOKEN} "
                      f"{cache_shape['bytes_per_token']}")
+        # ... and the state a slot keeps beside it, with what that switches off
+        lines.append(f"# TYPE {EM.STATE_LAYERS} gauge")
+        lines.append(f"{EM.STATE_LAYERS} {cache_shape['state_layers']}")
+        lines.append(f"# TYPE {EM.STATE_BYTES_PER_SLOT} gauge")
+        lines.append(f"{EM.STATE_BYTES_PER_SLOT} "
+                     f"{cache_shape['state_bytes_per_slot']}")
+        lines.append(f"# TYPE {EM.PREFIX_REUSE} gauge")
+        lines.append(f"{EM.PREFIX_REUSE} {cache_shape['prefix_reuse']}")
         lines.append(f"# TYPE {EM.HOST_GAP_MS_PER_TURN} gauge")
         lines.append(f"{EM.HOST_GAP_MS_PER_TURN} "
                      f"{round(tl['host_gap_ms_per_turn'], 6)}")

@@ -1,0 +1,570 @@
+"""A decoder whose layers are of two kinds: most carry a recurrent state of
+fixed size (the gated delta rule with a decay per key channel, behind a short
+causal convolution: ops/linear_state.py), every few attend over the paged
+K/V cache (grouped-query attention with no positional term and an output
+gate).  Every layer ends in routed experts, of which this chip holds a share,
+plus a shared expert.  ``solar_open2`` is the published config read here;
+docs/linear_state.md has the equations.
+
+Per layer (pre-norm residual blocks):
+
+  * **Linear layer.**  q̂, k̂, v̂ = W x; a depth-wise convolution of
+    ``short_conv_kernel_size`` over time on each, then SiLU; q and k L2-normed
+    a head (q also by d^-1/2); decay g = -exp(A_log) · softplus(W_f↑ W_f↓ x +
+    b_dt) a key channel, step beta = 2 · sigmoid(W_β x); the recurrence
+    (``delta_rule_step`` for one token a row, ``delta_rule_scan`` for a
+    prefill chunk); RMSNorm a head and a low-rank sigmoid gate; W_o.
+  * **GQA layer.**  softmax(q kᵀ d^-1/2) v through the K/V pool and the
+    Pallas kernels every dense model here uses (ops/paged_attention.py), no
+    rope, no q/k norm; o ⊙ sigmoid(W_gate x); W_o.
+  * **Experts**: as models/glm_dsa.py — the router scores all
+    ``router_experts``, this chip computes the part its own give.
+
+Parameters are stacked per kind, and each run of consecutive layers of one
+kind is one ``lax.scan``.
+
+**The state is held per engine slot**, not per block: the cache is a dict of
+``kv`` (the pool, over the attending layers only), ``state``, ``conv``,
+``state_pos`` and ``moe_counts``.  ``forward`` is told which slot each row of
+the dispatch sits in (``seq_slots``; None: row i is slot i, a decode over the
+slot array) and owns these rules: a row whose first position is 0 starts from
+zeros; a token with ``slot_idx < 0`` is an identity step; a row with no real
+token leaves its slot bit for bit as it was; ``state_pos[slot]`` becomes the
+position after the row's last real token, and a row that continues at
+another position than that is counted (``moe_counts[0, 0, 5]``).
+
+One chip only, and nothing that moves blocks knows the state: the engine
+refuses those paths at start-up (``private_cache_layout``,
+``recurrent_state``).
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from dynamo_tpu.models.deepseek import moe_route
+from dynamo_tpu.models.glm_dsa import ROUTER_BIAS_STD
+from dynamo_tpu.models.llama import (
+    grouped_expert_dispatch,
+    rms_norm,
+    split_heads,
+)
+from dynamo_tpu.ops import linear_state
+from dynamo_tpu.ops.paged_attention import (
+    paged_attention_layer,
+    prefill_attention,
+    rows_by_length,
+    write_kv_cache_layer,
+)
+
+Params = Any
+
+__all__ = ["HybridLinearConfig", "HybridLinearModel", "DECAY_PROJ_STD"]
+
+QK_NORM_EPS = 1e-6
+# standard deviation of what the seeded low-rank decay projection adds to
+# b_dt.  With fan-in-scaled weights it is 1 and, worse, softplus of a
+# zero-centred number is ~0.7: alpha = exp(-0.7 · 1..16) forgets in three
+# tokens, and a state that forgets cannot show a broken chunk carry.  At 0.5
+# the decay still moves with the token (a factor e^±0.5 on softplus) and its
+# median stays where b_dt puts it
+DECAY_PROJ_STD = 0.5
+# a forward adds its three counts (real tokens x linear layers, sequences
+# started from zeros, position mismatches) to ``moe_counts[0, 0, 3:6]``
+STATE_COUNTS = 3
+
+
+@dataclass
+class HybridLinearConfig:
+    vocab_size: int
+    hidden_size: int
+    num_layers: int
+    num_heads: int                 # GQA query heads
+    num_kv_heads: int
+    head_dim: int
+    linear_heads: int
+    linear_head_dim: int           # key and value width a head
+    conv_kernel: int
+    gate_rank: int                 # rank of the decay and output gates
+    gqa_layers: tuple              # indices of the attending layers
+    moe_intermediate_size: int
+    n_routed_experts: int          # experts held HERE
+    router_experts: int            # experts the router chooses among
+    expert_first: int
+    num_experts_per_tok: int
+    n_shared_experts: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    scoring_func: str = "sigmoid"
+    topk_method: str = "noaux_tc"
+    rms_norm_eps: float = 1e-5
+    max_position_embeddings: int = 4096
+    dtype: str = "bfloat16"
+
+    @property
+    def jax_dtype(self):
+        return {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[self.dtype]
+
+    @property
+    def linear_layers(self) -> int:
+        return self.num_layers - len(self.gqa_layers)
+
+    @property
+    def conv_width(self) -> int:
+        """q̂ ‖ k̂ ‖ v̂ of one token."""
+        return 3 * self.linear_heads * self.linear_head_dim
+
+    @classmethod
+    def from_hf_config(cls, cfg: dict, dtype: str = "bfloat16"
+                       ) -> "HybridLinearConfig":
+        """The published ``solar_open2`` keys -> HybridLinearConfig.  Raises,
+        by name, on what this port does not compute.  ``expert_parallel``
+        (not a published key) says which share of a layer's experts this
+        chip holds, as for models/glm_dsa.py."""
+        g = cfg.get
+        if g("model_type") != "solar_open2":
+            raise NotImplementedError(f"model_type {g('model_type')!r}")
+        lin = g("linear_attn_config") or {}
+        n = int(g("num_hidden_layers"))
+        gqa = tuple(int(i) for i in g("gqa_layers") or ())
+        period = int(g("gqa_interval", 3)) + 1
+        if gqa != tuple(range(0, n, period)):
+            raise NotImplementedError(
+                f"gqa_layers {list(gqa)} disagree with gqa_interval "
+                f"{period - 1} over {n} layers (every {period}th from 0)")
+        if bool(g("kda_use_full_proj", False)):
+            raise NotImplementedError(
+                "kda_use_full_proj=True (full-rank decay projection)")
+        if not bool(g("kda_allow_neg_eigval", True)):
+            raise NotImplementedError(
+                "kda_allow_neg_eigval=False (beta in (0, 1))")
+        if lin.get("num_kv_heads") not in (None, lin.get("num_heads")):
+            raise NotImplementedError(
+                "linear_attn_config.num_kv_heads "
+                f"{lin.get('num_kv_heads')} != num_heads "
+                f"{lin.get('num_heads')} (grouped linear heads)")
+        if bool(g("use_rope", False)):
+            raise NotImplementedError("use_rope=True (rotary GQA layers)")
+        if not bool(g("use_gqa_gate", True)):
+            raise NotImplementedError("use_gqa_gate=False")
+        if int(g("first_k_dense_replace", 0)):
+            raise NotImplementedError("first_k_dense_replace > 0")
+        if bool(g("tie_word_embeddings", False)):
+            raise NotImplementedError("tie_word_embeddings=True")
+        if g("rope_scaling") is not None:
+            raise NotImplementedError(f"rope_scaling {g('rope_scaling')!r}")
+        if int(g("n_group", 1) or 1) != 1 or int(g("topk_group", 1) or 1) != 1:
+            raise NotImplementedError("group-limited expert choice")
+        if g("scoring_func", "sigmoid") != "sigmoid":
+            raise NotImplementedError(f"scoring_func {g('scoring_func')!r}")
+        if g("topk_method", "noaux_tc") != "noaux_tc":
+            raise NotImplementedError(f"topk_method {g('topk_method')!r}")
+        ep = g("expert_parallel") or {}
+        held = int(g("n_routed_experts"))
+        total = int(ep.get("router_experts", held))
+        first = int(ep.get("first_expert", 0))
+        if not 0 <= first <= total - held:
+            raise ValueError(
+                f"experts {first}..{first + held - 1} are not among the "
+                f"router's {total}")
+        d = int(lin["head_dim"])
+        return cls(
+            vocab_size=int(g("vocab_size")), hidden_size=int(g("hidden_size")),
+            num_layers=n, num_heads=int(g("num_attention_heads")),
+            num_kv_heads=int(g("num_key_value_heads")),
+            head_dim=int(g("head_dim")),
+            linear_heads=int(lin["num_heads"]), linear_head_dim=d,
+            conv_kernel=int(lin["short_conv_kernel_size"]),
+            gate_rank=d, gqa_layers=gqa,
+            moe_intermediate_size=int(g("moe_intermediate_size")),
+            n_routed_experts=held, router_experts=total, expert_first=first,
+            num_experts_per_tok=int(g("num_experts_per_tok")),
+            n_shared_experts=int(g("n_shared_experts", 1)),
+            routed_scaling_factor=float(g("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(g("norm_topk_prob", True)),
+            rms_norm_eps=float(g("rms_norm_eps", 1e-5)),
+            max_position_embeddings=int(g("max_position_embeddings", 4096)),
+            dtype=dtype,
+        )
+
+
+@dataclass(frozen=True)
+class _Run:
+    """Consecutive layers of one kind: a scan."""
+    kind: str        # "gqa" | "linear"
+    start: int       # first index within the kind's stacks (= its row of
+    count: int       # ``kv`` or of ``state`` / ``conv``)
+    layer0: int      # first layer index (row of ``moe_counts``)
+
+
+class HybridLinearModel:
+    """Engine-facing functional model (same protocol as LlamaModel)."""
+
+    private_cache_layout = True
+    # a state per engine slot beside the pool: the engine hands ``forward``
+    # the slots of a prefill dispatch's rows, keeps prefix reuse off, and
+    # refuses what packs several sequences into one row axis
+    recurrent_state = True
+    supports_ragged_prefill = False
+    supports_unified_dispatch = False
+    supports_seq_parallel = False
+
+    def __init__(self, config: HybridLinearConfig, state_dtype=jnp.float32):
+        """``state_dtype``: what ``state`` is *stored* in between dispatches
+        (the arithmetic is float32 either way).  float32 is the model; bf16
+        is the negative control of the check
+        (scripts/hybrid_linear_longctx_check.py)."""
+        self.config = config
+        self.state_dtype = state_dtype
+        self.sm_scale = float(config.head_dim ** -0.5)
+        runs, seen = [], {"gqa": 0, "linear": 0}
+        for li in range(config.num_layers):
+            kind = "gqa" if li in config.gqa_layers else "linear"
+            if runs and runs[-1].kind == kind:
+                last = runs[-1]
+                runs[-1] = _Run(kind, last.start, last.count + 1, last.layer0)
+            else:
+                runs.append(_Run(kind, seen[kind], 1, li))
+            seen[kind] += 1
+        self.runs = tuple(runs)
+        self.group_sizes = {k: n for k, n in seen.items() if n}
+        self._draw = jax.jit(self._draw_params)
+
+    # ------------------------------------------------------------------ init
+    def init_params(self, rng: jax.Array) -> Params:
+        """Seeded weights: normal / sqrt(fan-in), norms 1, and the decay's
+        own (A_log = ln U(1, 16) a head; b_dt the inverse softplus of a
+        log-uniform step in [0.001, 0.1] a channel, the family's
+        initialisation; the low-rank decay projection scaled by
+        ``DECAY_PROJ_STD``).  Keys are drawn in a fixed order: a new
+        parameter goes after the ones that are there.  One program: made
+        array by array, the draws are some forty compilations (200 s of a
+        first start on the chip)."""
+        return self._draw(rng)
+
+    def _draw_params(self, rng: jax.Array) -> Params:
+        cfg = self.config
+        dt = cfg.jax_dtype
+        dm = cfg.hidden_size
+        h, hk, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        lh, ld, r = cfg.linear_heads, cfg.linear_head_dim, cfg.gate_rank
+        keys = iter(jax.random.split(rng, 64))
+
+        def dense(shape, fan_in, scale=1.0):
+            return (scale * jax.random.normal(next(keys), shape, jnp.float32)
+                    / math.sqrt(fan_in)).astype(dt)
+
+        def experts(n: int) -> dict:
+            e, f = cfg.n_routed_experts, cfg.moe_intermediate_size
+            fs = f * cfg.n_shared_experts
+            return {
+                "mlp_norm": jnp.ones((n, dm), dt),
+                "router": dense((n, dm, cfg.router_experts), dm),
+                "router_bias": ROUTER_BIAS_STD * jax.random.normal(
+                    next(keys), (n, cfg.router_experts), jnp.float32),
+                "w_gate": dense((n, e, dm, f), dm),
+                "w_up": dense((n, e, dm, f), dm),
+                "w_down": dense((n, e, f, dm), f),
+                "shared_gate": dense((n, dm, fs), dm),
+                "shared_up": dense((n, dm, fs), dm),
+                "shared_down": dense((n, fs, dm), fs),
+            }
+
+        def gqa(n: int) -> dict:
+            return {
+                "attn_norm": jnp.ones((n, dm), dt),
+                "wq": dense((n, dm, h * dh), dm),
+                "wk": dense((n, dm, hk * dh), dm),
+                "wv": dense((n, dm, hk * dh), dm),
+                "w_gate_attn": dense((n, dm, h * dh), dm),
+                "wo": dense((n, h * dh, dm), h * dh),
+                **experts(n),
+            }
+
+        def linear(n: int) -> dict:
+            step = jnp.exp(jax.random.uniform(
+                next(keys), (n, lh * ld), jnp.float32,
+                math.log(0.001), math.log(0.1)))
+            return {
+                "attn_norm": jnp.ones((n, dm), dt),
+                "wq": dense((n, dm, lh * ld), dm),
+                "wk": dense((n, dm, lh * ld), dm),
+                "wv": dense((n, dm, lh * ld), dm),
+                "conv_w": dense((n, 3 * lh * ld, cfg.conv_kernel),
+                                cfg.conv_kernel),
+                "decay_down": dense((n, dm, r), dm),
+                "decay_up": dense((n, r, lh * ld), r, DECAY_PROJ_STD),
+                "a_log": jnp.log(jax.random.uniform(
+                    next(keys), (n, lh), jnp.float32, 1.0, 16.0)),
+                # softplus^-1(s) = ln(e^s - 1)
+                "dt_bias": jnp.log(jnp.expm1(step)),
+                "w_beta": dense((n, dm, lh), dm),
+                "out_norm": jnp.ones((n, ld), dt),
+                "gate_down": dense((n, dm, r), dm),
+                "gate_up": dense((n, r, lh * ld), r),
+                "wo": dense((n, lh * ld, dm), lh * ld),
+                **experts(n),
+            }
+
+        make = {"gqa": gqa, "linear": linear}
+        return {
+            "embed": dense((cfg.vocab_size, dm), dm),
+            "groups": {kind: make[kind](n)
+                       for kind, n in sorted(self.group_sizes.items())},
+            "final_norm": jnp.ones((dm,), dt),
+            "lm_head": dense((dm, cfg.vocab_size), dm),
+        }
+
+    def partition_specs(self) -> Params:
+        raise NotImplementedError(
+            "HybridLinearModel serves one chip's share of an expert-parallel "
+            "deployment on one chip; it has no partition specs (neither the "
+            "exchange of an expert-parallel layer nor a recurrent state "
+            "sharded over heads is built)")
+
+    def cache_spec(self, quant: bool = False):
+        if quant:
+            raise NotImplementedError("int8 K/V beside a recurrent state")
+        return {"kv": P(), "state": P(), "conv": P(), "state_pos": P(),
+                "moe_counts": P()}
+
+    # --------------------------------------------------------------- kv cache
+    def init_kv_cache(self, num_blocks: int, block_size: int, dtype=None,
+                      slots: int | None = None):
+        """``kv``: the K/V pool in LlamaModel's layout over the attending
+        layers only, [L_gqa, N, 2, Bs, Hk·D], first in the pytree's order of
+        what the engine counts a token's cache bytes by; ``state``
+        [L_lin, slots, H, d, d] float32, ``conv`` [L_lin, slots, K-1, 3·H·d]
+        and ``state_pos`` [slots] (ops/linear_state.py), indexed by the
+        engine's slot; ``moe_counts`` int32 [L, 1, 6]: what the expert
+        layers counted (as models/glm_dsa.py) and, in row 0, what the linear
+        layers did — real tokens × layers advanced, sequences started from
+        zeros, rows that continued at another position than their slot's."""
+        cfg = self.config
+        if dtype is not None and jnp.dtype(dtype) != jnp.dtype(cfg.jax_dtype):
+            raise NotImplementedError(f"K/V cache dtype {dtype!r}")
+        if slots is None:
+            raise ValueError(
+                "a recurrent state is held per engine slot: init_kv_cache "
+                "needs slots= (EngineCore passes max_batch_size)")
+        return {
+            "kv": jnp.zeros(
+                (len(cfg.gqa_layers), num_blocks, 2, block_size,
+                 cfg.num_kv_heads * cfg.head_dim), cfg.jax_dtype),
+            **linear_state.init_state(
+                cfg.linear_layers, slots, cfg.linear_heads,
+                cfg.linear_head_dim, cfg.linear_head_dim, cfg.conv_width,
+                cfg.conv_kernel, cfg.jax_dtype, self.state_dtype),
+            "moe_counts": jnp.zeros((cfg.num_layers, 1, 6), jnp.int32),
+        }
+
+    def state_bytes_per_slot(self) -> int:
+        cfg = self.config
+        per_layer = (cfg.linear_heads * cfg.linear_head_dim ** 2
+                     * jnp.dtype(self.state_dtype).itemsize
+                     + (cfg.conv_kernel - 1) * cfg.conv_width
+                     * jnp.dtype(cfg.jax_dtype).itemsize)
+        return cfg.linear_layers * per_layer
+
+    # ---------------------------------------------------------------- forward
+    def _experts(self, group: dict, lp: dict, i, h, valid):
+        """h + MoE(RMSNorm(h)) and the layer's three counts."""
+        cfg = self.config
+        b, s, d = h.shape
+        xf = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps).reshape(b * s, d)
+        with jax.named_scope("moe_router"):
+            weights, topi = moe_route(cfg, lp["router"], xf,
+                                      lp["router_bias"])
+            real = valid.reshape(b * s, 1)
+            here = ((topi >= cfg.expert_first)
+                    & (topi < cfg.expert_first + cfg.n_routed_experts) & real)
+            counted = jnp.stack([
+                real.sum(dtype=jnp.int32) * cfg.num_experts_per_tok,
+                here.sum(dtype=jnp.int32), jnp.int32(1)])
+        with jax.named_scope("moe_experts"):
+            routed = grouped_expert_dispatch(
+                xf, weights, topi, cfg.router_experts,
+                group["w_gate"], group["w_up"], group["w_down"],
+                jax.nn.silu, layer=i,
+                held=(cfg.expert_first, cfg.n_routed_experts))
+        shared = (jax.nn.silu(xf @ lp["shared_gate"])
+                  * (xf @ lp["shared_up"])) @ lp["shared_down"]
+        return h + (routed + shared).reshape(b, s, d), counted
+
+    def _gqa(self, lp, ci, h, kv, positions, block_tables, seq_lens,
+             slot_idx, prefix_blocks, by_length):
+        cfg = self.config
+        b, s, _ = h.shape
+        with jax.named_scope("attn_proj"):
+            x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+            q = split_heads(x @ lp["wq"], cfg.num_heads)
+            k = split_heads(x @ lp["wk"], cfg.num_kv_heads)
+            v = (x @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
+            gate = jax.nn.sigmoid((x @ lp["w_gate_attn"]).astype(jnp.float32))
+        with jax.named_scope("attn"):
+            fast = prefix_blocks is not None and s > 1
+            kv = write_kv_cache_layer(kv, ci, k, v, slot_idx,
+                                      block_aligned=fast)
+            if fast:
+                attn = prefill_attention(
+                    q, k, v, kv, ci, block_tables, seq_lens, positions[:, 0],
+                    prefix_blocks, sm_scale=self.sm_scale)
+            elif by_length is not None:
+                # a decode step's rows reach the kernel longest first
+                # (rows_by_length), the state stays in slot order
+                order, inverse, tables, lens, at = by_length
+                attn = paged_attention_layer(
+                    q[order], kv, ci, tables, lens, at,
+                    sm_scale=self.sm_scale)[inverse]
+            else:
+                attn = paged_attention_layer(
+                    q, kv, ci, block_tables, seq_lens, positions,
+                    sm_scale=self.sm_scale)
+        with jax.named_scope("attn_out"):
+            o = (attn.reshape(b, s, -1).astype(jnp.float32)
+                 * gate).astype(h.dtype)
+            h = h + o @ lp["wo"]
+        return h, kv
+
+    def _linear(self, lp, si, h, state, conv, rows):
+        """One linear layer on ``h`` [B, S, Dm]; ``state`` / ``conv`` are the
+        whole leaves, ``si`` this layer's row of them.  ``rows`` = (slots or
+        None, fresh [B], alive [B], n_real [B], valid [B, S])."""
+        cfg = self.config
+        b, s, _ = h.shape
+        lh, ld = cfg.linear_heads, cfg.linear_head_dim
+        slots, fresh, alive, n_real, valid = rows
+        f32 = jnp.float32
+        with jax.named_scope("attn_proj"):
+            x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+            qkv = jnp.concatenate(
+                [x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]], axis=-1)
+            a = (x @ lp["decay_down"]) @ lp["decay_up"]
+            beta_logit = x @ lp["w_beta"]
+            out_gate = (x @ lp["gate_down"]) @ lp["gate_up"]
+        with jax.named_scope("attn"), jax.named_scope("linear"):
+            if slots is None:         # row i is slot i
+                old_s, old_c = state[si], conv[si]
+            else:
+                old_s, old_c = state[si, slots], conv[si, slots]
+            zero = fresh[:, None, None]
+            tail = jnp.where(zero, 0, old_c)
+            y, new_c = linear_state.short_conv(qkv, lp["conv_w"], tail,
+                                               n_real)
+            y = jax.nn.silu(y).reshape(b, s, 3, lh, ld)
+            q, k, v = y[:, :, 0], y[:, :, 1], y[:, :, 2]
+
+            def unit(t):
+                return t * jax.lax.rsqrt(
+                    jnp.sum(t * t, axis=-1, keepdims=True) + QK_NORM_EPS)
+
+            q, k = unit(q) * ld ** -0.5, unit(k)
+            g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * jax.nn.softplus(
+                a.astype(f32).reshape(b, s, lh, ld)
+                + lp["dt_bias"].astype(f32).reshape(lh, ld))
+            beta = 2.0 * jax.nn.sigmoid(beta_logit.astype(f32))
+            # padding: an identity step
+            g = jnp.where(valid[..., None, None], g, 0.0)
+            beta = jnp.where(valid[..., None], beta, 0.0)
+            with jax.named_scope("linear_state"):
+                s0 = jnp.where(zero[..., None], 0, old_s.astype(f32))
+                if s == 1:
+                    o, new_s = linear_state.delta_rule_step(
+                        q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
+                    o = o[:, None]
+                else:
+                    o, new_s = linear_state.delta_rule_scan(
+                        q, k, v, g, beta, s0)
+                # a row with no real token keeps its slot bit for bit
+                new_s = jnp.where(alive[:, None, None, None],
+                                  new_s.astype(state.dtype), old_s)
+            new_c = jnp.where(alive[:, None, None], new_c, old_c)
+            if slots is None:
+                state = state.at[si].set(new_s)
+                conv = conv.at[si].set(new_c)
+            else:
+                state = state.at[si, slots].set(new_s)
+                conv = conv.at[si, slots].set(new_c)
+            o = rms_norm(o, lp["out_norm"], cfg.rms_norm_eps)
+            o = (o * jax.nn.sigmoid(
+                out_gate.astype(f32).reshape(b, s, lh, ld))).astype(h.dtype)
+        with jax.named_scope("attn_out"):
+            h = h + o.reshape(b, s, lh * ld) @ lp["wo"]
+        return h, state, conv
+
+    def forward(self, params, tokens, positions, cache, block_tables,
+                seq_lens, slot_idx, prefix_blocks=None, seq_slots=None):
+        """(hidden [B,S,Dm], cache).  ``seq_slots`` int32 [B]: the engine
+        slot of each row; None: row i is slot i, and B is the number of
+        slots (a decode over the slot array).  Each row's S tokens are
+        consecutive positions of one sequence, real tokens first."""
+        cfg = self.config
+        b, s = tokens.shape
+        n_slots = cache["state_pos"].shape[0]
+        if seq_slots is None and b != n_slots:
+            raise ValueError(
+                f"{b} rows without seq_slots, {n_slots} slots: a dispatch "
+                "that is not over the slot array names its rows' slots")
+        valid = slot_idx >= 0
+        n_real = valid.sum(axis=1, dtype=jnp.int32)
+        alive = n_real > 0
+        first = positions[:, 0]
+        fresh = alive & (first == 0)
+        pos = cache["state_pos"]
+        held = pos if seq_slots is None else pos[seq_slots]
+        after = jnp.where(alive, first + n_real, held)
+        state_pos = after if seq_slots is None else pos.at[seq_slots].set(after)
+        counted = jnp.stack([
+            n_real.sum(dtype=jnp.int32) * cfg.linear_layers,
+            fresh.sum(dtype=jnp.int32),
+            (alive & ~fresh & (first != held)).sum(dtype=jnp.int32)])
+        rows = (seq_slots, fresh, alive, n_real, valid)
+        by_length = None
+        if s == 1:
+            order, inverse = rows_by_length(seq_lens)
+            by_length = (order, inverse, block_tables[order],
+                         seq_lens[order], positions[order])
+        with jax.named_scope("embed"):
+            hidden = params["embed"][tokens].astype(cfg.jax_dtype)
+
+        kv, state, conv = cache["kv"], cache["state"], cache["conv"]
+        counts = cache["moe_counts"].at[0, 0, STATE_COUNTS:].add(counted)
+        expert_keys = ("w_gate", "w_up", "w_down")
+        for run in self.runs:
+            group = params["groups"][run.kind]
+            sliced = {k: v for k, v in group.items() if k not in expert_keys}
+
+            def step(carry, at, group=group, sliced=sliced, kind=run.kind):
+                h, kv, state, conv, counts = carry
+                i, li = at
+                lp = jax.tree.map(lambda a: a[i], sliced)
+                if kind == "gqa":
+                    h, kv = self._gqa(lp, i, h, kv, positions, block_tables,
+                                      seq_lens, slot_idx, prefix_blocks,
+                                      by_length)
+                else:
+                    h, state, conv = self._linear(lp, i, h, state, conv, rows)
+                with jax.named_scope("mlp"):
+                    h, picked = self._experts(group, lp, i, h, valid)
+                    counts = counts.at[li, 0, :3].add(picked)
+                return (h, kv, state, conv, counts), None
+
+            n = jnp.arange(run.count, dtype=jnp.int32)
+            (hidden, kv, state, conv, counts), _ = jax.lax.scan(
+                step, (hidden, kv, state, conv, counts),
+                (run.start + n, run.layer0 + n))
+        hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps)
+        return hidden, {"kv": kv, "state": state, "conv": conv,
+                        "state_pos": state_pos, "moe_counts": counts}
+
+    def compute_logits(self, params, hidden):
+        with jax.named_scope("logits"):
+            w = params["lm_head"]
+            return jnp.matmul(hidden.astype(w.dtype), w,
+                              preferred_element_type=jnp.float32)

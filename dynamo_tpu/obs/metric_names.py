@@ -137,6 +137,11 @@ class EngineMetric:
     MOE_HELD_PICKS_TOTAL = "dynamo_tpu_engine_moe_held_picks_total"
     MOE_EXPERT_LAYER_CALLS_TOTAL = (
         "dynamo_tpu_engine_moe_expert_layer_calls_total")
+    # what the recurrent layers did, counted on the device
+    STATE_TOKENS_TOTAL = "dynamo_tpu_engine_state_tokens_total"
+    STATE_RESETS_TOTAL = "dynamo_tpu_engine_state_resets_total"
+    STATE_POSITION_MISMATCHES_TOTAL = (
+        "dynamo_tpu_engine_state_position_mismatches_total")
     # tokens dispatched and the passes of the layer stack run for them
     LOOP_TOKENS_TOTAL = "dynamo_tpu_engine_loop_tokens_total"
     LOOP_PASSES_TOTAL = "dynamo_tpu_engine_loop_passes_total"
@@ -151,6 +156,9 @@ class EngineMetric:
     # engine/counters.py cache_shape
     CACHE_LAYERS = "dynamo_tpu_engine_cache_layers"
     KV_BYTES_PER_TOKEN = "dynamo_tpu_engine_kv_bytes_per_token"
+    STATE_LAYERS = "dynamo_tpu_engine_state_layers"
+    STATE_BYTES_PER_SLOT = "dynamo_tpu_engine_state_bytes_per_slot"
+    PREFIX_REUSE = "dynamo_tpu_engine_prefix_reuse"
 
 
 class KvTransferMetric:
@@ -272,6 +280,9 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.MOE_ROUTER_PICKS_TOTAL: ("counter", ()),
     EngineMetric.MOE_HELD_PICKS_TOTAL: ("counter", ()),
     EngineMetric.MOE_EXPERT_LAYER_CALLS_TOTAL: ("counter", ()),
+    EngineMetric.STATE_TOKENS_TOTAL: ("counter", ()),
+    EngineMetric.STATE_RESETS_TOTAL: ("counter", ()),
+    EngineMetric.STATE_POSITION_MISMATCHES_TOTAL: ("counter", ()),
     EngineMetric.MESH_TP: ("gauge", ()),
     EngineMetric.MESH_DEVICES: ("gauge", ()),
     EngineMetric.LOOP_TOKENS_TOTAL: ("counter", ()),
@@ -280,6 +291,9 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL: ("counter", ()),
     EngineMetric.CACHE_LAYERS: ("gauge", ()),
     EngineMetric.KV_BYTES_PER_TOKEN: ("gauge", ()),
+    EngineMetric.STATE_LAYERS: ("gauge", ()),
+    EngineMetric.STATE_BYTES_PER_SLOT: ("gauge", ()),
+    EngineMetric.PREFIX_REUSE: ("gauge", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.BYTES_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.SECONDS_TOTAL: ("counter", ("src", "dst", "path")),

@@ -332,9 +332,11 @@ def prefill_attention(
         from dynamo_tpu.ops.pallas.prefill_attention import (
             paged_prefill_attention,
         )
+        from dynamo_tpu.ops.pallas.registry import prefill_rows_per_chunk
 
         kernel = functools.partial(
-            paged_prefill_attention, sm_scale=sm_scale, logit_cap=logit_cap)
+            paged_prefill_attention, sm_scale=sm_scale, logit_cap=logit_cap,
+            rows_per_chunk=prefill_rows_per_chunk(q.shape[2] // tp))
         return _per_kv_head(
             kernel, tp,
             (_HEADS4, _HEADS4, _HEADS4, _CACHE) + (_REPL,) * 4, _HEADS4,

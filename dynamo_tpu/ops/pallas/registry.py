@@ -42,6 +42,8 @@ __all__ = [
     "DECODE_KEYS_PER_UPDATE_SEQ",
     "PREFILL_ROWS_PER_CHUNK",
     "PREFILL_BLOCKS_PER_CHUNK",
+    "PREFILL_QUERY_ROWS",
+    "prefill_rows_per_chunk",
     "INT8_MATMUL_BM",
     "INT8_MATMUL_BN",
     "INT8_MATMUL_BK",
@@ -95,6 +97,10 @@ DECODE_SEQS_PER_UPDATE = 4
 DECODE_KEYS_PER_UPDATE_SEQ = 32
 PREFILL_ROWS_PER_CHUNK = 128
 PREFILL_BLOCKS_PER_CHUNK = 8
+# query rows (tokens x heads) a grid step of the prefill kernel holds: its
+# float32 accumulator and running max / sum are 3 x rows x 128 lanes, 6 MB at
+# 32 heads x 128 tokens, what the kernel was sized for
+PREFILL_QUERY_ROWS = 4096
 INT8_MATMUL_BM = 128
 INT8_MATMUL_BN = 512
 INT8_MATMUL_BK = 512
@@ -364,6 +370,14 @@ def decode_kernel_cost(
     dma += b * rows * hkd * 2 * q_bytes  # q in + out
     return _cost_dict(dma, chunks * 4 * rows * t * hkd,  # QK + PV matmuls
                       chunks * rows * t)                 # softmax exp
+
+
+def prefill_rows_per_chunk(heads: int) -> int:
+    """Tokens a grid step of the prefill kernel takes for ``heads`` query
+    heads (a shard's): 128 up to 32 heads, fewer beyond, so that the
+    scratch stays inside scoped VMEM (64 heads at 128 tokens ask for 22 MB
+    of 16)."""
+    return min(PREFILL_ROWS_PER_CHUNK, max(8, PREFILL_QUERY_ROWS // heads))
 
 
 def prefill_kernel_cost(

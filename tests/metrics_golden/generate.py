@@ -71,7 +71,8 @@ def reset_producers() -> None:
               fault_counters, transfer_costs, perf_model):
         c.reset()
     mesh_shape.update(tp=1, devices=1)
-    cache_shape.update(layers=0, bytes_per_token=0)
+    cache_shape.update(layers=0, bytes_per_token=0, state_layers=0,
+                       state_bytes_per_slot=0, prefix_reuse=1)
     step_timeline.reset()
     step_timeline._clock = time.perf_counter
 
@@ -137,7 +138,9 @@ def seed_http_metrics():
     mesh_shape.update(tp=4, devices=4)
     request_counters.record_loop(300, 1200)
     request_counters.record_decode_blocks(2400, 4096)
-    cache_shape.update(layers=192, bytes_per_token=1572864)
+    cache_shape.update(layers=192, bytes_per_token=1572864, state_layers=6,
+                       state_bytes_per_slot=26050560, prefix_reuse=0)
+    request_counters.record_state(7200, 12, 0)
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()
     persist_counters.record_spill(4096)

@@ -1,0 +1,94 @@
+"""The engine's part of a recurrent state (``seq_slots`` through
+``unified_step``, the slot-indexed leaves of the cache) adds no operation to
+the programs of a model without one, and the programs of the model with one
+have the shape the engine counts on."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dynamo_tpu.engine.core import multi_decode_step, unified_step
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.llama import LlamaModel
+
+# sha256 of the lowered text of the two serving programs (``unified_step``:
+# a 16-token chunk behind one cached block; ``multi_decode_step``: 4 rows) on
+# the parent commit 0b029b6, for the Mistral toy (ModelConfig.tiny(), GQA
+# 4/2) and the latent-attention toy with an indexer (tests/test_glm_dsa.py's
+# TINY).  After a change meant to alter every model's program, print the new
+# ones with ``PYTHONPATH=. python tests/test_hybrid_linear_programs.py``.
+PARENT_HLO = {
+    ("llama", "prefill"):
+        "3048b264db3fb244f18787ac7e9735d15deebf89b10cedd2e923f1ce085fb3e6",
+    ("llama", "decode"):
+        "c32bc14ae1c45b932a441d09f5601cffa1d5e9b2afade0008ab0faa8bccb2c33",
+    ("glm", "prefill"):
+        "bcbb250b0ded0d4365c61abc7186c417890c71baab55c0dfa040bbde756ca133",
+    ("glm", "decode"):
+        "d67cc3d72b16d13c847d4a4c406b89568f7ebc76653eb7eba074232c2700410d",
+}
+BS, M, B = 16, 4, 4
+
+
+def _toy(family: str):
+    if family == "llama":
+        return LlamaModel(ModelConfig.tiny(num_kv_heads=2))
+    from test_glm_dsa import TINY
+    from dynamo_tpu.models.glm_dsa import GlmDsaConfig, GlmDsaModel
+    return GlmDsaModel(GlmDsaConfig.from_hf_config(TINY, dtype="float32"))
+
+
+def _lowered(model, program: str, **extra) -> str:
+    params = jax.eval_shape(lambda: model.init_params(jax.random.key(0)))
+    slots = {"slots": B} if getattr(model, "recurrent_state", False) else {}
+    cache = jax.eval_shape(lambda: model.init_kv_cache(8, BS, **slots))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    if program == "prefill":
+        fn = lambda p, c, *a: unified_step(model, p, c, *a, prefix_blocks=1,
+                                           **extra)
+        args = (i32(1, 16), i32(1, 16), i32(1, M), i32(1), i32(1, 16), i32(1),
+                key, f32(1), i32(1), f32(1))
+    else:
+        fn = lambda p, c, *a: multi_decode_step(
+            model, p, c, *a, num_steps=1, block_size=BS)
+        args = (i32(B), i32(B), i32(B, M), i32(B), i32(B), key, f32(B),
+                i32(B), f32(B))
+    return jax.jit(fn).lower(params, cache, *args).as_text()
+
+
+def _digest(family: str, program: str) -> str:
+    return hashlib.sha256(
+        _lowered(_toy(family), program).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("family,program", sorted(PARENT_HLO))
+def test_a_model_without_a_state_lowers_to_the_program_it_had(family, program):
+    assert _digest(family, program) == PARENT_HLO[(family, program)]
+
+
+def test_the_hybrid_model_lowers_to_one_scan_a_run_of_layers():
+    """G | L L L | G | L L L: four scans in a decode step; a 16-token chunk
+    is one piece of the recurrence, so a prefill has the same four.  The chunk is told its
+    row's slot and the decode is not (its rows are the slot array)."""
+    from hybrid_linear_tiny import build
+
+    model, _ = build()
+    assert [(r.kind, r.count) for r in model.runs] == [
+        ("gqa", 1), ("linear", 3), ("gqa", 1), ("linear", 3)]
+    loops = lambda m, program, **kw: _lowered(m, program, **kw).count(
+        "stablehlo.while")
+    # three scans more than a model whose layers are one scan
+    assert loops(model, "decode") == loops(_toy("llama"), "decode") + 3
+    slot = jnp.zeros((1,), jnp.int32)
+    assert (loops(model, "prefill", seq_slots=slot)
+            == loops(_toy("llama"), "prefill") + 3)
+    with pytest.raises(ValueError, match="names its rows' slots"):
+        _lowered(model, "prefill")                      # 1 row, 4 slots
+
+
+if __name__ == "__main__":
+    print({k: _digest(*k) for k in sorted(PARENT_HLO)})

@@ -255,8 +255,11 @@ def _abstract_model(config_file: str, place, num_blocks=None, **overrides):
              if getattr(model, "private_cache_layout", False)
              else model.partition_specs())
     params = placed(shapes, specs)
-    cache = placed(jax.eval_shape(lambda: model.init_kv_cache(num_blocks, BS)),
-                   model.cache_spec())
+    slots = ({"slots": hf["serve"]["max_batch_size"]}     # a state per slot
+             if getattr(model, "recurrent_state", False) else {})
+    cache = placed(
+        jax.eval_shape(lambda: model.init_kv_cache(num_blocks, BS, **slots)),
+        model.cache_spec())
     return hf, cfg, model, params, cache, sds
 
 
@@ -914,4 +917,52 @@ def test_mistral4_cell_programs_keep_one_cache_and_name_their_kernels(
     assert mem.temp_size_in_bytes < cache_bytes // 16, mem.temp_size_in_bytes
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0.6 * V5E_HBM < total < 0.75 * V5E_HBM, total
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_linear_cell_programs_write_the_state_in_place(
+        topo, tpu_gate, program):
+    """solar-open2-ep16's decode program (64 rows = the slot array) and a
+    512-token chunk with 16 blocks of the prompt cached, whole (8 layers, 20
+    experts, the cell's cache and its 64 slots of state): the GQA layers
+    through the Pallas kernels by name, one scan a run of layers of a kind
+    (XLA unrolls a chunk's eight 64-token pieces inside the linear runs),
+    every leaf of the cache donated and written in place — no copy of
+    the 1.6 GB state among the temporaries — and weights + state + cache
+    inside the chip (~11 GB)."""
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "solar-open2-ep16.json",
+        lambda spec: SingleDeviceSharding(topo.devices[0]))
+    serve = dict(hf["serve"])
+    assert hf["attention_layers"] == len(cfg.gqa_layers) == 2
+    fn, args = _step_program(program, model, serve, sds, prefix_blocks=16)
+    if program == "prefill":        # the engine names the row's slot
+        from dynamo_tpu.engine.core import unified_step
+
+        fn = lambda p, c, *a: unified_step(
+            model, p, c, *a[:-1], prefix_blocks=16, seq_slots=a[-1])
+        args = (*args, sds((1,)))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    assert ("paged_decode_attention" if program == "decode"
+            else "paged_prefill_attention") in hlo
+    assert "ragged-dot" in hlo
+    # G | L L L | G | L L L
+    assert hlo.count(" while(") == 4
+    # the state is sliced and updated where it lies, never copied whole
+    assert not re.search(r"f32\[6,64,64,128,128\]\S* copy\(", hlo)
+    mem = compiled.memory_analysis()
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    state, pool = nbytes(cache["state"]), nbytes(cache["kv"])
+    assert state == 6 * 64 * 64 * 128 * 128 * 4 and pool == 6272 * 32 * 8192
+    held = sum(nbytes(a) for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= held                   # all donated
+    assert mem.temp_size_in_bytes < state // 4, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"# {program}: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"total {total / 1e9:.3f} GB")
     assert 0.6 * V5E_HBM < total < 0.75 * V5E_HBM, total
