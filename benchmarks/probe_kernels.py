@@ -316,6 +316,14 @@ def main() -> None:
     variants.append((
         "latent_cache/write_rows",
         lambda: write_rows(*probe_latent_dma_inputs(1 << 16, 576, 2048))))
+    # the recurrent state's decode step at Solar-Open2's widths (64 heads of
+    # 128 x 128 float32, 16 slots of a two-layer leaf: 537 MB)
+    from dynamo_tpu.ops.pallas.linear_state import state_update
+    from dynamo_tpu.ops.pallas.registry import probe_linear_state_inputs
+
+    variants.append((
+        "linear_state/update",
+        lambda: state_update(*probe_linear_state_inputs(2, 16, 64, 128))))
     ok = all([probe(lbl, fn) for lbl, fn in variants])
     sys.exit(0 if ok else 1)
 

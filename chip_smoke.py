@@ -328,6 +328,14 @@ def _child_kernels(arg: dict) -> None:
         got = write_rows(cache, rows, slots, interpret=interpret)
         return (np.asarray(got) >> 8), (ref >> 8)
 
+    def state_step(_quant):
+        from dynamo_tpu.ops.pallas.linear_state import state_update
+
+        a = reg.probe_linear_state_inputs(2, 8, 8, 128)
+        ref = reg.linear_state_reference(*a)
+        o, new = state_update(*a, interpret=interpret)
+        return reg.linear_state_rows(o, new[a[1]]), ref
+
     # tolerances of tests/test_pallas_kernels.py: bf16 operands 3e-2;
     # the int8 matmul rtol 5e-2 / atol 0.5
     cases = {
@@ -338,6 +346,7 @@ def _child_kernels(arg: dict) -> None:
         "mla_sparse_attention": [("sparse_latent", sparse)],
         "mla_masked_prefill": [("masked_latent", masked)],
         "latent_cache_dma": [("latent_write_rows", latent_dma)],
+        "linear_state_update": [("state_step", state_step)],
     }
     live = [k for k, meta in reg.KERNELS.items() if not meta["placeholder"]]
     assert sorted(live) == sorted(cases), (live, sorted(cases))
@@ -346,7 +355,8 @@ def _child_kernels(arg: dict) -> None:
         for label, fn in cases[kernel]:
             for quant in ([False] if kernel in (
                     "int8_matmul", "mla_sparse_attention",
-                    "mla_masked_prefill", "latent_cache_dma")
+                    "mla_masked_prefill", "latent_cache_dma",
+                    "linear_state_update")
                           else [False, True]):
                 t0 = time.monotonic()
                 got, ref = (np.asarray(x, np.float32) for x in fn(quant))

@@ -223,7 +223,8 @@ def _blocked_entries(rec: dict) -> list[dict]:
                 "index_map": None,
             })
             continue
-        block = [int(x) for x in block]
+        # a squeezed dimension (None) is a block of one
+        block = [1 if x is None else int(x) for x in block]
         nbytes = _itemsize(dtype)
         for x in block:
             nbytes *= x
@@ -281,8 +282,14 @@ def _index_map_facts(rec: dict) -> dict:
     enumerate in sequential TPU order (row-major, last axis fastest) —
     the order the race check's "consecutive revisits" notion refers
     to."""
+    import numpy as np
+
     grid = rec["grid"]
     steps = list(itertools.product(*[range(int(n)) for n in grid]))
+    # an index map sees the prefetched scalars behind the grid indices, as
+    # under pallas: the audit holds them at zero
+    prefetch = [np.zeros(shape, dtype) for shape, dtype in
+                rec["operands"][:rec["num_scalar_prefetch"]]]
     oob: list[dict] = []
     races: list[dict] = []
     max_revisit = 1
@@ -297,7 +304,7 @@ def _index_map_facts(rec: dict) -> dict:
         seen: dict[tuple, list[int]] = {}
         n_oob = 0
         for pos, step in enumerate(steps):
-            idx = tuple(int(x) for x in im(*step))
+            idx = tuple(int(x) for x in im(*step, *prefetch))
             if len(idx) != len(nblocks) or any(
                     not 0 <= i < n for i, n in zip(idx, nblocks)):
                 if n_oob < _MAX_OOB_PER_OPERAND:

@@ -551,6 +551,9 @@ class EngineCore:
             int(cache["state"].shape[0]) if self._recurrent else 0)
         self.state_bytes_per_slot = (
             model.state_bytes_per_slot() if self._recurrent else 0)
+        # ... and whether the decode program updates it in one kernel
+        self.state_update_kernel = int(
+            self._recurrent and model.state_update_impl()[0] == "pallas")
         cache_shape.update(layers=self.cache_layers,
                            bytes_per_token=self.kv_bytes_per_token,
                            state_layers=self.state_layers,
@@ -1534,6 +1537,7 @@ class EngineCore:
             "state_position_mismatches_total": self.state_counts[2],
             "state_layers": self.state_layers,
             "state_bytes_per_slot": self.state_bytes_per_slot,
+            "state_update_kernel": self.state_update_kernel,
             "prefix_reuse": int(self.prefix_reuse),
             "loop_tokens_total": self.loop_tokens,
             "loop_passes_total": self.loop_passes,

@@ -56,6 +56,7 @@ from dynamo_tpu.models.llama import (
     split_heads,
 )
 from dynamo_tpu.ops import linear_state
+from dynamo_tpu.ops.pallas.linear_state import state_update
 from dynamo_tpu.ops.paged_attention import (
     paged_attention_layer,
     prefill_attention,
@@ -372,6 +373,15 @@ class HybridLinearModel:
                      * jnp.dtype(cfg.jax_dtype).itemsize)
         return cfg.linear_layers * per_layer
 
+    def state_update_impl(self) -> tuple[str, str]:
+        """("pallas" | "xla", why) for a decode step's state update: the one
+        place the choice is made, before tracing (as
+        ``paged_attention.attention_impl``)."""
+        cfg = self.config
+        return linear_state.step_impl(
+            cfg.linear_heads, cfg.linear_head_dim, cfg.linear_head_dim,
+            self.state_dtype)
+
     # ---------------------------------------------------------------- forward
     def _experts(self, group: dict, lp: dict, i, h, valid):
         """h + MoE(RMSNorm(h)) and the layer's three counts."""
@@ -448,11 +458,15 @@ class HybridLinearModel:
             a = (x @ lp["decay_down"]) @ lp["decay_up"]
             beta_logit = x @ lp["w_beta"]
             out_gate = (x @ lp["gate_down"]) @ lp["gate_up"]
+        # a decode over the slot array updates the state where it lies, in
+        # one kernel (ops/pallas/linear_state.py); a prefill chunk, and any
+        # backend but the TPU, slices it, runs ops/linear_state.py and sets it
+        in_place = (s == 1 and slots is None
+                    and self.state_update_impl()[0] == "pallas")
         with jax.named_scope("attn"), jax.named_scope("linear"):
-            if slots is None:         # row i is slot i
-                old_s, old_c = state[si], conv[si]
-            else:
-                old_s, old_c = state[si, slots], conv[si, slots]
+            at = si if slots is None else (si, slots)    # row i is slot i
+            old_c = conv[at]
+            old_s = None if in_place else state[at]
             zero = fresh[:, None, None]
             tail = jnp.where(zero, 0, old_c)
             y, new_c = linear_state.short_conv(qkv, lp["conv_w"], tail,
@@ -473,24 +487,35 @@ class HybridLinearModel:
             g = jnp.where(valid[..., None, None], g, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
             with jax.named_scope("linear_state"):
-                s0 = jnp.where(zero[..., None], 0, old_s.astype(f32))
-                if s == 1:
-                    o, new_s = linear_state.delta_rule_step(
-                        q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], s0)
+                if in_place:
+                    # the zero start and the dead row's rule are the kernel's
+                    o, state = state_update(
+                        state, si, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                        beta[:, 0], fresh, alive)
                     o = o[:, None]
                 else:
-                    o, new_s = linear_state.delta_rule_scan(
-                        q, k, v, g, beta, s0)
-                # a row with no real token keeps its slot bit for bit
-                new_s = jnp.where(alive[:, None, None, None],
-                                  new_s.astype(state.dtype), old_s)
+                    s0 = jnp.where(zero[..., None], 0, old_s.astype(f32))
+                    if s == 1:
+                        o, new_s = linear_state.delta_rule_step(
+                            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                            s0)
+                        o = o[:, None]
+                    else:
+                        o, new_s = linear_state.delta_rule_scan(
+                            q, k, v, g, beta, s0)
+                    # a row with no real token keeps its slot bit for bit
+                    new_s = jnp.where(alive[:, None, None, None],
+                                      new_s.astype(state.dtype), old_s)
             new_c = jnp.where(alive[:, None, None], new_c, old_c)
-            if slots is None:
-                state = state.at[si].set(new_s)
-                conv = conv.at[si].set(new_c)
-            else:
-                state = state.at[si, slots].set(new_s)
-                conv = conv.at[si, slots].set(new_c)
+            if not in_place:
+                state = state.at[at].set(new_s)
+            conv = conv.at[at].set(new_c)
+            if in_place:
+                # the tail is written here, before the output projection:
+                # left to float behind the experts, XLA carries the whole
+                # 57 MB leaf through the layer scan in VMEM and moves it out
+                # and back under every layer's first projection (+0.12 ms)
+                conv, o = jax.lax.optimization_barrier((conv, o))
             o = rms_norm(o, lp["out_norm"], cfg.rms_norm_eps)
             o = (o * jax.nn.sigmoid(
                 out_gate.astype(f32).reshape(b, s, lh, ld))).astype(h.dtype)

@@ -26,11 +26,13 @@ state rounded to bf16 on every read is a different model.
 
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 
 __all__ = ["CHUNK", "delta_rule_step", "delta_rule_chunk", "delta_rule_scan",
-           "short_conv", "init_state", "unit_lower_inverse"]
+           "short_conv", "init_state", "unit_lower_inverse", "step_impl"]
 
 # tokens a chunk.  The decay is per channel, so the chunk's two score
 # matrices are sums over [C, C, dk] (no product of two [C, dk] factors gives
@@ -83,11 +85,32 @@ def unit_lower_inverse(a: jax.Array) -> jax.Array:
     return t[..., 0, :, :]
 
 
+def step_impl(heads: int, dk: int, dv: int, state_dtype) -> tuple[str, str]:
+    """``("pallas" | "xla", why)`` for one token a row over the slot array:
+    on the TPU the kernel that holds a head's matrix in VMEM between the
+    products and the update (ops/pallas/linear_state.py), elsewhere — and for
+    a geometry the kernel does not tile — ``delta_rule_step``.  A static
+    function of the environment, the backend and the shapes, asked before
+    tracing."""
+    from dynamo_tpu.ops.pallas.linear_state import state_update_supported
+
+    if os.environ.get("DYNAMO_DISABLE_PALLAS"):
+        return "xla", "DYNAMO_DISABLE_PALLAS is set"
+    backend = jax.default_backend()
+    if backend != "tpu":
+        return "xla", f"backend is {backend}"
+    if not state_update_supported(heads, dk, dv, state_dtype):
+        return "xla", (f"{heads} heads of {dk} x {dv} {jnp.dtype(state_dtype)}"
+                       " do not tile")
+    return "pallas", "tpu"
+
+
 def delta_rule_step(q, k, v, g, beta, state):
     """One token a row.  q, k, g [B, H, dk]; v [B, H, dv]; beta [B, H];
-    state [B, H, dk, dv] float32 -> (o [B, H, dv], state).  The state is
-    read twice and written once: S'^T k and S'^T q in one pass
-    (o = S'^T q + (k·q) u), the update in a second."""
+    state [B, H, dk, dv] float32 -> (o [B, H, dv], state).  The CPU's path
+    and the kernel's oracle: under XLA the state is read twice and written
+    once, S'^T k and S'^T q in one pass (o = S'^T q + (k·q) u), the update
+    in a second."""
     q, k, v, g, beta = (x.astype(F32) for x in (q, k, v, g, beta))
     decayed = state * jnp.exp(g)[..., None]
     sk = (decayed * k[..., None]).sum(axis=-2)

@@ -51,6 +51,8 @@ __all__ = [
     "MLA_SPARSE_LIST_ALIGN",
     "MLA_MASKED_TOKENS_PER_TILE",
     "MLA_MASKED_KEYS_PER_TILE",
+    "LINEAR_STATE_HEADS_PER_STEP",
+    "linear_state_heads_per_step",
     "V5E_VMEM_BYTES",
     "VMEM_BUDGET_BYTES",
     "SCOPED_VMEM_BYTES",
@@ -69,6 +71,8 @@ __all__ = [
     "mla_sparse_cost",
     "mla_masked_cost",
     "latent_dma_cost",
+    "linear_state_cost",
+    "linear_state_reference",
     "decode_cost_estimate",
     "prefill_cost_estimate",
     "ragged_cost_estimate",
@@ -114,6 +118,10 @@ MLA_SPARSE_LIST_ALIGN = 1024
 # keys a grid step (scores 2 MiB, accumulator 2 MiB in f32)
 MLA_MASKED_TOKENS_PER_TILE = 16
 MLA_MASKED_KEYS_PER_TILE = 512
+# recurrent state update: heads of one slot a grid step.  16 matrices of
+# 128 x 128 float32 are 1 MiB a buffer, 4 MiB double buffered in and out,
+# and their 48 q | k | g vectors fit the one 128-row tile a step transposes
+LINEAR_STATE_HEADS_PER_STEP = 16
 
 # v5e VMEM is 128 MiB per core (accelerator guide); budget 75% of it —
 # the compiler needs headroom for spills and the double-buffer pipeline.
@@ -166,6 +174,11 @@ KERNELS = {
     },
     "mla_masked_prefill": {
         "module": "dynamo_tpu.ops.pallas.mla_masked_prefill",
+        "placeholder": False,
+    },
+    # the recurrent state's decode step, one read and one write a matrix
+    "linear_state_update": {
+        "module": "dynamo_tpu.ops.pallas.linear_state",
         "placeholder": False,
     },
     "unified_ragged_attention": {
@@ -500,6 +513,26 @@ def mla_masked_cost(s: int, c: int, h: int, dq: int, dv: int,
     return _cost_dict(
         dma=_cdiv(s, tq) * c * dq * 2 + s * c * 4 + s * h * (dq * 2 + dv * 4),
         flops=2 * pairs * (dq + dv), trans=pairs)
+
+
+def linear_state_heads_per_step(heads: int) -> int | None:
+    """Heads a grid step of the state update takes: the most, up to
+    ``LINEAR_STATE_HEADS_PER_STEP``, that divide ``heads`` into whole
+    (8, 128) tiles of their vectors; None where no such group exists."""
+    for group in range(LINEAR_STATE_HEADS_PER_STEP, 0, -8):
+        if heads % group == 0:
+            return group
+    return None
+
+
+def linear_state_cost(rows: int, heads: int, dk: int, dv: int) -> dict:
+    """A decode row reads and writes each head's float32 matrix once
+    (cellbench/costs/linear_state.py counts the same 2·H·dk·dv·4 bytes) and
+    spends ~7 operations an element: the decay, two products with their
+    sums, the rank-one update."""
+    cells = rows * heads * dk * dv
+    return _cost_dict(dma=2 * cells * 4 + rows * heads * (3 * dk + 2 * dv) * 4,
+                      flops=7 * cells, trans=rows * heads * dk)
 
 
 def latent_dma_cost(rows: int, row_bytes: int) -> dict:
@@ -1171,6 +1204,86 @@ def _latent_dma_case(kind: str) -> dict:
     }
 
 
+def linear_state_rows(o, layer_state):
+    """``o`` [B, H, dv] beside a layer's state [B, H, dk, dv], a row a slot."""
+    import jax.numpy as jnp
+
+    b = o.shape[0]
+    return jnp.concatenate(
+        [o.reshape(b, -1), layer_state.reshape(b, -1)], axis=1)
+
+
+def linear_state_reference(state, layer, q, k, v, g, beta, fresh, alive):
+    """What ``linear_state.state_update`` must give, by ``delta_rule_step``
+    under XLA, as ``linear_state_rows``: a fresh slot from zeros, a dead slot's
+    state as it was and its ``o`` zero."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops.linear_state import delta_rule_step
+
+    old = state[layer]
+    o, new = delta_rule_step(
+        q, k, v, g, beta, jnp.where(fresh[:, None, None, None], 0, old))
+    return linear_state_rows(jnp.where(alive[:, None, None], o, 0),
+                             jnp.where(alive[:, None, None, None], new, old))
+
+
+def _linear_state_case() -> dict:
+    """Four slots of eight heads, layer 1 of a two-layer leaf: slot 1 starts
+    afresh, slot 2 has no token.  The poisoned run fills both with NaN
+    beforehand: the fresh row reads as from zeros, the dead one keeps its
+    NaN (not live) and gives an ``o`` of exact zeros.  Output: ``o`` and the
+    layer's new state, a row a slot."""
+    import jax.numpy as jnp
+
+    np = _np()
+    n_layers, b, h, d, layer = 2, 4, 8, 128, 1
+    fresh = np.array([False, True, False, False])
+    alive = np.array([True, True, False, True])
+
+    def build():
+        rng = np.random.default_rng(800)
+        unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+        f32 = lambda x: jnp.asarray(x, jnp.float32)
+        return {"q": f32(unit(rng.normal(size=(b, h, d))) * d ** -0.5),
+                "k": f32(unit(rng.normal(size=(b, h, d)))),
+                "v": f32(rng.normal(size=(b, h, d))),
+                "g": f32(-0.5 * rng.random(size=(b, h, d))),
+                "beta": f32(2 / (1 + np.exp(-rng.normal(size=(b, h))))),
+                "state": rng.normal(size=(n_layers, b, h, d, d)).astype(
+                    np.float32)}
+
+    def run(inp, poisoned: bool):
+        from dynamo_tpu.ops.pallas import linear_state as kernel
+
+        state = inp["state"].copy()
+        if poisoned:
+            state[layer, fresh | ~alive] = np.nan
+        o, new = kernel.state_update.__wrapped__(
+            jnp.asarray(state), jnp.int32(layer), inp["q"], inp["k"],
+            inp["v"], inp["g"], inp["beta"], jnp.asarray(fresh),
+            jnp.asarray(alive), interpret=True)
+        return linear_state_rows(o, new[layer])
+
+    def oracle(inp):
+        ref = np.asarray(linear_state_reference(
+            jnp.asarray(inp["state"]), layer, inp["q"], inp["k"], inp["v"],
+            inp["g"], inp["beta"], jnp.asarray(fresh), jnp.asarray(alive)))
+        live = np.broadcast_to(alive[:, None], ref.shape).copy()
+        zero = np.zeros(ref.shape, bool)
+        zero[~alive, :h * d] = True
+        return ref, live, zero
+
+    def pricing():
+        return linear_state_cost(b, h, d, d)
+
+    return {
+        "name": "state-step", "kernel": "linear_state_update",
+        "mode": "interpret", "atol": 1e-5,
+        "build": build, "run": run, "oracle": oracle, "pricing": pricing,
+    }
+
+
 # ---------------------------------------------- serving-scale (spec) ----
 
 
@@ -1283,6 +1396,7 @@ def audit_cases() -> list[dict]:
         _mla_masked_case(),
         _latent_dma_case("write"),
         _latent_dma_case("gather"),
+        _linear_state_case(),
         _spec_decode_8b(),
         _spec_prefill_8b(),
     ]
@@ -1464,7 +1578,30 @@ def probe_mla_masked_inputs(s, c, h, dq):
             jnp.where(jnp.asarray(mask), 0.0, -1e30).astype(jnp.float32))
 
 
+def probe_linear_state_inputs(layers, slots, heads, d):
+    """state [L,B,H,d,d] f32, layer, q, k, v, g [B,H,d], beta [B,H], fresh,
+    alive [B] (every eighth slot idle, one starting afresh)."""
+    import jax
+    import jax.numpy as jnp
+
+    np = _np()
+    rng = np.random.default_rng(0)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    at = np.arange(slots)
+    return (jax.random.normal(jax.random.PRNGKey(0),
+                              (layers, slots, heads, d, d), jnp.float32),
+            jnp.int32(layers - 1),
+            f32(unit(rng.normal(size=(slots, heads, d))) * d ** -0.5),
+            f32(unit(rng.normal(size=(slots, heads, d)))),
+            f32(rng.normal(size=(slots, heads, d))),
+            f32(-0.1 * rng.random(size=(slots, heads, d))),
+            f32(2 * rng.random(size=(slots, heads))),
+            jnp.asarray(at == 1), jnp.asarray(at % 8 != 7))
+
+
 _PROBE_BUILDERS = {
+    "linear_state_update": probe_linear_state_inputs,
     "mla_masked_prefill": probe_mla_masked_inputs,
     "mla_sparse_attention": probe_mla_sparse_inputs,
     "latent_cache_dma": probe_latent_dma_inputs,

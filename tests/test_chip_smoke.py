@@ -63,8 +63,9 @@ def test_tiny_reports_what_the_server_ran(tiny):
     assert warm and " 0 hits" not in warm[0]
     kernels = [l for l in tiny.lines if l.startswith("  kernel ")]
     # nine lines of the GQA kernels and the int8 matmul, then the sparse
-    # and the masked latent-attention kernels and the latent cache's writes
-    assert len(kernels) == 12 and all(
+    # and the masked latent-attention kernels, the latent cache's writes
+    # and the recurrent state's decode step
+    assert len(kernels) == 13 and all(
         l.endswith("PASS") and "interpret=True" in l for l in kernels)
     assert any("sparse_latent" in l for l in kernels)
     assert any("masked_latent" in l for l in kernels)

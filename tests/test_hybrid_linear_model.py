@@ -236,3 +236,44 @@ def test_the_seeded_decay_remembers():
     assert np.mean(alpha < 0.5) < 0.2          # and few forget at once
     # the token moves the decay: it is a gate, not a constant
     assert np.std(np.asarray(g), axis=0).mean() > 0.01 * -np.mean(np.asarray(g))
+
+
+def test_a_decode_through_the_state_kernel_is_the_decode_through_xla(
+        monkeypatch):
+    """The decode branch of ``_linear`` that the TPU takes (the state updated
+    where it lies by ops/pallas/linear_state.py, here interpreted), against
+    the slice / ``delta_rule_step`` / set form on the same cache: the live
+    rows' log-probabilities, state and ``conv``, the idle slots bit for bit,
+    ``state_pos`` and the counts equal.  Heads of 128 x 128: what the kernel tiles."""
+    import functools
+
+    from dynamo_tpu.models import hybrid_linear
+    from dynamo_tpu.ops.pallas.linear_state import state_update
+
+    cfg = dict(TINY, num_hidden_layers=4, gqa_layers=[0], linear_attn_config={
+        "short_conv_kernel_size": 4, "head_dim": 128, "num_heads": 8,
+        "num_kv_heads": None})
+    model, params = build(cfg)
+    assert model.state_update_impl() == ("xla", "backend is cpu")
+    toks = tokens_of(30, 4)
+    cache = fresh_cache(model)
+    _, cache = chunk(model, params, cache, toks, 0, 24, 2, 1)
+    # slot 1 holds what a finished request left; slot 3 starts at position 0
+    cache["state"] = cache["state"].at[:, 1].set(7.0)
+    rows = {2: (24, 1, toks[24]), 3: (0, 30, toks[0])}
+    want_lp, want_cache = decode(model, params, cache, rows)
+    monkeypatch.setattr(model, "state_update_impl", lambda: ("pallas", "test"))
+    monkeypatch.setattr(hybrid_linear, "state_update",
+                        functools.partial(state_update, interpret=True))
+    got_lp, got_cache = decode(model, params, jax.tree.map(jnp.array, cache),
+                               rows)
+    assert np.abs(got_lp[[2, 3]] - want_lp[[2, 3]]).max() < 1e-4
+    got_s, want_s = (np.asarray(c["state"]) for c in (got_cache, want_cache))
+    assert np.abs(got_s[:, [2, 3]] - want_s[:, [2, 3]]).max() < 1e-5
+    assert np.array_equal(got_s[:, [0, 1]], np.asarray(cache["state"])[:, [0, 1]])
+    # a later layer's inputs carry the earlier layers' rounding
+    assert np.abs(np.asarray(got_cache["conv"])
+                  - np.asarray(want_cache["conv"])).max() < 1e-4
+    for leaf in ("state_pos", "moe_counts"):
+        assert np.array_equal(np.asarray(got_cache[leaf]),
+                              np.asarray(want_cache[leaf]))

@@ -36,6 +36,8 @@ def test_engine_serves_it_in_chunks_then_decodes_against_the_reference():
     assert (m["state_layers"], m["cache_layers"]) == (6, 2)
     assert m["kv_bytes_per_token"] == 2 * 2 * 2 * 16 * 4      # 2 layers, K+V
     assert m["state_bytes_per_slot"] == 6 * (4 * 16 * 16 * 4 + 3 * 192 * 4)
+    # off the TPU the decode program steps the state through XLA
+    assert m["state_update_kernel"] == 0
     assert m["ahead_dispatches_total"] > 0
 
 
@@ -134,6 +136,7 @@ def test_the_engine_refuses_what_would_lose_the_state_and_reuses_no_block():
                        eos_token_ids=[])
     assert other.metrics()["prefix_reuse"] == 1
     assert other.metrics()["state_layers"] == 0
+    assert other.metrics()["state_update_kernel"] == 0
 
 
 def test_a_decode_dispatch_leaves_idle_and_prefilling_slots_bit_for_bit():

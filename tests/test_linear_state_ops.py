@@ -141,3 +141,97 @@ def test_state_leaves_have_the_stated_layout():
     assert ls.CHUNK == 64
     with pytest.raises(ValueError, match="power of two"):
         ls.unit_lower_inverse(jnp.zeros((3, 3)))
+
+
+# ----- the decode step's kernel (ops/pallas/linear_state.py), interpreted ----
+# two groups of eight heads a slot: a dead slot's steps name another's block
+KL, KB, KH, KD, KG = 3, 4, 16, 128, 8
+_ALL, _NONE = (True,) * KB, (False,) * KB
+
+
+@pytest.mark.parametrize("layer,alive,fresh,poison,decay,beta_shift,like_keys", [
+    (1, _ALL, _NONE, (), 0.5, 0.0, False),
+    # three dead slots full of NaN: bit for bit afterwards, and the live
+    # row's o and state as if they held numbers
+    (1, (True, False, False, False), _NONE, (1, 2, 3), 0.5, 0.0, False),
+    # ... in front of the first live slot, and with no slot alive at all
+    (1, (False, False, True, True), _NONE, (0, 1), 0.5, 0.0, False),
+    (1, _NONE, _NONE, (0, 2), 0.5, 0.0, False),
+    # a fresh row over a slot full of NaN reads as from zeros
+    (1, _ALL, (False, True, False, False), (1,), 0.5, 0.0, False),
+    (1, _ALL, _NONE, (), 50.0, 0.0, False),
+    (1, _ALL, _NONE, (), 1e-4, 0.0, False),
+    (1, _ALL, _NONE, (), 0.05, 5.0, True),
+    (0, (True, True, False, True), (True, False, False, False), (2,), 0.5,
+     0.0, False),
+    (KL - 1, (True, True, False, True), (False, False, False, True), (2,),
+     0.5, 0.0, False),
+], ids=["all-alive", "quarter-alive-poisoned", "dead-in-front", "none-alive",
+        "fresh-over-poison", "strong-decay", "no-decay", "beta-2-like-keys",
+        "layer-0", "layer-last"])
+def test_state_update_kernel_is_the_token_step(layer, alive, fresh, poison,
+                                               decay, beta_shift, like_keys):
+    from dynamo_tpu.ops.pallas.linear_state import state_update
+
+    ks = jax.random.split(jax.random.PRNGKey(layer), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (KB, KH, KD))) * KD ** -0.5
+    k = jax.random.normal(ks[1], (KB, KH, KD))
+    if like_keys:
+        k = k[:1] + 0.05 * k
+    k = unit(k)
+    v = jax.random.normal(ks[2], (KB, KH, KD))
+    g = -decay * jax.random.uniform(ks[3], (KB, KH, KD))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (KB, KH)) + beta_shift)
+    clean = jax.random.normal(ks[5], (KL, KB, KH, KD, KD))
+    alive, fresh = np.asarray(alive), np.asarray(fresh)
+    held = np.array(clean)
+    held[layer, list(poison)] = np.nan
+    # the oracle never sees the poison: a fresh row starts from zeros
+    start = jnp.where(fresh[:, None, None, None], 0, clean[layer])
+    want_o, want_s = ls.delta_rule_step(q, k, v, g, beta, start)
+    got_o, got = state_update(jnp.asarray(held), jnp.int32(layer), q, k, v,
+                              g, beta, jnp.asarray(fresh), jnp.asarray(alive),
+                              heads_per_step=KG, interpret=True)
+    got_o, got = np.asarray(got_o), np.asarray(got)
+    others = [i for i in range(KL) if i != layer]
+    assert np.array_equal(got[others], held[others], equal_nan=True)
+    assert np.array_equal(got[layer][~alive], held[layer][~alive],
+                          equal_nan=True)
+    assert np.array_equal(got_o[~alive], np.zeros_like(got_o[~alive]))
+    if alive.any():
+        assert not np.isnan(got[layer][alive]).any()
+        scale = max(1.0, float(np.abs(want_s).max()))
+        assert np.abs(got[layer] - want_s)[alive].max() < 2e-6 * scale
+        assert np.abs(got_o - want_o)[alive].max() < 2e-6 * scale
+
+
+def test_a_dead_slot_names_the_block_that_is_resident():
+    """What a grid step's matrices are by ``_resident``: a live slot's own
+    (group -1); a dead slot's the last group of the live slot before it, so
+    that the pipeline sees the index it has and moves nothing; the dead
+    slots in front of the first live one that one's first group; slot 0's
+    first with nobody alive (the kernel then copies it through)."""
+    from dynamo_tpu.ops.pallas.linear_state import _resident
+
+    def named(*alive):
+        row, group = _resident(jnp.asarray(alive), 4)
+        return list(zip(np.asarray(row).tolist(), np.asarray(group).tolist()))
+
+    assert named(True, True, True) == [(0, -1), (1, -1), (2, -1)]
+    assert named(True, False, False, True, False) == [
+        (0, -1), (0, 3), (0, 3), (3, -1), (3, 3)]
+    assert named(False, False, True, False) == [(2, 0), (2, 0), (2, -1), (2, 3)]
+    assert named(False, False) == [(0, 0), (0, 0)]
+
+
+def test_state_update_rule_is_the_oracle_off_the_tpu(monkeypatch):
+    """On the CPU, and for a state the kernel does not tile, the step is
+    ``delta_rule_step``; on the TPU at the published widths the kernel."""
+    assert ls.step_impl(64, 128, 128, jnp.float32) == ("xla", "backend is cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ls.step_impl(64, 128, 128, jnp.float32) == ("pallas", "tpu")
+    assert ls.step_impl(64, 128, 128, jnp.bfloat16)[0] == "xla"
+    assert ls.step_impl(4, 16, 16, jnp.float32)[0] == "xla"
+    monkeypatch.setenv("DYNAMO_DISABLE_PALLAS", "1")
+    assert ls.step_impl(64, 128, 128, jnp.float32)[0] == "xla"

@@ -930,7 +930,9 @@ def test_hybrid_linear_cell_programs_write_the_state_in_place(
     (XLA unrolls a chunk's eight 64-token pieces inside the linear runs),
     every leaf of the cache donated and written in place — no copy of
     the 1.6 GB state among the temporaries — and weights + state + cache
-    inside the chip (~11 GB)."""
+    inside the chip (~11 GB).  The decode program updates the state by the
+    kernel of ops/pallas/linear_state.py (compiled here for the v5e with no
+    VMEM limit of its own: inside the compiler's scoped 16 MiB)."""
     hf, cfg, model, params, cache, sds = _abstract_model(
         "solar-open2-ep16.json",
         lambda spec: SingleDeviceSharding(topo.devices[0]))
@@ -953,6 +955,32 @@ def test_hybrid_linear_cell_programs_write_the_state_in_place(
     assert hlo.count(" while(") == 4
     # the state is sliced and updated where it lies, never copied whole
     assert not re.search(r"f32\[6,64,64,128,128\]\S* copy\(", hlo)
+    if program == "decode":
+        # ... and by one kernel a linear layer, inside the scope that
+        # kernel.linear_attn_roofline reads (readers/scope_roofline.py):
+        # no XLA pass over a layer's 268 MB is left under it
+        calls = [line for line in hlo.splitlines()
+                 if "custom-call(" in line and "linear_state_update" in line]
+        assert len(calls) == 2                     # one a run of L L L
+        for line in calls:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "linear_state" in op_name.split("/"), op_name
+        assert "f32[64,64,128,128]" not in hlo
+        # the convolution's tails are written before the output projection
+        # (an optimization_barrier in _linear): left to float behind the
+        # experts, the whole 57 MB leaf rode the layer scan in VMEM and was
+        # copied out and back under every layer's first projection
+        assert not re.search(
+            r"bf16\[6,64,3,24576\]\{3,2,1,0:[^}]*S\(1\)\}", hlo)
+        assert model.state_update_impl() == ("pallas", "tpu")
+        from dynamo_tpu.ops.pallas import registry
+
+        # 16 heads' matrices a grid step, double buffered in and out: 4 MiB
+        # of the 16 the compiler gives a kernel that asks for no more
+        group = registry.linear_state_heads_per_step(64)
+        assert group == registry.LINEAR_STATE_HEADS_PER_STEP == 16
+        assert (2 * registry.DOUBLE_BUFFER * group * 128 * 128 * 4
+                == registry.SCOPED_VMEM_BYTES // 4)
     mem = compiled.memory_analysis()
     nbytes = lambda a: a.size * a.dtype.itemsize
     state, pool = nbytes(cache["state"]), nbytes(cache["kv"])
