@@ -14,6 +14,7 @@ KN006 census flags any registered kernel that loses probe coverage).
 
 Usage:  python benchmarks/probe_kernels.py [bf16|int8|all] [8b|1b|probe]
         python benchmarks/probe_kernels.py lengths [out.json]   # decode sweep
+        python benchmarks/probe_kernels.py experts [out.json [cell:call,...]]  # grouped matmul
 """
 
 from __future__ import annotations
@@ -100,21 +101,43 @@ def decode_length_mixes(rows: int, rng) -> dict:
     }
 
 
+def profiled_device_ns(fn, args, calls: int) -> dict:
+    """{line name: [(event name, duration ns)]} of the first TPU's ``XLA
+    Ops`` and ``XLA Modules`` lines over ``calls`` calls of ``fn`` under the
+    profiler, after one call outside it.  An op event's name is its HLO
+    instruction: a custom call's begins with the kernel's name (its users
+    only mention it)."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as tmp:
+        with jax.profiler.trace(tmp):
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        path = glob.glob(f"{tmp}/plugins/profile/*/*.xplane.pb")[0]
+        data = ProfileData.from_file(path)
+    return {line.name: [(e.name, e.duration_ns) for e in line.events]
+            for p in data.planes if p.name == "/device:TPU:0"
+            for line in p.lines if line.name in ("XLA Ops", "XLA Modules")}
+
+
 def time_decode_lengths(out_path: str | None) -> None:
     """µs a call of ``paged_decode_attention_mq`` (the custom call's own
     device time, from a profile of 20 calls) and GB/s of the bytes the
     contexts hold, rows in slot order and grouped by length.  (Run with
     this file copied over the parent of PR 40, the same table reads the
     kernel that fetched every row up to its group's longest.)"""
-    import glob
     import inspect
     import json
-    import tempfile
 
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.profiler import ProfileData
 
     from dynamo_tpu.ops.pallas import decode_attention as da
     from dynamo_tpu.ops.pallas.registry import decode_tiling
@@ -127,20 +150,9 @@ def time_decode_lengths(out_path: str | None) -> None:
     print(f"# device {jax.devices()[0].device_kind}")
 
     def kernel_us(fn, args) -> float:
-        jax.block_until_ready(fn(*args))
-        with tempfile.TemporaryDirectory() as tmp:
-            with jax.profiler.trace(tmp):
-                for _ in range(calls):
-                    out = fn(*args)
-                jax.block_until_ready(out)
-            path = glob.glob(f"{tmp}/plugins/profile/*/*.xplane.pb")[0]
-            data = ProfileData.from_file(path)
-        # an event's name is its HLO instruction: the custom call's begins
-        # with the kernel's name (its users only mention it)
-        took = [e.duration_ns for p in data.planes if p.name == "/device:TPU:0"
-                for line in p.lines if line.name == "XLA Ops"
-                for e in line.events
-                if e.name.startswith("%paged_decode_attention")]
+        took = [ns for name, ns in
+                profiled_device_ns(fn, args, calls)["XLA Ops"]
+                if name.startswith("%paged_decode_attention")]
         assert len(took) == calls, (len(took), calls)
         return float(np.median(took)) / 1e3
 
@@ -192,10 +204,170 @@ def time_decode_lengths(out_path: str | None) -> None:
             json.dump(table, f, indent=1)
 
 
+# The experts' grouped matmul alone at the MoE cells' shapes: (router's
+# experts, experts held here, top-k, Dm, F) and the dispatches a cell runs
+# (tokens, of which live: an idle decode slot is token 0 at position 0, so
+# all idle rows pick the same experts).
+EXPERT_GEOMS = {
+    "qwen3-30b-a3b": dict(router=128, held=128, k=8, dm=2048, f=768,
+                          calls={"decode": (32, 3), "chunk": (512, 512)}),
+    "solar-open2-ep16": dict(router=320, held=20, k=8, dm=4096, f=1280,
+                             calls={"decode": (64, 64), "chunk": (512, 512)}),
+    "glm-5.2-ep16": dict(router=256, held=16, k=8, dm=6144, f=2048,
+                         calls={"decode": (32, 32), "question": (128, 128),
+                                "chunk": (2048, 2048)}),
+    "mistral-small-4-ep8": dict(router=128, held=16, k=4, dm=4096, f=2048,
+                                calls={"decode": (32, 32),
+                                       "question": (256, 256),
+                                       "chunk": (2048, 2048)}),
+}
+# rows an expert for the threshold's sweep, every held expert alike
+EXPERT_ROWS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024)
+
+
+def expert_group_sizes(rng, tokens: int, live: int, router: int, held: int,
+                       k: int):
+    """Rows each held expert gets when ``live`` tokens pick k distinct
+    experts of ``router`` at random and the other tokens all pick the same
+    k: int32 [held] (the held experts are the first ``held``)."""
+    import numpy as np
+
+    picks = [rng.permutation(router)[:k] for _ in range(live)]
+    picks += [rng.permutation(router)[:k]] * (tokens - live)
+    flat = np.concatenate(picks)
+    return np.bincount(flat[flat < held], minlength=held).astype(np.int32)
+
+
+def time_experts(out_path: str | None, only: str | None = None) -> None:
+    """us a call of the grouped matmul (the custom call's own device time,
+    median of 20 in a profile) and GB/s of the touched experts' weights, the
+    kernel beside ``lax.ragged_dot``, both reading layer L - 1 of a stacked
+    [L, E, K, N] array in place: each cell's dispatches, a decode's also at
+    the two next-narrower slices of N, and rows an expert from 1 to 1,024 at
+    Qwen3's and GLM's expert sizes (the sweep behind
+    ``GROUPED_MATMUL_MAX_ROWS_PER_GROUP``).  ``only``: the dispatches to
+    time, ``cell:call,...``, and no sweep."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops.pallas.grouped_matmul import (
+        grouped_expert_matmul, grouped_matmul_plan,
+    )
+    from dynamo_tpu.ops.pallas.registry import (
+        grouped_matmul_row_tile, grouped_matmul_tiling,
+    )
+
+    layers, calls = 2, 20
+    print(f"# device {jax.devices()[0].device_kind}")
+
+    def device_us(fn, args, prefix, ops_a_call=1) -> tuple[float, float]:
+        """(the named operations, the whole program) a call, in us."""
+        lines = profiled_device_ns(fn, args, calls)
+        ops = [ns for name, ns in lines["XLA Ops"] if name.startswith(prefix)]
+        programs = [ns for _, ns in lines["XLA Modules"]]
+        assert len(ops) == calls * ops_a_call, (prefix, len(ops))
+        return (float(np.median(ops)) * ops_a_call / 1e3,
+                float(np.median(programs[-calls:])) / 1e3)
+
+    def measure(row, sizes, m, k, n, stacks, slices):
+        """One shape over ``stacks`` weight arrays (gate and up: 2, one call
+        of the kernel and two of ragged_dot; down: 1): ragged_dot, then the
+        kernel at each slice of N a weight block."""
+        e = sizes.shape[0]
+        touched = int((sizes > 0).sum())
+        weight_bytes = stacks * touched * k * n * 2
+        kx, *kws = jax.random.split(jax.random.key(50), 1 + stacks)
+        xs = jax.random.normal(kx, (m, k), jnp.bfloat16)
+        ws = tuple(jax.random.normal(kw, (layers, e, k, n), jnp.bfloat16)
+                   for kw in kws)
+        li = jnp.int32(layers - 1)
+        gs = jnp.asarray(sizes)
+
+        def xla(xs, ws, gs, li):
+            full = jax.lax.dynamic_update_slice(
+                jnp.zeros(layers * e, jnp.int32), gs, (li * e,))
+            return [jax.lax.ragged_dot(xs, w.reshape(layers * e, k, n), full)
+                    for w in ws]
+
+        row = dict(row, m=m, k=k, n=n, stacks=stacks, touched=touched,
+                   weight_mb=round(weight_bytes / 1e6, 1))
+        us, whole = device_us(jax.jit(xla), (xs, ws, gs, li),
+                              "%ragged-dot-none", stacks)
+        row["ragged_dot_us"] = round(us, 1)
+        row["ragged_dot_program_us"] = round(whole, 1)
+        row["ragged_dot_gb_s"] = round(weight_bytes / us / 1e3, 1)
+        want = np.asarray(jax.jit(xla)(xs, ws, gs, li)[-1], np.float32)
+        rows = int(sizes.sum())
+        tm = grouped_matmul_row_tile(m, max(k, n))
+        for tn in slices:
+            def kernel(xs, ws, gs, li, tn=tn):
+                plan = grouped_matmul_plan(gs, m, tm)
+                return grouped_expert_matmul(
+                    xs, tuple(w.reshape(layers * e, k, n) for w in ws),
+                    plan, li * e, tm=tm, tn=tn)
+            fn = jax.jit(kernel)
+            got = np.asarray(fn(xs, ws, gs, li)[-1], np.float32)
+            worst = float(np.abs(got[:rows] - want[:rows]).max())
+            us, whole = device_us(fn, (xs, ws, gs, li),
+                                  "%grouped_expert_matmul")
+            out = dict(row, tm=tm, tn=tn,
+                       kernel_us=round(us, 1),
+                       kernel_program_us=round(whole, 1),
+                       kernel_gb_s=round(weight_bytes / us / 1e3, 1),
+                       worst_abs_diff=round(worst, 4))
+            table.append(out)
+            print(json.dumps(out), flush=True)
+
+    def rule(m, k, n, stacks):
+        return grouped_matmul_tiling(grouped_matmul_row_tile(m, max(k, n)),
+                                     k, n, weights=stacks)
+
+    def slices_round(m, k, n, stacks):
+        """The registry's slice of N, then the two next narrower."""
+        tn = rule(m, k, n, stacks)
+        slices = [t for t in range(128, n + 1, 128) if n % t == 0]
+        at = slices.index(tn)
+        return [tn] + [t for t in slices[max(at - 2, 0):at]
+                       if k * t * 2 >= 1 << 20]
+
+    table: list = []
+    rng = np.random.default_rng(50)
+    for cell, g in EXPERT_GEOMS.items():
+        for call, (tokens, live) in g["calls"].items():
+            if only and f"{cell}:{call}" not in only.split(","):
+                continue
+            sizes = expert_group_sizes(rng, tokens, live, g["router"],
+                                       g["held"], g["k"])
+            m = tokens * g["k"]
+            for proj, k, n, stacks in (("gate+up", g["dm"], g["f"], 2),
+                                       ("down", g["f"], g["dm"], 1)):
+                measure({"cell": cell, "call": call, "proj": proj}, sizes, m,
+                        k, n, stacks, slices_round(m, k, n, stacks)
+                        if call == "decode" else [rule(m, k, n, stacks)])
+    for cell in () if only else ("qwen3-30b-a3b", "glm-5.2-ep16"):
+        g = EXPERT_GEOMS[cell]
+        for per in EXPERT_ROWS:
+            sizes = np.full(g["held"], per, np.int32)
+            m = per * g["held"]
+            measure({"cell": cell, "call": f"rows {per}", "proj": "gate+up"},
+                    sizes, m, g["dm"], g["f"], 2,
+                    [rule(m, g["dm"], g["f"], 2)])
+    if out_path:
+        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump(table, f, indent=1)
+
+
 def main() -> None:
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which == "topk":
         time_topk()
+        return
+    if which == "experts":
+        time_experts(*sys.argv[2:4])
         return
     if which == "lengths":
         time_decode_lengths(sys.argv[2] if len(sys.argv) > 2 else None)
@@ -324,6 +496,21 @@ def main() -> None:
     variants.append((
         "linear_state/update",
         lambda: state_update(*probe_linear_state_inputs(2, 16, 64, 128))))
+    # the experts' grouped matmul at Solar-Open2's decode shape (512 sorted
+    # rows, 32 of them on 20 held experts of 4,096 x 1,280, layer 1 of two)
+    from dynamo_tpu.ops.pallas import grouped_matmul as gmm
+    from dynamo_tpu.ops.pallas.registry import (
+        grouped_matmul_row_tile, probe_grouped_matmul_inputs,
+    )
+
+    def experts():
+        xs, w, sizes, first = probe_grouped_matmul_inputs(
+            512, 2, 20, 4096, 1280, 32)
+        tm = grouped_matmul_row_tile(512, 4096)
+        plan = gmm.grouped_matmul_plan(sizes, 512, tm)
+        return gmm.grouped_expert_matmul(xs, (w, w), plan, first, tm=tm)
+
+    variants.append(("experts/grouped_matmul", experts))
     ok = all([probe(lbl, fn) for lbl, fn in variants])
     sys.exit(0 if ok else 1)
 

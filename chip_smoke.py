@@ -336,6 +336,18 @@ def _child_kernels(arg: dict) -> None:
         o, new = state_update(*a, interpret=interpret)
         return reg.linear_state_rows(o, new[a[1]]), ref
 
+    def experts(_quant):
+        from dynamo_tpu.ops.pallas import grouped_matmul as gmm
+
+        # 40 of 192 sorted rows in groups, the last layer of a stack of two
+        xs, w, sizes, first = reg.probe_grouped_matmul_inputs(
+            192, 2, 8, 256, 384, 40)
+        tm = reg.grouped_matmul_row_tile(192, 384)
+        plan = gmm.grouped_matmul_plan(sizes, 192, tm)
+        got, = gmm.grouped_expert_matmul(xs, (w,), plan, first, tm=tm,
+                                         interpret=interpret)
+        return got, reg.grouped_matmul_reference(xs, w, sizes, first)
+
     # tolerances of tests/test_pallas_kernels.py: bf16 operands 3e-2;
     # the int8 matmul rtol 5e-2 / atol 0.5
     cases = {
@@ -347,6 +359,7 @@ def _child_kernels(arg: dict) -> None:
         "mla_masked_prefill": [("masked_latent", masked)],
         "latent_cache_dma": [("latent_write_rows", latent_dma)],
         "linear_state_update": [("state_step", state_step)],
+        "grouped_expert_matmul": [("experts", experts)],
     }
     live = [k for k, meta in reg.KERNELS.items() if not meta["placeholder"]]
     assert sorted(live) == sorted(cases), (live, sorted(cases))
@@ -356,7 +369,7 @@ def _child_kernels(arg: dict) -> None:
             for quant in ([False] if kernel in (
                     "int8_matmul", "mla_sparse_attention",
                     "mla_masked_prefill", "latent_cache_dma",
-                    "linear_state_update")
+                    "linear_state_update", "grouped_expert_matmul")
                           else [False, True]):
                 t0 = time.monotonic()
                 got, ref = (np.asarray(x, np.float32) for x in fn(quant))

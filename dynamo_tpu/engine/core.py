@@ -299,8 +299,10 @@ def unified_token_step(
 
 @jax.jit
 def expert_totals(moe_counts):
-    """int32 [3] — router picks, picks on the experts held here, expert-layer
-    calls — summed over the layers of a cache's ``moe_counts`` [L, 1, 3].  A
+    """int32 [4] — router picks, picks on the experts held here, expert-layer
+    calls, experts with a row (whose weights a layer read) — summed over the
+    layers of a cache's ``moe_counts`` [L, 1, 4]; a model with recurrent
+    layers appends its own three (models/hybrid_linear.py).  A
     buffer of its own: the cache is donated to the next dispatch, this is
     read back with the dispatch's outputs."""
     return moe_counts.sum(axis=(0, 1))
@@ -699,10 +701,13 @@ class EngineCore:
         self.attn_selected_tokens = 0
         # what a model's expert layers count on the device (the cache's
         # ``moe_counts``): router picks, those that fell on the experts
-        # held here, expert-layer calls; read back with each dispatch
+        # held here, expert-layer calls, experts with at least one row a
+        # layer (x an expert's bytes: what the grouped matmul streamed);
+        # read back with each dispatch
         self.moe_router_picks = 0
         self.moe_held_picks = 0
         self.moe_expert_layer_calls = 0
+        self.moe_experts_touched = 0
         # ... and its recurrent layers, in the same array: real tokens x
         # layers advanced, sequences started from zeros, rows that went on
         # at another position than their slot's state stood at (always 0:
@@ -1408,17 +1413,19 @@ class EngineCore:
         out, experts = jax.device_get((tuple(rec.out), rec.experts))
         self.device_gets += 1
         if experts is not None:
-            picks, held, calls, *state = (int(n) for n in experts)
+            picks, held, calls, touched, *state = (int(n) for n in experts)
             if state:
                 request_counters.record_state(*(
                     n - had for n, had in zip(state, self.state_counts)))
                 self.state_counts = tuple(state)
             request_counters.record_experts(
                 picks - self.moe_router_picks, held - self.moe_held_picks,
-                calls - self.moe_expert_layer_calls)
+                calls - self.moe_expert_layer_calls,
+                touched - self.moe_experts_touched)
             self.moe_router_picks = picks
             self.moe_held_picks = held
             self.moe_expert_layer_calls = calls
+            self.moe_experts_touched = touched
         self._host_post()
         rec.finish(out)
         for req in rec.ended:
@@ -1532,6 +1539,7 @@ class EngineCore:
             "moe_router_picks_total": self.moe_router_picks,
             "moe_held_picks_total": self.moe_held_picks,
             "moe_expert_layer_calls_total": self.moe_expert_layer_calls,
+            "moe_experts_touched_total": self.moe_experts_touched,
             "state_tokens_total": self.state_counts[0],
             "state_resets_total": self.state_counts[1],
             "state_position_mismatches_total": self.state_counts[2],

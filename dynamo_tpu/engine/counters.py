@@ -369,6 +369,12 @@ class RequestCounters:
         dynamo_tpu_engine_moe_expert_layer_calls_total counter (expert layers
                                                        run, one a layer and
                                                        dispatch)
+        dynamo_tpu_engine_moe_experts_touched_total    counter (held experts
+                                                       with at least one row,
+                                                       summed over layers:
+                                                       x an expert's bytes,
+                                                       what the grouped
+                                                       matmul streamed)
         dynamo_tpu_engine_state_tokens_total           counter (a model
                                                        with recurrent layers:
                                                        real tokens x such
@@ -451,10 +457,12 @@ class RequestCounters:
         self.attn_context_tokens_total += context
         self.attn_selected_tokens_total += selected
 
-    def record_experts(self, picks: int, held: int, calls: int) -> None:
+    def record_experts(self, picks: int, held: int, calls: int,
+                       touched: int) -> None:
         self.moe_router_picks_total += picks
         self.moe_held_picks_total += held
         self.moe_expert_layer_calls_total += calls
+        self.moe_experts_touched_total += touched
 
     def record_state(self, tokens: int, resets: int, mismatches: int) -> None:
         self.state_tokens_total += tokens
@@ -490,6 +498,7 @@ class RequestCounters:
         self.moe_router_picks_total = 0
         self.moe_held_picks_total = 0
         self.moe_expert_layer_calls_total = 0
+        self.moe_experts_touched_total = 0
         self.state_tokens_total = 0
         self.state_resets_total = 0
         self.state_position_mismatches_total = 0

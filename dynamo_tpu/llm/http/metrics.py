@@ -346,7 +346,8 @@ class Metrics:
         lines.append(f"{EM.ATTN_SELECTED_TOKENS_TOTAL} "
                      f"{rc.attn_selected_tokens_total}")
         # the expert layers' own counts, read back from the device: router
-        # picks, those on the experts held here, expert-layer calls
+        # picks, those on the experts held here, expert-layer calls, experts
+        # with a row
         lines.append(f"# TYPE {EM.MOE_ROUTER_PICKS_TOTAL} counter")
         lines.append(f"{EM.MOE_ROUTER_PICKS_TOTAL} "
                      f"{rc.moe_router_picks_total}")
@@ -355,6 +356,9 @@ class Metrics:
         lines.append(f"# TYPE {EM.MOE_EXPERT_LAYER_CALLS_TOTAL} counter")
         lines.append(f"{EM.MOE_EXPERT_LAYER_CALLS_TOTAL} "
                      f"{rc.moe_expert_layer_calls_total}")
+        lines.append(f"# TYPE {EM.MOE_EXPERTS_TOUCHED_TOTAL} counter")
+        lines.append(f"{EM.MOE_EXPERTS_TOUCHED_TOTAL} "
+                     f"{rc.moe_experts_touched_total}")
         # what a model's recurrent layers did, from the same read-back
         lines.append(f"# TYPE {EM.STATE_TOKENS_TOTAL} counter")
         lines.append(f"{EM.STATE_TOKENS_TOTAL} {rc.state_tokens_total}")

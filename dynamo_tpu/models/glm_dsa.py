@@ -56,6 +56,8 @@ from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.models.deepseek import apply_rope_interleaved, moe_route
 from dynamo_tpu.models.llama import (
+    EXPERT_COUNTS,
+    experts_touched,
     grouped_expert_dispatch,
     rms_norm,
     rope_inv_freq,
@@ -592,10 +594,10 @@ class GlmDsaModel:
         """The cache of ops/latent_cache.py: the latent row once a token
         and layer, the indexer's key beside it in ``full`` layers; with no
         indexer the dense layout and, beside it, what the expert layers
-        counted (``moe_counts`` int32 [L, 1, 3]: router picks, picks that
-        fell on the experts held here, calls — shaped like a part of the
-        cache, after ``latent`` in the pytree's order; ``EngineCore`` reads
-        its sums back with each dispatch)."""
+        counted (``moe_counts`` int32 [L, 1, 4]: router picks, picks that
+        fell on the experts held here, calls, experts touched — shaped like
+        a part of the cache, after ``latent`` in the pytree's order;
+        ``EngineCore`` reads its sums back with each dispatch)."""
         cfg = self.config
         if dtype is not None and jnp.dtype(dtype) != jnp.dtype(cfg.jax_dtype):
             raise NotImplementedError(f"latent cache dtype {dtype!r}")
@@ -604,7 +606,8 @@ class GlmDsaModel:
                 **latent_cache.init_dense_cache(
                     cfg.num_layers, num_blocks, block_size, cfg.head_dim,
                     cfg.jax_dtype),
-                "moe_counts": jnp.zeros((cfg.num_layers, 1, 3), jnp.int32)}
+                "moe_counts": jnp.zeros(
+                    (cfg.num_layers, 1, EXPERT_COUNTS), jnp.int32)}
         return latent_cache.init_latent_cache(
             cfg.num_layers, cfg.full_layers, num_blocks, block_size,
             cfg.head_dim, cfg.index_head_dim, cfg.jax_dtype)
@@ -747,9 +750,11 @@ class GlmDsaModel:
         return h, cache, sel
 
     def _mlp(self, group: dict, lp: dict, i, x, dense: bool, valid=None):
-        """(the layer's output, int32 [3] or None: what an expert layer
+        """(the layer's output, int32 [4] or None: what an expert layer
         counts for the tokens of ``valid`` [B, S] — None: all — the router's
-        picks, those that fell on the experts held here, and this call)."""
+        picks, those that fell on the experts held here, this call, and the
+        held experts with a row of any token: those whose weights it
+        reads)."""
         cfg = self.config
         if dense:
             return (jax.nn.silu(x @ lp["w_gate"]) * (x @ lp["w_up"])) \
@@ -765,7 +770,9 @@ class GlmDsaModel:
                     & (topi < cfg.expert_first + cfg.n_routed_experts) & real)
             counted = jnp.stack([
                 real.sum(dtype=jnp.int32) * cfg.num_experts_per_tok,
-                here.sum(dtype=jnp.int32), jnp.int32(1)])
+                here.sum(dtype=jnp.int32), jnp.int32(1),
+                experts_touched(topi, cfg.expert_first,
+                                cfg.n_routed_experts)])
         with jax.named_scope("moe_experts"):
             # the group's whole expert stacks, read where they lie
             routed = grouped_expert_dispatch(

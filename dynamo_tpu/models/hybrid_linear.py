@@ -31,7 +31,7 @@ slot array) and owns these rules: a row whose first position is 0 starts from
 zeros; a token with ``slot_idx < 0`` is an identity step; a row with no real
 token leaves its slot bit for bit as it was; ``state_pos[slot]`` becomes the
 position after the row's last real token, and a row that continues at
-another position than that is counted (``moe_counts[0, 0, 5]``).
+another position than that is counted (``moe_counts[0, 0, 6]``).
 
 One chip only, and nothing that moves blocks knows the state: the engine
 refuses those paths at start-up (``private_cache_layout``,
@@ -51,6 +51,8 @@ from jax.sharding import PartitionSpec as P
 from dynamo_tpu.models.deepseek import moe_route
 from dynamo_tpu.models.glm_dsa import ROUTER_BIAS_STD
 from dynamo_tpu.models.llama import (
+    EXPERT_COUNTS,
+    experts_touched,
     grouped_expert_dispatch,
     rms_norm,
     split_heads,
@@ -77,7 +79,8 @@ QK_NORM_EPS = 1e-6
 # median stays where b_dt puts it
 DECAY_PROJ_STD = 0.5
 # a forward adds its three counts (real tokens x linear layers, sequences
-# started from zeros, position mismatches) to ``moe_counts[0, 0, 3:6]``
+# started from zeros, position mismatches) to ``moe_counts[0, 0, 4:7]``,
+# behind the expert layers' ``EXPERT_COUNTS``
 STATE_COUNTS = 3
 
 
@@ -343,7 +346,7 @@ class HybridLinearModel:
         what the engine counts a token's cache bytes by; ``state``
         [L_lin, slots, H, d, d] float32, ``conv`` [L_lin, slots, K-1, 3·H·d]
         and ``state_pos`` [slots] (ops/linear_state.py), indexed by the
-        engine's slot; ``moe_counts`` int32 [L, 1, 6]: what the expert
+        engine's slot; ``moe_counts`` int32 [L, 1, 7]: what the expert
         layers counted (as models/glm_dsa.py) and, in row 0, what the linear
         layers did — real tokens × layers advanced, sequences started from
         zeros, rows that continued at another position than their slot's."""
@@ -362,7 +365,8 @@ class HybridLinearModel:
                 cfg.linear_layers, slots, cfg.linear_heads,
                 cfg.linear_head_dim, cfg.linear_head_dim, cfg.conv_width,
                 cfg.conv_kernel, cfg.jax_dtype, self.state_dtype),
-            "moe_counts": jnp.zeros((cfg.num_layers, 1, 6), jnp.int32),
+            "moe_counts": jnp.zeros(
+                (cfg.num_layers, 1, EXPERT_COUNTS + STATE_COUNTS), jnp.int32),
         }
 
     def state_bytes_per_slot(self) -> int:
@@ -384,7 +388,7 @@ class HybridLinearModel:
 
     # ---------------------------------------------------------------- forward
     def _experts(self, group: dict, lp: dict, i, h, valid):
-        """h + MoE(RMSNorm(h)) and the layer's three counts."""
+        """h + MoE(RMSNorm(h)) and the layer's four counts."""
         cfg = self.config
         b, s, d = h.shape
         xf = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps).reshape(b * s, d)
@@ -396,7 +400,9 @@ class HybridLinearModel:
                     & (topi < cfg.expert_first + cfg.n_routed_experts) & real)
             counted = jnp.stack([
                 real.sum(dtype=jnp.int32) * cfg.num_experts_per_tok,
-                here.sum(dtype=jnp.int32), jnp.int32(1)])
+                here.sum(dtype=jnp.int32), jnp.int32(1),
+                experts_touched(topi, cfg.expert_first,
+                                cfg.n_routed_experts)])
         with jax.named_scope("moe_experts"):
             routed = grouped_expert_dispatch(
                 xf, weights, topi, cfg.router_experts,
@@ -559,13 +565,14 @@ class HybridLinearModel:
             hidden = params["embed"][tokens].astype(cfg.jax_dtype)
 
         kv, state, conv = cache["kv"], cache["state"], cache["conv"]
-        counts = cache["moe_counts"].at[0, 0, STATE_COUNTS:].add(counted)
+        counts = cache["moe_counts"].at[0, 0, EXPERT_COUNTS:].add(counted)
         expert_keys = ("w_gate", "w_up", "w_down")
-        for run in self.runs:
-            group = params["groups"][run.kind]
+
+        def layer_step(kind: str):
+            group = params["groups"][kind]
             sliced = {k: v for k, v in group.items() if k not in expert_keys}
 
-            def step(carry, at, group=group, sliced=sliced, kind=run.kind):
+            def step(carry, at):
                 h, kv, state, conv, counts = carry
                 i, li = at
                 lp = jax.tree.map(lambda a: a[i], sliced)
@@ -577,12 +584,19 @@ class HybridLinearModel:
                     h, state, conv = self._linear(lp, i, h, state, conv, rows)
                 with jax.named_scope("mlp"):
                     h, picked = self._experts(group, lp, i, h, valid)
-                    counts = counts.at[li, 0, :3].add(picked)
+                    counts = counts.at[li, 0, :EXPERT_COUNTS].add(picked)
                 return (h, kv, state, conv, counts), None
+            return step
 
+        # one step function a kind: the runs of a kind (G | L L L | G | L L L)
+        # are scans of the same length over the same function, which
+        # ``lax.scan`` then traces once between them
+        steps = {kind: layer_step(kind)
+                 for kind in {run.kind for run in self.runs}}
+        for run in self.runs:
             n = jnp.arange(run.count, dtype=jnp.int32)
             (hidden, kv, state, conv, counts), _ = jax.lax.scan(
-                step, (hidden, kv, state, conv, counts),
+                steps[run.kind], (hidden, kv, state, conv, counts),
                 (run.start + n, run.layer0 + n))
         hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps)
         return hidden, {"kv": kv, "state": state, "conv": conv,

@@ -861,10 +861,25 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
     ``first .. first+count-1`` of the ``num_experts`` the router chose
     among (one chip's share of an expert-parallel layer).  Assignments to
     the others sort last, belong to no group, and add nothing: the result
-    is the part of the layer's sum that the held experts give."""
+    is the part of the layer's sum that the held experts give.
+
+    Where weights are the bound — on a TPU, few rows an expert by the
+    static shapes (``grouped_matmul_impl``: T·k over the experts the router
+    chooses among) — the three products are the Pallas kernel
+    ``ops/pallas/grouped_matmul.py``, which streams each touched expert's
+    weights once from where they lie: one algorithm whose best form changes
+    with a shape the trace knows.  Above that, off the TPU, and under a
+    mesh (GSPMD partitions ``ragged_dot`` on F; a Mosaic call it cannot)
+    ``lax.ragged_dot`` stays."""
+    from dynamo_tpu.ops.pallas import grouped_matmul as gmm
+    from dynamo_tpu.ops.pallas.registry import grouped_matmul_row_tile
+
     t, d = xf.shape
     k = topi.shape[1]
     flat_e = topi.reshape(t * k)
+    kernel = gmm.grouped_matmul_impl(
+        t * k, num_experts, d, w_gate.shape[-1], xf.dtype, w_gate.dtype
+    ) == "pallas"
     here = None
     if held is not None:
         first, num_experts = held
@@ -880,13 +895,25 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
             flat_e, length=num_experts + 1)[:num_experts].astype(jnp.int32)
     if layer is not None:
         groups = w_gate.shape[0] * num_experts
-        group_sizes = jax.lax.dynamic_update_slice(
-            jnp.zeros(groups, jnp.int32), group_sizes, (layer * num_experts,))
+        if not kernel:
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros(groups, jnp.int32), group_sizes,
+                (layer * num_experts,))
         w_gate, w_up, w_down = (
             w.reshape(groups, *w.shape[2:]) for w in (w_gate, w_up, w_down))
-    gate = jax.lax.ragged_dot(xs, w_gate, group_sizes)
-    up = jax.lax.ragged_dot(xs, w_up, group_sizes)
-    out = jax.lax.ragged_dot(act(gate) * up, w_down, group_sizes)  # [T*k, Dm]
+    if kernel:
+        # the plan is over this layer's experts; the kernel is told where
+        # they begin in the stack
+        tm = grouped_matmul_row_tile(t * k, max(d, w_gate.shape[-1]))
+        plan = gmm.grouped_matmul_plan(group_sizes, t * k, tm)
+        first_group = 0 if layer is None else layer * num_experts
+        dot = lambda x, *ws: gmm.grouped_expert_matmul(
+            x, ws, plan, first_group, tm=tm)
+    else:
+        dot = lambda x, *ws: [
+            jax.lax.ragged_dot(x, w, group_sizes) for w in ws]
+    gate, up = dot(xs, w_gate, w_up)     # one read of xs, two streams
+    out, = dot(act(gate) * up, w_down)   # [T*k, Dm]
     out = out * weights.reshape(t * k)[order, None].astype(out.dtype)
     if here is not None:
         # rows past the last group are whatever the grouped dot left there
@@ -894,6 +921,26 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
     # unsort (inverse permutation) then reduce the k slots of each token;
     # gather+reshape-sum keeps the combine deterministic (no scatter-add)
     return out[jnp.argsort(order)].reshape(t, k, d).sum(axis=1)
+
+
+# what a counting model's expert layer adds to its row of the cache's
+# ``moe_counts``: router picks, picks on the experts held here, calls,
+# experts touched
+EXPERT_COUNTS = 4
+
+
+def experts_touched(topi, first: int, count: int):
+    """int32 scalar: how many of the experts ``first .. first+count-1`` have
+    at least one of the picks ``topi`` [T, k] — the experts whose weights a
+    ``grouped_expert_dispatch`` of these picks reads.  (The dispatch's own
+    count of rows a held expert, so that XLA computes it once for both: a
+    compare of every pick with every expert cost the TPU compiler 12
+    CPU-seconds a Solar chunk program.)"""
+    flat = topi.reshape(-1)
+    here = (flat >= first) & (flat < first + count)
+    sizes = jnp.bincount(jnp.where(here, flat - first, count),
+                         length=count + 1)[:count]
+    return (sizes > 0).sum(dtype=jnp.int32)
 
 
 def _moe_mlp_grouped(cfg: ModelConfig, lp: dict, x: jax.Array,

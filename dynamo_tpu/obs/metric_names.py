@@ -137,6 +137,7 @@ class EngineMetric:
     MOE_HELD_PICKS_TOTAL = "dynamo_tpu_engine_moe_held_picks_total"
     MOE_EXPERT_LAYER_CALLS_TOTAL = (
         "dynamo_tpu_engine_moe_expert_layer_calls_total")
+    MOE_EXPERTS_TOUCHED_TOTAL = "dynamo_tpu_engine_moe_experts_touched_total"
     # what the recurrent layers did, counted on the device
     STATE_TOKENS_TOTAL = "dynamo_tpu_engine_state_tokens_total"
     STATE_RESETS_TOTAL = "dynamo_tpu_engine_state_resets_total"
@@ -280,6 +281,7 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.MOE_ROUTER_PICKS_TOTAL: ("counter", ()),
     EngineMetric.MOE_HELD_PICKS_TOTAL: ("counter", ()),
     EngineMetric.MOE_EXPERT_LAYER_CALLS_TOTAL: ("counter", ()),
+    EngineMetric.MOE_EXPERTS_TOUCHED_TOTAL: ("counter", ()),
     EngineMetric.STATE_TOKENS_TOTAL: ("counter", ()),
     EngineMetric.STATE_RESETS_TOTAL: ("counter", ()),
     EngineMetric.STATE_POSITION_MISMATCHES_TOTAL: ("counter", ()),

@@ -53,6 +53,15 @@ __all__ = [
     "MLA_MASKED_KEYS_PER_TILE",
     "LINEAR_STATE_HEADS_PER_STEP",
     "linear_state_heads_per_step",
+    "GROUPED_MATMUL_ROW_TILE",
+    "GROUPED_MATMUL_ROW_TILE_BYTES",
+    "GROUPED_MATMUL_COMPILER_VMEM_BYTES",
+    "GROUPED_MATMUL_BLOCK_BYTES",
+    "GROUPED_MATMUL_MAX_ROWS_PER_GROUP",
+    "grouped_matmul_row_tile",
+    "grouped_matmul_tiling",
+    "grouped_matmul_pairs",
+    "grouped_matmul_vmem_bytes",
     "V5E_VMEM_BYTES",
     "VMEM_BUDGET_BYTES",
     "SCOPED_VMEM_BYTES",
@@ -73,6 +82,8 @@ __all__ = [
     "latent_dma_cost",
     "linear_state_cost",
     "linear_state_reference",
+    "grouped_matmul_cost",
+    "grouped_matmul_reference",
     "decode_cost_estimate",
     "prefill_cost_estimate",
     "ragged_cost_estimate",
@@ -122,6 +133,24 @@ MLA_MASKED_KEYS_PER_TILE = 512
 # 128 x 128 float32 are 1 MiB a buffer, 4 MiB double buffered in and out,
 # and their 48 q | k | g vectors fit the one 128-row tile a step transposes
 LINEAR_STATE_HEADS_PER_STEP = 16
+# the experts' grouped matmul: sorted rows a row tile (a pair's matmul takes
+# the whole tile, so the tile is what the matrix unit streams past each
+# weight it loads) and the bytes it may hold (at 6,144 columns 64 rows), the
+# bytes of one weight block (the whole K and a slice of N:
+# ``grouped_matmul_tiling``), what the compiler keeps beside the buffers a
+# kernel declares (0.8 MB read on the chip), and the rows an expert up to
+# which the kernel is taken (``grouped_matmul.py::grouped_matmul_impl``):
+# one row tile.  Up to there a group meets each of its weight blocks once
+# or twice and the block's DMA hides the matmul; beyond, a group spans
+# several tiles, the matrix unit sets the pace, and XLA's ``ragged-dot``
+# closes in: on the chip the kernel won by 2.1-3.0x at 32 rows, 2.0-2.9x at
+# 64, 1.6-2.7x at 128 and 1.2-2.0x at 256 (GLM's 25.2 MB and Qwen3's 3.1 MB
+# experts), and lost at 512 at GLM's (the sweep: PERF.md section 6, PR 50)
+GROUPED_MATMUL_ROW_TILE = 128
+GROUPED_MATMUL_ROW_TILE_BYTES = 1024 * 1024
+GROUPED_MATMUL_BLOCK_BYTES = 5632 * 1024
+GROUPED_MATMUL_COMPILER_VMEM_BYTES = 1024 * 1024
+GROUPED_MATMUL_MAX_ROWS_PER_GROUP = GROUPED_MATMUL_ROW_TILE
 
 # v5e VMEM is 128 MiB per core (accelerator guide); budget 75% of it —
 # the compiler needs headroom for spills and the double-buffer pipeline.
@@ -179,6 +208,12 @@ KERNELS = {
     # the recurrent state's decode step, one read and one write a matrix
     "linear_state_update": {
         "module": "dynamo_tpu.ops.pallas.linear_state",
+        "placeholder": False,
+    },
+    # the experts' grouped matmul where an expert has few rows: a stream of
+    # the touched experts' weights
+    "grouped_expert_matmul": {
+        "module": "dynamo_tpu.ops.pallas.grouped_matmul",
         "placeholder": False,
     },
     "unified_ragged_attention": {
@@ -533,6 +568,68 @@ def linear_state_cost(rows: int, heads: int, dk: int, dv: int) -> dict:
     cells = rows * heads * dk * dv
     return _cost_dict(dma=2 * cells * 4 + rows * heads * (3 * dk + 2 * dv) * 4,
                       flops=7 * cells, trans=rows * heads * dk)
+
+
+def grouped_matmul_row_tile(m: int, k: int, x_bytes: int = 2) -> int:
+    """Sorted rows a row tile of the grouped matmul at ``k`` columns — the
+    wider of a layer's two (Dm and F), so that one plan serves its three
+    projections: ``GROUPED_MATMUL_ROW_TILE``, halved while a tile passes
+    ``GROUPED_MATMUL_ROW_TILE_BYTES``."""
+    tm = GROUPED_MATMUL_ROW_TILE
+    while tm > 16 and tm * k * x_bytes > GROUPED_MATMUL_ROW_TILE_BYTES:
+        tm //= 2
+    return min(tm, m)
+
+
+def grouped_matmul_vmem_bytes(tm: int, tn: int, k: int, w_bytes: int = 2,
+                              x_bytes: int = 2, weights: int = 1) -> int:
+    """VMEM a grid step of the grouped matmul holds: the pipeline's two
+    buffers of the row tile and, for each of the ``weights`` stacks the
+    call multiplies it with, of the weight block and the output tile, a
+    float32 product a stack before it is rounded, and the compiler's own."""
+    return (DOUBLE_BUFFER * (tm * k * x_bytes + weights * (
+        k * tn * w_bytes + tm * tn * x_bytes)) + weights * tm * tn * 4
+        + GROUPED_MATMUL_COMPILER_VMEM_BYTES)
+
+
+def grouped_matmul_tiling(tm: int, k: int, n: int, w_bytes: int = 2,
+                          x_bytes: int = 2, weights: int = 1) -> int:
+    """TN of the grouped matmul at TM rows a tile.  A weight block is the
+    whole K and the widest slice of N (whole lanes, dividing N) of at most
+    ``GROUPED_MATMUL_BLOCK_BYTES``: sized by its bytes, so that a grid
+    step's fixed cost stays a small share of its DMA whatever the expert's
+    shape (down: 3.1 MB whole in Qwen3, 5.2 of Solar's 10.5, 4.2 of
+    Mistral-Small-4's 16.8 and of GLM's 25.2), narrowed further while a
+    step's buffers pass the scoped VMEM of one kernel (a call over two
+    stacks, gate and up, holds a block of each: 3.1 MB whole in Qwen3, 2.1
+    in Solar and Mistral-Small-4, 3.1 in GLM)."""
+    lanes = 128
+    if n % lanes:
+        return n
+    slices = [t for t in range(lanes, n + 1, lanes) if n % t == 0]
+    fits = [t for t in slices
+            if k * t * w_bytes <= GROUPED_MATMUL_BLOCK_BYTES
+            and grouped_matmul_vmem_bytes(tm, t, k, w_bytes, x_bytes, weights)
+            <= SCOPED_VMEM_BYTES]
+    return max(fits, default=lanes)
+
+
+def grouped_matmul_pairs(m: int, groups: int, tm: int) -> int:
+    """The static length of a plan: every row tile has a pair, and each
+    group with rows adds at most one (it starts inside a tile another
+    opened, or opens its own)."""
+    return _cdiv(m, tm) + max(min(groups, m) - 1, 0)
+
+
+def grouped_matmul_cost(m: int, touched: int, k: int, n: int,
+                        w_bytes: int = 2, x_bytes: int = 2,
+                        weights: int = 1) -> dict:
+    """Grouped matmul of ``m`` sorted rows with each of ``weights`` stacks
+    over ``touched`` experts with rows: each one's K x N weights once, the
+    rows in once and out once a stack, two operations a row and weight."""
+    return _cost_dict(
+        dma=weights * (touched * k * n * w_bytes + m * n * x_bytes)
+        + m * k * x_bytes, flops=2 * weights * m * k * n, trans=0)
 
 
 def latent_dma_cost(rows: int, row_bytes: int) -> dict:
@@ -1284,7 +1381,108 @@ def _linear_state_case() -> dict:
     }
 
 
+def grouped_matmul_reference(xs, w, group_sizes, first_group=0):
+    """What ``grouped_expert_matmul`` must give: ``lax.ragged_dot`` over the
+    E groups at ``first_group`` of ``w``'s G, in float32, rows of no group
+    zero (``ragged_dot`` leaves them unspecified)."""
+    import jax
+    import jax.numpy as jnp
+
+    sizes = jnp.zeros(w.shape[0], jnp.int32).at[
+        first_group:first_group + group_sizes.shape[0]].set(group_sizes)
+    out = jax.lax.ragged_dot(jnp.nan_to_num(xs).astype(jnp.float32),
+                             w.astype(jnp.float32), sizes)
+    in_group = jnp.arange(xs.shape[0]) < group_sizes.sum()
+    return jnp.where(in_group[:, None], out, 0)
+
+
+def _grouped_matmul_case() -> dict:
+    """40 sorted rows in tiles of 8 over layer 1's six experts of a stacked
+    [3 x 6] array: offsets off the tiling (3, 8, 15), two empty groups, a
+    group over two tiles, and 24 rows of no group, which the poisoned run
+    fills with NaN: they come out exact zeros and touch no live row."""
+    import jax.numpy as jnp
+
+    np = _np()
+    m, k, n, e, layers, layer = 40, 128, 256, 6, 3, 1
+    sizes = np.asarray([3, 5, 7, 0, 0, 1], np.int32)
+    total = int(sizes.sum())
+
+    def build():
+        rng = np.random.default_rng(900)
+        return {"xs": rng.normal(size=(m, k)).astype(np.float32),
+                "w": jnp.asarray(rng.normal(size=(layers * e, k, n)) * 0.1,
+                                 jnp.float32),
+                "sizes": jnp.asarray(sizes)}
+
+    def run(inp, poisoned: bool):
+        from dynamo_tpu.ops.pallas import grouped_matmul as gmm
+
+        xs = inp["xs"].copy()
+        if poisoned:
+            xs[total:] = np.nan
+        plan = gmm.grouped_matmul_plan(inp["sizes"], m, 8)
+        return gmm.grouped_expert_matmul.__wrapped__(
+            jnp.asarray(xs), (inp["w"],), plan, layer * e, tm=8, tn=128,
+            interpret=True)[0]
+
+    def oracle(inp):
+        ref = np.asarray(grouped_matmul_reference(
+            jnp.asarray(inp["xs"]), inp["w"], inp["sizes"], layer * e))
+        live = np.zeros(ref.shape, bool)
+        live[:total] = True
+        return ref, live, ~live
+
+    def pricing():
+        return grouped_matmul_cost(m, 4, k, n, w_bytes=4, x_bytes=4)
+
+    return {
+        "name": "grouped-experts", "kernel": "grouped_expert_matmul",
+        "mode": "interpret", "atol": 1e-4,
+        "build": build, "run": run, "oracle": oracle, "pricing": pricing,
+    }
+
+
 # ---------------------------------------------- serving-scale (spec) ----
+
+
+def _spec_grouped_matmul(name: str, m: int, layers: int, e: int, k: int,
+                         n: int, weights: int) -> dict:
+    """A cell's grouped matmul at its own widths, shape-traced: the weight
+    blocks the tiling rule picks (``weights`` stacks a call), double
+    buffered, inside the VMEM budget."""
+
+    def build():
+        import jax
+        import jax.numpy as jnp
+
+        f = jax.ShapeDtypeStruct
+        return {"xs": f((m, k), jnp.bfloat16),
+                "w": f((layers * e, k, n), jnp.bfloat16),
+                "sizes": f((e,), jnp.int32), "first": f((), jnp.int32)}
+
+    def run(inp, poisoned: bool):
+        import jax
+
+        from dynamo_tpu.ops.pallas import grouped_matmul as gmm
+
+        def fn(xs, w, sizes, first):
+            tm = grouped_matmul_row_tile(m, max(k, n))
+            plan = gmm.grouped_matmul_plan(sizes, m, tm)
+            return gmm.grouped_expert_matmul.__wrapped__(
+                xs, (w,) * weights, plan, first, tm=tm)
+
+        return jax.eval_shape(fn, inp["xs"], inp["w"], inp["sizes"],
+                              inp["first"])
+
+    def pricing():
+        return grouped_matmul_cost(m, min(e, m), k, n, weights=weights)
+
+    return {
+        "name": name, "kernel": "grouped_expert_matmul", "mode": "spec",
+        "build": build, "run": run, "oracle": None, "pricing": pricing,
+    }
+
 
 
 def _spec_decode_8b() -> dict:
@@ -1397,8 +1595,16 @@ def audit_cases() -> list[dict]:
         _latent_dma_case("write"),
         _latent_dma_case("gather"),
         _linear_state_case(),
+        _grouped_matmul_case(),
         _spec_decode_8b(),
         _spec_prefill_8b(),
+        # Solar-Open2's decode (64 rows x top-8 over 20 held experts of
+        # 4,096 x 1,280, gate and up in one call) and GLM-5.2's down
+        # projection (the widest output tile)
+        _spec_grouped_matmul("experts-solar-decode", 512, 8, 20, 4096, 1280,
+                             weights=2),
+        _spec_grouped_matmul("experts-glm-down", 256, 4, 16, 2048, 6144,
+                             weights=1),
     ]
 
 
@@ -1600,7 +1806,24 @@ def probe_linear_state_inputs(layers, slots, heads, d):
             jnp.asarray(at == 1), jnp.asarray(at % 8 != 7))
 
 
+def probe_grouped_matmul_inputs(m, layers, e, k, n, rows):
+    """xs [m, K], w [L·E, K, N] bf16, group_sizes [E] (``rows`` of the m
+    spread over the experts, some with none), the last layer's first group."""
+    import jax
+    import jax.numpy as jnp
+
+    np = _np()
+    rng = np.random.default_rng(0)
+    kx, kw = jax.random.split(jax.random.PRNGKey(0))
+    sizes = np.bincount(rng.integers(0, e, rows), minlength=e)
+    return (jax.random.normal(kx, (m, k), jnp.bfloat16),
+            jax.random.normal(kw, (layers * e, k, n), jnp.bfloat16)
+            * k ** -0.5,
+            jnp.asarray(sizes, jnp.int32), jnp.int32((layers - 1) * e))
+
+
 _PROBE_BUILDERS = {
+    "grouped_expert_matmul": probe_grouped_matmul_inputs,
     "linear_state_update": probe_linear_state_inputs,
     "mla_masked_prefill": probe_mla_masked_inputs,
     "mla_sparse_attention": probe_mla_sparse_inputs,

@@ -134,7 +134,7 @@ def seed_http_metrics():
     request_counters.record_operands(440)
     request_counters.record_prompt(1000, 768)
     request_counters.record_sparse_decode(48000, 4096)
-    request_counters.record_experts(4608, 576, 36)
+    request_counters.record_experts(4608, 576, 36, 540)
     mesh_shape.update(tp=4, devices=4)
     request_counters.record_loop(300, 1200)
     request_counters.record_decode_blocks(2400, 4096)

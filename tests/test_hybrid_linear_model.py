@@ -89,8 +89,8 @@ def test_prefill_in_chunks_then_decode_is_the_reference():
     got = np.concatenate(got)
     assert np.abs(got - want(params, toks, np.arange(80))).max() < ROUNDING
     counts = np.asarray(cache["moe_counts"])
-    assert counts[0, 0, 3] == 6 * (20 + 75 + 2 * 5)        # tokens x layers
-    assert counts[0, 0, 4] == 2 and counts[0, 0, 5] == 0   # resets, mismatches
+    assert counts[0, 0, 4] == 6 * (20 + 75 + 2 * 5)        # tokens x layers
+    assert counts[0, 0, 5] == 2 and counts[0, 0, 6] == 0   # resets, mismatches
     assert list(np.asarray(cache["state_pos"])) == [25, 0, 80, 0]
 
 
@@ -113,7 +113,7 @@ def test_padding_and_idle_rows_change_nothing():
                               np.asarray(plain[leaf])[:, 1])
     assert list(np.asarray(after["state_pos"])) == [0, 24, 0, 6]
     # slot 3 went on at position 5 with a state that stood at 0
-    assert np.asarray(after["moe_counts"])[0, 0, 5] == 1
+    assert np.asarray(after["moe_counts"])[0, 0, 6] == 1
 
 
 def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():

@@ -180,7 +180,8 @@ def test_expert_shares_add_up_to_the_uncut_layer():
                       {k: v for k, v in g.items() if k not in experts})
     x = jax.random.normal(jax.random.PRNGKey(7), (1, 24, 64), jnp.float32)
     full, counted = whole._mlp(g, lp, 1, x, dense=False)
-    assert [int(n) for n in counted] == [48, 48, 1]   # every pick is held
+    # every pick is held, and 24 tokens' top-2 touch all 8 experts
+    assert [int(n) for n in counted] == [48, 48, 1, 8]
     layer = ref.make_layer(whole_cfg)
     r_all, r_shared = layer({**lp, **{k: g[k][1] for k in experts}}, x[0])
     np.testing.assert_allclose(np.asarray(full[0]), r_all + r_shared,

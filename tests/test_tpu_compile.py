@@ -190,6 +190,9 @@ def test_attention_phase_compiles_under_tp4(topo, tpu_gate, phase):
 # call on the TPU and takes whole buffers, so a layer the scan slices out of
 # the stacked [L, E, Dm, F] arrays is materialised: three copies of
 # E·Dm·F·2 = 403 MB a layer, 62% of the cell's device time (ledger, PR 25).
+# On one chip the three products are ``grouped_expert_matmul``
+# (ops/pallas/grouped_matmul.py, PR 50: few rows an expert), which takes the
+# stacked arrays as they are; under a mesh ``ragged_dot`` stays.
 _HLO_INSTR = re.compile(
     r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(")
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(")
@@ -223,6 +226,12 @@ def _largest_produced(hlo: str) -> tuple[int, str]:
         size = max(int(bits.group()) // 8, 1) if bits else 1
         worst = max(worst, (size * math.prod(dims), f"{name} = {op}"))
     return worst
+
+
+def _grouped_matmul_calls(hlo: str) -> list[str]:
+    """The custom calls of ops/pallas/grouped_matmul.py in an optimised HLO."""
+    return [line for line in hlo.splitlines()
+            if "custom-call(" in line and "grouped_expert_matmul" in line]
 
 
 def _abstract_model(config_file: str, place, num_blocks=None, **overrides):
@@ -303,7 +312,22 @@ def test_qwen3_moe_reads_expert_weights_in_place(topo, tpu_gate, case):
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *args).compile()
     hlo = compiled.as_text()
-    assert hlo.count("ragged-dot-none") >= 3 and "tpu_custom_call" in hlo
+    assert "tpu_custom_call" in hlo
+    if tp > 1:
+        assert hlo.count("ragged-dot-none") >= 3
+        assert "grouped_expert_matmul" not in hlo
+    else:
+        # 2 rows an expert (decode) and 32 (a 512-token chunk): the kernel
+        # (gate and up in one call, down in another), its weight operands
+        # the whole stacks [L·E, K, N] as they lie
+        calls = _grouped_matmul_calls(hlo)
+        assert len(calls) >= 2 and "ragged-dot" not in hlo
+        stacks = {f"bf16[{2 * cfg.num_experts},{k},{n}]" for k, n in (
+            (cfg.hidden_size, cfg.intermediate_size),
+            (cfg.intermediate_size, cfg.hidden_size))}
+        for line in calls:
+            layouts = line[line.index("operand_layout_constraints"):]
+            assert any(stack in layouts for stack in stacks), line
     # Per device, a layer's experts are expert_bytes / tp.  At tp 4 a shard
     # is F/4 = 192 wide, not a multiple of the 128 lanes, and the static
     # rule keeps the sliced form (copies of a layer's shard, 101 MB): the
@@ -615,6 +639,92 @@ def test_decode_kernel_fits_scoped_vmem_at_every_probe_geometry(topo, geom):
     assert "tpu_custom_call" in hlo
 
 
+def _expert_geoms():
+    from benchmarks.probe_kernels import EXPERT_GEOMS
+
+    return sorted(EXPERT_GEOMS)
+
+
+@pytest.mark.parametrize("cell", _expert_geoms())
+def test_grouped_matmul_compiles_at_every_cell_shape(topo, cell):
+    """The experts' grouped matmul alone at the four MoE cells' widths and
+    dispatches (``benchmarks/probe_kernels.py experts``), both projections'
+    shapes, reading the last layer of a stacked [L·E, K, N] array: the block
+    the tiling rule picks fits the scoped VMEM, the compiler takes the
+    kernel, and the stack is its operand as it lies — no copy of a layer's
+    experts among the temporaries."""
+    from benchmarks.probe_kernels import EXPERT_GEOMS
+    from dynamo_tpu.ops.pallas import grouped_matmul as gmm
+    from dynamo_tpu.ops.pallas import registry
+
+    g = EXPERT_GEOMS[cell]
+    layers, e = 2, g["held"]
+    one = SingleDeviceSharding(topo.devices[0])
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+    for tokens, _ in g["calls"].values():
+        m = tokens * g["k"]
+        # gate and up in one call, down alone
+        for k, n, stacks in ((g["dm"], g["f"], 2), (g["f"], g["dm"], 1)):
+            tm = registry.grouped_matmul_row_tile(m, max(k, n))
+            tn = registry.grouped_matmul_tiling(tm, k, n, weights=stacks)
+            assert registry.grouped_matmul_vmem_bytes(
+                tm, tn, k, weights=stacks) <= registry.SCOPED_VMEM_BYTES
+
+            def fn(xs, w, sizes, layer, m=m, tm=tm, stacks=stacks):
+                plan = gmm.grouped_matmul_plan(sizes, m, tm)
+                return gmm.grouped_expert_matmul(
+                    xs, (w,) * stacks, plan, layer * e, tm=tm)
+
+            compiled = jax.jit(fn).lower(
+                sds((m, k), jnp.bfloat16),
+                sds((layers * e, k, n), jnp.bfloat16),
+                sds((e,), jnp.int32), sds((), jnp.int32)).compile()
+            assert "grouped_expert_matmul" in compiled.as_text()
+            temp = compiled.memory_analysis().temp_size_in_bytes
+            assert temp < k * n * 2, (m, k, n, temp)
+
+
+@pytest.mark.parametrize("case", ["many-rows", "mesh"])
+def test_grouped_matmul_leaves_many_rows_and_meshes_to_ragged_dot(
+        topo, tpu_gate, case):
+    """``grouped_expert_dispatch`` over Qwen3's stacked experts, on the
+    described chip(s): twice the rows an expert the rule takes the kernel
+    for, and a decode step's rows under a two-device mesh that shards F —
+    both are XLA's ``ragged-dot``, and neither holds the kernel."""
+    from dynamo_tpu.models.llama import grouped_expert_dispatch
+    from dynamo_tpu.ops.pallas import registry
+
+    layers, e, k_top, d, f = 2, 128, 8, 2048, 768
+    cap = registry.GROUPED_MATMUL_MAX_ROWS_PER_GROUP
+    if case == "mesh":
+        mesh = Mesh(np.array(topo.devices[:2]).reshape(1, 2),
+                    ("data", "model"))
+        place = lambda spec=P(): NamedSharding(mesh, spec)
+        under = lambda: jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+        t = 32
+    else:
+        place = lambda spec=P(): SingleDeviceSharding(topo.devices[0])
+        under, t = contextlib.nullcontext, 2 * cap * e // k_top
+    sds = lambda shape, dt, spec=P(): jax.ShapeDtypeStruct(
+        shape, dt, sharding=place(spec))
+    up, down = P(None, None, None, "model"), P(None, None, "model", None)
+
+    def fn(xf, weights, topi, w_gate, w_up, w_down, layer):
+        with under():
+            return grouped_expert_dispatch(
+                xf, weights, topi, e, w_gate, w_up, w_down, jax.nn.silu,
+                layer=layer)
+
+    hlo = jax.jit(fn).lower(
+        sds((t, d), jnp.bfloat16), sds((t, k_top), jnp.float32),
+        sds((t, k_top), jnp.int32), sds((layers, e, d, f), jnp.bfloat16, up),
+        sds((layers, e, d, f), jnp.bfloat16, up),
+        sds((layers, e, f, d), jnp.bfloat16, down),
+        sds((), jnp.int32)).compile().as_text()
+    assert hlo.count("ragged-dot-none") >= 3
+    assert "grouped_expert_matmul" not in hlo
+
+
 def _kernel_body_converts(fn, *args):
     """(operand avals of the decode kernel's pallas_call, [(from dtype,
     shape)] of every conversion to float32 inside its body, nested
@@ -909,7 +1019,11 @@ def test_mistral4_cell_programs_keep_one_cache_and_name_their_kernels(
         params, cache, *args).compile()
     hlo = compiled.as_text()
     assert f"mla_dense_{program}" in hlo and hlo.count(" while(") == 1
-    assert "ragged-dot" in hlo
+    # 1 row an expert (decode), 8 (a question) and 64 (a document's chunk),
+    # all inside a row tile: the grouped matmul's kernel (gate + up, down)
+    # and no ragged-dot custom call
+    assert len(_grouped_matmul_calls(hlo)) == 2
+    assert "ragged-dot" not in hlo
     mem = compiled.memory_analysis()
     cache_bytes = cache["latent"].size * 2
     assert cache_bytes == 14400 * 32 * 9 * 768
@@ -950,7 +1064,10 @@ def test_hybrid_linear_cell_programs_write_the_state_in_place(
     hlo = compiled.as_text()
     assert ("paged_decode_attention" if program == "decode"
             else "paged_prefill_attention") in hlo
-    assert "ragged-dot" in hlo
+    # 1.6 rows an expert (decode) and 6.4 (a chunk): the grouped matmul's
+    # kernel, two calls (gate + up, down) a run of layers, and no ragged-dot
+    assert len(_grouped_matmul_calls(hlo)) == 2 * 4
+    assert "ragged-dot" not in hlo
     # G | L L L | G | L L L
     assert hlo.count(" while(") == 4
     # the state is sliced and updated where it lies, never copied whole
