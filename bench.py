@@ -616,12 +616,12 @@ def _ramp_and_measure(engine, steps: int, guard_s: float = 900.0):
     print(f"# ramp (prefill x{engine.prefill_steps} + warmup): "
           f"{time.perf_counter() - t0:.1f}s", file=sys.stderr)
 
-    tok0, t0 = engine.tokens_generated, time.perf_counter()
+    tok0, t0 = engine.counts.tokens_generated, time.perf_counter()
     d0 = engine.decode_steps
     while engine.decode_steps - d0 < steps and engine.has_work():
         engine.step()
     dt = time.perf_counter() - t0
-    toks = engine.tokens_generated - tok0
+    toks = engine.counts.tokens_generated - tok0
     tok_s = toks / dt if dt > 0 else 0.0
     itl_ms = dt / max(engine.decode_steps - d0, 1) * 1000
     print(f"# decode: {toks} tokens in {dt:.2f}s, ITL {itl_ms:.2f} ms/step",

@@ -81,15 +81,15 @@ def run_arm(model, params, cfg, spec_tokens: int, batch: int, steps: int,
         engine.step()
     engine.step()
 
-    tok0, t0 = engine.tokens_generated, time.perf_counter()
-    d0, a0 = engine.decode_steps, engine.spec_accepted
+    tok0, t0 = engine.counts.tokens_generated, time.perf_counter()
+    d0, a0 = engine.decode_steps, engine.counts.spec_accepted
     while engine.decode_steps - d0 < steps and engine.has_work() \
             and time.monotonic() < guard:
         engine.step()
     dt = time.perf_counter() - t0
-    toks = engine.tokens_generated - tok0
+    toks = engine.counts.tokens_generated - tok0
     dsteps = max(engine.decode_steps - d0, 1)
-    accepted = engine.spec_accepted - a0
+    accepted = engine.counts.spec_accepted - a0
     return {
         "arm": (f"draft{spec_tokens}" if draft is not None
                 else f"spec{spec_tokens}" if spec_tokens else "off"),

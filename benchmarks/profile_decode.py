@@ -8,9 +8,7 @@ between measured ITL and the HBM roofline is attributable, not guessed.
 Run on the real chip:  python benchmarks/profile_decode.py [1b|8b]
 Env: DYNAMO_PROF_BATCH (64), DYNAMO_PROF_CTX (512), DYNAMO_PROF_QUANT
 (int8|none), DYNAMO_PROF_STEPS (burst length, 64), DYNAMO_PROF_PARTS
-(comma list of exact part names to run a subset),
-DYNAMO_DECODE_SEQS_PER_GROUP / DYNAMO_DECODE_BLOCKS_PER_CHUNK (decode
-kernel geometry — also honoured by part 3).
+(comma list of exact part names to run a subset).
 
 Prints a JSON line per component: {"part", "ms", "hbm_gb", "gbps"}.
 """
@@ -163,16 +161,12 @@ def main() -> None:
         ms = timeit(lambda: fwd(params, cache, tokens))
         emit("forward_no_attention", ms, param_gb - v_ * h * wbytes / 1e9)
 
-    # 3. paged attention kernel alone (per layer x layers) — honours the
-    # same geometry knobs as the serving path (paged_attention.py), so
-    # an on-chip sweep actually varies this component
+    # 3. paged attention kernel alone (per layer x layers), at the tiling
+    # the serving path takes (registry.decode_tiling)
     if want("attention_all_layers"):
         q = jnp.ones((batch, cfg.num_heads, hd), cfg.jax_dtype)
-        spg = int(os.environ.get("DYNAMO_DECODE_SEQS_PER_GROUP", "8"))
-        bpc = int(os.environ.get("DYNAMO_DECODE_BLOCKS_PER_CHUNK", "4"))
         att = jax.jit(lambda qq, cc: paged_decode_attention(
-            qq, cc, jnp.int32(0), bt, seq_lens, interpret=not on_accel,
-            seqs_per_group=spg, blocks_per_chunk=bpc))
+            qq, cc, jnp.int32(0), bt, seq_lens, interpret=not on_accel))
         ms_layer = timeit(lambda: att(q, cache))
         emit("attention_all_layers", ms_layer * nl, kv_gb)
 

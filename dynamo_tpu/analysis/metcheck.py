@@ -66,6 +66,7 @@ __all__ = [
     "check_metric_facts",
     "census_snapshot",
     "render_docs_table",
+    "render_docs_counts",
     "run_metrics",
 ]
 
@@ -85,6 +86,8 @@ MET_RULES = {
 DEFAULT_METRICS_MANIFEST_PATH = Path(__file__).parent / "metrics_manifest.json"
 
 METRIC_PREFIX = "dynamo_tpu_"
+
+_REGISTRY = "dynamo_tpu.obs.metric_names"
 
 # histogram child-series suffixes fold back onto the base name
 _HIST_SUFFIXES = ("_bucket", "_sum", "_count")
@@ -715,62 +718,54 @@ def _producer_scope(path: str) -> bool:
 # ------------------------------------------------------------- engine dict ----
 
 
-def _engine_facts(index: ProjectIndex, classmap,
-                  sink: _Sink) -> dict:
-    """EngineCore.metrics() key surface + its constant-key consumers."""
-    entry = None
-    for key in classmap:
-        if key.endswith(".EngineCore"):
-            entry = classmap[key]
-            break
-    keys: set[str] = set()
-    if entry is not None:
-        modpath, node = entry
-        attrtype: dict[str, str] = {}
-        for n in ast.walk(node):
+def _engine_facts(index: ProjectIndex, classmap, sink: _Sink,
+                  declared) -> dict:
+    """EngineCore.metrics() key surface + its constant-key consumers: the
+    keys of the ``declared`` counts, the live gauges metrics() writes by
+    hand, and the ``stats()`` of the tiers it folds in."""
+    keys = {e.key for e in declared if e.key}
+    node = next((classmap[k][1] for k in classmap
+                 if k.endswith(".EngineCore")), None)
+    metrics_fn = None if node is None else next(
+        (m for m in node.body
+         if isinstance(m, ast.FunctionDef) and m.name == "metrics"), None)
+    if metrics_fn is not None:
+        # self.X = Ctor(...) anywhere in the class: what self.X.stats() is
+        attrtype = {
+            n.targets[0].attr: dotted_name(n.value.func)
+            for n in ast.walk(node)
+            if isinstance(n, ast.Assign) and len(n.targets) == 1
+            and isinstance(n.targets[0], ast.Attribute)
+            and dotted_name(n.targets[0].value) == "self"
+            and isinstance(n.value, ast.Call)}
+        for n in ast.walk(metrics_fn):
+            if isinstance(n, ast.Dict):
+                keys.update(k.value for k in n.keys
+                            if isinstance(k, ast.Constant)
+                            and isinstance(k.value, str))
             if (isinstance(n, ast.Assign) and len(n.targets) == 1
-                    and isinstance(n.targets[0], ast.Attribute)
-                    and isinstance(n.targets[0].value, ast.Name)
-                    and n.targets[0].value.id == "self"
-                    and isinstance(n.value, ast.Call)):
-                cd = dotted_name(n.value.func)
-                if cd:
-                    attrtype[n.targets[0].attr] = cd
-        metrics_fn = None
-        for m in node.body:
-            if isinstance(m, ast.FunctionDef) and m.name == "metrics":
-                metrics_fn = m
-                break
-        if metrics_fn is not None:
-            for n in ast.walk(metrics_fn):
-                if isinstance(n, ast.Dict):
-                    for k in n.keys:
-                        if isinstance(k, ast.Constant) and isinstance(
-                                k.value, str):
-                            keys.add(k.value)
-                if (isinstance(n, ast.Assign) and len(n.targets) == 1
-                        and isinstance(n.targets[0], ast.Subscript)
-                        and isinstance(n.targets[0].slice, ast.Constant)
-                        and isinstance(n.targets[0].slice.value, str)):
-                    keys.add(n.targets[0].slice.value)
-                # out.update(self.X.stats()) — fold in that class's keys
-                if (isinstance(n, ast.Call)
-                        and isinstance(n.func, ast.Attribute)
-                        and n.func.attr == "update" and n.args
-                        and isinstance(n.args[0], ast.Call)
-                        and isinstance(n.args[0].func, ast.Attribute)):
-                    inner = n.args[0].func
-                    d = dotted_name(inner.value)
-                    if d and d.startswith("self."):
-                        cd = attrtype.get(d[5:])
-                        cls_entry = _resolve_class(index, classmap, cd)
-                        if cls_entry:
-                            keys.update(_surface_keys(
-                                classmap, cls_entry, inner.attr))
+                    and isinstance(n.targets[0], ast.Subscript)
+                    and isinstance(n.targets[0].slice, ast.Constant)
+                    and isinstance(n.targets[0].slice.value, str)):
+                keys.add(n.targets[0].slice.value)
+            # out.update(self.X.stats()) — fold in that class's keys
+            if (isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+                    and n.func.attr == "update" and n.args
+                    and isinstance(n.args[0], ast.Call)
+                    and isinstance(n.args[0].func, ast.Attribute)):
+                inner = n.args[0].func
+                d = dotted_name(inner.value) or ""
+                cls_entry = _resolve_class(
+                    index, classmap, attrtype.get(d.removeprefix("self.")))
+                if cls_entry:
+                    keys.update(_surface_keys(classmap, cls_entry,
+                                              inner.attr))
     return {
         "keys": sorted(keys),
         "consumers": {k: sorted(set(v))
                       for k, v in sorted(sink.engine_reads.items())},
+        # docs/observability.md's listing of the declared counts
+        "listing": render_docs_counts(declared) if declared else None,
     }
 
 
@@ -802,6 +797,16 @@ def collect_metric_facts(paths=None, root=None) -> tuple[dict, list]:
         else _scan_files(root)
     index = ProjectIndex.build(files, root=root)
     consts = _const_table(index)
+    # the engine's counts are declared, not extracted: a scan that holds the
+    # registry takes their names, types and metrics() keys from its table
+    # (and the constants the table adds to EngineMetric, which no AST shows)
+    declared: tuple = ()
+    if _REGISTRY in index.modules:
+        from dynamo_tpu.obs.metric_names import ENGINE_COUNTS, EngineMetric
+        declared = ENGINE_COUNTS
+        consts.update(
+            (f"{_REGISTRY}.EngineMetric.{const}", name)
+            for const, name in vars(EngineMetric).items() if const.isupper())
 
     classmap: dict[str, tuple[str, ast.ClassDef]] = {}
     singletons: dict[str, str] = {}
@@ -842,6 +847,10 @@ def collect_metric_facts(paths=None, root=None) -> tuple[dict, list]:
         else:
             census[name] = {"type": typ, "labels": set(), "renderer": site,
                             "backings": []}
+    for e in declared:
+        if e.name:
+            census[e.name] = {"type": e.kind, "labels": set(), "backings": [],
+                              "renderer": f"{_REGISTRY}.ENGINE_COUNTS"}
     render_modules = {mod for _n, _t, _s, mod in sink.type_decls}
     untyped: dict[str, str] = {}
     for s in sink.samples:
@@ -893,7 +902,7 @@ def collect_metric_facts(paths=None, root=None) -> tuple[dict, list]:
         "consumers": {n: sorted(s) for n, s in sorted(consumers.items())},
         "consumers_prefix": {n: sorted(s) for n, s
                              in sorted(consumers_prefix.items())},
-        "engine": _engine_facts(index, classmap, sink),
+        "engine": _engine_facts(index, classmap, sink, declared),
     }
 
     intrinsic = _intrinsic_findings(
@@ -1117,19 +1126,19 @@ def check_metric_facts(facts: dict, manifest: Manifest, intrinsic: list, *,
                     f"{sorted(metrics[name]['labels'])}"))
 
     if docs_text is not None:
-        expected = render_docs_table(metrics)
-        actual = _docs_table_section(docs_text)
-        if actual is None:
-            findings.append(TraceFinding(
-                "docs/observability.md", "MT005", "docs-markers",
-                f"missing {DOCS_BEGIN} / {DOCS_END} markers around the "
-                "metric reference table"))
-        elif actual.strip() != expected.strip():
-            findings.append(TraceFinding(
-                "docs/observability.md", "MT005", "docs-table",
-                "metric reference table drifted from the census — "
-                "regenerate with "
-                "`dynamo-tpu lint --metrics --update-baseline`"))
+        for key, (begin, end), expected in _docs_sections(facts):
+            actual = _docs_section(docs_text, begin, end)
+            if actual is None:
+                findings.append(TraceFinding(
+                    "docs/observability.md", "MT005", "docs-markers",
+                    f"missing {begin} / {end} markers around the "
+                    f"generated {key}"))
+            elif actual.strip() != expected.strip():
+                findings.append(TraceFinding(
+                    "docs/observability.md", "MT005", f"docs-{key}",
+                    f"generated {key} drifted from the census — "
+                    "regenerate with "
+                    "`dynamo-tpu lint --metrics --update-baseline`"))
     return sorted(findings)
 
 
@@ -1137,6 +1146,8 @@ def check_metric_facts(facts: dict, manifest: Manifest, intrinsic: list, *,
 
 DOCS_BEGIN = "<!-- metcheck:begin -->"
 DOCS_END = "<!-- metcheck:end -->"
+COUNTS_BEGIN = "<!-- metcheck:counts:begin -->"
+COUNTS_END = "<!-- metcheck:counts:end -->"
 
 
 def render_docs_table(metrics: dict) -> str:
@@ -1150,25 +1161,45 @@ def render_docs_table(metrics: dict) -> str:
     return "\n".join(lines) + "\n"
 
 
-def _docs_table_section(text: str) -> Optional[str]:
-    if DOCS_BEGIN not in text or DOCS_END not in text:
+def render_docs_counts(declared) -> str:
+    """The generated listing of what an engine counts (between the
+    metcheck:counts markers): the table of obs/metric_names.py, in its
+    order, with each entry's help."""
+    lines = ["| on `/metrics` | in `metrics()` | type | what it says |",
+             "| --- | --- | --- | --- |"]
+    for e in declared:
+        name = f"`{e.name}`" if e.name else "-"
+        key = f"`{e.key}`" if e.key else "-"
+        lines.append(f"| {name} | {key} | {e.kind} | {e.help} |")
+    return "\n".join(lines) + "\n"
+
+
+def _docs_sections(facts: dict) -> list:
+    """(key, (begin, end), text) of every generated section of the docs."""
+    sections = [("table", (DOCS_BEGIN, DOCS_END),
+                 render_docs_table(facts["metrics"]))]
+    listing = (facts.get("engine") or {}).get("listing")
+    if listing:
+        sections.append(("counts", (COUNTS_BEGIN, COUNTS_END), listing))
+    return sections
+
+
+def _docs_section(text: str, begin: str, end: str) -> Optional[str]:
+    if begin not in text or end not in text:
         return None
-    return text.split(DOCS_BEGIN, 1)[1].split(DOCS_END, 1)[0]
+    return text.split(begin, 1)[1].split(end, 1)[0]
 
 
-def _write_docs_table(root: Path, metrics: dict) -> bool:
+def _write_docs(root: Path, facts: dict) -> None:
     path = root / "docs" / "observability.md"
     if not path.is_file():
-        return False
+        return
     text = path.read_text()
-    if DOCS_BEGIN not in text or DOCS_END not in text:
-        return False
-    head, rest = text.split(DOCS_BEGIN, 1)
-    _old, tail = rest.split(DOCS_END, 1)
-    path.write_text(
-        head + DOCS_BEGIN + "\n" + render_docs_table(metrics)
-        + DOCS_END + tail)
-    return True
+    for _key, (begin, end), generated in _docs_sections(facts):
+        if begin in text and end in text:
+            head, rest = text.split(begin, 1)
+            text = head + begin + "\n" + generated + end + rest.split(end, 1)[1]
+    path.write_text(text)
 
 
 # -------------------------------------------------------------------- CLI ----
@@ -1230,7 +1261,7 @@ def run_metrics(args, out) -> int:
     docs_text = docs_path.read_text() if docs_path.is_file() else None
 
     if getattr(args, "update_baseline", False):
-        _write_docs_table(root, facts["metrics"])
+        _write_docs(root, facts)
         docs_text = docs_path.read_text() if docs_path.is_file() else None
         findings = check_metric_facts(
             facts, manifest, intrinsic, registry=registry,

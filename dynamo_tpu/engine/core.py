@@ -46,9 +46,7 @@ import numpy as np
 
 from dynamo_tpu.engine import operands
 from dynamo_tpu.engine.config import EngineConfig
-from dynamo_tpu.engine.counters import counters as prefill_counters
-from dynamo_tpu.engine.counters import (cache_shape, mesh_shape,
-                                        request_counters)
+from dynamo_tpu.engine.counters import EngineCounts, track_engine
 from dynamo_tpu.engine.grammar import (
     INIT_STATE, JsonGrammar, compile_choice_vocab, compile_regex_vocab,
     compose_tables, device_tables, grammar_advance, grammar_mask,
@@ -60,6 +58,7 @@ from dynamo_tpu.llm.kv.block_manager import KvBlockManager, NoFreeBlocks
 from dynamo_tpu.llm.protocols import FinishReason, LLMEngineOutput
 from dynamo_tpu.models.llama import LlamaModel
 from dynamo_tpu.obs import tracing
+from dynamo_tpu.obs.metric_names import ENGINE_COUNTS
 from dynamo_tpu.obs.perfmodel import perf_model
 from dynamo_tpu.utils.mesh import AXIS_DATA, AXIS_MODEL
 from dynamo_tpu.obs.timeline import step_timeline
@@ -299,12 +298,10 @@ def unified_token_step(
 
 @jax.jit
 def expert_totals(moe_counts):
-    """int32 [4] — router picks, picks on the experts held here, expert-layer
-    calls, experts with a row (whose weights a layer read) — summed over the
-    layers of a cache's ``moe_counts`` [L, 1, 4]; a model with recurrent
-    layers appends its own three (models/hybrid_linear.py).  A
-    buffer of its own: the cache is donated to the next dispatch, this is
-    read back with the dispatch's outputs."""
+    """int32 [C]: a cache's ``moe_counts`` [L, 1, C] summed over its layers,
+    a total for each of the model's ``moe_count_keys``.  A buffer of its own:
+    the cache is donated to the next dispatch, this is read back with the
+    dispatch's outputs."""
     return moe_counts.sum(axis=(0, 1))
 
 
@@ -338,10 +335,10 @@ class _Inflight:
     # rows / tokens / ctx for the profiler's dyn.readback event of this
     # dispatch (EngineCore._carried); empty with no profiler session
     carried: dict = dataclasses.field(default_factory=dict)
-    # what the model's expert layers had counted once this dispatch ran
-    # (``expert_totals`` of the cache's ``moe_counts``), on the device; None
-    # for a model that counts nothing
-    experts: Any = None
+    # what the model had counted once this dispatch ran (``expert_totals``
+    # of the cache's ``moe_counts``), on the device; None for a model that
+    # counts nothing
+    counted: Any = None
 
 
 # what a dispatch carried, when no profiler session is open to be told
@@ -539,28 +536,30 @@ class EngineCore:
                 make_cache, out_shardings=self._cache_sharding())()
         self.params = params
         self.cache = cache
-        # what this engine is spread over: metrics() and /metrics say it
-        self.mesh_tp = 1 if mesh is None else mesh.shape.get(AXIS_MODEL, 1)
-        self.mesh_devices = 1 if mesh is None else mesh.size
-        mesh_shape.update(tp=self.mesh_tp, devices=self.mesh_devices)
+        # what this engine counts (obs/metric_names.py ENGINE_COUNTS says
+        # what each is): metrics() reads it, /metrics the sum over the
+        # process's engines.  Retired at close(), or when collected unclosed
+        self.counts = counts = EngineCounts()
+        self._retire_counts = track_engine(self, counts)
+        # what this engine is spread over
+        counts.mesh_tp = 1 if mesh is None else mesh.shape.get(AXIS_MODEL, 1)
+        counts.mesh_devices = 1 if mesh is None else mesh.size
         # what the cache is made of: its layers (a looped decoder keeps one
         # per pass of every layer) and what one token costs across them all
-        self.cache_layers = int(jax.tree.leaves(self._pool())[0].shape[0])
-        self.kv_bytes_per_token = (
+        counts.cache_layers = int(jax.tree.leaves(self._pool())[0].shape[0])
+        counts.kv_bytes_per_token = (
             self.kv_bytes_per_block() // config.block_size)
         # ... and what a slot's recurrent state costs, whatever its length
-        self.state_layers = (
-            int(cache["state"].shape[0]) if self._recurrent else 0)
-        self.state_bytes_per_slot = (
-            model.state_bytes_per_slot() if self._recurrent else 0)
-        # ... and whether the decode program updates it in one kernel
-        self.state_update_kernel = int(
-            self._recurrent and model.state_update_impl()[0] == "pallas")
-        cache_shape.update(layers=self.cache_layers,
-                           bytes_per_token=self.kv_bytes_per_token,
-                           state_layers=self.state_layers,
-                           state_bytes_per_slot=self.state_bytes_per_slot,
-                           prefix_reuse=int(self.prefix_reuse))
+        if self._recurrent:
+            counts.state_layers = int(cache["state"].shape[0])
+            counts.state_bytes_per_slot = model.state_bytes_per_slot()
+            # ... and whether the decode program updates it in one kernel
+            counts.state_update_kernel = int(
+                model.state_update_impl()[0] == "pallas")
+        counts.prefix_reuse = int(self.prefix_reuse)
+        # the totals a model keeps on the device, in the order of the columns
+        # of its cache's ``moe_counts`` (read back with each dispatch)
+        self._device_count_keys = getattr(model, "moe_count_keys", ())
 
         # where a dispatch's small operands go under a mesh
         # (``_upload_dispatch``): replicated over it, the layout the jitted
@@ -568,7 +567,6 @@ class EngineCore:
         # out; None with no mesh (the default device, uncommitted)
         self._operand_sharding = None if mesh is None else (
             jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
-        self.operand_buffers = 0         # buffers put x devices put to
 
         # an operand like the others: under a mesh it lives where they go,
         # and is split there by the program that takes the two buffers apart
@@ -661,79 +659,18 @@ class EngineCore:
         # chunk granularity is the documented fallback, docs/kv_streaming.md)
         # and once more with done=True when the prefill completes
         self._commit_hooks: dict[str, Callable[[list[int], bool], None]] = {}
-        # perf counters
+        # steps of the loop by kind, and prefill work actually computed
+        # (dedupe- and cancel-aware): read by tests, benchmarks and
+        # cellbench, on no metrics surface
         self.steps = 0
         self.prefill_steps = 0
-        # prefill batching: dispatches (any path), sequences packed over
-        # them, and the token budget offered/used by batched dispatches
-        self.prefill_dispatches = 0
-        self.prefill_rows_dispatched = 0
-        self.prefill_budget_offered = 0
-        self.prefill_budget_used = 0
         self.decode_steps = 0
-        self.tokens_generated = 0
-        self.prompt_tokens_computed = 0  # actual prefill work (dedupe-aware)
         self.sp_prefills = 0             # seq-parallel long-prefill dispatches
-        self.spec_steps = 0              # speculative verify dispatches
-        self.spec_proposed = 0           # tokens proposed by n-gram lookup
-        self.spec_accepted = 0           # proposals the model agreed with
-        # unified mixed prefill+decode dispatch (unified_token_dispatch)
-        self.unified_dispatches = 0      # mixed dispatches issued
-        self.unified_decode_rows = 0     # decode rows packed over them
-        self.unified_prefill_tokens = 0  # prefill tokens packed over them
-        self.unified_budget_offered = 0  # flat-axis budget offered
-        self.unified_budget_used = 0     # decode rows + prefill tokens
-        self.device_gets = 0             # step-loop jax.device_get calls
-        # counted where the work happens (cellbench reads them as core.*)
-        self.decode_dispatches = 0       # pure-decode dispatches (burst/spec)
-        self.decode_rows_dispatched = 0  # RUNNING rows packed over them
-        self.requests_finished = 0       # any finish reason
-        self.requests_cut_short = 0      # LENGTH because block space ran out
-        self.first_tokens = 0            # requests that emitted a first token
-        # prefix reuse: prompt tokens of completed prefills, and of those
-        # the tokens served from reused blocks
-        self.prompt_tokens_admitted = 0
-        self.prompt_tokens_cached = 0
-        # a latent-attention model: positions its decode rows could see,
-        # and positions they attended to (all of them without an indexer)
+        self.prompt_tokens_computed = 0
         self._index_topk = int(getattr(model.config, "index_topk", 0) or 0)
-        self.attn_context_tokens = 0
-        self.attn_selected_tokens = 0
-        # what a model's expert layers count on the device (the cache's
-        # ``moe_counts``): router picks, those that fell on the experts
-        # held here, expert-layer calls, experts with at least one row a
-        # layer (x an expert's bytes: what the grouped matmul streamed);
-        # read back with each dispatch
-        self.moe_router_picks = 0
-        self.moe_held_picks = 0
-        self.moe_expert_layer_calls = 0
-        self.moe_experts_touched = 0
-        # ... and its recurrent layers, in the same array: real tokens x
-        # layers advanced, sequences started from zeros, rows that went on
-        # at another position than their slot's state stood at (always 0:
-        # the slot contract, docs/linear_state.md)
-        self.state_counts = (0, 0, 0)
-        # tokens dispatched (prefill and decode) and, over them, the passes
-        # of the layer stack run: ut_steps a token for a looped decoder
+        # passes of the layer stack a token runs: ut_steps for a looped decoder
         self._ut_steps = int(getattr(model.config, "ut_steps", 1) or 1)
-        self.loop_tokens = 0
-        self.loop_passes = 0
-        # K/V blocks the rows of the decode dispatches own (what the
-        # flash-decode kernel fetches a layer), and what fetching every
-        # slot up to the longest row of its group fetched for them
-        self.decode_kv_blocks_walked = 0
-        self.decode_kv_blocks_group_bound = 0
         self._decode_tiling = self._flash_decode_tiling()
-        self.first_token_s = 0.0         # sum of (first emit - submitted_at)
-        # ... and the two stages of it that follow the slot, summed over
-        # the same requests (EngineRequest's stamps; the third is the
-        # queue wait): in a slot with nothing issued for it yet, and from
-        # its first dispatch to its first token
-        self.turn_wait_s = 0.0
-        self.prefill_span_s = 0.0
-        # requests ready to prefill, summed at every prefill dispatch:
-        # over prefill_dispatches, how many stood ready when one was served
-        self.prefill_ready_rows = 0
         # one clock read a finished dispatch, stamped on every output its
         # host work emits (LLMEngineOutput.emitted_at)
         self._emit_at = 0.0
@@ -744,9 +681,6 @@ class EngineCore:
         self._admit_seq = 0              # order of admission: prefill is served in it
         # dispatch-ahead (``_settle``): at most one dispatch un-read-back
         self._inflight: Optional[_Inflight] = None
-        self.ahead_dispatches = 0        # issued with their predecessor un-read
-        self.ahead_discards = 0          # rows whose ahead-sample a late stop threw away
-        self.pipeline_drains = 0         # turns that had to read back first
         # what a decode dispatch carries when there is nothing to carry:
         # made once, so every decode call has the same operands
         self._no_carry = self._carry_operand(
@@ -911,7 +845,7 @@ class EngineCore:
 
         if hasattr(self.model, "attention_impls"):
             return None
-        mc, tp = self.model.config, self.mesh_tp
+        mc, tp = self.model.config, self.counts.mesh_tp
         q_bytes = jnp.dtype(mc.jax_dtype).itemsize
         return decode_tiling(
             max(1, mc.num_heads // tp),
@@ -934,9 +868,8 @@ class EngineCore:
         blocks = -(-seq_lens // self.config.block_size)
         walked = int(blocks.sum())
         bound = int((-(-blocks.reshape(-1, g).max(axis=1) // c)).sum()) * g * c
-        self.decode_kv_blocks_walked += walked
-        self.decode_kv_blocks_group_bound += bound
-        request_counters.record_decode_blocks(walked, bound)
+        self.counts.decode_kv_blocks_walked_total += walked
+        self.counts.decode_kv_blocks_group_bound_total += bound
 
     def attention_impls(self) -> dict[str, tuple[str, str]]:
         """phase -> ("pallas" | "xla", why), as the dispatch in
@@ -958,7 +891,7 @@ class EngineCore:
                 block_size=self.config.block_size, quant=self.cache_quant,
                 windowed=(window is not None
                           and self.config.max_model_len > window),
-                tp=self.mesh_tp)
+                tp=self.counts.mesh_tp)
             for phase in ATTENTION_PHASES
         }
 
@@ -1223,9 +1156,8 @@ class EngineCore:
             bufs = jax.device_put(bufs, self._operand_sharding)
             self._rng, rng, (up, up_kw) = operand_prologue(
                 self._rng, bufs, layout=layout)
-            put = len(bufs) * self.mesh_devices
-        self.operand_buffers += put
-        request_counters.record_operands(put)
+            put = len(bufs) * self.mesh.size
+        self.counts.operand_buffers_total += put
         gkw.update(up_kw)
         return up, rng, gkw
 
@@ -1388,13 +1320,12 @@ class EngineCore:
         it may stay in flight for the next turn."""
         prev, self._inflight = self._inflight, rec
         if isinstance(self.cache, dict) and "moe_counts" in self.cache:
-            rec.experts = expert_totals(self.cache["moe_counts"])
+            rec.counted = expert_totals(self.cache["moe_counts"])
         if prev is not None:
             if rec.kind == "decode_multi":
                 # counted like decode_dispatches_total, at the dispatch:
                 # their ratio is how often a decode hid its round trip
-                self.ahead_dispatches += 1
-                request_counters.record_ahead()
+                self.counts.ahead_dispatches_total += 1
             self._finish_dispatch(prev)
         if not self._may_stay_in_flight(rec):
             self._inflight = None
@@ -1410,22 +1341,12 @@ class EngineCore:
         # ONE batched transfer: per-array np.asarray would issue a
         # device->host round trip per output (per-array latency is the
         # cost that matters on a remote-attached chip)
-        out, experts = jax.device_get((tuple(rec.out), rec.experts))
-        self.device_gets += 1
-        if experts is not None:
-            picks, held, calls, touched, *state = (int(n) for n in experts)
-            if state:
-                request_counters.record_state(*(
-                    n - had for n, had in zip(state, self.state_counts)))
-                self.state_counts = tuple(state)
-            request_counters.record_experts(
-                picks - self.moe_router_picks, held - self.moe_held_picks,
-                calls - self.moe_expert_layer_calls,
-                touched - self.moe_experts_touched)
-            self.moe_router_picks = picks
-            self.moe_held_picks = held
-            self.moe_expert_layer_calls = calls
-            self.moe_experts_touched = touched
+        out, counted = jax.device_get((tuple(rec.out), rec.counted))
+        self.counts.device_gets_total += 1
+        if counted is not None:
+            # totals since the cache was made, so set and not added
+            for key, total in zip(self._device_count_keys, counted):
+                setattr(self.counts, key, int(total))
         self._host_post()
         rec.finish(out)
         for req in rec.ended:
@@ -1437,8 +1358,7 @@ class EngineCore:
         there is nothing to issue behind it."""
         rec, self._inflight = self._inflight, None
         if rec is not None:
-            self.pipeline_drains += 1
-            request_counters.record_drain()
+            self.counts.pipeline_drains_total += 1
             self._finish_dispatch(rec)
 
     # ------------------------------------------------------- cross-thread API
@@ -1485,14 +1405,25 @@ class EngineCore:
             except queue.Empty:
                 break
 
+    def _count_prefill(self, rows: int, tokens: int, budget: int = 0) -> None:
+        """One prefill dispatch: ``rows`` sequences packed, ``tokens`` prompt
+        tokens computed, under a token budget of ``budget`` (0 for a
+        one-request or seq-parallel dispatch: those offer none)."""
+        c = self.counts
+        c.prefill_dispatches_total += 1
+        c.prefill_rows_dispatched += rows
+        c.prefill_tokens_total += tokens
+        if budget > 0:
+            c.prefill_budget_offered += budget
+            c.prefill_budget_used += tokens
+        self._count_tokens(tokens)
+
     def _count_tokens(self, tokens: int) -> None:
         """``tokens`` went out in a dispatch: each runs the layer stack
         ``ut_steps`` times (passes / tokens = cellbench's
         loop.passes_per_token)."""
-        passes = tokens * self._ut_steps
-        self.loop_tokens += tokens
-        self.loop_passes += passes
-        request_counters.record_loop(tokens, passes)
+        self.counts.loop_tokens_total += tokens
+        self.counts.loop_passes_total += tokens * self._ut_steps
 
     def metrics(self) -> dict:
         """ForwardPassMetrics equivalent (ref kv_router/protocols.rs:30-47)."""
@@ -1504,74 +1435,9 @@ class EngineCore:
             "kv_total_blocks": self.block_manager.num_blocks,
             "num_requests_waiting": self.waiting.qsize() + len(self._admitted),
             "kv_usage_perc": self.block_manager.usage,
-            "tokens_generated": self.tokens_generated,
-            "spec_steps": self.spec_steps,
-            "spec_proposed": self.spec_proposed,
-            "spec_accepted": self.spec_accepted,
-            # prefill batching (token-budget ragged prefill)
-            "prefill_dispatches_total": self.prefill_dispatches,
-            "prefill_batch_occupancy": (
-                self.prefill_rows_dispatched / self.prefill_dispatches
-                if self.prefill_dispatches else 0.0
-            ),
-            "prefill_budget_utilization": (
-                self.prefill_budget_used / self.prefill_budget_offered
-                if self.prefill_budget_offered else 0.0
-            ),
-            # unified mixed prefill+decode dispatch
-            "unified_dispatches_total": self.unified_dispatches,
-            "unified_decode_rows": self.unified_decode_rows,
-            "unified_prefill_tokens": self.unified_prefill_tokens,
-            "unified_budget_utilization": (
-                self.unified_budget_used / self.unified_budget_offered
-                if self.unified_budget_offered else 0.0
-            ),
-            "device_gets_total": self.device_gets,
-            "decode_dispatches_total": self.decode_dispatches,
-            "decode_rows_dispatched_total": self.decode_rows_dispatched,
-            "requests_finished_total": self.requests_finished,
-            "requests_cut_short_total": self.requests_cut_short,
-            "first_tokens_total": self.first_tokens,
-            "prompt_tokens_admitted_total": self.prompt_tokens_admitted,
-            "prompt_tokens_cached_total": self.prompt_tokens_cached,
-            "attn_context_tokens_total": self.attn_context_tokens,
-            "attn_selected_tokens_total": self.attn_selected_tokens,
-            "moe_router_picks_total": self.moe_router_picks,
-            "moe_held_picks_total": self.moe_held_picks,
-            "moe_expert_layer_calls_total": self.moe_expert_layer_calls,
-            "moe_experts_touched_total": self.moe_experts_touched,
-            "state_tokens_total": self.state_counts[0],
-            "state_resets_total": self.state_counts[1],
-            "state_position_mismatches_total": self.state_counts[2],
-            "state_layers": self.state_layers,
-            "state_bytes_per_slot": self.state_bytes_per_slot,
-            "state_update_kernel": self.state_update_kernel,
-            "prefix_reuse": int(self.prefix_reuse),
-            "loop_tokens_total": self.loop_tokens,
-            "loop_passes_total": self.loop_passes,
-            "decode_kv_blocks_walked_total": self.decode_kv_blocks_walked,
-            "decode_kv_blocks_group_bound_total":
-                self.decode_kv_blocks_group_bound,
-            "cache_layers": self.cache_layers,
-            "kv_bytes_per_token": self.kv_bytes_per_token,
-            "first_token_seconds_total": self.first_token_s,
-            # the stages of it behind the slot (over first_tokens_total),
-            # and the prefill backlog (over prefill_dispatches_total)
-            "turn_wait_seconds_total": self.turn_wait_s,
-            "prefill_span_seconds_total": self.prefill_span_s,
-            "prefill_ready_rows_total": self.prefill_ready_rows,
-            # dispatch-ahead: ahead / decode_dispatches_total = how
-            # often a decode hid its round trip; discards = late stops;
-            # drains = turns that read back before they could issue
-            "ahead_dispatches_total": self.ahead_dispatches,
-            "ahead_discards_total": self.ahead_discards,
-            "pipeline_drains_total": self.pipeline_drains,
-            # host->device buffers the dispatches' operands took: over
-            # prefill + decode dispatches, buffers per dispatch
-            "operand_buffers_total": self.operand_buffers,
-            "mesh_tp": self.mesh_tp,
-            "mesh_devices": self.mesh_devices,
         }
+        out.update((e.key, e.value(self.counts))
+                   for e in ENGINE_COUNTS if e.key)
         if self.host_pool is not None:
             out.update(self.host_pool.stats())
         if self.persist_store is not None:
@@ -1915,8 +1781,7 @@ class EngineCore:
         """A prefill dispatch goes out with ``ready`` standing ready for
         one: over the dispatches, the backlog a served request stood in
         (1.0 = nobody ever waited behind another's chunk)."""
-        self.prefill_ready_rows += len(ready)
-        prefill_counters.record_ready(len(ready))
+        self.counts.prefill_ready_rows_total += len(ready)
 
     # ---------------------------------------------------------------- prefill
     def _reserve_own(self, req: EngineRequest) -> None:
@@ -2010,10 +1875,7 @@ class EngineCore:
             reqs=(req,), carried=carried,
         )
         self.prefill_steps += 1
-        self.prefill_dispatches += 1
-        self.prefill_rows_dispatched += 1
-        prefill_counters.record(rows=1, tokens=take)
-        self._count_tokens(take)
+        self._count_prefill(rows=1, tokens=take)
 
         def finish(out):
             if req.state is not RequestState.PREFILL:
@@ -2170,12 +2032,7 @@ class EngineCore:
         )
         self.steps += 1
         self.prefill_steps += 1
-        self.prefill_dispatches += 1
-        self.prefill_rows_dispatched += r_real
-        self.prefill_budget_offered += budget
-        self.prefill_budget_used += take_sum
-        prefill_counters.record(rows=r_real, tokens=take_sum, budget=budget)
-        self._count_tokens(take_sum)
+        self._count_prefill(rows=r_real, tokens=take_sum, budget=budget)
 
         def finish(out):
             for r, (req, take, final) in enumerate(sel):
@@ -2205,9 +2062,8 @@ class EngineCore:
         ):
             self._last_was_prefill = False
         req.state = RequestState.RUNNING
-        self.prompt_tokens_admitted += req.prompt_len
-        self.prompt_tokens_cached += req.cached_tokens
-        request_counters.record_prompt(req.prompt_len, req.cached_tokens)
+        self.counts.prompt_tokens_admitted_total += req.prompt_len
+        self.counts.prompt_tokens_cached_total += req.cached_tokens
         if req.remote_decode:
             # prefill-only request: emit the first sampled token, hold the
             # blocks for transfer-out, free the slot (ref prefill_worker.py:148
@@ -2221,7 +2077,7 @@ class EngineCore:
             self._by_id.pop(req.request_id, None)
             req.state = RequestState.FINISHED
             req.finish_reason = FinishReason.STOP
-            self.tokens_generated += 1
+            self.counts.tokens_generated += 1
             req.emit(
                 LLMEngineOutput(
                     token_ids=[int(sampled[0])],
@@ -2400,27 +2256,20 @@ class EngineCore:
         )
         step_timeline.enter("readback")
         sampled, lps, cids, clps = jax.device_get(out)  # one batched pull
-        self.device_gets += 1
+        self.counts.device_gets_total += 1
         self._host_post()
         self.steps += 1
         self.prefill_steps += 1
         self.decode_steps += 1
         self.prompt_tokens_computed += take_sum
-        self.prefill_dispatches += 1
-        self.prefill_rows_dispatched += len(sel)
-        self.prefill_budget_offered += budget
-        self.prefill_budget_used += take_sum
-        self.unified_dispatches += 1
-        self.unified_decode_rows += n_dec
-        self.unified_prefill_tokens += take_sum
-        self.unified_budget_offered += cfg.prefill_token_budget
-        self.unified_budget_used += n_dec + take_sum
-        self._count_tokens(n_dec + take_sum)
-        prefill_counters.record(rows=len(sel), tokens=take_sum,
-                                budget=budget)
-        prefill_counters.record_unified(
-            decode_rows=n_dec, prefill_tokens=take_sum,
-            budget=cfg.prefill_token_budget)
+        self._count_prefill(rows=len(sel), tokens=take_sum, budget=budget)
+        c = self.counts
+        c.unified_dispatches_total += 1
+        c.unified_decode_rows += n_dec
+        c.unified_prefill_tokens += take_sum
+        c.unified_budget_offered += cfg.prefill_token_budget
+        c.unified_budget_used += n_dec + take_sum
+        self._count_tokens(n_dec)
 
         for r, req in enumerate(dec):
             want_lp = req.sampling.logprobs or req.sampling.top_logprobs > 0
@@ -2574,7 +2423,7 @@ class EngineCore:
         step_timeline.enter("readback")
         sampled, lps, cids, clps = jax.device_get(
             (sampled, lps, cids, clps))  # one batched transfer
-        self.device_gets += 1
+        self.counts.device_gets_total += 1
         self._host_post()
         nb = -(-req.prompt_len // bs)
         self.cache = scatter_blocks_inplace(
@@ -2584,10 +2433,7 @@ class EngineCore:
         self.steps += 1
         self.prefill_steps += 1
         self.sp_prefills += 1
-        self.prefill_dispatches += 1
-        self.prefill_rows_dispatched += 1
-        prefill_counters.record(rows=1, tokens=req.prompt_len)
-        self._count_tokens(req.prompt_len)
+        self._count_prefill(rows=1, tokens=req.prompt_len)
         self.prompt_tokens_computed += req.prompt_len
         req.computed_tokens = req.prompt_len
         self._commit_prefill_blocks(req)
@@ -2758,14 +2604,13 @@ class EngineCore:
         )
         step_timeline.enter("readback")
         verified = jax.device_get(verified)
-        self.device_gets += 1
+        self.counts.device_gets_total += 1
         self._host_post()
         self.steps += 1
         self.decode_steps += 1
-        self.spec_steps += 1
-        self.decode_dispatches += 1
-        self.decode_rows_dispatched += len(rows)
-        request_counters.record_decode(len(rows))
+        self.counts.spec_steps += 1
+        self.counts.decode_dispatches_total += 1
+        self.counts.decode_rows_dispatched_total += len(rows)
         self._count_decode_blocks(seq_lens, tokens.shape[1])
         self._count_tokens(len(rows) * tokens.shape[1])
         for req in rows:
@@ -2778,8 +2623,8 @@ class EngineCore:
             while a < len(prop) and prop[a] == int(verified[i, a]):
                 a += 1
             emit = [int(verified[i, j]) for j in range(a + 1)]
-            self.spec_proposed += len(prop)
-            self.spec_accepted += a
+            self.counts.spec_proposed += len(prop)
+            self.counts.spec_accepted += a
             allowed = min(len(emit), int(limits[i] - (req.seq.total_tokens - 1)))
             for t in emit[:allowed]:
                 if req.state is not RequestState.RUNNING:
@@ -2927,18 +2772,16 @@ class EngineCore:
             num_steps=k_steps, k_cand=k_cand, exact=exact,
             carry_rows=carry_rows, carried=carried,
         )  # [K, B], [K, B], [K, B, C], [K, B, C]
-        self.decode_dispatches += 1
-        self.decode_rows_dispatched += len(active)
-        request_counters.record_decode(len(active))
+        self.counts.decode_dispatches_total += 1
+        self.counts.decode_rows_dispatched_total += len(active)
         self._count_decode_blocks(seq_lens)
         self._count_tokens(len(active) * k_steps)
         if self._private_cache_layout:
             ctx = int(seq_lens.sum())
             picked = (int(np.minimum(seq_lens, self._index_topk).sum())
                       if self._index_topk else ctx)
-            self.attn_context_tokens += ctx
-            self.attn_selected_tokens += picked
-            request_counters.record_sparse_decode(ctx, picked)
+            self.counts.attn_context_tokens_total += ctx
+            self.counts.attn_selected_tokens_total += picked
 
         def finish(out):
             sampled, lps, cids, clps = out
@@ -2948,8 +2791,7 @@ class EngineCore:
                     # stopped on a token, or aborted, while this dispatch
                     # was already issued behind the one that told: its
                     # sample is past the stop and is thrown away
-                    self.ahead_discards += 1
-                    request_counters.record_ahead_discard()
+                    self.counts.ahead_discards_total += 1
                     continue
                 slot = req.slot
                 want_lp = req.sampling.logprobs or req.sampling.top_logprobs > 0
@@ -3035,7 +2877,7 @@ class EngineCore:
                 )
         req.seq.append(token)
         req.generated += 1
-        self.tokens_generated += 1
+        self.counts.tokens_generated += 1
         gkey = self._grammar_key(req)
         if gkey is not None and self._grammar is not None:
             # host mirror of the in-scan grammar advance (deterministic:
@@ -3090,11 +2932,10 @@ class EngineCore:
         ttft = now - req.submitted_at
         turn_wait = req.first_issue_at - req.admitted_at
         span = now - req.first_issue_at
-        self.first_tokens += 1
-        self.first_token_s += ttft
-        self.turn_wait_s += turn_wait
-        self.prefill_span_s += span
-        request_counters.record_first_token(ttft, turn_wait, span)
+        self.counts.first_tokens_total += 1
+        self.counts.first_token_seconds_total += ttft
+        self.counts.turn_wait_seconds_total += turn_wait
+        self.counts.prefill_span_seconds_total += span
         if tracing.enabled() and req.trace:
             tracing.record_span("engine.queue", req.trace,
                                 req.submitted_at, req.admitted_at)
@@ -3112,8 +2953,7 @@ class EngineCore:
         """End a running request because its block space ran out: the
         client sees ``finish_reason: "length"`` like a ``max_tokens`` stop,
         so this counter is the only place the two can be told apart."""
-        self.requests_cut_short += 1
-        request_counters.record_cut_short()
+        self.counts.requests_cut_short_total += 1
         self._finish_slot(req, FinishReason.LENGTH)
 
     def _release_slot(self, req: EngineRequest) -> None:
@@ -3143,8 +2983,7 @@ class EngineCore:
         self._by_id.pop(req.request_id, None)
         req.state = RequestState.FINISHED
         req.finish_reason = reason
-        self.requests_finished += 1
-        request_counters.record_finish()
+        self.counts.requests_finished_total += 1
         if req.first_token_at and tracing.enabled() and req.trace:
             # ends at the stamp its last output carries (the consumer may
             # have closed engine.generate by now), or now if none goes out
@@ -3162,8 +3001,7 @@ class EngineCore:
         """Finish a request that never got a slot."""
         req.state = RequestState.FINISHED
         req.finish_reason = reason
-        self.requests_finished += 1
-        request_counters.record_finish()
+        self.counts.requests_finished_total += 1
         req.emit(LLMEngineOutput(token_ids=[], finish_reason=reason))
 
     # ------------------------------------------------- disaggregation support
@@ -3414,6 +3252,8 @@ class EngineCore:
         self._offload_thread = None
         if getattr(self, "persist_store", None) is not None:
             self.persist_store.close()
+        if getattr(self, "_retire_counts", None) is not None:
+            self._retire_counts()
 
     def _restore_from_host(self, req: EngineRequest) -> None:
         """Upload host-resident prefix blocks into the request's fresh

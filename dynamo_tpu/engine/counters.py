@@ -1,110 +1,102 @@
-"""Process-global prefill-batching counters.
+"""Process-global counters of the engine plane.
 
-Same dependency-free idiom as ``dynamo_tpu/fault/counters.py``: the
-engine layer records, the llm layer (http/metrics.py render) and the
-benchmarks read — no import cycles.  The HTTP metrics endpoint exposes:
+Same dependency-free idiom as ``dynamo_tpu/fault/counters.py``: the engine
+layer records, the llm layer (http/metrics.py render) and the benchmarks
+read — no import cycles.
 
-    dynamo_tpu_engine_prefill_dispatches_total     counter
-    dynamo_tpu_engine_prefill_tokens_total         counter
-    dynamo_tpu_engine_prefill_batch_occupancy      gauge (rows/dispatch)
-    dynamo_tpu_engine_prefill_budget_utilization   gauge (used/offered)
-    dynamo_tpu_engine_prefill_ready_rows_total     counter (requests ready
-                                                   to prefill, summed at
-                                                   every prefill dispatch:
-                                                   over the dispatches, the
-                                                   backlog a served request
-                                                   stood in)
-    dynamo_tpu_engine_unified_dispatches_total     counter
-    dynamo_tpu_engine_unified_decode_rows_total    counter
-    dynamo_tpu_engine_unified_prefill_tokens_total counter
-    dynamo_tpu_engine_unified_budget_utilization   gauge (used/offered)
-
-The ``unified_*`` family counts the mixed prefill+decode dispatches of
-the unified token-budget scheduler (engine/core.py ``_run_unified``):
-how many turns collapsed the legacy two-dispatch interleave into one,
-how many decode rows and prefill tokens shared each flat axis, and how
-full the offered axis budget ran.
+What an ``EngineCore`` counts is declared in ``obs/metric_names.py``
+(``ENGINE_COUNTS``: name, type, ``metrics()`` key and help of each count):
+``EngineCounts`` is one engine's store of them, written on its thread, and
+``engine_totals`` the sum over the engines this process has had, which is
+what ``/metrics`` renders.  The persist tier, the streamed handoff and the
+sharded control plane each record into a singleton of their own below.
 """
 
 from __future__ import annotations
 
-__all__ = ["PrefillCounters", "counters", "PersistCounters", "persist_counters",
+import threading
+import weakref
+
+from dynamo_tpu.obs.metric_names import ENGINE_COUNTS
+
+__all__ = ["EngineCounts", "track_engine", "engine_totals", "reset",
+           "PersistCounters", "persist_counters",
            "KvStreamCounters", "kv_stream_counters",
-           "KvShardCounters", "kv_shard_counters",
-           "RequestCounters", "request_counters"]
+           "KvShardCounters", "kv_shard_counters"]
+
+# attribute -> initial value: every stored entry, then the operands of the
+# derived ones that have no entry of their own
+_STORED = {e.attr: e.initial for e in ENGINE_COUNTS if e.ratio is None}
+for _e in ENGINE_COUNTS:
+    for _attr in _e.ratio or ():
+        _STORED.setdefault(_attr, 0)
+# set at construction and true of one engine: not summed, the newest's shown
+_SHAPE = tuple(e.attr for e in ENGINE_COUNTS
+               if e.kind == "gauge" and e.ratio is None)
+_SUMMED = tuple(attr for attr in _STORED if attr not in _SHAPE)
 
 
-class PrefillCounters:
+class EngineCounts:
+    """One engine's counts: a plain attribute each, so that counting on the
+    engine thread is one attribute add (no lock: one writer, and a reader
+    takes whatever whole number stands there)."""
+
+    __slots__ = tuple(_STORED)
+
     def __init__(self) -> None:
-        self.reset()
-
-    def record(self, rows: int, tokens: int, budget: int = 0) -> None:
-        """One prefill dispatch: ``rows`` sequences packed, ``tokens``
-        prompt tokens computed.  ``budget`` is the token budget offered
-        (0 for legacy one-request / seq-parallel dispatches — those don't
-        count toward budget utilization)."""
-        self.dispatches_total += 1
-        self.rows_total += rows
-        self.tokens_total += tokens
-        if budget > 0:
-            self.budget_offered_total += budget
-            self.budget_used_total += tokens
-
-    def record_ready(self, rows: int) -> None:
-        """A prefill dispatch went out with ``rows`` requests standing
-        ready for one (itself included)."""
-        self.ready_rows_total += rows
-
-    def record_unified(self, decode_rows: int, prefill_tokens: int,
-                       budget: int) -> None:
-        """One unified mixed dispatch: ``decode_rows`` 1-token decode
-        rows plus ``prefill_tokens`` prompt tokens packed on one flat
-        axis, under an offered budget of ``budget`` tokens."""
-        self.unified_dispatches_total += 1
-        self.unified_decode_rows_total += decode_rows
-        self.unified_prefill_tokens_total += prefill_tokens
-        self.unified_budget_offered_total += budget
-        self.unified_budget_used_total += decode_rows + prefill_tokens
-
-    @property
-    def unified_budget_utilization(self) -> float:
-        """(decode rows + prefill tokens) / budget offered over unified
-        dispatches."""
-        if not self.unified_budget_offered_total:
-            return 0.0
-        return (self.unified_budget_used_total
-                / self.unified_budget_offered_total)
-
-    @property
-    def batch_occupancy(self) -> float:
-        """Mean sequences per prefill dispatch (lifetime)."""
-        if not self.dispatches_total:
-            return 0.0
-        return self.rows_total / self.dispatches_total
-
-    @property
-    def budget_utilization(self) -> float:
-        """Tokens packed / budget offered over batched dispatches."""
-        if not self.budget_offered_total:
-            return 0.0
-        return self.budget_used_total / self.budget_offered_total
-
-    def reset(self) -> None:
-        """Test isolation hook — the counters are process-global."""
-        self.dispatches_total = 0
-        self.rows_total = 0
-        self.tokens_total = 0
-        self.budget_offered_total = 0
-        self.budget_used_total = 0
-        self.ready_rows_total = 0
-        self.unified_dispatches_total = 0
-        self.unified_decode_rows_total = 0
-        self.unified_prefill_tokens_total = 0
-        self.unified_budget_offered_total = 0
-        self.unified_budget_used_total = 0
+        for attr, initial in _STORED.items():
+            setattr(self, attr, initial)
 
 
-counters = PrefillCounters()
+_lock = threading.Lock()       # guards the three below, not the counting
+_live: list[EngineCounts] = []
+_retired = EngineCounts()      # engines closed or collected, summed
+_newest = _retired             # whose shape gauges /metrics shows
+
+
+def track_engine(owner, counts: EngineCounts):
+    """``owner`` (an engine) counts into ``counts`` from now on.  Returns
+    the callable that retires them — folds them into the process total, so
+    that no counter on ``/metrics`` falls when an engine goes; the owner
+    calls it from ``close()``, and it runs by itself if the owner is
+    collected unclosed.  Once retired, further counting is not seen."""
+    global _newest
+    with _lock:
+        _live.append(counts)
+        _newest = counts
+    return weakref.finalize(owner, _retire, counts)
+
+
+def _retire(counts: EngineCounts) -> None:
+    with _lock:
+        if counts in _live:     # else reset() has forgotten them
+            _live.remove(counts)
+            _add(_retired, counts)
+
+
+def _add(total: EngineCounts, counts: EngineCounts) -> None:
+    for attr in _SUMMED:
+        setattr(total, attr, getattr(total, attr) + getattr(counts, attr))
+
+
+def engine_totals() -> EngineCounts:
+    """Every engine this process has had, live or gone, summed; the shape
+    gauges (mesh, cache) are those of the engine built last."""
+    total = EngineCounts()
+    with _lock:
+        for counts in (_retired, *_live):
+            _add(total, counts)
+        for attr in _SHAPE:
+            setattr(total, attr, getattr(_newest, attr))
+    return total
+
+
+def reset() -> None:
+    """Test isolation hook: forget every engine counted so far."""
+    global _retired, _newest
+    with _lock:
+        _live.clear()
+        _retired = _newest = EngineCounts()
 
 
 class PersistCounters:
@@ -273,271 +265,3 @@ class KvShardCounters:
 
 
 kv_shard_counters = KvShardCounters()
-
-
-class RequestCounters:
-    """What an operator asks of the decode path and of request endings,
-    counted on the engine thread where it happens.
-
-        dynamo_tpu_engine_decode_dispatches_total      counter (pure-decode
-                                                       dispatches: burst or
-                                                       speculative verify)
-        dynamo_tpu_engine_decode_rows_dispatched_total counter (running rows
-                                                       packed over them)
-        dynamo_tpu_engine_requests_finished_total      counter (any reason)
-        dynamo_tpu_engine_requests_cut_short_total     counter (ended with
-                                                       ``length`` because
-                                                       KV block space ran
-                                                       out — not max_tokens,
-                                                       not max_model_len)
-        dynamo_tpu_engine_first_tokens_total           counter (requests that
-                                                       emitted a token)
-        dynamo_tpu_engine_first_token_seconds_total    counter (sum of first
-                                                       emit - submit: TTFT
-                                                       as the engine sees it)
-        dynamo_tpu_engine_turn_wait_seconds_total      counter (of that, the
-                                                       sum of first dispatch
-                                                       that carried the
-                                                       request - slot: in a
-                                                       slot, nothing issued
-                                                       for it yet)
-        dynamo_tpu_engine_prefill_span_seconds_total   counter (and of first
-                                                       emit - that dispatch:
-                                                       its chunks, the turns
-                                                       between them, the
-                                                       readback; with the
-                                                       queue wait the three
-                                                       add up to the TTFT)
-        dynamo_tpu_engine_ahead_dispatches_total       counter (decode
-                                                       dispatches issued
-                                                       with a dispatch in
-                                                       flight: over decode
-                                                       dispatches, how often
-                                                       the host's round trip
-                                                       is hidden)
-        dynamo_tpu_engine_ahead_discards_total         counter (rows whose
-                                                       ahead-sample a stop
-                                                       found one dispatch
-                                                       late threw away: the
-                                                       mechanism's waste)
-        dynamo_tpu_engine_pipeline_drains_total        counter (turns that
-                                                       read back before they
-                                                       could issue: why the
-                                                       engagement is not 1)
-        dynamo_tpu_engine_operand_buffers_total        counter (host->device
-                                                       buffers the operands
-                                                       of the dispatches
-                                                       took: buffers put x
-                                                       devices put to; over
-                                                       prefill + decode
-                                                       dispatches 2 x devices
-                                                       under a mesh, the
-                                                       number of arrays with
-                                                       none)
-        dynamo_tpu_engine_prompt_tokens_admitted_total counter (prompt tokens
-                                                       of requests whose
-                                                       prefill completed)
-        dynamo_tpu_engine_prompt_tokens_cached_total   counter (of those, the
-                                                       tokens served from
-                                                       reused blocks: over
-                                                       admitted, the prefix
-                                                       cache's hit share)
-        dynamo_tpu_engine_attn_context_tokens_total    counter (latent-
-                                                       attention models:
-                                                       cached positions the
-                                                       decode rows dispatched
-                                                       could see, summed)
-        dynamo_tpu_engine_attn_selected_tokens_total   counter (of those, the
-                                                       positions attended to:
-                                                       min(context,
-                                                       index_topk) a row, all
-                                                       of them without an
-                                                       indexer; over context,
-                                                       how sparse attention
-                                                       was)
-        dynamo_tpu_engine_moe_router_picks_total       counter (experts the
-                                                       router picked for the
-                                                       real tokens of every
-                                                       dispatch, counted on
-                                                       the device: top-k a
-                                                       token and expert layer)
-        dynamo_tpu_engine_moe_held_picks_total         counter (of those, the
-                                                       picks on the experts
-                                                       this chip holds: the
-                                                       rows its experts
-                                                       computed)
-        dynamo_tpu_engine_moe_expert_layer_calls_total counter (expert layers
-                                                       run, one a layer and
-                                                       dispatch)
-        dynamo_tpu_engine_moe_experts_touched_total    counter (held experts
-                                                       with at least one row,
-                                                       summed over layers:
-                                                       x an expert's bytes,
-                                                       what the grouped
-                                                       matmul streamed)
-        dynamo_tpu_engine_state_tokens_total           counter (a model
-                                                       with recurrent layers:
-                                                       real tokens x such
-                                                       layers advanced)
-        dynamo_tpu_engine_state_resets_total           counter (sequences
-                                                       started from a zero
-                                                       state: position 0)
-        dynamo_tpu_engine_state_position_mismatches_total  counter (rows that
-                                                       went on at another
-                                                       position than their
-                                                       slot's state stood at:
-                                                       0, the slot contract)
-        dynamo_tpu_engine_loop_tokens_total            counter (tokens that
-                                                       went out in a prefill
-                                                       or decode dispatch)
-        dynamo_tpu_engine_loop_passes_total            counter (passes of the
-                                                       layer stack run for
-                                                       them: ut_steps a token
-                                                       for a looped decoder,
-                                                       1 otherwise; over
-                                                       tokens, passes a token)
-        dynamo_tpu_engine_decode_kv_blocks_walked_total
-                                                       counter (K/V blocks the
-                                                       rows of the decode
-                                                       dispatches own,
-                                                       ceil(context / block)
-                                                       a row: what the decode
-                                                       kernel fetches a layer)
-        dynamo_tpu_engine_decode_kv_blocks_group_bound_total
-                                                       counter (what fetching
-                                                       every slot of a group
-                                                       up to the group's
-                                                       longest row took for
-                                                       the same dispatches;
-                                                       1 - walked / bound is
-                                                       the share of fetches a
-                                                       row's own walk spares)
-    The ``moe_*`` and ``state_*`` three are counted on the device, inside the
-    model's forward, and read back with each dispatch's outputs; the others
-    on the host from lengths it already has.
-    """
-
-    def __init__(self) -> None:
-        self.reset()
-
-    def record_decode(self, rows: int) -> None:
-        self.decode_dispatches_total += 1
-        self.decode_rows_dispatched_total += rows
-
-    def record_finish(self) -> None:
-        self.requests_finished_total += 1
-
-    def record_cut_short(self) -> None:
-        self.requests_cut_short_total += 1
-
-    def record_first_token(self, seconds: float, turn_wait: float,
-                           prefill_span: float) -> None:
-        self.first_tokens_total += 1
-        self.first_token_seconds_total += seconds
-        self.turn_wait_seconds_total += turn_wait
-        self.prefill_span_seconds_total += prefill_span
-
-    def record_ahead(self) -> None:
-        self.ahead_dispatches_total += 1
-
-    def record_ahead_discard(self) -> None:
-        self.ahead_discards_total += 1
-
-    def record_drain(self) -> None:
-        self.pipeline_drains_total += 1
-
-    def record_operands(self, buffers: int) -> None:
-        self.operand_buffers_total += buffers
-
-    def record_prompt(self, tokens: int, cached: int) -> None:
-        self.prompt_tokens_admitted_total += tokens
-        self.prompt_tokens_cached_total += cached
-
-    def record_sparse_decode(self, context: int, selected: int) -> None:
-        self.attn_context_tokens_total += context
-        self.attn_selected_tokens_total += selected
-
-    def record_experts(self, picks: int, held: int, calls: int,
-                       touched: int) -> None:
-        self.moe_router_picks_total += picks
-        self.moe_held_picks_total += held
-        self.moe_expert_layer_calls_total += calls
-        self.moe_experts_touched_total += touched
-
-    def record_state(self, tokens: int, resets: int, mismatches: int) -> None:
-        self.state_tokens_total += tokens
-        self.state_resets_total += resets
-        self.state_position_mismatches_total += mismatches
-
-    def record_loop(self, tokens: int, passes: int) -> None:
-        self.loop_tokens_total += tokens
-        self.loop_passes_total += passes
-
-    def record_decode_blocks(self, walked: int, group_bound: int) -> None:
-        self.decode_kv_blocks_walked_total += walked
-        self.decode_kv_blocks_group_bound_total += group_bound
-
-    def reset(self) -> None:
-        """Test isolation hook — the counters are process-global."""
-        self.decode_dispatches_total = 0
-        self.decode_rows_dispatched_total = 0
-        self.requests_finished_total = 0
-        self.requests_cut_short_total = 0
-        self.first_tokens_total = 0
-        self.first_token_seconds_total = 0.0
-        self.turn_wait_seconds_total = 0.0
-        self.prefill_span_seconds_total = 0.0
-        self.ahead_dispatches_total = 0
-        self.ahead_discards_total = 0
-        self.pipeline_drains_total = 0
-        self.operand_buffers_total = 0
-        self.prompt_tokens_admitted_total = 0
-        self.prompt_tokens_cached_total = 0
-        self.attn_context_tokens_total = 0
-        self.attn_selected_tokens_total = 0
-        self.moe_router_picks_total = 0
-        self.moe_held_picks_total = 0
-        self.moe_expert_layer_calls_total = 0
-        self.moe_experts_touched_total = 0
-        self.state_tokens_total = 0
-        self.state_resets_total = 0
-        self.state_position_mismatches_total = 0
-        self.loop_tokens_total = 0
-        self.loop_passes_total = 0
-        self.decode_kv_blocks_walked_total = 0
-        self.decode_kv_blocks_group_bound_total = 0
-
-
-request_counters = RequestCounters()
-
-
-# The mesh the engine of this process runs on, written when an ``EngineCore``
-# is built (the last one built wins; its own ``metrics()`` says the same):
-#
-#     dynamo_tpu_engine_mesh_tp       gauge (size of the tensor-parallel axis
-#                                     "model"; 1 with no mesh)
-#     dynamo_tpu_engine_mesh_devices  gauge (devices of the mesh; 1 with no mesh)
-#
-# A number of a sharded server (a step time, a collective's share) means
-# something else than one chip's: a scrape says which it is looking at.
-mesh_shape = {"tp": 1, "devices": 1}
-
-# The KV cache of that engine, written beside it:
-#
-#     dynamo_tpu_engine_cache_layers        gauge (layers of the cache: the
-#                                           model's layers, times its passes
-#                                           for a looped decoder)
-#     dynamo_tpu_engine_kv_bytes_per_token  gauge (bytes one token holds
-#                                           across all of them: what sizes
-#                                           num_blocks and a block transfer)
-#     dynamo_tpu_engine_state_layers        gauge (layers that keep a
-#                                           recurrent state per slot; 0 for
-#                                           a model without one)
-#     dynamo_tpu_engine_state_bytes_per_slot  gauge (what one slot's state
-#                                           holds across them, whatever the
-#                                           sequence's length)
-#     dynamo_tpu_engine_prefix_reuse        gauge (1: cached blocks are
-#                                           reused; 0: off, by configuration
-#                                           or because of such a state)
-cache_shape = {"layers": 0, "bytes_per_token": 0, "state_layers": 0,
-               "state_bytes_per_slot": 0, "prefix_reuse": 1}

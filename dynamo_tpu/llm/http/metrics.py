@@ -19,10 +19,8 @@ import time
 from collections import defaultdict
 from typing import Iterator
 
-from dynamo_tpu.engine.counters import counters as prefill_counters
-from dynamo_tpu.engine.counters import (cache_shape, kv_shard_counters,
-                                        kv_stream_counters, mesh_shape,
-                                        persist_counters, request_counters)
+from dynamo_tpu.engine.counters import (engine_totals, kv_shard_counters,
+                                        kv_stream_counters, persist_counters)
 from dynamo_tpu.fault.counters import counters as fault_counters
 from dynamo_tpu.obs.costs import transfer_costs
 from dynamo_tpu.obs.metric_names import EngineMetric as EM
@@ -31,6 +29,7 @@ from dynamo_tpu.obs.metric_names import HttpMetric as HM
 from dynamo_tpu.obs.metric_names import KvShardMetric as SHM
 from dynamo_tpu.obs.metric_names import KvStreamMetric as STM
 from dynamo_tpu.obs.metric_names import KvTransferMetric as KM
+from dynamo_tpu.obs.metric_names import PREFILL_FAMILY, REQUEST_FAMILY
 from dynamo_tpu.obs.metric_names import PerfMetric as PM
 from dynamo_tpu.obs.perfmodel import perf_model
 from dynamo_tpu.obs.timeline import CLASSES, PHASES, step_timeline
@@ -66,6 +65,17 @@ class Histogram:
         yield f'{name}_bucket{{{labels},le="+Inf"}} {self.n}'
         yield f'{name}_sum{{{labels}}} {round(self.total, 6)}'
         yield f'{name}_count{{{labels}}} {self.n}'
+
+
+def _render_counts(lines: list[str], family, totals) -> None:
+    """The ``# TYPE`` and sample line of every named entry of ``family``."""
+    for entry in family:
+        if entry.name:
+            value = entry.value(totals)
+            if isinstance(value, float):
+                value = round(value, 6)
+            lines.append(f"# TYPE {entry.name} {entry.kind}")
+            lines.append(f"{entry.name} {value}")
 
 
 class Metrics:
@@ -157,38 +167,10 @@ class Metrics:
         lines.append(f"# TYPE {FM.SUSPECT_INSTANCES} gauge")
         lines.append(f"{FM.SUSPECT_INSTANCES} "
                      f"{fault_counters.suspect_instances()}")
-        # prefill batching (process-global, like the fault plane): how
-        # well the token-budget ragged prefill packs the device
-        lines.append(f"# TYPE {EM.PREFILL_DISPATCHES_TOTAL} counter")
-        lines.append(f"{EM.PREFILL_DISPATCHES_TOTAL} "
-                     f"{prefill_counters.dispatches_total}")
-        lines.append(f"# TYPE {EM.PREFILL_TOKENS_TOTAL} counter")
-        lines.append(f"{EM.PREFILL_TOKENS_TOTAL} "
-                     f"{prefill_counters.tokens_total}")
-        lines.append(f"# TYPE {EM.PREFILL_BATCH_OCCUPANCY} gauge")
-        lines.append(f"{EM.PREFILL_BATCH_OCCUPANCY} "
-                     f"{round(prefill_counters.batch_occupancy, 6)}")
-        lines.append(f"# TYPE {EM.PREFILL_BUDGET_UTILIZATION} gauge")
-        lines.append(f"{EM.PREFILL_BUDGET_UTILIZATION} "
-                     f"{round(prefill_counters.budget_utilization, 6)}")
-        # requests ready to prefill, summed at every prefill dispatch
-        lines.append(f"# TYPE {EM.PREFILL_READY_ROWS_TOTAL} counter")
-        lines.append(f"{EM.PREFILL_READY_ROWS_TOTAL} "
-                     f"{prefill_counters.ready_rows_total}")
-        # unified mixed prefill+decode dispatch: how many turns collapsed
-        # the two-dispatch interleave into one, and what shared the axis
-        lines.append(f"# TYPE {EM.UNIFIED_DISPATCHES_TOTAL} counter")
-        lines.append(f"{EM.UNIFIED_DISPATCHES_TOTAL} "
-                     f"{prefill_counters.unified_dispatches_total}")
-        lines.append(f"# TYPE {EM.UNIFIED_DECODE_ROWS_TOTAL} counter")
-        lines.append(f"{EM.UNIFIED_DECODE_ROWS_TOTAL} "
-                     f"{prefill_counters.unified_decode_rows_total}")
-        lines.append(f"# TYPE {EM.UNIFIED_PREFILL_TOKENS_TOTAL} counter")
-        lines.append(f"{EM.UNIFIED_PREFILL_TOKENS_TOTAL} "
-                     f"{prefill_counters.unified_prefill_tokens_total}")
-        lines.append(f"# TYPE {EM.UNIFIED_BUDGET_UTILIZATION} gauge")
-        lines.append(f"{EM.UNIFIED_BUDGET_UTILIZATION} "
-                     f"{round(prefill_counters.unified_budget_utilization, 6)}")
+        # what the engines of this process counted (obs/metric_names.py
+        # declares each): prefill batching and the unified dispatch here ...
+        totals = engine_totals()
+        _render_counts(lines, PREFILL_FAMILY, totals)
         # persistent prefix-cache tier (llm/kv/persist.py): blocks/tokens
         # restored from disk instead of re-prefilled, spill volume, and
         # the store's current footprint
@@ -291,115 +273,10 @@ class Metrics:
             for c in CLASSES:
                 lines.append(f'{name}{{class="{c}"}} '
                              f"{round(tl[f'{c}_{key}'], 6)}")
-        # decode occupancy, request endings, engine-side TTFT
-        rc = request_counters
-        lines.append(f"# TYPE {EM.DECODE_DISPATCHES_TOTAL} counter")
-        lines.append(f"{EM.DECODE_DISPATCHES_TOTAL} "
-                     f"{rc.decode_dispatches_total}")
-        lines.append(f"# TYPE {EM.DECODE_ROWS_DISPATCHED_TOTAL} counter")
-        lines.append(f"{EM.DECODE_ROWS_DISPATCHED_TOTAL} "
-                     f"{rc.decode_rows_dispatched_total}")
-        lines.append(f"# TYPE {EM.REQUESTS_FINISHED_TOTAL} counter")
-        lines.append(f"{EM.REQUESTS_FINISHED_TOTAL} "
-                     f"{rc.requests_finished_total}")
-        lines.append(f"# TYPE {EM.REQUESTS_CUT_SHORT_TOTAL} counter")
-        lines.append(f"{EM.REQUESTS_CUT_SHORT_TOTAL} "
-                     f"{rc.requests_cut_short_total}")
-        lines.append(f"# TYPE {EM.FIRST_TOKENS_TOTAL} counter")
-        lines.append(f"{EM.FIRST_TOKENS_TOTAL} {rc.first_tokens_total}")
-        lines.append(f"# TYPE {EM.FIRST_TOKEN_SECONDS_TOTAL} counter")
-        lines.append(f"{EM.FIRST_TOKEN_SECONDS_TOTAL} "
-                     f"{round(rc.first_token_seconds_total, 6)}")
-        # ... and its two stages behind the slot (the queue wait is the
-        # third): nothing issued for the request yet, then its prefill
-        lines.append(f"# TYPE {EM.TURN_WAIT_SECONDS_TOTAL} counter")
-        lines.append(f"{EM.TURN_WAIT_SECONDS_TOTAL} "
-                     f"{round(rc.turn_wait_seconds_total, 6)}")
-        lines.append(f"# TYPE {EM.PREFILL_SPAN_SECONDS_TOTAL} counter")
-        lines.append(f"{EM.PREFILL_SPAN_SECONDS_TOTAL} "
-                     f"{round(rc.prefill_span_seconds_total, 6)}")
-        # dispatch-ahead: how often the host's round trip ran under the
-        # device program, what late stops wasted, what broke the chain
-        lines.append(f"# TYPE {EM.AHEAD_DISPATCHES_TOTAL} counter")
-        lines.append(f"{EM.AHEAD_DISPATCHES_TOTAL} "
-                     f"{rc.ahead_dispatches_total}")
-        lines.append(f"# TYPE {EM.AHEAD_DISCARDS_TOTAL} counter")
-        lines.append(f"{EM.AHEAD_DISCARDS_TOTAL} {rc.ahead_discards_total}")
-        lines.append(f"# TYPE {EM.PIPELINE_DRAINS_TOTAL} counter")
-        lines.append(f"{EM.PIPELINE_DRAINS_TOTAL} {rc.pipeline_drains_total}")
-        # host->device buffers the dispatches' operands took (buffers put
-        # x devices put to): over the dispatches, buffers per dispatch
-        lines.append(f"# TYPE {EM.OPERAND_BUFFERS_TOTAL} counter")
-        lines.append(f"{EM.OPERAND_BUFFERS_TOTAL} {rc.operand_buffers_total}")
-        # prefix reuse (cached / admitted) and, for a model with a
-        # sparse-attention indexer, how sparse decode attention was
-        lines.append(f"# TYPE {EM.PROMPT_TOKENS_ADMITTED_TOTAL} counter")
-        lines.append(f"{EM.PROMPT_TOKENS_ADMITTED_TOTAL} "
-                     f"{rc.prompt_tokens_admitted_total}")
-        lines.append(f"# TYPE {EM.PROMPT_TOKENS_CACHED_TOTAL} counter")
-        lines.append(f"{EM.PROMPT_TOKENS_CACHED_TOTAL} "
-                     f"{rc.prompt_tokens_cached_total}")
-        lines.append(f"# TYPE {EM.ATTN_CONTEXT_TOKENS_TOTAL} counter")
-        lines.append(f"{EM.ATTN_CONTEXT_TOKENS_TOTAL} "
-                     f"{rc.attn_context_tokens_total}")
-        lines.append(f"# TYPE {EM.ATTN_SELECTED_TOKENS_TOTAL} counter")
-        lines.append(f"{EM.ATTN_SELECTED_TOKENS_TOTAL} "
-                     f"{rc.attn_selected_tokens_total}")
-        # the expert layers' own counts, read back from the device: router
-        # picks, those on the experts held here, expert-layer calls, experts
-        # with a row
-        lines.append(f"# TYPE {EM.MOE_ROUTER_PICKS_TOTAL} counter")
-        lines.append(f"{EM.MOE_ROUTER_PICKS_TOTAL} "
-                     f"{rc.moe_router_picks_total}")
-        lines.append(f"# TYPE {EM.MOE_HELD_PICKS_TOTAL} counter")
-        lines.append(f"{EM.MOE_HELD_PICKS_TOTAL} {rc.moe_held_picks_total}")
-        lines.append(f"# TYPE {EM.MOE_EXPERT_LAYER_CALLS_TOTAL} counter")
-        lines.append(f"{EM.MOE_EXPERT_LAYER_CALLS_TOTAL} "
-                     f"{rc.moe_expert_layer_calls_total}")
-        lines.append(f"# TYPE {EM.MOE_EXPERTS_TOUCHED_TOTAL} counter")
-        lines.append(f"{EM.MOE_EXPERTS_TOUCHED_TOTAL} "
-                     f"{rc.moe_experts_touched_total}")
-        # what a model's recurrent layers did, from the same read-back
-        lines.append(f"# TYPE {EM.STATE_TOKENS_TOTAL} counter")
-        lines.append(f"{EM.STATE_TOKENS_TOTAL} {rc.state_tokens_total}")
-        lines.append(f"# TYPE {EM.STATE_RESETS_TOTAL} counter")
-        lines.append(f"{EM.STATE_RESETS_TOTAL} {rc.state_resets_total}")
-        lines.append(f"# TYPE {EM.STATE_POSITION_MISMATCHES_TOTAL} counter")
-        lines.append(f"{EM.STATE_POSITION_MISMATCHES_TOTAL} "
-                     f"{rc.state_position_mismatches_total}")
-        # the mesh this engine runs on (1 and 1 with no mesh)
-        lines.append(f"# TYPE {EM.MESH_TP} gauge")
-        lines.append(f"{EM.MESH_TP} {mesh_shape['tp']}")
-        lines.append(f"# TYPE {EM.MESH_DEVICES} gauge")
-        lines.append(f"{EM.MESH_DEVICES} {mesh_shape['devices']}")
-        # tokens dispatched and the passes of the layer stack run for them
-        # (a looped decoder: ut_steps a token), and what the cache is made
-        # of: its layers, and the bytes a token holds across them
-        lines.append(f"# TYPE {EM.LOOP_TOKENS_TOTAL} counter")
-        lines.append(f"{EM.LOOP_TOKENS_TOTAL} {rc.loop_tokens_total}")
-        lines.append(f"# TYPE {EM.LOOP_PASSES_TOTAL} counter")
-        lines.append(f"{EM.LOOP_PASSES_TOTAL} {rc.loop_passes_total}")
-        # K/V blocks the decode rows own (what the decode kernel fetches a
-        # layer) and what fetching up to each group's longest row took
-        lines.append(f"# TYPE {EM.DECODE_KV_BLOCKS_WALKED_TOTAL} counter")
-        lines.append(f"{EM.DECODE_KV_BLOCKS_WALKED_TOTAL} "
-                     f"{rc.decode_kv_blocks_walked_total}")
-        lines.append(f"# TYPE {EM.DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL} counter")
-        lines.append(f"{EM.DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL} "
-                     f"{rc.decode_kv_blocks_group_bound_total}")
-        lines.append(f"# TYPE {EM.CACHE_LAYERS} gauge")
-        lines.append(f"{EM.CACHE_LAYERS} {cache_shape['layers']}")
-        lines.append(f"# TYPE {EM.KV_BYTES_PER_TOKEN} gauge")
-        lines.append(f"{EM.KV_BYTES_PER_TOKEN} "
-                     f"{cache_shape['bytes_per_token']}")
-        # ... and the state a slot keeps beside it, with what that switches off
-        lines.append(f"# TYPE {EM.STATE_LAYERS} gauge")
-        lines.append(f"{EM.STATE_LAYERS} {cache_shape['state_layers']}")
-        lines.append(f"# TYPE {EM.STATE_BYTES_PER_SLOT} gauge")
-        lines.append(f"{EM.STATE_BYTES_PER_SLOT} "
-                     f"{cache_shape['state_bytes_per_slot']}")
-        lines.append(f"# TYPE {EM.PREFIX_REUSE} gauge")
-        lines.append(f"{EM.PREFIX_REUSE} {cache_shape['prefix_reuse']}")
+        # ... and decode occupancy, request endings, the engine-side TTFT
+        # and its stages, dispatch-ahead, what the models counted on the
+        # device, the mesh and the cache
+        _render_counts(lines, REQUEST_FAMILY, totals)
         lines.append(f"# TYPE {EM.HOST_GAP_MS_PER_TURN} gauge")
         lines.append(f"{EM.HOST_GAP_MS_PER_TURN} "
                      f"{round(tl['host_gap_ms_per_turn'], 6)}")

@@ -56,6 +56,7 @@ from jax.sharding import PartitionSpec as P
 
 from dynamo_tpu.models.deepseek import apply_rope_interleaved, moe_route
 from dynamo_tpu.models.llama import (
+    EXPERT_COUNT_KEYS,
     EXPERT_COUNTS,
     experts_touched,
     grouped_expert_dispatch,
@@ -469,6 +470,7 @@ class GlmDsaModel:
     # latent_cache.py): EngineCore refuses what would move blocks without
     # knowing it
     private_cache_layout = True
+    moe_count_keys = EXPERT_COUNT_KEYS
     supports_ragged_prefill = False
     supports_unified_dispatch = False
     supports_seq_parallel = False

@@ -51,6 +51,7 @@ from jax.sharding import PartitionSpec as P
 from dynamo_tpu.models.deepseek import moe_route
 from dynamo_tpu.models.glm_dsa import ROUTER_BIAS_STD
 from dynamo_tpu.models.llama import (
+    EXPERT_COUNT_KEYS,
     EXPERT_COUNTS,
     experts_touched,
     grouped_expert_dispatch,
@@ -80,8 +81,10 @@ QK_NORM_EPS = 1e-6
 DECAY_PROJ_STD = 0.5
 # a forward adds its three counts (real tokens x linear layers, sequences
 # started from zeros, position mismatches) to ``moe_counts[0, 0, 4:7]``,
-# behind the expert layers' ``EXPERT_COUNTS``
-STATE_COUNTS = 3
+# behind the expert layers' ``EXPERT_COUNTS``; named like those
+STATE_COUNT_KEYS = ("state_tokens_total", "state_resets_total",
+                    "state_position_mismatches_total")
+STATE_COUNTS = len(STATE_COUNT_KEYS)
 
 
 @dataclass
@@ -215,6 +218,7 @@ class HybridLinearModel:
     # the slots of a prefill dispatch's rows, keeps prefix reuse off, and
     # refuses what packs several sequences into one row axis
     recurrent_state = True
+    moe_count_keys = EXPERT_COUNT_KEYS + STATE_COUNT_KEYS
     supports_ragged_prefill = False
     supports_unified_dispatch = False
     supports_seq_parallel = False

@@ -878,8 +878,7 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
     k = topi.shape[1]
     flat_e = topi.reshape(t * k)
     kernel = gmm.grouped_matmul_impl(
-        t * k, num_experts, d, w_gate.shape[-1], xf.dtype, w_gate.dtype
-    ) == "pallas"
+        t * k, num_experts, d, w_gate.shape[-1], xf.dtype, w_gate.dtype)
     here = None
     if held is not None:
         first, num_experts = held
@@ -924,9 +923,13 @@ def grouped_expert_dispatch(xf, weights, topi, num_experts,
 
 
 # what a counting model's expert layer adds to its row of the cache's
-# ``moe_counts``: router picks, picks on the experts held here, calls,
-# experts touched
-EXPERT_COUNTS = 4
+# ``moe_counts``, by the key of the engine's count (obs/metric_names.py) each
+# column is read back into: a model whose cache carries ``moe_counts`` says
+# in ``moe_count_keys`` what its columns are, in order
+EXPERT_COUNT_KEYS = ("moe_router_picks_total", "moe_held_picks_total",
+                     "moe_expert_layer_calls_total",
+                     "moe_experts_touched_total")
+EXPERT_COUNTS = len(EXPERT_COUNT_KEYS)
 
 
 def experts_touched(topi, first: int, count: int):

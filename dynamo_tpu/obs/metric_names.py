@@ -15,17 +15,24 @@ never a silently-zero bench column.
 dtmet census is checked against; ``docs/observability.md``'s metric
 reference table is generated from it (drift fails ``lint --metrics``).
 
+``ENGINE_COUNTS`` is the one declaration of what an ``EngineCore`` counts:
+the engine's store, ``metrics()``, the render, ``SCHEMA``'s rows and the
+``EngineMetric`` constants of those names all follow it (``EngineCount``).
+
 Zero-dependency base layer (like the rest of ``obs/``): importable
 from the engine, llm, components, benchmarks and tests without cycles.
 """
 
 from __future__ import annotations
 
+from typing import NamedTuple, Optional
+
 __all__ = [
     "HTTP_PREFIX", "FAULT_PREFIX", "ENGINE_PREFIX", "KV_PREFIX",
     "STREAM_PREFIX", "SHARD_PREFIX", "PERF_PREFIX", "ROUTER_PREFIX",
     "HttpMetric", "FaultMetric", "EngineMetric", "KvTransferMetric",
     "KvStreamMetric", "KvShardMetric", "PerfMetric", "RouterMetric",
+    "EngineCount", "PREFILL_FAMILY", "REQUEST_FAMILY", "ENGINE_COUNTS",
     "SCHEMA", "metric_names",
 ]
 
@@ -68,22 +75,14 @@ class FaultMetric:
 
 
 class EngineMetric:
-    """Engine plane: prefill batching, unified dispatch, persist tier
-    (``engine/counters.py``) and the step timeline
-    (``obs/timeline.py``)."""
+    """Engine plane.  The names below are the families that stay
+    hand-rendered: the persist tier (``engine/counters.py``
+    ``PersistCounters``) and the step timeline (``obs/timeline.py``).
+    Every unlabelled count and gauge an ``EngineCore`` produces is an
+    entry of ``ENGINE_COUNTS`` further down, which adds its constant
+    here (``dynamo_tpu_engine_loop_passes_total`` ->
+    ``EngineMetric.LOOP_PASSES_TOTAL``)."""
 
-    PREFILL_DISPATCHES_TOTAL = "dynamo_tpu_engine_prefill_dispatches_total"
-    PREFILL_TOKENS_TOTAL = "dynamo_tpu_engine_prefill_tokens_total"
-    PREFILL_BATCH_OCCUPANCY = "dynamo_tpu_engine_prefill_batch_occupancy"
-    PREFILL_BUDGET_UTILIZATION = (
-        "dynamo_tpu_engine_prefill_budget_utilization")
-    PREFILL_READY_ROWS_TOTAL = "dynamo_tpu_engine_prefill_ready_rows_total"
-    UNIFIED_DISPATCHES_TOTAL = "dynamo_tpu_engine_unified_dispatches_total"
-    UNIFIED_DECODE_ROWS_TOTAL = "dynamo_tpu_engine_unified_decode_rows_total"
-    UNIFIED_PREFILL_TOKENS_TOTAL = (
-        "dynamo_tpu_engine_unified_prefill_tokens_total")
-    UNIFIED_BUDGET_UTILIZATION = (
-        "dynamo_tpu_engine_unified_budget_utilization")
     PERSIST_HITS_TOTAL = "dynamo_tpu_engine_persist_hits_total"
     PERSIST_MISSES_TOTAL = "dynamo_tpu_engine_persist_misses_total"
     PERSIST_RESTORED_TOKENS_TOTAL = (
@@ -107,59 +106,6 @@ class EngineMetric:
         "dynamo_tpu_engine_step_class_launch_seconds_total")
     STEP_CLASS_READBACK_SECONDS_TOTAL = (
         "dynamo_tpu_engine_step_class_readback_seconds_total")
-    # engine/counters.py RequestCounters
-    DECODE_DISPATCHES_TOTAL = "dynamo_tpu_engine_decode_dispatches_total"
-    DECODE_ROWS_DISPATCHED_TOTAL = (
-        "dynamo_tpu_engine_decode_rows_dispatched_total")
-    REQUESTS_FINISHED_TOTAL = "dynamo_tpu_engine_requests_finished_total"
-    REQUESTS_CUT_SHORT_TOTAL = "dynamo_tpu_engine_requests_cut_short_total"
-    FIRST_TOKENS_TOTAL = "dynamo_tpu_engine_first_tokens_total"
-    FIRST_TOKEN_SECONDS_TOTAL = (
-        "dynamo_tpu_engine_first_token_seconds_total")
-    # a first token's stages behind the slot (EngineCore._first_token)
-    TURN_WAIT_SECONDS_TOTAL = "dynamo_tpu_engine_turn_wait_seconds_total"
-    PREFILL_SPAN_SECONDS_TOTAL = (
-        "dynamo_tpu_engine_prefill_span_seconds_total")
-    # dispatch-ahead (EngineCore._settle)
-    AHEAD_DISPATCHES_TOTAL = "dynamo_tpu_engine_ahead_dispatches_total"
-    AHEAD_DISCARDS_TOTAL = "dynamo_tpu_engine_ahead_discards_total"
-    PIPELINE_DRAINS_TOTAL = "dynamo_tpu_engine_pipeline_drains_total"
-    # operand upload (EngineCore._upload_dispatch)
-    OPERAND_BUFFERS_TOTAL = "dynamo_tpu_engine_operand_buffers_total"
-    # prefix reuse and sparse attention, from lengths the host has
-    PROMPT_TOKENS_ADMITTED_TOTAL = (
-        "dynamo_tpu_engine_prompt_tokens_admitted_total")
-    PROMPT_TOKENS_CACHED_TOTAL = "dynamo_tpu_engine_prompt_tokens_cached_total"
-    ATTN_CONTEXT_TOKENS_TOTAL = "dynamo_tpu_engine_attn_context_tokens_total"
-    ATTN_SELECTED_TOKENS_TOTAL = "dynamo_tpu_engine_attn_selected_tokens_total"
-    # what the expert layers counted on the device (a share of the experts)
-    MOE_ROUTER_PICKS_TOTAL = "dynamo_tpu_engine_moe_router_picks_total"
-    MOE_HELD_PICKS_TOTAL = "dynamo_tpu_engine_moe_held_picks_total"
-    MOE_EXPERT_LAYER_CALLS_TOTAL = (
-        "dynamo_tpu_engine_moe_expert_layer_calls_total")
-    MOE_EXPERTS_TOUCHED_TOTAL = "dynamo_tpu_engine_moe_experts_touched_total"
-    # what the recurrent layers did, counted on the device
-    STATE_TOKENS_TOTAL = "dynamo_tpu_engine_state_tokens_total"
-    STATE_RESETS_TOTAL = "dynamo_tpu_engine_state_resets_total"
-    STATE_POSITION_MISMATCHES_TOTAL = (
-        "dynamo_tpu_engine_state_position_mismatches_total")
-    # tokens dispatched and the passes of the layer stack run for them
-    LOOP_TOKENS_TOTAL = "dynamo_tpu_engine_loop_tokens_total"
-    LOOP_PASSES_TOTAL = "dynamo_tpu_engine_loop_passes_total"
-    # K/V blocks the decode rows own, and what a group-max fetch took
-    DECODE_KV_BLOCKS_WALKED_TOTAL = (
-        "dynamo_tpu_engine_decode_kv_blocks_walked_total")
-    DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL = (
-        "dynamo_tpu_engine_decode_kv_blocks_group_bound_total")
-    # engine/counters.py mesh_shape
-    MESH_TP = "dynamo_tpu_engine_mesh_tp"
-    MESH_DEVICES = "dynamo_tpu_engine_mesh_devices"
-    # engine/counters.py cache_shape
-    CACHE_LAYERS = "dynamo_tpu_engine_cache_layers"
-    KV_BYTES_PER_TOKEN = "dynamo_tpu_engine_kv_bytes_per_token"
-    STATE_LAYERS = "dynamo_tpu_engine_state_layers"
-    STATE_BYTES_PER_SLOT = "dynamo_tpu_engine_state_bytes_per_slot"
-    PREFIX_REUSE = "dynamo_tpu_engine_prefix_reuse"
 
 
 class KvTransferMetric:
@@ -218,6 +164,204 @@ class RouterMetric:
     KV_HIT_RATE_PERCENT = "dynamo_tpu_kv_hit_rate_percent"
 
 
+class EngineCount(NamedTuple):
+    """One unlabelled count or gauge of an ``EngineCore``, declared once.
+
+    ``engine/counters.py`` builds the engine's store from these entries (one
+    attribute each, named ``attr``), ``EngineCore.metrics()`` is ``key ->
+    value`` over them, ``/metrics`` renders ``name`` in the table's order,
+    ``SCHEMA`` and ``EngineMetric`` take ``name`` from here, and the metrics
+    manifest and the two listings of ``docs/observability.md`` are generated
+    from them (``dynamo-tpu lint --metrics --update-baseline``)."""
+
+    name: Optional[str]   # on /metrics, spelled in full; None: metrics() only
+    kind: str             # "counter" | "gauge"
+    help: str
+    key: Optional[str]    # in EngineCore.metrics(); None: /metrics only
+    # a derived value: one attribute of the store over another, 0.0 while
+    # the second is 0 (its operands need no entry of their own)
+    ratio: Optional[tuple[str, str]] = None
+    initial: float = 0    # what a store starts from, and /metrics with no engine
+
+    @property
+    def attr(self) -> str:
+        """The store's attribute: the key, else the name less the prefix."""
+        return self.key or _short(self.name)
+
+    def value(self, counts):
+        """This entry's value in ``counts`` (a store, or a sum of stores)."""
+        if self.ratio is None:
+            return getattr(counts, self.attr)
+        over = getattr(counts, self.ratio[1])
+        return getattr(counts, self.ratio[0]) / over if over else 0.0
+
+
+def _short(name: str) -> str:
+    return name[len(ENGINE_PREFIX) + 1:]
+
+
+_SHORT = object()
+
+
+def _count(name, kind, help, *, key=_SHORT, ratio=None, initial=0):
+    """An entry whose ``metrics()`` key is the name less the prefix unless
+    ``key`` says otherwise (None: none)."""
+    return EngineCount(name, kind, help,
+                       _short(name) if key is _SHORT else key, ratio, initial)
+
+
+# In the order /metrics renders them.  The first family goes out before the
+# persist, stream, shard and timeline families, the second after them.
+PREFILL_FAMILY = (
+    _count("dynamo_tpu_engine_prefill_dispatches_total", "counter",
+           "prefill dispatches, any path"),
+    _count("dynamo_tpu_engine_prefill_tokens_total", "counter",
+           "prompt tokens they computed", key=None),
+    _count("dynamo_tpu_engine_prefill_batch_occupancy", "gauge",
+           "mean sequences a prefill dispatch",
+           ratio=("prefill_rows_dispatched", "prefill_dispatches_total")),
+    _count("dynamo_tpu_engine_prefill_budget_utilization", "gauge",
+           "tokens packed over the token budget offered, over the batched "
+           "dispatches (a one-request or seq-parallel dispatch offers none)",
+           ratio=("prefill_budget_used", "prefill_budget_offered")),
+    _count("dynamo_tpu_engine_prefill_ready_rows_total", "counter",
+           "requests ready to prefill, summed at every prefill dispatch: "
+           "over the dispatches, the backlog a served request stood in"),
+    _count("dynamo_tpu_engine_unified_dispatches_total", "counter",
+           "mixed prefill+decode dispatches of the unified token-budget "
+           "scheduler: turns that made one dispatch of two"),
+    _count("dynamo_tpu_engine_unified_decode_rows_total", "counter",
+           "decode rows packed over them", key="unified_decode_rows"),
+    _count("dynamo_tpu_engine_unified_prefill_tokens_total", "counter",
+           "prefill tokens packed over them", key="unified_prefill_tokens"),
+    _count("dynamo_tpu_engine_unified_budget_utilization", "gauge",
+           "decode rows + prefill tokens over the flat-axis budget offered",
+           ratio=("unified_budget_used", "unified_budget_offered")),
+)
+
+REQUEST_FAMILY = (
+    _count("dynamo_tpu_engine_decode_dispatches_total", "counter",
+           "pure-decode dispatches: burst or speculative verify"),
+    _count("dynamo_tpu_engine_decode_rows_dispatched_total", "counter",
+           "running rows packed over them"),
+    _count("dynamo_tpu_engine_requests_finished_total", "counter",
+           "requests finished, for any reason"),
+    _count("dynamo_tpu_engine_requests_cut_short_total", "counter",
+           "of those, ended with `length` because KV block space ran out "
+           "(not max_tokens, not max_model_len)"),
+    _count("dynamo_tpu_engine_first_tokens_total", "counter",
+           "requests that emitted a token"),
+    _count("dynamo_tpu_engine_first_token_seconds_total", "counter",
+           "sum of first emit - submit: TTFT as the engine sees it",
+           initial=0.0),
+    _count("dynamo_tpu_engine_turn_wait_seconds_total", "counter",
+           "of that, the sum of first dispatch that carried the request - "
+           "slot: in a slot, nothing issued for it yet", initial=0.0),
+    _count("dynamo_tpu_engine_prefill_span_seconds_total", "counter",
+           "and of first emit - that dispatch (its chunks, the turns between "
+           "them, the readback); with the queue wait the three add up to the "
+           "TTFT", initial=0.0),
+    _count("dynamo_tpu_engine_ahead_dispatches_total", "counter",
+           "decode dispatches issued with a dispatch in flight: over decode "
+           "dispatches, how often the host's round trip is hidden"),
+    _count("dynamo_tpu_engine_ahead_discards_total", "counter",
+           "rows whose ahead-sample a stop found one dispatch late threw "
+           "away: the mechanism's waste"),
+    _count("dynamo_tpu_engine_pipeline_drains_total", "counter",
+           "turns that read back before they could issue (or had nothing to "
+           "issue): why the ahead share is not 1"),
+    _count("dynamo_tpu_engine_operand_buffers_total", "counter",
+           "host->device buffers the dispatches' operands took, buffers put "
+           "x devices put to: over prefill + decode dispatches, 2 x devices "
+           "under a mesh, the number of arrays (9) with none"),
+    _count("dynamo_tpu_engine_prompt_tokens_admitted_total", "counter",
+           "prompt tokens of requests whose prefill completed"),
+    _count("dynamo_tpu_engine_prompt_tokens_cached_total", "counter",
+           "of those, the tokens served from reused blocks: over admitted, "
+           "the prefix cache's hit share (cellbench's kv.prefix_hit_pct)"),
+    _count("dynamo_tpu_engine_attn_context_tokens_total", "counter",
+           "latent-attention models: cached positions the decode rows "
+           "dispatched could see, summed"),
+    _count("dynamo_tpu_engine_attn_selected_tokens_total", "counter",
+           "of those, the positions attended to, min(context, index_topk) a "
+           "row, all of them without an indexer: over context, how sparse "
+           "decode attention was"),
+    _count("dynamo_tpu_engine_moe_router_picks_total", "counter",
+           "experts the router picked for the real tokens of every dispatch "
+           "(top-k a token and expert layer), counted on the device in the "
+           "cache's moe_counts and read back with each dispatch"),
+    _count("dynamo_tpu_engine_moe_held_picks_total", "counter",
+           "of those, the picks on the experts this chip holds: the rows its "
+           "experts computed (over router picks, moe.held_pick_pct)"),
+    _count("dynamo_tpu_engine_moe_expert_layer_calls_total", "counter",
+           "expert layers run, one a layer and dispatch"),
+    _count("dynamo_tpu_engine_moe_experts_touched_total", "counter",
+           "held experts with at least one row, summed over layers: x an "
+           "expert's bytes, what the grouped matmul streamed"),
+    _count("dynamo_tpu_engine_state_tokens_total", "counter",
+           "a model with recurrent layers (docs/linear_state.md): real "
+           "tokens x such layers advanced, counted on the device like the "
+           "moe_* four"),
+    _count("dynamo_tpu_engine_state_resets_total", "counter",
+           "sequences started from a zero state (position 0): one a request, "
+           "since such a model reuses no prefix"),
+    _count("dynamo_tpu_engine_state_position_mismatches_total", "counter",
+           "rows that went on at another position than their slot's state "
+           "stood at: 0, the slot contract"),
+    _count("dynamo_tpu_engine_mesh_tp", "gauge",
+           "size of the tensor-parallel axis `model` of the engine's mesh; 1 "
+           "with no mesh", initial=1),
+    _count("dynamo_tpu_engine_mesh_devices", "gauge",
+           "devices of that mesh; 1 with no mesh", initial=1),
+    _count("dynamo_tpu_engine_loop_tokens_total", "counter",
+           "tokens that went out in a prefill or decode dispatch"),
+    _count("dynamo_tpu_engine_loop_passes_total", "counter",
+           "passes of the layer stack run for them: ut_steps a token for a "
+           "looped decoder (docs/looped_layers.md), 1 otherwise; over "
+           "tokens, loop.passes_per_token"),
+    _count("dynamo_tpu_engine_decode_kv_blocks_walked_total", "counter",
+           "K/V blocks the rows of the decode dispatches own, ceil(context / "
+           "block) a row: what the decode kernel fetches a layer"),
+    _count("dynamo_tpu_engine_decode_kv_blocks_group_bound_total", "counter",
+           "what fetching every slot of a kernel group up to the group's "
+           "longest row took for the same dispatches; 1 - walked / bound is "
+           "the share of fetches a row's own walk spares"),
+    _count("dynamo_tpu_engine_cache_layers", "gauge",
+           "layers of the K/V cache: the model's layers, times its passes "
+           "for a looped decoder"),
+    _count("dynamo_tpu_engine_kv_bytes_per_token", "gauge",
+           "bytes one token holds across all of them: what sizes num_blocks "
+           "and a block transfer"),
+    _count("dynamo_tpu_engine_state_layers", "gauge",
+           "layers that keep a recurrent state per slot; 0 for a model "
+           "without one"),
+    _count("dynamo_tpu_engine_state_bytes_per_slot", "gauge",
+           "what one slot's state holds across them, whatever the "
+           "sequence's length"),
+    _count("dynamo_tpu_engine_prefix_reuse", "gauge",
+           "1: cached blocks are reused; 0: off, by configuration or because "
+           "a recurrent state rules it out", initial=1),
+    # on EngineCore.metrics() alone
+    _count(None, "counter", "tokens emitted", key="tokens_generated"),
+    _count(None, "counter", "speculative verify dispatches",
+           key="spec_steps"),
+    _count(None, "counter", "tokens the n-gram lookup or the draft proposed",
+           key="spec_proposed"),
+    _count(None, "counter", "proposals the model agreed with",
+           key="spec_accepted"),
+    _count(None, "counter", "jax.device_get calls of the step loop",
+           key="device_gets_total"),
+    _count(None, "gauge", "1: the decode program updates a slot's recurrent "
+           "state in one kernel", key="state_update_kernel"),
+)
+
+ENGINE_COUNTS = PREFILL_FAMILY + REQUEST_FAMILY
+
+for _e in ENGINE_COUNTS:
+    if _e.name:
+        setattr(EngineMetric, _short(_e.name).upper(), _e.name)
+
+
 # name -> (type, labels) — the committed label-schema contract.
 # Histogram entries list their sample labels WITHOUT the implicit "le"
 # (the render side adds it on _bucket lines); the dtmet census
@@ -236,15 +380,6 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     FaultMetric.MIGRATIONS_TOTAL: ("counter", ()),
     FaultMetric.DRAINS_IN_PROGRESS: ("gauge", ()),
     FaultMetric.SUSPECT_INSTANCES: ("gauge", ()),
-    EngineMetric.PREFILL_DISPATCHES_TOTAL: ("counter", ()),
-    EngineMetric.PREFILL_TOKENS_TOTAL: ("counter", ()),
-    EngineMetric.PREFILL_BATCH_OCCUPANCY: ("gauge", ()),
-    EngineMetric.PREFILL_BUDGET_UTILIZATION: ("gauge", ()),
-    EngineMetric.PREFILL_READY_ROWS_TOTAL: ("counter", ()),
-    EngineMetric.UNIFIED_DISPATCHES_TOTAL: ("counter", ()),
-    EngineMetric.UNIFIED_DECODE_ROWS_TOTAL: ("counter", ()),
-    EngineMetric.UNIFIED_PREFILL_TOKENS_TOTAL: ("counter", ()),
-    EngineMetric.UNIFIED_BUDGET_UTILIZATION: ("gauge", ()),
     EngineMetric.PERSIST_HITS_TOTAL: ("counter", ()),
     EngineMetric.PERSIST_MISSES_TOTAL: ("counter", ()),
     EngineMetric.PERSIST_RESTORED_TOKENS_TOTAL: ("counter", ()),
@@ -262,40 +397,6 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.STEP_CLASS_DEVICE_SECONDS_TOTAL: ("counter", ("class",)),
     EngineMetric.STEP_CLASS_LAUNCH_SECONDS_TOTAL: ("counter", ("class",)),
     EngineMetric.STEP_CLASS_READBACK_SECONDS_TOTAL: ("counter", ("class",)),
-    EngineMetric.DECODE_DISPATCHES_TOTAL: ("counter", ()),
-    EngineMetric.DECODE_ROWS_DISPATCHED_TOTAL: ("counter", ()),
-    EngineMetric.REQUESTS_FINISHED_TOTAL: ("counter", ()),
-    EngineMetric.REQUESTS_CUT_SHORT_TOTAL: ("counter", ()),
-    EngineMetric.FIRST_TOKENS_TOTAL: ("counter", ()),
-    EngineMetric.FIRST_TOKEN_SECONDS_TOTAL: ("counter", ()),
-    EngineMetric.TURN_WAIT_SECONDS_TOTAL: ("counter", ()),
-    EngineMetric.PREFILL_SPAN_SECONDS_TOTAL: ("counter", ()),
-    EngineMetric.AHEAD_DISPATCHES_TOTAL: ("counter", ()),
-    EngineMetric.AHEAD_DISCARDS_TOTAL: ("counter", ()),
-    EngineMetric.PIPELINE_DRAINS_TOTAL: ("counter", ()),
-    EngineMetric.OPERAND_BUFFERS_TOTAL: ("counter", ()),
-    EngineMetric.PROMPT_TOKENS_ADMITTED_TOTAL: ("counter", ()),
-    EngineMetric.PROMPT_TOKENS_CACHED_TOTAL: ("counter", ()),
-    EngineMetric.ATTN_CONTEXT_TOKENS_TOTAL: ("counter", ()),
-    EngineMetric.ATTN_SELECTED_TOKENS_TOTAL: ("counter", ()),
-    EngineMetric.MOE_ROUTER_PICKS_TOTAL: ("counter", ()),
-    EngineMetric.MOE_HELD_PICKS_TOTAL: ("counter", ()),
-    EngineMetric.MOE_EXPERT_LAYER_CALLS_TOTAL: ("counter", ()),
-    EngineMetric.MOE_EXPERTS_TOUCHED_TOTAL: ("counter", ()),
-    EngineMetric.STATE_TOKENS_TOTAL: ("counter", ()),
-    EngineMetric.STATE_RESETS_TOTAL: ("counter", ()),
-    EngineMetric.STATE_POSITION_MISMATCHES_TOTAL: ("counter", ()),
-    EngineMetric.MESH_TP: ("gauge", ()),
-    EngineMetric.MESH_DEVICES: ("gauge", ()),
-    EngineMetric.LOOP_TOKENS_TOTAL: ("counter", ()),
-    EngineMetric.LOOP_PASSES_TOTAL: ("counter", ()),
-    EngineMetric.DECODE_KV_BLOCKS_WALKED_TOTAL: ("counter", ()),
-    EngineMetric.DECODE_KV_BLOCKS_GROUP_BOUND_TOTAL: ("counter", ()),
-    EngineMetric.CACHE_LAYERS: ("gauge", ()),
-    EngineMetric.KV_BYTES_PER_TOKEN: ("gauge", ()),
-    EngineMetric.STATE_LAYERS: ("gauge", ()),
-    EngineMetric.STATE_BYTES_PER_SLOT: ("gauge", ()),
-    EngineMetric.PREFIX_REUSE: ("gauge", ()),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.BYTES_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.SECONDS_TOTAL: ("counter", ("src", "dst", "path")),
@@ -326,6 +427,7 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     RouterMetric.KV_CACHE_USAGE: ("gauge", ("worker",)),
     RouterMetric.ROUTING_DECISIONS_TOTAL: ("counter", ("worker",)),
     RouterMetric.KV_HIT_RATE_PERCENT: ("gauge", ("worker",)),
+    **{e.name: (e.kind, ()) for e in ENGINE_COUNTS if e.name},
 }
 
 

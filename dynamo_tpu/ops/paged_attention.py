@@ -244,16 +244,11 @@ def paged_attention_layer(
         )
 
         if s == 1:
-            # tuning knobs for on-chip sweeps (benchmarks/profile_decode.py):
-            # group size trades per-grid-step fixed cost against VMEM.
-            # Unset, the kernel takes the tiling its geometry allows
+            # the kernel takes the tiling its geometry allows
             # (registry.decode_tiling: 8 rows a group, ~512 KiB a row-chunk)
-            spg = int(os.environ.get("DYNAMO_DECODE_SEQS_PER_GROUP", 0)) or None
-            bpc = int(os.environ.get("DYNAMO_DECODE_BLOCKS_PER_CHUNK", 0)) or None
             kernel = functools.partial(
                 paged_decode_attention, sm_scale=sm_scale,
-                logit_cap=logit_cap, seqs_per_group=spg,
-                blocks_per_chunk=bpc)
+                logit_cap=logit_cap)
             out = _per_kv_head(
                 kernel, tp, (_HEADS3, _CACHE, _REPL, _REPL, _REPL), _HEADS3,
             )(q[:, 0], cache, layer, block_tables, seq_lens)

@@ -58,24 +58,23 @@ __all__ = ["grouped_matmul_impl", "grouped_matmul_plan",
 
 
 def grouped_matmul_impl(m: int, groups: int, k: int, n: int, x_dtype,
-                        w_dtype) -> str:
-    """``"pallas"`` or ``"xla"`` for a grouped matmul of ``m`` sorted rows
-    over ``groups`` experts the router chooses among — a static function of
-    the environment, the backend, the mesh the caller traces under and the
-    shapes, asked before tracing.  The kernel where weights are the bound
-    (``m / groups`` rows an expert, up to one row tile:
-    ``GROUPED_MATMUL_MAX_ROWS_PER_GROUP``); ``lax.ragged_dot`` above it,
+                        w_dtype) -> bool:
+    """Whether a grouped matmul of ``m`` sorted rows over ``groups`` experts
+    the router chooses among is the Pallas kernel (else ``lax.ragged_dot``,
+    XLA's) — a static function of the environment, the backend, the mesh the
+    caller traces under and the shapes, asked before tracing.  The kernel
+    where weights are the bound (``m / groups`` rows an expert, up to one row
+    tile: ``GROUPED_MATMUL_MAX_ROWS_PER_GROUP``); ``lax.ragged_dot`` above it,
     off the TPU, for other than bf16 rows and weights of whole lanes, and
     under a mesh, where GSPMD partitions it on F."""
     mesh = jax.sharding.get_abstract_mesh()
     bf16 = jnp.dtype(jnp.bfloat16)
-    fits = (not os.environ.get("DYNAMO_DISABLE_PALLAS")
+    return (not os.environ.get("DYNAMO_DISABLE_PALLAS")
             and jax.default_backend() == "tpu"
             and (mesh.empty or mesh.size == 1)
             and jnp.dtype(x_dtype) == jnp.dtype(w_dtype) == bf16
             and k % 128 == 0 and n % 128 == 0
             and m <= groups * GROUPED_MATMUL_MAX_ROWS_PER_GROUP)
-    return "pallas" if fits else "xla"
 
 
 @functools.partial(jax.jit, static_argnames=("m", "tm"))

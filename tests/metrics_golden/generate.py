@@ -55,24 +55,18 @@ class _Clock:
 def reset_producers() -> None:
     """Reset every process-global producer the HTTP render reads (the
     same singletons the tier-1 tests isolate against)."""
-    from dynamo_tpu.engine.counters import (cache_shape, counters,
-                                            kv_shard_counters,
+    from dynamo_tpu.engine import counters as engine_counters
+    from dynamo_tpu.engine.counters import (kv_shard_counters,
                                             kv_stream_counters,
-                                            mesh_shape,
-                                            persist_counters,
-                                            request_counters)
+                                            persist_counters)
     from dynamo_tpu.fault.counters import counters as fault_counters
     from dynamo_tpu.obs.costs import transfer_costs
     from dynamo_tpu.obs.perfmodel import perf_model
     from dynamo_tpu.obs.timeline import step_timeline
 
-    for c in (counters, persist_counters, kv_stream_counters,
-              kv_shard_counters, request_counters,
-              fault_counters, transfer_costs, perf_model):
+    for c in (engine_counters, persist_counters, kv_stream_counters,
+              kv_shard_counters, fault_counters, transfer_costs, perf_model):
         c.reset()
-    mesh_shape.update(tp=1, devices=1)
-    cache_shape.update(layers=0, bytes_per_token=0, state_layers=0,
-                       state_bytes_per_slot=0, prefix_reuse=1)
     step_timeline.reset()
     step_timeline._clock = time.perf_counter
 
@@ -80,12 +74,9 @@ def reset_producers() -> None:
 def seed_http_metrics():
     """Fixed recording across every producer family; returns the
     seeded ``Metrics`` instance (render via ``render_http``)."""
-    from dynamo_tpu.engine.counters import (cache_shape, counters,
-                                            kv_shard_counters,
+    from dynamo_tpu.engine.counters import (EngineCounts, kv_shard_counters,
                                             kv_stream_counters,
-                                            mesh_shape,
-                                            persist_counters,
-                                            request_counters)
+                                            persist_counters, track_engine)
     from dynamo_tpu.fault.counters import counters as fault_counters
     from dynamo_tpu.llm.http.metrics import Metrics
     from dynamo_tpu.obs.costs import transfer_costs
@@ -115,32 +106,55 @@ def seed_http_metrics():
     fault_counters.drains_in_progress = 1
     fault_counters.register_suspect_source(lambda: (7,))
 
-    counters.record(4, 96, budget=128)
-    counters.record(2, 64, budget=128)
-    counters.record_unified(6, 90, 128)
-    counters.record_ready(3)
-    counters.record_ready(1)
-    request_counters.record_decode(12)
-    request_counters.record_decode(11)
-    request_counters.record_finish()
-    request_counters.record_finish()
-    request_counters.record_cut_short()
-    request_counters.record_first_token(0.125, 0.0625, 0.03125)
-    for _ in range(5):
-        request_counters.record_ahead()
-    request_counters.record_ahead_discard()
-    request_counters.record_drain()
-    request_counters.record_drain()
-    request_counters.record_operands(440)
-    request_counters.record_prompt(1000, 768)
-    request_counters.record_sparse_decode(48000, 4096)
-    request_counters.record_experts(4608, 576, 36, 540)
-    mesh_shape.update(tp=4, devices=4)
-    request_counters.record_loop(300, 1200)
-    request_counters.record_decode_blocks(2400, 4096)
-    cache_shape.update(layers=192, bytes_per_token=1572864, state_layers=6,
-                       state_bytes_per_slot=26050560, prefix_reuse=0)
-    request_counters.record_state(7200, 12, 0)
+    # what an engine counted: a store as an ``EngineCore`` builds one, the
+    # render's to read for as long as ``m`` lives
+    ec = EngineCounts()
+    track_engine(m, ec)
+    # two batched prefills (4 rows, 96 tokens; 2 rows, 64) and one unified
+    # dispatch (6 decode rows + 90 prefill tokens), each under a budget of
+    # 128; 3 then 1 requests stood ready
+    ec.prefill_dispatches_total = 2
+    ec.prefill_rows_dispatched = 4 + 2
+    ec.prefill_tokens_total = ec.prefill_budget_used = 96 + 64
+    ec.prefill_budget_offered = 2 * 128
+    ec.prefill_ready_rows_total = 3 + 1
+    ec.unified_dispatches_total = 1
+    ec.unified_decode_rows = 6
+    ec.unified_prefill_tokens = 90
+    ec.unified_budget_offered = 128
+    ec.unified_budget_used = 6 + 90
+    ec.decode_dispatches_total = 2
+    ec.decode_rows_dispatched_total = 12 + 11
+    ec.requests_finished_total = 2
+    ec.requests_cut_short_total = 1
+    ec.first_tokens_total = 1
+    ec.first_token_seconds_total = 0.125
+    ec.turn_wait_seconds_total = 0.0625
+    ec.prefill_span_seconds_total = 0.03125
+    ec.ahead_dispatches_total = 5
+    ec.ahead_discards_total = 1
+    ec.pipeline_drains_total = 2
+    ec.operand_buffers_total = 440
+    ec.prompt_tokens_admitted_total = 1000
+    ec.prompt_tokens_cached_total = 768
+    ec.attn_context_tokens_total = 48000
+    ec.attn_selected_tokens_total = 4096
+    ec.moe_router_picks_total = 4608
+    ec.moe_held_picks_total = 576
+    ec.moe_expert_layer_calls_total = 36
+    ec.moe_experts_touched_total = 540
+    ec.state_tokens_total = 7200
+    ec.state_resets_total = 12
+    ec.mesh_tp = ec.mesh_devices = 4
+    ec.loop_tokens_total = 300
+    ec.loop_passes_total = 1200
+    ec.decode_kv_blocks_walked_total = 2400
+    ec.decode_kv_blocks_group_bound_total = 4096
+    ec.cache_layers = 192
+    ec.kv_bytes_per_token = 1572864
+    ec.state_layers = 6
+    ec.state_bytes_per_slot = 26050560
+    ec.prefix_reuse = 0
     persist_counters.record_restore(2, 32)
     persist_counters.record_miss()
     persist_counters.record_spill(4096)

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine import AsyncLLMEngine, EngineConfig, EngineCore
-from dynamo_tpu.engine.counters import request_counters
+from dynamo_tpu.engine import counters as engine_counters
 from dynamo_tpu.engine.grammar import JsonGrammar
 from dynamo_tpu.engine.request import EngineRequest, RequestState
 from dynamo_tpu.llm.http.metrics import Metrics
@@ -503,7 +503,7 @@ def test_an_operation_of_another_thread_sees_a_quiescent_engine(tiny):
 
 # ------------------------------------ the counters, where an operator looks
 def test_the_counters_are_on_metrics_and_on_the_http_render(tiny):
-    request_counters.reset()
+    engine_counters.reset()
     core = make_core(tiny)
     submit(core, "a", prompt(8, 0), max_tokens=9, stop_token_ids=[])
     run(core)

@@ -140,21 +140,21 @@ def test_the_rule_takes_the_kernel_only_where_weights_bound(monkeypatch):
     bf16 = jnp.bfloat16
     rule = lambda m, e, x=bf16, w=bf16: gmm.grouped_matmul_impl(
         m, e, 2048, 768, x, w)
-    assert rule(256, 128) == "xla"                  # the CPU
+    assert not rule(256, 128)                       # the CPU
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cap = reg.GROUPED_MATMUL_MAX_ROWS_PER_GROUP
-    assert rule(256, 128) == "pallas"               # a decode step
-    assert rule(128 * cap, 128) == "pallas"
-    assert rule(128 * cap + 1, 128) == "xla"        # rows enough for XLA's
-    assert rule(256, 128, jnp.float32, jnp.float32) == "xla"
-    assert gmm.grouped_matmul_impl(256, 128, 2048, 96, bf16, bf16) == "xla"
+    assert rule(256, 128) is True                   # a decode step
+    assert rule(128 * cap, 128)
+    assert not rule(128 * cap + 1, 128)             # rows enough for XLA's
+    assert not rule(256, 128, jnp.float32, jnp.float32)
+    assert not gmm.grouped_matmul_impl(256, 128, 2048, 96, bf16, bf16)
     monkeypatch.setenv("DYNAMO_DISABLE_PALLAS", "1")
-    assert rule(256, 128) == "xla"
+    assert not rule(256, 128)
     monkeypatch.delenv("DYNAMO_DISABLE_PALLAS")
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]).reshape(1, 2),
                              ("data", "model"))
     with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-        assert rule(256, 128) == "xla"              # GSPMD's ragged_dot
+        assert not rule(256, 128)                   # GSPMD's ragged_dot
 
 
 def test_dispatch_through_the_kernel_is_the_dispatch_through_ragged_dot(
@@ -181,7 +181,7 @@ def test_dispatch_through_the_kernel_is_the_dispatch_through_ragged_dot(
             layer=jnp.int32(2), held=(4, held)), np.float32)
 
     want = dispatch()
-    monkeypatch.setattr(gmm, "grouped_matmul_impl", lambda *a: "pallas")
+    monkeypatch.setattr(gmm, "grouped_matmul_impl", lambda *a: True)
     kernel = gmm.grouped_expert_matmul
     monkeypatch.setattr(gmm, "grouped_expert_matmul",
                         lambda *a, **kw: kernel(*a, interpret=True, **kw))
@@ -242,7 +242,7 @@ def test_experts_touched_counts_the_held_experts_with_a_row(monkeypatch):
 
 def test_experts_touched_reaches_metrics_and_the_exposition():
     from dynamo_tpu.engine import EngineConfig, EngineCore
-    from dynamo_tpu.engine.counters import request_counters
+    from dynamo_tpu.engine.counters import engine_totals
     from dynamo_tpu.engine.request import EngineRequest
     from dynamo_tpu.llm.http.metrics import Metrics
     from dynamo_tpu.llm.protocols import SamplingOptions, StopConditions
@@ -253,7 +253,7 @@ def test_experts_touched_reaches_metrics_and_the_exposition():
     core = EngineCore(model, params, EngineConfig(
         max_batch_size=4, max_model_len=128, block_size=BS, num_blocks=NB,
         prefill_chunk_tokens=32), eos_token_ids=[])
-    before = request_counters.moe_experts_touched_total
+    before = engine_totals().moe_experts_touched_total
     core.submit(EngineRequest(
         request_id="r", prompt=[int(t) for t in tokens_of(20, seed=2)],
         sampling=SamplingOptions(temperature=0.0),
@@ -266,6 +266,6 @@ def test_experts_touched_reaches_metrics_and_the_exposition():
     assert m["moe_experts_touched_total"] == on_device > 0
     # at most the 2 held experts a layer and call
     assert m["moe_experts_touched_total"] <= 2 * m["moe_expert_layer_calls_total"]
-    assert (request_counters.moe_experts_touched_total - before
+    assert (engine_totals().moe_experts_touched_total - before
             == m["moe_experts_touched_total"])
     assert f"{EM.MOE_EXPERTS_TOUCHED_TOTAL} " in Metrics().render()

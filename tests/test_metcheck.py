@@ -247,6 +247,29 @@ def test_mt005_docs_table_cross_check():
     assert docs_keys(stale) == {"docs-table"}
 
 
+def test_mt005_docs_counts_listing_cross_check(real):
+    """The listing of what an engine counts is generated from the
+    registry's table: a stale one between its markers is census drift,
+    the committed one is clean."""
+    from dynamo_tpu.analysis.metcheck import COUNTS_BEGIN, COUNTS_END
+    from dynamo_tpu.obs.metric_names import ENGINE_COUNTS
+
+    facts, _, docs_text, _ = real
+    listing = facts["engine"]["listing"]
+    assert all(f"`{e.name}`" in listing for e in ENGINE_COUNTS if e.name)
+    head, rest = docs_text.split(COUNTS_BEGIN, 1)
+    stale = head + COUNTS_BEGIN + "\n| gone |\n" + COUNTS_END \
+        + rest.split(COUNTS_END, 1)[1]
+
+    def docs_keys(text):
+        return {f.key for f in check_metric_facts(
+            facts, Manifest(entrypoints=census_snapshot(facts)), [],
+            docs_text=text) if f.rule == "MT005"}
+
+    assert docs_keys(docs_text) == set()
+    assert docs_keys(stale) == {"docs-counts"}
+
+
 def test_rule_table_complete():
     assert sorted(MET_RULES) == [f"MT00{i}" for i in range(1, 6)]
 

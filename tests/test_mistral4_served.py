@@ -119,5 +119,5 @@ def test_block_movers_are_refused_for_a_cache_without_an_indexer_too():
         core.gather_blocks_np([1])
     # one row a token and layer, padded to whole lane groups (48 -> 128
     # float32 elements here); the three counts a layer are not the cache's
-    assert core.kv_bytes_per_token == 3 * 128 * 4
-    assert core.cache_layers == 3
+    assert core.counts.kv_bytes_per_token == 3 * 128 * 4
+    assert core.counts.cache_layers == 3

@@ -17,7 +17,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from dynamo_tpu.engine import EngineConfig, EngineCore, operands
-from dynamo_tpu.engine.counters import request_counters
+from dynamo_tpu.engine import counters as engine_counters
 from dynamo_tpu.engine.grammar import JsonGrammar
 from dynamo_tpu.engine.request import EngineRequest
 from dynamo_tpu.llm.http.metrics import Metrics
@@ -182,7 +182,7 @@ def test_the_key_sequence_is_one_split_a_dispatch(tiny, tp, case, request):
 def test_operand_buffers_are_counted_on_metrics_and_on_the_http_render(
         tiny, tp, request):
     where = request.getfixturevalue("mesh") if tp > 1 else None
-    request_counters.reset()
+    engine_counters.reset()
     core = make_core(tiny, where)
     core.submit(EngineRequest(
         "a", prompt(8, 0), SamplingOptions(temperature=0.0),
@@ -208,7 +208,7 @@ def test_decode_kv_blocks_are_counted_on_metrics_and_on_the_http_render(
     the flash-decode kernel fetches a layer) beside what the same dispatch
     fetched when every slot went up to its group's longest row."""
     where = request.getfixturevalue("mesh") if tp > 1 else None
-    request_counters.reset()
+    engine_counters.reset()
     core = make_core(tiny, where)
     calls = watch(core, "_multi_fn")
     for i, n in enumerate((8, 40)):       # two rows of unlike length, 4 slots

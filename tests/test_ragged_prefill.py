@@ -223,12 +223,12 @@ def test_budget_utilization_metric(setup):
 
 def test_prefill_gauges_on_http_metrics(setup):
     """The batching gauges ride /metrics next to the fault counters."""
-    from dynamo_tpu.engine.counters import counters as prefill_counters
+    from dynamo_tpu.engine import counters as engine_counters
     from dynamo_tpu.llm.http.metrics import Metrics
     from dynamo_tpu.obs.metric_names import EngineMetric as EM
 
     model, params, _ = setup
-    prefill_counters.reset()
+    engine_counters.reset()
     rng = np.random.RandomState(5)
     specs = [
         (f"r{i}", [int(x) for x in rng.randint(3, 259, size=16)],

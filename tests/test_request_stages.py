@@ -16,8 +16,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine import EngineConfig, EngineCore
-from dynamo_tpu.engine.counters import counters as prefill_counters
-from dynamo_tpu.engine.counters import request_counters
+from dynamo_tpu.engine import counters as engine_counters
 from dynamo_tpu.engine.request import EngineRequest, RequestState
 from dynamo_tpu.llm.protocols import (LLMEngineOutput, SamplingOptions,
                                       StopConditions)
@@ -75,14 +74,14 @@ def run_dry(core, limit=400):
 def stages(tiny):
     """Two 40-token prompts admitted in one turn and prefilled in chunks of
     16, then the first prompt again (a prefix hit of four blocks)."""
-    request_counters.reset()
-    prefill_counters.reset()
+    engine_counters.reset()
     step_timeline.reset()
     core = make_core(*tiny)
     a, _ = submit(core, "a", 40, 5, seed=1)
     b, _ = submit(core, "b", 40, 5, seed=2)
     core.step()             # admits both: two stand ready, a's chunk goes
-    first = (core.prefill_ready_rows, core.prefill_dispatches)
+    first = (core.counts.prefill_ready_rows_total,
+             core.counts.prefill_dispatches_total)
     run_dry(core)
     c, outs = submit(core, "c", 40, 4, seed=1)
     run_dry(core)
@@ -116,11 +115,11 @@ def test_the_three_stages_add_up_to_the_engine_ttft(stages):
     assert sum(r.queue_wait_s for r in reqs) + m["turn_wait_seconds_total"] \
         + m["prefill_span_seconds_total"] == pytest.approx(
             m["first_token_seconds_total"], rel=1e-9)
-    # the process-global mirror /metrics renders
-    assert request_counters.turn_wait_seconds_total == pytest.approx(
-        m["turn_wait_seconds_total"])
-    assert request_counters.prefill_span_seconds_total == pytest.approx(
-        m["prefill_span_seconds_total"])
+    # what /metrics renders: the process's engines summed, here this one
+    totals = engine_counters.engine_totals()
+    assert totals.turn_wait_seconds_total == m["turn_wait_seconds_total"]
+    assert totals.prefill_span_seconds_total \
+        == m["prefill_span_seconds_total"]
 
 
 def test_the_prefill_backlog_is_counted_at_the_dispatch(stages):
@@ -130,8 +129,9 @@ def test_the_prefill_backlog_is_counted_at_the_dispatch(stages):
     # a's three chunks with b ready behind them, b's three alone, c's one
     assert m["prefill_dispatches_total"] == 7
     assert m["prefill_ready_rows_total"] == 3 * 2 + 3 * 1 + 1
-    assert prefill_counters.ready_rows_total == m["prefill_ready_rows_total"]
-    assert prefill_counters.dispatches_total == m["prefill_dispatches_total"]
+    totals = engine_counters.engine_totals()
+    assert totals.prefill_ready_rows_total == m["prefill_ready_rows_total"]
+    assert totals.prefill_dispatches_total == m["prefill_dispatches_total"]
 
 
 def test_every_emitted_output_carries_its_dispatch_s_clock_read(stages):

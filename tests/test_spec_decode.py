@@ -112,12 +112,12 @@ def test_spec_accepts_on_cyclic_model():
     spec = EngineCore(model, params, _cfg(spec_tokens=4), eos_token_ids=[])
     got = _run(spec, prompt, 24, "spec")
     assert got == want  # greedy-exact
-    assert spec.spec_steps > 0
-    assert spec.spec_accepted > 0
+    assert spec.counts.spec_steps > 0
+    assert spec.counts.spec_accepted > 0
     # perfect proposals: ~5 tokens per dispatch vs 1 for the base engine
     assert spec.decode_steps < base.decode_steps / 2
-    accept_rate = spec.spec_accepted / max(spec.spec_proposed, 1)
-    assert accept_rate > 0.9, (spec.spec_accepted, spec.spec_proposed)
+    accept_rate = spec.counts.spec_accepted / max(spec.counts.spec_proposed, 1)
+    assert accept_rate > 0.9, (spec.counts.spec_accepted, spec.counts.spec_proposed)
 
 
 def test_spec_greedy_exact_on_real_model():
@@ -133,7 +133,7 @@ def test_spec_greedy_exact_on_real_model():
     spec = EngineCore(model, params, _cfg(spec_tokens=3), eos_token_ids=[])
     got = _run(spec, prompt, 20, "s")
     assert got == want
-    assert spec.spec_steps > 0  # proposals were attempted
+    assert spec.counts.spec_steps > 0  # proposals were attempted
 
 
 def test_spec_defers_to_sampler_features():
@@ -155,7 +155,7 @@ def test_spec_defers_to_sampler_features():
         if not core.step():
             break
     assert sum(len(o.token_ids) for o in outs) == 8
-    assert core.spec_steps == 0
+    assert core.counts.spec_steps == 0
 
 
 def test_spec_accepts_under_temperature():
@@ -179,8 +179,8 @@ def test_spec_accepts_under_temperature():
     assert len(got) == 16
     # positions 8.. continue the cycle deterministically at scale 25
     assert got == [CYCLE[(8 + j) % 4] for j in range(16)]
-    assert core.spec_steps > 0
-    assert core.spec_accepted > 0
+    assert core.counts.spec_steps > 0
+    assert core.counts.spec_accepted > 0
 
 
 @pytest.mark.parametrize("scale", [1.0, 25.0])
@@ -213,7 +213,7 @@ def test_spec_seeded_stream_identical(scale):
     spec, core = run(4, "on")
     assert len(base) == 24
     assert spec == base
-    assert core.spec_steps > 0
+    assert core.counts.spec_steps > 0
 
 
 def test_spec_respects_block_limits():
@@ -283,9 +283,9 @@ def test_spec_skips_batch_with_low_proposal_coverage(monkeypatch):
         return core
 
     # 1 proposing row of 4: the gate keeps the burst path
-    assert run(marked_rows=1).spec_steps == 0
+    assert run(marked_rows=1).counts.spec_steps == 0
     # 3 proposing rows of 4: speculation engages
-    assert run(marked_rows=3).spec_steps > 0
+    assert run(marked_rows=3).counts.spec_steps > 0
 
 
 # ------------------------------------------------------ draft-model spec ----
@@ -319,9 +319,9 @@ def test_draft_model_identical_to_target_accepts_everything():
     got = _drain_engine(spec, prompt, 24, "s", temperature=0.0)
     assert got == want
     assert spec.draft is not None and spec.draft.dispatches > 0
-    assert spec.spec_steps > 0
-    accept = spec.spec_accepted / max(spec.spec_proposed, 1)
-    assert accept > 0.9, (spec.spec_accepted, spec.spec_proposed)
+    assert spec.counts.spec_steps > 0
+    accept = spec.counts.spec_accepted / max(spec.counts.spec_proposed, 1)
+    assert accept > 0.9, (spec.counts.spec_accepted, spec.counts.spec_proposed)
     # dispatch win: ~24/(k+1) verify steps instead of 24 decode steps
     assert spec.decode_steps < base.decode_steps / 2
 
@@ -343,7 +343,7 @@ def test_draft_model_different_weights_still_exact():
                           eos_token_ids=[], draft=(model, draft_params))
         got = _drain_engine(spec, prompt, 16, "s", **samp)
         assert got == want, samp
-        assert spec.spec_steps > 0
+        assert spec.counts.spec_steps > 0
 
 
 def test_draft_blocks_released_on_finish():
@@ -406,7 +406,7 @@ def test_draft_long_prompt_catches_up_across_steps():
                       draft=(model, params))
     got = _drain_engine(spec, prompt, 10, "s", temperature=0.0)
     assert got == want
-    assert spec.spec_steps > 0
+    assert spec.counts.spec_steps > 0
 
 
 def test_draft_model_with_int8_caches_still_exact():
@@ -431,4 +431,4 @@ def test_draft_model_with_int8_caches_still_exact():
         assert is_quant(spec.cache) and is_quant(spec.draft.cache)
         got = _drain_engine(spec, prompt, 16, "s", **samp)
         assert got == want, samp
-        assert spec.spec_steps > 0
+        assert spec.counts.spec_steps > 0

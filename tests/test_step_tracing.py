@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine import EngineConfig, EngineCore
-from dynamo_tpu.engine.counters import request_counters
+from dynamo_tpu.engine import counters as engine_counters
 from dynamo_tpu.engine.request import EngineRequest
 from dynamo_tpu.llm.protocols import (FinishReason, SamplingOptions,
                                       StopConditions)
@@ -345,7 +345,7 @@ def test_profiled_dispatch_events_say_what_the_dispatch_carried(tiny, tmp_path):
 def test_counters_cut_short_is_not_max_tokens(tiny):
     """A cache made to run out: the request that loses its block space is
     counted cut short; the one that stops at max_tokens is not."""
-    request_counters.reset()
+    engine_counters.reset()
     core = make_core(*tiny, num_blocks=6, max_batch_size=2)
     # 8-token blocks: 17-token prompts take 3 blocks each, all 6; the first
     # to need a 4th block (at 24 tokens) finds none
@@ -366,11 +366,11 @@ def test_counters_cut_short_is_not_max_tokens(tiny):
     rows = m["decode_rows_dispatched_total"] / m["decode_dispatches_total"]
     assert 1.0 < rows < 2.0
     assert all(isinstance(m[k], (int, float)) for k in m)
-    # the process-global mirror /metrics renders
-    assert request_counters.requests_cut_short_total == 1
-    assert request_counters.requests_finished_total == 2
-    assert request_counters.decode_dispatches_total \
-        == m["decode_dispatches_total"]
+    # what /metrics renders: the process's engines summed, here this one
+    totals = engine_counters.engine_totals()
+    assert totals.requests_cut_short_total == 1
+    assert totals.requests_finished_total == 2
+    assert totals.decode_dispatches_total == m["decode_dispatches_total"]
 
     # too long for the model is LENGTH too, and is not "cut short"
     core = make_core(*tiny)

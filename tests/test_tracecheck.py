@@ -466,8 +466,8 @@ def test_seeded_run_ragged_and_spec_once():
     # verify path engages deterministically
     prompts = [list(range(1, 11)), list(range(5, 16))]
     _drive(core, prompts, max_tokens=6)
-    assert core.prefill_dispatches >= 1
-    assert core.spec_steps >= 1, "spec verify never engaged"
+    assert core.counts.prefill_dispatches_total >= 1
+    assert core.counts.spec_steps >= 1, "spec verify never engaged"
     assert core._ragged_fn._cache_size() == 1
     assert core._spec_fn._cache_size() == 1
     assert core._step_fn._cache_size() == 0  # batching replaced it
@@ -513,7 +513,7 @@ def test_seeded_run_unified_once():
         enable_prefix_reuse=False,
     ))
     drive(core)
-    assert core.unified_dispatches >= 1
+    assert core.counts.unified_dispatches_total >= 1
     assert core._unified_fn._cache_size() == 1
 
     compile_events = []

@@ -123,7 +123,7 @@ def test_mixed_workload_parity(setup):
     legacy = make_core(model, params, grammar, prefill_chunk_tokens=16,
                        prefill_token_budget=64)
     ref = run_staggered(legacy, specs)
-    assert legacy.unified_dispatches == 0
+    assert legacy.counts.unified_dispatches_total == 0
 
     uni_core = make_core(model, params, grammar, prefill_chunk_tokens=16,
                          prefill_token_budget=64,
@@ -131,9 +131,9 @@ def test_mixed_workload_parity(setup):
     uni = run_staggered(uni_core, specs)
     # the mixed path actually engaged, and each engagement packed decode
     # rows AND prefill tokens onto one axis
-    assert uni_core.unified_dispatches > 0
-    assert uni_core.unified_decode_rows > 0
-    assert uni_core.unified_prefill_tokens > 0
+    assert uni_core.counts.unified_dispatches_total > 0
+    assert uni_core.counts.unified_decode_rows > 0
+    assert uni_core.counts.unified_prefill_tokens > 0
 
     assert_stream_parity(specs, ref, uni)
     # logprob parity on the top_logprobs request (ids exact, values tight)
@@ -172,7 +172,7 @@ def test_prefill_only_and_decode_only_parity(setup):
         got = run_staggered(uni_core, specs, head=len(specs), stagger=0)
         assert_stream_parity(specs, ref, got)
         # no mixed turns existed, so the unified impl never dispatched
-        assert uni_core.unified_dispatches == 0
+        assert uni_core.counts.unified_dispatches_total == 0
         assert uni_core._unified_fn._cache_size() == 0
 
 
@@ -206,7 +206,7 @@ def test_mixed_turn_is_one_dispatch(setup):
         assert core.steps == steps_before + 1          # ONE jitted call
         assert deco.generated == gen_before + 1        # decode advanced
         assert pref.computed_tokens > computed_before  # prefill advanced
-    assert core.unified_dispatches >= 3  # 48 tokens / 16-token chunks
+    assert core.counts.unified_dispatches_total >= 3  # 48 tokens / 16-token chunks
 
     # the legacy interleave pays 2 dispatches per (chunk, burst) pair on
     # the identical scenario — strictly more total dispatches
@@ -225,7 +225,7 @@ def test_mixed_turn_is_one_dispatch(setup):
     steps0 = legacy.steps
     while pref2.computed_tokens < pref2.prompt_len:
         legacy.step()
-    assert legacy.steps - steps0 > core.unified_dispatches
+    assert legacy.steps - steps0 > core.counts.unified_dispatches_total
 
 
 def test_join_under_batching_unified(setup):
@@ -247,7 +247,7 @@ def test_join_under_batching_unified(setup):
     core = make_core(model, params, prefill_token_budget=128,
                      unified_token_dispatch=True)
     outs = run_staggered(core, specs, head=1, stagger=3)
-    assert core.unified_dispatches > 0
+    assert core.counts.unified_dispatches_total > 0
     assert flat(outs["a"]) == flat(outs["b"])
     # owner computed 41 tokens; the joiner only its uncovered tail (the
     # final partial block) — plus the decoy's 8-token prompt
@@ -288,7 +288,7 @@ def test_mid_batch_abort_of_prefill_row(setup):
         return core, outs
 
     core, outs = run(abort_victim=True)
-    assert core.unified_dispatches > 0
+    assert core.counts.unified_dispatches_total > 0
     from dynamo_tpu.llm.protocols import FinishReason
 
     assert outs["victim"][-1].finish_reason == FinishReason.CANCELLED
@@ -316,7 +316,7 @@ def test_unified_int8_cache_parity(setup):
                          prefill_token_budget=64, cache_dtype="int8",
                          unified_token_dispatch=True)
     got = run_staggered(uni_core, specs, head=1, stagger=3)
-    assert uni_core.unified_dispatches > 0
+    assert uni_core.counts.unified_dispatches_total > 0
     assert_stream_parity(specs, ref, got)
 
 
@@ -370,12 +370,12 @@ def test_mixed_kernel_cpu_oracle():
 
 def test_unified_gauges_on_http_metrics(setup):
     """The unified counters ride /metrics next to the prefill gauges."""
-    from dynamo_tpu.engine.counters import counters as prefill_counters
+    from dynamo_tpu.engine import counters as engine_counters
     from dynamo_tpu.llm.http.metrics import Metrics
     from dynamo_tpu.obs.metric_names import EngineMetric as EM
 
     model, params, _ = setup
-    prefill_counters.reset()
+    engine_counters.reset()
     rng = np.random.RandomState(6)
     specs = [
         ("deco", [int(x) for x in rng.randint(3, 259, size=8)],
@@ -386,12 +386,12 @@ def test_unified_gauges_on_http_metrics(setup):
     core = make_core(model, params, prefill_token_budget=32,
                      unified_token_dispatch=True)
     run_staggered(core, specs, head=1, stagger=3)
-    assert core.unified_dispatches > 0
+    assert core.counts.unified_dispatches_total > 0
     text = Metrics().render()
     assert (f"{EM.UNIFIED_DISPATCHES_TOTAL} "
-            f"{core.unified_dispatches}") in text
+            f"{core.counts.unified_dispatches_total}") in text
     assert (f"{EM.UNIFIED_DECODE_ROWS_TOTAL} "
-            f"{core.unified_decode_rows}") in text
+            f"{core.counts.unified_decode_rows}") in text
     assert (f"{EM.UNIFIED_PREFILL_TOKENS_TOTAL} "
-            f"{core.unified_prefill_tokens}") in text
+            f"{core.counts.unified_prefill_tokens}") in text
     assert f"{EM.UNIFIED_BUDGET_UTILIZATION} " in text
