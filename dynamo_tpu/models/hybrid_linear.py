@@ -1,22 +1,30 @@
 """A decoder whose layers are of two kinds: most carry a recurrent state of
-fixed size (the gated delta rule with a decay per key channel, behind a short
-causal convolution: ops/linear_state.py), every few attend over the paged
-K/V cache (grouped-query attention with no positional term and an output
-gate).  Every layer ends in routed experts, of which this chip holds a share,
-plus a shared expert.  ``solar_open2`` is the published config read here;
-docs/linear_state.md has the equations.
+fixed size behind a short causal convolution, every few attend over the paged
+K/V cache (grouped-query attention with no positional term).  Every layer
+ends in routed experts, of which this chip holds a share, plus a shared
+expert.  The recurrence is the configuration's (``recurrence``): the gated
+delta rule with a decay per key channel (``solar_open2``,
+ops/linear_state.py) or the state-space recurrence with a scalar decay a head
+(``granitemoehybrid``'s Mamba-2 layers, ops/ssm_state.py).
+docs/linear_state.md has the equations of both.
 
-Per layer (pre-norm residual blocks):
+Per layer (pre-norm residual blocks; a mixer's and an expert layer's output
+times ``residual_multiplier``):
 
-  * **Linear layer.**  q̂, k̂, v̂ = W x; a depth-wise convolution of
+  * **Delta-rule layer.**  q̂, k̂, v̂ = W x; a depth-wise convolution of
     ``short_conv_kernel_size`` over time on each, then SiLU; q and k L2-normed
     a head (q also by d^-1/2); decay g = -exp(A_log) · softplus(W_f↑ W_f↓ x +
     b_dt) a key channel, step beta = 2 · sigmoid(W_β x); the recurrence
     (``delta_rule_step`` for one token a row, ``delta_rule_scan`` for a
     prefill chunk); RMSNorm a head and a low-rank sigmoid gate; W_o.
-  * **GQA layer.**  softmax(q kᵀ d^-1/2) v through the K/V pool and the
+  * **State-space layer.**  z ‖ xBC ‖ dt = W_in x; one depth-wise convolution
+    with a bias over x‖B‖C, then SiLU; Δ = softplus(dt + b_dt), decay
+    exp(Δ·A) a head; the recurrence (``ssd_step`` / ``ssd_scan``); RMSNorm
+    over the whole width of y ⊙ SiLU(z); W_out.
+  * **GQA layer.**  softmax(q kᵀ · scale) v through the K/V pool and the
     Pallas kernels every dense model here uses (ops/paged_attention.py), no
-    rope, no q/k norm; o ⊙ sigmoid(W_gate x); W_o.
+    rope, no q/k norm; scale d^-1/2 or ``attention_multiplier``;
+    o ⊙ sigmoid(W_gate x) where the model has the gate; W_o.
   * **Experts**: as models/glm_dsa.py — the router scores all
     ``router_experts``, this chip computes the part its own give.
 
@@ -58,7 +66,7 @@ from dynamo_tpu.models.llama import (
     rms_norm,
     split_heads,
 )
-from dynamo_tpu.ops import linear_state
+from dynamo_tpu.ops import linear_state, ssm_state
 from dynamo_tpu.ops.pallas.linear_state import state_update
 from dynamo_tpu.ops.paged_attention import (
     paged_attention_layer,
@@ -113,6 +121,21 @@ class HybridLinearConfig:
     rms_norm_eps: float = 1e-5
     max_position_embeddings: int = 4096
     dtype: str = "bfloat16"
+    # what the layers that do not attend run: "delta" (the gated delta rule,
+    # a state of linear_head_dim x linear_head_dim a head) or "ssd" (the
+    # state-space recurrence, linear_head_dim x state_dim a head, B and C
+    # shared by the heads of one of ``ssm_groups``, chunks of ``ssm_chunk``)
+    recurrence: str = "delta"
+    state_dim: int = 0
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
+    gqa_gate: bool = True
+    attention_multiplier: float | None = None   # softmax scale; None: d^-1/2
+    shared_intermediate_size: int | None = None # None: n_shared x an expert's
+    tie_word_embeddings: bool = False
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     @property
     def jax_dtype(self):
@@ -123,18 +146,39 @@ class HybridLinearConfig:
         return self.num_layers - len(self.gqa_layers)
 
     @property
+    def ssm_width(self) -> int:
+        return self.linear_heads * self.linear_head_dim
+
+    @property
     def conv_width(self) -> int:
-        """q̂ ‖ k̂ ‖ v̂ of one token."""
+        """What the convolution runs over: q̂ ‖ k̂ ‖ v̂ of one token, or
+        x ‖ B ‖ C."""
+        if self.recurrence == "ssd":
+            return self.ssm_width + 2 * self.ssm_groups * self.state_dim
         return 3 * self.linear_heads * self.linear_head_dim
+
+    @property
+    def state_shape(self) -> tuple:
+        """One slot's state in one recurrent layer."""
+        d = self.linear_head_dim
+        return (self.linear_heads, d,
+                self.state_dim if self.recurrence == "ssd" else d)
+
+    @property
+    def shared_width(self) -> int:
+        return (self.shared_intermediate_size
+                or self.moe_intermediate_size * self.n_shared_experts)
 
     @classmethod
     def from_hf_config(cls, cfg: dict, dtype: str = "bfloat16"
                        ) -> "HybridLinearConfig":
-        """The published ``solar_open2`` keys -> HybridLinearConfig.  Raises,
-        by name, on what this port does not compute.  ``expert_parallel``
-        (not a published key) says which share of a layer's experts this
-        chip holds, as for models/glm_dsa.py."""
+        """The published ``solar_open2`` or ``granitemoehybrid`` keys ->
+        HybridLinearConfig.  Raises, by name, on what this port does not
+        compute.  ``expert_parallel`` (not a published key) says which share
+        of a layer's experts this chip holds, as for models/glm_dsa.py."""
         g = cfg.get
+        if g("model_type") == "granitemoehybrid":
+            return cls._from_granite(cfg, dtype)
         if g("model_type") != "solar_open2":
             raise NotImplementedError(f"model_type {g('model_type')!r}")
         lin = g("linear_attn_config") or {}
@@ -162,8 +206,6 @@ class HybridLinearConfig:
             raise NotImplementedError("use_gqa_gate=False")
         if int(g("first_k_dense_replace", 0)):
             raise NotImplementedError("first_k_dense_replace > 0")
-        if bool(g("tie_word_embeddings", False)):
-            raise NotImplementedError("tie_word_embeddings=True")
         if g("rope_scaling") is not None:
             raise NotImplementedError(f"rope_scaling {g('rope_scaling')!r}")
         if int(g("n_group", 1) or 1) != 1 or int(g("topk_group", 1) or 1) != 1:
@@ -172,14 +214,7 @@ class HybridLinearConfig:
             raise NotImplementedError(f"scoring_func {g('scoring_func')!r}")
         if g("topk_method", "noaux_tc") != "noaux_tc":
             raise NotImplementedError(f"topk_method {g('topk_method')!r}")
-        ep = g("expert_parallel") or {}
-        held = int(g("n_routed_experts"))
-        total = int(ep.get("router_experts", held))
-        first = int(ep.get("first_expert", 0))
-        if not 0 <= first <= total - held:
-            raise ValueError(
-                f"experts {first}..{first + held - 1} are not among the "
-                f"router's {total}")
+        held, total, first = _held_experts(cfg, "n_routed_experts")
         d = int(lin["head_dim"])
         return cls(
             vocab_size=int(g("vocab_size")), hidden_size=int(g("hidden_size")),
@@ -198,7 +233,87 @@ class HybridLinearConfig:
             rms_norm_eps=float(g("rms_norm_eps", 1e-5)),
             max_position_embeddings=int(g("max_position_embeddings", 4096)),
             dtype=dtype,
+            tie_word_embeddings=bool(g("tie_word_embeddings", False)),
         )
+
+    @classmethod
+    def _from_granite(cls, cfg: dict, dtype: str) -> "HybridLinearConfig":
+        """``granitemoehybrid``: ``layer_types`` says which layers attend,
+        ``mamba_*`` sizes the others, ``intermediate_size`` is one expert's
+        width and ``shared_intermediate_size`` the shared MLP's."""
+        g = cfg.get
+        n = int(g("num_hidden_layers"))
+        kinds = list(g("layer_types") or ())
+        if len(kinds) != n or set(kinds) - {"mamba", "attention"}:
+            raise NotImplementedError(
+                f"layer_types {kinds} over {n} layers (one of 'mamba' | "
+                "'attention' a layer)")
+        if g("position_embedding_type", "nope") != "nope":
+            raise NotImplementedError(
+                f"position_embedding_type {g('position_embedding_type')!r} "
+                "(rotary attention layers)")
+        for key in ("attention_bias", "mamba_proj_bias"):
+            if bool(g(key, False)):
+                raise NotImplementedError(f"{key}=True")
+        if not bool(g("mamba_conv_bias", True)):
+            raise NotImplementedError("mamba_conv_bias=False")
+        if g("hidden_act", "silu") != "silu":
+            raise NotImplementedError(f"hidden_act {g('hidden_act')!r}")
+        if g("normalization_function", "rmsnorm") != "rmsnorm":
+            raise NotImplementedError(
+                f"normalization_function {g('normalization_function')!r}")
+        dm = int(g("hidden_size"))
+        heads, p = int(g("mamba_n_heads")), int(g("mamba_d_head"))
+        if heads * p != int(g("mamba_expand")) * dm:
+            raise ValueError(
+                f"mamba_n_heads {heads} x mamba_d_head {p} is not "
+                f"mamba_expand {g('mamba_expand')} x hidden_size {dm}")
+        groups = int(g("mamba_n_groups", 1))
+        if heads % groups:
+            raise ValueError(f"{heads} heads in {groups} groups")
+        held, total, first = _held_experts(cfg, "num_local_experts")
+        hq = int(g("num_attention_heads"))
+        return cls(
+            vocab_size=int(g("vocab_size")), hidden_size=dm, num_layers=n,
+            num_heads=hq, num_kv_heads=int(g("num_key_value_heads")),
+            head_dim=int(g("head_dim") or dm // hq),
+            linear_heads=heads, linear_head_dim=p,
+            conv_kernel=int(g("mamba_d_conv")), gate_rank=0,
+            gqa_layers=tuple(i for i, k in enumerate(kinds)
+                             if k == "attention"),
+            moe_intermediate_size=int(g("intermediate_size")),
+            n_routed_experts=held, router_experts=total, expert_first=first,
+            num_experts_per_tok=int(g("num_experts_per_tok")),
+            n_shared_experts=1, routed_scaling_factor=1.0,
+            # softmax over the chosen logits = softmax over all of them,
+            # renormalised over the chosen
+            norm_topk_prob=True, scoring_func="softmax", topk_method="greedy",
+            rms_norm_eps=float(g("rms_norm_eps", 1e-5)),
+            max_position_embeddings=int(g("max_position_embeddings", 4096)),
+            dtype=dtype, recurrence="ssd",
+            state_dim=int(g("mamba_d_state")), ssm_groups=groups,
+            ssm_chunk=int(g("mamba_chunk_size", 256)), gqa_gate=False,
+            attention_multiplier=float(g("attention_multiplier")),
+            shared_intermediate_size=int(g("shared_intermediate_size")),
+            tie_word_embeddings=bool(g("tie_word_embeddings", False)),
+            embedding_multiplier=float(g("embedding_multiplier", 1.0)),
+            residual_multiplier=float(g("residual_multiplier", 1.0)),
+            logits_scaling=float(g("logits_scaling", 1.0)),
+        )
+
+
+def _held_experts(cfg: dict, key: str) -> tuple[int, int, int]:
+    """(experts held here, experts the router chooses among, the first held)
+    from the file's count under ``key`` and its ``expert_parallel`` block."""
+    ep = cfg.get("expert_parallel") or {}
+    held = int(cfg[key])
+    total = int(ep.get("router_experts", held))
+    first = int(ep.get("first_expert", 0))
+    if not 0 <= first <= total - held:
+        raise ValueError(
+            f"experts {first}..{first + held - 1} are not among the "
+            f"router's {total}")
+    return held, total, first
 
 
 @dataclass(frozen=True)
@@ -230,7 +345,9 @@ class HybridLinearModel:
         (scripts/hybrid_linear_longctx_check.py)."""
         self.config = config
         self.state_dtype = state_dtype
-        self.sm_scale = float(config.head_dim ** -0.5)
+        self.sm_scale = float(config.head_dim ** -0.5
+                              if config.attention_multiplier is None
+                              else config.attention_multiplier)
         runs, seen = [], {"gqa": 0, "linear": 0}
         for li in range(config.num_layers):
             kind = "gqa" if li in config.gqa_layers else "linear"
@@ -248,10 +365,10 @@ class HybridLinearModel:
     def init_params(self, rng: jax.Array) -> Params:
         """Seeded weights: normal / sqrt(fan-in), norms 1, and the decay's
         own (A_log = ln U(1, 16) a head; b_dt the inverse softplus of a
-        log-uniform step in [0.001, 0.1] a channel, the family's
-        initialisation; the low-rank decay projection scaled by
-        ``DECAY_PROJ_STD``).  Keys are drawn in a fixed order: a new
-        parameter goes after the ones that are there.  One program: made
+        log-uniform step in [0.001, 0.1] a channel — a head for the
+        state-space layers — the family's initialisation; what the input
+        adds to b_dt scaled by ``DECAY_PROJ_STD``).  Keys are drawn in a
+        fixed order: a new parameter goes after the ones that are there.  One program: made
         array by array, the draws are some forty compilations (200 s of a
         first start on the chip)."""
         return self._draw(rng)
@@ -268,37 +385,48 @@ class HybridLinearModel:
             return (scale * jax.random.normal(next(keys), shape, jnp.float32)
                     / math.sqrt(fan_in)).astype(dt)
 
+        def decay_step(shape):
+            """The inverse softplus of a step log-uniform in [0.001, 0.1]:
+            softplus^-1(s) = ln(e^s - 1)."""
+            step = jnp.exp(jax.random.uniform(
+                next(keys), shape, jnp.float32,
+                math.log(0.001), math.log(0.1)))
+            return jnp.log(jnp.expm1(step))
+
+        def a_log(n: int):
+            return jnp.log(jax.random.uniform(
+                next(keys), (n, lh), jnp.float32, 1.0, 16.0))
+
         def experts(n: int) -> dict:
             e, f = cfg.n_routed_experts, cfg.moe_intermediate_size
-            fs = f * cfg.n_shared_experts
-            return {
-                "mlp_norm": jnp.ones((n, dm), dt),
-                "router": dense((n, dm, cfg.router_experts), dm),
-                "router_bias": ROUTER_BIAS_STD * jax.random.normal(
-                    next(keys), (n, cfg.router_experts), jnp.float32),
-                "w_gate": dense((n, e, dm, f), dm),
-                "w_up": dense((n, e, dm, f), dm),
-                "w_down": dense((n, e, f, dm), f),
-                "shared_gate": dense((n, dm, fs), dm),
-                "shared_up": dense((n, dm, fs), dm),
-                "shared_down": dense((n, fs, dm), fs),
-            }
+            fs = cfg.shared_width
+            out = {"mlp_norm": jnp.ones((n, dm), dt),
+                   "router": dense((n, dm, cfg.router_experts), dm)}
+            if cfg.topk_method == "noaux_tc":      # the correction bias
+                out["router_bias"] = ROUTER_BIAS_STD * jax.random.normal(
+                    next(keys), (n, cfg.router_experts), jnp.float32)
+            out.update(
+                w_gate=dense((n, e, dm, f), dm),
+                w_up=dense((n, e, dm, f), dm),
+                w_down=dense((n, e, f, dm), f),
+                shared_gate=dense((n, dm, fs), dm),
+                shared_up=dense((n, dm, fs), dm),
+                shared_down=dense((n, fs, dm), fs))
+            return out
 
         def gqa(n: int) -> dict:
-            return {
-                "attn_norm": jnp.ones((n, dm), dt),
-                "wq": dense((n, dm, h * dh), dm),
-                "wk": dense((n, dm, hk * dh), dm),
-                "wv": dense((n, dm, hk * dh), dm),
-                "w_gate_attn": dense((n, dm, h * dh), dm),
-                "wo": dense((n, h * dh, dm), h * dh),
-                **experts(n),
-            }
+            out = {"attn_norm": jnp.ones((n, dm), dt),
+                   "wq": dense((n, dm, h * dh), dm),
+                   "wk": dense((n, dm, hk * dh), dm),
+                   "wv": dense((n, dm, hk * dh), dm)}
+            if cfg.gqa_gate:
+                out["w_gate_attn"] = dense((n, dm, h * dh), dm)
+            out["wo"] = dense((n, h * dh, dm), h * dh)
+            out.update(experts(n))
+            return out
 
         def linear(n: int) -> dict:
-            step = jnp.exp(jax.random.uniform(
-                next(keys), (n, lh * ld), jnp.float32,
-                math.log(0.001), math.log(0.1)))
+            dt_bias = decay_step((n, lh * ld))
             return {
                 "attn_norm": jnp.ones((n, dm), dt),
                 "wq": dense((n, dm, lh * ld), dm),
@@ -308,10 +436,8 @@ class HybridLinearModel:
                                 cfg.conv_kernel),
                 "decay_down": dense((n, dm, r), dm),
                 "decay_up": dense((n, r, lh * ld), r, DECAY_PROJ_STD),
-                "a_log": jnp.log(jax.random.uniform(
-                    next(keys), (n, lh), jnp.float32, 1.0, 16.0)),
-                # softplus^-1(s) = ln(e^s - 1)
-                "dt_bias": jnp.log(jnp.expm1(step)),
+                "a_log": a_log(n),
+                "dt_bias": dt_bias,
                 "w_beta": dense((n, dm, lh), dm),
                 "out_norm": jnp.ones((n, ld), dt),
                 "gate_down": dense((n, dm, r), dm),
@@ -320,14 +446,46 @@ class HybridLinearModel:
                 **experts(n),
             }
 
-        make = {"gqa": gqa, "linear": linear}
-        return {
+        def conv_init(shape):
+            bound = cfg.conv_kernel ** -0.5
+            return jax.random.uniform(next(keys), shape, jnp.float32,
+                                      -bound, bound).astype(dt)
+
+        def ssd(n: int) -> dict:
+            inner, w = cfg.ssm_width, cfg.conv_width
+            dt_bias = decay_step((n, lh))
+            # z ‖ xBC ‖ dt; the dt columns scaled as the delta rule's
+            # low-rank decay projection is, and for its reason
+            cols = jnp.concatenate([jnp.ones((inner + w,), jnp.float32),
+                                    jnp.full((lh,), DECAY_PROJ_STD)])
+            w_in = (jax.random.normal(next(keys), (n, dm, inner + w + lh),
+                                      jnp.float32)
+                    * cols / math.sqrt(dm)).astype(dt)
+            return {
+                "attn_norm": jnp.ones((n, dm), dt),
+                "w_in": w_in,
+                # Mamba-2's: a depth-wise Conv1d's default, U(±K^-1/2)
+                "conv_w": conv_init((n, w, cfg.conv_kernel)),
+                "conv_b": conv_init((n, w)),
+                "a_log": a_log(n),
+                "dt_bias": dt_bias,
+                "d_skip": jnp.ones((n, lh), jnp.float32),
+                "out_norm": jnp.ones((n, inner), dt),
+                "wo": dense((n, inner, dm), inner),
+                **experts(n),
+            }
+
+        make = {"gqa": gqa,
+                "linear": ssd if cfg.recurrence == "ssd" else linear}
+        out = {
             "embed": dense((cfg.vocab_size, dm), dm),
             "groups": {kind: make[kind](n)
                        for kind, n in sorted(self.group_sizes.items())},
             "final_norm": jnp.ones((dm,), dt),
-            "lm_head": dense((dm, cfg.vocab_size), dm),
         }
+        if not cfg.tie_word_embeddings:
+            out["lm_head"] = dense((dm, cfg.vocab_size), dm)
+        return out
 
     def partition_specs(self) -> Params:
         raise NotImplementedError(
@@ -348,9 +506,10 @@ class HybridLinearModel:
         """``kv``: the K/V pool in LlamaModel's layout over the attending
         layers only, [L_gqa, N, 2, Bs, Hk·D], first in the pytree's order of
         what the engine counts a token's cache bytes by; ``state``
-        [L_lin, slots, H, d, d] float32, ``conv`` [L_lin, slots, K-1, 3·H·d]
-        and ``state_pos`` [slots] (ops/linear_state.py), indexed by the
-        engine's slot; ``moe_counts`` int32 [L, 1, 7]: what the expert
+        [L_lin, slots, *state_shape] float32 (H, d, d; H, P, N for the
+        state-space layers), ``conv`` [L_lin, slots, K-1, conv_width] and
+        ``state_pos`` [slots] (ops/linear_state.py), indexed by the engine's
+        slot; ``moe_counts`` int32 [L, 1, 7]: what the expert
         layers counted (as models/glm_dsa.py) and, in row 0, what the linear
         layers did — real tokens × layers advanced, sequences started from
         zeros, rows that continued at another position than their slot's."""
@@ -366,8 +525,7 @@ class HybridLinearModel:
                 (len(cfg.gqa_layers), num_blocks, 2, block_size,
                  cfg.num_kv_heads * cfg.head_dim), cfg.jax_dtype),
             **linear_state.init_state(
-                cfg.linear_layers, slots, cfg.linear_heads,
-                cfg.linear_head_dim, cfg.linear_head_dim, cfg.conv_width,
+                cfg.linear_layers, slots, *cfg.state_shape, cfg.conv_width,
                 cfg.conv_kernel, cfg.jax_dtype, self.state_dtype),
             "moe_counts": jnp.zeros(
                 (cfg.num_layers, 1, EXPERT_COUNTS + STATE_COUNTS), jnp.int32),
@@ -375,7 +533,7 @@ class HybridLinearModel:
 
     def state_bytes_per_slot(self) -> int:
         cfg = self.config
-        per_layer = (cfg.linear_heads * cfg.linear_head_dim ** 2
+        per_layer = (math.prod(cfg.state_shape)
                      * jnp.dtype(self.state_dtype).itemsize
                      + (cfg.conv_kernel - 1) * cfg.conv_width
                      * jnp.dtype(cfg.jax_dtype).itemsize)
@@ -385,12 +543,17 @@ class HybridLinearModel:
         """("pallas" | "xla", why) for a decode step's state update: the one
         place the choice is made, before tracing (as
         ``paged_attention.attention_impl``)."""
-        cfg = self.config
-        return linear_state.step_impl(
-            cfg.linear_heads, cfg.linear_head_dim, cfg.linear_head_dim,
-            self.state_dtype)
+        if self.config.recurrence == "ssd":
+            return "xla", "the state-space recurrence has no kernel"
+        return linear_state.step_impl(*self.config.state_shape,
+                                      self.state_dtype)
 
     # ---------------------------------------------------------------- forward
+    def _add(self, h, out):
+        """The residual stream plus a mixer's or an expert layer's output."""
+        m = self.config.residual_multiplier
+        return h + (out if m == 1.0 else out * jnp.asarray(m, out.dtype))
+
     def _experts(self, group: dict, lp: dict, i, h, valid):
         """h + MoE(RMSNorm(h)) and the layer's four counts."""
         cfg = self.config
@@ -398,7 +561,7 @@ class HybridLinearModel:
         xf = rms_norm(h, lp["mlp_norm"], cfg.rms_norm_eps).reshape(b * s, d)
         with jax.named_scope("moe_router"):
             weights, topi = moe_route(cfg, lp["router"], xf,
-                                      lp["router_bias"])
+                                      lp.get("router_bias"))
             real = valid.reshape(b * s, 1)
             here = ((topi >= cfg.expert_first)
                     & (topi < cfg.expert_first + cfg.n_routed_experts) & real)
@@ -415,7 +578,7 @@ class HybridLinearModel:
                 held=(cfg.expert_first, cfg.n_routed_experts))
         shared = (jax.nn.silu(xf @ lp["shared_gate"])
                   * (xf @ lp["shared_up"])) @ lp["shared_down"]
-        return h + (routed + shared).reshape(b, s, d), counted
+        return self._add(h, (routed + shared).reshape(b, s, d)), counted
 
     def _gqa(self, lp, ci, h, kv, positions, block_tables, seq_lens,
              slot_idx, prefix_blocks, by_length):
@@ -426,7 +589,9 @@ class HybridLinearModel:
             q = split_heads(x @ lp["wq"], cfg.num_heads)
             k = split_heads(x @ lp["wk"], cfg.num_kv_heads)
             v = (x @ lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-            gate = jax.nn.sigmoid((x @ lp["w_gate_attn"]).astype(jnp.float32))
+            if cfg.gqa_gate:
+                gate = jax.nn.sigmoid(
+                    (x @ lp["w_gate_attn"]).astype(jnp.float32))
         with jax.named_scope("attn"):
             fast = prefix_blocks is not None and s > 1
             kv = write_kv_cache_layer(kv, ci, k, v, slot_idx,
@@ -447,10 +612,65 @@ class HybridLinearModel:
                     q, kv, ci, block_tables, seq_lens, positions,
                     sm_scale=self.sm_scale)
         with jax.named_scope("attn_out"):
-            o = (attn.reshape(b, s, -1).astype(jnp.float32)
-                 * gate).astype(h.dtype)
-            h = h + o @ lp["wo"]
+            o = attn.reshape(b, s, -1)
+            if cfg.gqa_gate:
+                o = o.astype(jnp.float32) * gate
+            h = self._add(h, o.astype(h.dtype) @ lp["wo"])
         return h, kv
+
+    def _ssd(self, lp, si, h, state, conv, rows):
+        """One state-space layer; arguments as ``_linear``.  The state's own
+        read-update-write is under ``ssm_state``, the mixer between its
+        projections under ``ssm``."""
+        cfg = self.config
+        b, s, _ = h.shape
+        heads, p, ns = cfg.state_shape
+        gn = cfg.ssm_groups * ns
+        slots, fresh, alive, n_real, valid = rows
+        f32 = jnp.float32
+        with jax.named_scope("attn_proj"):
+            x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+            z, xbc, dt = jnp.split(
+                x @ lp["w_in"],
+                (cfg.ssm_width, cfg.ssm_width + cfg.conv_width), axis=-1)
+        with jax.named_scope("attn"), jax.named_scope("ssm"):
+            at = si if slots is None else (si, slots)    # row i is slot i
+            old_c, old_s = conv[at], state[at]
+            tail = jnp.where(fresh[:, None, None], 0, old_c)
+            y, new_c = linear_state.short_conv(xbc, lp["conv_w"], tail,
+                                               n_real, lp["conv_b"])
+            xs, bm, cm = jnp.split(
+                jax.nn.silu(y), (cfg.ssm_width, cfg.ssm_width + gn), axis=-1)
+            xs = xs.reshape(b, s, heads, p)
+            bm = bm.reshape(b, s, cfg.ssm_groups, ns)
+            cm = cm.reshape(b, s, cfg.ssm_groups, ns)
+            step = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+            step = jnp.where(valid[..., None], step, 0.0)    # padding
+            a_head = -jnp.exp(lp["a_log"].astype(f32))
+            with jax.named_scope("ssm_state"):
+                s0 = jnp.where(fresh[:, None, None, None], 0,
+                               old_s.astype(f32))
+                if s == 1:
+                    o, new_s = ssm_state.ssd_step(
+                        xs[:, 0], step[:, 0], a_head, bm[:, 0], cm[:, 0],
+                        lp["d_skip"], s0)
+                    o = o[:, None]
+                else:
+                    o, new_s = ssm_state.ssd_scan(
+                        xs, step, a_head, bm, cm, lp["d_skip"], s0,
+                        cfg.ssm_chunk)
+                # a row with no real token keeps its slot bit for bit
+                new_s = jnp.where(alive[:, None, None, None],
+                                  new_s.astype(state.dtype), old_s)
+                state = state.at[at].set(new_s)
+            conv = conv.at[at].set(
+                jnp.where(alive[:, None, None], new_c, old_c))
+            # the gate inside the norm, one group over the whole width
+            o = o.reshape(b, s, cfg.ssm_width) * jax.nn.silu(z.astype(f32))
+            o = rms_norm(o, lp["out_norm"], cfg.rms_norm_eps).astype(h.dtype)
+        with jax.named_scope("attn_out"):
+            h = self._add(h, o @ lp["wo"])
+        return h, state, conv
 
     def _linear(self, lp, si, h, state, conv, rows):
         """One linear layer on ``h`` [B, S, Dm]; ``state`` / ``conv`` are the
@@ -530,7 +750,7 @@ class HybridLinearModel:
             o = (o * jax.nn.sigmoid(
                 out_gate.astype(f32).reshape(b, s, lh, ld))).astype(h.dtype)
         with jax.named_scope("attn_out"):
-            h = h + o.reshape(b, s, lh * ld) @ lp["wo"]
+            h = self._add(h, o.reshape(b, s, lh * ld) @ lp["wo"])
         return h, state, conv
 
     def forward(self, params, tokens, positions, cache, block_tables,
@@ -567,10 +787,14 @@ class HybridLinearModel:
                          seq_lens[order], positions[order])
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(cfg.jax_dtype)
+            if cfg.embedding_multiplier != 1.0:
+                hidden = hidden * jnp.asarray(cfg.embedding_multiplier,
+                                              hidden.dtype)
 
         kv, state, conv = cache["kv"], cache["state"], cache["conv"]
         counts = cache["moe_counts"].at[0, 0, EXPERT_COUNTS:].add(counted)
         expert_keys = ("w_gate", "w_up", "w_down")
+        recur = self._ssd if cfg.recurrence == "ssd" else self._linear
 
         def layer_step(kind: str):
             group = params["groups"][kind]
@@ -585,7 +809,7 @@ class HybridLinearModel:
                                       seq_lens, slot_idx, prefix_blocks,
                                       by_length)
                 else:
-                    h, state, conv = self._linear(lp, i, h, state, conv, rows)
+                    h, state, conv = recur(lp, i, h, state, conv, rows)
                 with jax.named_scope("mlp"):
                     h, picked = self._experts(group, lp, i, h, valid)
                     counts = counts.at[li, 0, :EXPERT_COUNTS].add(picked)
@@ -607,7 +831,12 @@ class HybridLinearModel:
                         "state_pos": state_pos, "moe_counts": counts}
 
     def compute_logits(self, params, hidden):
+        cfg = self.config
         with jax.named_scope("logits"):
-            w = params["lm_head"]
-            return jnp.matmul(hidden.astype(w.dtype), w,
-                              preferred_element_type=jnp.float32)
+            w = (params["embed"].T if cfg.tie_word_embeddings
+                 else params["lm_head"])
+            logits = jnp.matmul(hidden.astype(w.dtype), w,
+                                preferred_element_type=jnp.float32)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
+            return logits

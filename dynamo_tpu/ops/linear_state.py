@@ -182,19 +182,21 @@ def delta_rule_scan(q, k, v, g, beta, state, chunk: int = CHUNK):
     return o.reshape(o.shape[0], whole, *o.shape[3:])[:, :s], state
 
 
-def short_conv(x, w, tail, n_real):
+def short_conv(x, w, tail, n_real, bias=None):
     """Causal depth-wise convolution over time.  x [B, S, D] (this
     dispatch's inputs, real tokens first), w [D, K], tail [B, K-1, D] (the
     K-1 inputs before x[:, 0]; zeros at a sequence's start), n_real [B]
-    (how many of the S are real) -> (y [B, S, D] float32 with
-    y_t = sum_i w[:, i] ⊙ xx_{t+i}, xx = tail ‖ x; the new tail: the K-1
-    inputs before position n_real, in ``tail``'s type).  With n_real 0 the
-    tail comes back as it was."""
+    (how many of the S are real), bias [D] or None -> (y [B, S, D] float32
+    with y_t = sum_i w[:, i] ⊙ xx_{t+i} (+ bias), xx = tail ‖ x; the new
+    tail: the K-1 inputs before position n_real, in ``tail``'s type).  With
+    n_real 0 the tail comes back as it was."""
     kk = w.shape[1]
     xx = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     s = x.shape[1]
     wf = w.astype(F32)
     y = sum(xx[:, i:i + s].astype(F32) * wf[:, i] for i in range(kk))
+    if bias is not None:
+        y = y + bias.astype(F32)
     new = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
         row, n, kk - 1, axis=0))(xx, n_real)
     return y, new.astype(tail.dtype)

@@ -599,10 +599,11 @@ def grouped_matmul_tiling(tm: int, k: int, n: int, w_bytes: int = 2,
     ``GROUPED_MATMUL_BLOCK_BYTES``: sized by its bytes, so that a grid
     step's fixed cost stays a small share of its DMA whatever the expert's
     shape (down: 3.1 MB whole in Qwen3, 5.2 of Solar's 10.5, 4.2 of
-    Mistral-Small-4's 16.8 and of GLM's 25.2), narrowed further while a
-    step's buffers pass the scoped VMEM of one kernel (a call over two
-    stacks, gate and up, holds a block of each: 3.1 MB whole in Qwen3, 2.1
-    in Solar and Mistral-Small-4, 3.1 in GLM)."""
+    Mistral-Small-4's 16.8 and of GLM's 25.2, 3.1 of granite-4.0-h-small's
+    6.3), narrowed further while a step's buffers pass the scoped VMEM of
+    one kernel (a call over two stacks, gate and up, holds a block of each:
+    3.1 MB whole in Qwen3, 2.1 in Solar and Mistral-Small-4, 3.1 in GLM and,
+    at K 4,096 / N 768, in granite-4.0-h-small: half of its N)."""
     lanes = 128
     if n % lanes:
         return n
@@ -1605,6 +1606,10 @@ def audit_cases() -> list[dict]:
                              weights=2),
         _spec_grouped_matmul("experts-glm-down", 256, 4, 16, 2048, 6144,
                              weights=1),
+        # granite-4.0-h-small's decode (64 rows x top-10 over the 36 held
+        # experts of 4,096 x 768: Qwen3's N at Solar's K, 8.9 rows an expert)
+        _spec_grouped_matmul("experts-granite-decode", 640, 10, 36, 4096, 768,
+                             weights=2),
     ]
 
 

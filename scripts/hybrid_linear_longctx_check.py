@@ -1,12 +1,13 @@
-"""The agreement of the served Solar-Open2 configuration with its reference
-over a long answer, which the benchmark's ``correct`` cannot reach: it sees 8
+"""The agreement of a served recurrent-state configuration (``--config``:
+solar-open2-ep16, the delta rule, by default; granite-4.0-h-small-ep2, the
+state-space recurrence) with its reference over a long answer, which the benchmark's ``correct`` cannot reach: it sees 8
 greedy tokens behind at most 700, and the question a recurrent state raises is
 what a thousand updates do to it.
 
     chiprun --timeout 1800 -- python3 scripts/hybrid_linear_longctx_check.py \
-        [--prompt 2048] [--answer 1024] [--seed N] [--tiny]
-    ... scripts/hybrid_linear_longctx_check.py --check-seeds a,b,c \
-        [--bf16-state | --cache-one-precision-down]
+        [--config NAME] [--prompt 2048] [--answer 1024] [--seed N] [--tiny]
+    ... scripts/hybrid_linear_longctx_check.py [--config NAME] \
+        --check-seeds a,b,c [--bf16-state | --cache-one-precision-down]
 
 (The second form runs the benchmark's own check alone, once a seed, and prints
 its margins: how the configuration's ``check`` rule was set, and its negative
@@ -19,12 +20,13 @@ configuration states — the state in bf16, the K/V rows and the convolution's
 tail in float8 (e4m3) — which the rule rejects.)
 
 One process, on the chip (``--tiny``: a toy size on the CPU, to rehearse the
-script).  It builds cellbench/configs/solar-open2-ep16.json at its published
+script).  It builds cellbench/configs/<config>.json at its published
 widths with seeded weights and serves it through ``EngineCore`` with the
 cell's ``serve`` block: one request of ``--prompt`` tokens (four chunks of
 512: the state crosses three chunk boundaries) and ``--answer`` greedy tokens,
-each a decode step that updates 6 x 64 heads' states.  Against
-cellbench/reference/hybrid_linear.py run over the whole sequence (the
+each a decode step that updates every recurrent layer's state (6 x 64 heads;
+9 x 128).  Against the configuration's own reference (cellbench/reference/
+hybrid_linear.py, granite_hybrid.py) run over the whole sequence (the
 recurrence one token at a time):
 
   (i)   the top-20 log-probabilities the engine returned for every generated
@@ -57,8 +59,6 @@ sys.path.insert(0, str(ROOT))
 from scripts.glm_longctx_check import (  # noqa: E402
     ask, logprob_verdict, note, serve)
 
-CONFIG = ROOT / "cellbench/configs/solar-open2-ep16.json"
-
 TINY = dict(
     model_type="solar_open2", vocab_size=512, hidden_size=64,
     num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=2,
@@ -77,6 +77,30 @@ TINY = dict(
     serve={"max_batch_size": 4, "block_size": 16, "max_model_len": 1024,
            "prefill_chunk_tokens": 64, "num_blocks": 256},
     check={"abs_tol": 0.06, "share_within": 0.98, "median_tol": 0.002})
+
+TINY_GRANITE = dict(
+    model_type="granitemoehybrid", vocab_size=512, hidden_size=64,
+    num_hidden_layers=6, attention_layers=1,
+    layer_types=["mamba", "mamba", "attention", "mamba", "mamba", "mamba"],
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    attention_bias=False, attention_multiplier=0.1,
+    position_embedding_type="nope", mamba_n_heads=4, mamba_d_head=32,
+    mamba_d_state=16, mamba_n_groups=1, mamba_d_conv=4, mamba_expand=2,
+    mamba_chunk_size=32, mamba_conv_bias=True, mamba_proj_bias=False,
+    intermediate_size=32, shared_intermediate_size=48, num_local_experts=4,
+    num_experts_per_tok=3, embedding_multiplier=12, residual_multiplier=0.22,
+    logits_scaling=16, tie_word_embeddings=True, rms_norm_eps=1e-5,
+    max_position_embeddings=4096,
+    expert_parallel={"chips": 2, "router_experts": 8, "first_expert": 4},
+    dtype="float32", reference="granite_hybrid",
+    model_class=TINY["model_class"], config_class=TINY["config_class"],
+    serve=TINY["serve"],
+    # logits over logits_scaling 16: the float32 toy reads 0 to the last bit
+    # and its cache one precision down 2.7e-4
+    check={"abs_tol": 0.06, "share_within": 0.98, "median_tol": 1e-4})
+
+# --config: the benchmark's file and the toy that rehearses it
+CONFIGS = {"solar-open2-ep16": TINY, "granite-4.0-h-small-ep2": TINY_GRANITE}
 
 
 def bf16_state() -> None:
@@ -116,15 +140,17 @@ def cache_one_precision_down() -> None:
         write(cache, layer, f8(k), f8(v), *rest, **kw))
     conv = linear_state.short_conv
 
-    def short_conv(x, w, tail, n_real):
-        y, new = conv(x, w, tail, n_real)
+    def short_conv(x, w, tail, n_real, bias=None):
+        y, new = conv(x, w, tail, n_real, bias)
         return y, f8(new)
 
     linear_state.short_conv = short_conv
 
 
 # tolerances at which --check-seeds also prints the share of pairs within
-SHARES_AT = (0.15, 0.2, 0.25, 0.3, 0.35, 0.4)
+# (the small ones for a model whose logits are divided by logits_scaling)
+SHARES_AT = (0.003, 0.004, 0.005, 0.006, 0.008, 0.01, 0.015,
+             0.15, 0.2, 0.25, 0.3, 0.35, 0.4)
 
 CONTROLS = {"bf16_state": bf16_state,
             "cache_one_precision_down": cache_one_precision_down}
@@ -198,6 +224,8 @@ async def check_margins(config: dict, seeds: list[int]) -> list[dict]:
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--config", default="solar-open2-ep16",
+                   choices=sorted(CONFIGS))
     p.add_argument("--check-seeds", default=None,
                    help="run only the benchmark's own check, once a seed "
                         "(comma separated), and print its margins")
@@ -224,7 +252,8 @@ def main(argv=None) -> int:
         rows = []
         for seed in a.check_seeds.split(","):
             out = subprocess.run(
-                [sys.executable, __file__, "--check-seeds", seed]
+                [sys.executable, __file__, "--config", a.config,
+                 "--check-seeds", seed]
                 + (["--bf16-state"] if a.bf16_state else [])
                 + (["--cache-one-precision-down"]
                    if a.cache_one_precision_down else [])
@@ -243,14 +272,15 @@ def main(argv=None) -> int:
     from cellbench import spec
 
     if a.tiny:
-        config = TINY
+        config = CONFIGS[a.config]
         a.prompt, a.answer = 200, 96
-        a.num_blocks = TINY["serve"]["num_blocks"]
+        a.num_blocks = config["serve"]["num_blocks"]
     else:
         if jax.devices()[0].platform != "tpu":
             raise SystemExit("no TPU: the published widths are compared on "
                              "the chip (--tiny rehearses on the CPU)")
-        config = spec.read_json(CONFIG)
+        config = spec.read_json(
+            ROOT / "cellbench/configs" / f"{a.config}.json")
     from dynamo_tpu.utils.compilation_cache import enable_persistent_cache
 
     note(f"compile cache: {enable_persistent_cache()}")

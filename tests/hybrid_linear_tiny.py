@@ -98,9 +98,10 @@ def drain(core) -> None:
         pass
 
 
-def worst_delta(params, prompt, answer, cfg: dict = TINY) -> float:
+def worst_delta(params, prompt, answer, cfg: dict = TINY, want=want) -> float:
     """Teacher-forced: the largest |log-probability - reference's| over the
-    top candidates of every generated position of one request."""
+    top candidates of every generated position of one request (``want``:
+    another toy's reference)."""
     tokens, tops = answer
     seq = list(prompt) + list(tokens)
     at = np.arange(len(prompt) - 1, len(seq) - 1)

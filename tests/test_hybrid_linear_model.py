@@ -155,7 +155,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
      "num_kv_heads"),
     ({"use_rope": True}, "use_rope"),
     ({"first_k_dense_replace": 1}, "first_k_dense_replace"),
-    ({"tie_word_embeddings": True}, "tie_word_embeddings"),
+    ({"use_gqa_gate": False}, "use_gqa_gate"),
     ({"rope_scaling": {"type": "yarn", "factor": 4}}, "rope_scaling"),
     ({"gqa_layers": [0, 5]}, "gqa_interval"),
     ({"gqa_interval": 1}, "gqa_interval"),

@@ -70,6 +70,27 @@ def test_a_model_without_a_state_lowers_to_the_program_it_had(family, program):
     assert _digest(family, program) == PARENT_HLO[(family, program)]
 
 
+# the delta-rule toy (hybrid_linear_tiny.TINY) on the parent of the PR that
+# gave the model class a second recurrence (b0b93f4): that PR's switches
+# (multipliers, the optional gate, tied embeddings, the router's bias) add
+# nothing to the programs of the model that was there
+PARENT_DELTA_RULE_HLO = {
+    "decode": "1747f99a2b048b2f18cf4d3cad70eb3bc263b520d1ad51af65c7c358f7ec6367",
+    "prefill": "bd8afad81fb2f2467009d0e9fe106c4754741e1c4d9d3ed2c902bcd0473dc201",
+}
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_DELTA_RULE_HLO))
+def test_the_delta_rule_model_lowers_to_the_program_it_had(program):
+    from hybrid_linear_tiny import build
+
+    model, _ = build()
+    extra = ({"seq_slots": jnp.zeros((1,), jnp.int32)}
+             if program == "prefill" else {})
+    assert hashlib.sha256(_lowered(model, program, **extra).encode()
+                          ).hexdigest() == PARENT_DELTA_RULE_HLO[program]
+
+
 def test_the_hybrid_model_lowers_to_one_scan_a_run_of_layers():
     """G | L L L | G | L L L: four scans in a decode step; a 16-token chunk
     is one piece of the recurrence, so a prefill has the same four.  The chunk is told its

@@ -1111,3 +1111,58 @@ def test_hybrid_linear_cell_programs_write_the_state_in_place(
           f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
           f"total {total / 1e9:.3f} GB")
     assert 0.6 * V5E_HBM < total < 0.75 * V5E_HBM, total
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_granite_hybrid_cell_programs_write_the_state_in_place(
+        topo, tpu_gate, program):
+    """granite-4.0-h-small-ep2's decode program (64 rows = the slot array)
+    and a 512-token chunk (two SSD pieces of 256) with 16 blocks of the
+    prompt cached, whole (10 layers m m m m m A m m m m, 36 experts, the
+    cell's cache and its 64 slots of state): the one attending layer through
+    the Pallas GQA kernels at 32/8 heads, the experts through the grouped
+    matmul at K 4096 / N 768 (8.9 rows an expert in decode, 71 in a chunk),
+    one scan a run of layers, the state-space recurrence under XLA with no
+    custom call of its own, every leaf of the cache donated and written in
+    place, and weights + state + K/V inside the chip (~12.8 GB)."""
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "granite-4.0-h-small-ep2.json",
+        lambda spec: SingleDeviceSharding(topo.devices[0]))
+    serve = dict(hf["serve"])
+    assert hf["attention_layers"] == len(cfg.gqa_layers) == 1
+    assert model.state_update_impl()[0] == "xla"
+    fn, args = _step_program(program, model, serve, sds, prefix_blocks=16)
+    if program == "prefill":        # the engine names the row's slot
+        from dynamo_tpu.engine.core import unified_step
+
+        fn = lambda p, c, *a: unified_step(
+            model, p, c, *a[:-1], prefix_blocks=16, seq_slots=a[-1])
+        args = (*args, sds((1,)))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    assert ("paged_decode_attention" if program == "decode"
+            else "paged_prefill_attention") in hlo
+    assert len(_grouped_matmul_calls(hlo)) == 2 * 3     # m x5 | A | m x4
+    assert "ragged-dot" not in hlo
+    assert "linear_state_update" not in hlo
+    # m x5 | m x4: the run of one attending layer is a scan of one step,
+    # which XLA inlines (a chunk's two pieces are a scan of their own)
+    assert hlo.count(" while(") == (2 if program == "decode" else 4)
+    # the state is sliced and updated where it lies, never copied whole
+    assert not re.search(r"f32\[9,64,128,64,128\]\S* copy\(", hlo)
+    mem = compiled.memory_analysis()
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    state, pool = nbytes(cache["state"]), nbytes(cache["kv"])
+    assert state == 9 * 64 * 128 * 64 * 128 * 4 and pool == 6272 * 32 * 4096
+    assert cache["conv"].shape == (9, 64, 3, 8448)
+    held = sum(nbytes(a) for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= held                   # all donated
+    assert mem.temp_size_in_bytes < state // 4, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"# {program}: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"total {total / 1e9:.3f} GB")
+    assert 12.7e9 < mem.argument_size_in_bytes < 12.9e9
+    assert 0.74 * V5E_HBM < total < 0.82 * V5E_HBM, total
