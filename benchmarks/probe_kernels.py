@@ -15,10 +15,12 @@ KN006 census flags any registered kernel that loses probe coverage).
 Usage:  python benchmarks/probe_kernels.py [bf16|int8|all] [8b|1b|probe]
         python benchmarks/probe_kernels.py lengths [out.json]   # decode sweep
         python benchmarks/probe_kernels.py experts [out.json [cell:call,...]]  # grouped matmul
+        python benchmarks/probe_kernels.py state [out.json]     # the two recurrent states' decode steps
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 import traceback
@@ -361,8 +363,101 @@ def time_experts(out_path: str | None, only: str | None = None) -> None:
             json.dump(table, f, indent=1)
 
 
+def time_state(out_path: str | None) -> None:
+    """ms a call of a recurrent state's decode step over the slot array at
+    its cell's shape (64 slots, layer 1 of a two-layer leaf; the operations'
+    own device time, median of 20 in a profile) and GB/s of the state's one
+    read and one write, the kernel beside the XLA form the model ran before
+    it (slice, step, ``where(alive)``, set, the leaf donated): the
+    state-space update at granite-4.0-h-small's 128 heads of 64 x 128 at
+    every tiling of its heads, and the delta rule's at Solar-Open2's 64
+    heads of 128 x 128.  Every slot alive, then every eighth idle."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops import linear_state, ssm_state
+    from dynamo_tpu.ops.pallas import linear_state as delta_kernel
+    from dynamo_tpu.ops.pallas import ssm_state as ssm_kernel
+    from dynamo_tpu.ops.pallas import registry as reg
+
+    slots, calls = 64, 20
+    print(f"# device {jax.devices()[0].device_kind}")
+
+    def xla_form(step):
+        @functools.partial(jax.jit, donate_argnums=(0,))
+        def fn(state, layer, *rest):
+            *vectors, fresh, alive = rest
+            old = state[layer]
+            y, new = step(*vectors,
+                          jnp.where(fresh[:, None, None, None], 0, old))
+            new = jnp.where(alive[:, None, None, None], new, old)
+            return y, state.at[layer].set(new)
+        return fn
+
+    def measure(label, fn, args, state_bytes, kw):
+        """The leaf is donated: each call takes the last one's."""
+        held = [jnp.array(args[0])]
+
+        def call(*rest):
+            y, held[0] = fn(held[0], *rest, **kw)
+            return y
+
+        lines = profiled_device_ns(call, args[1:], calls)
+        programs = [ns for _, ns in lines["XLA Modules"]][-calls:]
+        ops: dict = {}
+        for name, ns in lines["XLA Ops"]:    # "%fusion.1 = f32[...] fusion(..."
+            ops.setdefault(name.split(" = ")[0], []).append(ns)
+        ms = float(np.median(programs)) / 1e6
+        largest = sorted(((float(np.median(v)) / 1e6, k)
+                          for k, v in ops.items()), reverse=True)[:3]
+        row = {"what": label, "program_ms": round(ms, 4),
+               "gb_s": round(2 * state_bytes / ms / 1e6, 1),
+               "largest_ops_ms": [(k, round(v, 4)) for v, k in largest]}
+        print(json.dumps(row), flush=True)
+        return row, held[0]
+
+    rows = []
+    for idle in (False, True):
+        a = list(reg.probe_ssm_state_inputs(2, slots, 128, 64, 128, 1))
+        d = list(reg.probe_linear_state_inputs(2, slots, 64, 128))
+        if not idle:
+            a[-1] = d[-1] = jnp.ones((slots,), bool)
+        tag = "7/8 alive" if idle else "all alive"
+        nbytes = int(a[-1].sum()) * 128 * 64 * 128 * 4
+        row, want = measure(f"ssm xla, {tag}", xla_form(ssm_state.ssd_step),
+                            a, nbytes, {})
+        rows.append(row)
+        for group in (16, 32, 64):
+            row, got = measure(
+                f"ssm kernel, {group} heads a step, {tag}",
+                ssm_kernel.state_update, a, nbytes,
+                {"heads_per_step": group})
+            row["state_max_abs_diff_from_xla"] = float(
+                jnp.abs(got - want).max())
+            print(f"#   state after {calls + 1} steps, max |kernel - xla| "
+                  f"{row['state_max_abs_diff_from_xla']:.3g}")
+            rows.append(row)
+        del want, got
+        rows.append(measure(f"delta xla, {tag}",
+                            xla_form(linear_state.delta_rule_step), d,
+                            nbytes, {})[0])
+        rows.append(measure(f"delta kernel, {tag}",
+                            delta_kernel.state_update, d, nbytes, {})[0])
+    if out_path:
+        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump({"device": jax.devices()[0].device_kind,
+                       "rows": rows}, f, indent=1)
+
+
 def main() -> None:
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
+    if which == "state":
+        time_state(sys.argv[2] if len(sys.argv) > 2 else None)
+        return
     if which == "topk":
         time_topk()
         return
@@ -496,6 +591,15 @@ def main() -> None:
     variants.append((
         "linear_state/update",
         lambda: state_update(*probe_linear_state_inputs(2, 16, 64, 128))))
+    # ... and the state-space state's at granite-4.0-h-small's (128 heads of
+    # 64 x 128 float32, one group of B and C: the same 537 MB)
+    from dynamo_tpu.ops.pallas import ssm_state as ssm_kernel
+    from dynamo_tpu.ops.pallas.registry import probe_ssm_state_inputs
+
+    variants.append((
+        "ssm_state/update",
+        lambda: ssm_kernel.state_update(
+            *probe_ssm_state_inputs(2, 16, 128, 64, 128, 1))))
     # the experts' grouped matmul at Solar-Open2's decode shape (512 sorted
     # rows, 32 of them on 20 held experts of 4,096 x 1,280, layer 1 of two)
     from dynamo_tpu.ops.pallas import grouped_matmul as gmm

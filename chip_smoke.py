@@ -336,6 +336,14 @@ def _child_kernels(arg: dict) -> None:
         o, new = state_update(*a, interpret=interpret)
         return reg.linear_state_rows(o, new[a[1]]), ref
 
+    def ssm_step(_quant):
+        from dynamo_tpu.ops.pallas.ssm_state import state_update
+
+        a = reg.probe_ssm_state_inputs(2, 8, 32, 64, 128, 2)
+        ref = reg.ssm_state_reference(*a)
+        y, new = state_update(*a, interpret=interpret)
+        return reg.linear_state_rows(y, new[a[1]]), ref
+
     def experts(_quant):
         from dynamo_tpu.ops.pallas import grouped_matmul as gmm
 
@@ -359,6 +367,7 @@ def _child_kernels(arg: dict) -> None:
         "mla_masked_prefill": [("masked_latent", masked)],
         "latent_cache_dma": [("latent_write_rows", latent_dma)],
         "linear_state_update": [("state_step", state_step)],
+        "ssm_state_update": [("ssm_step", ssm_step)],
         "grouped_expert_matmul": [("experts", experts)],
     }
     live = [k for k, meta in reg.KERNELS.items() if not meta["placeholder"]]
@@ -369,7 +378,8 @@ def _child_kernels(arg: dict) -> None:
             for quant in ([False] if kernel in (
                     "int8_matmul", "mla_sparse_attention",
                     "mla_masked_prefill", "latent_cache_dma",
-                    "linear_state_update", "grouped_expert_matmul")
+                    "linear_state_update", "ssm_state_update",
+                    "grouped_expert_matmul")
                           else [False, True]):
                 t0 = time.monotonic()
                 got, ref = (np.asarray(x, np.float32) for x in fn(quant))

@@ -68,6 +68,7 @@ from dynamo_tpu.models.llama import (
 )
 from dynamo_tpu.ops import linear_state, ssm_state
 from dynamo_tpu.ops.pallas.linear_state import state_update
+from dynamo_tpu.ops.pallas.ssm_state import state_update as ssm_state_update
 from dynamo_tpu.ops.paged_attention import (
     paged_attention_layer,
     prefill_attention,
@@ -544,9 +545,19 @@ class HybridLinearModel:
         place the choice is made, before tracing (as
         ``paged_attention.attention_impl``)."""
         if self.config.recurrence == "ssd":
-            return "xla", "the state-space recurrence has no kernel"
+            return ssm_state.step_impl(*self.config.state_shape,
+                                       self.config.ssm_groups,
+                                       self.state_dtype)
         return linear_state.step_impl(*self.config.state_shape,
                                       self.state_dtype)
+
+    def _updates_in_place(self, s: int, slots) -> bool:
+        """A decode over the slot array updates the state where it lies, in
+        its recurrence's kernel (ops/pallas/linear_state.py, ssm_state.py); a
+        prefill chunk, and any backend but the TPU, slices it, runs the XLA
+        form and sets it."""
+        return (s == 1 and slots is None
+                and self.state_update_impl()[0] == "pallas")
 
     # ---------------------------------------------------------------- forward
     def _add(self, h, out):
@@ -633,9 +644,11 @@ class HybridLinearModel:
             z, xbc, dt = jnp.split(
                 x @ lp["w_in"],
                 (cfg.ssm_width, cfg.ssm_width + cfg.conv_width), axis=-1)
+        in_place = self._updates_in_place(s, slots)
         with jax.named_scope("attn"), jax.named_scope("ssm"):
             at = si if slots is None else (si, slots)    # row i is slot i
-            old_c, old_s = conv[at], state[at]
+            old_c = conv[at]
+            old_s = None if in_place else state[at]
             tail = jnp.where(fresh[:, None, None], 0, old_c)
             y, new_c = linear_state.short_conv(xbc, lp["conv_w"], tail,
                                                n_real, lp["conv_b"])
@@ -648,21 +661,28 @@ class HybridLinearModel:
             step = jnp.where(valid[..., None], step, 0.0)    # padding
             a_head = -jnp.exp(lp["a_log"].astype(f32))
             with jax.named_scope("ssm_state"):
-                s0 = jnp.where(fresh[:, None, None, None], 0,
-                               old_s.astype(f32))
-                if s == 1:
-                    o, new_s = ssm_state.ssd_step(
-                        xs[:, 0], step[:, 0], a_head, bm[:, 0], cm[:, 0],
-                        lp["d_skip"], s0)
+                if in_place:
+                    # the zero start and the dead row's rule are the kernel's
+                    o, state = ssm_state_update(
+                        state, si, xs[:, 0], step[:, 0], a_head, bm[:, 0],
+                        cm[:, 0], lp["d_skip"], fresh, alive)
                     o = o[:, None]
                 else:
-                    o, new_s = ssm_state.ssd_scan(
-                        xs, step, a_head, bm, cm, lp["d_skip"], s0,
-                        cfg.ssm_chunk)
-                # a row with no real token keeps its slot bit for bit
-                new_s = jnp.where(alive[:, None, None, None],
-                                  new_s.astype(state.dtype), old_s)
-                state = state.at[at].set(new_s)
+                    s0 = jnp.where(fresh[:, None, None, None], 0,
+                                   old_s.astype(f32))
+                    if s == 1:
+                        o, new_s = ssm_state.ssd_step(
+                            xs[:, 0], step[:, 0], a_head, bm[:, 0], cm[:, 0],
+                            lp["d_skip"], s0)
+                        o = o[:, None]
+                    else:
+                        o, new_s = ssm_state.ssd_scan(
+                            xs, step, a_head, bm, cm, lp["d_skip"], s0,
+                            cfg.ssm_chunk)
+                    # a row with no real token keeps its slot bit for bit
+                    new_s = jnp.where(alive[:, None, None, None],
+                                      new_s.astype(state.dtype), old_s)
+                    state = state.at[at].set(new_s)
             conv = conv.at[at].set(
                 jnp.where(alive[:, None, None], new_c, old_c))
             # the gate inside the norm, one group over the whole width
@@ -688,11 +708,7 @@ class HybridLinearModel:
             a = (x @ lp["decay_down"]) @ lp["decay_up"]
             beta_logit = x @ lp["w_beta"]
             out_gate = (x @ lp["gate_down"]) @ lp["gate_up"]
-        # a decode over the slot array updates the state where it lies, in
-        # one kernel (ops/pallas/linear_state.py); a prefill chunk, and any
-        # backend but the TPU, slices it, runs ops/linear_state.py and sets it
-        in_place = (s == 1 and slots is None
-                    and self.state_update_impl()[0] == "pallas")
+        in_place = self._updates_in_place(s, slots)
         with jax.named_scope("attn"), jax.named_scope("linear"):
             at = si if slots is None else (si, slots)    # row i is slot i
             old_c = conv[at]

@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["CHUNK", "delta_rule_step", "delta_rule_chunk", "delta_rule_scan",
-           "short_conv", "init_state", "unit_lower_inverse", "step_impl"]
+           "short_conv", "init_state", "unit_lower_inverse", "step_impl",
+           "kernel_gate"]
 
 # tokens a chunk.  The decay is per channel, so the chunk's two score
 # matrices are sums over [C, C, dk] (no product of two [C, dk] factors gives
@@ -85,24 +86,31 @@ def unit_lower_inverse(a: jax.Array) -> jax.Array:
     return t[..., 0, :, :]
 
 
-def step_impl(heads: int, dk: int, dv: int, state_dtype) -> tuple[str, str]:
-    """``("pallas" | "xla", why)`` for one token a row over the slot array:
-    on the TPU the kernel that holds a head's matrix in VMEM between the
-    products and the update (ops/pallas/linear_state.py), elsewhere — and for
-    a geometry the kernel does not tile — ``delta_rule_step``.  A static
-    function of the environment, the backend and the shapes, asked before
-    tracing."""
-    from dynamo_tpu.ops.pallas.linear_state import state_update_supported
-
+def kernel_gate(tiles: bool, geometry: str) -> tuple[str, str]:
+    """``("pallas" | "xla", why)`` for a decode step's state update, either
+    recurrence's: the kernel on the TPU where it ``tiles`` the state, the
+    XLA form elsewhere.  A static function of the environment, the backend
+    and the shapes, asked before tracing."""
     if os.environ.get("DYNAMO_DISABLE_PALLAS"):
         return "xla", "DYNAMO_DISABLE_PALLAS is set"
     backend = jax.default_backend()
     if backend != "tpu":
         return "xla", f"backend is {backend}"
-    if not state_update_supported(heads, dk, dv, state_dtype):
-        return "xla", (f"{heads} heads of {dk} x {dv} {jnp.dtype(state_dtype)}"
-                       " do not tile")
+    if not tiles:
+        return "xla", f"{geometry} do not tile"
     return "pallas", "tpu"
+
+
+def step_impl(heads: int, dk: int, dv: int, state_dtype) -> tuple[str, str]:
+    """``kernel_gate`` for one token a row over the slot array: on the TPU
+    the kernel that holds a head's matrix in VMEM between the products and
+    the update (ops/pallas/linear_state.py), elsewhere — and for a geometry
+    the kernel does not tile — ``delta_rule_step``."""
+    from dynamo_tpu.ops.pallas.linear_state import state_update_supported
+
+    return kernel_gate(
+        state_update_supported(heads, dk, dv, state_dtype),
+        f"{heads} heads of {dk} x {dv} {jnp.dtype(state_dtype)}")
 
 
 def delta_rule_step(q, k, v, g, beta, state):

@@ -53,6 +53,8 @@ __all__ = [
     "MLA_MASKED_KEYS_PER_TILE",
     "LINEAR_STATE_HEADS_PER_STEP",
     "linear_state_heads_per_step",
+    "SSM_STATE_BLOCK_BYTES",
+    "ssm_state_heads_per_step",
     "GROUPED_MATMUL_ROW_TILE",
     "GROUPED_MATMUL_ROW_TILE_BYTES",
     "GROUPED_MATMUL_COMPILER_VMEM_BYTES",
@@ -82,6 +84,8 @@ __all__ = [
     "latent_dma_cost",
     "linear_state_cost",
     "linear_state_reference",
+    "ssm_state_cost",
+    "ssm_state_reference",
     "grouped_matmul_cost",
     "grouped_matmul_reference",
     "decode_cost_estimate",
@@ -133,6 +137,10 @@ MLA_MASKED_KEYS_PER_TILE = 512
 # 128 x 128 float32 are 1 MiB a buffer, 4 MiB double buffered in and out,
 # and their 48 q | k | g vectors fit the one 128-row tile a step transposes
 LINEAR_STATE_HEADS_PER_STEP = 16
+# state-space state update: the bytes of one slot's matrices a grid step, as
+# many heads as make them (32 of 64 x 128 float32: 16 rows of x at two heads
+# a 128-lane row, turned into columns in one tile)
+SSM_STATE_BLOCK_BYTES = 1024 * 1024
 # the experts' grouped matmul: sorted rows a row tile (a pair's matmul takes
 # the whole tile, so the tile is what the matrix unit streams past each
 # weight it loads) and the bytes it may hold (at 6,144 columns 64 rows), the
@@ -208,6 +216,11 @@ KERNELS = {
     # the recurrent state's decode step, one read and one write a matrix
     "linear_state_update": {
         "module": "dynamo_tpu.ops.pallas.linear_state",
+        "placeholder": False,
+    },
+    # the state-space state's decode step, the same
+    "ssm_state_update": {
+        "module": "dynamo_tpu.ops.pallas.ssm_state",
         "placeholder": False,
     },
     # the experts' grouped matmul where an expert has few rows: a stream of
@@ -568,6 +581,35 @@ def linear_state_cost(rows: int, heads: int, dk: int, dv: int) -> dict:
     cells = rows * heads * dk * dv
     return _cost_dict(dma=2 * cells * 4 + rows * heads * (3 * dk + 2 * dv) * 4,
                       flops=7 * cells, trans=rows * heads * dk)
+
+
+def ssm_state_heads_per_step(heads: int, groups: int, p: int,
+                             n: int) -> int | None:
+    """Heads a grid step of the state-space update takes: the most, up to
+    ``SSM_STATE_BLOCK_BYTES`` of float32 matrices and the 128 a turned tile
+    of x rows holds, that fill whole sublane tiles of 128-lane x rows and
+    divide the ``heads / groups`` heads which share one B and C; None where
+    no such group exists."""
+    if heads % groups or 128 % p:
+        return None
+    tile = 8 * 128 // p             # heads whose x rows are one (8, 128) tile
+    most = min(SSM_STATE_BLOCK_BYTES // (p * n * 4), 128) // tile * tile
+    for group in range(most, 0, -tile):
+        if (heads // groups) % group == 0:
+            return group
+    return None
+
+
+def ssm_state_cost(rows: int, heads: int, p: int, n: int,
+                   groups: int = 1) -> dict:
+    """A decode row reads and writes each head's float32 matrix once beside
+    its x, y, B, C and Δ (cellbench/costs/ssm_state.py counts the same) and
+    spends ~5 operations an element: the decay, the rank-one update, the
+    read-out."""
+    cells = rows * heads * p * n
+    return _cost_dict(
+        dma=2 * cells * 4 + rows * (2 * heads * p + 2 * groups * n + heads) * 4,
+        flops=5 * cells, trans=rows * heads)
 
 
 def grouped_matmul_row_tile(m: int, k: int, x_bytes: int = 2) -> int:
@@ -1311,75 +1353,119 @@ def linear_state_rows(o, layer_state):
         [o.reshape(b, -1), layer_state.reshape(b, -1)], axis=1)
 
 
-def linear_state_reference(state, layer, q, k, v, g, beta, fresh, alive):
-    """What ``linear_state.state_update`` must give, by ``delta_rule_step``
-    under XLA, as ``linear_state_rows``: a fresh slot from zeros, a dead slot's
-    state as it was and its ``o`` zero."""
+def _slot_step_reference(step, state, layer, *rest):
+    """A recurrence's ``step`` (its vectors, then the state) on layer
+    ``layer`` of a slot array under XLA, as ``linear_state_rows``: a fresh
+    slot from zeros, a dead slot's state as it was and its output zero.
+    ``rest``: the step's vectors, then ``fresh`` and ``alive`` [B]."""
     import jax.numpy as jnp
 
-    from dynamo_tpu.ops.linear_state import delta_rule_step
-
+    *vectors, fresh, alive = rest
     old = state[layer]
-    o, new = delta_rule_step(
-        q, k, v, g, beta, jnp.where(fresh[:, None, None, None], 0, old))
+    o, new = step(*vectors, jnp.where(fresh[:, None, None, None], 0, old))
     return linear_state_rows(jnp.where(alive[:, None, None], o, 0),
                              jnp.where(alive[:, None, None, None], new, old))
 
 
-def _linear_state_case() -> dict:
-    """Four slots of eight heads, layer 1 of a two-layer leaf: slot 1 starts
+def linear_state_reference(state, layer, q, k, v, g, beta, fresh, alive):
+    """What ``linear_state.state_update`` must give, by ``delta_rule_step``
+    (``_slot_step_reference``)."""
+    from dynamo_tpu.ops.linear_state import delta_rule_step
+
+    return _slot_step_reference(delta_rule_step, state, layer, q, k, v, g,
+                                beta, fresh, alive)
+
+
+def ssm_state_reference(state, layer, x, dt, a_head, b, c, d, fresh, alive):
+    """What ``ssm_state.state_update`` must give, by ``ssd_step``
+    (``_slot_step_reference``)."""
+    from dynamo_tpu.ops.ssm_state import ssd_step
+
+    return _slot_step_reference(ssd_step, state, layer, x, dt, a_head, b, c,
+                                d, fresh, alive)
+
+
+def _slot_state_case(name: str, kernel: str, vectors, state_shape: tuple,
+                     reference, pricing) -> dict:
+    """An audit case of a recurrent state's decode step: four slots, layer 1
+    of a two-layer leaf ``state_shape`` [2, 4, H, ., .]; slot 1 starts
     afresh, slot 2 has no token.  The poisoned run fills both with NaN
     beforehand: the fresh row reads as from zeros, the dead one keeps its
-    NaN (not live) and gives an ``o`` of exact zeros.  Output: ``o`` and the
-    layer's new state, a row a slot."""
+    NaN (not live) and gives an output of exact zeros.  ``vectors(rng)``:
+    the step's vectors in the kernel's order.  Output: ``o`` and the layer's
+    new state, a row a slot."""
+    import importlib
+
     import jax.numpy as jnp
 
     np = _np()
-    n_layers, b, h, d, layer = 2, 4, 8, 128, 1
+    layer = 1
     fresh = np.array([False, True, False, False])
     alive = np.array([True, True, False, True])
+    module = KERNELS[kernel]["module"]
 
     def build():
         rng = np.random.default_rng(800)
-        unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
-        f32 = lambda x: jnp.asarray(x, jnp.float32)
-        return {"q": f32(unit(rng.normal(size=(b, h, d))) * d ** -0.5),
-                "k": f32(unit(rng.normal(size=(b, h, d)))),
-                "v": f32(rng.normal(size=(b, h, d))),
-                "g": f32(-0.5 * rng.random(size=(b, h, d))),
-                "beta": f32(2 / (1 + np.exp(-rng.normal(size=(b, h))))),
-                "state": rng.normal(size=(n_layers, b, h, d, d)).astype(
-                    np.float32)}
+        return {"vectors": tuple(jnp.asarray(v, jnp.float32)
+                                 for v in vectors(rng)),
+                "state": rng.normal(size=state_shape).astype(np.float32)}
 
     def run(inp, poisoned: bool):
-        from dynamo_tpu.ops.pallas import linear_state as kernel
-
         state = inp["state"].copy()
         if poisoned:
             state[layer, fresh | ~alive] = np.nan
-        o, new = kernel.state_update.__wrapped__(
-            jnp.asarray(state), jnp.int32(layer), inp["q"], inp["k"],
-            inp["v"], inp["g"], inp["beta"], jnp.asarray(fresh),
-            jnp.asarray(alive), interpret=True)
+        o, new = importlib.import_module(module).state_update.__wrapped__(
+            jnp.asarray(state), jnp.int32(layer), *inp["vectors"],
+            jnp.asarray(fresh), jnp.asarray(alive), interpret=True)
         return linear_state_rows(o, new[layer])
 
     def oracle(inp):
-        ref = np.asarray(linear_state_reference(
-            jnp.asarray(inp["state"]), layer, inp["q"], inp["k"], inp["v"],
-            inp["g"], inp["beta"], jnp.asarray(fresh), jnp.asarray(alive)))
+        ref = np.asarray(reference(
+            jnp.asarray(inp["state"]), layer, *inp["vectors"],
+            jnp.asarray(fresh), jnp.asarray(alive)))
         live = np.broadcast_to(alive[:, None], ref.shape).copy()
         zero = np.zeros(ref.shape, bool)
-        zero[~alive, :h * d] = True
+        # the columns of ``o``: the row less one slot's state
+        zero[~alive, :ref.shape[1] - math.prod(state_shape[2:])] = True
         return ref, live, zero
 
-    def pricing():
-        return linear_state_cost(b, h, d, d)
-
     return {
-        "name": "state-step", "kernel": "linear_state_update",
-        "mode": "interpret", "atol": 1e-5,
+        "name": name, "kernel": kernel, "mode": "interpret", "atol": 1e-5,
         "build": build, "run": run, "oracle": oracle, "pricing": pricing,
     }
+
+
+def _linear_state_case() -> dict:
+    """The delta rule's: eight heads of 128 x 128."""
+    np = _np()
+    b, h, d = 4, 8, 128
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+
+    def vectors(rng):
+        return (unit(rng.normal(size=(b, h, d))) * d ** -0.5,
+                unit(rng.normal(size=(b, h, d))),
+                rng.normal(size=(b, h, d)),
+                -0.5 * rng.random(size=(b, h, d)),
+                2 / (1 + np.exp(-rng.normal(size=(b, h)))))
+
+    return _slot_state_case(
+        "state-step", "linear_state_update", vectors, (2, b, h, d, d),
+        linear_state_reference, lambda: linear_state_cost(b, h, d, d))
+
+
+def _ssm_state_case() -> dict:
+    """The state-space recurrence's: 32 heads of 64 x 128 in two groups of B
+    and C, a grid step a group."""
+    b, h, p, n, g = 4, 32, 64, 128, 2
+
+    def vectors(rng):
+        return (rng.normal(size=(b, h, p)), 0.1 * rng.random(size=(b, h)),
+                -rng.uniform(1, 16, size=h), rng.normal(size=(b, g, n)),
+                rng.normal(size=(b, g, n)), rng.normal(size=h))
+
+    return _slot_state_case(
+        "ssm-step", "ssm_state_update", vectors, (2, b, h, p, n),
+        ssm_state_reference, lambda: ssm_state_cost(b, h, p, n, g))
 
 
 def grouped_matmul_reference(xs, w, group_sizes, first_group=0):
@@ -1596,6 +1682,7 @@ def audit_cases() -> list[dict]:
         _latent_dma_case("write"),
         _latent_dma_case("gather"),
         _linear_state_case(),
+        _ssm_state_case(),
         _grouped_matmul_case(),
         _spec_decode_8b(),
         _spec_prefill_8b(),
@@ -1811,6 +1898,29 @@ def probe_linear_state_inputs(layers, slots, heads, d):
             jnp.asarray(at == 1), jnp.asarray(at % 8 != 7))
 
 
+def probe_ssm_state_inputs(layers, slots, heads, p, n, groups):
+    """state [L,B,H,P,N] f32, layer, x [B,H,P], dt [B,H], a_head [H], b, c
+    [B,G,N], d [H], fresh, alive [B] (every eighth slot idle, one starting
+    afresh)."""
+    import jax
+    import jax.numpy as jnp
+
+    np = _np()
+    rng = np.random.default_rng(0)
+    f32 = lambda x: jnp.asarray(x, jnp.float32)
+    at = np.arange(slots)
+    return (jax.random.normal(jax.random.PRNGKey(0),
+                              (layers, slots, heads, p, n), jnp.float32),
+            jnp.int32(layers - 1),
+            f32(rng.normal(size=(slots, heads, p))),
+            f32(0.1 * rng.random(size=(slots, heads))),
+            f32(-rng.uniform(1, 16, size=heads)),
+            f32(rng.normal(size=(slots, groups, n))),
+            f32(rng.normal(size=(slots, groups, n))),
+            f32(rng.normal(size=heads)),
+            jnp.asarray(at == 1), jnp.asarray(at % 8 != 7))
+
+
 def probe_grouped_matmul_inputs(m, layers, e, k, n, rows):
     """xs [m, K], w [L·E, K, N] bf16, group_sizes [E] (``rows`` of the m
     spread over the experts, some with none), the last layer's first group."""
@@ -1830,6 +1940,7 @@ def probe_grouped_matmul_inputs(m, layers, e, k, n, rows):
 _PROBE_BUILDERS = {
     "grouped_expert_matmul": probe_grouped_matmul_inputs,
     "linear_state_update": probe_linear_state_inputs,
+    "ssm_state_update": probe_ssm_state_inputs,
     "mla_masked_prefill": probe_mla_masked_inputs,
     "mla_sparse_attention": probe_mla_sparse_inputs,
     "latent_cache_dma": probe_latent_dma_inputs,

@@ -10,8 +10,10 @@ decay (one number a head and token), B and C shared by the heads of a group:
 
     S_t = exp(a_t) S_{t-1} + (Δ_t x_t) ⊗ B_t;    y_t = S_t C_t + D ⊙ x_t
 
-``ssd_step`` is that, one token a row.  ``ssd_chunk`` is the same map for Q
-tokens of one sequence at once — with G_i = sum_{k<=i} a_k,
+``ssd_step`` is that, one token a row: the CPU's path and the oracle of the
+kernel that a decode over the slot array runs on the TPU
+(ops/pallas/ssm_state.py, chosen by ``step_impl``).  ``ssd_chunk`` is the
+same map for Q tokens of one sequence at once — with G_i = sum_{k<=i} a_k,
 
     y_i = sum_{j<=i} exp(G_i - G_j) (C_i·B_j) Δ_j x_j + exp(G_i) S_in C_i,
     S_out = exp(G_Q) S_in + sum_j exp(G_Q - G_j) (Δ_j x_j) ⊗ B_j
@@ -29,7 +31,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["ssd_step", "ssd_chunk", "ssd_scan"]
+from dynamo_tpu.ops.linear_state import kernel_gate
+
+__all__ = ["ssd_step", "ssd_chunk", "ssd_scan", "step_impl"]
 
 F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -40,11 +44,28 @@ def _heads(t: jax.Array, heads: int) -> jax.Array:
     return jnp.repeat(t, heads // t.shape[-2], axis=-2)
 
 
+def step_impl(heads: int, p: int, n: int, groups: int,
+              state_dtype) -> tuple[str, str]:
+    """``kernel_gate`` for one token a row over the slot array: on the TPU
+    the kernel that reads ``y`` out of a head's matrix while it is in VMEM
+    (ops/pallas/ssm_state.py), elsewhere — and for a geometry the kernel
+    does not tile — ``ssd_step``."""
+    from dynamo_tpu.ops.pallas.ssm_state import state_update_supported
+
+    return kernel_gate(
+        state_update_supported(heads, p, n, groups, state_dtype),
+        f"{heads} heads of {p} x {n} {jnp.dtype(state_dtype)} in {groups} "
+        "groups")
+
+
 def ssd_step(x, dt, a_head, b, c, d, state):
     """One token a row.  x [B, H, P]; dt [B, H] (the step Δ >= 0); a_head
     [H] (A < 0); b, c [B, G, N]; d [H]; state [B, H, P, N] float32 ->
-    (y [B, H, P], state).  Under XLA the state is read once and written
-    once: the read-out is of the state just made, in the same fusion."""
+    (y [B, H, P], state).  The CPU's path and the kernel's oracle: under
+    XLA on the TPU, between a slice of the slot array and its set, the state
+    is read twice and written once — one fusion makes the new state to read
+    ``y`` out of it, a second makes it again to write it (PERF.md §6,
+    PR 52)."""
     x, dt, b, c = (t.astype(F32) for t in (x, dt, b, c))
     h = x.shape[1]
     decay = jnp.exp(dt * a_head.astype(F32))

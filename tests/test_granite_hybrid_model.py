@@ -60,6 +60,50 @@ def test_prefill_in_chunks_then_decode_is_the_reference():
     assert model.state_update_impl()[0] == "xla"
 
 
+def test_a_decode_through_the_state_kernel_is_the_decode_through_xla(
+        monkeypatch):
+    """The decode branch of ``_ssd`` that the TPU takes (the state updated
+    where it lies by ops/pallas/ssm_state.py, here interpreted), against the
+    slice / ``ssd_step`` / set form on the same cache: the live rows'
+    log-probabilities, state and ``conv``, the idle slots bit for bit,
+    ``state_pos`` and the counts equal.  Sixteen heads of 64 x 128: what the
+    kernel tiles."""
+    import functools
+
+    from dynamo_tpu.models import hybrid_linear
+    from dynamo_tpu.ops.pallas.ssm_state import state_update
+
+    model, params = build(dict(TINY, mamba_n_heads=16, mamba_d_head=64,
+                               mamba_d_state=128, mamba_expand=16))
+    assert model.state_update_impl() == ("xla", "backend is cpu")
+    toks = tokens_of(30, 4)
+    cache = fresh_cache(model)
+    _, cache = chunk(model, params, cache, toks, 0, 24, 2, 1)
+    # slot 1 holds what a finished request left; slot 3 starts at position 0
+    cache["state"] = cache["state"].at[:, 1].set(7.0)
+    rows = {2: (24, 1, toks[24]), 3: (0, 30, toks[0])}
+    want_lp, want_cache = decode(model, params, cache, rows)
+    monkeypatch.setattr(model, "state_update_impl", lambda: ("pallas", "test"))
+    monkeypatch.setattr(hybrid_linear, "ssm_state_update",
+                        functools.partial(state_update, interpret=True))
+    got_lp, got_cache = decode(model, params, jax.tree.map(jnp.array, cache),
+                               rows)
+    assert np.abs(got_lp[[2, 3]] - want_lp[[2, 3]]).max() < 1e-4
+    got_s, want_s = (np.asarray(c["state"]) for c in (got_cache, want_cache))
+    assert np.abs(got_s[:, [2, 3]] - want_s[:, [2, 3]]).max() < 1e-5
+    assert np.array_equal(got_s[:, [0, 1]], np.asarray(cache["state"])[:, [0, 1]])
+    # a later layer's inputs carry the earlier layers' rounding
+    assert np.abs(np.asarray(got_cache["conv"])
+                  - np.asarray(want_cache["conv"])).max() < 1e-4
+    assert np.array_equal(np.asarray(got_cache["state_pos"]),
+                          np.asarray(want_cache["state_pos"]))
+    # all but the experts touched (column 3), which count every row's picks:
+    # an idle row's y is zero here and the padding token's there
+    got_n, want_n = (np.delete(np.asarray(c["moe_counts"]), 3, axis=-1)
+                     for c in (got_cache, want_cache))
+    assert np.array_equal(got_n, want_n)
+
+
 @pytest.mark.parametrize("left_out", [
     {"embedding_multiplier": 1}, {"residual_multiplier": 1},
     {"logits_scaling": 1}, {"attention_multiplier": 0.25}],

@@ -1122,15 +1122,16 @@ def test_granite_hybrid_cell_programs_write_the_state_in_place(
     cell's cache and its 64 slots of state): the one attending layer through
     the Pallas GQA kernels at 32/8 heads, the experts through the grouped
     matmul at K 4096 / N 768 (8.9 rows an expert in decode, 71 in a chunk),
-    one scan a run of layers, the state-space recurrence under XLA with no
-    custom call of its own, every leaf of the cache donated and written in
-    place, and weights + state + K/V inside the chip (~12.8 GB)."""
+    one scan a run of layers, every leaf of the cache donated and written in
+    place, and weights + state + K/V inside the chip (~12.8 GB).  The decode
+    program updates the state by the kernel of ops/pallas/ssm_state.py; a
+    chunk's recurrence stays XLA's, with no custom call of its own."""
     hf, cfg, model, params, cache, sds = _abstract_model(
         "granite-4.0-h-small-ep2.json",
         lambda spec: SingleDeviceSharding(topo.devices[0]))
     serve = dict(hf["serve"])
     assert hf["attention_layers"] == len(cfg.gqa_layers) == 1
-    assert model.state_update_impl()[0] == "xla"
+    assert model.state_update_impl() == ("pallas", "tpu")
     fn, args = _step_program(program, model, serve, sds, prefix_blocks=16)
     if program == "prefill":        # the engine names the row's slot
         from dynamo_tpu.engine.core import unified_step
@@ -1146,11 +1147,37 @@ def test_granite_hybrid_cell_programs_write_the_state_in_place(
     assert len(_grouped_matmul_calls(hlo)) == 2 * 3     # m x5 | A | m x4
     assert "ragged-dot" not in hlo
     assert "linear_state_update" not in hlo
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "ssm_state_update" in line]
     # m x5 | m x4: the run of one attending layer is a scan of one step,
     # which XLA inlines (a chunk's two pieces are a scan of their own)
     assert hlo.count(" while(") == (2 if program == "decode" else 4)
     # the state is sliced and updated where it lies, never copied whole
     assert not re.search(r"f32\[9,64,128,64,128\]\S* copy\(", hlo)
+    if program == "decode":
+        # ... and by one kernel a Mamba layer, inside the scope that
+        # kernel.ssm_state_roofline reads (readers/scope_roofline.py): no
+        # XLA pass over a layer's 268 MB is left in the program
+        assert len(calls) == 2                     # m x5 | m x4
+        for line in calls:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "ssm_state" in op_name.split("/"), op_name
+        assert "f32[64,128,64,128]" not in hlo
+        # the convolution's tails are not carried through the layer scan
+        # in VMEM (what Solar's program did once its state's slice and set
+        # were gone: test_hybrid_linear_cell_programs_...)
+        assert not re.search(
+            r"bf16\[9,64,3,8448\]\{[\d,]*:[^}]*S\(1\)\}", hlo)
+        from dynamo_tpu.ops.pallas import registry
+
+        # 32 heads' matrices a grid step, double buffered in and out: 4 MiB
+        # of the 16 the compiler gives a kernel that asks for no more
+        group = registry.ssm_state_heads_per_step(128, 1, 64, 128)
+        assert group * 64 * 128 * 4 == registry.SSM_STATE_BLOCK_BYTES
+        assert (2 * registry.DOUBLE_BUFFER * registry.SSM_STATE_BLOCK_BYTES
+                == registry.SCOPED_VMEM_BYTES // 4)
+    else:
+        assert not calls
     mem = compiled.memory_analysis()
     nbytes = lambda a: a.size * a.dtype.itemsize
     state, pool = nbytes(cache["state"]), nbytes(cache["kv"])
