@@ -317,6 +317,20 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(tuple(shape), dtype)
 
 
+def _operands(*host):
+    """What ``EngineCore._upload_dispatch`` hands a serving call for host
+    arrays of these shapes (``None``: where the impl takes its key; the
+    buffer's first word says which of the key block's it is): the one
+    buffer's shape, and the static layout that takes it apart."""
+    import numpy as np
+
+    from dynamo_tpu.engine import operands
+
+    bufs, layout = operands.pack((np.zeros((), np.int32), tuple(
+        a if a is None else np.zeros(a.shape, a.dtype) for a in host), {}))
+    return tuple(_sds(b.shape, b.dtype) for b in bufs), layout
+
+
 def _tiny_model_config():
     from dynamo_tpu.models.config import ModelConfig
 
@@ -363,20 +377,21 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
         )
     )
     i32, f32 = jnp.int32, jnp.float32
-    rng = _sds((2,), jnp.uint32)
+    keys = _sds(core._keys.shape, core._keys.dtype)
     pb_axis = [0] + _pow2s_upto(m)
     min_elems = model_cfg.hidden_size
     eps: list[Entrypoint] = []
 
     def build_step(s_bucket, prefix_blocks):
-        args = (params, cache,
-                _sds((1, s_bucket), i32), _sds((1, s_bucket), i32),
-                _sds((1, m), i32), _sds((1,), i32),
-                _sds((1, s_bucket), i32), _sds((1,), i32), rng,
-                _sds((1,), f32), _sds((1,), i32), _sds((1,), f32))
+        bufs, layout = _operands(
+            _sds((1, s_bucket), i32), _sds((1, s_bucket), i32),
+            _sds((1, m), i32), _sds((1,), i32),
+            _sds((1, s_bucket), i32), _sds((1,), i32), None,
+            _sds((1,), f32), _sds((1,), i32), _sds((1,), f32))
         return Signature(
-            f"s={s_bucket},pb={prefix_blocks}", args,
-            dict(prefix_blocks=prefix_blocks, k_cand=K_MAX, exact=False),
+            f"s={s_bucket},pb={prefix_blocks}", (params, cache, keys, bufs),
+            dict(layout=layout, prefix_blocks=prefix_blocks, k_cand=K_MAX,
+                 exact=False),
         )
 
     eps.append(Entrypoint(
@@ -384,7 +399,7 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
         axes={"s_bucket": list(cfg.prefill_buckets),
               "prefix_blocks": pb_axis},
         build=build_step,
-        jit_fn=core._step_fn, raw_fn=core._step_impl,
+        jit_fn=core._step_fn, raw_fn=core._step_fn.__wrapped__,
         donate_argnums=(1,),
         representatives=[
             dict(s_bucket=cfg.prefill_buckets[-1], prefix_blocks=0),
@@ -394,14 +409,14 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
     ))
 
     def build_multi(num_steps):
-        args = (params, cache,
-                _sds((b,), i32), _sds((b,), i32), _sds((b, m), i32),
-                _sds((b,), i32), _sds((b,), i32), rng,
-                _sds((b,), f32), _sds((b,), i32), _sds((b,), f32))
+        bufs, layout = _operands(
+            _sds((b,), i32), _sds((b,), i32), _sds((b, m), i32),
+            _sds((b,), i32), _sds((b,), i32), None,
+            _sds((b,), f32), _sds((b,), i32), _sds((b,), f32))
         return Signature(
-            f"k={num_steps}", args,
-            dict(num_steps=num_steps, k_cand=K_MAX, exact=False,
-                 use_penalties=False),
+            f"k={num_steps}", (params, cache, keys, bufs),
+            dict(layout=layout, num_steps=num_steps, k_cand=K_MAX,
+                 exact=False, use_penalties=False),
         )
 
     bursts = sorted({cfg.interactive_decode_steps, max(1, cfg.decode_steps)})
@@ -409,7 +424,7 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
         name=f"engine.decode_multi[{tag}]",
         axes={"num_steps": bursts},
         build=build_multi,
-        jit_fn=core._multi_fn, raw_fn=core._multi_impl,
+        jit_fn=core._multi_fn, raw_fn=core._multi_fn.__wrapped__,
         donate_argnums=(1,),
         representatives=[dict(num_steps=bursts[-1])],
         upcast_min_elems=min_elems,
@@ -419,24 +434,36 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
         s = cfg.spec_tokens + 1
 
         def build_spec(m_used):
-            args = (params, cache,
-                    _sds((b, s), i32), _sds((b, s), i32),
-                    _sds((b, m_used), i32), _sds((b,), i32),
-                    _sds((b, s), i32), rng,
-                    _sds((b,), f32), _sds((b,), i32), _sds((b,), f32),
-                    _sds((b,), f32), _sds((b,), i32), _sds((b,), bool))
-            return Signature(f"m_used={m_used}", args,
-                             dict(k_cand=K_MAX, exact=False))
+            bufs, layout = _operands(
+                _sds((b, s), i32), _sds((b, s), i32),
+                _sds((b, m_used), i32), _sds((b,), i32),
+                _sds((b, s), i32), None,
+                _sds((b,), f32), _sds((b,), i32), _sds((b,), f32),
+                _sds((b,), f32), _sds((b,), i32), _sds((b,), bool))
+            return Signature(f"m_used={m_used}", (params, cache, keys, bufs),
+                             dict(layout=layout, k_cand=K_MAX, exact=False))
 
         eps.append(Entrypoint(
             name=f"engine.spec_verify[{tag}]",
             axes={"m_used": _pow2s_upto(m)},
             build=build_spec,
-            jit_fn=core._spec_fn, raw_fn=core._spec_impl,
+            jit_fn=core._spec_fn, raw_fn=core._spec_fn.__wrapped__,
             donate_argnums=(1,),
             representatives=[dict(m_used=_pow2s_upto(m)[-1])],
             upcast_min_elems=min_elems,
         ))
+
+    def flat_axis_operands(t_bucket, r_pad):
+        """Of a ragged prefill and of a unified dispatch: rows of several
+        sequences on one token axis."""
+        return _operands(
+            _sds((1, t_bucket), i32), _sds((1, t_bucket), i32),
+            _sds((r_pad, m), i32), _sds((r_pad,), i32),
+            _sds((1, t_bucket), i32), _sds((1, t_bucket), i32),
+            _sds((r_pad,), i32), _sds((r_pad,), i32),
+            _sds((r_pad,), i32), None,
+            _sds((r_pad,), f32), _sds((r_pad,), i32),
+            _sds((r_pad,), f32))
 
     if cfg.prefill_token_budget > 0 and getattr(
             model, "supports_ragged_prefill", False):
@@ -451,18 +478,12 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
             min_rows = r_pad // 2 + 1 if r_pad > 1 else 1
             if min_rows * bs > t_bucket:
                 return None
-            args = (params, cache,
-                    _sds((1, t_bucket), i32), _sds((1, t_bucket), i32),
-                    _sds((r_pad, m), i32), _sds((r_pad,), i32),
-                    _sds((1, t_bucket), i32), _sds((1, t_bucket), i32),
-                    _sds((r_pad,), i32), _sds((r_pad,), i32),
-                    _sds((r_pad,), i32), rng,
-                    _sds((r_pad,), f32), _sds((r_pad,), i32),
-                    _sds((r_pad,), f32))
+            bufs, layout = flat_axis_operands(t_bucket, r_pad)
             return Signature(
-                f"t={t_bucket},r={r_pad},pb={prefix_blocks}", args,
-                dict(prefix_blocks=prefix_blocks, k_cand=K_MAX,
-                     exact=False),
+                f"t={t_bucket},r={r_pad},pb={prefix_blocks}",
+                (params, cache, keys, bufs),
+                dict(layout=layout, prefix_blocks=prefix_blocks,
+                     k_cand=K_MAX, exact=False),
             )
 
         eps.append(Entrypoint(
@@ -470,7 +491,7 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
             axes={"t_bucket": t_axis, "r_pad": r_axis,
                   "prefix_blocks": pb_axis},
             build=build_ragged,
-            jit_fn=core._ragged_fn, raw_fn=core._ragged_impl,
+            jit_fn=core._ragged_fn, raw_fn=core._ragged_fn.__wrapped__,
             donate_argnums=(1,),
             representatives=[
                 dict(t_bucket=t_axis[-1], r_pad=r_axis[-1],
@@ -499,18 +520,12 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
             min_rows = r_pad // 2 + 1 if r_pad > 1 else 1
             if min_rows > b or (t_bucket - d_region) // bs < 1:
                 return None
-            args = (params, cache,
-                    _sds((1, t_bucket), i32), _sds((1, t_bucket), i32),
-                    _sds((r_pad, m), i32), _sds((r_pad,), i32),
-                    _sds((1, t_bucket), i32), _sds((1, t_bucket), i32),
-                    _sds((r_pad,), i32), _sds((r_pad,), i32),
-                    _sds((r_pad,), i32), rng,
-                    _sds((r_pad,), f32), _sds((r_pad,), i32),
-                    _sds((r_pad,), f32))
+            bufs, layout = flat_axis_operands(t_bucket, r_pad)
             return Signature(
-                f"t={t_bucket},r={r_pad},pb={prefix_blocks}", args,
-                dict(row_tokens=d_region, prefix_blocks=prefix_blocks,
-                     k_cand=K_MAX, exact=False),
+                f"t={t_bucket},r={r_pad},pb={prefix_blocks}",
+                (params, cache, keys, bufs),
+                dict(layout=layout, row_tokens=d_region,
+                     prefix_blocks=prefix_blocks, k_cand=K_MAX, exact=False),
             )
 
         eps.append(Entrypoint(
@@ -518,7 +533,7 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
             axes={"t_bucket": tu_axis, "r_pad": ru_axis,
                   "prefix_blocks": pb_axis},
             build=build_unified,
-            jit_fn=core._unified_fn, raw_fn=core._unified_impl,
+            jit_fn=core._unified_fn, raw_fn=core._unified_fn.__wrapped__,
             donate_argnums=(1,),
             representatives=[
                 dict(t_bucket=tu_axis[-1], r_pad=ru_axis[-1],

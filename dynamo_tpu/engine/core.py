@@ -305,15 +305,41 @@ def expert_totals(moe_counts):
     return moe_counts.sum(axis=(0, 1))
 
 
-@functools.partial(jax.jit, static_argnames=("layout",))
-def operand_prologue(key, bufs, *, layout):
-    """Under a mesh, ahead of every serving program: the next key of the
-    chain, this dispatch's key, and the dispatch's small operands out of
-    their two buffers (``EngineCore._upload_dispatch``) — one small program
-    a dispatch where ``jax.random.split`` and the unpacking of its result
-    were two."""
-    key, dispatch_key = jax.random.split(key)
-    return key, dispatch_key, operands.unpack(bufs, layout)
+# dispatches a refill of the engine's key block serves
+KEY_BLOCK = 256
+
+
+def key_block(key, length):
+    """``length`` steps of ``jax.random.split``'s chain at once: the key
+    they leave and the keys they draw, one a dispatch, in order.  The split
+    is some hundred operations to trace and lower wherever it stands: in
+    every serving program it was 0.1 s of set-up a program (PERF.md, PR 55),
+    here it is lowered once an engine."""
+    return jax.lax.scan(lambda key, _: tuple(jax.random.split(key)), key,
+                        length=length)
+
+
+def packed(impl):
+    """``impl`` as the jitted serving calls take it: ``(*resident, keys,
+    bufs, layout=, **kw)`` for ``impl(*resident, *operands, **operand_kw,
+    **kw)``.  ``bufs`` is a dispatch's small operands as one transfer
+    (``EngineCore._upload_dispatch``): the program takes it apart itself,
+    and hands ``impl`` the dispatch's key, which it reads from the engine's
+    key block at the place the buffer says, where the operands hold
+    ``None``.  No program runs ahead of the serving one but ``key_block``,
+    once in ``KEY_BLOCK`` dispatches.  The name stays ``impl``'s, and with
+    it the compiled module's (``jit__step_impl``: the benchmark finds
+    programs by it)."""
+
+    def serve(*args, layout, **kw):
+        *resident, keys, bufs = args
+        key_at, ops, ops_kw = operands.unpack(bufs, layout)
+        key = jax.lax.dynamic_index_in_dim(keys, key_at, keepdims=False)
+        ops = (key if a is None else a for a in ops)
+        return impl(*resident, *ops, **ops_kw, **kw)
+
+    serve.__name__, serve.__qualname__ = impl.__name__, impl.__qualname__
+    return serve
 
 
 @dataclasses.dataclass
@@ -561,17 +587,24 @@ class EngineCore:
         # of its cache's ``moe_counts`` (read back with each dispatch)
         self._device_count_keys = getattr(model, "moe_count_keys", ())
 
-        # where a dispatch's small operands go under a mesh
-        # (``_upload_dispatch``): replicated over it, the layout the jitted
-        # serving calls are compiled for, so that a call re-lays nothing
-        # out; None with no mesh (the default device, uncommitted)
+        # where a dispatch's small operands go (``_upload_dispatch``):
+        # replicated over the mesh, the layout the jitted serving calls are
+        # compiled for, so that a call re-lays nothing out; None with no
+        # mesh (the default device, uncommitted)
         self._operand_sharding = None if mesh is None else (
             jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()))
 
-        # an operand like the others: under a mesh it lives where they go,
-        # and is split there by the program that takes the two buffers apart
-        self._rng = jax.device_put(
-            jax.random.PRNGKey(config.seed), self._operand_sharding)
+        # the engine's keys: ``jax.random.split``'s chain from the seed, a
+        # key a dispatch, drawn ``KEY_BLOCK`` at a time into ``_keys``,
+        # which lives where the operands go; a dispatch's buffer says which
+        # of them is its own (``_next_key``)
+        self._key_block = jax.jit(
+            key_block, static_argnames="length",
+            out_shardings=self._operand_sharding)
+        self._rng, self._keys = self._key_block(jax.device_put(
+            jax.random.PRNGKey(config.seed), self._operand_sharding),
+            length=KEY_BLOCK)
+        self._key_at = 0
 
         def under_mesh(impl):
             """``impl`` traced with the engine's mesh in scope: the
@@ -588,28 +621,32 @@ class EngineCore:
 
             return traced
 
+        # the jitted entry points: each impl behind the one transfer of its
+        # operands (``packed``): ``(params, cache, keys, bufs, layout=,
+        # ...)`` -> the impl's results
         self._step_fn = jax.jit(
-            under_mesh(self._step_impl), donate_argnums=(1,),
-            static_argnames=("prefix_blocks", "k_cand", "exact"),
+            under_mesh(packed(self._step_impl)), donate_argnums=(1,),
+            static_argnames=("layout", "prefix_blocks", "k_cand", "exact"),
         )
         self._multi_fn = jax.jit(
-            under_mesh(self._multi_impl), donate_argnums=(1,),
-            static_argnames=("num_steps", "k_cand", "exact", "use_penalties"),
+            under_mesh(packed(self._multi_impl)), donate_argnums=(1,),
+            static_argnames=("layout", "num_steps", "k_cand", "exact",
+                             "use_penalties"),
         )
         self._spec_fn = jax.jit(
-            under_mesh(self._spec_impl), donate_argnums=(1,),
-            static_argnames=("k_cand", "exact"),
+            under_mesh(packed(self._spec_impl)), donate_argnums=(1,),
+            static_argnames=("layout", "k_cand", "exact"),
         )
         self._ragged_fn = jax.jit(
-            under_mesh(self._ragged_impl), donate_argnums=(1,),
-            static_argnames=("prefix_blocks", "k_cand", "exact"),
+            under_mesh(packed(self._ragged_impl)), donate_argnums=(1,),
+            static_argnames=("layout", "prefix_blocks", "k_cand", "exact"),
         )
         # the fifth donated serving impl: unified mixed prefill+decode
         # dispatch (decode rows + prefill spans on one flat token axis)
         self._unified_fn = jax.jit(
-            under_mesh(self._unified_impl), donate_argnums=(1,),
-            static_argnames=("row_tokens", "prefix_blocks", "k_cand",
-                             "exact"),
+            under_mesh(packed(self._unified_impl)), donate_argnums=(1,),
+            static_argnames=("layout", "row_tokens", "prefix_blocks",
+                             "k_cand", "exact"),
         )
         # sequence-parallel long-prefill (ring attention over the "data"
         # axis): one dispatch computes the whole prompt with the sequence
@@ -634,7 +671,8 @@ class EngineCore:
                 )
             self._sp_size = mesh.shape[AXIS_DATA]
             self._sp_fn = jax.jit(
-                self._sp_impl, static_argnames=("nb", "k_cand", "exact")
+                packed(self._sp_impl),
+                static_argnames=("layout", "nb", "k_cand", "exact"),
             )
 
         self.slots: list[Optional[EngineRequest]] = [None] * config.max_batch_size
@@ -1123,43 +1161,44 @@ class EngineCore:
             exact = True
         return k_cand, exact
 
+    def _next_key(self):
+        """Where in ``self._keys`` the next dispatch's key lies: the chain
+        moves one split a dispatch, whatever the dispatch is."""
+        if self._key_at == len(self._keys):
+            self._rng, self._keys = self._key_block(
+                self._rng, length=len(self._keys))
+            self._key_at = 0
+        at = self._key_at
+        self._key_at += 1
+        return np.asarray(at, np.int32)
+
     def _upload_dispatch(self, host_args, gkw=None):
-        """ONE batched host->device upload for a dispatch's small
-        operands — positional AND grammar/extras rows (per-array
-        jnp.asarray would issue a transfer round trip each; per-transfer
-        latency is the cost that matters on a remote-attached chip) — and
-        the dispatch's key, the next of ``jax.random.split``'s chain.
+        """ONE host->device transfer a device for a dispatch's small
+        operands, positional AND grammar/extras rows: what the host pays
+        for a put does not depend on its size, a put per array was most of
+        a launch (on one chip 3.5-4.8 ms of a turn for nine arrays; under a
+        mesh each array is a transfer *a device*, and an uncommitted one is
+        re-laid out inside the jitted call on pjit's slow path).  The
+        arrays travel as one int32 buffer (``operands.pack``), put once,
+        from the host, to where the serving program wants them (replicated
+        over the mesh, or the default device); the program takes the
+        buffer apart itself (``packed``).  ``None`` among ``host_args``
+        marks where the impl takes the dispatch's key; the buffer's first
+        word says where in ``self._keys`` that is.
 
-        Under a mesh each array is one transfer *a device*, and an
-        uncommitted array on device 0 is re-laid out over the mesh inside
-        every jitted serving call, argument by argument, on pjit's slow
-        path (on four chips 3 ms of a 25 ms turn with the devices idle).
-        So there the operands travel as two buffers (``operands.pack``: the
-        int32/bool arrays as one, the float32 ones as another), put once,
-        from the host, replicated; ``operand_prologue`` takes them apart on
-        the devices and draws the key, and every operand of the serving
-        call arrives committed in the layout its executable was compiled
-        for.  With no mesh two transfers for nine gain nothing end to end
-        (PERF.md, PRs 32 and 33) and the tree goes up as it is.
-
-        Returns (device_args tuple, the dispatch's key, gkw with its host
-        arrays replaced)."""
-        gkw = dict(gkw or {})
-        host_kw = {k: v for k, v in gkw.items() if isinstance(v, np.ndarray)}
-        tree = (tuple(np.asarray(a) for a in host_args), host_kw)
-        if self.mesh is None:
-            self._rng, rng = jax.random.split(self._rng)
-            up, up_kw = jax.device_put(tree)
-            put = len(tree[0]) + len(host_kw)
-        else:
-            bufs, layout = operands.pack(tree)
-            bufs = jax.device_put(bufs, self._operand_sharding)
-            self._rng, rng, (up, up_kw) = operand_prologue(
-                self._rng, bufs, layout=layout)
-            put = len(bufs) * self.mesh.size
-        self.counts.operand_buffers_total += put
-        gkw.update(up_kw)
-        return up, rng, gkw
+        Returns (the buffers, their static layout, gkw without its host
+        arrays: what is left lives on the device already)."""
+        host_kw, device_kw = {}, {}
+        for k, v in (gkw or {}).items():
+            (host_kw if isinstance(v, np.ndarray) else device_kw)[k] = v
+        bufs, layout = operands.pack((
+            self._next_key(),
+            tuple(a if a is None else np.asarray(a) for a in host_args),
+            host_kw))
+        bufs = jax.device_put(bufs, self._operand_sharding)
+        self.counts.operand_buffers_total += (
+            len(bufs) * self.counts.mesh_devices)
+        return bufs, layout, device_kw
 
     def _run_step(self, tokens, positions, block_tables, seq_lens, slot_idx,
                   last_idx, temp, top_k, top_p, prefix_blocks=None,
@@ -1173,22 +1212,20 @@ class EngineCore:
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
         step_timeline.enter("upload", carried=carried)
-        up, rng, gkw = self._upload_dispatch(
+        bufs, layout, gkw = self._upload_dispatch(
             (tokens, positions, block_tables, seq_lens, slot_idx, last_idx,
-             temp, top_k, top_p), gkw)
+             None, temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="step")
         self._note_issue(reqs)
+        statics = dict(layout=layout, prefix_blocks=prefix_blocks,
+                       k_cand=k_cand, exact=exact)
         if perf_model.wants("step"):
             perf_model.offer(
                 "step", self._step_fn,
-                (self.params, self.cache, *up[:6], rng, *up[6:]), kw=gkw,
-                statics=dict(prefix_blocks=prefix_blocks, k_cand=k_cand,
-                             exact=exact))
+                (self.params, self.cache, self._keys, bufs), kw=gkw,
+                statics=statics)
         out, self.cache = self._step_fn(
-            self.params, self.cache,
-            *up[:6], rng, *up[6:],
-            prefix_blocks=prefix_blocks, k_cand=k_cand, exact=exact, **gkw,
-        )
+            self.params, self.cache, self._keys, bufs, **statics, **gkw)
         self.steps += 1
         return out
 
@@ -1201,7 +1238,7 @@ class EngineCore:
         device.  Rows marked in ``carry_rows`` start from the last sample
         of the decode in flight (``multi_decode_step``)."""
         use_pen = pen is not None
-        host = [tokens, positions, block_tables, seq_lens, limits,
+        host = [tokens, positions, block_tables, seq_lens, limits, None,
                 temp, top_k, top_p] + (list(pen) if use_pen else [])
         gkw = self._gram_kwargs(gram)
         gkw.update(extras or {})
@@ -1210,21 +1247,17 @@ class EngineCore:
             self._carry_operand(self._inflight.out[0]) if carry_rows.any()
             else self._no_carry)
         step_timeline.enter("upload", carried=carried)
-        up, rng, gkw = self._upload_dispatch(host, gkw)
+        bufs, layout, gkw = self._upload_dispatch(host, gkw)
         step_timeline.enter("dispatch", kind="decode_multi")
-        up = list(up)
-        args = up[:5] + [rng] + up[5:]
+        statics = dict(layout=layout, num_steps=num_steps, k_cand=k_cand,
+                       exact=exact, use_penalties=use_pen)
         if perf_model.wants("decode_multi"):
             perf_model.offer(
                 "decode_multi", self._multi_fn,
-                (self.params, self.cache, *args), kw=gkw,
-                statics=dict(num_steps=num_steps, k_cand=k_cand,
-                             exact=exact, use_penalties=use_pen))
+                (self.params, self.cache, self._keys, bufs), kw=gkw,
+                statics=statics)
         out, self.cache = self._multi_fn(
-            self.params, self.cache, *args,
-            num_steps=num_steps, k_cand=k_cand, exact=exact,
-            use_penalties=use_pen, **gkw,
-        )
+            self.params, self.cache, self._keys, bufs, **statics, **gkw)
         self.steps += 1
         return out
 
@@ -2015,21 +2048,20 @@ class EngineCore:
         take_sum = sum(take for _, take, _ in sel)
         carried = self._carried(r_real, take_sum, seq_lens)
         step_timeline.enter("upload", carried=carried)
-        up, rng, gkw = self._upload_dispatch(
+        bufs, layout, gkw = self._upload_dispatch(
             (tokens, positions, bt, seq_lens, slot_idx, seq_ids, starts,
-             roff, last_idx, temp, top_k, top_p), gkw)
+             roff, last_idx, None, temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="prefill_ragged")
         self._note_issue(req for req, _, _ in sel)
+        statics = dict(layout=layout, prefix_blocks=pb, k_cand=k_cand,
+                       exact=exact)
         if perf_model.wants("prefill_ragged"):
             perf_model.offer(
                 "prefill_ragged", self._ragged_fn,
-                (self.params, self.cache, *up[:9], rng, *up[9:]), kw=gkw,
-                statics=dict(prefix_blocks=pb, k_cand=k_cand,
-                             exact=exact))
+                (self.params, self.cache, self._keys, bufs), kw=gkw,
+                statics=statics)
         out, self.cache = self._ragged_fn(
-            self.params, self.cache, *up[:9], rng, *up[9:],
-            prefix_blocks=pb, k_cand=k_cand, exact=exact, **gkw,
-        )
+            self.params, self.cache, self._keys, bufs, **statics, **gkw)
         self.steps += 1
         self.prefill_steps += 1
         self._count_prefill(rows=r_real, tokens=take_sum, budget=budget)
@@ -2238,22 +2270,20 @@ class EngineCore:
         take_sum = sum(take for _, take, _ in sel)
         step_timeline.enter("upload", carried=self._carried(
             r_real, n_dec + take_sum, seq_lens))
-        up, rng, gkw = self._upload_dispatch(
+        bufs, layout, gkw = self._upload_dispatch(
             (tokens, positions, bt, seq_lens, slot_idx, seq_ids, starts,
-             roff, last_idx, temp, top_k, top_p), gkw)
+             roff, last_idx, None, temp, top_k, top_p), gkw)
         step_timeline.enter("dispatch", kind="unified")
         self._note_issue(req for req, _, _ in sel)
+        statics = dict(layout=layout, row_tokens=d_region, prefix_blocks=pb,
+                       k_cand=k_cand, exact=exact)
         if perf_model.wants("unified"):
             perf_model.offer(
                 "unified", self._unified_fn,
-                (self.params, self.cache, *up[:9], rng, *up[9:]), kw=gkw,
-                statics=dict(row_tokens=d_region, prefix_blocks=pb,
-                             k_cand=k_cand, exact=exact))
+                (self.params, self.cache, self._keys, bufs), kw=gkw,
+                statics=statics)
         out, self.cache = self._unified_fn(
-            self.params, self.cache, *up[:9], rng, *up[9:],
-            row_tokens=d_region, prefix_blocks=pb, k_cand=k_cand,
-            exact=exact, **gkw,
-        )
+            self.params, self.cache, self._keys, bufs, **statics, **gkw)
         step_timeline.enter("readback")
         sampled, lps, cids, clps = jax.device_get(out)  # one batched pull
         self.counts.device_gets_total += 1
@@ -2402,24 +2432,21 @@ class EngineCore:
         k_cand, exact = self._sampling_mode([req])
         step_timeline.enter("upload", carried=self._carried(
             1, req.prompt_len, last_idx + 1))
-        up, rng, _ = self._upload_dispatch((
-            tokens, positions, last_idx,
+        bufs, layout, _ = self._upload_dispatch((
+            tokens, positions, last_idx, None,
             np.asarray([req.sampling.temperature], np.float32),
             np.asarray([req.sampling.top_k], np.int32),
             np.asarray([req.sampling.top_p], np.float32),
         ))
         step_timeline.enter("dispatch", kind="sp_prefill")
         self._note_issue((req,))
+        statics = dict(layout=layout, nb=nb_pad, k_cand=k_cand, exact=exact)
         if perf_model.wants("sp_prefill"):
             perf_model.offer(
-                "sp_prefill", self._sp_fn,
-                (self.params, up[0], up[1], up[2], rng, up[3], up[4],
-                 up[5]),
-                statics=dict(nb=nb_pad, k_cand=k_cand, exact=exact))
+                "sp_prefill", self._sp_fn, (self.params, self._keys, bufs),
+                statics=statics)
         (sampled, lps, cids, clps), blocks = self._sp_fn(
-            self.params, up[0], up[1], up[2], rng, up[3], up[4], up[5],
-            nb=nb_pad, k_cand=k_cand, exact=exact,
-        )
+            self.params, self._keys, bufs, **statics)
         step_timeline.enter("readback")
         sampled, lps, cids, clps = jax.device_get(
             (sampled, lps, cids, clps))  # one batched transfer
@@ -2588,20 +2615,17 @@ class EngineCore:
         k_cand, exact = self._sampling_mode(rows)
         step_timeline.enter("upload", carried=self._carried(
             len(rows), len(rows) * s, seq_lens))
-        up, rng, _ = self._upload_dispatch(
-            (tokens, positions, bt[:, :m_used], seq_lens, slot_idx,
+        bufs, layout, _ = self._upload_dispatch(
+            (tokens, positions, bt[:, :m_used], seq_lens, slot_idx, None,
              temp, top_k, top_p, min_p, seeds, seed_rows))
         step_timeline.enter("dispatch", kind="spec_verify")
+        statics = dict(layout=layout, k_cand=k_cand, exact=exact)
         if perf_model.wants("spec_verify"):
             perf_model.offer(
                 "spec_verify", self._spec_fn,
-                (self.params, self.cache, *up[:5], rng, *up[5:]),
-                statics=dict(k_cand=k_cand, exact=exact))
+                (self.params, self.cache, self._keys, bufs), statics=statics)
         verified, self.cache = self._spec_fn(
-            self.params, self.cache,
-            *up[:5], rng, *up[5:],
-            k_cand=k_cand, exact=exact,
-        )
+            self.params, self.cache, self._keys, bufs, **statics)
         step_timeline.enter("readback")
         verified = jax.device_get(verified)
         self.counts.device_gets_total += 1

@@ -272,8 +272,8 @@ REQUEST_FAMILY = (
            "issue): why the ahead share is not 1"),
     _count("dynamo_tpu_engine_operand_buffers_total", "counter",
            "host->device buffers the dispatches' operands took, buffers put "
-           "x devices put to: over prefill + decode dispatches, 2 x devices "
-           "under a mesh, the number of arrays (9) with none"),
+           "x devices put to: over prefill + decode dispatches, 1 x the "
+           "devices (one packed buffer a dispatch)"),
     _count("dynamo_tpu_engine_prompt_tokens_admitted_total", "counter",
            "prompt tokens of requests whose prefill completed"),
     _count("dynamo_tpu_engine_prompt_tokens_cached_total", "counter",

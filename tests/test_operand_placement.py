@@ -1,15 +1,18 @@
-"""A dispatch's operands arrive where the program wants them (PRs 32, 33).
+"""A dispatch's operands arrive where the program wants them, in one
+transfer (PRs 32, 33, 55).
 
-Under a mesh ``EngineCore._upload_dispatch`` sends a dispatch's small
-operands as two buffers (``engine/operands.py``), puts them once, from the
-host, to the replicated sharding of the engine's mesh, and has one small
-program take them apart there and draw the dispatch's key; the carry and
-the grammar tables live in the same layout: a jitted serving call then
-finds every operand committed as its executable was compiled for and
-re-lays nothing out (on four chips that re-layout, inside every call, was
-3 ms of a 25 ms turn with the devices idle).  With no mesh the operands are
-what they always were: the plain put of the tree, uncommitted, on the
-default device, and the key split on the host's side of the call."""
+``EngineCore._upload_dispatch`` sends a dispatch's small operands as one
+int32 buffer (``engine/operands.py``), put once, from the host: to the
+replicated sharding of the engine's mesh, or with no mesh to the default
+device.  The jitted serving call takes the buffer apart itself
+(``engine/core.py::packed``) and reads its key out of the engine's key
+block, ``jax.random.split``'s chain drawn ``KEY_BLOCK`` dispatches at a
+time, at the place the buffer says; the block, the carry and the grammar
+tables live in the same layout: a call finds every operand committed as its
+executable was compiled for and re-lays nothing out (on four chips that
+re-layout, inside every call, was 3 ms of a 25 ms turn with the devices
+idle), and no program runs ahead of it but the block's, once in
+``KEY_BLOCK`` dispatches."""
 
 import jax
 import numpy as np
@@ -17,6 +20,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from dynamo_tpu.engine import EngineConfig, EngineCore, operands
+from dynamo_tpu.engine import core as engine_core
 from dynamo_tpu.engine import counters as engine_counters
 from dynamo_tpu.engine.grammar import JsonGrammar
 from dynamo_tpu.engine.request import EngineRequest
@@ -28,7 +32,8 @@ from dynamo_tpu.utils.mesh import build_mesh
 
 EOS = 2
 # the jitted entry point a case watches, the engine that reaches it, and
-# where the key sits among its operands (after params and cache)
+# where its impl takes the dispatch's key among its operands (after params
+# and cache): ``None`` there in what the buffer unpacks to
 CASES = {
     "prefill": ("_step_fn", dict(prefill_chunk_tokens=16), 6),
     "decode": ("_multi_fn", dict(prefill_chunk_tokens=16), 5),
@@ -81,6 +86,20 @@ def watch(core, name, calls=None, *tag):
     return calls
 
 
+def taken_apart(args, kw):
+    """(positional operands, keyword operands) of a watched call, as the
+    program unpacks them from its one buffer."""
+    _, bufs = args
+    return operands.unpack(jax.device_get(bufs), kw["layout"])[1:]
+
+
+def key_of(args, kw):
+    """The key a watched call hands its impl: the one of the engine's key
+    block that the buffer's first word names."""
+    keys, bufs = args
+    return np.asarray(keys)[int(np.asarray(bufs[0])[0])]
+
+
 def prompt(n, seed):
     return [int(t) for t in
             np.random.RandomState(seed).randint(3, 200, size=n)]
@@ -111,12 +130,20 @@ def serve(core):
 
 
 def arrived(calls):
-    """Every array a watched call was handed, and nothing but arrays and
-    the Python scalars of its static arguments."""
+    """Every array a watched call was handed — the key block, ONE int32
+    buffer, and what lives on the device already (the carry, the grammar's
+    tables) — and nothing but arrays and the Python scalars of its static
+    arguments."""
     for args, kw, *_ in calls:
-        leaves = jax.tree.leaves((args, kw))
-        assert len(leaves) >= 10
-        for leaf in leaves:
+        keys, bufs = args
+        assert keys.dtype == np.uint32
+        assert keys.shape == (engine_core.KEY_BLOCK, 2)
+        assert len(bufs) == 1
+        assert bufs[0].dtype == np.int32 and bufs[0].ndim == 1
+        ops, ops_kw = taken_apart(args, kw)
+        assert len(ops) + len(ops_kw) >= 10
+        kw = {k: v for k, v in kw.items() if k != "layout"}
+        for leaf in jax.tree.leaves((args, kw)):
             if isinstance(leaf, (bool, int)):
                 continue            # prefix_blocks, k_cand, exact, ...
             # a host array here would be uploaded inside the call
@@ -136,11 +163,14 @@ def test_every_operand_arrives_replicated_over_the_mesh(tiny, mesh, case):
         assert a.committed and a.sharding.is_equivalent_to(
             replicated, a.ndim), (a.shape, a.sharding)
         assert len(a.addressable_shards) == 4
-    assert all(args[at].dtype == np.uint32 for args, _ in calls)
-    kws = set().union(*(kw for _, kw in calls))
+    apart = [taken_apart(args, kw) for args, kw in calls]
+    assert all([a is None for a in ops] == [i == at for i in range(len(ops))]
+               for ops, _ in apart)
+    kws = set().union(*(kw for _, kw in calls),
+                      *(ops_kw for _, ops_kw in apart))
     if case == "decode":
         # a decode with nothing to carry and one that carries: one layout
-        assert {bool(kw["carry_rows"].any()) for _, kw in calls} == {
+        assert {bool(ops_kw["carry_rows"].any()) for _, ops_kw in apart} == {
             False, True}
         assert "carry_tokens" in kws
     if case != "ragged":    # the ragged engine's final chunks ride _step_fn
@@ -148,8 +178,9 @@ def test_every_operand_arrives_replicated_over_the_mesh(tiny, mesh, case):
 
 
 @pytest.mark.parametrize("case", ["prefill", "decode"])
-def test_with_no_mesh_the_operands_are_where_they_were(tiny, case):
-    name, cfg, _ = CASES[case]
+def test_with_no_mesh_it_is_one_buffer_a_dispatch_on_the_default_device(
+        tiny, case):
+    name, cfg, at = CASES[case]
     core = make_core(tiny, None, **cfg)
     calls = watch(core, name)
     serve(core)
@@ -157,14 +188,20 @@ def test_with_no_mesh_the_operands_are_where_they_were(tiny, case):
     default = SingleDeviceSharding(jax.devices()[0])
     for a in arrived(calls):
         assert not a.committed and a.sharding == default
+    assert all(taken_apart(args, kw)[0][at] is None for args, kw in calls)
 
 
 @pytest.mark.parametrize("case", ["decode", "ragged", "unified"])
 @pytest.mark.parametrize("tp", [1, 4])
-def test_the_key_sequence_is_one_split_a_dispatch(tiny, tp, case, request):
-    """Placing the key moves it, it does not change it, and neither does
-    splitting it in one program: dispatch by dispatch, on four devices as
-    on one, the keys are ``jax.random.split``'s chain from the seed."""
+def test_the_key_sequence_is_one_split_a_dispatch(tiny, tp, case, request,
+                                                  monkeypatch):
+    """Placing the keys moves them, it does not change them, and neither
+    does drawing them a block at a time: dispatch by dispatch, on four
+    devices as on one, through chunked prefills and decodes that carry a
+    sample and over several refills of the block, the keys are
+    ``jax.random.split``'s chain from the seed, as they were when the host
+    split one a dispatch."""
+    monkeypatch.setattr(engine_core, "KEY_BLOCK", 5)
     where = request.getfixturevalue("mesh") if tp > 1 else None
     core = make_core(tiny, where, **CASES[case][1])
     calls = []
@@ -173,9 +210,21 @@ def test_the_key_sequence_is_one_split_a_dispatch(tiny, tp, case, request):
     serve(core)
     assert len(calls) >= 12
     chain = jax.random.PRNGKey(core.config.seed)
-    for args, _, at in calls:
+    # what an impl is handed, by a stand-in that gives its operands back
+    probe = engine_core.packed(lambda params, cache, *ops, **kw: ops)
+    for n, (args, kw, at) in enumerate(calls):
+        assert args[0].shape == (5, 2)
         chain, key = jax.random.split(chain)
-        np.testing.assert_array_equal(np.asarray(args[at]), np.asarray(key))
+        np.testing.assert_array_equal(key_of(args, kw), np.asarray(key))
+        if n < 7:
+            ops = probe(None, None, *args, layout=kw["layout"])
+            np.testing.assert_array_equal(np.asarray(ops[at]),
+                                          np.asarray(key))
+    # the chain stands where the last block drawn left it
+    blocks = -(-len(calls) // 5)
+    for _ in range(5 * blocks - len(calls)):
+        chain, _ = jax.random.split(chain)
+    np.testing.assert_array_equal(np.asarray(core._rng), np.asarray(chain))
 
 
 @pytest.mark.parametrize("tp", [1, 4])
@@ -192,10 +241,9 @@ def test_operand_buffers_are_counted_on_metrics_and_on_the_http_render(
     m = core.metrics()
     dispatches = m["prefill_dispatches_total"] + m["decode_dispatches_total"]
     assert dispatches == 1 + 8
-    # under a mesh a dispatch uploads two buffers (its nine small arrays
-    # packed by dtype: one program on the devices takes them apart), each
-    # to every device; with no mesh the nine arrays as they are
-    per_dispatch = 2 * tp if tp > 1 else 9
+    # a dispatch uploads one buffer (its nine small arrays packed: the
+    # serving program takes them apart), to every device of the mesh
+    per_dispatch = tp
     assert m["operand_buffers_total"] == per_dispatch * dispatches
     assert (f"dynamo_tpu_engine_operand_buffers_total "
             f"{per_dispatch * dispatches}\n" in Metrics().render() + "\n")
@@ -224,8 +272,8 @@ def test_decode_kv_blocks_are_counted_on_metrics_and_on_the_http_render(
     # a chunk as long as the rule's cap or the table
     c = min(core._decode_tiling[1], core.config.max_blocks_per_seq)
     assert core._decode_tiling[0] >= 4 and c > 1
-    for args, _ in calls:
-        lens = np.asarray(args[3])        # seq_lens, by slot
+    for args, kw in calls:
+        lens = np.asarray(taken_apart(args, kw)[0][3])  # seq_lens, by slot
         assert lens.shape == (4,) and (lens > 0).sum() in (1, 2)
         blocks = -(-lens // bs)
         walked += int(blocks.sum())
@@ -239,7 +287,8 @@ def test_decode_kv_blocks_are_counted_on_metrics_and_on_the_http_render(
             in text)
 
 
-# ------------------------------------------- the two buffers, taken apart
+# -------------------------------------------- the one buffer, taken apart
+F32 = np.float32
 TREES = {
     "a decode": ((np.arange(4, dtype=np.int32),
                   np.arange(8, dtype=np.int32).reshape(4, 2),
@@ -251,21 +300,43 @@ TREES = {
                                  np.zeros((2, 0), np.int32),
                                  np.ones(2, np.float16)),
                                 {"seeds": np.arange(2, dtype=np.uint8)}),
+    # a float32 travels as its bits: values that an arithmetic round trip
+    # (or a compare-and-rebuild) would not give back
+    "floats whose bits matter": ((np.array(
+        [-0.0, 0.0, 1e-45, -1e-45, 1.1754942e-38, np.inf, -np.inf, np.nan,
+         0.1, -3.4028235e38], F32),
+        np.array([0x7FC00001, 0xFFC12345, 0x00000001, 0x80000000],
+                 np.uint32).view(F32).reshape(2, 2)), {}),
+    "int, bool and float mixed": ((np.array([[-1, 2**31 - 1, -2**31]],
+                                            np.int32),
+                                   None,
+                                   np.array([0.7, -0.0], F32),
+                                   np.array([[True], [False]]),
+                                   np.zeros((0,), F32)),
+                                  {"top_p": np.array(0.9, F32),
+                                   "rows": np.array([False, True, True]),
+                                   "seeds": np.array([5, 0, 1234],
+                                                     np.int32)}),
 }
 
 
 @pytest.mark.parametrize("name", sorted(TREES))
 def test_pack_and_unpack_give_the_tree_back(name):
     tree = TREES[name]
-    (ints, floats, *others), layout = operands.pack(tree)
-    assert ints.dtype == np.int32 and floats.dtype == np.float32
-    assert ints.ndim == floats.ndim == 1 and hash(layout) == hash(layout)
-    packed = {np.dtype(np.int32), np.dtype(bool), np.dtype(np.float32)}
-    assert all(o.dtype not in packed for o in others)
+    (buf, *others), layout = operands.pack(tree)
+    assert buf.dtype == np.int32 and buf.ndim == 1
+    assert hash(layout) == hash(layout)
+    packed_dtypes = {np.dtype(np.int32), np.dtype(bool), np.dtype(F32)}
+    leaves = jax.tree.leaves(tree)
+    assert all(o.dtype not in packed_dtypes for o in others)
+    assert len(others) == sum(a.dtype not in packed_dtypes for a in leaves)
+    assert buf.size == sum(a.size for a in leaves
+                           if a.dtype in packed_dtypes)
     back = jax.jit(operands.unpack, static_argnames="layout")(
-        (ints, floats, *others), layout=layout)
+        (buf, *others), layout=layout)
     want, got = jax.tree.flatten(tree), jax.tree.flatten(back)
     assert want[1] == got[1]
     for a, b in zip(want[0], got[0], strict=True):
         assert a.dtype == b.dtype and a.shape == b.shape
-        np.testing.assert_array_equal(a, np.asarray(b))
+        # bit for bit: -0.0, a denormal, a NaN's payload
+        assert a.tobytes() == np.asarray(b).tobytes()

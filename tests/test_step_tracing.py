@@ -11,7 +11,7 @@ import jax
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine import EngineConfig, EngineCore
+from dynamo_tpu.engine import EngineConfig, EngineCore, operands
 from dynamo_tpu.engine import counters as engine_counters
 from dynamo_tpu.engine.request import EngineRequest
 from dynamo_tpu.llm.protocols import (FinishReason, SamplingOptions,
@@ -439,11 +439,16 @@ def test_model_scopes_reach_the_compiled_program(tiny):
     model, params = tiny
     core = make_core(model, params)
     b, m = 4, core.config.max_blocks_per_seq
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, np.int32)
-    f32 = lambda *s: jax.ShapeDtypeStruct(s, np.float32)
+    i32 = lambda *s: np.zeros(s, np.int32)
+    f32 = lambda *s: np.zeros(s, np.float32)
+    # a decode's operands as the engine sends them: one buffer (its first
+    # word: which key of the block), None where the impl takes its key
+    bufs, layout = operands.pack((
+        i32(), (i32(b), i32(b), i32(b, m), i32(b), i32(b), None, f32(b),
+                i32(b), f32(b)), {}))
     text = core._multi_fn.lower(
-        core.params, core.cache, i32(b), i32(b), i32(b, m), i32(b), i32(b),
-        jax.random.PRNGKey(0), f32(b), i32(b), f32(b), num_steps=1,
+        core.params, core.cache, core._keys, bufs, layout=layout,
+        num_steps=1,
     ).as_text(debug_info=True)
     for scope in ("embed", "attn_proj", "attn", "attn_out", "mlp", "logits",
                   "sample"):

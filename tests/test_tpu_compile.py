@@ -184,6 +184,58 @@ def test_attention_phase_compiles_under_tp4(topo, tpu_gate, phase):
         per_device, cache_bytes)
 
 
+@pytest.mark.parametrize("tp", [1, 4])
+def test_packed_decode_program_compiles(topo, tpu_gate, tp):
+    """PR 55: a decode as the engine jits it — its small operands one int32
+    buffer that the program takes apart, float32 rows out of their bits,
+    its key read out of the key block — compiles for the chip as ONE
+    program named for its impl, the buffer replicated under --tp 4."""
+    from dynamo_tpu.engine import operands
+    from dynamo_tpu.engine.core import KEY_BLOCK, multi_decode_step, packed
+
+    if tp > 1:
+        mesh = Mesh(np.array(topo.devices[:tp]).reshape(1, tp),
+                    ("data", "model"))
+        place = lambda spec: NamedSharding(mesh, spec)
+    else:
+        mesh = None
+        place = lambda spec: SingleDeviceSharding(topo.devices[0])
+    _, cfg, model, params, cache, sds = _abstract_model(
+        "mistral-7b.json", place, N_BLOCKS, num_hidden_layers=2)
+    b = 32
+
+    def _multi_impl(params, cache, *a, **kw):
+        with (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+              if mesh else contextlib.nullcontext()):
+            return multi_decode_step(model, params, cache, *a, num_steps=1,
+                                     block_size=BS, **kw)
+
+    i32 = lambda *shape: np.zeros(shape, np.int32)
+    f32 = lambda *shape: np.zeros(shape, np.float32)
+    bufs, layout = operands.pack((
+        i32(),
+        (i32(b), i32(b), i32(b, M), i32(b), i32(b), None, f32(b), i32(b),
+         f32(b)),
+        {"carry_rows": np.zeros(b, bool), "min_p": f32(b)}))
+    assert len(bufs) == 1
+    lowered = jax.jit(packed(_multi_impl), donate_argnums=(1,),
+                      static_argnames="layout").lower(
+        params, cache, sds((KEY_BLOCK, 2), jnp.uint32),
+        tuple(sds(a.shape, a.dtype) for a in bufs), layout=layout,
+        carry_tokens=sds((1, b)))
+    assert "module @jit__multi_impl " in lowered.as_text()
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo                 # the decode kernel
+    # the program reads its key, it does not make it: no split of the
+    # engine's key in two
+    assert "tensor<2x2xui32>" not in lowered.as_text()
+    # the one buffer is an operand of the entry computation as it is
+    assert re.search(rf"s32\[{bufs[0].size}\]\S* parameter\(", hlo)
+    # ... and the cache still aliases its output
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
 # ---------------------------------------------------------------------------
 # Qwen3-30B-A3B, the benchmark's MoE configuration, at depth 2: the expert
 # weights are read where they lie.  ``lax.ragged_dot`` is a Mosaic custom
