@@ -78,7 +78,9 @@ from dynamo_tpu.ops.paged_attention import (
 
 Params = Any
 
-__all__ = ["HybridLinearConfig", "HybridLinearModel", "DECAY_PROJ_STD"]
+__all__ = ["HybridLinearConfig", "HybridLinearModel", "DECAY_PROJ_STD",
+           "STATE_COUNT_KEYS", "slot_rows", "decode_rows_by_length",
+           "paged_gqa"]
 
 QK_NORM_EPS = 1e-6
 # standard deviation of what the seeded low-rank decay projection adds to
@@ -315,6 +317,62 @@ def _held_experts(cfg: dict, key: str) -> tuple[int, int, int]:
             f"experts {first}..{first + held - 1} are not among the "
             f"router's {total}")
     return held, total, first
+
+
+def slot_rows(pos, positions, slot_idx, seq_slots, layers: int):
+    """The slot contract's part of a forward (docs/linear_state.md), for any
+    model that keeps something per engine slot.  ``pos`` is the cache's
+    ``state_pos`` [slots]; ``positions`` / ``slot_idx`` [B, S] the
+    dispatch's; ``seq_slots`` [B] or None (row i is slot i) ->
+    (rows = (seq_slots, fresh [B], alive [B], n_real [B], valid [B, S]), the
+    new ``state_pos``, and the three counts of ``STATE_COUNT_KEYS``: real
+    tokens x ``layers``, sequences started from zeros, rows that went on at
+    another position than their slot's)."""
+    valid = slot_idx >= 0
+    n_real = valid.sum(axis=1, dtype=jnp.int32)
+    alive = n_real > 0
+    first = positions[:, 0]
+    fresh = alive & (first == 0)
+    held = pos if seq_slots is None else pos[seq_slots]
+    after = jnp.where(alive, first + n_real, held)
+    state_pos = after if seq_slots is None else pos.at[seq_slots].set(after)
+    counted = jnp.stack([
+        n_real.sum(dtype=jnp.int32) * layers,
+        fresh.sum(dtype=jnp.int32),
+        (alive & ~fresh & (first != held)).sum(dtype=jnp.int32)])
+    return (seq_slots, fresh, alive, n_real, valid), state_pos, counted
+
+
+def decode_rows_by_length(block_tables, seq_lens, positions):
+    """A decode step's rows as the kernel takes them, longest first
+    (``rows_by_length``), made once before the layer scan: (order, inverse,
+    tables, lens, positions in that order)."""
+    order, inverse = rows_by_length(seq_lens)
+    return (order, inverse, block_tables[order], seq_lens[order],
+            positions[order])
+
+
+def paged_gqa(q, k, v, kv, ci, positions, block_tables, seq_lens, slot_idx,
+              prefix_blocks, by_length, sm_scale):
+    """Write this dispatch's k, v [B, S, Hk, D] into row ``ci`` of the pool
+    and attend: (attention [B, S, H, D], the pool).  A prefill chunk through
+    ``prefill_attention``, a decode step's rows longest first
+    (``by_length``), the state staying in slot order."""
+    s = q.shape[1]
+    fast = prefix_blocks is not None and s > 1
+    kv = write_kv_cache_layer(kv, ci, k, v, slot_idx, block_aligned=fast)
+    if fast:
+        attn = prefill_attention(
+            q, k, v, kv, ci, block_tables, seq_lens, positions[:, 0],
+            prefix_blocks, sm_scale=sm_scale)
+    elif by_length is not None:
+        order, inverse, tables, lens, at = by_length
+        attn = paged_attention_layer(
+            q[order], kv, ci, tables, lens, at, sm_scale=sm_scale)[inverse]
+    else:
+        attn = paged_attention_layer(
+            q, kv, ci, block_tables, seq_lens, positions, sm_scale=sm_scale)
+    return attn, kv
 
 
 @dataclass(frozen=True)
@@ -604,24 +662,9 @@ class HybridLinearModel:
                 gate = jax.nn.sigmoid(
                     (x @ lp["w_gate_attn"]).astype(jnp.float32))
         with jax.named_scope("attn"):
-            fast = prefix_blocks is not None and s > 1
-            kv = write_kv_cache_layer(kv, ci, k, v, slot_idx,
-                                      block_aligned=fast)
-            if fast:
-                attn = prefill_attention(
-                    q, k, v, kv, ci, block_tables, seq_lens, positions[:, 0],
-                    prefix_blocks, sm_scale=self.sm_scale)
-            elif by_length is not None:
-                # a decode step's rows reach the kernel longest first
-                # (rows_by_length), the state stays in slot order
-                order, inverse, tables, lens, at = by_length
-                attn = paged_attention_layer(
-                    q[order], kv, ci, tables, lens, at,
-                    sm_scale=self.sm_scale)[inverse]
-            else:
-                attn = paged_attention_layer(
-                    q, kv, ci, block_tables, seq_lens, positions,
-                    sm_scale=self.sm_scale)
+            attn, kv = paged_gqa(q, k, v, kv, ci, positions, block_tables,
+                                 seq_lens, slot_idx, prefix_blocks,
+                                 by_length, self.sm_scale)
         with jax.named_scope("attn_out"):
             o = attn.reshape(b, s, -1)
             if cfg.gqa_gate:
@@ -782,25 +825,12 @@ class HybridLinearModel:
             raise ValueError(
                 f"{b} rows without seq_slots, {n_slots} slots: a dispatch "
                 "that is not over the slot array names its rows' slots")
-        valid = slot_idx >= 0
-        n_real = valid.sum(axis=1, dtype=jnp.int32)
-        alive = n_real > 0
-        first = positions[:, 0]
-        fresh = alive & (first == 0)
-        pos = cache["state_pos"]
-        held = pos if seq_slots is None else pos[seq_slots]
-        after = jnp.where(alive, first + n_real, held)
-        state_pos = after if seq_slots is None else pos.at[seq_slots].set(after)
-        counted = jnp.stack([
-            n_real.sum(dtype=jnp.int32) * cfg.linear_layers,
-            fresh.sum(dtype=jnp.int32),
-            (alive & ~fresh & (first != held)).sum(dtype=jnp.int32)])
-        rows = (seq_slots, fresh, alive, n_real, valid)
-        by_length = None
-        if s == 1:
-            order, inverse = rows_by_length(seq_lens)
-            by_length = (order, inverse, block_tables[order],
-                         seq_lens[order], positions[order])
+        rows, state_pos, counted = slot_rows(
+            cache["state_pos"], positions, slot_idx, seq_slots,
+            cfg.linear_layers)
+        valid = rows[-1]
+        by_length = (decode_rows_by_length(block_tables, seq_lens, positions)
+                     if s == 1 else None)
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(cfg.jax_dtype)
             if cfg.embedding_multiplier != 1.0:

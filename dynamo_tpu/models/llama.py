@@ -163,8 +163,15 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               inv_freq: Optional[jax.Array] = None) -> jax.Array:
-    """HF-Llama rotate-half RoPE.  x: [B,S,H,D], positions: [B,S]."""
+               inv_freq: Optional[jax.Array] = None,
+               rotary_dim: Optional[int] = None) -> jax.Array:
+    """HF-Llama rotate-half RoPE.  x: [B,S,H,D], positions: [B,S].  With
+    ``rotary_dim`` < D (``partial_rotary_factor``) the first ``rotary_dim``
+    dimensions of every head are rotated, half against half within them, and
+    the others pass as they are."""
+    if rotary_dim is not None and rotary_dim != x.shape[-1]:
+        turned = apply_rope(x[..., :rotary_dim], positions, theta, inv_freq)
+        return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
     d = x.shape[-1]
     half = d // 2
     if inv_freq is None:

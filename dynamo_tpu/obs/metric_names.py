@@ -298,6 +298,10 @@ REQUEST_FAMILY = (
     _count("dynamo_tpu_engine_moe_experts_touched_total", "counter",
            "held experts with at least one row, summed over layers: x an "
            "expert's bytes, what the grouped matmul streamed"),
+    _count("dynamo_tpu_engine_moe_skip_picks_total", "counter",
+           "of the router's picks, those on a skip output (models/zaya.py: a "
+           "token that takes no expert in that layer); router picks = held "
+           "picks + skip picks there (over router picks, moe.skip_pick_pct)"),
     _count("dynamo_tpu_engine_state_tokens_total", "counter",
            "a model with recurrent layers (docs/linear_state.md): real "
            "tokens x such layers advanced, counted on the device like the "

@@ -32,8 +32,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["CHUNK", "delta_rule_step", "delta_rule_chunk", "delta_rule_scan",
-           "short_conv", "init_state", "unit_lower_inverse", "step_impl",
-           "kernel_gate"]
+           "short_conv", "grouped_conv", "carried_tail", "init_state",
+           "unit_lower_inverse", "step_impl", "kernel_gate"]
 
 # tokens a chunk.  The decay is per channel, so the chunk's two score
 # matrices are sums over [C, C, dk] (no product of two [C, dk] factors gives
@@ -190,6 +190,16 @@ def delta_rule_scan(q, k, v, g, beta, state, chunk: int = CHUNK):
     return o.reshape(o.shape[0], whole, *o.shape[3:])[:, :s], state
 
 
+def carried_tail(xx, n_real, width: int, dtype):
+    """What a causal operation over time carries to the next dispatch: of
+    xx = tail ‖ x [B, K-1+S, D] (x real tokens first) the ``width`` = K-1
+    rows before position ``n_real`` [B] of x, in ``dtype``.  With n_real 0
+    that is the old tail as it was."""
+    new = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
+        row, n, width, axis=0))(xx, n_real)
+    return new.astype(dtype)
+
+
 def short_conv(x, w, tail, n_real, bias=None):
     """Causal depth-wise convolution over time.  x [B, S, D] (this
     dispatch's inputs, real tokens first), w [D, K], tail [B, K-1, D] (the
@@ -205,9 +215,27 @@ def short_conv(x, w, tail, n_real, bias=None):
     y = sum(xx[:, i:i + s].astype(F32) * wf[:, i] for i in range(kk))
     if bias is not None:
         y = y + bias.astype(F32)
-    new = jax.vmap(lambda row, n: jax.lax.dynamic_slice_in_dim(
-        row, n, kk - 1, axis=0))(xx, n_real)
-    return y, new.astype(tail.dtype)
+    return y, carried_tail(xx, n_real, kk - 1, tail.dtype)
+
+
+def grouped_conv(x, w, tail, n_real, bias=None):
+    """Causal convolution over time that mixes the channels of a group (a
+    head): x [B, S, G·Di], w [K, G, Di, Do] (a Di x Do matrix a group and
+    tap), tail [B, K-1, G·Di], n_real [B], bias [G·Do] or None -> (y
+    [B, S, G·Do] float32 with y_t[g] = sum_i xx_{t+i}[g] w[i, g] (+ bias),
+    xx = tail ‖ x; the new tail, as ``short_conv``'s).  K·G small matrix
+    products on the matrix unit, summed in float32; ``short_conv`` is its
+    depth-wise sibling."""
+    kk, g, di, do = w.shape
+    xx = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    b, s = x.shape[:2]
+    xg = xx.reshape(b, kk - 1 + s, g, di)
+    y = sum(jnp.einsum("bsgi,gio->bsgo", xg[:, i:i + s], w[i],
+                       preferred_element_type=F32) for i in range(kk))
+    y = y.reshape(b, s, g * do)
+    if bias is not None:
+        y = y + bias.astype(F32)
+    return y, carried_tail(xx, n_real, kk - 1, tail.dtype)
 
 
 def init_state(layers: int, slots: int, heads: int, dk: int, dv: int,

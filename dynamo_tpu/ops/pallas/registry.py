@@ -1572,10 +1572,10 @@ def _spec_grouped_matmul(name: str, m: int, layers: int, e: int, k: int,
 
 
 
-def _spec_decode_8b() -> dict:
-    """8B-serving decode shape, shape-traced only: VMEM budget and
-    pricing at the geometry that matters, without executing."""
-    b, h, hk, d, bs, n, m, L = 64, 32, 8, 128, 16, 4096, 128, 32
+def _spec_decode_8b(name: str = "decode-8b", b=64, h=32, hk=8, d=128, bs=16,
+                    n=4096, m=128, L=32) -> dict:
+    """8B-serving decode shape (or a cell's own), shape-traced only: VMEM
+    budget and pricing at the geometry that matters, without executing."""
 
     def build():
         import jax
@@ -1611,17 +1611,17 @@ def _spec_decode_8b() -> dict:
             q_bytes=2)
 
     return {
-        "name": "decode-8b", "kernel": "paged_decode_attention_mq",
+        "name": name, "kernel": "paged_decode_attention_mq",
         "mode": "spec", "build": build, "run": run, "oracle": None,
         "pricing": pricing,
     }
 
 
-def _spec_prefill_8b() -> dict:
+def _spec_prefill_8b(name: str = "prefill-8b", b=1, s=2048, h=32, hk=4,
+                     d=128, bs=16, n=4096, m=128, L=32) -> dict:
     """S=2048 prefill at the documented serving tile (Hk*D=512), shape
     traced: this is the case the rows_per_chunk=128 VMEM claim is
-    machine-checked against."""
-    b, s, h, hk, d, bs, n, m, L = 1, 2048, 32, 4, 128, 16, 4096, 128, 32
+    machine-checked against.  (Or a cell's own geometry.)"""
 
     def build():
         import jax
@@ -1659,7 +1659,7 @@ def _spec_prefill_8b() -> dict:
             q_bytes=2)
 
     return {
-        "name": "prefill-8b", "kernel": "paged_prefill_attention",
+        "name": name, "kernel": "paged_prefill_attention",
         "mode": "spec", "build": build, "run": run, "oracle": None,
         "pricing": pricing,
     }
@@ -1697,6 +1697,15 @@ def audit_cases() -> list[dict]:
         # experts of 4,096 x 768: Qwen3's N at Solar's K, 8.9 rows an expert)
         _spec_grouped_matmul("experts-granite-decode", 640, 10, 36, 4096, 768,
                              weights=2),
+        # ZAYA1-8B on one chip: 64 rows x top-1 over 16 wide experts of
+        # 2,048 x 2,048 (gate and up in one call: N 4,096 of weights a
+        # group, 4 rows an expert), and 8/2 heads of 128 in blocks of 32 —
+        # a K/V row of 256 lanes, which only a --tp 4 shard had reached
+        _spec_grouped_matmul("experts-zaya-decode", 64, 20, 16, 2048, 2048,
+                             weights=2),
+        _spec_decode_8b("decode-zaya", b=64, h=8, hk=2, bs=32, n=6272, L=20),
+        _spec_prefill_8b("prefill-zaya", s=512, h=8, hk=2, bs=32, n=6272,
+                         L=20),
     ]
 
 

@@ -222,6 +222,29 @@ async def check_margins(config: dict, seeds: list[int]) -> list[dict]:
     return out
 
 
+def a_process_a_seed(script: str, seeds: str, control, flags: list) -> int:
+    """``script --check-seeds <seed> <flags>`` once a seed of the comma
+    separated ``seeds``, each in a process of its own: a served model's
+    arrays outlive its engine, and two do not fit the chip (the caller has
+    not touched jax yet).  Prints every seed's margins and one JSON line of
+    them all."""
+    import subprocess
+
+    rows = []
+    for seed in seeds.split(","):
+        out = subprocess.run(
+            [sys.executable, script, "--check-seeds", seed, *flags],
+            stdin=subprocess.DEVNULL, capture_output=True, text=True)
+        last = [l for l in out.stdout.splitlines() if l.startswith("{")]
+        if out.returncode or not last:
+            print(out.stdout[-2000:], out.stderr[-2000:], flush=True)
+            return 1
+        rows += json.loads(last[-1])["checks"]
+        note(f"check seed {seed}: {json.dumps(rows[-1])}")
+    print(json.dumps({"control": control, "checks": rows}), flush=True)
+    return 0
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--config", default="solar-open2-ep16",
@@ -245,28 +268,13 @@ def main(argv=None) -> int:
     control = ("cache_one_precision_down" if a.cache_one_precision_down
                else "bf16_state" if a.bf16_state else None)
     if a.check_seeds and "," in a.check_seeds:
-        # one process a seed: a served model's arrays outlive its engine, and
-        # two do not fit the chip (this process has not touched jax yet)
-        import subprocess
-
-        rows = []
-        for seed in a.check_seeds.split(","):
-            out = subprocess.run(
-                [sys.executable, __file__, "--config", a.config,
-                 "--check-seeds", seed]
-                + (["--bf16-state"] if a.bf16_state else [])
-                + (["--cache-one-precision-down"]
-                   if a.cache_one_precision_down else [])
-                + (["--tiny"] if a.tiny else []),
-                stdin=subprocess.DEVNULL, capture_output=True, text=True)
-            last = [l for l in out.stdout.splitlines() if l.startswith("{")]
-            if out.returncode or not last:
-                print(out.stdout[-2000:], out.stderr[-2000:], flush=True)
-                return 1
-            rows += json.loads(last[-1])["checks"]
-            note(f"check seed {seed}: {json.dumps(rows[-1])}")
-        print(json.dumps({"control": control, "checks": rows}), flush=True)
-        return 0
+        return a_process_a_seed(
+            __file__, a.check_seeds, control,
+            ["--config", a.config]
+            + (["--bf16-state"] if a.bf16_state else [])
+            + (["--cache-one-precision-down"]
+               if a.cache_one_precision_down else [])
+            + (["--tiny"] if a.tiny else []))
     import jax
 
     from cellbench import spec

@@ -143,6 +143,7 @@ def seed_http_metrics():
     ec.moe_held_picks_total = 576
     ec.moe_expert_layer_calls_total = 36
     ec.moe_experts_touched_total = 540
+    ec.moe_skip_picks_total = 271
     ec.state_tokens_total = 7200
     ec.state_resets_total = 12
     ec.mesh_tp = ec.mesh_devices = 4

@@ -125,6 +125,8 @@ def test_plan_lists_the_pairs_that_have_rows():
     (2048, 4096, 1, 128, 1024),
     (6144, 2048, 2, 64, 256),   # GLM-5.2: an eighth of 25.2 MB, 64 rows
     (2048, 6144, 1, 64, 1024),
+    (2048, 2048, 2, 128, 512),  # ZAYA1: a quarter of 8.4 MB, twice (N 4,096)
+    (2048, 2048, 1, 128, 1024),
 ])
 def test_tiling_sizes_a_block_by_its_bytes(k, n, stacks, rows, want):
     tm = reg.grouped_matmul_row_tile(512, max(k, n))

@@ -635,6 +635,7 @@ def test_decode_tiling_follows_the_geometry():
         "mistral-7b": (32, 1024, (8, 4), 4), "llama-3-8b": (32, 1024, (8, 4), 4),
         "qwen3-30b-a3b": (32, 512, (8, 8), 4),
         "mistral-7b-tp4 (a shard)": (8, 256, (8, 16), 4),
+        "zaya1-8b (one chip, whole)": (8, 256, (8, 16), 4),
         "ouro-2.6b": (16, 2048, (8, 2), 2)}
     for name, (rows, hkd, tiling, r) in accepted.items():
         g, c = registry.decode_tiling(rows, hkd, BS)
@@ -643,6 +644,8 @@ def test_decode_tiling_follows_the_geometry():
         assert registry.decode_seqs_per_update(g, c, BS) == r, name
         assert registry.decode_vmem_bytes(g, c, rows, hkd, BS) \
             <= registry.SCOPED_VMEM_BYTES, name
+    # ... and a prefill grid step takes 128 tokens of ZAYA's 8 heads
+    assert registry.prefill_rows_per_chunk(8) == 128
     # an int8 block brings a scale tile: two copies and two semaphores a site
     g, c = registry.decode_tiling(8, 256, BS, cache_bytes=1)
     assert 2 * g * c <= registry.DECODE_MAX_DMA_SITES and c == 16
@@ -1245,3 +1248,70 @@ def test_granite_hybrid_cell_programs_write_the_state_in_place(
           f"total {total / 1e9:.3f} GB")
     assert 12.7e9 < mem.argument_size_in_bytes < 12.9e9
     assert 0.74 * V5E_HBM < total < 0.82 * V5E_HBM, total
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "reference"])
+def test_zaya_cell_programs_fit_the_chip_with_every_leaf_in_place(
+        topo, tpu_gate, program):
+    """zaya1-8b's decode program (64 rows = the slot array), a 512-token
+    chunk with 16 blocks of the prompt cached, and the check's float32
+    reference over its longest sequence (700 + 8 tokens, padded to 768),
+    whole (20 layers, 16 experts, the 262,272-row tied matrix, the cell's
+    pool and its 64 slots of tails): every layer through the Pallas GQA
+    kernels at 8/2 heads of 128 (256 lanes a K/V row), the experts through
+    the grouped matmul at K 2,048 / N 2,048 (4 rows an expert in decode, 32
+    in a chunk), one scan over the layers, every leaf of the cache donated
+    and written in place, and weights + K/V + tails inside the chip
+    (~13.5 GB) with room for the reference's temporaries beside them."""
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "zaya1-8b.json", lambda spec: SingleDeviceSharding(topo.devices[0]))
+    serve = dict(hf["serve"])
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    weights = sum(nbytes(a) for a in jax.tree.leaves(params))
+    held = sum(nbytes(a) for a in jax.tree.leaves(cache))
+    assert 9.37e9 < weights < 9.39e9
+    assert nbytes(cache["kv"]) == 6272 * 32 * 20 * 1024      # 4.11 GB
+    assert cache["state"].shape == (20, 64, 2688)
+    if program == "reference":
+        from cellbench import spec
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        ref = spec.load_module(root, "reference", hf["reference"])
+        compiled = jax.jit(ref.make_forward(hf)).lower(
+            params, sds((768,)), sds((8,))).compile()
+        mem = compiled.memory_analysis()
+        print(f"# reference: temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
+        # beside the served model: its weights are the reference's
+        # arguments, the cache is the engine's
+        assert mem.temp_size_in_bytes < 1.6e9, mem.temp_size_in_bytes
+        assert (weights + held + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes) < 0.93 * V5E_HBM
+        return
+    fn, args = _step_program(program, model, serve, sds, prefix_blocks=16)
+    if program == "prefill":        # the engine names the row's slot
+        from dynamo_tpu.engine.core import unified_step
+
+        fn = lambda p, c, *a: unified_step(
+            model, p, c, *a[:-1], prefix_blocks=16, seq_slots=a[-1])
+        args = (*args, sds((1,)))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    assert ("paged_decode_attention" if program == "decode"
+            else "paged_prefill_attention") in hlo
+    assert len(_grouped_matmul_calls(hlo)) == 2      # gate + up, down: one scan
+    assert "ragged-dot" not in hlo
+    assert hlo.count(" while(") == 1
+    # neither the pool nor the tails are copied whole
+    assert not re.search(r"bf16\[20,6272,2,32,256\]\S* copy\(", hlo)
+    assert not re.search(r"bf16\[20,64,2688\]\S* copy\(", hlo)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held                   # all donated
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"# {program}: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"total {total / 1e9:.3f} GB")
+    assert 13.4e9 < mem.argument_size_in_bytes < 13.6e9
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+    assert 0.79 * V5E_HBM < total < 0.84 * V5E_HBM, total
