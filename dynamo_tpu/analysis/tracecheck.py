@@ -382,7 +382,16 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
     min_elems = model_cfg.hidden_size
     eps: list[Entrypoint] = []
 
+    def keyed(phase, prefix_blocks, span):
+        """Whether a prefill dispatch can reach jit with this static
+        ``prefix_blocks``: the engine hands over what its dispatch rule
+        maps a prefix to (``EngineCore._prefix_blocks``), so a value the
+        mapping turns into another names a program it never builds."""
+        return core._prefix_blocks(phase, prefix_blocks, span) == prefix_blocks
+
     def build_step(s_bucket, prefix_blocks):
+        if not keyed("prefill", prefix_blocks, s_bucket):
+            return None
         bufs, layout = _operands(
             _sds((1, s_bucket), i32), _sds((1, s_bucket), i32),
             _sds((1, m), i32), _sds((1,), i32),
@@ -476,7 +485,8 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
             # pow2ceil(r_real) == r_pad needs r_real > r_pad/2 rows, each
             # at least one block wide on the flat axis
             min_rows = r_pad // 2 + 1 if r_pad > 1 else 1
-            if min_rows * bs > t_bucket:
+            if min_rows * bs > t_bucket or not keyed(
+                    "ragged", prefix_blocks, t_bucket):
                 return None
             bufs, layout = flat_axis_operands(t_bucket, r_pad)
             return Signature(
@@ -518,7 +528,8 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
             # pow2ceil(r_real) == r_pad needs more rows than the slots
             # can supply, or no block-wide span fits past the region
             min_rows = r_pad // 2 + 1 if r_pad > 1 else 1
-            if min_rows > b or (t_bucket - d_region) // bs < 1:
+            if min_rows > b or (t_bucket - d_region) // bs < 1 or not keyed(
+                    "ragged", prefix_blocks, t_bucket):
                 return None
             bufs, layout = flat_axis_operands(t_bucket, r_pad)
             return Signature(

@@ -54,6 +54,7 @@ from dynamo_tpu.engine.grammar import (
 from dynamo_tpu.engine.request import EngineRequest, RequestState
 from dynamo_tpu.engine.sampling import K_MAX, sample_full
 from dynamo_tpu.ops.block_copy import gather_blocks_padded, scatter_blocks_inplace
+from dynamo_tpu.ops.paged_attention import prefill_program_key
 from dynamo_tpu.llm.kv.block_manager import KvBlockManager, NoFreeBlocks
 from dynamo_tpu.llm.protocols import FinishReason, LLMEngineOutput
 from dynamo_tpu.models.llama import LlamaModel
@@ -583,6 +584,12 @@ class EngineCore:
             counts.state_update_kernel = int(
                 model.state_update_impl()[0] == "pallas")
         counts.prefix_reuse = int(self.prefix_reuse)
+        # whether the model's forward sizes something of its own by a
+        # prefill's ``prefix_blocks``; where it only hands the value to the
+        # attention call, that call's dispatch rule says whether the value
+        # may key a program (``_prefix_blocks``)
+        self._prefix_sizes_forward = bool(
+            getattr(model, "prefix_blocks_sizes_forward", True))
         # the totals a model keeps on the device, in the order of the columns
         # of its cache's ``moe_counts`` (read back with each dispatch)
         self._device_count_keys = getattr(model, "moe_count_keys", ())
@@ -648,6 +655,9 @@ class EngineCore:
             static_argnames=("layout", "row_tokens", "prefix_blocks",
                              "k_cand", "exact"),
         )
+        # the three a prefill chunk goes out through: their jit caches are
+        # the prefill programs this engine has built (``_count_prefill``)
+        self._prefill_fns = (self._step_fn, self._ragged_fn, self._unified_fn)
         # sequence-parallel long-prefill (ring attention over the "data"
         # axis): one dispatch computes the whole prompt with the sequence
         # sharded across the mesh — SURVEY §5 long-context path
@@ -908,6 +918,25 @@ class EngineCore:
         bound = int((-(-blocks.reshape(-1, g).max(axis=1) // c)).sum()) * g * c
         self.counts.decode_kv_blocks_walked_total += walked
         self.counts.decode_kv_blocks_group_bound_total += bound
+
+    def _prefix_blocks(self, phase: str, blocks: int, span: int) -> int:
+        """The static ``prefix_blocks`` of a prefill dispatch ("prefill":
+        one request's chunk; "ragged": a flat token axis) of ``span``
+        tokens whose longest cached prefix is ``blocks`` blocks: rounded up
+        to a power of two, so that the programs stay O(log) where the value
+        sizes a gather, and one value for every prefix where the dispatch
+        rule says nothing reads it (``ops/paged_attention.py::
+        prefill_program_key``: the flash kernel streams the prefix by its
+        true length, which the operands carry)."""
+        pb = 0 if blocks == 0 else min(
+            1 << (blocks - 1).bit_length(), self.config.max_blocks_per_seq)
+        if self._prefix_sizes_forward:
+            return pb
+        mc = self.model.config
+        return prefill_program_key(
+            phase, pb, span, getattr(mc, "sliding_window", None),
+            num_kv_heads=mc.num_kv_heads, block_size=self.config.block_size,
+            quant=self.cache_quant, tp=self.counts.mesh_tp)
 
     def attention_impls(self) -> dict[str, tuple[str, str]]:
         """phase -> ("pallas" | "xla", why), as the dispatch in
@@ -1449,6 +1478,8 @@ class EngineCore:
         if budget > 0:
             c.prefill_budget_offered += budget
             c.prefill_budget_used += tokens
+        c.prefill_programs_total = sum(
+            fn._cache_size() for fn in self._prefill_fns)
         self._count_tokens(tokens)
 
     def _count_tokens(self, tokens: int) -> None:
@@ -1876,12 +1907,10 @@ class EngineCore:
         seq_lens = np.asarray([end], np.int32)
         last_idx = np.asarray([take - 1], np.int32)
 
-        # prefill fast path: cached-prefix blocks, bucketed to powers of two
-        # so the executable count stays O(log) (prefill_attention gathers
-        # only these instead of the whole padded table)
-        pb = req.computed_tokens // cfg.block_size
-        pb = 0 if pb == 0 else 1 << (pb - 1).bit_length()
-        pb = min(pb, m)
+        # prefill fast path: attention over this chunk and the cached-prefix
+        # blocks alone, not the whole padded table
+        pb = self._prefix_blocks(
+            "prefill", req.computed_tokens // cfg.block_size, s)
 
         k_cand, exact = self._sampling_mode([req])
         gram = None
@@ -2010,10 +2039,9 @@ class EngineCore:
             top_p[r] = req.sampling.top_p
             max_pb = max(max_pb, begin // bs)
             off += -(-take // bs) * bs
-        # cached-prefix gather bound: max over rows, pow2-bucketed like the
-        # single-request path (rows with shorter prefixes mask by start)
-        pb = 0 if max_pb == 0 else 1 << (max_pb - 1).bit_length()
-        pb = min(pb, m)
+        # cached-prefix gather bound: max over rows, like the single-request
+        # path (rows with shorter prefixes mask by start)
+        pb = self._prefix_blocks("ragged", max_pb, t_pad)
 
         finals = [(r, req) for r, (req, _, fin) in enumerate(sel) if fin]
         final_reqs = [req for _, req in finals]
@@ -2228,8 +2256,7 @@ class EngineCore:
             top_p[r] = req.sampling.top_p
             max_pb = max(max_pb, begin // bs)
             off += -(-take // bs) * bs
-        pb = 0 if max_pb == 0 else 1 << (max_pb - 1).bit_length()
-        pb = min(pb, m)
+        pb = self._prefix_blocks("ragged", max_pb, t_pad)
 
         # sampling rows: every decode row plus final-chunk prefill rows
         # (mid-chunk rows' samples are discarded below)

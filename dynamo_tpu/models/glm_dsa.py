@@ -474,6 +474,9 @@ class GlmDsaModel:
     supports_ragged_prefill = False
     supports_unified_dispatch = False
     supports_seq_parallel = False
+    # forward() sizes its context gather by ``prefix_blocks`` (ctx_blocks):
+    # a program a bucket of it
+    prefix_blocks_sizes_forward = True
 
     def __init__(self, config: GlmDsaConfig):
         self.config = config

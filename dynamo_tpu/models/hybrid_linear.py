@@ -396,6 +396,8 @@ class HybridLinearModel:
     supports_ragged_prefill = False
     supports_unified_dispatch = False
     supports_seq_parallel = False
+    # ``prefix_blocks`` goes to ``prefill_attention`` and nowhere else
+    prefix_blocks_sizes_forward = False
 
     def __init__(self, config: HybridLinearConfig, state_dtype=jnp.float32):
         """``state_dtype``: what ``state`` is *stored* in between dispatches

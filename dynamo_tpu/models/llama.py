@@ -195,6 +195,10 @@ class LlamaModel:
     # rows leading the flat axis via ``ragged_row_tokens``) — the engine
     # gates the unified token-budget scheduler on this
     supports_unified_dispatch = True
+    # forward() hands a prefill's ``prefix_blocks`` to the attention call
+    # and reads it nowhere else, so whether the value keys a program is that
+    # call's dispatch rule to say (EngineCore._prefix_blocks)
+    prefix_blocks_sizes_forward = False
 
     def __init__(self, config: ModelConfig):
         if config.ut_steps < 1:

@@ -228,6 +228,8 @@ class ZayaModel:
     supports_ragged_prefill = False
     supports_unified_dispatch = False
     supports_seq_parallel = False
+    # ``prefix_blocks`` goes to ``prefill_attention`` and nowhere else
+    prefix_blocks_sizes_forward = False
 
     def __init__(self, config: ZayaConfig, kept=None):
         """``kept``: a function every array a sequence keeps between

@@ -237,6 +237,11 @@ PREFILL_FAMILY = (
     _count("dynamo_tpu_engine_unified_budget_utilization", "gauge",
            "decode rows + prefill tokens over the flat-axis budget offered",
            ratio=("unified_budget_used", "unified_budget_offered")),
+    _count("dynamo_tpu_engine_prefill_programs_total", "counter",
+           "distinct programs the prefill entry points hold (the jit caches "
+           "of the one-request, the batched and the unified dispatch): one a "
+           "token bucket where the flash kernel streams the prefix, one a "
+           "bucket x cached-prefix bucket where the prefix sizes a gather"),
 )
 
 REQUEST_FAMILY = (

@@ -44,6 +44,7 @@ __all__ = [
     "rows_by_length",
     "prefill_attention",
     "ragged_prefill_attention",
+    "prefill_program_key",
     "SPARSE_PHASES",
     "sparse_attention_impl",
     "sparse_latent_attention",
@@ -277,6 +278,43 @@ def paged_attention_layer(
     )
 
 
+def _prefill_impl(
+    phase: str, prefix_blocks: int, span: int, window: int | None, *,
+    num_kv_heads: int, block_size: int, quant: bool = False, tp: int = 1,
+) -> tuple[str, int | None]:
+    """``("pallas" | "xla", the window to mask by)`` for a prefill dispatch
+    of ``span`` tokens on its token axis behind ``prefix_blocks`` cached
+    blocks: what ``prefill_attention`` ("prefill") and
+    ``ragged_prefill_attention`` ("ragged") go by.  A sliding window
+    matters only when the STATIC attended span (visible prefix + these
+    tokens) can exceed it; otherwise full attention is exact (no window to
+    mask by) and the flash kernel stays in play."""
+    if window is not None and prefix_blocks * block_size + span <= window:
+        window = None
+    if span <= 1:
+        return "xla", window
+    return attention_impl(
+        phase, num_kv_heads=num_kv_heads, block_size=block_size, quant=quant,
+        windowed=window is not None, tp=tp)[0], window
+
+
+def prefill_program_key(
+    phase: str, prefix_blocks: int, span: int, window: int | None = None,
+    **geometry,
+) -> int:
+    """The ``prefix_blocks`` a prefill dispatch may hand ``jax.jit`` as a
+    static argument, for a model whose forward passes it to the attention
+    call below and reads it nowhere else.  On the flash path the kernel
+    streams the prefix by its true length and the value decides nothing
+    (the span fits the window, or there is none), so every prefix gets the
+    one value 0 and two dispatches whose lowered modules would be the same
+    meet one program.  On the XLA path it sizes the gather and stays as it
+    is.  ``geometry``: ``num_kv_heads``, ``block_size``, ``quant`` and
+    ``tp``, as ``attention_impl`` takes them."""
+    impl, _ = _prefill_impl(phase, prefix_blocks, span, window, **geometry)
+    return 0 if impl == "pallas" else prefix_blocks
+
+
 def prefill_attention(
     q: jax.Array,             # [B, S, H, D] — fresh queries (contiguous from `start`)
     k_new: jax.Array,         # [B, S, Hk, D] — this chunk's keys (pre-cache-write values)
@@ -311,19 +349,15 @@ def prefill_attention(
         sm_scale = 1.0 / (d**0.5)
     data_ = cache.data if quant else cache
     bs_ = data_.shape[3]
-    # sliding window matters only when the STATIC attended span (visible
-    # prefix + this chunk) can exceed it; otherwise full attention is
-    # exact and the flash kernel stays in play
-    windowed = window is not None and prefix_blocks * bs_ + s > window
-    if not windowed:
-        window = None
     tp = tp_size()
-    if s > 1 and attention_impl(
-            "prefill", num_kv_heads=hk, block_size=bs_, quant=quant,
-            windowed=windowed, tp=tp)[0] == "pallas":
+    impl, window = _prefill_impl(
+        "prefill", prefix_blocks, s, window, num_kv_heads=hk,
+        block_size=bs_, quant=quant, tp=tp)
+    if impl == "pallas":
         # flash path: online softmax, scores never leave VMEM; the cached
-        # prefix streams from HBM by its TRUE length (start), so the
-        # static prefix_blocks bucket doesn't even force recompiles here
+        # prefix streams from HBM by its TRUE length (start), so nothing
+        # here reads the static prefix_blocks bucket: the engine keys no
+        # program by it on this path (``prefill_program_key``)
         from dynamo_tpu.ops.pallas.prefill_attention import (
             paged_prefill_attention,
         )
@@ -437,15 +471,11 @@ def ragged_prefill_attention(
         sm_scale = 1.0 / (d**0.5)
     data = cache.data if quant else cache
     _, n, _, bs, hkd = data.shape
-    # same window routing as prefill_attention: only when the static
-    # attended span can actually exceed the window
-    windowed = window is not None and prefix_blocks * bs + t > window
-    if not windowed:
-        window = None
     tp = tp_size()
-    if t > 1 and attention_impl(
-            "ragged", num_kv_heads=hk, block_size=bs, quant=quant,
-            windowed=windowed, tp=tp)[0] == "pallas":
+    impl, window = _prefill_impl(
+        "ragged", prefix_blocks, t, window, num_kv_heads=hk, block_size=bs,
+        quant=quant, tp=tp)
+    if impl == "pallas":
         from dynamo_tpu.ops.pallas.prefill_attention import (
             ragged_paged_prefill_attention,
         )

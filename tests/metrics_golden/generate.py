@@ -123,6 +123,7 @@ def seed_http_metrics():
     ec.unified_prefill_tokens = 90
     ec.unified_budget_offered = 128
     ec.unified_budget_used = 6 + 90
+    ec.prefill_programs_total = 3       # two batched shapes and the unified
     ec.decode_dispatches_total = 2
     ec.decode_rows_dispatched_total = 12 + 11
     ec.requests_finished_total = 2

@@ -243,13 +243,81 @@ def test_no_operand_shape_is_served_under_two_layouts(tiny, tp):
         assert fns[name]._cache_size() == len(programs), name
 
 
+# ------------------------------------- a prefill program a (bucket, prefix)
+# PR 57: what keys a prefill program is the dispatch rule's to say
+# (``EngineCore._prefix_blocks``).  Here, under XLA, the cached prefix sizes a
+# gather and keeps its power-of-two bucket; the runs below hold the tokens of
+# a prompt of four chunks and of one that finds 40 of its tokens cached to the
+# tree before (the golden file's ``prefix-*`` entries), through each of the
+# three prefill entry points.
+# run -> (the entry point watched, the engine, the ``prefix_blocks`` it is
+# handed: the chunks behind 0, 2, 4 and 6 blocks and the hit's behind 5 and
+# 7; the unified dispatch takes the hit's chunks beside the first request's
+# decode row, whose 9 blocks of context bound the gather)
+PREFIX_RUNS = {
+    "prefix-step": ("_step_fn", CHUNKED, {0, 2, 4, 8}),
+    "prefix-ragged": ("_ragged_fn", dict(**CHUNKED, prefill_token_budget=64),
+                      {0, 2, 4, 8}),
+    "prefix-unified": ("_unified_fn", dict(
+        **CHUNKED, prefill_token_budget=64, unified_token_dispatch=True),
+        {16}),
+}
+
+
+def serve_prefix(core):
+    """A 64-token prompt (four chunks of 16: behind 0, 2, 4 and 6 cached
+    blocks of 8), a few decodes, and while it decodes a prompt whose first
+    40 tokens are the first one's, so that its first chunk goes out behind
+    5 cached blocks.  Returns each request's tokens."""
+    toks = {"long": [], "hit": []}
+    first = prompt(64, 7)
+    asks = {"long": (first, S(temperature=0.7, top_p=0.9)),
+            "hit": (first[:40] + prompt(20, 8), S(temperature=0.0))}
+    for rid, (tokens, sampling) in asks.items():
+        core.submit(EngineRequest(
+            rid, tokens, sampling,
+            StopConditions(max_tokens=6, ignore_eos=True),
+            lambda out, rid=rid: toks[rid].extend(
+                int(t) for t in out.token_ids)))
+        for _ in range(6):
+            core.step()
+    while core.step():
+        pass
+    return toks
+
+
+def run_prefix(tiny, name):
+    attr, cfg, _ = PREFIX_RUNS[name]
+    core = make_core(tiny, None, **cfg)
+    held = [getattr(core, run[0]) for run in PREFIX_RUNS.values()]
+    calls = watch(core, attr)
+    return core, calls, serve_prefix(core), held
+
+
+@pytest.mark.parametrize("name", sorted(PREFIX_RUNS))
+def test_chunks_behind_a_cached_prefix_give_the_tokens_of_the_tree_before(
+        tiny, name):
+    core, calls, toks, held = run_prefix(tiny, name)
+    assert toks == json.loads(GOLDEN.read_text())[name]
+    m = core.metrics()
+    assert m["prompt_tokens_cached_total"] == 40          # the hit was one
+    # the XLA form gathers the prefix: every bucket of it keys a program
+    keyed = {kw["prefix_blocks"] for _, kw in calls}
+    assert keyed == PREFIX_RUNS[name][2]
+    # ... and the count of them is what the jit caches hold
+    assert m["prefill_programs_total"] == sum(
+        fn._cache_size() for fn in held) >= len(keyed)
+
+
 if __name__ == "__main__":
     from dynamo_tpu.utils.platform import force_cpu_devices
 
     force_cpu_devices(8)        # as tests/conftest.py does
     model = LlamaModel(ModelConfig.tiny())
     weights = model, model.init_params(jax.random.PRNGKey(0))
+    golden = {name: run(weights, name)[1] for name in RUNS}
+    golden.update((name, run_prefix(weights, name)[2]) for name in PREFIX_RUNS)
     GOLDEN.write_text("{\n" + ",\n".join(
-        f' "{name}": {json.dumps(run(weights, name)[1])}'
-        for name in sorted(RUNS)) + "\n}\n")
+        f' "{name}": {json.dumps(golden[name])}'
+        for name in sorted(golden)) + "\n}\n")
     print(GOLDEN.read_text())

@@ -1315,3 +1315,111 @@ def test_zaya_cell_programs_fit_the_chip_with_every_leaf_in_place(
     assert 13.4e9 < mem.argument_size_in_bytes < 13.6e9
     assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
     assert 0.79 * V5E_HBM < total < 0.84 * V5E_HBM, total
+
+
+# ---------------------------------------------------------------------------
+# PR 57: a prefill program a token bucket, not a bucket x cached-prefix
+# bucket.  On the flash path nothing the program computes reads the static
+# ``prefix_blocks`` (the kernel streams the prefix by the true length its
+# operands carry), so the engine hands jit one value for every prefix there
+# (``EngineCore._prefix_blocks`` through ``ops/paged_attention.py::
+# prefill_program_key``) and keeps the bucket wherever it sizes a gather.
+_KEY_S, _KEY_PREFIXES = 64, (0, 16, 64)     # a 64-token chunk; cached blocks
+
+
+def _keyed_toy(family: str, **overrides):
+    """A toy of each model class at kernel-friendly widths (heads of 128,
+    bfloat16, blocks of 32)."""
+    import hybrid_linear_tiny
+    import zaya_tiny
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.models.llama import LlamaModel
+
+    if family == "llama":
+        return LlamaModel(ModelConfig.tiny(
+            num_kv_heads=2, head_dim=128, dtype="bfloat16", **overrides))
+    if family == "hybrid_linear":
+        from dynamo_tpu.models.hybrid_linear import (HybridLinearConfig,
+                                                     HybridLinearModel)
+        return HybridLinearModel(HybridLinearConfig.from_hf_config(
+            dict(hybrid_linear_tiny.TINY, head_dim=128), dtype="bfloat16"))
+    if family == "zaya":
+        from dynamo_tpu.models.zaya import ZayaConfig, ZayaModel
+        return ZayaModel(ZayaConfig.from_hf_config(
+            dict(zaya_tiny.TINY, head_dim=128), dtype="bfloat16"))
+    from test_glm_dsa import TINY
+    from dynamo_tpu.models.glm_dsa import GlmDsaConfig, GlmDsaModel
+    return GlmDsaModel(GlmDsaConfig.from_hf_config(TINY, dtype="bfloat16"))
+
+
+def _keyed_engine(model):
+    """(engine, lower): an engine round ``model`` on shapes alone, and the
+    text of its one-request prefill program lowered for the TPU with a given
+    static ``prefix_blocks``, through the engine's own jitted entry point."""
+    from dynamo_tpu.engine import EngineConfig, EngineCore, operands
+    from dynamo_tpu.engine.sampling import K_MAX
+
+    s = _KEY_S
+    params = jax.eval_shape(lambda: model.init_params(jax.random.key(0)))
+    core = EngineCore(model, params, EngineConfig(
+        max_batch_size=4, max_model_len=_KEY_PREFIXES[-1] * BS + s,
+        block_size=BS, num_blocks=80, prefill_buckets=[s],
+        prefill_chunk_tokens=s), eos_token_ids=[])
+    i32 = lambda *shape: np.zeros(shape, np.int32)
+    f32 = lambda *shape: np.zeros(shape, np.float32)
+    bufs, layout = operands.pack((
+        i32(),
+        (i32(1, s), i32(1, s), i32(1, core.config.max_blocks_per_seq), i32(1),
+         i32(1, s), i32(1), None, f32(1), i32(1), f32(1)),
+        ({"seq_slots": i32(1)} if getattr(model, "recurrent_state", False)
+         else {})))
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+    def lower(prefix_blocks: int) -> str:
+        return core._step_fn.trace(
+            params, jax.tree.map(sds, core.cache), sds(core._keys),
+            tuple(sds(b) for b in bufs), layout=layout,
+            prefix_blocks=prefix_blocks, k_cand=K_MAX, exact=False,
+        ).lower(lowering_platforms=("tpu",)).as_text()
+
+    return core, lower
+
+
+@pytest.mark.parametrize("case", [
+    "llama-flash", "hybrid_linear-flash", "zaya-flash", "llama-window",
+    "llama-xla", "glm-flash"])
+def test_prefix_blocks_keys_a_prefill_program_only_where_it_sizes_a_gather(
+        monkeypatch, tpu_gate, case):
+    family, dispatch = case.split("-")
+    if dispatch == "xla":
+        monkeypatch.setenv("DYNAMO_DISABLE_PALLAS_PREFILL", "1")
+    # a window of 1,024: 16 cached blocks and the chunk fit it, 64 do not
+    model = _keyed_toy(family, **(
+        {"sliding_window": 1024} if dispatch == "window" else {}))
+    core, lower = _keyed_engine(model)
+    keys = [core._prefix_blocks("prefill", pb, _KEY_S) for pb in _KEY_PREFIXES]
+    if family == "glm":
+        # the model sizes its own context gather by the value: a program a
+        # bucket, whatever its attention kernels do
+        assert keys == list(_KEY_PREFIXES)
+        assert lower(16) != lower(64)
+    elif dispatch == "flash":
+        # one jit entry for a chunk behind 0, 16 and 64 cached blocks ...
+        assert keys == [0, 0, 0]
+        # ... because the modules the three values lower to are one module
+        modules = {lower(pb) for pb in _KEY_PREFIXES}
+        assert len(modules) == 1
+        assert "paged_prefill_attention" in modules.pop()
+    elif dispatch == "window":
+        # one value a side of the window: inside it the flash kernel, past
+        # it XLA's masked gather over that many blocks
+        assert keys == [0, 0, 64]
+        inside, past = lower(16), lower(64)
+        assert inside == lower(0) != past
+        assert "paged_prefill_attention" in inside
+        assert "paged_prefill_attention" not in past
+    else:
+        # the XLA form gathers ``prefix_blocks`` blocks: the bucket stays
+        assert keys == list(_KEY_PREFIXES)
+        assert lower(16) != lower(64)
+        assert "paged_prefill_attention" not in lower(16)
