@@ -18,7 +18,9 @@ not its sum; ``jaxpr_to_mlir_module_duration`` is one a lowered module,
 ``cache_retrieval_time_sec`` one a load from the persistent cache,
 ``gc_gen<n>`` CPython's collections of that generation.  ``marks``: each
 stage's seconds, the heap at its end (objects frozen and tracked) and the
-prefill programs.  Run it
+prefill programs; ``marks.window``: what the program counted inside the
+window (the step timeline's and the engine's counters, after − before),
+which an untraced run prints nowhere else.  Run it
 from the root of the checkout it should measure: a parent commit unpacked
 elsewhere is measured by this file run from there.
 """
@@ -62,14 +64,18 @@ class Stages:
         count[0] += 1
         count[1] += duration
 
-    def staged(self, name: str, fn, after=None):
+    def staged(self, name: str, fn, after=None, of_result=None):
         """``fn`` (a coroutine function) as the stage ``name``: timed, and
-        ``after(*its arguments)`` recorded when it ends."""
+        ``after(*its arguments)`` recorded when it ends, ``of_result(what it
+        returned)`` under that function's name."""
         async def run(*args, **kw):
             self.now = name
             t = time.monotonic()
             try:
-                return await fn(*args, **kw)
+                out = await fn(*args, **kw)
+                if of_result is not None:
+                    self.marks[of_result.__name__] = of_result(out)
+                return out
             finally:
                 self.marks[name + "_s"] = time.monotonic() - t
                 self.marks[name + "_heap"] = {
@@ -99,6 +105,18 @@ def prefill_programs(served, *_) -> dict:
             "prefill_dispatches_total": metrics.get("prefill_dispatches_total")}
 
 
+def window(phase: dict) -> dict:
+    """What the program counted inside the window: after − before of every
+    ``timeline.*`` and ``core.*`` number both edges hold.  An untraced run's
+    result line carries only the client's metrics; the engine's own
+    (a turn's phases by class, did the device wait: PERF.md §3) are these,
+    and under ``--trace 1`` they are the traced run's, whose profiler slows
+    the host well beyond its 2 s slice (PERF.md §6, PR 58)."""
+    before, after = phase["edges"]
+    return {k: after[k] - before[k] for k in sorted(after)
+            if k.startswith(("timeline.", "core.")) and k in before}
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--out", default=None, help="also write the line here")
@@ -115,7 +133,8 @@ def main(argv=None) -> int:
     bench.server.start = stages.staged("start", bench.server.start)
     bench.warm_up = stages.staged("warm_up", bench.warm_up, prefill_programs)
     bench.check.run = stages.staged("check", bench.check.run, prefill_programs)
-    bench.load_phase = stages.staged("load", bench.load_phase)
+    bench.load_phase = stages.staged("load", bench.load_phase,
+                                     of_result=window)
     rc = bench.main(rest)
     line = json.dumps({"argv": rest, **stages.report()})
     print("# stages: " + line, file=sys.stderr, flush=True)

@@ -240,7 +240,7 @@ class _ModuleWalk:
 
     def _consume_in(self, expr, env) -> None:
         """Constant dict-key reads inside an unresolved template hole
-        still count as consumption (``{round(tl['ewma_wall_ms'], 6)}``
+        still count as consumption (``{round(tl['wall_seconds_total'], 6)}``
         consumes the snapshot key)."""
         for n in ast.walk(expr):
             if isinstance(n, ast.Subscript) and isinstance(n.ctx, ast.Load):

@@ -1381,6 +1381,8 @@ class EngineCore:
         work the device no longer waits for), then ``rec`` itself unless
         it may stay in flight for the next turn."""
         prev, self._inflight = self._inflight, rec
+        # under a mesh the array's own is_ready() covers its shards
+        step_timeline.in_flight(rec.out[0].is_ready)
         if isinstance(self.cache, dict) and "moe_counts" in self.cache:
             rec.counted = expert_totals(self.cache["moe_counts"])
         if prev is not None:
@@ -1454,6 +1456,7 @@ class EngineCore:
         callers get an error finish instead of a hung stream.  A dispatch
         in flight is dropped unread: its samples may be the failed step's."""
         fl, self._inflight = self._inflight, None
+        step_timeline.in_flight(None)
         for req in (fl.ended if fl is not None else ()):
             self._release_slot(req)   # ended already: no second finish
         for req in [r for r in self.slots if r is not None]:

@@ -258,8 +258,10 @@ class Metrics:
         # busy steps by what they dispatched: prompt processing, token
         # generation, or both in one step — wall, its device-facing
         # part (dispatch -> readback returned), and the two halves a
-        # turn's pace is read from: launch (upload + dispatch) and
-        # readback (the host blocked on the device: its slack)
+        # turn's pace is read from: launch (upload + dispatch, and the
+        # upload alone) and readback (the host blocked on the device: its
+        # slack — and in how many turns it had none: the dispatch it read
+        # was done already)
         for name, key in (
                 (EM.STEP_CLASS_STEPS_TOTAL, "steps_total"),
                 (EM.STEP_CLASS_WALL_SECONDS_TOTAL, "wall_seconds_total"),
@@ -267,12 +269,30 @@ class Metrics:
                  "device_seconds_total"),
                 (EM.STEP_CLASS_LAUNCH_SECONDS_TOTAL,
                  "launch_seconds_total"),
+                (EM.STEP_CLASS_UPLOAD_SECONDS_TOTAL,
+                 "upload_seconds_total"),
                 (EM.STEP_CLASS_READBACK_SECONDS_TOTAL,
-                 "readback_seconds_total")):
+                 "readback_seconds_total"),
+                (EM.STEP_CLASS_READY_READBACKS_TOTAL,
+                 "ready_readbacks_total")):
             lines.append(f"# TYPE {name} counter")
             for c in CLASSES:
                 lines.append(f'{name}{{class="{c}"}} '
                              f"{round(tl[f'{c}_{key}'], 6)}")
+        # did the device wait?  A launch is starved when the dispatch
+        # before it had finished by the time it was issued; lo and hi
+        # bracket how long the chip stood with nothing queued.  A replica
+        # whose lo grows is host-bound (docs/observability.md)
+        lines.append(f"# TYPE {EM.LAUNCHES_TOTAL} counter")
+        lines.append(f"{EM.LAUNCHES_TOTAL} {tl['launches_total']}")
+        lines.append(f"# TYPE {EM.STARVED_LAUNCHES_TOTAL} counter")
+        lines.append(f"{EM.STARVED_LAUNCHES_TOTAL} "
+                     f"{tl['starved_launches_total']}")
+        lines.append(f"# TYPE {EM.DEVICE_WAIT_SECONDS_TOTAL} counter")
+        for b in ("lo", "hi"):
+            lines.append(
+                f'{EM.DEVICE_WAIT_SECONDS_TOTAL}{{bound="{b}"}} '
+                f"{round(tl[f'device_wait_{b}_seconds_total'], 6)}")
         # ... and decode occupancy, request endings, the engine-side TTFT
         # and its stages, dispatch-ahead, what the models counted on the
         # device, the mesh and the cache
@@ -280,14 +300,6 @@ class Metrics:
         lines.append(f"# TYPE {EM.HOST_GAP_MS_PER_TURN} gauge")
         lines.append(f"{EM.HOST_GAP_MS_PER_TURN} "
                      f"{round(tl['host_gap_ms_per_turn'], 6)}")
-        # smoothed per-step companions to the lifetime means above — the
-        # signal a live dashboard watches while a run warms up
-        lines.append(f"# TYPE {EM.STEP_WALL_MS_EWMA} gauge")
-        lines.append(f"{EM.STEP_WALL_MS_EWMA} "
-                     f"{round(tl['ewma_wall_ms'], 6)}")
-        lines.append(f"# TYPE {EM.HOST_GAP_MS_EWMA} gauge")
-        lines.append(f"{EM.HOST_GAP_MS_EWMA} "
-                     f"{round(tl['ewma_host_gap_ms'], 6)}")
         # measured KV-transfer costs per (src, dst, path) edge
         costs = transfer_costs.snapshot()
         if costs:

@@ -94,8 +94,6 @@ class EngineMetric:
     STEP_WALL_SECONDS_TOTAL = "dynamo_tpu_engine_step_wall_seconds_total"
     STEP_PHASE_SECONDS_TOTAL = "dynamo_tpu_engine_step_phase_seconds_total"
     HOST_GAP_MS_PER_TURN = "dynamo_tpu_engine_host_gap_ms_per_turn"
-    STEP_WALL_MS_EWMA = "dynamo_tpu_engine_step_wall_ms_ewma"
-    HOST_GAP_MS_EWMA = "dynamo_tpu_engine_host_gap_ms_ewma"
     # busy steps by what they dispatched (class: prefill, decode, mixed)
     STEP_CLASS_STEPS_TOTAL = "dynamo_tpu_engine_step_class_steps_total"
     STEP_CLASS_WALL_SECONDS_TOTAL = (
@@ -104,8 +102,18 @@ class EngineMetric:
         "dynamo_tpu_engine_step_class_device_seconds_total")
     STEP_CLASS_LAUNCH_SECONDS_TOTAL = (
         "dynamo_tpu_engine_step_class_launch_seconds_total")
+    STEP_CLASS_UPLOAD_SECONDS_TOTAL = (
+        "dynamo_tpu_engine_step_class_upload_seconds_total")
     STEP_CLASS_READBACK_SECONDS_TOTAL = (
         "dynamo_tpu_engine_step_class_readback_seconds_total")
+    # readbacks that found their dispatch finished: the host had no slack
+    STEP_CLASS_READY_READBACKS_TOTAL = (
+        "dynamo_tpu_engine_step_class_ready_readbacks_total")
+    # did the device wait?  Launches, those before which it had run dry,
+    # and for how long at least (lo) and at most (hi) (obs/timeline.py)
+    LAUNCHES_TOTAL = "dynamo_tpu_engine_launches_total"
+    STARVED_LAUNCHES_TOTAL = "dynamo_tpu_engine_starved_launches_total"
+    DEVICE_WAIT_SECONDS_TOTAL = "dynamo_tpu_engine_device_wait_seconds_total"
 
 
 class KvTransferMetric:
@@ -399,13 +407,16 @@ SCHEMA: dict[str, tuple[str, tuple[str, ...]]] = {
     EngineMetric.STEP_WALL_SECONDS_TOTAL: ("counter", ()),
     EngineMetric.STEP_PHASE_SECONDS_TOTAL: ("counter", ("phase",)),
     EngineMetric.HOST_GAP_MS_PER_TURN: ("gauge", ()),
-    EngineMetric.STEP_WALL_MS_EWMA: ("gauge", ()),
-    EngineMetric.HOST_GAP_MS_EWMA: ("gauge", ()),
     EngineMetric.STEP_CLASS_STEPS_TOTAL: ("counter", ("class",)),
     EngineMetric.STEP_CLASS_WALL_SECONDS_TOTAL: ("counter", ("class",)),
     EngineMetric.STEP_CLASS_DEVICE_SECONDS_TOTAL: ("counter", ("class",)),
     EngineMetric.STEP_CLASS_LAUNCH_SECONDS_TOTAL: ("counter", ("class",)),
+    EngineMetric.STEP_CLASS_UPLOAD_SECONDS_TOTAL: ("counter", ("class",)),
     EngineMetric.STEP_CLASS_READBACK_SECONDS_TOTAL: ("counter", ("class",)),
+    EngineMetric.STEP_CLASS_READY_READBACKS_TOTAL: ("counter", ("class",)),
+    EngineMetric.LAUNCHES_TOTAL: ("counter", ()),
+    EngineMetric.STARVED_LAUNCHES_TOTAL: ("counter", ()),
+    EngineMetric.DEVICE_WAIT_SECONDS_TOTAL: ("counter", ("bound",)),
     KvTransferMetric.CALLS_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.BYTES_TOTAL: ("counter", ("src", "dst", "path")),
     KvTransferMetric.SECONDS_TOTAL: ("counter", ("src", "dst", "path")),

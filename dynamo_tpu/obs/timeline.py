@@ -48,14 +48,55 @@ context lengths) — the engine builds them only while :meth:`profiling`.
 With no session open a phase costs a flag test
 (``TraceAnnotation.is_enabled()``) and no object.
 
-The headline derived number is **host_gap_ms_per_turn** — wall time
-per dispatching step spent *outside* dispatch+readback: the host's own
-work per step.  While a dispatch is in flight that time runs under the
-device program (hidden), and it is device time lost only in the steps
-that read back first.  The aggregates are always on: per busy step about
-twenty clock reads, two small dicts and a handful of float adds;
-per-step *spans* of the dtspan plane are emitted only when that plane is
-enabled.
+**Did the device wait?**  The timeline answers it itself, untraced.  When
+``EngineCore._settle`` makes a dispatch the one in flight it hands over a
+non-blocking probe of it (:meth:`StepTimeline.in_flight`:
+``jax.Array.is_ready`` of one output; under a mesh that covers the shards).
+The probe of the newest program issued is polled wherever the clock is read
+anyway — at ``begin``, at every ``enter`` and at ``end`` (a launch's own close
+polls the program *before* it; the one it issued is polled from the next
+clock read on) — until it has said "done"; a poll may only observe (never ``block_until_ready``,
+never a sleep; one that raises is dropped).  Two stamps are kept for the
+device: ``t_busy``, the newest clock read at which it was known to hold work
+(a poll that said "not done", the return of a readback that blocked, the open
+of the dispatch phase that issued the newest program: it starts no earlier),
+and ``t_done``, the oldest clock read at which the newest program was seen
+finished (a poll that said "done", or the return of its readback).  At the
+close of a ``dispatch`` phase (open ``t_d0``, close ``t_d1``) the launch is
+booked: the program before it not seen done by ``t_d1`` — the device had work
+queued throughout, ``launches_total`` += 1 and no more; seen done, or nothing
+in flight (the serial step, after a drain) — a **starved launch**:
+``starved_launches_total`` += 1, ``device_wait_lo_seconds_total`` +=
+max(0, ``t_d0`` − ``t_done``) (a true lower bound: the program finished no
+later, the next cannot start before its jitted call begins) and
+``device_wait_hi_seconds_total`` += ``t_d1`` − ``t_busy`` (short only of the
+runtime's enqueue-to-start latency: ``benchmarks/device_wait_check.py``
+measures it from a profile).  **Idle is not waiting**: neither reaches back
+beyond the ``begin`` of the first step after one that ended with nothing
+issued and nothing in flight (the engine thread sleeps between the two).
+While :meth:`profiling`, the ``dyn.*`` event of the phase that opens next
+carries the launch's ``dev_wait_lo_us`` / ``dev_wait_hi_us`` (0 when not
+starved), so a kept profile holds the bracket beside the real gap on the
+``XLA Modules`` line.  What it cannot see: the
+enqueue-to-start latency inside ``hi``, a wait shorter than the distance
+between two polls (``lo`` reads 0), and, across chips, the exposed share of a
+collective (the device is busy, waiting for its peers).  At the open of a
+``readback`` the dispatch about to be read is polled once more:
+done already → ``<class>_ready_readbacks_total`` += 1 for the class of the
+*step* — the turns in which the host had no slack at all.  In a turn that
+issued ahead that is the look the launch's close took, so there the ready
+readbacks are the starved launches told apart by class (the launch counters
+are kept as totals only); the two differ by the serial steps, whose launch
+is starved by rule and whose readback blocks.
+
+The headline derived number of the host's side is
+**host_gap_ms_per_turn** — wall time per dispatching step spent *outside*
+dispatch+readback: the host's own work per step.  While a dispatch is in
+flight that time runs under the device program (hidden), and it is device
+time lost only in the steps that read back first.  The aggregates are
+always on: per busy step about twenty clock reads, at most nine polls, two
+small dicts and a handful of float adds; per-step *spans* of the dtspan
+plane are emitted only when that plane is enabled.
 
 ``enter("dispatch", kind=...)`` names the **dispatch kind** (``step``,
 ``decode_multi``, ``prefill_ragged``, ``unified``, ``sp_prefill``,
@@ -74,7 +115,8 @@ classes in one step, or no kind at all) — the class of the dispatch the
 step **issued**, or, when it issued none, of what it finished; so the
 class walls add up to ``wall_seconds_total`` and a class's steps count
 its dispatches.  The class also gets the step's ``upload`` + ``dispatch``
-(**launch**: what it costs the host to hand the device its next program)
+(**launch**: what it costs the host to hand the device its next program;
+the ``upload`` alone beside it, so that launch − upload is the jitted call)
 and its ``readback`` (the host standing blocked on the device: **the
 host's slack in that turn** — near zero, the host sets the pace and the
 device waits); wall − launch − readback is the host's own work in a turn
@@ -97,7 +139,7 @@ import time
 from typing import Callable, Optional
 
 __all__ = ["StepTimeline", "step_timeline", "PHASES", "CLASSES",
-           "KIND_CLASS"]
+           "CLASS_KEYS", "KIND_CLASS"]
 
 PHASES = (
     "kv_spill_restore",
@@ -117,6 +159,14 @@ _DEVICE_FACING = ("dispatch", "readback")
 _SPAN_NAMES = {p: f"dyn.{p}" for p in PHASES}
 
 CLASSES = ("prefill", "decode", "mixed")
+# the snapshot's ``<class>_<key>`` entries (``class_totals``), and which of
+# them are counts
+CLASS_KEYS = (
+    "steps_total", "wall_seconds_total", "device_seconds_total",
+    "launch_seconds_total", "upload_seconds_total", "readback_seconds_total",
+    "ready_readbacks_total",
+)
+_COUNTS = {k for k in CLASS_KEYS if not k.endswith("seconds_total")}
 KIND_CLASS = {
     "step": "prefill",
     "prefill_ragged": "prefill",
@@ -138,7 +188,10 @@ def _trace_annotation():
 class StepTimeline:
     """Process-global (one engine thread writes, metrics readers read;
     torn reads of monotonically-increasing floats are acceptable for
-    monitoring)."""
+    monitoring).  Two engines in one process interleave their steps on it:
+    the numbers are then of neither, but no interleaving may raise, so what
+    another thread can empty between a test and a use is read once into a
+    local (``_flight`` is a tuple, replaced and never changed in place)."""
 
     def __init__(self,
                  clock: Callable[[], float] = time.perf_counter) -> None:
@@ -156,20 +209,34 @@ class StepTimeline:
         self.wall_s_total = 0.0       # busy-step wall time
         self.phase_s_total = {p: 0.0 for p in PHASES}
         self.host_gap_s_total = 0.0   # busy wall - dispatch - readback
-        self.ewma_wall_s = 0.0
-        self.ewma_host_gap_s = 0.0
         # device-facing seconds (dispatch -> readback returned) split by
         # jitted-entrypoint kind — the denominator of the dtperf gauge
         self.dispatch_kind_s: dict[str, float] = {}
         self.dispatch_kind_n: dict[str, int] = {}
-        # busy steps by what they dispatched: count, wall, device-facing
-        self.class_steps = {c: 0 for c in CLASSES}
-        self.class_wall_s = {c: 0.0 for c in CLASSES}
-        self.class_device_s = {c: 0.0 for c in CLASSES}
-        # upload + dispatch, and readback, of those steps
-        self.class_launch_s = {c: 0.0 for c in CLASSES}
-        self.class_readback_s = {c: 0.0 for c in CLASSES}
-        self._alpha = 0.05
+        # by class, under the snapshot's ``<class>_<key>``: busy steps by
+        # what they dispatched (count, wall, device-facing, launch with
+        # its upload, readback and how many found their dispatch done)
+        self.class_totals = {k: dict.fromkeys(CLASSES, 0 if k in _COUNTS
+                                              else 0.0) for k in CLASS_KEYS}
+        # every launch, those before which the device had run dry, and
+        # for how long at least and at most
+        self.launches_total = 0
+        self.starved_launches_total = 0
+        self.device_wait_lo_s_total = 0.0
+        self.device_wait_hi_s_total = 0.0
+        # the device, watched from the host: un-read dispatches oldest
+        # first as [probe, seen done]; the probe in_flight() handed for the
+        # one the open dispatch phase issues; t_busy / t_done / t_cut of
+        # the module docstring (t_done None: the newest is not known done)
+        self._flight: tuple = ()
+        self._issued_probe: Optional[Callable[[], bool]] = None
+        self._t_busy = 0.0
+        self._t_done: Optional[float] = 0.0
+        self._t_cut = 0.0
+        self._t_d0 = 0.0
+        self._no_work = True
+        self._step_ready = 0
+        self._wait_args: Optional[dict] = None
         self._t0: Optional[float] = None
         self._t0_ns = 0
         self._last = 0.0
@@ -194,6 +261,11 @@ class StepTimeline:
         self._issued = set()
         self._kind = None
         self._carried = {}
+        self._step_ready = 0
+        if self._no_work:
+            # idle is not waiting: the step before had nothing to run
+            self._t_cut = now
+        self._observe(now)
         self._t0_ns = time.monotonic_ns()
         self._open(phase)
 
@@ -214,7 +286,12 @@ class StepTimeline:
         next ``carried``; an empty dict says "not known"."""
         if self._t0 is None:
             return  # dispatch helper invoked outside step() (tests)
-        self._close(self._clock())
+        now = self._clock()
+        launched = self._phase == "dispatch"
+        self._leave(now)
+        if not launched:
+            self._observe(now)
+        self._close(now)
         if carried is not None:
             self._carried = carried
         if kind is not None:
@@ -223,16 +300,97 @@ class StepTimeline:
                 self._issued.add(kind)
                 self.dispatch_kind_n[kind] = \
                     self.dispatch_kind_n.get(kind, 0) + 1
+        if phase == "dispatch":
+            self._t_d0 = now
+        elif phase == "readback":
+            flight = self._flight
+            if launched and len(flight) == 1:
+                self._observe(now)  # what is read is what was just issued
+            if flight and (flight[0][1] or self._t_done is not None):
+                self._step_ready += 1   # the host had no slack this turn
         self._open(phase)
+
+    def in_flight(self, probe: Optional[Callable[[], bool]]) -> None:
+        """The dispatch that the open ``dispatch`` phase issued stays
+        un-read for now, and ``probe()`` says without blocking whether it
+        has finished (``jax.Array.is_ready`` of one of its outputs).
+        ``None``: the engine dropped what it had un-read (``fail_all``)."""
+        if probe is None:
+            self._flight = ()
+        elif self._t0 is not None and self._phase == "dispatch":
+            self._issued_probe = probe
+
+    # ------------------------------------------- the device, from the host
+    def _observe(self, now: float) -> None:
+        """One look, at the clock read ``now``, at the newest program
+        issued — unless it has been seen finished already.  A probe may
+        only observe; one that raises is dropped (its program then counts
+        as running until it is read back)."""
+        flight = self._flight
+        if self._t_done is not None or not flight:
+            return
+        entry = flight[-1]
+        if entry[0] is None:
+            return
+        try:
+            done = entry[0]()
+        except Exception:
+            entry[0] = None  # monitoring must never break the step loop
+            return
+        if done:
+            entry[1] = True
+            self._t_done = now
+        else:
+            self._t_busy = now
+
+    def _leave(self, now: float) -> None:
+        """The open phase closes at ``now``: a ``dispatch`` phase has
+        launched a program, a ``readback`` has returned."""
+        if self._phase == "dispatch":
+            self._observe(now)
+            self._launched(now)
+        elif self._phase == "readback":
+            flight = self._flight
+            self._flight = flight[1:]
+            if not flight or not flight[0][1]:
+                self._t_busy = now      # it blocked: work until now
+            if len(flight) < 2 and self._t_done is None:
+                self._t_done = now      # the newest program has been read
+
+    def _launched(self, now: float) -> None:
+        """Book the launch whose ``dispatch`` phase ran ``_t_d0`` .. ``now``
+        (module docstring, "Did the device wait?")."""
+        self.launches_total += 1
+        lo = hi = 0.0
+        t_done = self._t_done
+        if t_done is not None:
+            # the device had nothing queued: it waited for this launch
+            lo = max(0.0, self._t_d0 - max(t_done, self._t_cut))
+            hi = now - max(self._t_busy, self._t_cut)
+            self.starved_launches_total += 1
+            self.device_wait_lo_s_total += lo
+            self.device_wait_hi_s_total += hi
+        self._wait_args = ({"dev_wait_lo_us": round(lo * 1e6, 1),
+                            "dev_wait_hi_us": round(hi * 1e6, 1)}
+                           if self._annotation.is_enabled() else None)
+        # the program just issued is the newest now; the engine keeps at
+        # most two un-read, and then only until the older is read
+        self._flight = self._flight[-1:] + ([self._issued_probe, False],)
+        self._issued_probe = None
+        self._t_done = None
+        self._t_busy = max(self._t_busy, self._t_d0)  # it starts no earlier
 
     def _open(self, phase: str) -> None:
         self._phase = phase
         if self._annotation.is_enabled():
             kind = self._kind if phase in _DEVICE_FACING else None
             carried = self._carried if phase in _DISPATCH_PHASES else {}
+            # the launch that closed last, on the event that follows it
+            wait, self._wait_args = self._wait_args or {}, None
             span = self._annotation(
                 _SPAN_NAMES[phase], step=self.busy_steps_total,
-                kind=kind or "", t_mono_ns=time.monotonic_ns(), **carried)
+                kind=kind or "", t_mono_ns=time.monotonic_ns(), **carried,
+                **wait)
             span.__enter__()
             self._span = span
 
@@ -251,15 +409,21 @@ class StepTimeline:
             self._span = None
 
     def end(self) -> None:
-        if self._t0 is None:
+        t0 = self._t0
+        if t0 is None:
             return
         now = self._clock()
+        launched = self._phase == "dispatch"
+        self._leave(now)
+        if not launched:
+            self._observe(now)
         self._close(now)
         phases = self._phases
-        wall = now - self._t0
+        wall = now - t0
         t0_ns = self._t0_ns
         self._t0 = None
         busy = any(phases.get(p) for p in _DISPATCH_PHASES)
+        self._no_work = not busy and not self._flight
         self.steps_total += 1
         if not busy:
             return  # idle polls would drown the per-turn numbers
@@ -268,22 +432,21 @@ class StepTimeline:
         self.busy_steps_total += 1
         self.wall_s_total += wall
         self.host_gap_s_total += gap
-        for p, v in phases.items():
+        # list(): a second engine's step may add to these meanwhile
+        for p, v in list(phases.items()):
             self.phase_s_total[p] = self.phase_s_total.get(p, 0.0) + v
         classes = {KIND_CLASS.get(k, "mixed")
-                   for k in (self._issued or self._step_kinds)}
+                   for k in list(self._issued or self._step_kinds)}
         cls = classes.pop() if len(classes) == 1 else "mixed"
-        self.class_steps[cls] += 1
-        self.class_wall_s[cls] += wall
-        self.class_device_s[cls] += facing
-        self.class_launch_s[cls] += (phases.get("upload", 0.0)
-                                     + phases.get("dispatch", 0.0))
-        self.class_readback_s[cls] += phases.get("readback", 0.0)
-        a = self._alpha
-        self.ewma_wall_s = wall if self.busy_steps_total == 1 else (
-            (1 - a) * self.ewma_wall_s + a * wall)
-        self.ewma_host_gap_s = gap if self.busy_steps_total == 1 else (
-            (1 - a) * self.ewma_host_gap_s + a * gap)
+        upload = phases.get("upload", 0.0)
+        for key, v in (
+                ("steps_total", 1), ("wall_seconds_total", wall),
+                ("device_seconds_total", facing),
+                ("launch_seconds_total", upload + phases.get("dispatch", 0.0)),
+                ("upload_seconds_total", upload),
+                ("readback_seconds_total", phases.get("readback", 0.0)),
+                ("ready_readbacks_total", self._step_ready)):
+            self.class_totals[key][cls] += v
         self._emit_step_span(t0_ns, wall, phases)
 
     # ----------------------------------------------------------- trace emit
@@ -347,19 +510,14 @@ class StepTimeline:
             "busy_steps_total": self.busy_steps_total,
             "wall_seconds_total": self.wall_s_total,
             "host_gap_ms_per_turn": self.host_gap_ms_per_turn,
-            "ewma_wall_ms": self.ewma_wall_s * 1e3,
-            "ewma_host_gap_ms": self.ewma_host_gap_s * 1e3,
             "phases": {p: self.phase_s_total.get(p, 0.0) for p in PHASES},
+            "launches_total": self.launches_total,
+            "starved_launches_total": self.starved_launches_total,
+            "device_wait_lo_seconds_total": self.device_wait_lo_s_total,
+            "device_wait_hi_seconds_total": self.device_wait_hi_s_total,
             # flat, so that a reader of top-level numbers gets them
-            **{f"{c}_steps_total": self.class_steps[c] for c in CLASSES},
-            **{f"{c}_wall_seconds_total": self.class_wall_s[c]
-               for c in CLASSES},
-            **{f"{c}_device_seconds_total": self.class_device_s[c]
-               for c in CLASSES},
-            **{f"{c}_launch_seconds_total": self.class_launch_s[c]
-               for c in CLASSES},
-            **{f"{c}_readback_seconds_total": self.class_readback_s[c]
-               for c in CLASSES},
+            **{f"{c}_{k}": by_class[c]
+               for k, by_class in self.class_totals.items() for c in CLASSES},
             "dispatch_kinds": {
                 k: {
                     "seconds": self.dispatch_kind_s[k],

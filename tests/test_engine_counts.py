@@ -38,8 +38,8 @@ HAND_RENDERED = {
     EngineMetric.PERSIST_SPILL_BYTES_TOTAL,
     EngineMetric.PERSIST_RESIDENT_BYTES, EngineMetric.STEPS_TOTAL,
     EngineMetric.BUSY_STEPS_TOTAL, EngineMetric.STEP_WALL_SECONDS_TOTAL,
-    EngineMetric.HOST_GAP_MS_PER_TURN, EngineMetric.STEP_WALL_MS_EWMA,
-    EngineMetric.HOST_GAP_MS_EWMA}
+    EngineMetric.HOST_GAP_MS_PER_TURN, EngineMetric.LAUNCHES_TOTAL,
+    EngineMetric.STARVED_LAUNCHES_TOTAL}
 
 
 @pytest.fixture(scope="module")
