@@ -584,6 +584,10 @@ class EngineCore:
             counts.state_update_kernel = int(
                 model.state_update_impl()[0] == "pallas")
         counts.prefix_reuse = int(self.prefix_reuse)
+        # ... and which layers read a window of the context only
+        counts.window_layers = int(getattr(model.config, "window_layers", 0))
+        counts.sliding_window = int(
+            getattr(model.config, "sliding_window", None) or 0)
         # whether the model's forward sizes something of its own by a
         # prefill's ``prefix_blocks``; where it only hands the value to the
         # attention call, that call's dispatch rule says whether the value
@@ -916,11 +920,22 @@ class EngineCore:
         g, c = decode_group_and_chunk(
             len(seq_lens), s_q, self.config.max_blocks_per_seq,
             *self._decode_tiling)
-        blocks = -(-seq_lens // self.config.block_size)
+        bs = self.config.block_size
+        blocks = -(-seq_lens // bs)
         walked = int(blocks.sum())
         bound = int((-(-blocks.reshape(-1, g).max(axis=1) // c)).sum()) * g * c
         self.counts.decode_kv_blocks_walked_total += walked
         self.counts.decode_kv_blocks_group_bound_total += bound
+        layers = self.counts.window_layers
+        if layers:
+            # a window layer's walk begins at the block that holds the
+            # first query's oldest visible key (position context - s_q + 1
+            # - window: ops/pallas/decode_attention.py)
+            first = np.maximum(
+                seq_lens - s_q + 1 - self.counts.sliding_window, 0) // bs
+            self.counts.decode_kv_window_blocks_walked_total += layers * int(
+                (blocks - np.minimum(first, blocks)).sum())
+            self.counts.decode_kv_window_blocks_span_total += layers * walked
 
     def _prefix_blocks(self, phase: str, blocks: int, span: int) -> int:
         """The static ``prefix_blocks`` of a prefill dispatch ("prefill":
@@ -945,7 +960,8 @@ class EngineCore:
         """phase -> ("pallas" | "xla", why), as the dispatch in
         ops/paged_attention.py decides it for this engine's geometry and
         mesh (the same static rule, asked up front for the start-up
-        line).  ``windowed`` is the worst case over a request's life."""
+        line).  ``windowed`` is the worst case over a request's life; the
+        line then names the layers that have the window."""
         from dynamo_tpu.ops.paged_attention import (
             ATTENTION_PHASES,
             attention_impl,
@@ -955,15 +971,21 @@ class EngineCore:
             # a model with attention kernels of its own names them
             return self.model.attention_impls()
         window = getattr(self.model.config, "sliding_window", None)
-        return {
+        windowed = window is not None and self.config.max_model_len > window
+        impls = {
             phase: attention_impl(
                 phase, num_kv_heads=self.model.config.num_kv_heads,
                 block_size=self.config.block_size, quant=self.cache_quant,
-                windowed=(window is not None
-                          and self.config.max_model_len > window),
-                tp=self.counts.mesh_tp)
+                windowed=windowed, tp=self.counts.mesh_tp)
             for phase in ATTENTION_PHASES
         }
+        if windowed:    # which layers: "6 window layers of 1,024, 2 full"
+            n = self.counts.window_layers
+            kinds = (f"{n} window layers of {window:,}, "
+                     f"{self.model.config.num_layers - n} full")
+            impls = {phase: (impl, f"{why}; {kinds}")
+                     for phase, (impl, why) in impls.items()}
+        return impls
 
     # ------------------------------------------------------- JSON grammar
     def attach_grammar_tokenizer(self, tokenizer, eos_ids=None) -> None:

@@ -28,11 +28,44 @@ SUPPORTED_ARCHITECTURES = {
     "GemmaForCausalLM",
     "Gemma2ForCausalLM",
     "OuroForCausalLM",
+    "MellumForCausalLM",
 }
+
+# kinds of attention layer a ``layer_types`` list may name
+LAYER_KINDS = ("sliding_attention", "full_attention")
+# rope_type of one kind's ``rope_parameters`` -> how LlamaModel builds it
+ROPE_KINDS = ("default", "linear", "llama3", "yarn")
+
+
+def layer_period(layer_types) -> tuple:
+    """The repeating unit of a layer pattern: the shortest prefix P with
+    ``layer_types[i] == P[i mod len(P)]`` for every layer.  The stack must be
+    a whole number of them (``LlamaModel`` scans periods, each period's layers
+    unrolled in its body): a last period cut short is refused."""
+    types = tuple(layer_types)
+    p = next(p for p in range(1, len(types) + 1)
+             if all(t == types[i % p] for i, t in enumerate(types)))
+    if len(types) % p:
+        raise ValueError(
+            f"layer_types: {len(types)} layers are not a whole number of the "
+            f"period {types[:p]} (a ragged last period)")
+    return types[:p]
 
 
 @dataclass
 class ModelConfig:
+    """What ``LlamaModel`` needs to know of an architecture.
+
+    Layers of more than one kind: a stack whose layers differ only in their
+    attention's window and rope (``layer_types`` + ``rope_parameters``: window
+    layers served as windows, by the windowed Pallas kernels on the TPU,
+    beside full layers with a rope of their own, YaRN included) is one
+    configuration of this class, because the stacked layer parameters keep
+    one shape.  Still refused or served in full: a stack that mixes dense and
+    expert MLPs (refused: two parameter shapes), Gemma2's and Qwen2/3's
+    window interleaves (served with full attention and a warning, see
+    ``sliding_window``), YaRN as a uniform ``rope_scaling`` (refused)."""
+
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -48,14 +81,29 @@ class ModelConfig:
     attention_bias: bool = False
     # Qwen3-style per-head RMSNorm on q and k (over head_dim, before RoPE)
     qk_norm: bool = False
-    # Uniform sliding-window size (Mistral/Phi3): attention masks keys
-    # older than `window` positions — EXACT HF semantics.  The attention
-    # dispatch applies it only when the static context bound can exceed
-    # the window (ops/paged_attention.py); deployments whose max_model_len
-    # fits inside the window keep the flash kernels (full == windowed
-    # there).  Gemma2's interleaved local/global windows are NOT this
-    # field — from_hf_config nulls it for Gemma2 with a warning.
+    # Sliding-window size: attention masks keys older than `window`
+    # positions — EXACT HF semantics (query p sees key j iff 0 <= p - j <
+    # window).  With ``layer_types`` None every layer has it (Mistral/Phi3);
+    # otherwise the layers of kind "sliding_attention" have it and the
+    # "full_attention" ones do not.  The attention dispatch applies it only
+    # when the static context bound can exceed the window
+    # (ops/paged_attention.py): a deployment whose max_model_len fits inside
+    # it traces full attention, which is exact there.  Two interleaves are
+    # still NOT served as windows: from_hf_config nulls this field, with a
+    # warning, for Gemma2 (local/global by layer parity) and for a Qwen2 /
+    # Qwen3 config with max_window_layers != 0 (no configuration of the
+    # benchmark is theirs).
     sliding_window: Optional[int] = None
+    # The kind of every layer ("sliding_attention" | "full_attention"), a
+    # whole number of equal periods (``layer_period``); None = one kind, the
+    # stack as it always was.  ``rope_parameters`` then holds one rope per
+    # kind, in HF's per-kind form ({"rope_type", "rope_theta", and for YaRN
+    # "factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "attention_factor"}): LlamaModel turns q and k of a layer
+    # by its kind's frequencies and multiplies cos and sin by its
+    # attention factor.
+    layer_types: Optional[tuple] = None
+    rope_parameters: Optional[dict] = None
     # MoE (Mixtral-style); num_experts == 0 → dense MLP
     num_experts: int = 0
     num_experts_per_tok: int = 2
@@ -95,6 +143,28 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_heads
+        if self.layer_types is not None:
+            self.layer_types = tuple(self.layer_types)
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, "
+                    f"num_layers is {self.num_layers}")
+            layer_period(self.layer_types)      # refuses a ragged period
+            for kind in set(self.layer_types):
+                if kind not in LAYER_KINDS:
+                    raise ValueError(
+                        f"layer_types: unknown kind {kind!r} "
+                        f"(known: {LAYER_KINDS})")
+                rope = (self.rope_parameters or {}).get(kind)
+                if rope is None:
+                    raise ValueError(
+                        f"rope_parameters has no rope for layers of kind "
+                        f"{kind!r}")
+                rope_type = rope.get("rope_type", "default")
+                if rope_type not in ROPE_KINDS:
+                    raise ValueError(
+                        f"rope_parameters[{kind!r}]: unknown rope kind "
+                        f"{rope_type!r} (known: {ROPE_KINDS})")
 
     @property
     def jax_dtype(self):
@@ -103,6 +173,22 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def period(self) -> Optional[tuple]:
+        """The kinds of one period of ``layer_types``; None for a stack of
+        one kind."""
+        return None if self.layer_types is None else layer_period(
+            self.layer_types)
+
+    @property
+    def window_layers(self) -> int:
+        """Layers whose attention is masked by ``sliding_window``."""
+        if self.sliding_window is None:
+            return 0
+        if self.layer_types is None:
+            return self.num_layers
+        return self.layer_types.count("sliding_attention")
 
     @classmethod
     def tiny(cls, **kw) -> "ModelConfig":
@@ -137,9 +223,15 @@ class ModelConfig:
                 f"unsupported architecture {arch!r}; supported: "
                 f"{sorted(SUPPORTED_ARCHITECTURES)}"
             )
+        if not archs and cfg.get("model_type") == "mellum":
+            arch = "MellumForCausalLM"
         gemma = arch in ("GemmaForCausalLM", "Gemma2ForCausalLM")
         ouro = arch == "OuroForCausalLM"
-        qwen3_moe = arch == "Qwen3MoeForCausalLM"
+        mellum = arch == "MellumForCausalLM"
+        # the Qwen3-MoE family's keys and conventions: num_experts,
+        # moe_intermediate_size, norm_topk_prob (default false), a per-head
+        # RMSNorm on q and k that no key announces
+        qwen3_moe = arch == "Qwen3MoeForCausalLM" or mellum
         if qwen3_moe and (
             cfg.get("decoder_sparse_step", 1) != 1 or cfg.get("mlp_only_layers")
         ):
@@ -153,12 +245,28 @@ class ModelConfig:
         if rs:
             kind = rs.get("rope_type") or rs.get("type")
             if kind not in ("llama3", "linear", "default", None):
-                # longrope/yarn/dynamic are not implemented — be loud, a
-                # silently-unscaled rope corrupts every long prompt
+                # longrope/dynamic are not implemented, and YaRN is served
+                # only as one kind's rope of ``rope_parameters`` (below) —
+                # be loud, a silently-unscaled rope corrupts every long
+                # prompt
                 raise ValueError(
                     f"rope_scaling type {kind!r} not supported "
                     "(supported: llama3, linear)"
                 )
+        layer_types = ropes = None
+        if mellum:
+            # two kinds of attention layer, a rope each (__post_init__
+            # checks kinds, period and ropes); every layer routes
+            layer_types = tuple(cfg["layer_types"])
+            ropes = {kind: dict(rope)
+                     for kind, rope in cfg["rope_parameters"].items()
+                     if isinstance(rope, dict)}
+            dense = sorted(set(cfg.get("mlp_layer_types") or ()) - {"sparse"})
+            if dense:
+                raise ValueError(
+                    f"mlp_layer_types: layers of kind {dense} are not "
+                    "supported (every layer must be 'sparse': the stacked "
+                    "layer parameters have one shape)")
         act = cfg.get("hidden_activation") or cfg.get("hidden_act") or "silu"
         # original Gemma-1 configs say "gelu" but the canonical weights were
         # trained with tanh-approx GELU (transformers maps it the same way);
@@ -175,8 +283,18 @@ class ModelConfig:
                 f"supported: {sorted(act_map)}"
             )
         sliding = cfg.get("sliding_window")
-        if sliding and arch in ("Qwen2ForCausalLM", "Qwen3ForCausalLM",
-                                "Qwen3MoeForCausalLM"):
+        if sliding and mellum:
+            if not cfg.get("use_sliding_window", True):
+                sliding = None      # every layer attends in full
+            elif cfg.get("max_window_layers", 0) != 0:
+                # layer_types says which layers have the window; a second
+                # rule by depth has no published reading for this family
+                raise ValueError(
+                    f"{arch} with max_window_layers="
+                    f"{cfg['max_window_layers']}: only 0 (layer_types alone "
+                    "decides which layers have the window) is supported")
+        elif sliding and arch in ("Qwen2ForCausalLM", "Qwen3ForCausalLM",
+                                  "Qwen3MoeForCausalLM"):
             if not cfg.get("use_sliding_window"):
                 # HF Qwen configs carry sliding_window but gate it behind
                 # use_sliding_window (default False) — honoring the number
@@ -225,7 +343,10 @@ class ModelConfig:
             num_heads=cfg["num_attention_heads"],
             num_kv_heads=cfg.get("num_key_value_heads", cfg["num_attention_heads"]),
             head_dim=cfg.get("head_dim"),
-            rope_theta=cfg.get("rope_theta", 10000.0),
+            # one number for the code that knows one rope; a model with
+            # rope_parameters turns by its kinds' own
+            rope_theta=(ropes.get(layer_types[0], {}).get("rope_theta", 1e4)
+                        if ropes else cfg.get("rope_theta", 10000.0)),
             rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
             max_position_embeddings=cfg.get("max_position_embeddings", 4096),
             # HF Gemma checkpoints tie embeddings and omit the flag
@@ -233,8 +354,11 @@ class ModelConfig:
             # HF Qwen2 attention always carries QKV bias; Llama exposes an
             # explicit attention_bias flag (default False)
             attention_bias=cfg.get("attention_bias", arch == "Qwen2ForCausalLM"),
-            qk_norm=arch in ("Qwen3ForCausalLM", "Qwen3MoeForCausalLM"),
+            qk_norm=arch in ("Qwen3ForCausalLM", "Qwen3MoeForCausalLM",
+                             "MellumForCausalLM"),
             sliding_window=sliding,
+            layer_types=layer_types,
+            rope_parameters=ropes,
             num_experts=cfg.get("num_local_experts",
                                 cfg.get("num_experts", 0) if qwen3_moe else 0),
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2),

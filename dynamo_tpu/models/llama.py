@@ -6,7 +6,12 @@ Design (TPU-first, not a port):
     run paged attention over their full context (ops/paged_attention.py).
   * ``lax.scan`` over layers: per-layer weights are stacked on a leading L
     axis so the whole stack compiles once — fast XLA compiles even at 80
-    layers.  What the loop body reads decides how a stack reaches it:
+    layers.  A stack whose layers differ in kind (``ModelConfig.layer_types``:
+    window and full attention, a rope each; docs/window_layers.md) keeps the
+    one stacked pytree, because every layer's parameters have one shape, and
+    scans PERIODS of the pattern, the period's layers unrolled in the body,
+    each with its kind's static window, frequencies and score factor.  What
+    the loop body reads decides how a stack reaches it:
       - norms, attention projections and the dense FFN ride the scan as
         ``xs``; their consumers are XLA dot fusions, which read the
         layer's slice in place (the chip's trace shows the FFN matrices
@@ -140,8 +145,10 @@ def yarn_inv_freq(head_dim: int, theta: float, factor: float,
     ``beta_slow`` times are divided by ``factor``, and the band between is
     a ramp over the pair index, from floor(d(beta_fast)) to
     ceil(d(beta_slow)) with d(r) = D·ln(original_max / 2πr) / (2 ln θ).
-    The latent-attention family's readers call it (models/glm_dsa.py);
-    ``ModelConfig`` still refuses YaRN for the Llama family."""
+    The latent-attention family's readers call it (models/glm_dsa.py), and
+    ``kind_rope`` below for a layer kind whose ``rope_parameters`` say
+    ``yarn``; as a uniform ``rope_scaling`` ``ModelConfig`` still refuses
+    YaRN for the Llama family."""
     half = head_dim // 2
     inv = 1.0 / (theta ** (np.arange(0, half, dtype=np.float64) * 2.0
                            / head_dim))
@@ -162,13 +169,32 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
+def kind_rope(head_dim: int, rope: dict) -> tuple[jax.Array, float]:
+    """(inverse frequencies [D/2], the factor on cos and sin) of one layer
+    kind's rope, from its entry of HF ``rope_parameters``.  YaRN blends the
+    frequencies at every position, not only past the trained context, and
+    multiplies cos and sin by ``attention_factor`` (the config's, else
+    0.1·ln(factor) + 1), so the kind's scores carry its square."""
+    theta = float(rope["rope_theta"])
+    if rope.get("rope_type", "default") != "yarn":
+        return rope_inv_freq(head_dim, theta, rope), 1.0
+    factor = float(rope["factor"])
+    inv = yarn_inv_freq(
+        head_dim, theta, factor, int(rope["original_max_position_embeddings"]),
+        float(rope.get("beta_fast", 32.0)), float(rope.get("beta_slow", 1.0)))
+    return inv, float(rope.get("attention_factor")
+                      or yarn_mscale(factor, 1.0))
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
                inv_freq: Optional[jax.Array] = None,
-               rotary_dim: Optional[int] = None) -> jax.Array:
+               rotary_dim: Optional[int] = None,
+               factor: float = 1.0) -> jax.Array:
     """HF-Llama rotate-half RoPE.  x: [B,S,H,D], positions: [B,S].  With
     ``rotary_dim`` < D (``partial_rotary_factor``) the first ``rotary_dim``
     dimensions of every head are rotated, half against half within them, and
-    the others pass as they are."""
+    the others pass as they are.  ``factor`` multiplies cos and sin (YaRN's
+    attention factor, the HF way)."""
     if rotary_dim is not None and rotary_dim != x.shape[-1]:
         turned = apply_rope(x[..., :rotary_dim], positions, theta, inv_freq)
         return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)
@@ -179,6 +205,8 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
     angles = positions.astype(jnp.float32)[:, :, None] * inv_freq[None, None, :]
     cos = jnp.cos(angles)[:, :, None, :]  # [B,S,1,half]
     sin = jnp.sin(angles)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -212,6 +240,12 @@ class LlamaModel:
         self.inv_freq = rope_inv_freq(
             config.head_dim, config.rope_theta, config.rope_scaling
         )
+        # a stack of more than one kind of layer: each kind's rope, and the
+        # kinds of one period (forward scans periods)
+        self.period = config.period
+        self.kind_ropes = {
+            kind: kind_rope(config.head_dim, config.rope_parameters[kind])
+            for kind in set(self.period or ())}
 
     @property
     def cache_layers(self) -> int:
@@ -222,9 +256,11 @@ class LlamaModel:
 
     @property
     def supports_seq_parallel(self) -> bool:
-        """``forward_seq_parallel`` walks the stack once: the engine
-        refuses ``sp_prefill_threshold`` for a looped decoder at start-up."""
-        return self.config.ut_steps == 1
+        """``forward_seq_parallel`` walks a stack of one kind once: the
+        engine refuses ``sp_prefill_threshold`` at start-up for a looped
+        decoder and for a stack with ``layer_types`` (ring attention under
+        a window or a rope per layer kind is not written)."""
+        return self.config.ut_steps == 1 and self.period is None
 
     # ------------------------------------------------------------------ init
     def init_params(self, rng: jax.Array, quantized: bool = False) -> Params:
@@ -546,17 +582,51 @@ class LlamaModel:
             experts = {k: layers[k] for k in _EXPERT_KEYS}
             layers = {k: w for k, w in layers.items() if k not in experts}
 
-        def layer_step(carry, layer_in, first=None):
+        def attend(q, k, v, cache, ci, window):
+            if ragged_prefill:
+                seq_ids, seq_starts, row_offsets = ragged
+                return ragged_prefill_attention(
+                    q, k, v, cache, ci, block_tables, seq_lens,
+                    seq_starts, row_offsets, seq_ids, prefix_blocks,
+                    sm_scale=self.sm_scale,
+                    logit_cap=cfg.attn_logit_softcap,
+                    window=window,
+                )
+            if fast_prefill:
+                return prefill_attention(
+                    q, k, v, cache, ci, block_tables, seq_lens,
+                    positions[:, 0], prefix_blocks,
+                    sm_scale=self.sm_scale,
+                    logit_cap=cfg.attn_logit_softcap,
+                    window=window,
+                )
+            return paged_attention_layer(
+                q, cache, ci, block_tables, seq_lens, positions,
+                sm_scale=self.sm_scale,
+                logit_cap=cfg.attn_logit_softcap,
+                window=window,
+            )
+
+        def layer_step(carry, layer_in, first=None, kind=None):
             """``first``: the cache layer of this pass's layer 0 (None: 0,
-            the only pass)."""
+            the only pass).  ``kind`` (static): the layer's entry of
+            ``layer_types`` - its rope, whether the window is its, and the
+            scope its attention call shows under (``window`` / ``full``,
+            inside ``attn``); None in a stack of one kind."""
             h, cache = carry
             lp, li = layer_in   # this layer's weights, its index in L
             ci = li if first is None else first + li   # its cache layer
+            window, rope = cfg.sliding_window, {"inv_freq": self.inv_freq}
+            if kind is not None:
+                inv_freq, factor = self.kind_ropes[kind]
+                rope = {"inv_freq": inv_freq, "factor": factor}
+                if kind == "full_attention":
+                    window = None
             with jax.named_scope("attn_proj"):
                 x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps, uo)
                 q, k, v = _qkv_proj(cfg, lp, x, b, s)
-                q = apply_rope(q, positions, cfg.rope_theta, self.inv_freq)
-                k = apply_rope(k, positions, cfg.rope_theta, self.inv_freq)
+                q = apply_rope(q, positions, cfg.rope_theta, **rope)
+                k = apply_rope(k, positions, cfg.rope_theta, **rope)
             with jax.named_scope("attn"):
                 # fast_prefill/ragged imply the engine's block-aligned
                 # contiguous span layout — unlocks the block-granular write
@@ -565,30 +635,12 @@ class LlamaModel:
                     block_aligned=fast_prefill or ragged_prefill,
                     row_tokens=ragged_row_tokens if ragged_prefill else 0,
                 )
-                if ragged_prefill:
-                    seq_ids, seq_starts, row_offsets = ragged
-                    attn = ragged_prefill_attention(
-                        q, k, v, cache, ci, block_tables, seq_lens,
-                        seq_starts, row_offsets, seq_ids, prefix_blocks,
-                        sm_scale=self.sm_scale,
-                        logit_cap=cfg.attn_logit_softcap,
-                        window=cfg.sliding_window,
-                    )
-                elif fast_prefill:
-                    attn = prefill_attention(
-                        q, k, v, cache, ci, block_tables, seq_lens,
-                        positions[:, 0], prefix_blocks,
-                        sm_scale=self.sm_scale,
-                        logit_cap=cfg.attn_logit_softcap,
-                        window=cfg.sliding_window,
-                    )
+                if kind is None:
+                    attn = attend(q, k, v, cache, ci, window)
                 else:
-                    attn = paged_attention_layer(
-                        q, cache, ci, block_tables, seq_lens, positions,
-                        sm_scale=self.sm_scale,
-                        logit_cap=cfg.attn_logit_softcap,
-                        window=cfg.sliding_window,
-                    )
+                    with jax.named_scope(
+                            "full" if window is None else "window"):
+                        attn = attend(q, k, v, cache, ci, window)
             with jax.named_scope("attn_out"):
                 attn_out = matmul(attn.reshape(b, s, hq * dh), lp["wo"])
                 if cfg.post_norms:  # sandwich: norm the residual branch
@@ -609,13 +661,36 @@ class LlamaModel:
                 h = h + mlp_out
             return (h, cache), None
 
+        def period_step(carry, period_in, first=None):
+            """One period of ``layer_types``, its layers unrolled: each
+            with its kind's static window, frequencies and factor.  The
+            scanned weights arrive as [P, ...]; the closed-over expert
+            stacks are read at layer period x P + j."""
+            lps, pi = period_in
+            for j, kind in enumerate(self.period):
+                lp = jax.tree.map(lambda w: w[j], lps)
+                carry, _ = layer_step(
+                    carry, (lp, pi * len(self.period) + j), first, kind)
+            return carry, None
+
         def run_pass(hidden, cache, first=None):
             """The layer scan once, then the final norm."""
-            (hidden, cache), _ = jax.lax.scan(
-                lambda carry, layer_in: layer_step(carry, layer_in, first),
-                (hidden, cache),
-                (layers, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
-            )
+            if self.period is None:
+                (hidden, cache), _ = jax.lax.scan(
+                    lambda carry, layer_in: layer_step(carry, layer_in, first),
+                    (hidden, cache),
+                    (layers, jnp.arange(cfg.num_layers, dtype=jnp.int32)),
+                )
+            else:
+                p = len(self.period)
+                (hidden, cache), _ = jax.lax.scan(
+                    lambda carry, period_in: period_step(
+                        carry, period_in, first),
+                    (hidden, cache),
+                    (jax.tree.map(
+                        lambda w: w.reshape(-1, p, *w.shape[1:]), layers),
+                     jnp.arange(cfg.num_layers // p, dtype=jnp.int32)),
+                )
             with jax.named_scope("logits"):
                 hidden = rms_norm(hidden, params["final_norm"],
                                   cfg.rms_norm_eps, cfg.rmsnorm_unit_offset)
@@ -684,9 +759,10 @@ class LlamaModel:
         cfg = self.config
         if not self.supports_seq_parallel:
             raise NotImplementedError(
-                "seq-parallel prefill walks the layer stack once; a looped "
-                f"decoder (ut_steps={cfg.ut_steps}) is served by forward() "
-                "only: disable sp_prefill_threshold")
+                "seq-parallel prefill walks a stack of one kind of layer "
+                f"once; a looped decoder (ut_steps={cfg.ut_steps}) or a "
+                f"stack with layer_types (period {self.period}) is served "
+                "by forward() only: disable sp_prefill_threshold")
         b, s = tokens.shape
         dh, hq, hk = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
 

@@ -348,6 +348,16 @@ REQUEST_FAMILY = (
            "what fetching every slot of a kernel group up to the group's "
            "longest row took for the same dispatches; 1 - walked / bound is "
            "the share of fetches a row's own walk spares"),
+    _count("dynamo_tpu_engine_decode_kv_window_blocks_walked_total",
+           "counter",
+           "K/V blocks the decode rows' walks take in the layers of "
+           "sliding-window attention, summed over those layers: a row's "
+           "blocks from the one that holds position context - window to its "
+           "last (docs/window_layers.md); 0 for a model without such layers"),
+    _count("dynamo_tpu_engine_decode_kv_window_blocks_span_total", "counter",
+           "the blocks those rows own, ceil(context / block) a row, summed "
+           "over the same layers: what the walk would take without the "
+           "window; walked / span is attn.window_walked_pct"),
     _count("dynamo_tpu_engine_cache_layers", "gauge",
            "layers of the K/V cache: the model's layers, times its passes "
            "for a looped decoder"),
@@ -375,6 +385,12 @@ REQUEST_FAMILY = (
            key="device_gets_total"),
     _count(None, "gauge", "1: the decode program updates a slot's recurrent "
            "state in one kernel", key="state_update_kernel"),
+    _count(None, "gauge", "layers whose attention is masked by the model's "
+           "sliding window (every layer of a uniform window model, the "
+           "sliding_attention layers of layer_types); 0 without a window",
+           key="window_layers"),
+    _count(None, "gauge", "that window, in tokens; 0 without one",
+           key="sliding_window"),
 )
 
 ENGINE_COUNTS = PREFILL_FAMILY + REQUEST_FAMILY

@@ -79,10 +79,12 @@ def attention_impl(
     serving path catches a kernel error and falls back, so a kernel
     this says ``pallas`` for either compiles or takes the server down.
 
-    ``windowed``: the static attended span can exceed a sliding window
-    (the kernels are full-attention).  ``tp``: size of the mesh's
-    tensor-parallel axis the call is traced under; the kernels then run
-    per kv-head shard under ``shard_map``.
+    ``windowed``: the static attended span can exceed a sliding window;
+    the kernels then run in their windowed form (a row's walk begins at
+    the block its band begins in), which the answer names and nothing
+    else: int8 K/V and a mesh keep the rules they have.  ``tp``: size of
+    the mesh's tensor-parallel axis the call is traced under; the kernels
+    then run per kv-head shard under ``shard_map``.
     """
     for var in ("DYNAMO_DISABLE_PALLAS", ATTENTION_PHASES[phase]):
         if os.environ.get(var):
@@ -90,8 +92,6 @@ def attention_impl(
     backend = jax.default_backend()
     if backend != "tpu":
         return "xla", f"backend is {backend}"
-    if windowed:
-        return "xla", "sliding window shorter than the attended span"
     if quant and block_size % 32:
         # int8 payload tiles are (32, 128): Bs % 32 != 0 pads the block's
         # sublane dim and the kernels' per-block DMA cannot slice a
@@ -103,7 +103,8 @@ def attention_impl(
         # the scale pool's head axis is tile-padded, so an even split of
         # it does not follow the data's head-major lane split
         return "xla", "int8 KV scale pool is not sharded per kv head"
-    return "pallas", "tpu" if tp == 1 else f"tpu, shard_map over tp={tp}"
+    why = "tpu" if tp == 1 else f"tpu, shard_map over tp={tp}"
+    return "pallas", why + ", windowed kernel" if windowed else why
 
 
 # sparse latent attention (ops/pallas/mla_sparse_attention.py): the phase is
@@ -221,10 +222,11 @@ def paged_attention_layer(
     serves S > MQ_MAX_S and non-TPU backends by materialising the layer
     slice.
 
-    ``window`` (Mistral/Phi3 sliding window) routes to the position-exact
-    oracle ONLY when the STATIC context bound (M·Bs) can actually exceed
-    the window — a deployment whose max_model_len fits inside the window
-    is mathematically full attention and keeps the flash kernels.
+    ``window`` (a sliding window) counts ONLY when the STATIC context
+    bound (M·Bs) can actually exceed it — a deployment whose max_model_len
+    fits inside the window is mathematically full attention and traces the
+    kernels' full form.  Where it counts, the kernels take it as a static
+    argument (their ``*_window*`` form) and the oracle masks by it.
     """
     b, s, h, d = q.shape
     quant = is_quant(cache)
@@ -249,7 +251,7 @@ def paged_attention_layer(
             # (registry.decode_tiling: 8 rows a group, ~512 KiB a row-chunk)
             kernel = functools.partial(
                 paged_decode_attention, sm_scale=sm_scale,
-                logit_cap=logit_cap)
+                logit_cap=logit_cap, window=window)
             out = _per_kv_head(
                 kernel, tp, (_HEADS3, _CACHE, _REPL, _REPL, _REPL), _HEADS3,
             )(q[:, 0], cache, layer, block_tables, seq_lens)
@@ -258,7 +260,7 @@ def paged_attention_layer(
         # only the owned blocks instead of gathering the padded table
         kernel = functools.partial(
             paged_decode_attention_mq, sm_scale=sm_scale,
-            logit_cap=logit_cap)
+            logit_cap=logit_cap, window=window)
         return _per_kv_head(
             kernel, tp, (_HEADS4, _CACHE, _REPL, _REPL, _REPL, _REPL),
             _HEADS4,
@@ -285,17 +287,20 @@ def _prefill_impl(
     """``("pallas" | "xla", the window to mask by)`` for a prefill dispatch
     of ``span`` tokens on its token axis behind ``prefix_blocks`` cached
     blocks: what ``prefill_attention`` ("prefill") and
-    ``ragged_prefill_attention`` ("ragged") go by.  A sliding window
-    matters only when the STATIC attended span (visible prefix + these
-    tokens) can exceed it; otherwise full attention is exact (no window to
-    mask by) and the flash kernel stays in play."""
-    if window is not None and prefix_blocks * block_size + span <= window:
-        window = None
-    if span <= 1:
-        return "xla", window
-    return attention_impl(
+    ``ragged_prefill_attention`` ("ragged") go by.  On the flash path the
+    kernel masks by the window whatever the prefix (it streams the prefix
+    by its true length, and ``prefix_blocks`` is the one value 0 there:
+    ``prefill_program_key``); on the XLA path a sliding window matters only
+    when the STATIC attended span (visible prefix + these tokens) can
+    exceed it, and otherwise full attention is exact (no window to mask
+    by)."""
+    impl = "xla" if span <= 1 else attention_impl(
         phase, num_kv_heads=num_kv_heads, block_size=block_size, quant=quant,
-        windowed=window is not None, tp=tp)[0], window
+        windowed=window is not None, tp=tp)[0]
+    if (impl == "xla" and window is not None
+            and prefix_blocks * block_size + span <= window):
+        window = None
+    return impl, window
 
 
 def prefill_program_key(
@@ -306,10 +311,11 @@ def prefill_program_key(
     static argument, for a model whose forward passes it to the attention
     call below and reads it nowhere else.  On the flash path the kernel
     streams the prefix by its true length and the value decides nothing
-    (the span fits the window, or there is none), so every prefix gets the
-    one value 0 and two dispatches whose lowered modules would be the same
-    meet one program.  On the XLA path it sizes the gather and stays as it
-    is.  ``geometry``: ``num_kv_heads``, ``block_size``, ``quant`` and
+    (a window the kernel masks by itself, at every prefix), so every
+    prefix gets the one value 0 and two dispatches whose lowered modules
+    would be the same meet one program.  On the XLA path it sizes the
+    gather and stays as it is.  ``geometry``: ``num_kv_heads``,
+    ``block_size``, ``quant`` and
     ``tp``, as ``attention_impl`` takes them."""
     impl, _ = _prefill_impl(phase, prefix_blocks, span, window, **geometry)
     return 0 if impl == "pallas" else prefix_blocks
@@ -350,6 +356,8 @@ def prefill_attention(
     data_ = cache.data if quant else cache
     bs_ = data_.shape[3]
     tp = tp_size()
+    if window is not None and block_tables.shape[1] * bs_ <= window:
+        window = None  # the table cannot hold a context past the window
     impl, window = _prefill_impl(
         "prefill", prefix_blocks, s, window, num_kv_heads=hk,
         block_size=bs_, quant=quant, tp=tp)
@@ -365,7 +373,8 @@ def prefill_attention(
 
         kernel = functools.partial(
             paged_prefill_attention, sm_scale=sm_scale, logit_cap=logit_cap,
-            rows_per_chunk=prefill_rows_per_chunk(q.shape[2] // tp))
+            rows_per_chunk=prefill_rows_per_chunk(q.shape[2] // tp),
+            window=window)
         return _per_kv_head(
             kernel, tp,
             (_HEADS4, _HEADS4, _HEADS4, _CACHE) + (_REPL,) * 4, _HEADS4,
@@ -381,8 +390,13 @@ def prefill_attention(
     allow_f = (i[None, :, None] >= i[None, None, :]) & (i[None, None, :] < fresh)
     if window is not None:
         # fresh-fresh distance is the chunk-index gap (both offsets from
-        # the same block-aligned start)
-        allow_f &= (i[None, :, None] - i[None, None, :]) < window
+        # the same block-aligned start).  A padding query more than a
+        # window past the last real token would see no column at all, and
+        # its NaN row would reach the real rows through the next layer's
+        # V (0 * NaN): padding queries keep the unwindowed mask, as finite
+        # and as discarded as they are without a window
+        padding = i[None, :, None] >= fresh
+        allow_f &= ((i[None, :, None] - i[None, None, :]) < window) | padding
     sf = jnp.where(allow_f[:, None, None], sf, -jnp.inf)
 
     if prefix_blocks == 0:
@@ -411,7 +425,7 @@ def prefill_attention(
         # prefix slot t IS absolute position t (the fast path's identity
         # block layout); query i sits at absolute start + i
         q_pos = start[:, None, None] + i[None, :, None]
-        allow_p &= (q_pos - slot[None, None, :]) < window
+        allow_p &= ((q_pos - slot[None, None, :]) < window) | padding
     sp = jnp.where(allow_p[:, None, None], sp, -jnp.inf)
 
     scores = jnp.concatenate([sp, sf], axis=-1)  # [B, Hk, G, S, T+S]
@@ -472,6 +486,8 @@ def ragged_prefill_attention(
     data = cache.data if quant else cache
     _, n, _, bs, hkd = data.shape
     tp = tp_size()
+    if window is not None and block_tables.shape[1] * bs <= window:
+        window = None  # the table cannot hold a context past the window
     impl, window = _prefill_impl(
         "ragged", prefix_blocks, t, window, num_kv_heads=hk, block_size=bs,
         quant=quant, tp=tp)
@@ -482,7 +498,7 @@ def ragged_prefill_attention(
 
         kernel = functools.partial(
             ragged_paged_prefill_attention, sm_scale=sm_scale,
-            logit_cap=logit_cap)
+            logit_cap=logit_cap, window=window)
         return _per_kv_head(
             kernel, tp,
             (_HEADS4, _HEADS4, _HEADS4, _CACHE) + (_REPL,) * 5, _HEADS4,

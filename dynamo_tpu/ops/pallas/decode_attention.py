@@ -55,8 +55,16 @@ Design (one grid step per GROUP of G sequences, work ∝ each row's context):
     left the unit idle between them.  An update is skipped when all its R
     sequences have ended; one that ended before the others runs masked.
 
+  * A static ``window`` (layers of sliding-window attention) gives the
+    kernel a second form, ``paged_decode_attention_window*`` in a profile:
+    each row's walk begins at the block that holds its first visible key
+    (position len - window for a decode row), chunk by chunk from there,
+    and the positions before the band are masked inside that block.  With
+    ``window=None`` none of that arithmetic is traced.
+
 Semantics match `paged_attention` with S=1: each query row attends over
-slots [0, seq_len) of its own block table.  Rows with seq_len == 0 yield 0.
+slots [0, seq_len) of its own block table (the last ``window`` of them
+under a window).  Rows with seq_len == 0 yield 0.
 
 Reference parity: the reference's engines delegate decode attention to
 vLLM/TRT-LLM paged-attention CUDA kernels; this is the TPU-native
@@ -104,7 +112,7 @@ def _kernel(
     kvbuf,       # [2, G, C, 2, Bs, HkD] cache-dtype (double buffer)
     sems,        # [2, G, C] DMA semaphores
     # (scbuf [2, G, C, 2, Hp, Sp] f32 + scsems when quant)
-    **static,    # c, g, r, s_q, hk, sm_scale, logit_cap
+    **static,    # c, g, r, s_q, hk, sm_scale, logit_cap, window
 ):
     return _kernel_impl(seq_ref, q0_ref, bt_ref, layer_ref, q_ref, cache_ref,
                         None, out_ref, acc_ref, m_ref, l_ref, kvbuf, sems,
@@ -130,6 +138,7 @@ def _kernel_impl(
     hk: int,
     sm_scale: float,
     logit_cap=None,
+    window=None,
 ):
     gi = pl.program_id(0)
     bs, hkd = kvbuf.shape[4], kvbuf.shape[5]
@@ -144,13 +153,28 @@ def _kernel_impl(
     split_p = jnp.dtype(op_dt).itemsize < 4
 
     seq = [seq_ref[gi * g + j] for j in range(g)]
-    # group-wide chunk bound: max seq_len among the G sequences
-    max_len = functools.reduce(jnp.maximum, seq)
-    num_chunks = pl.cdiv(max_len, t)  # data-dependent loop bound
+    if window is None:
+        # group-wide chunk bound: max seq_len among the G sequences
+        max_len = functools.reduce(jnp.maximum, seq)
+        num_chunks = pl.cdiv(max_len, t)  # data-dependent loop bound
     # blocks a row owns (0 for an empty slot), clamped to the table width:
     # a caller-side seq_len beyond the table must not index SMEM out of
     # bounds
     owned = [jnp.minimum(pl.cdiv(n, bs), bt_ref.shape[1]) for n in seq]
+    if window is None:
+        block_of = lambda j, ci, i: ci * c + i
+    else:
+        # Sliding window: query sq of a row (at q0 + sq) sees key p iff
+        # 0 <= q0 + sq - p < window, so nothing before q0 - window + 1 is
+        # read by any of them.  Every row walks from ITS OWN first block
+        # (chunk ci of row j holds blocks first[j] + ci*C ..), so a row
+        # fetches at most window / Bs + 1 blocks whatever its length and
+        # whatever rows share its group.
+        first = [jnp.maximum(q0_ref[gi * g + j] - (window - 1), 0) // bs
+                 for j in range(g)]
+        num_chunks = functools.reduce(
+            jnp.maximum, [pl.cdiv(owned[j] - first[j], c) for j in range(g)])
+        block_of = lambda j, ci, i: first[j] + ci * c + i
 
     def block_dmas(ci, slot, wait=False):
         """Start, or wait for, the copies of chunk ``ci``: each under the
@@ -158,9 +182,9 @@ def _kernel_impl(
         wait."""
         for j in range(g):          # static unroll over group
             for i in range(c):      # static unroll: C copies per seq per chunk
-                @pl.when(ci * c + i < owned[j])
+                @pl.when(block_of(j, ci, i) < owned[j])
                 def _copy(j=j, i=i):
-                    bid = bt_ref[gi * g + j, ci * c + i]
+                    bid = bt_ref[gi * g + j, block_of(j, ci, i)]
                     # K and V are adjacent in the [.., 2, Bs, HkD] block:
                     # ONE DMA
                     dmas = [pltpu.make_async_copy(
@@ -196,12 +220,17 @@ def _kernel_impl(
         rows = s_q * h
         for j0 in range(0, g, r):  # static unroll: R sequences an update
             js = range(j0, j0 + r)
-            live = functools.reduce(jnp.maximum, [seq[j] for j in js])
+            if window is None:
+                live = functools.reduce(jnp.maximum, [seq[j] for j in js])
+                todo = ci * t < live
+            else:   # chunk ci of row j starts at position first[j]*Bs + ci*T
+                todo = functools.reduce(jnp.logical_or, [
+                    first[j] * bs + ci * t < seq[j] for j in js])
 
             # skip chunks past the end of all R sequences (and zero-length
             # rows: their acc/l stay 0 -> output 0).  A sequence that ended
             # before the others of its update runs it fully masked.
-            @pl.when(ci * t < live)
+            @pl.when(todo)
             def _update(j0=j0, js=js):
                 jsl = slice(j0, j0 + r)
                 q = q_ref[jsl].astype(op_dt)  # [R, S*H, HkD]
@@ -219,8 +248,12 @@ def _kernel_impl(
                 # 0 here.  Keep the `jnp.where`s.
                 slot_pos = ci * t + jax.lax.broadcasted_iota(
                     jnp.int32, (t, 1), 0)
-                v = jnp.where(jnp.stack([slot_pos < seq[j] for j in js]),
-                              v, jnp.zeros_like(v))
+                if window is None:
+                    v_live = jnp.stack([slot_pos < seq[j] for j in js])
+                else:
+                    v_live = jnp.stack([first[j] * bs + slot_pos < seq[j]
+                                        for j in js])
+                v = jnp.where(v_live, v, jnp.zeros_like(v))
 
                 s = jax.lax.dot_general(
                     q, k, (((2,), (2,)), ((0,), (0,))),
@@ -252,9 +285,19 @@ def _kernel_impl(
                 # causal per query: query sq (row sq*H + h) sits at absolute
                 # position q0 + sq and sees cache slots <= that position
                 sq = jax.lax.broadcasted_iota(jnp.int32, (rows, t), 0) // h
-                owned_pos = jnp.stack([pos < seq[j] for j in js])
-                seen = owned_pos & jnp.stack(
-                    [pos <= q0_ref[gi * g + j] + sq for j in js])
+                if window is None:
+                    owned_pos = jnp.stack([pos < seq[j] for j in js])
+                    seen = owned_pos & jnp.stack(
+                        [pos <= q0_ref[gi * g + j] + sq for j in js])
+                else:
+                    # each row's own positions, and the band's older edge
+                    own = [first[j] * bs + pos for j in js]
+                    q_pos = [q0_ref[gi * g + j] + sq for j in js]
+                    owned_pos = jnp.stack(
+                        [p < seq[j] for p, j in zip(own, js)])
+                    seen = owned_pos & jnp.stack(
+                        [(p <= qp) & (qp - p < window)
+                         for p, qp in zip(own, q_pos)])
                 s = jnp.where(seen, s, NEG_INF)
 
                 m_prev = m_ref[jsl, :, :1]
@@ -295,7 +338,7 @@ def _kernel_impl(
 @functools.partial(
     jax.jit,
     static_argnames=("sm_scale", "logit_cap", "blocks_per_chunk",
-                     "seqs_per_group", "interpret"),
+                     "seqs_per_group", "window", "interpret"),
 )
 def paged_decode_attention(
     q: jax.Array,             # [B, H, D]
@@ -307,6 +350,7 @@ def paged_decode_attention(
     logit_cap: float | None = None,
     blocks_per_chunk: int | None = None,
     seqs_per_group: int | None = None,
+    window: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """One decode step of attention for B sequences.  Returns [B, H, D]."""
@@ -315,14 +359,15 @@ def paged_decode_attention(
         seq_lens - 1,  # the single query is the sequence tail
         sm_scale=sm_scale, logit_cap=logit_cap,
         blocks_per_chunk=blocks_per_chunk, seqs_per_group=seqs_per_group,
-        interpret=interpret,
+        window=window, interpret=interpret,
     )[:, 0]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("sm_scale", "logit_cap", "blocks_per_chunk",
-                     "seqs_per_group", "seqs_per_update", "interpret"),
+                     "seqs_per_group", "seqs_per_update", "window",
+                     "interpret"),
 )
 def paged_decode_attention_mq(
     q: jax.Array,             # [B, S, H, D] — S contiguous trailing queries
@@ -336,6 +381,7 @@ def paged_decode_attention_mq(
     blocks_per_chunk: int | None = None,
     seqs_per_group: int | None = None,
     seqs_per_update: int | None = None,
+    window: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Multi-query flash decode: S queries per row (query j at position
@@ -348,7 +394,13 @@ def paged_decode_attention_mq(
     ``seqs_per_group`` / ``blocks_per_chunk`` / ``seqs_per_update`` left
     None follow the geometry (``registry.decode_tiling``: 8 rows a group
     and ~512 KiB of K/V a row-chunk, less where the kernel's scratch would
-    not fit; ``registry.decode_seqs_per_update``)."""
+    not fit; ``registry.decode_seqs_per_update``).
+
+    ``window`` (static): a sliding window - query j sees key p iff
+    0 <= q0_pos + j - p < window.  A row's walk then begins at the block
+    that holds q0_pos - window + 1, no block before it is fetched, and the
+    call shows in a profile as ``paged_decode_attention_window_mq``; None
+    traces the full-attention kernel as it was."""
     from dynamo_tpu.ops.kv_quant import is_quant
 
     quant = is_quant(cache)
@@ -418,19 +470,22 @@ def paged_decode_attention_mq(
     cost = decode_cost_estimate(
         b, s_q, h, hk, d, bs, m, cache_bytes=data.dtype.itemsize,
         quant=quant, blocks_per_chunk=blocks_per_chunk,
-        q_bytes=q.dtype.itemsize)
+        q_bytes=q.dtype.itemsize, window=window)
 
     out = pl.pallas_call(
         functools.partial(_kernel_quant if quant else _kernel, c=c, g=g,
                           r=r, s_q=s_q, hk=hk, sm_scale=sm_scale,
-                          logit_cap=logit_cap),
+                          logit_cap=logit_cap, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, hkd), q.dtype),
         interpret=interpret,
         cost_estimate=cost,
         # the name a profile shows; cellbench's kernel.decode_attn_roofline
-        # matches the prefix paged_decode_attention
-        name="paged_decode_attention_mq" + ("_int8" if quant else ""),
+        # matches the prefix paged_decode_attention, its
+        # kernel.window_decode_roofline paged_decode_attention_window
+        name=("paged_decode_attention_mq" if window is None
+              else "paged_decode_attention_window_mq")
+        + ("_int8" if quant else ""),
     )(*operands)
 
     # Collapse the block-diagonal layout back to [B, S, H, D].

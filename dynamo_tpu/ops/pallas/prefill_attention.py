@@ -48,25 +48,21 @@ NEG_INF = -1e30
 def _kernel(
     seq_ref, start_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref, cache_ref,
     out_ref, acc_ref, m_ref, l_ref, kvbuf, sems,
-    *, c: int, tq: int, hk: int, g: int, d: int, sm_scale: float,
-    logit_cap=None,
+    **static,    # c, tq, hk, g, d, sm_scale, logit_cap, window
 ):
     return _kernel_impl(seq_ref, start_ref, bt_ref, layer_ref, q_ref, k_ref,
                         v_ref, cache_ref, None, out_ref, acc_ref, m_ref,
-                        l_ref, kvbuf, sems, None, None, c=c, tq=tq, hk=hk,
-                        g=g, d=d, sm_scale=sm_scale, logit_cap=logit_cap)
+                        l_ref, kvbuf, sems, None, None, **static)
 
 
 def _kernel_quant(
     seq_ref, start_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref, cache_ref,
     scale_ref, out_ref, acc_ref, m_ref, l_ref, kvbuf, sems, scbuf, scsems,
-    *, c: int, tq: int, hk: int, g: int, d: int, sm_scale: float,
-    logit_cap=None,
+    **static,
 ):
     return _kernel_impl(seq_ref, start_ref, bt_ref, layer_ref, q_ref, k_ref,
                         v_ref, cache_ref, scale_ref, out_ref, acc_ref, m_ref,
-                        l_ref, kvbuf, sems, scbuf, scsems, c=c, tq=tq, hk=hk,
-                        g=g, d=d, sm_scale=sm_scale, logit_cap=logit_cap)
+                        l_ref, kvbuf, sems, scbuf, scsems, **static)
 
 
 def _kernel_impl(
@@ -104,6 +100,7 @@ def _kernel_impl(
     d: int,
     sm_scale: float,
     logit_cap=None,
+    window=None,
 ):
     quant = scale_ref is not None
     bi = pl.program_id(0)
@@ -114,6 +111,21 @@ def _kernel_impl(
     prefix = start_ref[bi]                  # cached-prefix token count
     fresh = seq_ref[bi] - prefix            # valid fresh tokens
     n_pref = pl.cdiv(prefix, t)             # data-dependent chunk bound
+    # Sliding window: the query at position p sees key j iff 0 <= p - j <
+    # window.  This grid step's first query sits at prefix + ri*TQ, so no
+    # query of it reads a position before ``band_lo``: the prefix walk
+    # begins at the BLOCK that holds it (chunk ci is blocks blk0 + ci*C ..,
+    # none before blk0 is fetched) and the fresh walk at its tile.
+    fresh0 = 0
+    from_blk0 = lambda x: x     # a block index / a position of the walk
+    from_pos0 = lambda x: x
+    if window is not None:
+        band_lo = prefix + ri * tq - (window - 1)
+        blk0 = jnp.maximum(band_lo, 0) // bs
+        n_pref = pl.cdiv(jnp.maximum(pl.cdiv(prefix, bs) - blk0, 0), c)
+        from_blk0 = lambda x: blk0 + x
+        from_pos0 = lambda x: blk0 * bs + x
+        fresh0 = jnp.maximum(band_lo - prefix, 0) // tq
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -123,14 +135,19 @@ def _kernel_impl(
     rows = jax.lax.rem(
         jax.lax.broadcasted_iota(jnp.int32, (g * tq, 1), 0), tq)
 
-    def flash_update(h, s_scores, v_cols, p_scale=None):
+    def flash_update(h, s_scores, v_cols, p_scale=None, seen=None):
         """Online-softmax fold of one [G*TQ, TKV] score tile (masked).
         ``p_scale`` [1, TKV] rescales P before the PV product (int8 V
-        dequant folded per column; softmax stats use the true probs)."""
+        dequant folded per column; softmax stats use the true probs).
+        ``seen`` (the mask, under a window): a query whose band begins
+        after this tile has seen no column yet, its m is still NEG_INF and
+        exp(NEG_INF - NEG_INF) is 1 - select, do not trust the exp."""
         m_prev = m_ref[h, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s_scores, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s_scores - m_new)
+        if seen is not None:
+            p = jnp.where(seen, p, 0.0)
         l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
         pv = jnp.dot(p if p_scale is None else p * p_scale, v_cols,
@@ -146,7 +163,7 @@ def _kernel_impl(
         m_table = bt_ref.shape[1]
         out = []
         for i in range(c):  # static unroll: C block copies per chunk
-            bid = bt_ref[bi, jnp.minimum(ci * c + i, m_table - 1)]
+            bid = bt_ref[bi, jnp.minimum(from_blk0(ci * c + i), m_table - 1)]
             out.append(pltpu.make_async_copy(
                 cache_ref.at[lyr, bid], kvbuf.at[slot, i], sems.at[slot, i]
             ))
@@ -183,17 +200,21 @@ def _kernel_impl(
                 [scbuf[slot, i, 0][:hk, :bs] for i in range(c)], axis=-1)
             scv = jnp.concatenate(
                 [scbuf[slot, i, 1][:hk, :bs] for i in range(c)], axis=-1)
-        col = ci * t + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)
+        col = from_pos0(
+            ci * t + jax.lax.broadcasted_iota(jnp.int32, (1, t), 1))
         allow = col < prefix                              # [1, T]
+        live = allow                                      # V scales' mask
+        if window is not None:                            # [G*TQ, T]
+            allow = allow & (prefix + ri * tq + rows - col < window)
         # dead prefix slots (past `prefix` in the tail block) may hold
         # non-finite pool garbage; the score mask zeroes their P columns
         # but 0 * NaN-V survives the PV product — zero V rows (and the V
         # scales) for them outright
-        vmask = ci * t + jax.lax.broadcasted_iota(
-            jnp.int32, (t, 1), 0) < prefix
+        vmask = from_pos0(ci * t + jax.lax.broadcasted_iota(
+            jnp.int32, (t, 1), 0)) < prefix
         vc = jnp.where(vmask, vc, 0.0)
         if quant:
-            scv = jnp.where(allow, scv, 0.0)
+            scv = jnp.where(live, scv, 0.0)
         for h in range(hk):  # static unroll over kv heads
             s_ = jax.lax.dot_general(
                 q_head(h), kc[:, h * d:(h + 1) * d],
@@ -207,7 +228,8 @@ def _kernel_impl(
                 s_ = softcap(s_, logit_cap)
             s_ = jnp.where(allow, s_, NEG_INF)
             flash_update(h, s_, vc[:, h * d:(h + 1) * d],
-                         p_scale=scv[h:h + 1, :] if quant else None)
+                         p_scale=scv[h:h + 1, :] if quant else None,
+                         seen=None if window is None else allow)
         return 0
 
     jax.lax.fori_loop(0, n_pref, pref_body, 0)
@@ -220,6 +242,8 @@ def _kernel_impl(
         col = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, tq), 1)
         # causal by fresh index + clip padding columns
         allow = (col <= ri * tq + rows) & (col < fresh)      # [G*TQ, TQ]
+        if window is not None:
+            allow = allow & (ri * tq + rows - col < window)
         # fresh padding tokens may be non-finite — zero their V rows
         vc = jnp.where(col0 + jax.lax.broadcasted_iota(
             jnp.int32, (tq, 1), 0) < fresh, vc, 0.0)
@@ -231,10 +255,11 @@ def _kernel_impl(
             if logit_cap is not None:
                 s_ = softcap(s_, logit_cap)
             s_ = jnp.where(allow, s_, NEG_INF)
-            flash_update(h, s_, vc[:, h * d:(h + 1) * d])
+            flash_update(h, s_, vc[:, h * d:(h + 1) * d],
+                         seen=None if window is None else allow)
         return 0
 
-    jax.lax.fori_loop(0, ri + 1, fresh_body, 0)
+    jax.lax.fori_loop(fresh0, ri + 1, fresh_body, 0)
 
     for h in range(hk):
         denom = jnp.maximum(l_ref[h, :, :1], 1e-9)  # padding rows → 0
@@ -246,7 +271,7 @@ def _kernel_impl(
 @functools.partial(
     jax.jit,
     static_argnames=("sm_scale", "logit_cap", "rows_per_chunk",
-                     "blocks_per_chunk", "interpret"),
+                     "blocks_per_chunk", "window", "interpret"),
 )
 def paged_prefill_attention(
     q: jax.Array,             # [B, S, H, D]
@@ -265,10 +290,15 @@ def paged_prefill_attention(
     # geometry (KN001) against registry.VMEM_BUDGET_BYTES
     rows_per_chunk: int = PREFILL_ROWS_PER_CHUNK,
     blocks_per_chunk: int = PREFILL_BLOCKS_PER_CHUNK,
+    window: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Flash prefill for S fresh tokens against fresh K/V + cached prefix.
-    Returns [B, S, H, D]."""
+    Returns [B, S, H, D].  ``window`` (static): a sliding window - prefix
+    blocks wholly before a grid step's band are not streamed, key tiles
+    wholly outside it are skipped, its older edge is masked, and a profile
+    shows ``paged_prefill_attention_window``; None traces the kernel as it
+    was."""
     from dynamo_tpu.ops.kv_quant import is_quant
 
     quant = is_quant(cache)
@@ -339,21 +369,23 @@ def paged_prefill_attention(
     cost = prefill_cost_estimate(
         b, s, h, hk, d, bs, m, cache_bytes=data.dtype.itemsize,
         quant=quant, rows_per_chunk=rows_per_chunk,
-        blocks_per_chunk=blocks_per_chunk)
+        blocks_per_chunk=blocks_per_chunk, window=window)
 
     out = pl.pallas_call(
         functools.partial(
             _kernel_quant if quant else _kernel,
             c=c, tq=tq, hk=hk, g=g, d=d, sm_scale=float(sm_scale),
-            logit_cap=logit_cap,
+            logit_cap=logit_cap, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, g, s, d), q.dtype),
         interpret=interpret,
         cost_estimate=cost,
         # the name a profile shows; cellbench's kernel.prefill_attn_roofline
-        # matches the prefix paged_prefill_attention
-        name="paged_prefill_attention" + ("_int8" if quant else ""),
+        # matches the prefix paged_prefill_attention, its
+        # kernel.window_prefill_roofline paged_prefill_attention_window
+        name="paged_prefill_attention" + ("" if window is None else "_window")
+        + ("_int8" if quant else ""),
     )(*operands)
     # [B, Hk, G, S, D] -> [B, S, H, D]
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)
@@ -380,28 +412,24 @@ def paged_prefill_attention(
 def _ragged_kernel(
     start_ref, roff_ref, rend_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref,
     cache_ref, out_ref, acc_ref, m_ref, l_ref, kvbuf, sems,
-    *, c: int, tq: int, hk: int, g: int, d: int, r_rows: int,
-    sm_scale: float, logit_cap=None,
+    **static,    # c, tq, hk, g, d, r_rows, sm_scale, logit_cap, window
 ):
     return _ragged_kernel_impl(
         start_ref, roff_ref, rend_ref, bt_ref, layer_ref, q_ref, k_ref,
         v_ref, cache_ref, None, out_ref, acc_ref, m_ref, l_ref, kvbuf,
-        sems, None, None, c=c, tq=tq, hk=hk, g=g, d=d, r_rows=r_rows,
-        sm_scale=sm_scale, logit_cap=logit_cap)
+        sems, None, None, **static)
 
 
 def _ragged_kernel_quant(
     start_ref, roff_ref, rend_ref, bt_ref, layer_ref, q_ref, k_ref, v_ref,
     cache_ref, scale_ref, out_ref, acc_ref, m_ref, l_ref, kvbuf, sems,
     scbuf, scsems,
-    *, c: int, tq: int, hk: int, g: int, d: int, r_rows: int,
-    sm_scale: float, logit_cap=None,
+    **static,
 ):
     return _ragged_kernel_impl(
         start_ref, roff_ref, rend_ref, bt_ref, layer_ref, q_ref, k_ref,
         v_ref, cache_ref, scale_ref, out_ref, acc_ref, m_ref, l_ref,
-        kvbuf, sems, scbuf, scsems, c=c, tq=tq, hk=hk, g=g, d=d,
-        r_rows=r_rows, sm_scale=sm_scale, logit_cap=logit_cap)
+        kvbuf, sems, scbuf, scsems, **static)
 
 
 def _ragged_kernel_impl(
@@ -436,6 +464,7 @@ def _ragged_kernel_impl(
     r_rows: int,
     sm_scale: float,
     logit_cap=None,
+    window=None,
 ):
     quant = scale_ref is not None
     ri = pl.program_id(0)
@@ -463,11 +492,13 @@ def _ragged_kernel_impl(
 
     sid_q = sid_at(qflat)                  # [G*TQ, 1]
 
-    def flash_update(h, s_scores, v_cols, p_scale=None):
+    def flash_update(h, s_scores, v_cols, p_scale=None, seen=None):
         m_prev = m_ref[h, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s_scores, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s_scores - m_new)
+        if seen is not None:    # under a window: see _kernel_impl
+            p = jnp.where(seen, p, 0.0)
         l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
         pv = jnp.dot(p if p_scale is None else p * p_scale, v_cols,
@@ -478,11 +509,14 @@ def _ragged_kernel_impl(
         return q_ref[0, h].reshape(g * tq, d).astype(jnp.float32) * sm_scale
 
     # ------------------------------------------------ prefix phase (per row)
-    def block_dmas(r, ci, slot):
+    def block_dmas(r, ci, slot, blk0=None):
         m_table = bt_ref.shape[1]
         out = []
         for i in range(c):  # static unroll: C block copies per chunk
-            bid = bt_ref[r, jnp.minimum(ci * c + i, m_table - 1)]
+            blk = ci * c + i
+            if blk0 is not None:    # a windowed walk begins at block blk0
+                blk = blk0 + blk
+            bid = bt_ref[r, jnp.minimum(blk, m_table - 1)]
             out.append(pltpu.make_async_copy(
                 cache_ref.at[lyr, bid], kvbuf.at[slot, i], sems.at[slot, i]
             ))
@@ -497,10 +531,26 @@ def _ragged_kernel_impl(
         prefix = start_ref[r]
         overlap = (q0 < rend_ref[r]) & (q0 + tq > roff_ref[r])
 
-        @pl.when(overlap & (prefix > 0))
+        # Under a window the row's first query in this tile (flat index
+        # max(q0, row offset), position prefix + its offset in the span)
+        # reads nothing before ``band_lo``: the walk begins at its block.
+        blk0 = None
+        from_pos0 = lambda x: x
+        some = prefix > 0
+        if window is not None:
+            band_lo = (prefix + jnp.maximum(q0 - roff_ref[r], 0)
+                       - (window - 1))
+            blk0 = jnp.maximum(band_lo, 0) // bs
+            from_pos0 = lambda x: blk0 * bs + x
+            some = blk0 * bs < prefix
+
+        @pl.when(overlap & some)
         def _row():
-            n_pref = pl.cdiv(prefix, t_chunk)
-            for dma in block_dmas(r, 0, 0):
+            if window is None:
+                n_pref = pl.cdiv(prefix, t_chunk)
+            else:
+                n_pref = pl.cdiv(pl.cdiv(prefix, bs) - blk0, c)
+            for dma in block_dmas(r, 0, 0, blk0):
                 dma.start()
 
             def pref_body(ci, _):
@@ -508,10 +558,11 @@ def _ragged_kernel_impl(
 
                 @pl.when(ci + 1 < n_pref)
                 def _prefetch():
-                    for dma in block_dmas(r, ci + 1, jax.lax.rem(ci + 1, 2)):
+                    for dma in block_dmas(r, ci + 1, jax.lax.rem(ci + 1, 2),
+                                          blk0):
                         dma.start()
 
-                for dma in block_dmas(r, ci, slot):
+                for dma in block_dmas(r, ci, slot, blk0):
                     dma.wait()
 
                 kc = kvbuf[slot, :, 0].reshape(t_chunk, hk * d).astype(
@@ -525,15 +576,18 @@ def _ragged_kernel_impl(
                     scv = jnp.concatenate(
                         [scbuf[slot, i, 1][:hk, :bs] for i in range(c)],
                         axis=-1)
-                col = ci * t_chunk + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, t_chunk), 1)
+                col = from_pos0(ci * t_chunk + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, t_chunk), 1))
                 # only this row's queries see this row's prefix slots
                 allow = (col < prefix) & (sid_q == r)
+                if window is not None:
+                    allow = allow & (
+                        prefix + qflat - roff_ref[r] - col < window)
                 # dead tail-block slots may be non-finite pool garbage —
                 # zero their V rows (and V scales); the score mask alone
                 # leaves 0 * NaN in the PV product
-                vmask = ci * t_chunk + jax.lax.broadcasted_iota(
-                    jnp.int32, (t_chunk, 1), 0) < prefix
+                vmask = from_pos0(ci * t_chunk + jax.lax.broadcasted_iota(
+                    jnp.int32, (t_chunk, 1), 0)) < prefix
                 vc = jnp.where(vmask, vc, 0.0)
                 if quant:
                     scv = jnp.where(col < prefix, scv, 0.0)
@@ -549,7 +603,8 @@ def _ragged_kernel_impl(
                         s_ = softcap(s_, logit_cap)
                     s_ = jnp.where(allow, s_, NEG_INF)
                     flash_update(h, s_, vc[:, h * d:(h + 1) * d],
-                                 p_scale=scv[h:h + 1, :] if quant else None)
+                                 p_scale=scv[h:h + 1, :] if quant else None,
+                                 seen=None if window is None else allow)
                 return 0
 
             jax.lax.fori_loop(0, n_pref, pref_body, 0)
@@ -575,6 +630,8 @@ def _ragged_kernel_impl(
         # uniform-weight PV mean (exp(NEG_INF - NEG_INF) = 1), which the
         # caller discards, matching the base kernel's padding contract
         allow = (sid_c == sid_q) & (col <= qflat) & (sid_q >= 0)
+        if window is not None:   # flat gap = position gap inside a span
+            allow = allow & (qflat - col < window)
         for h in range(hk):
             s_ = jax.lax.dot_general(
                 q_head(h), kc[:, h * d:(h + 1) * d],
@@ -583,10 +640,14 @@ def _ragged_kernel_impl(
             if logit_cap is not None:
                 s_ = softcap(s_, logit_cap)
             s_ = jnp.where(allow, s_, NEG_INF)
-            flash_update(h, s_, vc[:, h * d:(h + 1) * d])
+            flash_update(h, s_, vc[:, h * d:(h + 1) * d],
+                         seen=None if window is None else allow)
         return 0
 
-    jax.lax.fori_loop(0, ri + 1, fresh_body, 0)
+    # a key tile wholly older than q0 - window + 1 is in no query's band
+    fresh0 = (0 if window is None
+              else jnp.maximum(q0 - (window - 1), 0) // tq)
+    jax.lax.fori_loop(fresh0, ri + 1, fresh_body, 0)
 
     for h in range(hk):
         denom = jnp.maximum(l_ref[h, :, :1], 1e-9)  # keep padding finite
@@ -598,7 +659,7 @@ def _ragged_kernel_impl(
 @functools.partial(
     jax.jit,
     static_argnames=("sm_scale", "logit_cap", "rows_per_chunk",
-                     "blocks_per_chunk", "interpret"),
+                     "blocks_per_chunk", "window", "interpret"),
 )
 def ragged_paged_prefill_attention(
     q: jax.Array,             # [1, T, H, D] — packed fresh queries
@@ -614,13 +675,15 @@ def ragged_paged_prefill_attention(
     logit_cap: float | None = None,
     rows_per_chunk: int = PREFILL_ROWS_PER_CHUNK,
     blocks_per_chunk: int = PREFILL_BLOCKS_PER_CHUNK,
+    window: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
     """Flash ragged (mixed-chunk) attention: T packed fresh tokens of up
     to R sequences against fresh K/V + each row's own cached prefix.
     Rows may be prefill chunks or 1-token decode rows (``starts`` need
-    not be block-aligned — see the module comment).  Returns
-    [1, T, H, D]."""
+    not be block-aligned — see the module comment).  ``window``: as
+    ``paged_prefill_attention``'s (``paged_prefill_attention_window_ragged``
+    in a profile).  Returns [1, T, H, D]."""
     from dynamo_tpu.ops.kv_quant import is_quant
 
     quant = is_quant(cache)
@@ -689,19 +752,20 @@ def ragged_paged_prefill_attention(
     cost = ragged_cost_estimate(
         t, r_rows, h, hk, d, bs, m, cache_bytes=data.dtype.itemsize,
         quant=quant, rows_per_chunk=rows_per_chunk,
-        blocks_per_chunk=blocks_per_chunk)
+        blocks_per_chunk=blocks_per_chunk, window=window)
 
     out = pl.pallas_call(
         functools.partial(
             _ragged_kernel_quant if quant else _ragged_kernel,
             c=c, tq=tq, hk=hk, g=g, d=d, r_rows=r_rows,
-            sm_scale=float(sm_scale), logit_cap=logit_cap,
+            sm_scale=float(sm_scale), logit_cap=logit_cap, window=window,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, hk, g, t, d), q.dtype),
         interpret=interpret,
         cost_estimate=cost,
-        name="paged_prefill_attention_ragged" + ("_int8" if quant else ""),
+        name="paged_prefill_attention" + ("" if window is None else "_window")
+        + "_ragged" + ("_int8" if quant else ""),
     )(*operands)
     # [1, Hk, G, T, D] -> [1, T, H, D]
     return out.transpose(0, 3, 1, 2, 4).reshape(1, t, h, d)

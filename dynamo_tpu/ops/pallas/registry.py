@@ -701,29 +701,36 @@ def _cost_estimate(cost: dict):
 
 
 def decode_cost_estimate(b, s_q, h, hk, d, bs, m, cache_bytes, quant,
-                         blocks_per_chunk, q_bytes=2):
+                         blocks_per_chunk, q_bytes=2, window=None):
     """Worst-case (full-table context) CostEstimate for the decode
     pallas_call — seq_lens are dynamic at trace time, so the static
-    bound is every row at M*Bs context."""
+    bound is every row at M*Bs context, or at the ``window`` and the block
+    its older edge falls in where the kernel walks a window."""
+    span = m * bs if window is None else min(m * bs, window + bs)
     return _cost_estimate(decode_kernel_cost(
-        b, s_q, h, hk, d, bs, m, [m * bs] * b, cache_bytes=cache_bytes,
+        b, s_q, h, hk, d, bs, m, [span] * b, cache_bytes=cache_bytes,
         quant=quant, q_bytes=q_bytes, blocks_per_chunk=blocks_per_chunk,
     ))
 
 
 def prefill_cost_estimate(b, s, h, hk, d, bs, m, cache_bytes, quant,
-                          rows_per_chunk, blocks_per_chunk):
+                          rows_per_chunk, blocks_per_chunk, window=None):
+    """Worst case for the prefill pallas_call: a full-table prefix, or the
+    ``window`` of it a windowed call streams."""
+    span = m * bs if window is None else min(m * bs, window)
     return _cost_estimate(prefill_kernel_cost(
-        b, s, h, hk, d, bs, m, [m * bs] * b, cache_bytes=cache_bytes,
+        b, s, h, hk, d, bs, m, [span] * b, cache_bytes=cache_bytes,
         quant=quant, rows_per_chunk=rows_per_chunk,
         blocks_per_chunk=blocks_per_chunk,
     ))
 
 
 def ragged_cost_estimate(t_tokens, r_rows, h, hk, d, bs, m, cache_bytes,
-                         quant, rows_per_chunk, blocks_per_chunk):
+                         quant, rows_per_chunk, blocks_per_chunk,
+                         window=None):
+    span = m * bs if window is None else min(m * bs, window)
     return _cost_estimate(ragged_kernel_cost(
-        t_tokens, h, hk, d, bs, m, [m * bs] * r_rows,
+        t_tokens, h, hk, d, bs, m, [span] * r_rows,
         cache_bytes=cache_bytes, quant=quant,
         rows_per_chunk=rows_per_chunk, blocks_per_chunk=blocks_per_chunk,
     ))

@@ -153,6 +153,10 @@ def seed_http_metrics():
     ec.loop_passes_total = 1200
     ec.decode_kv_blocks_walked_total = 2400
     ec.decode_kv_blocks_group_bound_total = 4096
+    ec.decode_kv_window_blocks_walked_total = 6336
+    ec.decode_kv_window_blocks_span_total = 143616
+    ec.window_layers = 6
+    ec.sliding_window = 1024
     ec.cache_layers = 192
     ec.kv_bytes_per_token = 1572864
     ec.state_layers = 6

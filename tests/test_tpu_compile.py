@@ -894,7 +894,14 @@ def test_tp_rules_that_keep_the_xla_path(tpu_gate):
     kw = dict(num_kv_heads=8, block_size=BS)
     assert pa.attention_impl("decode", tp=4, quant=True, **kw)[0] == "xla"
     assert pa.attention_impl("decode", tp=16, **kw)[0] == "xla"
-    assert pa.attention_impl("prefill", windowed=True, **kw)[0] == "xla"
+    # a sliding window is no exception since PR 60 (the kernels' windowed
+    # form), and leaves the mesh's and int8's rules as they are
+    assert pa.attention_impl("prefill", windowed=True, **kw) == (
+        "pallas", "tpu, windowed kernel")
+    assert pa.attention_impl("decode", windowed=True, tp=4, **kw) == (
+        "pallas", "tpu, shard_map over tp=4, windowed kernel")
+    assert pa.attention_impl("decode", windowed=True, tp=4, quant=True,
+                             **kw)[0] == "xla"
     assert pa.attention_impl(
         "decode", num_kv_heads=8, block_size=16, quant=True)[0] == "xla"
 
@@ -1426,6 +1433,7 @@ def test_prefix_blocks_keys_a_prefill_program_only_where_it_sizes_a_gather(
     if dispatch == "xla":
         monkeypatch.setenv("DYNAMO_DISABLE_PALLAS_PREFILL", "1")
     # a window of 1,024: 16 cached blocks and the chunk fit it, 64 do not
+    # (which decided the dispatch until PR 60)
     model = _keyed_toy(family, **(
         {"sliding_window": 1024} if dispatch == "window" else {}))
     core, lower = _keyed_engine(model)
@@ -1443,15 +1451,106 @@ def test_prefix_blocks_keys_a_prefill_program_only_where_it_sizes_a_gather(
         assert len(modules) == 1
         assert "paged_prefill_attention" in modules.pop()
     elif dispatch == "window":
-        # one value a side of the window: inside it the flash kernel, past
-        # it XLA's masked gather over that many blocks
-        assert keys == [0, 0, 64]
-        inside, past = lower(16), lower(64)
-        assert inside == lower(0) != past
-        assert "paged_prefill_attention" in inside
-        assert "paged_prefill_attention" not in past
+        # since PR 60 the kernel masks by the window itself at every prefix
+        # (its windowed form: the table, 64 blocks and the chunk, can hold
+        # a context past 1,024), so a window model too builds one program
+        assert keys == [0, 0, 0]
+        modules = {lower(pb) for pb in _KEY_PREFIXES}
+        assert len(modules) == 1
+        assert "paged_prefill_attention_window" in modules.pop()
     else:
         # the XLA form gathers ``prefix_blocks`` blocks: the bucket stays
         assert keys == list(_KEY_PREFIXES)
         assert lower(16) != lower(64)
         assert "paged_prefill_attention" not in lower(16)
+
+
+# ---------------------------------------------------------------------------
+# PR 60: mellum2-12b-a2.5b.  The attention kernels had only ever run at
+# max_model_len <= 4,096 (128 blocks a row); this cell's tables are 32 x 1,152
+# blocks and its prefixes up to 1,024 blocks, and six of its eight layers call
+# the kernels' windowed form.
+@pytest.mark.parametrize("program,chunk", [
+    ("decode", None), ("prefill", 2048), ("prefill", 256), ("reference", 768)],
+    ids=["decode", "document-chunk", "question", "reference"])
+def test_mellum_cell_programs_name_both_kernel_forms_and_fit_the_chip(
+        topo, tpu_gate, program, chunk):
+    """The cell's decode program (32 rows, a 32 x 1,152 block table), a
+    2,048-token document chunk, a question's 256-token bucket and the check's
+    float32 reference over its longest sequence (700 + 8 tokens, padded to
+    768), whole (8 layers = two periods, 64 experts, the 98,304-row head, the
+    cell's 12,288-block pool): one scan over the two periods, each layer of
+    a period with its own attention call - three of the windowed kernel's
+    name and one of the full kernel's - the experts through the grouped
+    matmul at K 2,304 / N 896 (4 rows an expert in decode, 32 in a question;
+    a document chunk's 256 are ``ragged_dot``'s) read where they lie, the pool donated and
+    written in place, and weights + K/V inside the chip with room for the
+    programs' temporaries and the reference beside them."""
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "mellum2-12b-a2.5b.json",
+        lambda spec: SingleDeviceSharding(topo.devices[0]))
+    serve = dict(hf["serve"])
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    weights = sum(nbytes(a) for a in jax.tree.leaves(params))
+    held = nbytes(cache)
+    assert 7.58e9 < weights < 7.60e9                          # 3.795 B x 2
+    assert held == 12288 * 32 * 8 * 2048                      # 6.0 GiB
+    assert cfg.period == ("sliding_attention",) * 3 + ("full_attention",)
+    assert (cfg.window_layers, cfg.sliding_window) == (6, 1024)
+    if program == "reference":
+        from cellbench import spec
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        ref = spec.load_module(root, "reference", hf["reference"])
+        compiled = jax.jit(ref.make_forward(hf)).lower(
+            params, sds((chunk,)), sds((8,))).compile()
+        mem = compiled.memory_analysis()
+        print(f"# reference: temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
+        # beside the served model: its weights are the reference's
+        # arguments, the cache is the engine's
+        assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes
+        assert (weights + held + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes) < 0.93 * V5E_HBM
+        return
+    if chunk:
+        serve["prefill_chunk_tokens"] = chunk
+    # on the flash path every prefix is the one program (PR 57): the engine
+    # hands jit prefix_blocks 0 whatever is cached
+    from dynamo_tpu.ops.paged_attention import prefill_program_key
+    assert prefill_program_key(
+        "prefill", 1024, chunk or 2048, cfg.sliding_window,
+        num_kv_heads=cfg.num_kv_heads, block_size=BS) == 0
+    fn, args = _step_program(program, model, serve, sds, prefix_blocks=0)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    stem = ("paged_decode_attention" if program == "decode"
+            else "paged_prefill_attention")
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and stem in line]
+    # a period's four layers, unrolled in the one scan's body
+    assert len(calls) == 4, [c[:120] for c in calls]
+    assert sum(f"{stem}_window" in c for c in calls) == 3
+    scoped = [re.search(r'op_name="([^"]*)"', c).group(1) for c in calls]
+    assert sum("/attn/window/" in s for s in scoped) == 3, scoped
+    assert sum("/attn/full/" in s for s in scoped) == 1, scoped
+    if chunk == 2048:   # 256 rows an expert: past the kernel's one row tile
+        assert not _grouped_matmul_calls(hlo)
+    else:               # gate + up, down a layer
+        assert len(_grouped_matmul_calls(hlo)) == 2 * 4
+        assert "ragged-dot" not in hlo
+    assert hlo.count(" while(") == 1
+    assert not re.search(r"bf16\[8,12288,2,32,512\]\S* copy\(", hlo)
+    # no layer's expert stack is sliced out of the [8, 64, ...] arrays
+    assert not re.search(r"bf16\[(4,)?64,2304,896\]\S* (copy|dynamic-slice)\(",
+                         hlo)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held                    # donated
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"# {program} {chunk}: arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, total {total / 1e9:.3f} GB")
+    assert 14.0e9 < mem.argument_size_in_bytes < 14.1e9
+    assert mem.temp_size_in_bytes < 0.8e9, mem.temp_size_in_bytes
+    assert total < 0.9 * V5E_HBM, total
