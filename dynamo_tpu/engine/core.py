@@ -63,7 +63,7 @@ from dynamo_tpu.obs.metric_names import ENGINE_COUNTS
 from dynamo_tpu.obs.perfmodel import perf_model
 from dynamo_tpu.utils.mesh import AXIS_DATA, AXIS_MODEL
 from dynamo_tpu.obs.timeline import step_timeline
-from dynamo_tpu.tokens import TokenBlockSequence
+from dynamo_tpu.tokens import STRIDE_BLOCKS, BlockChainMemo
 
 log = logging.getLogger("dynamo_tpu.engine")
 
@@ -431,6 +431,11 @@ class EngineCore:
             config.block_size,
             enable_prefix_reuse=self.prefix_reuse,
         )
+        # the block chains of prompts already admitted (_admit alone reads
+        # and writes it, on the engine's thread): as many strides as the
+        # pool's blocks could hold, since a chain the cache cannot keep is
+        # not worth remembering
+        self._chain_memo = BlockChainMemo(config.num_blocks // STRIDE_BLOCKS)
         cache_dtype = config.cache_dtype or model.config.dtype
         self.cache_quant = str(cache_dtype) == "int8"
         # a cache in a layout of the model's own (ops/latent_cache.py: rows
@@ -1805,7 +1810,14 @@ class EngineCore:
                     # ids: wait for constrained slots to free
                     # (NoFreeBlocks-style backpressure, not an error)
                     break
-            req.seq = TokenBlockSequence(req.prompt, self.config.block_size)
+            if req.seq is None:
+                # built once: a request that NoFreeBlocks sends round again
+                # keeps its chain, and one that starts as an earlier prompt
+                # did takes that prompt's blocks from the memo
+                req.seq, reused = self._chain_memo.sequence(
+                    req.prompt, self.config.block_size)
+                self.counts.prompt_blocks_admitted_total += len(req.seq.blocks)
+                self.counts.prompt_blocks_reused_total += reused
             try:
                 alloc = self.block_manager.allocate(
                     req.seq.sequence_hashes(), req.prompt_len

@@ -292,6 +292,12 @@ REQUEST_FAMILY = (
     _count("dynamo_tpu_engine_prompt_tokens_cached_total", "counter",
            "of those, the tokens served from reused blocks: over admitted, "
            "the prefix cache's hit share (cellbench's kv.prefix_hit_pct)"),
+    _count("dynamo_tpu_engine_prompt_blocks_admitted_total", "counter",
+           "full blocks of the prompts whose block chain admission built"),
+    _count("dynamo_tpu_engine_prompt_blocks_reused_total", "counter",
+           "of those, the blocks taken from the chain memo (tokens.py "
+           "BlockChainMemo) and not hashed again: over admitted, cellbench's "
+           "kv.chain_reuse_pct"),
     _count("dynamo_tpu_engine_attn_context_tokens_total", "counter",
            "latent-attention models: cached positions the decode rows "
            "dispatched could see, summed"),

@@ -22,6 +22,8 @@ pass over a memoryview, not per-token Python loops.
 
 from __future__ import annotations
 
+from array import array
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Iterable, Optional, Sequence
 
@@ -42,6 +44,8 @@ __all__ = [
     "TokenBlock",
     "PartialTokenBlock",
     "TokenBlockSequence",
+    "STRIDE_BLOCKS",
+    "BlockChainMemo",
 ]
 
 
@@ -252,3 +256,72 @@ class TokenBlockSequence:
             f"TokenBlockSequence(blocks={len(self.blocks)}, "
             f"partial={len(self.partial.tokens)}/{self.block_size})"
         )
+
+
+# Blocks a stride of the chain memo covers.  At the serving block size of 32
+# that is 2,048 tokens: a long prompt is a few to sixteen strides (a lookup
+# each), and a shared prefix is found to within a stride of its end.  A
+# smaller stride finds a little more and looks up more; 64 is where the
+# hit's cost stops falling (benchmarks/probe_admission.py).
+STRIDE_BLOCKS = 64
+
+
+class BlockChainMemo:
+    """The block chains of prompts already built, by strides of
+    ``STRIDE_BLOCKS`` blocks, so that a prompt which starts as an earlier one
+    did takes that one's ``TokenBlock``s instead of hashing them again (a
+    document asked many questions: ~770 blocks, two xxh3 and a tuple each).
+
+    Stride j's key is xxh3-64 of the key of stride j - 1 (salt and block size
+    for the first) and the stride's raw token bytes, so a key names the whole
+    prefix up to the stride's end, its salt and its block size.  A held key
+    yields the stride's blocks, which are frozen and shared between
+    sequences; the first stride that misses and everything after it go
+    through ``TokenBlockSequence.extend``, and the whole strides so built
+    are kept.  Equal 64-bit keys are taken for equal prefixes with no second
+    look at the tokens: the trust the prefix cache already puts in
+    ``sequence_hash`` when it hands one request another's K/V.
+
+    At most ``capacity`` strides are held, the least recently used dropped
+    first.  One owner, one thread (the engine's): no lock.
+    """
+
+    def __init__(self, capacity: int):
+        self.capacity = capacity
+        self._strides: OrderedDict[int, tuple[TokenBlock, ...]] = OrderedDict()
+
+    def __len__(self) -> int:
+        return len(self._strides)
+
+    def sequence(
+        self, tokens: Sequence[int], block_size: int, salt: int = 0
+    ) -> tuple[TokenBlockSequence, int]:
+        """``TokenBlockSequence(tokens, block_size, salt)``, equal in every
+        field of every block, and how many of its blocks the memo supplied."""
+        seq = TokenBlockSequence(block_size=block_size, salt=salt)
+        per = STRIDE_BLOCKS
+        span = per * block_size
+        n_strides = len(tokens) // span if self.capacity > 0 else 0
+        if n_strides == 0:      # a chat prompt: nothing to look up or keep
+            seq.extend(tokens)
+            return seq, 0
+        raw = array("I", tokens).tobytes()
+        keys: list[int] = []
+        key = compute_hash(np.array([salt, block_size], np.uint64).tobytes())
+        for j in range(n_strides):
+            key = compute_hash(
+                np.uint64(key).tobytes() + raw[j * span * 4 : (j + 1) * span * 4])
+            keys.append(key)
+        held = self._strides
+        hits = 0
+        while hits < n_strides and keys[hits] in held:
+            held.move_to_end(keys[hits])
+            seq.blocks.extend(held[keys[hits]])
+            hits += 1
+        seq.extend(tokens[hits * span :])
+        for j in range(hits, n_strides):
+            held[keys[j]] = tuple(seq.blocks[j * per : (j + 1) * per])
+            held.move_to_end(keys[j])
+        while len(held) > self.capacity:
+            held.popitem(last=False)
+        return seq, hits * per

@@ -138,6 +138,8 @@ def seed_http_metrics():
     ec.operand_buffers_total = 440
     ec.prompt_tokens_admitted_total = 1000
     ec.prompt_tokens_cached_total = 768
+    ec.prompt_blocks_admitted_total = 128
+    ec.prompt_blocks_reused_total = 96
     ec.attn_context_tokens_total = 48000
     ec.attn_selected_tokens_total = 4096
     ec.attn_fetched_tokens_total = 21000
