@@ -691,6 +691,22 @@ def main() -> None:
         "ssm_state/update",
         lambda: ssm_kernel.state_update(
             *probe_ssm_state_inputs(2, 16, 128, 64, 128, 1))))
+    # ... and the selective state's two at AI21-Jamba2-3B's (16 numbers a
+    # channel, 5,120 channels = 40 register rows: 16 slots' decode step of a
+    # two-layer leaf, and one request's 512-token chunk)
+    from dynamo_tpu.ops.pallas import selective_state as selective_kernel
+    from dynamo_tpu.ops.pallas.registry import (
+        probe_selective_scan_inputs, probe_selective_step_inputs,
+    )
+
+    variants.append((
+        "selective_state/update",
+        lambda: selective_kernel.state_update(
+            *probe_selective_step_inputs(2, 16, 16, 40))))
+    variants.append((
+        "selective_state/scan",
+        lambda: selective_kernel.state_scan(
+            *probe_selective_scan_inputs(2, 16, 16, 40, 512))))
     # the experts' grouped matmul at Solar-Open2's decode shape (512 sorted
     # rows, 32 of them on 20 held experts of 4,096 x 1,280, layer 1 of two)
     from dynamo_tpu.ops.pallas import grouped_matmul as gmm

@@ -344,6 +344,22 @@ def _child_kernels(arg: dict) -> None:
         y, new = state_update(*a, interpret=interpret)
         return reg.linear_state_rows(y, new[a[1]]), ref
 
+    def selective_step(_quant):
+        from dynamo_tpu.ops.pallas.selective_state import state_update
+
+        a = reg.probe_selective_step_inputs(2, 8, 16, 8)
+        ref = reg.selective_step_reference(*a)
+        y, new = state_update(*a, interpret=interpret)
+        return reg.linear_state_rows(y, new[a[1]]), ref
+
+    def selective_scan(_quant):
+        from dynamo_tpu.ops.pallas.selective_state import state_scan
+
+        a = reg.probe_selective_scan_inputs(2, 8, 16, 16, 64)
+        ref = reg.selective_scan_reference(*a)
+        y, new = state_scan(*a, interpret=interpret)
+        return reg.linear_state_rows(y, new[a[1]][a[2]]), ref
+
     def experts(_quant):
         from dynamo_tpu.ops.pallas import grouped_matmul as gmm
 
@@ -368,6 +384,8 @@ def _child_kernels(arg: dict) -> None:
         "latent_cache_dma": [("latent_write_rows", latent_dma)],
         "linear_state_update": [("state_step", state_step)],
         "ssm_state_update": [("ssm_step", ssm_step)],
+        "selective_state_update": [("sel_step", selective_step)],
+        "selective_state_scan": [("sel_scan", selective_scan)],
         "grouped_expert_matmul": [("experts", experts)],
     }
     live = [k for k, meta in reg.KERNELS.items() if not meta["placeholder"]]
@@ -379,6 +397,7 @@ def _child_kernels(arg: dict) -> None:
                     "int8_matmul", "mla_sparse_attention",
                     "mla_masked_prefill", "latent_cache_dma",
                     "linear_state_update", "ssm_state_update",
+                    "selective_state_update", "selective_state_scan",
                     "grouped_expert_matmul")
                           else [False, True]):
                 t0 = time.monotonic()

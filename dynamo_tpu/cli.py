@@ -38,7 +38,8 @@ MODELS_PREFIX = "models/"  # under {namespace}/
 def _load_any_checkpoint(path: str, dtype):
     """(model, params, quantized) for any supported checkpoint format:
     native (dynamo-tpu quantize), GGUF, or HF safetensors dir (Llama
-    family via the unified decoder; DeepSeek dirs via the MLA model).
+    family via the unified decoder; DeepSeek dirs via the MLA model; Jamba
+    dirs via the hybrid recurrent model).
     ``dtype`` None = native checkpoints keep their stored dtype, others
     bf16."""
     from dynamo_tpu.models.checkpoint import is_native_checkpoint, load_checkpoint
@@ -56,7 +57,9 @@ def _load_any_checkpoint(path: str, dtype):
         return LlamaModel(cfg), params, False
     from dynamo_tpu.models.loader import (
         is_deepseek_dir,
+        is_jamba_dir,
         load_deepseek_dir,
+        load_jamba_dir,
         load_model_dir,
     )
 
@@ -65,6 +68,11 @@ def _load_any_checkpoint(path: str, dtype):
 
         dcfg, params = load_deepseek_dir(path, dtype=dtype or "bfloat16")
         return DeepseekModel(dcfg), params, False
+    if is_jamba_dir(path):
+        from dynamo_tpu.models.hybrid_linear import HybridLinearModel
+
+        jcfg, params = load_jamba_dir(path, dtype=dtype or "bfloat16")
+        return HybridLinearModel(jcfg), params, False
     cfg, params = load_model_dir(path, dtype=dtype or "bfloat16")
     return LlamaModel(cfg), params, False
 

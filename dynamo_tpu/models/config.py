@@ -31,6 +31,14 @@ SUPPORTED_ARCHITECTURES = {
     "MellumForCausalLM",
 }
 
+# architectures served by a model class of their own, not by the unified
+# decoder this file configures: architecture -> (model_type, model class);
+# models/loader.py and cli._load_any_checkpoint route a checkpoint directory by it
+OTHER_ARCHITECTURES = {
+    "JambaForCausalLM": (
+        "jamba", "dynamo_tpu.models.hybrid_linear:HybridLinearModel"),
+}
+
 # kinds of attention layer a ``layer_types`` list may name
 LAYER_KINDS = ("sliding_attention", "full_attention")
 # rope_type of one kind's ``rope_parameters`` -> how LlamaModel builds it
@@ -218,6 +226,10 @@ class ModelConfig:
             cfg = dict(path_or_dict)
         archs = cfg.get("architectures") or []
         arch = archs[0] if archs else "LlamaForCausalLM"
+        if arch in OTHER_ARCHITECTURES:
+            raise ValueError(
+                f"{arch} is served by {OTHER_ARCHITECTURES[arch][1]}, not by "
+                "the unified decoder's ModelConfig")
         if arch not in SUPPORTED_ARCHITECTURES:
             raise ValueError(
                 f"unsupported architecture {arch!r}; supported: "

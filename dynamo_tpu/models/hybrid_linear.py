@@ -2,11 +2,14 @@
 fixed size behind a short causal convolution, every few attend over the paged
 K/V cache (grouped-query attention with no positional term).  Every layer
 ends in routed experts, of which this chip holds a share, plus a shared
-expert.  The recurrence is the configuration's (``recurrence``): the gated
-delta rule with a decay per key channel (``solar_open2``,
-ops/linear_state.py) or the state-space recurrence with a scalar decay a head
-(``granitemoehybrid``'s Mamba-2 layers, ops/ssm_state.py).
-docs/linear_state.md has the equations of both.
+expert — or, where the model has no experts, in one gated MLP with no router.
+The recurrence is the configuration's (``recurrence``): the gated delta rule
+with a decay per key channel (``solar_open2``, ops/linear_state.py), the
+state-space recurrence with a scalar decay a head (``granitemoehybrid``'s
+Mamba-2 layers, ops/ssm_state.py) or the selective scan with a decay a
+channel and state index (``jamba``'s Mamba-1 layers,
+ops/selective_state.py).  docs/linear_state.md has the equations of all
+three.
 
 Per layer (pre-norm residual blocks; a mixer's and an expert layer's output
 times ``residual_multiplier``):
@@ -21,12 +24,20 @@ times ``residual_multiplier``):
     with a bias over x‖B‖C, then SiLU; Δ = softplus(dt + b_dt), decay
     exp(Δ·A) a head; the recurrence (``ssd_step`` / ``ssd_scan``); RMSNorm
     over the whole width of y ⊙ SiLU(z); W_out.
+  * **Selective layer.**  x̃ ‖ z = W_in x; a depth-wise convolution with a
+    bias over x̃, then SiLU; δ ‖ B ‖ C = W_x x̂, each through an RMS norm of
+    its own; Δ = softplus(W_dt δ + b_dt) a channel, decay exp(Δ·A) a channel
+    and state index; the recurrence (``selective_step`` /
+    ``selective_scan``); (y + D ⊙ x̂) ⊙ SiLU(z); W_out.  No heads, no norm
+    on the output.
   * **GQA layer.**  softmax(q kᵀ · scale) v through the K/V pool and the
     Pallas kernels every dense model here uses (ops/paged_attention.py), no
     rope, no q/k norm; scale d^-1/2 or ``attention_multiplier``;
     o ⊙ sigmoid(W_gate x) where the model has the gate; W_o.
   * **Experts**: as models/glm_dsa.py — the router scores all
-    ``router_experts``, this chip computes the part its own give.
+    ``router_experts``, this chip computes the part its own give.  With
+    ``n_routed_experts`` 0 the layer's feed-forward is W_down(SiLU(W_gate u)
+    ⊙ W_up u) and nothing else: no router, no shared expert.
 
 Parameters are stacked per kind, and each run of consecutive layers of one
 kind is one ``lax.scan``.
@@ -66,8 +77,12 @@ from dynamo_tpu.models.llama import (
     rms_norm,
     split_heads,
 )
-from dynamo_tpu.ops import linear_state, ssm_state
+from dynamo_tpu.ops import linear_state, selective_state, ssm_state
 from dynamo_tpu.ops.pallas.linear_state import state_update
+from dynamo_tpu.ops.pallas.selective_state import (
+    state_scan as selective_state_scan,
+    state_update as selective_state_update,
+)
 from dynamo_tpu.ops.pallas.ssm_state import state_update as ssm_state_update
 from dynamo_tpu.ops.paged_attention import (
     paged_attention_layer,
@@ -125,9 +140,12 @@ class HybridLinearConfig:
     max_position_embeddings: int = 4096
     dtype: str = "bfloat16"
     # what the layers that do not attend run: "delta" (the gated delta rule,
-    # a state of linear_head_dim x linear_head_dim a head) or "ssd" (the
+    # a state of linear_head_dim x linear_head_dim a head), "ssd" (the
     # state-space recurrence, linear_head_dim x state_dim a head, B and C
     # shared by the heads of one of ``ssm_groups``, chunks of ``ssm_chunk``)
+    # or "selective" (the selective scan: state_dim numbers a channel, the
+    # channels as ``linear_heads`` rows of ``linear_head_dim`` = 128 lanes,
+    # ``gate_rank`` the rank of the step's bottleneck)
     recurrence: str = "delta"
     state_dim: int = 0
     ssm_groups: int = 1
@@ -154,16 +172,22 @@ class HybridLinearConfig:
 
     @property
     def conv_width(self) -> int:
-        """What the convolution runs over: q̂ ‖ k̂ ‖ v̂ of one token, or
-        x ‖ B ‖ C."""
+        """What the convolution runs over: q̂ ‖ k̂ ‖ v̂ of one token,
+        x ‖ B ‖ C, or x̃ alone."""
         if self.recurrence == "ssd":
             return self.ssm_width + 2 * self.ssm_groups * self.state_dim
+        if self.recurrence == "selective":
+            return self.ssm_width
         return 3 * self.linear_heads * self.linear_head_dim
 
     @property
     def state_shape(self) -> tuple:
-        """One slot's state in one recurrent layer."""
+        """One slot's state in one recurrent layer: (H, d, d) of the delta
+        rule, (H, P, N) of the state-space layers, (N, rows, lanes) of the
+        selective ones (N leading, the channels filling rows of lanes)."""
         d = self.linear_head_dim
+        if self.recurrence == "selective":
+            return (self.state_dim, self.linear_heads, d)
         return (self.linear_heads, d,
                 self.state_dim if self.recurrence == "ssd" else d)
 
@@ -175,13 +199,15 @@ class HybridLinearConfig:
     @classmethod
     def from_hf_config(cls, cfg: dict, dtype: str = "bfloat16"
                        ) -> "HybridLinearConfig":
-        """The published ``solar_open2`` or ``granitemoehybrid`` keys ->
-        HybridLinearConfig.  Raises, by name, on what this port does not
+        """The published ``solar_open2``, ``granitemoehybrid`` or ``jamba``
+        keys -> HybridLinearConfig.  Raises, by name, on what this port does not
         compute.  ``expert_parallel`` (not a published key) says which share
         of a layer's experts this chip holds, as for models/glm_dsa.py."""
         g = cfg.get
         if g("model_type") == "granitemoehybrid":
             return cls._from_granite(cfg, dtype)
+        if g("model_type") == "jamba":
+            return cls._from_jamba(cfg, dtype)
         if g("model_type") != "solar_open2":
             raise NotImplementedError(f"model_type {g('model_type')!r}")
         lin = g("linear_attn_config") or {}
@@ -302,6 +328,59 @@ class HybridLinearConfig:
             embedding_multiplier=float(g("embedding_multiplier", 1.0)),
             residual_multiplier=float(g("residual_multiplier", 1.0)),
             logits_scaling=float(g("logits_scaling", 1.0)),
+        )
+
+
+    @classmethod
+    def _from_jamba(cls, cfg: dict, dtype: str) -> "HybridLinearConfig":
+        """``jamba``: layer i attends iff i % ``attn_layer_period`` ==
+        ``attn_layer_offset`` (``JambaConfig.layers_block_type``), ``mamba_*``
+        sizes the others, and with ``num_experts`` 1 every layer's
+        feed-forward is one gated MLP of ``intermediate_size``
+        (``layers_num_experts``)."""
+        g = cfg.get
+        if int(g("num_experts", 16)) > 1:
+            raise NotImplementedError(
+                f"num_experts {g('num_experts', 16)} (Jamba's sparse "
+                "mixture every expert_layer_period-th layer; this port "
+                "serves the router-less MLP of num_experts 1)")
+        if g("sliding_window") is not None:
+            raise NotImplementedError(
+                f"sliding_window {g('sliding_window')!r}")
+        if bool(g("mamba_proj_bias", False)):
+            raise NotImplementedError("mamba_proj_bias=True")
+        if not bool(g("mamba_conv_bias", True)):
+            raise NotImplementedError("mamba_conv_bias=False")
+        if g("hidden_act", "silu") != "silu":
+            raise NotImplementedError(f"hidden_act {g('hidden_act')!r}")
+        n, dm = int(g("num_hidden_layers")), int(g("hidden_size"))
+        period, offset = (int(g("attn_layer_period", 8)),
+                          int(g("attn_layer_offset", 4)))
+        if not 0 <= offset < period:
+            raise ValueError(
+                f"attn_layer_offset {offset} is not below attn_layer_period "
+                f"{period}")
+        inner = int(g("mamba_expand", 2)) * dm
+        lanes = 128 if inner % 128 == 0 else inner
+        rank = g("mamba_dt_rank", "auto")
+        hq = int(g("num_attention_heads"))
+        return cls(
+            vocab_size=int(g("vocab_size")), hidden_size=dm, num_layers=n,
+            num_heads=hq, num_kv_heads=int(g("num_key_value_heads") or hq),
+            head_dim=int(g("head_dim") or dm // hq),
+            linear_heads=inner // lanes, linear_head_dim=lanes,
+            conv_kernel=int(g("mamba_d_conv", 4)),
+            gate_rank=-(-dm // 16) if rank == "auto" else int(rank),
+            gqa_layers=tuple(i for i in range(n) if i % period == offset),
+            moe_intermediate_size=int(g("intermediate_size")),
+            n_routed_experts=0, router_experts=0, expert_first=0,
+            num_experts_per_tok=0, n_shared_experts=0,
+            routed_scaling_factor=1.0, norm_topk_prob=True,
+            rms_norm_eps=float(g("rms_norm_eps", 1e-6)),
+            max_position_embeddings=int(g("max_position_embeddings", 4096)),
+            dtype=dtype, recurrence="selective",
+            state_dim=int(g("mamba_d_state", 16)), gqa_gate=False,
+            tie_word_embeddings=bool(g("tie_word_embeddings", False)),
         )
 
 
@@ -428,7 +507,9 @@ class HybridLinearModel:
         own (A_log = ln U(1, 16) a head; b_dt the inverse softplus of a
         log-uniform step in [0.001, 0.1] a channel — a head for the
         state-space layers — the family's initialisation; what the input
-        adds to b_dt scaled by ``DECAY_PROJ_STD``).  Keys are drawn in a
+        adds to b_dt scaled by ``DECAY_PROJ_STD``; the selective layers'
+        A_log = ln(1..N) a channel and W_dt uniform in ±rank^-1/2, Mamba's
+        published initialisation).  Keys are drawn in a
         fixed order: a new parameter goes after the ones that are there.  One program: made
         array by array, the draws are some forty compilations (200 s of a
         first start on the chip)."""
@@ -475,6 +556,16 @@ class HybridLinearModel:
                 shared_down=dense((n, fs, dm), fs))
             return out
 
+        def mlp(n: int) -> dict:
+            """The feed-forward of a model without experts: one gated MLP."""
+            f = cfg.moe_intermediate_size
+            return {"mlp_norm": jnp.ones((n, dm), dt),
+                    "mlp_gate": dense((n, dm, f), dm),
+                    "mlp_up": dense((n, dm, f), dm),
+                    "mlp_down": dense((n, f, dm), f)}
+
+        feed_forward = experts if cfg.n_routed_experts else mlp
+
         def gqa(n: int) -> dict:
             out = {"attn_norm": jnp.ones((n, dm), dt),
                    "wq": dense((n, dm, h * dh), dm),
@@ -483,7 +574,7 @@ class HybridLinearModel:
             if cfg.gqa_gate:
                 out["w_gate_attn"] = dense((n, dm, h * dh), dm)
             out["wo"] = dense((n, h * dh, dm), h * dh)
-            out.update(experts(n))
+            out.update(feed_forward(n))
             return out
 
         def linear(n: int) -> dict:
@@ -536,8 +627,34 @@ class HybridLinearModel:
                 **experts(n),
             }
 
+        def selective(n: int) -> dict:
+            inner, ns = cfg.ssm_width, cfg.state_dim
+            return {
+                "attn_norm": jnp.ones((n, dm), dt),
+                "w_in": dense((n, dm, 2 * inner), dm),          # x̃ ‖ z
+                "conv_w": conv_init((n, inner, cfg.conv_kernel)),
+                "conv_b": conv_init((n, inner)),
+                "w_x": dense((n, inner, r + 2 * ns), inner),    # δ ‖ B ‖ C
+                "dt_norm": jnp.ones((n, r), dt),
+                "b_norm": jnp.ones((n, ns), dt),
+                "c_norm": jnp.ones((n, ns), dt),
+                "w_dt": jax.random.uniform(
+                    next(keys), (n, r, inner), jnp.float32,
+                    -r ** -0.5, r ** -0.5).astype(dt),
+                "dt_bias": decay_step((n, inner)),
+                # [N, channels]: the published A_log [channels, N] turned,
+                # as the state lies
+                "a_log": jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, ns + 1, dtype=jnp.float32))[:, None],
+                    (n, ns, inner)),
+                "d_skip": jnp.ones((n, inner), jnp.float32),
+                "wo": dense((n, inner, dm), inner),
+                **feed_forward(n),
+            }
+
         make = {"gqa": gqa,
-                "linear": ssd if cfg.recurrence == "ssd" else linear}
+                "linear": {"ssd": ssd, "selective": selective,
+                           "delta": linear}[cfg.recurrence]}
         out = {
             "embed": dense((cfg.vocab_size, dm), dm),
             "groups": {kind: make[kind](n)
@@ -567,8 +684,9 @@ class HybridLinearModel:
         """``kv``: the K/V pool in LlamaModel's layout over the attending
         layers only, [L_gqa, N, 2, Bs, Hk·D], first in the pytree's order of
         what the engine counts a token's cache bytes by; ``state``
-        [L_lin, slots, *state_shape] float32 (H, d, d; H, P, N for the
-        state-space layers), ``conv`` [L_lin, slots, K-1, conv_width] and
+        [L_lin, slots, *state_shape] float32 (H, d, d of the delta rule;
+        H, P, N of the state-space layers; N, rows, lanes of the selective
+        ones), ``conv`` [L_lin, slots, K-1, conv_width] and
         ``state_pos`` [slots] (ops/linear_state.py), indexed by the engine's
         slot; ``moe_counts`` int32 [L, 1, 7]: what the expert
         layers counted (as models/glm_dsa.py) and, in row 0, what the linear
@@ -608,16 +726,30 @@ class HybridLinearModel:
             return ssm_state.step_impl(*self.config.state_shape,
                                        self.config.ssm_groups,
                                        self.state_dtype)
+        if self.config.recurrence == "selective":
+            return selective_state.step_impl(*self.config.state_shape,
+                                             self.state_dtype)
         return linear_state.step_impl(*self.config.state_shape,
                                       self.state_dtype)
 
+    def state_scan_impl(self) -> tuple[str, str]:
+        """``state_update_impl`` for a prefill chunk's recurrence: a kernel
+        for the selective scan alone, which has no matrix-product form of a
+        chunk; the other two run their chunked XLA forms."""
+        if self.config.recurrence != "selective":
+            return "xla", f"the {self.config.recurrence} chunk is XLA's"
+        return selective_state.scan_impl(*self.config.state_shape,
+                                         self.state_dtype)
+
     def _updates_in_place(self, s: int, slots) -> bool:
         """A decode over the slot array updates the state where it lies, in
-        its recurrence's kernel (ops/pallas/linear_state.py, ssm_state.py); a
-        prefill chunk, and any backend but the TPU, slices it, runs the XLA
-        form and sets it."""
-        return (s == 1 and slots is None
-                and self.state_update_impl()[0] == "pallas")
+        its recurrence's kernel (ops/pallas/linear_state.py, ssm_state.py,
+        selective_state.py), and so does a prefill chunk of the selective
+        scan; any other prefill chunk, and any backend but the TPU, slices
+        the state, runs the XLA form and sets it."""
+        if s == 1:
+            return slots is None and self.state_update_impl()[0] == "pallas"
+        return self.state_scan_impl()[0] == "pallas"
 
     # ---------------------------------------------------------------- forward
     def _add(self, h, out):
@@ -650,6 +782,13 @@ class HybridLinearModel:
         shared = (jax.nn.silu(xf @ lp["shared_gate"])
                   * (xf @ lp["shared_up"])) @ lp["shared_down"]
         return self._add(h, (routed + shared).reshape(b, s, d)), counted
+
+    def _mlp(self, lp: dict, h):
+        """h + W_down(SiLU(W_gate u) ⊙ W_up u), u = RMSNorm(h): the
+        feed-forward of a model without experts."""
+        x = rms_norm(h, lp["mlp_norm"], self.config.rms_norm_eps)
+        return self._add(h, (jax.nn.silu(x @ lp["mlp_gate"])
+                             * (x @ lp["mlp_up"])) @ lp["mlp_down"])
 
     def _gqa(self, lp, ci, h, kv, positions, block_tables, seq_lens,
              slot_idx, prefix_blocks, by_length):
@@ -814,6 +953,87 @@ class HybridLinearModel:
             h = self._add(h, o.reshape(b, s, lh * ld) @ lp["wo"])
         return h, state, conv
 
+    def _selective(self, lp, si, h, state, conv, rows):
+        """One selective state-space layer; arguments as ``_linear``.  The
+        whole mixer is under ``selective``; what a slot keeps (the
+        convolution's tail and the state) is read, advanced and written
+        under ``selective_step`` (one token a row) or ``selective_scan`` (a
+        chunk): two program classes, two scopes."""
+        cfg = self.config
+        b, s, _ = h.shape
+        ns, r, lanes = cfg.state_shape
+        inner, rank = cfg.ssm_width, cfg.gate_rank
+        slots, fresh, alive, n_real, valid = rows
+        f32 = jnp.float32
+        eps = cfg.rms_norm_eps
+        with jax.named_scope("selective"):
+            with jax.named_scope("attn_proj"):
+                x = rms_norm(h, lp["attn_norm"], eps)
+                xin, z = jnp.split(x @ lp["w_in"], 2, axis=-1)
+            in_place = self._updates_in_place(s, slots)
+            with jax.named_scope("attn"):
+                at = si if slots is None else (si, slots)   # row i is slot i
+                # what a slot keeps — the tail here, the state below — is
+                # read and written under one scope, the mixer's products
+                # between the two outside it
+                kept = "selective_step" if s == 1 else "selective_scan"
+                with jax.named_scope(kept):
+                    old_c = conv[at]
+                    tail = jnp.where(fresh[:, None, None], 0, old_c)
+                    y, new_c = linear_state.short_conv(
+                        xin, lp["conv_w"], tail, n_real, lp["conv_b"])
+                    xs = jax.nn.silu(y).astype(h.dtype)
+                delta, bm, cm = jnp.split(
+                    xs @ lp["w_x"], (rank, rank + ns), axis=-1)
+                delta = rms_norm(delta, lp["dt_norm"], eps)
+                bm = rms_norm(bm, lp["b_norm"], eps)
+                cm = rms_norm(cm, lp["c_norm"], eps)
+                step = jax.nn.softplus(
+                    jnp.matmul(delta, lp["w_dt"], preferred_element_type=f32)
+                    + lp["dt_bias"].astype(f32))
+                step = jnp.where(valid[..., None], step, 0.0)   # padding
+                a = -jnp.exp(lp["a_log"].astype(f32)).reshape(ns, r, lanes)
+                xf = xs.astype(f32).reshape(b, s, r, lanes)
+                step = step.reshape(b, s, r, lanes)
+                with jax.named_scope(kept):
+                    if in_place and s == 1:
+                        # the zero start and the dead row's rule are the
+                        # kernel's
+                        o, state = selective_state_update(
+                            state, si, xf[:, 0], step[:, 0], a, bm[:, 0],
+                            cm[:, 0], fresh, alive)
+                        o = o[:, None]
+                    elif in_place:
+                        # a dead row's steps are all zero: an identity
+                        o, state = selective_state_scan(
+                            state, si,
+                            jnp.arange(b) if slots is None else slots,
+                            xf, step, a, bm, cm, fresh)
+                    else:
+                        old_s = state[at]
+                        s0 = jnp.where(fresh[:, None, None, None], 0,
+                                       old_s.astype(f32))
+                        if s == 1:
+                            o, new_s = selective_state.selective_step(
+                                xf[:, 0], step[:, 0], a, bm[:, 0], cm[:, 0],
+                                s0)
+                            o = o[:, None]
+                        else:
+                            o, new_s = selective_state.selective_scan(
+                                xf, step, a, bm, cm, s0)
+                        # a row with no real token keeps its slot bit for bit
+                        new_s = jnp.where(alive[:, None, None, None],
+                                          new_s.astype(state.dtype), old_s)
+                        state = state.at[at].set(new_s)
+                    conv = conv.at[at].set(
+                        jnp.where(alive[:, None, None], new_c, old_c))
+                o = (o + lp["d_skip"].astype(f32).reshape(r, lanes) * xf
+                     ).reshape(b, s, inner)
+                o = (o * jax.nn.silu(z.astype(f32))).astype(h.dtype)
+            with jax.named_scope("attn_out"):
+                h = self._add(h, o @ lp["wo"])
+        return h, state, conv
+
     def forward(self, params, tokens, positions, cache, block_tables,
                 seq_lens, slot_idx, prefix_blocks=None, seq_slots=None):
         """(hidden [B,S,Dm], cache).  ``seq_slots`` int32 [B]: the engine
@@ -842,7 +1062,8 @@ class HybridLinearModel:
         kv, state, conv = cache["kv"], cache["state"], cache["conv"]
         counts = cache["moe_counts"].at[0, 0, EXPERT_COUNTS:].add(counted)
         expert_keys = ("w_gate", "w_up", "w_down")
-        recur = self._ssd if cfg.recurrence == "ssd" else self._linear
+        recur = {"ssd": self._ssd, "selective": self._selective,
+                 "delta": self._linear}[cfg.recurrence]
 
         def layer_step(kind: str):
             group = params["groups"][kind]
@@ -859,8 +1080,11 @@ class HybridLinearModel:
                 else:
                     h, state, conv = recur(lp, i, h, state, conv, rows)
                 with jax.named_scope("mlp"):
-                    h, picked = self._experts(group, lp, i, h, valid)
-                    counts = counts.at[li, 0, :EXPERT_COUNTS].add(picked)
+                    if cfg.n_routed_experts:
+                        h, picked = self._experts(group, lp, i, h, valid)
+                        counts = counts.at[li, 0, :EXPERT_COUNTS].add(picked)
+                    else:
+                        h = self._mlp(lp, h)
                 return (h, kv, state, conv, counts), None
             return step
 

@@ -21,7 +21,8 @@ import numpy as np
 
 from dynamo_tpu.models.config import ModelConfig
 
-__all__ = ["load_params_from_state_dict", "load_params_from_dir", "load_model_dir"]
+__all__ = ["load_params_from_state_dict", "load_params_from_dir", "load_model_dir",
+           "is_jamba_dir", "load_jamba_dir", "jamba_params_from_state_dict"]
 
 
 def _np(x) -> np.ndarray:
@@ -257,3 +258,93 @@ def load_deepseek_dir(model_dir: str | Path, dtype: str = "bfloat16"):
     )
     cfg.dtype = dtype
     return cfg, convert_hf_state_dict(_LazySafetensors(Path(model_dir)), cfg)
+
+
+def is_jamba_dir(model_dir: str | Path) -> bool:
+    """True when config.json declares ``JambaForCausalLM`` / ``jamba`` (the
+    Mamba-1 hybrid loads through models/hybrid_linear.py, not the unified
+    decoder)."""
+    p = Path(model_dir) / "config.json"
+    if not p.exists():
+        return False
+    try:
+        cfg = json.loads(p.read_text())
+    except Exception:
+        return False
+    from dynamo_tpu.models.config import OTHER_ARCHITECTURES
+
+    model_type = OTHER_ARCHITECTURES["JambaForCausalLM"][0]
+    return ("JambaForCausalLM" in (cfg.get("architectures") or [])
+            or cfg.get("model_type") == model_type)
+
+
+def jamba_params_from_state_dict(cfg, state: Mapping[str, Any]) -> dict:
+    """The published ``JambaForCausalLM`` names (modeling_jamba.py) -> the
+    stacked groups of models/hybrid_linear.py: ``gqa`` the attending layers,
+    ``linear`` the Mamba layers, each over its layers in order, weights
+    turned to ``x @ W``; ``A_log`` [I, N] turned to [N, I] as the state
+    lies, and it, ``D`` and ``dt_proj.bias`` kept float32."""
+    dt = cfg.jax_dtype
+
+    def stack(layers, name: str, turn: bool = True, dtype=dt) -> jnp.ndarray:
+        ws = [_np(state[f"model.layers.{i}.{name}"]) for i in layers]
+        return jnp.asarray(np.stack([w.T if turn else w for w in ws]), dtype)
+
+    def shared(layers) -> dict:
+        return {
+            "attn_norm": stack(layers, "input_layernorm.weight", False),
+            "mlp_norm": stack(layers, "pre_ff_layernorm.weight", False),
+            "mlp_gate": stack(layers, "feed_forward.gate_proj.weight"),
+            "mlp_up": stack(layers, "feed_forward.up_proj.weight"),
+            "mlp_down": stack(layers, "feed_forward.down_proj.weight"),
+        }
+
+    f32 = jnp.float32
+
+    def gqa(layers) -> dict:
+        return {
+            "wq": stack(layers, "self_attn.q_proj.weight"),
+            "wk": stack(layers, "self_attn.k_proj.weight"),
+            "wv": stack(layers, "self_attn.v_proj.weight"),
+            "wo": stack(layers, "self_attn.o_proj.weight"),
+            **shared(layers)}
+
+    def mamba(layers) -> dict:
+        return {
+            "w_in": stack(layers, "mamba.in_proj.weight"),
+            "conv_w": stack(layers, "mamba.conv1d.weight", False)[:, :, 0],
+            "conv_b": stack(layers, "mamba.conv1d.bias", False),
+            "w_x": stack(layers, "mamba.x_proj.weight"),
+            "dt_norm": stack(layers, "mamba.dt_layernorm.weight", False),
+            "b_norm": stack(layers, "mamba.b_layernorm.weight", False),
+            "c_norm": stack(layers, "mamba.c_layernorm.weight", False),
+            "w_dt": stack(layers, "mamba.dt_proj.weight"),
+            "dt_bias": stack(layers, "mamba.dt_proj.bias", False, f32),
+            "a_log": stack(layers, "mamba.A_log", True, f32),
+            "d_skip": stack(layers, "mamba.D", False, f32),
+            "wo": stack(layers, "mamba.out_proj.weight"),
+            **shared(layers)}
+
+    kinds = {"gqa": (gqa, list(cfg.gqa_layers)),
+             "linear": (mamba, [i for i in range(cfg.num_layers)
+                                if i not in cfg.gqa_layers])}
+    out = {
+        "embed": jnp.asarray(_np(state["model.embed_tokens.weight"]), dt),
+        "groups": {kind: make(layers)
+                   for kind, (make, layers) in kinds.items() if layers},
+        "final_norm": jnp.asarray(
+            _np(state["model.final_layernorm.weight"]), dt),
+    }
+    if not cfg.tie_word_embeddings:
+        out["lm_head"] = jnp.asarray(_np(state["lm_head.weight"]).T, dt)
+    return out
+
+
+def load_jamba_dir(model_dir: str | Path, dtype: str = "bfloat16"):
+    """(HybridLinearConfig, params) from a ``jamba`` HF directory."""
+    from dynamo_tpu.models.hybrid_linear import HybridLinearConfig
+
+    cfg = HybridLinearConfig.from_hf_config(
+        json.loads((Path(model_dir) / "config.json").read_text()), dtype=dtype)
+    return cfg, jamba_params_from_state_dict(
+        cfg, _LazySafetensors(Path(model_dir)))

@@ -55,6 +55,9 @@ __all__ = [
     "linear_state_heads_per_step",
     "SSM_STATE_BLOCK_BYTES",
     "ssm_state_heads_per_step",
+    "SELECTIVE_SCAN_TOKENS",
+    "SELECTIVE_SCAN_ROWS_PER_TILE",
+    "SELECTIVE_SCAN_SCALARS",
     "GROUPED_MATMUL_ROW_TILE",
     "GROUPED_MATMUL_ROW_TILE_BYTES",
     "GROUPED_MATMUL_COMPILER_VMEM_BYTES",
@@ -86,6 +89,10 @@ __all__ = [
     "linear_state_reference",
     "ssm_state_cost",
     "ssm_state_reference",
+    "selective_step_cost",
+    "selective_scan_cost",
+    "selective_step_reference",
+    "selective_scan_reference",
     "grouped_matmul_cost",
     "grouped_matmul_reference",
     "decode_cost_estimate",
@@ -141,6 +148,15 @@ LINEAR_STATE_HEADS_PER_STEP = 16
 # many heads as make them (32 of 64 x 128 float32: 16 rows of x at two heads
 # a 128-lane row, turned into columns in one tile)
 SSM_STATE_BLOCK_BYTES = 1024 * 1024
+# selective scan of a prefill chunk: the tokens a grid step walks with a
+# channel tile's state in registers (their x, Δ and y blocks are 512 KiB
+# each at 8 rows of 128 lanes), the register rows a channel tile (8: a
+# tile's N = 16 state registers, its 16 rows of A and a token's handful fit
+# the 64 the core has), and the numbers of B and of C one call reads from
+# SMEM (512 tokens x 16: 64 KiB each; a longer chunk goes in pieces)
+SELECTIVE_SCAN_TOKENS = 128
+SELECTIVE_SCAN_ROWS_PER_TILE = 8
+SELECTIVE_SCAN_SCALARS = 8192
 # the experts' grouped matmul: sorted rows a row tile (a pair's matmul takes
 # the whole tile, so the tile is what the matrix unit streams past each
 # weight it loads) and the bytes it may hold (at 6,144 columns 64 rows), the
@@ -221,6 +237,16 @@ KERNELS = {
     # the state-space state's decode step, the same
     "ssm_state_update": {
         "module": "dynamo_tpu.ops.pallas.ssm_state",
+        "placeholder": False,
+    },
+    # the selective state's decode step, the same, and the scan of a prefill
+    # chunk with a channel tile's state in registers
+    "selective_state_update": {
+        "module": "dynamo_tpu.ops.pallas.selective_state",
+        "placeholder": False,
+    },
+    "selective_state_scan": {
+        "module": "dynamo_tpu.ops.pallas.selective_state",
         "placeholder": False,
     },
     # the experts' grouped matmul where an expert has few rows: a stream of
@@ -610,6 +636,27 @@ def ssm_state_cost(rows: int, heads: int, p: int, n: int,
     return _cost_dict(
         dma=2 * cells * 4 + rows * (2 * heads * p + 2 * groups * n + heads) * 4,
         flops=5 * cells, trans=rows * heads)
+
+
+def selective_step_cost(rows: int, n: int, channels: int) -> dict:
+    """A decode row reads and writes its float32 state [N, channels] once
+    beside its x, Δ and y rows and its B and C
+    (cellbench/costs/selective_step.py counts the state's bytes the same) and
+    spends ~6 operations and one exponential an element: Δ A, the decay, the
+    update's two, the read-out's two."""
+    cells = rows * n * channels
+    return _cost_dict(dma=2 * cells * 4 + rows * (3 * channels + 2 * n) * 4,
+                      flops=6 * cells, trans=cells)
+
+
+def selective_scan_cost(rows: int, s: int, n: int, channels: int) -> dict:
+    """A prefill chunk of ``s`` tokens a row reads and writes the row's state
+    once, reads x̂ and Δ and writes y a token (float32), B and C a token, and
+    spends the decode step's operations a token and state element."""
+    cells = rows * s * n * channels
+    return _cost_dict(
+        dma=2 * rows * n * channels * 4 + rows * s * (3 * channels + 2 * n) * 4,
+        flops=6 * cells, trans=cells)
 
 
 def grouped_matmul_row_tile(m: int, k: int, x_bytes: int = 2) -> int:
@@ -1392,6 +1439,30 @@ def ssm_state_reference(state, layer, x, dt, a_head, b, c, d, fresh, alive):
                                 d, fresh, alive)
 
 
+def selective_step_reference(state, layer, x, dt, a, b, c, fresh, alive):
+    """What ``selective_state.state_update`` must give, by ``selective_step``
+    (``_slot_step_reference``; ``a`` [N, R, 128] is the layer's, not a
+    row's, and goes in behind the row's x and Δ)."""
+    from dynamo_tpu.ops.selective_state import selective_step
+
+    step = lambda x, dt, b, c, st: selective_step(x, dt, a, b, c, st)
+    return _slot_step_reference(step, state, layer, x, dt, b, c, fresh, alive)
+
+
+def selective_scan_reference(state, layer, slots, x, dt, a, b, c, fresh):
+    """What ``selective_state.state_scan`` must give, by ``selective_scan``
+    on the rows' slots: ``y`` [B, S·R·128] beside each row's new state, a
+    row a dispatch row (``linear_state_rows``)."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops.selective_state import selective_scan
+
+    old = state[layer][slots]
+    y, new = selective_scan(
+        x, dt, a, b, c, jnp.where(fresh[:, None, None, None], 0, old))
+    return linear_state_rows(y, new)
+
+
 def _slot_state_case(name: str, kernel: str, vectors, state_shape: tuple,
                      reference, pricing) -> dict:
     """An audit case of a recurrent state's decode step: four slots, layer 1
@@ -1473,6 +1544,75 @@ def _ssm_state_case() -> dict:
     return _slot_state_case(
         "ssm-step", "ssm_state_update", vectors, (2, b, h, p, n),
         ssm_state_reference, lambda: ssm_state_cost(b, h, p, n, g))
+
+
+def _selective_vectors(rng, lead: tuple, n: int, rows: int):
+    """x, Δ [*lead, R, 128], A [N, R, 128], B, C [*lead, N] of the selective
+    recurrence, in the kernels' order."""
+    np = _np()
+    a = -np.arange(1, n + 1, dtype=np.float32)[:, None, None] * np.ones(
+        (n, rows, 128), np.float32)
+    return (rng.normal(size=(*lead, rows, 128)),
+            0.1 * rng.random(size=(*lead, rows, 128)), a,
+            rng.normal(size=(*lead, n)), rng.normal(size=(*lead, n)))
+
+
+def _selective_step_case() -> dict:
+    """The selective recurrence's decode step: 16 numbers a channel, 1,024
+    channels (eight register rows)."""
+    b, n, rows = 4, 16, 8
+    return _slot_state_case(
+        "selective-step", "selective_state_update",
+        lambda rng: _selective_vectors(rng, (b,), n, rows),
+        (2, b, n, rows, 128), selective_step_reference,
+        lambda: selective_step_cost(b, n, rows * 128))
+
+
+def _selective_scan_case() -> dict:
+    """The selective recurrence's prefill scan: two rows of 24 tokens (three
+    token blocks of 8) into slots 2 and 0 of four, 2,048 channels (two
+    channel tiles); row 0 starts afresh — the poisoned run fills its slot
+    with NaN beforehand — and row 1's last 9 tokens are padding (Δ = 0).
+    Output: ``y`` and each row's new state, a row a dispatch row."""
+    import jax.numpy as jnp
+
+    np = _np()
+    layer, nb, s, n, rows = 1, 2, 24, 16, 16
+    slots = np.array([2, 0], np.int32)
+    fresh = np.array([True, False])
+
+    def build():
+        rng = np.random.default_rng(801)
+        x, dt, a, b, c = _selective_vectors(rng, (nb, s), n, rows)
+        dt[1, 15:] = 0.0
+        return {"vectors": tuple(jnp.asarray(v, jnp.float32)
+                                 for v in (x, dt, a, b, c)),
+                "state": rng.normal(size=(2, 4, n, rows, 128)
+                                    ).astype(np.float32)}
+
+    def run(inp, poisoned: bool):
+        from dynamo_tpu.ops.pallas.selective_state import state_scan
+
+        state = inp["state"].copy()
+        if poisoned:
+            state[layer, slots[fresh]] = np.nan
+        y, new = state_scan.__wrapped__(
+            jnp.asarray(state), jnp.int32(layer), jnp.asarray(slots),
+            *inp["vectors"], jnp.asarray(fresh), interpret=True)
+        return linear_state_rows(y, new[layer][slots])
+
+    def oracle(inp):
+        ref = np.asarray(selective_scan_reference(
+            jnp.asarray(inp["state"]), layer, jnp.asarray(slots),
+            *inp["vectors"], jnp.asarray(fresh)))
+        return ref, np.ones(ref.shape, bool), np.zeros(ref.shape, bool)
+
+    return {
+        "name": "selective-scan", "kernel": "selective_state_scan",
+        "mode": "interpret", "atol": 1e-5, "build": build, "run": run,
+        "oracle": oracle,
+        "pricing": lambda: selective_scan_cost(nb, s, n, rows * 128),
+    }
 
 
 def grouped_matmul_reference(xs, w, group_sizes, first_group=0):
@@ -1690,6 +1830,8 @@ def audit_cases() -> list[dict]:
         _latent_dma_case("gather"),
         _linear_state_case(),
         _ssm_state_case(),
+        _selective_step_case(),
+        _selective_scan_case(),
         _grouped_matmul_case(),
         _spec_decode_8b(),
         _spec_prefill_8b(),
@@ -1713,6 +1855,15 @@ def audit_cases() -> list[dict]:
         _spec_decode_8b("decode-zaya", b=64, h=8, hk=2, bs=32, n=6272, L=20),
         _spec_prefill_8b("prefill-zaya", s=512, h=8, hk=2, bs=32, n=6272,
                          L=20),
+        # AI21-Jamba2-3B's two attending layers: 20 query heads on ONE K/V
+        # head of 128 in blocks of 32 — a K/V row of 128 lanes (one lane
+        # tile: the least so far was 256) and 20 query rows a sequence, which
+        # is not a multiple of 8 sublanes (every other cell has 4 or 8 a K/V
+        # head): the query block, the accumulator and the m / l statistics
+        # are whole-array blocks of 20 rows that Mosaic pads to 24
+        _spec_decode_8b("decode-jamba", b=64, h=20, hk=1, bs=32, n=6272, L=2),
+        _spec_prefill_8b("prefill-jamba", s=512, h=20, hk=1, bs=32, n=6272,
+                         L=2),
     ]
 
 
@@ -1937,6 +2088,40 @@ def probe_ssm_state_inputs(layers, slots, heads, p, n, groups):
             jnp.asarray(at == 1), jnp.asarray(at % 8 != 7))
 
 
+def probe_selective_step_inputs(layers, slots, n, rows):
+    """state [L,B,N,R,128] f32, layer, x, dt [B,R,128], a [N,R,128], b, c
+    [B,N], fresh, alive [B] (every eighth slot idle, one starting afresh)."""
+    import jax
+    import jax.numpy as jnp
+
+    np = _np()
+    at = np.arange(slots)
+    vectors = _selective_vectors(np.random.default_rng(0), (slots,), n, rows)
+    return (jax.random.normal(jax.random.PRNGKey(0),
+                              (layers, slots, n, rows, 128), jnp.float32),
+            jnp.int32(layers - 1),
+            *(jnp.asarray(v, jnp.float32) for v in vectors),
+            jnp.asarray(at == 1), jnp.asarray(at % 8 != 7))
+
+
+def probe_selective_scan_inputs(layers, slots, n, rows, s):
+    """state [L,slots,N,R,128] f32, layer, the row's slot [1], x, dt
+    [1,S,R,128], a [N,R,128], b, c [1,S,N], fresh [1]: one request's chunk
+    of ``s`` tokens into the last slot, its last eighth padding."""
+    import jax
+    import jax.numpy as jnp
+
+    np = _np()
+    x, dt, a, b, c = _selective_vectors(
+        np.random.default_rng(0), (1, s), n, rows)
+    dt[:, s - s // 8:] = 0.0
+    return (jax.random.normal(jax.random.PRNGKey(0),
+                              (layers, slots, n, rows, 128), jnp.float32),
+            jnp.int32(layers - 1), jnp.asarray([slots - 1], jnp.int32),
+            *(jnp.asarray(v, jnp.float32) for v in (x, dt, a, b, c)),
+            jnp.asarray([False]))
+
+
 def probe_grouped_matmul_inputs(m, layers, e, k, n, rows):
     """xs [m, K], w [L·E, K, N] bf16, group_sizes [E] (``rows`` of the m
     spread over the experts, some with none), the last layer's first group."""
@@ -1957,6 +2142,8 @@ _PROBE_BUILDERS = {
     "grouped_expert_matmul": probe_grouped_matmul_inputs,
     "linear_state_update": probe_linear_state_inputs,
     "ssm_state_update": probe_ssm_state_inputs,
+    "selective_state_update": probe_selective_step_inputs,
+    "selective_state_scan": probe_selective_scan_inputs,
     "mla_masked_prefill": probe_mla_masked_inputs,
     "mla_sparse_attention": probe_mla_sparse_inputs,
     "latent_cache_dma": probe_latent_dma_inputs,

@@ -1,6 +1,6 @@
 """The agreement of a served recurrent-state configuration (``--config``:
 solar-open2-ep16, the delta rule, by default; granite-4.0-h-small-ep2, the
-state-space recurrence) with its reference over a long answer, which the benchmark's ``correct`` cannot reach: it sees 8
+state-space recurrence; jamba2-3b, the selective scan) with its reference over a long answer, which the benchmark's ``correct`` cannot reach: it sees 8
 greedy tokens behind at most 700, and the question a recurrent state raises is
 what a thousand updates do to it.
 
@@ -99,8 +99,23 @@ TINY_GRANITE = dict(
     # and its cache one precision down 2.7e-4
     check={"abs_tol": 0.06, "share_within": 0.98, "median_tol": 1e-4})
 
+TINY_JAMBA = dict(
+    model_type="jamba", vocab_size=512, hidden_size=64, intermediate_size=96,
+    num_hidden_layers=6, attention_layers=1, attn_layer_period=6,
+    attn_layer_offset=2, expert_layer_period=2, expert_layer_offset=1,
+    num_experts=1, num_experts_per_tok=1, num_attention_heads=4,
+    num_key_value_heads=1, head_dim=16, mamba_d_state=16, mamba_d_conv=4,
+    mamba_expand=2, mamba_dt_rank=4, mamba_conv_bias=True,
+    mamba_proj_bias=False, sliding_window=None, tie_word_embeddings=True,
+    rms_norm_eps=1e-6, max_position_embeddings=4096,
+    dtype="float32", reference="jamba_hybrid",
+    model_class=TINY["model_class"], config_class=TINY["config_class"],
+    serve=TINY["serve"],
+    check={"abs_tol": 0.06, "share_within": 1.0, "median_tol": 1e-4})
+
 # --config: the benchmark's file and the toy that rehearses it
-CONFIGS = {"solar-open2-ep16": TINY, "granite-4.0-h-small-ep2": TINY_GRANITE}
+CONFIGS = {"solar-open2-ep16": TINY, "granite-4.0-h-small-ep2": TINY_GRANITE,
+           "jamba2-3b": TINY_JAMBA}
 
 
 def bf16_state() -> None:

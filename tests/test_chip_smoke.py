@@ -64,14 +64,16 @@ def test_tiny_reports_what_the_server_ran(tiny):
     kernels = [l for l in tiny.lines if l.startswith("  kernel ")]
     # nine lines of the GQA kernels and the int8 matmul, then the sparse
     # and the masked latent-attention kernels, the latent cache's writes,
-    # the two recurrent states' decode steps and the experts' grouped matmul
-    assert len(kernels) == 15 and all(
+    # the three recurrent states' decode steps, the selective scan of a
+    # prefill chunk and the experts' grouped matmul
+    assert len(kernels) == 17 and all(
         l.endswith("PASS") and "interpret=True" in l for l in kernels)
     assert any("sparse_latent" in l for l in kernels)
     assert any("masked_latent" in l for l in kernels)
     assert any("latent_write_rows" in l for l in kernels)
     assert any(l.split()[1] == "experts" for l in kernels)
-    assert {"state_step", "ssm_step"} <= {l.split()[1] for l in kernels}
+    assert {"state_step", "ssm_step", "sel_step", "sel_scan"} <= {
+        l.split()[1] for l in kernels}
 
 
 def test_device_check_fails_on_a_cpu_machine():

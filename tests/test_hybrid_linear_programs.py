@@ -91,6 +91,42 @@ def test_the_delta_rule_model_lowers_to_the_program_it_had(program):
                           ).hexdigest() == PARENT_DELTA_RULE_HLO[program]
 
 
+# the state-space toy (granite_hybrid_tiny.TINY) and, again, the delta-rule
+# toy on the parent of the PR that gave the model class its third recurrence
+# and a feed-forward without experts (2fb8531): neither adds an operation to
+# the programs of the two recurrences that were there
+PARENT_SSD_HLO = {
+    "decode": "54dc87cc94475f898c451dff92a3f15d24707a4b56f6bbe78f0bc7848fbdcf08",
+    "prefill": "a52bbe7b19323615fb5c6d1f813f71a0136e4bfcabb9ce598bcc49c7332e1dd5",
+}
+
+
+@pytest.mark.parametrize("program", sorted(PARENT_SSD_HLO))
+def test_the_state_space_model_lowers_to_the_program_it_had(program):
+    from granite_hybrid_tiny import build
+
+    model, _ = build()
+    extra = ({"seq_slots": jnp.zeros((1,), jnp.int32)}
+             if program == "prefill" else {})
+    assert hashlib.sha256(_lowered(model, program, **extra).encode()
+                          ).hexdigest() == PARENT_SSD_HLO[program]
+
+
+def test_the_selective_model_lowers_to_one_scan_a_run_of_layers():
+    """m x7 | A | m x6: two scans more than a model whose layers are one (a
+    run of one layer is a scan of one step); on the CPU a chunk's recurrence
+    is one more, ``selective_scan``'s over its tokens."""
+    from jamba_tiny import build
+
+    model, _ = build()
+    slot = jnp.zeros((1,), jnp.int32)
+    for program, extra in (("decode", {}), ("prefill", {"seq_slots": slot})):
+        text = _lowered(model, program, **extra)
+        assert text.count("stablehlo.while") == _lowered(
+            _toy("llama"), program).count("stablehlo.while") + 2 + (
+                program == "prefill")       # ... and the chunk's token scan
+
+
 def test_the_hybrid_model_lowers_to_one_scan_a_run_of_layers():
     """G | L L L | G | L L L: four scans in a decode step; a 16-token chunk
     is one piece of the recurrence, so a prefill has the same four.  The chunk is told its

@@ -1554,3 +1554,88 @@ def test_mellum_cell_programs_name_both_kernel_forms_and_fit_the_chip(
     assert 14.0e9 < mem.argument_size_in_bytes < 14.1e9
     assert mem.temp_size_in_bytes < 0.8e9, mem.temp_size_in_bytes
     assert total < 0.9 * V5E_HBM, total
+
+
+@pytest.mark.parametrize("program", [
+    "decode", "prefill",
+    # 89 CPU-seconds of compiling (five scans of float32 layer bodies): over
+    # the tier-1 budget of a test; the builder ran it before the first chip
+    # call (temporaries 0.041 GB)
+    pytest.param("reference", marks=pytest.mark.slow)])
+def test_jamba_cell_programs_run_both_selective_kernels_in_place(
+        topo, tpu_gate, program):
+    """jamba2-3b's decode program (64 rows = the slot array), a 512-token
+    chunk with 16 blocks of the prompt cached, and the check's float32
+    reference over its longest sequence, whole (28 layers m x7 | A | m x13 |
+    A | m x6, the 65,536-row tied matrix, the cell's pool and its 64 slots of
+    state): the two attending layers through the Pallas GQA kernels at 20
+    query heads on ONE K/V head of 128 (a 128-lane cache row, 20 rows a
+    sequence: neither a multiple of 8 sublanes nor reached by another cell),
+    the 26 Mamba layers through ops/pallas/selective_state.py — the decode
+    update where the state lies and the chunk's scan, each inside the scope
+    its roofline metric reads — every leaf of the cache donated and written
+    in place, and weights + state + K/V inside the chip (~6.9 GB)."""
+    hf, cfg, model, params, cache, sds = _abstract_model(
+        "jamba2-3b.json", lambda spec: SingleDeviceSharding(topo.devices[0]))
+    serve = dict(hf["serve"])
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    weights = sum(nbytes(a) for a in jax.tree.leaves(params))
+    held = sum(nbytes(a) for a in jax.tree.leaves(cache))
+    assert hf["attention_layers"] == len(cfg.gqa_layers) == 2
+    assert [(r.kind, r.count) for r in model.runs] == [
+        ("linear", 7), ("gqa", 1), ("linear", 13), ("gqa", 1), ("linear", 6)]
+    assert cache["state"].shape == (26, 64, 16, 40, 128)
+    assert cache["kv"].shape == (2, 6272, 2, 32, 128)
+    assert model.state_update_impl() == ("pallas", "tpu")
+    assert model.state_scan_impl() == ("pallas", "tpu")
+    if program == "reference":
+        from cellbench import spec
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        ref = spec.load_module(root, "reference", hf["reference"])
+        compiled = jax.jit(ref.make_forward(hf)).lower(
+            params, sds((768,)), sds((8,))).compile()
+        mem = compiled.memory_analysis()
+        print(f"# reference: temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
+        assert mem.temp_size_in_bytes < 1.6e9, mem.temp_size_in_bytes
+        assert (weights + held + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes) < 0.6 * V5E_HBM
+        return
+    fn, args = _step_program(program, model, serve, sds, prefix_blocks=16)
+    if program == "prefill":        # the engine names the row's slot
+        from dynamo_tpu.engine.core import unified_step
+
+        fn = lambda p, c, *a: unified_step(
+            model, p, c, *a[:-1], prefix_blocks=16, seq_slots=a[-1])
+        args = (*args, sds((1,)))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    hlo = compiled.as_text()
+    assert ("paged_decode_attention" if program == "decode"
+            else "paged_prefill_attention") in hlo
+    kernel, scope = (("selective_state_update", "selective_step")
+                     if program == "decode"
+                     else ("selective_state_scan", "selective_scan"))
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and kernel in line]
+    assert len(calls) == 3                      # m x7 | m x13 | m x6
+    for line in calls:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+        assert scope in op_name and "selective" in op_name, op_name
+    other = ("selective_state_scan" if program == "decode"
+             else "selective_state_update")
+    assert other not in hlo
+    assert "ragged-dot" not in hlo and "grouped_expert_matmul" not in hlo
+    # the state is updated where it lies, never copied whole or a layer
+    assert not re.search(r"f32\[26,64,16,40,128\]\S* copy\(", hlo)
+    assert "f32[64,16,40,128]" not in hlo
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held                   # all donated
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"# {program}: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"total {total / 1e9:.3f} GB")
+    assert 6.8e9 < mem.argument_size_in_bytes < 7.0e9
+    assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+    assert 0.40 * V5E_HBM < total < 0.46 * V5E_HBM, total
