@@ -3,8 +3,13 @@
 Implements the runtime's AsyncEngine contract (generate(Context[BackendInput])
 → stream of LLMEngineOutput) so the engine slots directly into pipelines,
 the HTTP service, and distributed endpoints.  The engine core runs on its
-own thread (JAX dispatch blocks); tokens cross back via
-loop.call_soon_threadsafe into per-request asyncio queues.
+own thread (JAX dispatch blocks); outputs cross back into per-request
+asyncio queues in ONE loop.call_soon_threadsafe per event loop per
+dispatch: a request's ``emit`` only collects on the engine thread, and the
+core says where a batch is complete (``EngineCore.flush_outputs``: the end
+of a dispatch's host work, of a step, of ``fail_all``).  A wake-up a row
+is a system call and a hand-over of the interpreter lock a row, while the
+device's next program stands finished (docs/engine_scheduling.md).
 
 Cancellation: a stopped/killed Context aborts the request in the core at
 the next step boundary (reference: AsyncEngineContext::stop_generating
@@ -14,6 +19,7 @@ carried as ControlMessage::{Stop,Kill}, lib/runtime/src/engine.rs:76-84).
 from __future__ import annotations
 
 import asyncio
+import collections
 import concurrent.futures
 import gc
 import logging
@@ -31,6 +37,17 @@ from dynamo_tpu.runtime.engine import AsyncEngine, Context
 log = logging.getLogger("dynamo_tpu.engine")
 
 __all__ = ["AsyncLLMEngine"]
+
+
+# put into a stream's queue when its Context is stopped or killed
+_STOPPED = object()
+
+
+def _deliver(batch: list) -> None:
+    """On the event loop: a flush's outputs into their streams' queues, in
+    the order the engine emitted them."""
+    for put, out in batch:
+        put(out)
 
 
 def settle_heap() -> None:
@@ -55,6 +72,10 @@ class AsyncLLMEngine(AsyncEngine):
         # its program; the engine thread has then stopped for good.  The
         # serving entrypoint awaits it and exits non-zero (cli.py run).
         self.failed: concurrent.futures.Future = concurrent.futures.Future()
+        # emitted on the engine thread, not yet handed to the streams:
+        # event loop -> [(the stream's put_nowait, output)]
+        self._outbox: dict = collections.defaultdict(list)
+        core.flush_outputs = self._flush_outbox
 
     # --------------------------------------------------------------- lifecycle
     def start(self) -> "AsyncLLMEngine":
@@ -116,6 +137,25 @@ class AsyncLLMEngine(AsyncEngine):
                 on_compile_stage)
             gc.unfreeze()  # a stopped engine's programs may be collected
 
+    def _flush_outbox(self) -> None:
+        """Hand what the engine thread has emitted to the streams: one
+        wake-up per event loop, whatever the number of outputs.  Runs on
+        the thread that emitted (``EngineCore.flush_outputs``)."""
+        outbox = self._outbox
+        if not outbox:
+            return
+        counts = self.core.counts
+        for loop, batch in outbox.items():
+            counts.emit_hops_total += 1
+            counts.outputs_emitted_total += len(batch)
+            try:
+                loop.call_soon_threadsafe(_deliver, batch)
+            except RuntimeError:
+                # a closed loop costs its own streams, no other loop's
+                log.warning("event loop closed: %d outputs dropped",
+                            len(batch))
+        outbox.clear()
+
     async def run_on_engine(self, fn):
         """Run ``fn`` on the engine thread at a step boundary (cache/block
         bookkeeping must stay single-writer); await its result."""
@@ -160,10 +200,13 @@ class AsyncLLMEngine(AsyncEngine):
                 f"engine stopped after a failed build: {self.failed.result()!r}")
         inp = request.data
         loop = asyncio.get_running_loop()
-        out_q: asyncio.Queue[LLMEngineOutput] = asyncio.Queue()
+        out_q: asyncio.Queue = asyncio.Queue()  # LLMEngineOutput | _STOPPED
+
+        outbox, put = self._outbox, out_q.put_nowait
 
         def emit(out: LLMEngineOutput) -> None:
-            loop.call_soon_threadsafe(out_q.put_nowait, out)
+            # engine thread; leaves for the loop at the core's next flush
+            outbox[loop].append((put, out))
 
         # dtspan: one span per engine-side generation, parented on the
         # caller's context (HTTP root span or a TCP server hop) so the
@@ -189,39 +232,32 @@ class AsyncLLMEngine(AsyncEngine):
         # for the front end's pre-submit histogram, as queue_wait_s below
         request.annotations["submitted_at"] = req.submitted_at
 
+        # the one task a stream costs: a stopped or killed Context wakes the
+        # stream through its own queue, so a token is a plain queue get
         cancel_task = asyncio.ensure_future(request.stopped())
-        get_task: asyncio.Future | None = None
+
+        def on_stop(task: asyncio.Future) -> None:
+            if not task.cancelled():   # cancelled: the stream's own finally
+                put(_STOPPED)
+
+        cancel_task.add_done_callback(on_stop)
         try:
             while True:
-                get_task = asyncio.ensure_future(out_q.get())
-                done, _ = await asyncio.wait(
-                    [get_task, cancel_task], return_when=asyncio.FIRST_COMPLETED
-                )
-                if get_task in done:
-                    out = get_task.result()
-                    if (req.queue_wait_s is not None
-                            and "queue_wait_s" not in request.annotations):
-                        # surface admission wait for the HTTP histogram
-                        request.annotations["queue_wait_s"] = req.queue_wait_s
-                    yield out
-                    if out.finished:
-                        return
-                else:
-                    get_task.cancel()
+                out = await out_q.get()
+                if out is _STOPPED:
                     self.core.abort(req.request_id)
                     self._wake.set()
-                    # drain until the core confirms cancellation
-                    while True:
-                        out = await out_q.get()
-                        yield out
-                        if out.finished:
-                            return
+                    # drain on until the core confirms cancellation
+                    continue
+                if (req.queue_wait_s is not None
+                        and "queue_wait_s" not in request.annotations):
+                    # surface admission wait for the HTTP histogram
+                    request.annotations["queue_wait_s"] = req.queue_wait_s
+                yield out
+                if out.finished:
+                    return
         finally:
-            # a consumer abandoning the stream lands here from the
-            # `await asyncio.wait` — without the cancel, get_task stays
-            # pending on out_q.get() forever (dtsan task leak)
-            if get_task is not None and not get_task.done():
-                get_task.cancel()
+            # a consumer abandoning the stream lands here from the get
             cancel_task.cancel()
             if not request.is_stopped and req.finish_reason is None:
                 # consumer dropped the stream mid-generation

@@ -734,6 +734,11 @@ class EngineCore:
         # one clock read a finished dispatch, stamped on every output its
         # host work emits (LLMEngineOutput.emitted_at)
         self._emit_at = 0.0
+        # called, if set, where a batch of emits is complete (the end of a
+        # dispatch's host work, of a step, of fail_all): a front door whose
+        # ``emit`` only collects hands the batch over there in one hop
+        # (AsyncLLMEngine); a plain ``emit`` callable needs none
+        self.flush_outputs: Optional[Callable[[], None]] = None
         # cached _unified_penalties host buffers (invalidated on
         # admission/finish; incremental append between turns)
         self._pen_cache: Optional[dict] = None
@@ -1445,6 +1450,14 @@ class EngineCore:
         rec.finish(out)
         for req in rec.ended:
             self._release_slot(req)
+        # here and not only at the step's end: _settle may read two
+        # dispatches back in one step, and the first one's outputs must not
+        # wait for the second one's device_get
+        self._outputs_ready()
+
+    def _outputs_ready(self) -> None:
+        if self.flush_outputs is not None:
+            self.flush_outputs()
 
     def _drain_pipeline(self) -> None:
         """Finish the dispatch in flight, if any, before going on: what
@@ -1499,6 +1512,7 @@ class EngineCore:
                 self._finish(self.waiting.get_nowait(), FinishReason.ERROR)
             except queue.Empty:
                 break
+        self._outputs_ready()
 
     def _count_prefill(self, rows: int, tokens: int, budget: int = 0) -> None:
         """One prefill dispatch: ``rows`` sequences packed, ``tokens`` prompt
@@ -1574,6 +1588,9 @@ class EngineCore:
         try:
             return self._step_inner()
         finally:
+            # what an abort, a rejected admission or a failed step emitted
+            # outside any dispatch's host work
+            self._outputs_ready()
             step_timeline.end()
 
     def _step_inner(self) -> bool:

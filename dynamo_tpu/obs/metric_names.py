@@ -287,6 +287,15 @@ REQUEST_FAMILY = (
            "host->device buffers the dispatches' operands took, buffers put "
            "x devices put to: over prefill + decode dispatches, 1 x the "
            "devices (one packed buffer a dispatch)"),
+    _count("dynamo_tpu_engine_outputs_emitted_total", "counter",
+           "outputs the engine thread handed over to the streams' event "
+           "loops (AsyncLLMEngine; a core driven with a plain emit callable "
+           "counts none)"),
+    _count("dynamo_tpu_engine_emit_hops_total", "counter",
+           "wake-ups posted to an event loop for them, one a loop a flush "
+           "(the end of a dispatch's host work, of a step): outputs over "
+           "hops is cellbench's http.outputs_per_hop, rows a dispatch where "
+           "the hand-over is batched, 1 where every output woke the loop"),
     _count("dynamo_tpu_engine_prompt_tokens_admitted_total", "counter",
            "prompt tokens of requests whose prefill completed"),
     _count("dynamo_tpu_engine_prompt_tokens_cached_total", "counter",

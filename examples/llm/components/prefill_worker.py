@@ -46,6 +46,11 @@ class PrefillWorker:
                 await asyncio.wait_for(self._task, timeout=2)
             except (asyncio.TimeoutError, asyncio.CancelledError):
                 self._task.cancel()
+        # join the engine thread, as the colocated worker does: left running
+        # it goes on stepping into the process-wide step timeline
+        engine = getattr(self.worker, "engine", None)
+        if hasattr(engine, "shutdown"):
+            engine.shutdown()
 
     @dynamo_endpoint
     async def status(self, req: dict):

@@ -136,6 +136,8 @@ def seed_http_metrics():
     ec.ahead_discards_total = 1
     ec.pipeline_drains_total = 2
     ec.operand_buffers_total = 440
+    ec.outputs_emitted_total = 1200
+    ec.emit_hops_total = 40
     ec.prompt_tokens_admitted_total = 1000
     ec.prompt_tokens_cached_total = 768
     ec.prompt_blocks_admitted_total = 128
