@@ -42,7 +42,6 @@ def run_arm(model, params, cfg, spec_tokens: int, batch: int, steps: int,
     ecfg = EngineConfig(
         max_batch_size=batch, max_model_len=max_len, block_size=bs,
         num_blocks=batch * (max_len // bs) + 64,
-        decode_steps=8,  # short bursts: speculation replaces burst length
         prefill_chunk_tokens=512,
         spec_tokens=spec_tokens,
         enable_prefix_reuse=False,

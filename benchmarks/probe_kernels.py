@@ -7,7 +7,7 @@ interpret-mode tests cannot catch (round 4 found two: partial-tile scale
 DMA slices, and the prefill kernel's sublane-indexed q/out slices).
 
 Probe INPUTS come from ``ops/pallas/registry.py``'s ``probe_*_inputs``
-builders — the same tensors bench.py's pre-run probes and the kernel
+builders — the same tensors chip_smoke.py's kernel checks and the kernel
 plane's interpret audits consume — so a kernel this sweep exercises is
 by construction one the registry knows (``dynamo-tpu lint --kern``'s
 KN006 census flags any registered kernel that loses probe coverage).

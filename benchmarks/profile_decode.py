@@ -2,13 +2,13 @@
 
 Times the components of one decode step in isolation — weight streaming
 (the bf16/int8 matmul chain with attention stubbed), the paged-attention
-kernel, logits+sampling, and the full multi-step burst — so the gap
+kernel, logits+sampling, and the whole decode step — so the gap
 between measured ITL and the HBM roofline is attributable, not guessed.
 
 Run on the real chip:  python benchmarks/profile_decode.py [1b|8b]
 Env: DYNAMO_PROF_BATCH (64), DYNAMO_PROF_CTX (512), DYNAMO_PROF_QUANT
-(int8|none), DYNAMO_PROF_STEPS (burst length, 64), DYNAMO_PROF_PARTS
-(comma list of exact part names to run a subset).
+(int8|none), DYNAMO_PROF_PARTS (comma list of exact part names to run a
+subset).
 
 Prints a JSON line per component: {"part", "ms", "hbm_gb", "gbps"}.
 """
@@ -83,7 +83,6 @@ def main() -> None:
     batch = int(os.environ.get("DYNAMO_PROF_BATCH", "64" if on_accel else "8"))
     ctx = int(os.environ.get("DYNAMO_PROF_CTX", "512" if on_accel else "64"))
     quant = os.environ.get("DYNAMO_PROF_QUANT", "int8" if on_accel else "none")
-    k_steps = int(os.environ.get("DYNAMO_PROF_STEPS", "64" if on_accel else "4"))
     bs = 32 if on_accel else 16
     if not on_accel:
         name = "tiny"
@@ -115,7 +114,7 @@ def main() -> None:
     m = ctx // bs
     bt = (jnp.arange(batch)[:, None] * m + jnp.arange(m)[None, :]).astype(jnp.int32) % num_blocks
     seq_lens = jnp.full((batch,), ctx, jnp.int32)
-    limits = jnp.full((batch,), ctx + k_steps + 1, jnp.int32)
+    limits = jnp.full((batch,), ctx + 1, jnp.int32)
     rng = jax.random.PRNGKey(1)
     temp = jnp.zeros((batch,), jnp.float32)
     topk = jnp.zeros((batch,), jnp.int32)
@@ -136,23 +135,7 @@ def main() -> None:
         # inside "forward_no_attention")
         return not sel or name in sel
 
-    # 1. full multi-step burst (what the engine dispatches).  No donation
-    # here: the profiler reuses the same cache buffer across timed calls
-    # (the engine's real dispatch donates; in-place vs copy costs show up
-    # in single_step_dispatch below anyway)
-    if want("burst_total_per_step"):
-        burst = jax.jit(functools.partial(
-            multi_decode_step, model, num_steps=k_steps, block_size=bs,
-        ))
-        ms = timeit(
-            lambda: burst(params, cache, tokens, positions, bt, seq_lens,
-                          limits, rng, temp, topk, topp)[0],
-            iters=5, warmup=2,
-        )
-        emit("burst_total_per_step", ms / k_steps,
-             param_gb + kv_gb / 2)  # avg context grows over the burst
-
-    # 2. weights-only: forward with attention output zeroed via 0-len ctx
+    # 1. weights-only: forward with attention output zeroed via 0-len ctx
     if want("forward_no_attention"):
         zero_lens = jnp.zeros((batch,), jnp.int32)
         fwd = jax.jit(lambda p, c, t: model.forward(
@@ -161,7 +144,7 @@ def main() -> None:
         ms = timeit(lambda: fwd(params, cache, tokens))
         emit("forward_no_attention", ms, param_gb - v_ * h * wbytes / 1e9)
 
-    # 3. paged attention kernel alone (per layer x layers), at the tiling
+    # 2. paged attention kernel alone (per layer x layers), at the tiling
     # the serving path takes (registry.decode_tiling)
     if want("attention_all_layers"):
         q = jnp.ones((batch, cfg.num_heads, hd), cfg.jax_dtype)
@@ -170,7 +153,7 @@ def main() -> None:
         ms_layer = timeit(lambda: att(q, cache))
         emit("attention_all_layers", ms_layer * nl, kv_gb)
 
-    # 4. logits + sampling
+    # 3. logits + sampling
     if want("logits_sampling"):
         hidden = jnp.ones((batch, h), cfg.jax_dtype)
         lg = jax.jit(lambda p, hh: sample_full(
@@ -178,10 +161,11 @@ def main() -> None:
         ms = timeit(lambda: lg(params, hidden))
         emit("logits_sampling", ms, v_ * h * wbytes / 1e9)
 
-    # 5. dispatch overhead: same burst at K=1 vs K
+    # 4. the whole decode step (what the engine dispatches).  No donation
+    # here: the profiler reuses the same cache buffer across timed calls
     if want("single_step_dispatch"):
         one = jax.jit(functools.partial(
-            multi_decode_step, model, num_steps=1, block_size=bs,
+            multi_decode_step, model, block_size=bs,
         ))
         ms1 = timeit(
             lambda: one(params, cache, tokens, positions, bt, seq_lens,

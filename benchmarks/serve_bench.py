@@ -259,20 +259,9 @@ async def run_with_native(args):
                                "32" if on_accel else "4"))
     bs = 32 if on_accel else 16
     max_len = -(-(args.isl + args.osl + 64) // bs) * bs
-    if on_accel and not os.environ.get("DYNAMO_DISABLE_PALLAS"):
-        # same probe-or-degrade insurance as bench.py: a Mosaic lowering
-        # failure at this geometry must cost the kernel path, not the
-        # whole sweep (probes set the DISABLE env flags on failure)
-        import bench as _bench
-
-        mdl_cfg = MODELS[args.native]
-        if not _bench._probe_kv_quant(mdl_cfg, batch, max_len, bs, 512):
-            os.environ["DYNAMO_DISABLE_PALLAS_DECODE"] = "1"
-            os.environ["DYNAMO_DISABLE_PALLAS_PREFILL"] = "1"
     ecfg = EngineConfig(
         max_batch_size=batch, max_model_len=max_len, block_size=bs,
         num_blocks=batch * (max_len // bs) + 64,
-        decode_steps=8,
         prefill_chunk_tokens=512 if on_accel else 0,
         # token-budget ragged prefill: pack concurrent prompts' chunks
         # into one dispatch (the sweep's higher concurrency levels are

@@ -114,11 +114,10 @@ def _repo_root() -> Path:
 
 
 def _scan_files(root: Path) -> list[Path]:
-    """Default scan scope: the package, the benchmarks, bench.py, and
-    the tests — minus the analysis plane itself and its fixtures (the
+    """Default scan scope: the package, the benchmarks and the tests —
+    minus the analysis plane itself and its fixtures (the
     lint fixtures deliberately contain every violation)."""
     roots = [root / "dynamo_tpu", root / "benchmarks", root / "tests"]
-    bench = root / "bench.py"
     files: list[Path] = []
     for p in iter_python_files([r for r in roots if r.exists()]):
         rel = p.as_posix()
@@ -129,8 +128,6 @@ def _scan_files(root: Path) -> list[Path]:
         if p.name == "test_metcheck.py":
             continue
         files.append(p)
-    if bench.is_file():
-        files.append(bench)
     return files
 
 
@@ -712,7 +709,7 @@ def _producer_scope(path: str) -> bool:
     MT001 (tests/benchmarks must not mask dead telemetry)."""
     p = path
     return not (p.startswith("tests/") or p.startswith("benchmarks/")
-                or p == "bench.py" or "/tests/" in p)
+                or "/tests/" in p)
 
 
 # ------------------------------------------------------------- engine dict ----
@@ -1213,7 +1210,6 @@ _TOUCHES = (
     "dynamo_tpu/llm/http/metrics.py",
     "dynamo_tpu/components/metrics.py",
     "benchmarks/",
-    "bench.py",
     "dynamo_tpu/analysis/metcheck.py",
     "dynamo_tpu/analysis/metrics_manifest.json",
     "docs/observability.md",

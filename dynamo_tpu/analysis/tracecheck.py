@@ -21,7 +21,7 @@ families **without running any model math** (``jax.eval_shape`` /
 
 - **trace-signature census** — the declared matrix of shape/dtype/static
   signatures the scheduler can produce (prefill buckets × prefix-block
-  buckets, burst lengths, spec table slices, ragged token/row buckets).
+  buckets, spec table slices, ragged token/row buckets).
   The matrix is enumerated twice and hashed; an axis change, an
   unhashed static, or an undeclared signature shows up as drift.  The
   seeded runtime complement (tests/test_tracecheck.py) proves the hot
@@ -417,25 +417,24 @@ def _engine_entrypoints(tag: str, model_cfg, engine_cfg) -> list[Entrypoint]:
         upcast_min_elems=min_elems,
     ))
 
-    def build_multi(num_steps):
+    def build_multi():
         bufs, layout = _operands(
             _sds((b,), i32), _sds((b,), i32), _sds((b, m), i32),
             _sds((b,), i32), _sds((b,), i32), None,
             _sds((b,), f32), _sds((b,), i32), _sds((b,), f32))
         return Signature(
-            f"k={num_steps}", (params, cache, keys, bufs),
-            dict(layout=layout, num_steps=num_steps, k_cand=K_MAX,
-                 exact=False, use_penalties=False),
+            "decode", (params, cache, keys, bufs),
+            dict(layout=layout, k_cand=K_MAX, exact=False),
         )
 
-    bursts = sorted({cfg.interactive_decode_steps, max(1, cfg.decode_steps)})
+    # the one decode program: no axis, one signature
     eps.append(Entrypoint(
         name=f"engine.decode_multi[{tag}]",
-        axes={"num_steps": bursts},
+        axes={},
         build=build_multi,
         jit_fn=core._multi_fn, raw_fn=core._multi_fn.__wrapped__,
         donate_argnums=(1,),
-        representatives=[dict(num_steps=bursts[-1])],
+        representatives=[{}],
         upcast_min_elems=min_elems,
     ))
 
@@ -779,8 +778,7 @@ def build_registry() -> list[Entrypoint]:
     eps: list[Entrypoint] = []
     eps += _engine_entrypoints(
         "tiny-llama", tiny,
-        _tiny_engine_config(decode_steps=16, spec_tokens=2,
-                            prefill_token_budget=64,
+        _tiny_engine_config(spec_tokens=2, prefill_token_budget=64,
                             unified_token_dispatch=True),
     )
     eps += _engine_entrypoints(

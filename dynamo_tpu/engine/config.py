@@ -22,14 +22,10 @@ class EngineConfig:
     # batching
     max_batch_size: int = 8           # decode slots (static shape)
     max_model_len: int = 2048
-    # decode tokens generated per device dispatch (multi-step scheduling);
-    # >1 amortises dispatch overhead at the cost of stop-condition
-    # granularity (up to decode_steps-1 discarded samples per request)
-    decode_steps: int = 1
     # chunked prefill: max prompt tokens computed per prefill dispatch
     # (0 = whole remainder in one step).  Bounding the chunk keeps decode
     # ITL flat while long prompts prefill — the scheduler alternates one
-    # prefill chunk with one decode burst when both have work (the
+    # prefill chunk with one decode step when both have work (the
     # reference gets this from vLLM's chunked-prefill scheduler; ours is
     # native).  Rounded down to a block multiple so resumed chunks stay
     # block-aligned for the prefill fast path.
@@ -48,7 +44,7 @@ class EngineConfig:
     # each) lead the flat axis, waiting prefill chunks pack into the
     # remaining prefill_token_budget.  Replaces the chunked-prefill
     # alternation (one device round-trip per phase switch) with a single
-    # dispatch per turn; decode-only turns keep the multi-step burst and
+    # dispatch per turn; decode-only turns keep the decode step and
     # prefill-only turns the ragged batch.  Requires a model with the
     # ragged forward path; prefill_token_budget defaults on when unset.
     # Default off until parity-gated (tests/test_unified_dispatch.py
@@ -58,13 +54,6 @@ class EngineConfig:
     # the engine's one overlap of host and device and has no option.  The
     # name stays while cellbench/server.py passes it (ROADMAP D12).
     lookahead_dispatch: bool = False
-    # decode burst length while prefill work is pending (admitted/waiting
-    # requests or a mid-prefill slot): a long burst amortises the host
-    # round trip, and a freshly-arrived prompt waits a whole burst before
-    # its first chunk.  0 = min(8, decode_steps); never above decode_steps.
-    # Like decode_steps it has no CLI flag: `run` and `serve` decode one
-    # step a dispatch, under dispatch-ahead (ROADMAP D14).
-    interactive_decode_steps: int = 0
     # prompt-lookup speculative decoding (engine/spec.py): propose up to
     # spec_tokens continuation tokens by n-gram match against the sequence
     # itself and verify them in ONE dispatch.  Greedy-exact; engages only
@@ -133,11 +122,6 @@ class EngineConfig:
         if not self.prefill_buckets:
             self.prefill_buckets = default_buckets(self.max_model_len)
         self.prefill_buckets = sorted(self.prefill_buckets)
-        if self.interactive_decode_steps <= 0:
-            self.interactive_decode_steps = min(8, max(1, self.decode_steps))
-        self.interactive_decode_steps = min(
-            self.interactive_decode_steps, max(1, self.decode_steps)
-        )
         if self.prefill_chunk_tokens:
             # block-align the chunk so every resumed chunk starts on a block
             # boundary (required by the prefill fast path)

@@ -1,10 +1,9 @@
-"""Grammar-constrained decoding: JSON mode that runs INSIDE the decode scan.
+"""Grammar-constrained decoding: JSON mode whose mask runs INSIDE the decode step.
 
 OpenAI ``response_format={"type": "json_object"}`` guarantees the model
 emits syntactically valid JSON.  The reference delegates this to its
 engines' guided-decoding (vLLM/outlines run a host-side FSM between
-steps); that design needs a host round-trip per token, which would defeat
-this engine's multi-step decode scan (K tokens per device dispatch).
+steps and build the mask there, a [V] upload a row and token).
 
 TPU-native design — the automaton itself is device-computable:
 
@@ -20,9 +19,9 @@ TPU-native design — the automaton itself is device-computable:
   uploaded once on first use.
 * At each decode step the valid-token mask for a row is pure vectorised
   arithmetic: a table-row gather + bit compares against the row's
-  (state, depth, stack) — no host interaction, so JSON mode rides the
-  ``lax.scan`` decode burst at full speed.  After sampling, the row's
-  automaton state advances via scalar gathers in the same scan.
+  (state, depth, stack), three int32 a row from the host.  After the
+  readback the host advances the row's automaton by the sampled token
+  (``VocabTables.advance``: three table reads).
 * Tokens whose byte behaviour would depend on stack content *below* the
   levels they pop (e.g. ``},`` — the comma's meaning depends on the
   container we pop into) are conservatively masked; every JSON
@@ -302,8 +301,7 @@ class VocabTables:
 
     def advance(self, state: int, depth: int, stack: int, token: int
                 ) -> tuple[int, int, int]:
-        """Apply one sampled token to (state, depth, stack) — host mirror
-        of the in-scan update."""
+        """Apply one sampled token to (state, depth, stack)."""
         if token in self.eos_ids:
             return state, depth, stack
         ns = int(self.next_state[state, token])
@@ -1297,7 +1295,6 @@ class GrammarTables(NamedTuple):
     npops: object       # [S, V] int8
     popbits: object     # [S, V] int8
     npush: object       # [S, V] int8
-    pushbits: object    # [S, V] int8
     eos_ok: object      # [S] bool
     terminal_only: object  # [S] bool
     eos_cols: object    # [V] bool
@@ -1327,7 +1324,6 @@ def device_tables(tables: VocabTables, vocab_size: Optional[int] = None
         npops=jnp.asarray(fit(tables.npops)),
         popbits=jnp.asarray(fit(tables.popbits)),
         npush=jnp.asarray(fit(tables.npush)),
-        pushbits=jnp.asarray(fit(tables.pushbits)),
         eos_ok=jnp.asarray(tables.eos_ok),
         terminal_only=jnp.asarray(tables.terminal_only),
         eos_cols=jnp.asarray(eos_cols),
@@ -1338,8 +1334,8 @@ def grammar_mask(logits, gt: GrammarTables, jrows, state, depth, stack):
     """Mask invalid-next-token logits for grammar-constrained rows.
 
     logits [B, V] f32; jrows [B] bool (row uses the grammar); state/depth/
-    stack [B] int32.  Pure vectorised gathers + bit math — runs inside the
-    decode ``lax.scan`` with no host involvement.
+    stack [B] int32.  Pure vectorised gathers + bit math inside the
+    serving program.
     """
     import jax.numpy as jnp
 
@@ -1356,33 +1352,6 @@ def grammar_mask(logits, gt: GrammarTables, jrows, state, depth, stack):
     ok &= ~gt.terminal_only[state][:, None]
     ok = jnp.where(gt.eos_cols[None, :], gt.eos_ok[state][:, None], ok)
     return jnp.where(jrows[:, None] & ~ok, -1e30, logits)
-
-
-def grammar_advance(gt: GrammarTables, jrows, state, depth, stack, sampled):
-    """Advance each constrained row's (state, depth, stack) by its sampled
-    token (scalar gathers; mirrors VocabTables.advance)."""
-    import jax.numpy as jnp
-
-    ns = gt.next_state[state, sampled].astype(jnp.int32)
-    np_ = gt.npops[state, sampled].astype(jnp.int32)
-    nq = gt.npush[state, sampled].astype(jnp.int32)
-    qb = gt.pushbits[state, sampled].astype(jnp.int32)
-    d1 = jnp.clip(depth - np_, 0, MAX_DEPTH)
-    stack1 = (stack & ((1 << d1) - 1)) | (qb << d1)
-    depth1 = jnp.clip(d1 + nq, 0, MAX_DEPTH + MAX_TOKEN_OPS)
-    exposed = (stack1 >> jnp.maximum(depth1 - 1, 0)) & 1
-    resolved = jnp.where(
-        depth1 == 0,
-        AFTER_VALUE["T"],
-        jnp.where(exposed == SYM_OBJ, AFTER_VALUE["O"], AFTER_VALUE["A"]),
-    )
-    ns = jnp.where(ns == SENTINEL, resolved, ns)
-    upd = jrows & ~gt.eos_cols[sampled]
-    return (
-        jnp.where(upd, ns, state),
-        jnp.where(upd, depth1, depth),
-        jnp.where(upd, stack1, stack),
-    )
 
 
 class JsonGrammar:

@@ -2,7 +2,7 @@
 
 Every `pallas_call` site in ops/pallas/ is registered here together with
 the geometry matrix it is audited under (analysis/kerncheck.py, the
-dtkern plane) and probed under (benchmarks/probe_kernels.py, bench.py).
+dtkern plane) and probed under (benchmarks/probe_kernels.py, chip_smoke.py).
 The registry owns three things the kernels themselves must not:
 
 - **tile constants**: blocks-per-chunk / rows-per-chunk / matmul block
@@ -1898,7 +1898,7 @@ def fuzz_case(seed: int) -> dict:
 
 
 # ------------------------------------------------------ probe builders ----
-# bench.py and benchmarks/probe_kernels.py build their kernel probes
+# chip_smoke.py and benchmarks/probe_kernels.py build their kernel probes
 # from these, so probe coverage is registry coverage by construction.
 
 
@@ -1912,8 +1912,8 @@ def _probe_cache(rng, n, bs, hk, hd, dtype, quant):
 
 def probe_decode_inputs(batch, h, hk, hd, bs, n, bt_width, lens,
                         dtype=None, quant=False, s_q=0):
-    """Concrete decode-probe inputs at serving dims (bench.py's on-TPU
-    lowering probe and probe_kernels.py's sweep share this).  With
+    """Concrete decode-probe inputs at serving dims (chip_smoke.py's
+    kernel checks and probe_kernels.py's sweep share this).  With
     ``s_q > 0`` the multi-query shape is built instead: q gains a
     per-row query axis and a sixth element — the context lengths
     (``seq_lens - s_q``) the mq kernel takes — joins the tuple."""

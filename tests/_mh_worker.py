@@ -43,7 +43,7 @@ def main() -> None:
     if os.environ.get("DYN_MH_QUANT"):
         params = model.quantize_params(params)
     ecfg = EngineConfig(max_batch_size=2, max_model_len=64, block_size=16,
-                        num_blocks=16, decode_steps=2)
+                        num_blocks=16)
     engine = EngineCore(model, params, ecfg, mesh=mesh, eos_token_ids=[])
 
     toks: list[int] = []

@@ -26,8 +26,7 @@ def describe_batch(stats):
 
 
 def burst_decode(step_fn, state, rng_keys):
-    # the fused-burst idiom (engine/core.py multi_decode_step): k
-    # device turns accumulate under one scan, the host sees ONE
-    # trailing pull for the whole burst
+    # the fused-burst idiom: k device turns accumulate under one scan,
+    # the host sees ONE trailing pull for the whole burst
     state, samples = jax.lax.scan(step_fn, state, rng_keys)
     return state, jax.device_get(samples)

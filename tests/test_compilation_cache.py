@@ -75,7 +75,7 @@ def test_entrypoints_call_enable():
     """The wiring itself: every serving/bench entrypoint routes through
     enable_persistent_cache (source-level check — the call sites run
     on-accelerator paths a CPU test cannot reach end-to-end)."""
-    for rel in ("bench.py", "benchmarks/serve_bench.py",
-                "benchmarks/profile_decode.py", "dynamo_tpu/cli.py"):
+    for rel in ("benchmarks/serve_bench.py", "benchmarks/profile_decode.py",
+                "dynamo_tpu/cli.py"):
         text = (REPO / rel).read_text()
         assert "enable_persistent_cache" in text, rel

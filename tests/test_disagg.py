@@ -37,6 +37,17 @@ def run(coro):
     return asyncio.new_event_loop().run_until_complete(coro)
 
 
+async def until(cond, timeout_s=10.0):
+    """Wait for ``cond()`` to a deadline: what was published reaches its
+    watcher when the loop gets to it, which on a loaded machine is not
+    within any fixed sleep."""
+    loop = asyncio.get_running_loop()
+    deadline = loop.time() + timeout_s
+    while not cond():
+        assert loop.time() < deadline, "timed out waiting"
+        await asyncio.sleep(0.01)
+
+
 # ---------------------------------------------------------- transfer plane ----
 
 
@@ -106,8 +117,7 @@ def test_disagg_conf_hot_reload():
             assert r.conf.max_local_prefill_length == 512
             await r.publish(c, DisaggRouterConf(max_local_prefill_length=64,
                                                 max_prefill_queue_size=4))
-            await asyncio.sleep(0.1)
-            assert r.conf.max_local_prefill_length == 64
+            await until(lambda: r.conf.max_local_prefill_length == 64)
             assert r.conf.max_prefill_queue_size == 4
             await c.close()
         finally:
@@ -275,7 +285,8 @@ def test_disagg_e2e_matches_local(setup, force_tcp, cache_dtype,
             await worker.router.publish(
                 c_dec, DisaggRouterConf(max_local_prefill_length=1000)
             )
-            await asyncio.sleep(0.1)
+            await until(
+                lambda: worker.router.conf.max_local_prefill_length == 1000)
             prompt3 = rng.integers(1, 128, size=12).tolist()
             expected3 = await _drain(reference_engine, prompt3, 4)
             got3 = await _drain(worker, prompt3, 4)
@@ -293,7 +304,9 @@ def test_disagg_e2e_matches_local(setup, force_tcp, cache_dtype,
             reference_engine.shutdown()
             await srv.stop()
 
-    run(go())
+    # a timeout of its own: on a loaded machine this test has waited on its
+    # event loop for ever and held a whole run (alone it takes ~10 s)
+    run(asyncio.wait_for(go(), timeout=180))
 
 
 def test_disagg_sharded_decode_matches_local(setup, force_tcp):

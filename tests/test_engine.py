@@ -377,3 +377,97 @@ def test_logprobs_and_penalties_through_engine(setup):
         len(list(g)) for _, g in __import__("itertools").groupby(toks2)
     )
     assert max_run <= 2
+
+
+# ------------------------------------------- where a decode step's row ends
+# The three kinds of row the one decode step treats differently: a plain
+# row's token is carried on the device, so the next decode goes out before
+# the host has read it; a penalty buffer and a grammar state are the host's
+# to build from the token, so a batch with such a row is read back first.
+ROW_KINDS = {
+    "plain": SamplingOptions(temperature=0.0),
+    "penalties": SamplingOptions(temperature=0.0, frequency_penalty=0.5),
+    "json": SamplingOptions(temperature=0.0, json_mode=True),
+}
+EOS = 0
+
+
+@pytest.fixture(scope="module")
+def ascii_grammar():
+    """JSON mode over the toy's 128 tokens: token i is the byte i."""
+    from dynamo_tpu.engine.grammar import JsonGrammar
+
+    return JsonGrammar.from_token_bytes(
+        [None] + [bytes([i]) for i in range(1, 128)], eos_ids=[EOS])
+
+
+def serve_one(model, params, grammar, sampling, stops, seed, **cfg):
+    """One request through an engine of its own; returns (core, tokens,
+    the last output's finish reason)."""
+    cfg = EngineConfig(**{**dict(max_batch_size=1, max_model_len=128,
+                                 block_size=8, num_blocks=64,
+                                 prefill_buckets=[16]), **cfg})
+    core = EngineCore(model, params, cfg, eos_token_ids=[EOS],
+                      grammar=grammar)
+    outs = []
+    prompt = list(np.random.RandomState(seed).randint(1, 128, size=10))
+    core.submit(EngineRequest("x", prompt, sampling, stops, outs.append))
+    while core.step():
+        pass
+    assert all(s is None for s in core.slots)
+    return (core, [t for o in outs for t in o.token_ids],
+            outs[-1].finish_reason)
+
+
+@pytest.mark.parametrize("kind", sorted(ROW_KINDS))
+def test_a_stop_token_ends_the_row_at_the_token(setup, ascii_grammar, kind):
+    """A stop the host cannot foresee ends the stream AT its token.  A
+    plain row finds it one dispatch late (the next decode went out with
+    the token carried on the device) and throws that one sample away; the
+    other two kinds are read back before the next decode is built."""
+    _, model, params = setup
+    sampling = ROW_KINDS[kind]
+    _, ref, _ = serve_one(model, params, ascii_grammar, sampling,
+                          StopConditions(max_tokens=6, ignore_eos=True), 23)
+    # the first token past the first that the stream has not shown before
+    k = next(i for i in range(1, len(ref)) if ref[i] not in ref[:i])
+    core, toks, reason = serve_one(
+        model, params, ascii_grammar, sampling,
+        StopConditions(max_tokens=20, stop_token_ids=[ref[k]],
+                       ignore_eos=True), 23)
+    assert toks == ref[:k + 1]
+    assert reason == FinishReason.STOP
+    m = core.metrics()
+    carried = kind == "plain"
+    assert m["ahead_discards_total"] == (1 if carried else 0)
+    assert (m["ahead_dispatches_total"] > 0) == carried
+    assert core.block_manager.active_blocks == 0
+
+
+@pytest.mark.parametrize("kind", sorted(ROW_KINDS))
+def test_block_exhaustion_finishes_at_length(setup, ascii_grammar, kind):
+    """3 blocks of 8: at most 24 tokens of one sequence have K/V."""
+    _, model, params = setup
+    core, toks, reason = serve_one(
+        model, params, ascii_grammar, ROW_KINDS[kind],
+        StopConditions(max_tokens=100, ignore_eos=True), 24, num_blocks=3)
+    assert reason == FinishReason.LENGTH
+    # 24 block-resident tokens + the last sample (whose K/V is never needed)
+    assert len(toks) == 24 - 10 + 1
+    assert core.block_manager.free_blocks == 3  # everything released
+    assert core.metrics()["ahead_discards_total"] == 0
+
+
+@pytest.mark.parametrize("kind", sorted(ROW_KINDS))
+def test_decode_respects_max_model_len(setup, ascii_grammar, kind):
+    """The host foresees this end: the row is left out of the decode
+    behind the one that reaches the limit, and no sample is wasted."""
+    _, model, params = setup
+    core, toks, reason = serve_one(
+        model, params, ascii_grammar, ROW_KINDS[kind],
+        StopConditions(max_tokens=100, ignore_eos=True), 25,
+        max_model_len=16, num_blocks=8)
+    assert reason == FinishReason.LENGTH
+    assert len(toks) == 16 - 10  # total tokens capped at max_model_len
+    assert core.metrics()["ahead_discards_total"] == 0
+    assert core.decode_steps == len(toks) - 1

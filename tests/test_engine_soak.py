@@ -57,39 +57,37 @@ def _soak_model(family: str):
     return cfg, model, model.init_params(jax.random.PRNGKey(0))
 
 
-@pytest.mark.parametrize("seed,cache_dtype,draft,host,family,decode_steps", [
-    (0, None, False, False, "llama", 4), (7, None, False, False, "llama", 4),
-    (3, "int8", False, False, "llama", 4),
-    # one token a dispatch: the dispatch-ahead step (PR 29) under the same
-    # churn — a decode issued before the last one is read back, prefills
-    # in flight behind decodes, stops and aborts found one dispatch late,
-    # the tight pool and the host tier racing blocks held for a dispatch
-    # in flight
-    (31, None, False, False, "llama", 1),
-    (33, "int8", False, True, "llama", 1), (52, None, False, False, "llama", 1),
+# Every seed runs the dispatch-ahead step (PR 29): a decode issued before
+# the last one is read back, prefills in flight behind decodes, stops and
+# aborts found one dispatch late, the tight pool and the host tier racing
+# blocks held for a dispatch in flight.
+@pytest.mark.parametrize("seed,cache_dtype,draft,host,family", [
+    (0, None, False, False, "llama"), (7, None, False, False, "llama"),
+    (4, "int8", False, False, "llama"),
+    (31, None, False, False, "llama"),
+    (33, "int8", False, True, "llama"), (52, None, False, False, "llama"),
     # draft-model speculation churning against grammar rows, aborts,
     # chunked prefill and the tight block pool (draft pool even tighter)
-    (11, None, True, False, "llama", 4),
+    (11, None, True, False, "llama"),
     # host-offload tier ON: the tight device pool evicts constantly, so
     # the async kv-offload thread's reserve/write/publish races against
     # the engine thread's drain/restore the whole run — bf16 and int8
     # (seeds at which the pool does evict: "offload tier never engaged"
     # is this test's own premise, and about a third of seeds miss it
     # whatever order prefill is served in; 13 did once prefill went by
-    # admission and not by slot, PR 27)
-    (5, None, False, True, "llama", 4), (21, "int8", False, True, "llama", 4),
+    # admission and not by slot, PR 27; 19 did, and in 3 and 17 every JSON
+    # request met an abort, once every seed decoded a token a dispatch)
+    (5, None, False, True, "llama"), (21, "int8", False, True, "llama"),
     # MLA latent cache under the same churn, bf16 and int8+host-offload
-    (17, None, False, False, "mla", 4), (19, "int8", False, True, "mla", 4),
+    (18, None, False, False, "mla"), (23, "int8", False, True, "mla"),
 ])
-def test_engine_soak_invariants(seed, cache_dtype, draft, host, family,
-                                decode_steps):
+def test_engine_soak_invariants(seed, cache_dtype, draft, host, family):
     cfg, model, params = _soak_model(family)
     ecfg = EngineConfig(
         max_batch_size=4,
         max_model_len=192,
         block_size=BS,
         num_blocks=40,          # tight pool: forces eviction + NoFreeBlocks
-        decode_steps=decode_steps,
         prefill_chunk_tokens=32,
         enable_prefix_reuse=True,
         cache_dtype=cache_dtype,
@@ -203,9 +201,8 @@ def test_engine_soak_invariants(seed, cache_dtype, draft, host, family,
     # --- invariants -----------------------------------------------------
     assert submitted == n_requests
     m = engine.metrics()
-    # the soak ran the path it names: dispatch-ahead only at one token a dispatch
-    assert (m["ahead_dispatches_total"] > 0) is (
-        decode_steps == 1 and not draft)
+    # the soak ran the path it names: speculation keeps the serial step
+    assert (m["ahead_dispatches_total"] > 0) is (not draft)
     assert engine._inflight is None
     assert len(finished) == n_requests, (
         f"unfinished: {set(outs) - set(finished)}"

@@ -1,6 +1,6 @@
 """JSON mode through the real engine: grammar-masked sampling inside the
-multi-step decode scan and the prefill first-token path, with the host
-mirror advancing request state across bursts."""
+decode step and the prefill first-token path, with the host advancing
+request state between dispatches."""
 
 import json
 
@@ -64,19 +64,18 @@ def decode(toks, ids):
     return b"".join(toks[i] for i in ids if i != EOS and toks[i])
 
 
-@pytest.mark.parametrize("decode_steps", [1, 4])
 @pytest.mark.parametrize("temperature", [0.0, 1.0])
-def test_json_mode_emits_valid_json(setup, decode_steps, temperature):
+def test_json_mode_emits_valid_json(setup, temperature):
     model, params, grammar, toks = setup
     cfg = EngineConfig(
         max_batch_size=2, max_model_len=128, block_size=8, num_blocks=64,
-        prefill_buckets=[16, 32, 64, 128], decode_steps=decode_steps,
+        prefill_buckets=[16, 32, 64, 128],
     )
     core = EngineCore(model, params, cfg, eos_token_ids=[EOS],
                       grammar=grammar)
     for trial in range(3):
         ids, reason = run_one(core, toks, temperature=temperature,
-                              rid=f"j{decode_steps}-{temperature}-{trial}",
+                              rid=f"j{temperature}-{trial}",
                               prompt=[5 + trial, 6, 7, 8])
         text = decode(toks, ids).decode("utf-8", errors="replace")
         if reason is FinishReason.EOS:
@@ -97,11 +96,11 @@ def test_json_mode_emits_valid_json(setup, decode_steps, temperature):
 
 
 def test_json_mode_with_penalties_and_topk(setup):
-    """Grammar + penalties + top-k ride the same scan (both carries)."""
+    """Grammar + penalties + top-k in the same decode step."""
     model, params, grammar, toks = setup
     cfg = EngineConfig(
         max_batch_size=2, max_model_len=128, block_size=8, num_blocks=64,
-        prefill_buckets=[16, 32, 64, 128], decode_steps=4,
+        prefill_buckets=[16, 32, 64, 128],
     )
     core = EngineCore(model, params, cfg, eos_token_ids=[EOS], grammar=grammar)
     outs = []
@@ -127,11 +126,11 @@ def test_json_mode_with_penalties_and_topk(setup):
 
 def test_json_mode_mixed_batch(setup):
     """A json_mode request and a free-running request decode in the same
-    burst; only the constrained row is masked."""
+    dispatch; only the constrained row is masked."""
     model, params, grammar, toks = setup
     cfg = EngineConfig(
         max_batch_size=2, max_model_len=128, block_size=8, num_blocks=64,
-        prefill_buckets=[16, 32, 64, 128], decode_steps=4,
+        prefill_buckets=[16, 32, 64, 128],
     )
     core = EngineCore(model, params, cfg, eos_token_ids=[EOS], grammar=grammar)
     outs_j, outs_f = [], []
@@ -206,7 +205,7 @@ def test_guided_choice_emits_a_choice(setup):
     model, params, grammar, toks = setup
     cfg = EngineConfig(
         max_batch_size=2, max_model_len=128, block_size=8, num_blocks=64,
-        prefill_buckets=[16, 32, 64, 128], decode_steps=4,
+        prefill_buckets=[16, 32, 64, 128],
     )
     core = EngineCore(model, params, cfg, eos_token_ids=[EOS], grammar=grammar)
     choices = ["alpha", "beta", "true"]
@@ -234,7 +233,7 @@ def test_mixed_grammar_batch_json_and_choices(setup):
     model, params, grammar, toks = setup
     cfg = EngineConfig(
         max_batch_size=4, max_model_len=128, block_size=8, num_blocks=96,
-        prefill_buckets=[16, 32, 64, 128], decode_steps=4,
+        prefill_buckets=[16, 32, 64, 128],
     )
     core = EngineCore(model, params, cfg, eos_token_ids=[EOS], grammar=grammar)
     outs = {r: [] for r in ("json", "c1", "c2", "free")}
@@ -316,7 +315,7 @@ def test_guided_regex_through_engine(setup):
     model, params, grammar, toks = setup
     cfg = EngineConfig(
         max_batch_size=2, max_model_len=128, block_size=8, num_blocks=64,
-        prefill_buckets=[16, 32, 64, 128], decode_steps=4,
+        prefill_buckets=[16, 32, 64, 128],
     )
     core = EngineCore(model, params, cfg, eos_token_ids=[EOS], grammar=grammar)
     pattern = r"(up|down) [0-9][0-9]?%"
@@ -424,7 +423,7 @@ def test_json_mode_under_tp_mesh(setup):
     mesh = build_mesh((1, 2), MESH_AXES)
     cfg = EngineConfig(
         max_batch_size=2, max_model_len=128, block_size=8, num_blocks=64,
-        prefill_buckets=[16, 32, 64, 128], decode_steps=4,
+        prefill_buckets=[16, 32, 64, 128],
     )
     core = EngineCore(model, params, cfg, mesh=mesh, eos_token_ids=[EOS],
                       grammar=grammar)

@@ -60,13 +60,13 @@ def test_evicted_block_clears_committed_flag():
 
 
 # ----------------------------------------------------------- engine behavior
-def _engine(decode_steps=1, chunk=0):
+def _engine(chunk=0):
     cfg = ModelConfig.tiny()
     model = LlamaModel(cfg)
     params = model.init_params(jax.random.PRNGKey(0))
     ecfg = EngineConfig(
         max_batch_size=4, max_model_len=256, block_size=BS, num_blocks=64,
-        decode_steps=decode_steps, prefill_chunk_tokens=chunk,
+        prefill_chunk_tokens=chunk,
         enable_prefix_reuse=True,
     )
     return EngineCore(model, params, ecfg, eos_token_ids=[])

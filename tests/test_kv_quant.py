@@ -253,7 +253,7 @@ def test_engine_decode_with_int8_cache():
             model, params,
             EngineConfig(max_batch_size=2, max_model_len=64, block_size=8,
                          num_blocks=32, prefill_buckets=[16, 32, 64],
-                         decode_steps=4, cache_dtype=cache_dtype),
+                         cache_dtype=cache_dtype),
         )
         outs = []
         core.submit(EngineRequest(

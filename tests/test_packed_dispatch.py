@@ -58,14 +58,13 @@ RUNS = {
     "grammar-tp4": ("grammar", CHUNKED, (1, 4)),
     "penalties": ("penalties", CHUNKED, None),
     "penalties-tp4": ("penalties", CHUNKED, (1, 4)),
-    "burst": ("sampling", dict(decode_steps=4), None),
     "ragged": ("sampling", dict(**CHUNKED, prefill_token_budget=64), None),
     "unified": ("penalties", dict(**CHUNKED, prefill_token_budget=64,
                                   unified_token_dispatch=True), None),
     "spec": ("sampling", dict(spec_tokens=2), None),
     "seq-parallel": ("sampling", dict(sp_prefill_threshold=16), (2, 2)),
 }
-REACHES = {"burst": "_multi_fn", "ragged": "_ragged_fn",
+REACHES = {"sampling": "_multi_fn", "ragged": "_ragged_fn",
            "unified": "_unified_fn", "spec": "_spec_fn",
            "seq-parallel": "_sp_fn"}
 ENTRY_POINTS = ("_step_fn", "_multi_fn", "_spec_fn", "_ragged_fn",

@@ -130,7 +130,7 @@ def test_align_specs_and_sharded_engine_step():
     assert specs["layers"]["wo"].scale == P(None, None, None)  # reduced axis
 
     ecfg = EngineConfig(max_batch_size=2, max_model_len=64, block_size=BLOCK,
-                        num_blocks=16, decode_steps=2)
+                        num_blocks=16)
     engine = EngineCore(model, qparams, ecfg, mesh=mesh, eos_token_ids=[])
     done = []
     engine.submit(EngineRequest(

@@ -62,7 +62,7 @@ def test_logit_bias_promotes_and_demotes():
 
 def test_engine_logit_bias_and_min_p_e2e():
     """Greedy engine decode with a +100 bias emits the biased token every
-    step (through the multi-step scan's constant-bias closure)."""
+    step."""
     from dynamo_tpu.engine import EngineConfig, EngineCore
     from dynamo_tpu.engine.request import EngineRequest
     from dynamo_tpu.llm.protocols import SamplingOptions, StopConditions
@@ -79,8 +79,7 @@ def test_engine_logit_bias_and_min_p_e2e():
     core = EngineCore(
         model, params,
         EngineConfig(max_batch_size=2, max_model_len=64, block_size=8,
-                     num_blocks=32, prefill_buckets=[16, 32, 64],
-                     decode_steps=4),
+                     num_blocks=32, prefill_buckets=[16, 32, 64]),
     )
     outs = []
     core.submit(EngineRequest(
@@ -128,7 +127,7 @@ def test_parse_request_min_p_logit_bias():
 
 def test_seeded_sampling_is_deterministic_across_batches():
     """OpenAI `seed`: the same seeded request produces identical tokens
-    regardless of runs, batch composition, or burst boundaries; different
+    regardless of runs, batch composition, or dispatch boundaries; different
     seeds diverge."""
     from dynamo_tpu.engine import EngineConfig, EngineCore
     from dynamo_tpu.engine.request import EngineRequest
@@ -140,12 +139,11 @@ def test_seeded_sampling_is_deterministic_across_batches():
     model = LlamaModel(cfg)
     params = model.init_params(jax.random.PRNGKey(0))
 
-    def run(seed, decode_steps, companions, engine_seed, top_p=1.0):
+    def run(seed, companions, engine_seed, top_p=1.0):
         core = EngineCore(
             model, params,
             EngineConfig(max_batch_size=4, max_model_len=96, block_size=16,
-                         num_blocks=48, decode_steps=decode_steps,
-                         seed=engine_seed),
+                         num_blocks=48, seed=engine_seed),
         )
         outs = []
         core.submit(EngineRequest(
@@ -169,16 +167,14 @@ def test_seeded_sampling_is_deterministic_across_batches():
                 break
         return [t for o in outs for t in o.token_ids]
 
-    a = run(seed=1234, decode_steps=4, companions=0, engine_seed=0)
-    b = run(seed=1234, decode_steps=1, companions=2, engine_seed=99)
+    a = run(seed=1234, companions=0, engine_seed=0)
+    b = run(seed=1234, companions=2, engine_seed=99)
     assert len(a) == 14
     assert a == b  # same seed -> same stream, everything else varied
-    c = run(seed=4321, decode_steps=4, companions=0, engine_seed=0)
+    c = run(seed=4321, companions=0, engine_seed=0)
     assert c != a  # different seed diverges (overwhelmingly likely)
     # top_p < 1: the seeded pipeline normalizes over a FIXED candidate
     # window, so a k_cand-widening companion still cannot shift the stream
-    d = run(seed=1234, decode_steps=4, companions=0, engine_seed=0,
-            top_p=0.9)
-    e = run(seed=1234, decode_steps=1, companions=2, engine_seed=7,
-            top_p=0.9)
+    d = run(seed=1234, companions=0, engine_seed=0, top_p=0.9)
+    e = run(seed=1234, companions=2, engine_seed=7, top_p=0.9)
     assert d == e

@@ -45,60 +45,6 @@ def test_serve_bench_native_mode():
     assert lines[0]["ttft_p50_ms"] > 0
 
 
-def test_bench_py_cpu_smoke():
-    """The driver's scored artifact (`bench.py`) runs end-to-end on CPU
-    and emits a valid JSON line after EVERY phase — a bench regression
-    must fail the suite, not the round's measurement."""
-    import os
-
-    repo = Path(__file__).parent.parent
-    env = dict(
-        os.environ,
-        JAX_PLATFORMS="cpu",
-        PYTHONPATH=str(repo),
-        DYNAMO_BENCH_STEPS="2",
-        DYNAMO_BENCH_BATCH="2",
-        DYNAMO_BENCH_ISL="16",
-        DYNAMO_BENCH_TTFT_ISL="32",
-        DYNAMO_BENCH_MAX_LEN="256",
-        DYNAMO_BENCH_DECODE_STEPS="2",
-        DYNAMO_BENCH_MOE="1",
-    )
-    r = subprocess.run(
-        [sys.executable, str(repo / "bench.py")],
-        capture_output=True, text=True, timeout=420, env=env,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    lines = [l for l in r.stdout.strip().splitlines() if l.startswith("{")]
-    # incremental emission: the decode number is banked BEFORE the TTFT
-    # and MoE phases run, so a mid-run kill still scores (VERDICT r4
-    # missing #1) — the first line must already be a complete record
-    assert len(lines) >= 2, r.stdout
-    first = json.loads(lines[0])
-    assert first["metric"] == "decode_tok_s_per_chip"
-    assert first["value"] > 0
-    assert first["ttft_p50_ms"] is None  # banked before TTFT ran
-    # the driver parses the LAST line: the refined, full record
-    rec = json.loads(lines[-1])
-    assert rec["metric"] == "decode_tok_s_per_chip"
-    assert rec["value"] > 0
-    assert rec["platform"] == "cpu"
-    assert rec["ttft_p50_ms"] is None or rec["ttft_p50_ms"] > 0
-    # slot-starvation regression guard: an abort-triggered refill once
-    # FIFO-starved TTFT samples into waiting out a background's natural
-    # completion (max_tokens x ITL ~ tens of seconds even on tiny).
-    # A fresh 32-token prompt's first token on CPU tiny is tens of ms;
-    # the bound is ~100x slack for CI noise yet far below the pathology.
-    if rec["ttft_p50_ms"] is not None:
-        assert rec["ttft_p50_ms"] < 15_000, rec["ttft_p50_ms"]
-    assert "kernels" in rec and "prefill_tok_s" in rec
-    # MoE row: grouped-dispatch decode + grouped-vs-dense prefill A/B
-    moe = rec["moe"]
-    assert moe["decode_tok_s"] > 0
-    assert moe["num_experts"] > 0
-    assert moe["prefill_grouped_ms"] is None or moe["prefill_grouped_ms"] > 0
-
-
 def test_bench_router_smoke():
     """KV-routing A/B harness boots the real graph with 2 replicas and
     emits its comparison JSON (tiny workload; the ratio itself is
@@ -134,41 +80,3 @@ def test_bench_offload_smoke():
     assert by_mode["host_offload"]["host_blocks_stored"] > 0
     assert by_mode["host_offload"]["host_blocks_restored"] > 0
     assert by_mode["device_only"]["host_blocks_restored"] == 0
-
-
-def test_bench_emit_backfill_rules(monkeypatch):
-    """The scored artifact's merge logic in isolation: null fields
-    backfill from a carried partial of the SAME configuration; a
-    different configuration never inherits numbers."""
-    import importlib
-    import io
-    from contextlib import redirect_stdout
-
-    import bench
-
-    # _emit persists its line in DYNAMO_BENCH_PARTIAL; without the
-    # monkeypatch that would leak into the other tests' subprocess envs
-    monkeypatch.delenv("DYNAMO_BENCH_PARTIAL", raising=False)
-    monkeypatch.setenv("DYNAMO_BENCH_PARTIAL", "")
-    importlib.reload(bench)  # fresh _PARTIAL_BASE between tests
-    bench._PARTIAL_BASE.update({
-        "model": "8b", "quant": "int8", "kv_quant": "int8",
-        "value": 99.0, "ttft_p50_ms": 42.0, "moe": {"decode_tok_s": 5.0},
-    })
-
-    def emit(res):
-        buf = io.StringIO()
-        with redirect_stdout(buf):
-            bench._emit(dict(res))
-        return json.loads(buf.getvalue())
-
-    same = emit({"model": "8b", "quant": "int8", "kv_quant": "int8",
-                 "value": 120.0, "ttft_p50_ms": None})
-    assert same["value"] == 120.0          # fresh measurement wins
-    assert same["ttft_p50_ms"] == 42.0     # null backfills
-    assert same["moe"] == {"decode_tok_s": 5.0}
-
-    other = emit({"model": "1b", "quant": "none", "kv_quant": "none",
-                  "value": 50.0, "ttft_p50_ms": None})
-    assert other["ttft_p50_ms"] is None    # different config: no inherit
-    assert "moe" not in other

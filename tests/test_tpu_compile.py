@@ -207,7 +207,7 @@ def test_packed_decode_program_compiles(topo, tpu_gate, tp):
     def _multi_impl(params, cache, *a, **kw):
         with (jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
               if mesh else contextlib.nullcontext()):
-            return multi_decode_step(model, params, cache, *a, num_steps=1,
+            return multi_decode_step(model, params, cache, *a,
                                      block_size=BS, **kw)
 
     i32 = lambda *shape: np.zeros(shape, np.int32)
@@ -347,7 +347,7 @@ def test_qwen3_moe_reads_expert_weights_in_place(topo, tpu_gate, case):
     if case == "engine-decode":   # the nested scan the served path runs
         def fn(params, cache, *a):
             return multi_decode_step(model, params, cache, *a,
-                                     num_steps=1, block_size=BS)
+                                     block_size=BS)
         args = (sds((b,)), sds((b,)), sds((b, M)), sds((b,)), sds((b,)),
                 sds((2,), jnp.uint32), sds((b,), jnp.float32), sds((b,)),
                 sds((b,), jnp.float32))
@@ -458,7 +458,7 @@ def _step_program(program, model, serve, sds, mesh=None, prefix_blocks=1):
             with under_mesh():
                 return multi_decode_step(
                     model, params, cache, *a[:-2], carry_tokens=a[-2],
-                    carry_rows=a[-1], num_steps=1, block_size=bs)
+                    carry_rows=a[-1], block_size=bs)
     else:
         s = serve["prefill_chunk_tokens"]
         args = (sds((1, s)), sds((1, s)), sds((1, m)), sds((1,)), sds((1, s)),
@@ -1128,9 +1128,35 @@ def test_mistral4_cell_programs_keep_one_cache_and_name_their_kernels(
     assert 0.6 * V5E_HBM < total < 0.75 * V5E_HBM, total
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.fixture(scope="module", params=["decode", "prefill"])
+def solar_program(request, topo):
+    """(program, model, cache, compiled): solar-open2-ep16's decode program
+    or its 512-token chunk, whole, compiled for the described v5e once a
+    module.  The chunk alone is ~56 CPU-seconds of XLA on several threads:
+    paid here, in the set-up, it is not charged to the test that reads it
+    (tests/conftest.py budgets a test's call)."""
+    program = request.param
+    with pytest.MonkeyPatch.context() as patch:     # as ``tpu_gate``
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        hf, cfg, model, params, cache, sds = _abstract_model(
+            "solar-open2-ep16.json",
+            lambda spec: SingleDeviceSharding(topo.devices[0]))
+        assert hf["attention_layers"] == len(cfg.gqa_layers) == 2
+        fn, args = _step_program(program, model, dict(hf["serve"]), sds,
+                                 prefix_blocks=16)
+        if program == "prefill":        # the engine names the row's slot
+            from dynamo_tpu.engine.core import unified_step
+
+            fn = lambda p, c, *a: unified_step(
+                model, p, c, *a[:-1], prefix_blocks=16, seq_slots=a[-1])
+            args = (*args, sds((1,)))
+        compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+            params, cache, *args).compile()
+    return program, model, cache, compiled
+
+
 def test_hybrid_linear_cell_programs_write_the_state_in_place(
-        topo, tpu_gate, program):
+        solar_program, tpu_gate):
     """solar-open2-ep16's decode program (64 rows = the slot array) and a
     512-token chunk with 16 blocks of the prompt cached, whole (8 layers, 20
     experts, the cell's cache and its 64 slots of state): the GQA layers
@@ -1141,20 +1167,7 @@ def test_hybrid_linear_cell_programs_write_the_state_in_place(
     inside the chip (~11 GB).  The decode program updates the state by the
     kernel of ops/pallas/linear_state.py (compiled here for the v5e with no
     VMEM limit of its own: inside the compiler's scoped 16 MiB)."""
-    hf, cfg, model, params, cache, sds = _abstract_model(
-        "solar-open2-ep16.json",
-        lambda spec: SingleDeviceSharding(topo.devices[0]))
-    serve = dict(hf["serve"])
-    assert hf["attention_layers"] == len(cfg.gqa_layers) == 2
-    fn, args = _step_program(program, model, serve, sds, prefix_blocks=16)
-    if program == "prefill":        # the engine names the row's slot
-        from dynamo_tpu.engine.core import unified_step
-
-        fn = lambda p, c, *a: unified_step(
-            model, p, c, *a[:-1], prefix_blocks=16, seq_slots=a[-1])
-        args = (*args, sds((1,)))
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-        params, cache, *args).compile()
+    program, model, cache, compiled = solar_program
     hlo = compiled.as_text()
     assert ("paged_decode_attention" if program == "decode"
             else "paged_prefill_attention") in hlo

@@ -95,7 +95,7 @@ def test_the_cell_s_engine_config_is_pinned():
             ecfg.spec_tokens, ecfg.sp_prefill_threshold,
             ecfg.prefill_token_budget, ecfg.unified_token_dispatch,
             ecfg.lookahead_dispatch) == (0, None, None, 0, 0, 0, False, False)
-    assert ecfg.decode_steps == 1 and ecfg.enable_prefix_reuse
+    assert ecfg.enable_prefix_reuse
     # worst case of the traffic + the check's four prompts and the null block
     assert 64 * (2048 + 1024) // 32 + 128 == cfg["serve"]["num_blocks"]
 
