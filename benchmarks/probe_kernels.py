@@ -17,6 +17,7 @@ Usage:  python benchmarks/probe_kernels.py [bf16|int8|all] [8b|1b|probe]
         python benchmarks/probe_kernels.py experts [out.json [cell:call,...]]  # grouped matmul
         python benchmarks/probe_kernels.py state [out.json]     # the two recurrent states' decode steps
         python benchmarks/probe_kernels.py dense [out.json]     # dense latent decode by sharers a document
+        python benchmarks/probe_kernels.py question [out.json]  # a prefill chunk's two attention forms over a selection
 """
 
 from __future__ import annotations
@@ -541,8 +542,126 @@ def time_dense_decode(out_path: str | None) -> None:
             json.dump(table, f, indent=1)
 
 
+def time_question(out_path: str | None) -> None:
+    """The two forms of a GLM-5.2 prefill chunk's attention over a selection
+    (64 heads, rows of 576 in 640 lanes, 2,048 selected), each alone, µs a
+    call from a profile of 10 calls: ``mla_sparse_prefill_masked`` at a
+    question's shapes — the whole padded (256, 33,280) shape and what exists
+    of it, 160 tokens over 24,700 rows; the 64 bucket behind a 16 k prefix —
+    and at a document chunk's, with µs a live grid step and its share of the
+    chip's bf16 peak, then the same question at other tiles; the gather
+    (``mla_sparse_prefill``) at the same live queries with ns a fetched row;
+    and ``latent_cache.masked_attention`` whole (block gather, unpacking,
+    bias, kernel) with what it takes beside the kernel.  The numbers behind
+    ``registry.MLA_MASKED_TILE_NS`` / ``MLA_SPARSE_ROW_NS``."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.ops import latent_cache
+    from dynamo_tpu.ops.paged_attention import sparse_latent_attention
+    from dynamo_tpu.ops.pallas.mla_masked_prefill import (
+        mla_sparse_prefill_masked,
+    )
+    from dynamo_tpu.ops.pallas.registry import (
+        MLA_MASKED_KEYS_PER_TILE as TK, MLA_MASKED_TOKENS_PER_TILE as TQ,
+        mla_masked_cost, probe_mla_masked_inputs, probe_mla_sparse_inputs,
+    )
+
+    h, dq, dv, topk, calls = 64, 640, 512, 2048, 10
+    print(f"# device {jax.devices()[0].device_kind}")
+    table = []
+
+    def device_us(fn, args, prefix):
+        lines = profiled_device_ns(fn, args, calls)
+        took = [ns for name, ns in lines["XLA Ops"]
+                if name.startswith(prefix)]
+        assert len(took) == calls, (prefix, len(took))
+        whole = [ns for _, ns in lines["XLA Modules"]]
+        return float(np.median(took)) / 1e3, float(np.median(whole)) / 1e3
+
+    def emit(row):
+        table.append(row)
+        print(json.dumps(row), flush=True)
+
+    def masked(label, s, c, live, ctx, tq=TQ, tk=TK):
+        fn = jax.jit(functools.partial(
+            mla_sparse_prefill_masked, heads=h, dv=dv, sm_scale=1 / 16,
+            tokens_per_tile=tq, keys_per_tile=tk))
+        try:
+            us, _ = device_us(
+                fn, probe_mla_masked_inputs(s, c, h, dq, live, ctx),
+                "%mla_sparse_prefill_masked")
+        except Exception as e:      # a tile the chip's compiler refuses
+            emit({"form": "masked", "case": label, "tq": tq, "tk": tk,
+                  "error": str(e).splitlines()[0][:200]})
+            return
+        steps = -(-live // tq) * -(-ctx // tk)
+        cost = mla_masked_cost(s, c, h, dq, dv, tq, tk, live, ctx)
+        emit({"form": "masked", "case": label, "s": s, "c": c, "live": live,
+              "ctx": ctx, "tq": tq, "tk": tk, "us": round(us, 1),
+              "live_steps": steps, "us_a_live_step": round(us / steps, 3),
+              "us_a_live_token": round(us / live, 2),
+              "peak_pct": round(cost["flops"] / us / 197e6 * 100, 1)})
+
+    masked("question 256, whole padded shape", 256, 33280, 256, 33280)
+    masked("question 256, 160 tokens over 24,700", 256, 33280, 160, 24700)
+    masked("question 64, whole padded shape", 64, 16896, 64, 16896)
+    masked("question 64, 50 tokens over 12,000", 64, 16896, 50, 12000)
+    masked("chunk 2,048 at 34,816", 2048, 34816, 2048, 34816)
+    masked("chunk 2,048 at 18,432 of 34,816", 2048, 34816, 2048, 18432)
+    for tq, tk in ((16, 256), (8, 512), (16, 1024), (32, 512)):
+        masked("question 256, 160 tokens over 24,700", 256,
+               -(-33024 // tk) * tk, 160, 24700, tq, tk)
+
+    for n, live in ((256, 160), (64, 50)):
+        lens = np.where(np.arange(n) < live, topk, 0)
+        fn = jax.jit(functools.partial(
+            sparse_latent_attention, sm_scale=1 / 16, phase="prefill"))
+        us, whole = device_us(
+            fn, probe_mla_sparse_inputs(n, h, 576, topk, 1 << 16, lens),
+            "%mla_sparse_prefill")
+        emit({"form": "gather", "n": n, "live": live, "rows": live * topk,
+              "us": round(us, 1), "ns_a_row": round(us * 1e3 / live / topk, 2),
+              "us_a_live_token": round(us / live, 2),
+              "us_whole_call": round(whole, 1)})
+
+    # the masked form as the model calls it: the blocks gathered and
+    # unpacked, the mask turned into the bias, the kernel
+    bs, blocks = 32, 2048
+    rng = np.random.default_rng(65)
+    latent = jnp.asarray(
+        rng.integers(0, 1 << 30, (1, blocks, bs, 1, 384)) & 0x3FFF3FFF,
+        jnp.uint32)
+    for s, c, live, ctx in ((256, 33024, 160, 24700), (64, 16448, 50, 12000)):
+        bt = jnp.asarray(rng.permutation(blocks)[None, :c // bs], jnp.int32)
+        mask = np.tril(np.ones((s, c), bool), k=ctx - live)
+        mask &= rng.random((s, c)) < 0.1
+        mask[live:] = False
+        mask[:, ctx:] = False
+        q = jnp.asarray(rng.normal(size=(1, s, h, 576)) * 0.1, jnp.bfloat16)
+        fn = jax.jit(lambda *a: latent_cache.masked_attention(
+            *a[:5], 1 / 16, dv, *a[5:]))
+        args = (q, latent, jnp.int32(0), bt, jnp.asarray(mask)[None],
+                jnp.asarray([live], jnp.int32), jnp.asarray([ctx], jnp.int32))
+        us, whole = device_us(fn, args, "%mla_sparse_prefill_masked")
+        emit({"form": "masked_attention", "s": s, "c": c, "live": live,
+              "ctx": ctx, "us_kernel": round(us, 1),
+              "us_whole_call": round(whole, 1),
+              "us_beside_the_kernel": round(whole - us, 1)})
+    if out_path:
+        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump(table, f, indent=1)
+
+
 def main() -> None:
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
+    if which == "question":
+        time_question(sys.argv[2] if len(sys.argv) > 2 else None)
+        return
     if which == "dense":
         time_dense_decode(sys.argv[2] if len(sys.argv) > 2 else None)
         return
@@ -663,14 +782,20 @@ def main() -> None:
                     nq, 64, 576, 2048, 1 << 16,
                     np.linspace(1, 4096, nq).astype(np.int32)),
                 sm_scale=1 / 16, phase=phase)))
-    from dynamo_tpu.ops.pallas.mla_masked_prefill import mla_masked_prefill
+    from dynamo_tpu.ops.pallas.mla_masked_prefill import (
+        mla_sparse_prefill_masked,
+    )
     from dynamo_tpu.ops.pallas.registry import probe_mla_masked_inputs
 
-    variants.append((
-        "mla_masked/prefill",
-        lambda: mla_masked_prefill(
-            *probe_mla_masked_inputs(512, 4096, 64, 640), heads=64, dv=512,
-            sm_scale=1 / 16)))
+    # a chunk of 512 tokens over 4,096 rows, and a question: 160 of 256
+    # tokens over 24,700 of 33,280
+    for shape in ((512, 4096), (256, 33280, 160, 24700)):
+        variants.append((
+            f"mla_sparse_prefill_masked/{shape[0]}",
+            lambda shape=shape: mla_sparse_prefill_masked(
+                *probe_mla_masked_inputs(shape[0], shape[1], 64, 640,
+                                         *shape[2:]),
+                heads=64, dv=512, sm_scale=1 / 16)))
     variants.append((
         "latent_cache/write_rows",
         lambda: write_rows(*probe_latent_dma_inputs(1 << 16, 576, 2048))))

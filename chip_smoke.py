@@ -302,18 +302,23 @@ def _child_kernels(arg: dict) -> None:
 
     def masked(_quant):
         from dynamo_tpu.ops.pallas.mla_masked_prefill import (
-            mla_masked_prefill,
+            mla_sparse_prefill_masked,
         )
 
-        q, ctx, bias = reg.probe_mla_masked_inputs(32, 256, h, 128)
-        got = mla_masked_prefill(q, ctx, bias, heads=h, dv=128, sm_scale=0.2,
-                                 tokens_per_tile=8, keys_per_tile=128,
-                                 interpret=interpret)
+        # 27 of 32 tokens over 200 of 384 positions: a dead query tile, a
+        # dead key tile, both lengths inside a tile
+        q, ctx, bias, lens = reg.probe_mla_masked_inputs(
+            32, 384, h, 128, 27, 200)
+        got = mla_sparse_prefill_masked(
+            q, ctx, bias, lens, heads=h, dv=128, sm_scale=0.2,
+            tokens_per_tile=8, keys_per_tile=128, interpret=interpret)
         with jax.default_matmul_precision("highest"):
             sc = jnp.einsum("shd,cd->shc", q.astype(jnp.float32).reshape(
                 32, h, 128), ctx.astype(jnp.float32)) * 0.2 + bias[:, None, :]
             ref = jnp.einsum("shc,cd->shd", jax.nn.softmax(sc, axis=-1),
                              ctx.astype(jnp.float32))
+        # a dead token's mask is empty: the kernel gives it zeros
+        ref = jnp.where(jnp.arange(32)[:, None, None] < 27, ref, 0.0)
         return got, ref.reshape(32 * h, 128)
 
     def latent_dma(_quant):
@@ -380,7 +385,7 @@ def _child_kernels(arg: dict) -> None:
         "ragged_paged_prefill_attention": [("ragged", ragged)],
         "int8_matmul": [("int8_matmul", matmul)],
         "mla_sparse_attention": [("sparse_latent", sparse)],
-        "mla_masked_prefill": [("masked_latent", masked)],
+        "mla_sparse_prefill_masked": [("masked_latent", masked)],
         "latent_cache_dma": [("latent_write_rows", latent_dma)],
         "linear_state_update": [("state_step", state_step)],
         "ssm_state_update": [("ssm_step", ssm_step)],
@@ -395,7 +400,7 @@ def _child_kernels(arg: dict) -> None:
         for label, fn in cases[kernel]:
             for quant in ([False] if kernel in (
                     "int8_matmul", "mla_sparse_attention",
-                    "mla_masked_prefill", "latent_cache_dma",
+                    "mla_sparse_prefill_masked", "latent_cache_dma",
                     "linear_state_update", "ssm_state_update",
                     "selective_state_update", "selective_state_scan",
                     "grouped_expert_matmul")

@@ -677,6 +677,10 @@ class EngineCore:
         self.sp_prefills = 0             # seq-parallel long-prefill dispatches
         self.prompt_tokens_computed = 0
         self._index_topk = int(getattr(model.config, "index_topk", 0) or 0)
+        # (sequences, tokens, table blocks, block size, prefix blocks) ->
+        # whether a prefill chunk of that shape attends in masked form, where
+        # the model has two forms of attention over a selection
+        self._attends_masked = getattr(model, "attends_masked", None)
         # (block tables, lengths, block size) -> cached rows the model's
         # decode attention fetches a layer, where the model can say
         self._decode_rows_fetched = getattr(model, "decode_rows_fetched", None)
@@ -1914,6 +1918,9 @@ class EngineCore:
         # blocks alone, not the whole padded table
         pb = self._prefix_blocks(
             "prefill", req.computed_tokens // cfg.block_size, s)
+        # the form the model's forward traces for this shape (one rule)
+        masked = self._attends_masked is not None and self._attends_masked(
+            1, s, m, cfg.block_size, pb)
 
         k_cand, exact = self._sampling_mode([req])
         gram = None
@@ -1946,6 +1953,8 @@ class EngineCore:
             if req.state is not RequestState.PREFILL:
                 return  # cancelled while the chunk was in flight
             self.prompt_tokens_computed += take
+            if masked:
+                self.counts.prefill_masked_tokens_total += take
             req.computed_tokens = end
             self._commit_prefill_blocks(req)
             if final:  # else more chunks to go; the sample is discarded

@@ -20,13 +20,16 @@ Per layer (pre-norm residual blocks, docs/glm_dsa.md has the equations):
     over whole blocks on the TPU); a stack is of ``none`` layers only or of
     none of them, because the two read different cache layouts.
   * Attention over a selection takes one of two forms, chosen statically by
-    the number of query tokens in the call.  Up to ``SPARSE_MAX_QUERIES``
-    (decode steps, the short tail of a prompt after a prefix hit) each query
-    *gathers* its rows: ``sparse_latent_attention``, a Pallas kernel on the
-    TPU.  A longer prefill chunk scores every key of the context once for
-    all its queries and masks what was not selected
-    (``dense_masked_attention``): the same result, by the matrix unit,
-    where a gather would fetch 2,048 rows for each of 2,048 queries.
+    the call's shape (``attends_masked``).  In a decode step, and in a batch
+    of several sequences, each query *gathers* its rows:
+    ``sparse_latent_attention``, a Pallas kernel on the TPU.  A prefill
+    chunk of one sequence — a document's 2,048 tokens, a question's 64-256
+    after a prefix hit — scores every key of the context once for all its
+    queries and masks what was not selected
+    (``latent_cache.masked_attention``): the same result, by the matrix
+    unit, wherever a tile of 16 queries walking the context costs less than
+    their 16 x 2,048 one-row fetches (up to ~60 k positions on one v5e:
+    ``registry.masked_prefill_is_cheaper``).
   * **Experts**: the router scores all ``router_experts``; this chip
     computes the part of the sum its own ``n_routed_experts`` give
     (``grouped_expert_dispatch(held=...)``), plus the shared expert.  What
@@ -71,15 +74,16 @@ from dynamo_tpu.ops.paged_attention import (
     sparse_attention_impl,
     sparse_latent_attention,
 )
+from dynamo_tpu.ops.pallas.registry import (
+    MLA_MASKED_KEYS_PER_TILE,
+    masked_prefill_is_cheaper,
+)
 
 Params = Any
 
-__all__ = ["GlmDsaConfig", "GlmDsaModel", "SPARSE_MAX_QUERIES",
-           "ROUTER_BIAS_STD",
+__all__ = ["GlmDsaConfig", "GlmDsaModel", "ROUTER_BIAS_STD",
            "kth_largest", "index_scores", "select_mask", "selected_slots"]
 
-# query tokens in a call up to which each query gathers its own rows
-SPARSE_MAX_QUERIES = 256
 # queries scored at a time by the indexer ([tile, heads, context] in f32)
 INDEX_QUERY_TILE = 64
 # queries whose selection is compacted at a time ([tile, topk, context/128])
@@ -93,6 +97,15 @@ INDEX_NORM_EPS = 1e-6
 # stays as even as the trained bias is there to keep it
 ROUTER_BIAS_STD = 0.01
 NEG_INF = -jnp.inf
+
+
+def _context_blocks(s: int, table_blocks: int, block_size: int,
+                    prefix_blocks) -> int:
+    """Blocks of the table a call of ``s`` tokens a sequence reads: the
+    static ``prefix_blocks`` cached ones and its own; None: all."""
+    if prefix_blocks is None:
+        return table_blocks
+    return min(table_blocks, prefix_blocks + -(-s // block_size))
 
 
 @dataclass
@@ -634,12 +647,43 @@ class GlmDsaModel:
             impl, why = sparse_attention_impl("prefill")
             return {"decode": (impl, f"{why}; mla_dense_decode"),
                     "prefill": (impl, f"{why}; mla_dense_prefill")}
+        from dynamo_tpu.ops.pallas.mla_masked_prefill import KERNEL_NAME
+
         out = {p: sparse_attention_impl(p) for p in ("decode", "prefill")}
         impl, why = out["prefill"]
-        out["prefill_chunk"] = (impl, f"{why}; chunks over "
-                                f"{SPARSE_MAX_QUERIES} tokens score the whole "
-                                "context and mask the selection")
+        out["prefill"] = (impl, f"{why}; gathers in a batch of sequences")
+        out["prefill_chunk"] = (
+            impl, f"{why}; {KERNEL_NAME}: one sequence's chunk, a question "
+            "after a prefix hit too, scores the context once a tile of "
+            f"queries and masks the selection up to {self.masked_up_to():,} "
+            "positions, gathers over a longer context")
         return out
+
+    def attends_masked(self, b: int, s: int, table_blocks: int,
+                       block_size: int, prefix_blocks,
+                       probe: bool = False) -> bool:
+        """Which of its two forms the attention of a call over a selection
+        takes, from the call's static shape alone — ``forward`` traces by it
+        and the engine counts by it: a prefill chunk of one sequence masks
+        where walking its static context a tile of queries at a time costs
+        less than gathering each query's rows; a decode step, a batch of
+        sequences and a probe gather."""
+        cfg = self.config
+        if not cfg.indexed or b != 1 or s == 1 or probe:
+            return False
+        return masked_prefill_is_cheaper(
+            block_size * _context_blocks(
+                s, table_blocks, block_size, prefix_blocks), cfg.index_topk)
+
+    def masked_up_to(self) -> int:
+        """The longest static context, in whole key tiles, whose chunk still
+        attends masked (for the start-up line)."""
+        tiles = 0
+        while masked_prefill_is_cheaper(
+                (tiles + 1) * MLA_MASKED_KEYS_PER_TILE,
+                self.config.index_topk):
+            tiles += 1
+        return tiles * MLA_MASKED_KEYS_PER_TILE
 
     # ---------------------------------------------------------------- forward
     def _select(self, lp, fi, x, c_q, positions, cache, block_tables,
@@ -758,7 +802,8 @@ class GlmDsaModel:
             else:
                 out = latent_cache.masked_attention(
                     q_lat, cache["latent"], li,
-                    block_tables[:, :ctx_blocks], sel, self.sm_scale, dv=r)
+                    block_tables[:, :ctx_blocks], sel, self.sm_scale, dv=r,
+                    live=jnp.sum(slot_idx >= 0, axis=1), seq_lens=seq_lens)
         with jax.named_scope("attn_out"):
             o = jnp.einsum("bshr,rhv->bshv", out[..., :r].astype(h_in.dtype),
                            kv_b[..., nope:])
@@ -804,23 +849,21 @@ class GlmDsaModel:
                 seq_lens, slot_idx, prefix_blocks=None, probe=False):
         """(hidden [B,S,Dm], cache).  ``prefix_blocks`` (static) bounds the
         context a prefill chunk reads: that many cached blocks plus its
-        own; None reads the whole block table.  ``probe`` (static; gather
-        form only) also returns, for every ``full`` layer in order, what its
-        indexer selected: (positions [N, K], scores [N, K], nvalid [N]) —
-        for scripts/glm_longctx_check.py, not for serving."""
+        own; None reads the whole block table.  A chunk's padding tokens
+        (``slot_idx`` < 0) come after its live ones.  ``probe`` (static;
+        takes the gather form) also returns, for every ``full`` layer in
+        order, what its indexer selected: (positions [N, K], scores [N, K],
+        nvalid [N]) — for scripts/glm_longctx_check.py, not for serving."""
         cfg = self.config
         b, s = tokens.shape
         bs = cache["latent"].shape[2]
         m = block_tables.shape[1]
-        ctx_blocks = m if prefix_blocks is None else min(
-            m, prefix_blocks + -(-s // bs))
-        sparse = cfg.indexed and b * s <= SPARSE_MAX_QUERIES
+        ctx_blocks = _context_blocks(s, m, bs, prefix_blocks)
+        sparse = cfg.indexed and not self.attends_masked(
+            b, s, m, bs, prefix_blocks, probe)
         valid = slot_idx >= 0       # a padding token writes no row
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(cfg.jax_dtype)
-        if probe and not sparse:
-            raise ValueError(f"probe needs at most {SPARSE_MAX_QUERIES} "
-                             "query tokens (the gather form)")
         probes = []
         groups = None
         if not cfg.indexed:

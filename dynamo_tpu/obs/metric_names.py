@@ -319,6 +319,12 @@ REQUEST_FAMILY = (
            "kernel fetches a layer for those rows, what a group of rows that "
            "ask one document shares counted once (over context, cellbench's "
            "attn.fetched_pct)"),
+    _count("dynamo_tpu_engine_prefill_masked_tokens_total", "counter",
+           "latent attention with an indexer: prompt tokens computed whose "
+           "chunk attended in masked form, every key of the context scored "
+           "once a tile of queries (the model's attends_masked, the rule its "
+           "forward traced by; the others gathered their rows): over the "
+           "prompt tokens computed, cellbench's attn.prefill_masked_pct"),
     _count("dynamo_tpu_engine_moe_router_picks_total", "counter",
            "experts the router picked for the real tokens of every dispatch "
            "(top-k a token and expert layer), counted on the device in the "

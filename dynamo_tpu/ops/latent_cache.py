@@ -186,26 +186,43 @@ def sparse_attention_xla(q: jax.Array, latent: jax.Array, layer,
 
 def masked_attention(q: jax.Array, latent: jax.Array, layer,
                      block_tables: jax.Array, mask: jax.Array,
-                     sm_scale: float, dv: int) -> jax.Array:
+                     sm_scale: float, dv: int, live: jax.Array,
+                     seq_lens: jax.Array) -> jax.Array:
     """Attention of q [B, S, H, width] over the first C positions of each
     sequence's block table, restricted to ``mask`` [B, S, C]: f32
     [B, S, H, dv'] whose first ``dv`` elements are the weighted sum of the
-    rows' first ``dv``.  On the TPU, one sequence at a time, the kernel of
-    ops/pallas/mla_masked_prefill.py; else ``dense_masked_attention``."""
-    context = context_rows(latent, layer, block_tables)
+    rows' first ``dv``.  ``live`` [B] says how many of a sequence's S tokens
+    exist (the first ones) and ``seq_lens`` [B] how many of the C positions:
+    the mask holds nothing past either, and the kernel does no work there.
+    On the TPU, one sequence at a time, the kernel of
+    ops/pallas/mla_masked_prefill.py over a context padded to whole tiles of
+    keys (blocks named twice, masked); else ``dense_masked_attention``."""
+    from dynamo_tpu.ops.pallas.registry import (
+        MLA_MASKED_KEYS_PER_TILE,
+        MLA_MASKED_TOKENS_PER_TILE,
+    )
+
     b, s, h, width = q.shape
     c = mask.shape[-1]
+    bs = latent.shape[2]
     dq, dvp = -(-width // LANES) * LANES, -(-dv // LANES) * LANES
-    tk = max((d for d in range(LANES, 513, LANES) if c % d == 0), default=0)
-    if not (kernels_on() and b == 1 and tk and s % 8 == 0):
-        return dense_masked_attention(q, context, mask, sm_scale)
-    from dynamo_tpu.ops.pallas.mla_masked_prefill import mla_masked_prefill
-    from dynamo_tpu.ops.pallas.registry import MLA_MASKED_TOKENS_PER_TILE
+    # keys a grid step: the registry's tile, or all of a shorter context
+    tk = min(MLA_MASKED_KEYS_PER_TILE, -(-c // LANES) * LANES)
+    if not (kernels_on() and b == 1 and s % 8 == 0 and tk % bs == 0):
+        return dense_masked_attention(
+            q, context_rows(latent, layer, block_tables), mask, sm_scale)
+    from dynamo_tpu.ops.pallas.mla_masked_prefill import (
+        mla_sparse_prefill_masked,
+    )
 
+    pad = -c % tk
+    context = context_rows(
+        latent, layer, jnp.pad(block_tables, ((0, 0), (0, pad // bs))))
+    bias = jnp.where(jnp.pad(mask[0], ((0, 0), (0, pad))), 0.0, NEG_INF)
     tq = max(d for d in (MLA_MASKED_TOKENS_PER_TILE, 8) if s % d == 0)
-    out = mla_masked_prefill(
+    out = mla_sparse_prefill_masked(
         _pad_to(q[0], dq).reshape(s * h, dq), context[0, :, :dq],
-        jnp.where(mask[0], 0.0, NEG_INF).astype(jnp.float32),
+        bias.astype(jnp.float32), jnp.stack([live[0], seq_lens[0]]),
         heads=h, dv=dvp, sm_scale=sm_scale, tokens_per_tile=tq,
         keys_per_tile=tk)
     return out.reshape(1, s, h, dvp)

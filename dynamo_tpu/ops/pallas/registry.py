@@ -51,6 +51,8 @@ __all__ = [
     "MLA_SPARSE_LIST_ALIGN",
     "MLA_MASKED_TOKENS_PER_TILE",
     "MLA_MASKED_KEYS_PER_TILE",
+    "MLA_MASKED_TILE_NS",
+    "MLA_SPARSE_ROW_NS",
     "LINEAR_STATE_HEADS_PER_STEP",
     "linear_state_heads_per_step",
     "SSM_STATE_BLOCK_BYTES",
@@ -84,6 +86,7 @@ __all__ = [
     "int8_matmul_cost",
     "mla_sparse_cost",
     "mla_masked_cost",
+    "masked_prefill_is_cheaper",
     "latent_dma_cost",
     "linear_state_cost",
     "linear_state_reference",
@@ -140,6 +143,17 @@ MLA_SPARSE_LIST_ALIGN = 1024
 # keys a grid step (scores 2 MiB, accumulator 2 MiB in f32)
 MLA_MASKED_TOKENS_PER_TILE = 16
 MLA_MASKED_KEYS_PER_TILE = 512
+# what the two forms of a prefill chunk's attention over a selection cost on
+# one v5e chip, for ``masked_prefill_is_cheaper`` (benchmarks/probe_kernels.py
+# question, PR 65): a live grid step of the masked kernel at the tile above is
+# 6.95 us where every step is live (88% of the bf16 peak) and 7.28 us where
+# dead steps are skipped between them (160 tokens over 24,700 rows of a
+# (256, 33,280) shape: 3.57 ms); a row the gather fetches is 26.4 ns (one DMA
+# a row, bound by issuing them: 160 x 2,048 rows in 8.67 ms, 50 x 2,048 in
+# 2.70).  Beside its kernel the masked form copies and unpacks the context
+# and builds the bias, 0.58 ms at 33 k positions: ~8% of the kernel there
+MLA_MASKED_TILE_NS = 7300
+MLA_SPARSE_ROW_NS = 26
 # recurrent state update: heads of one slot a grid step.  16 matrices of
 # 128 x 128 float32 are 1 MiB a buffer, 4 MiB double buffered in and out,
 # and their 48 q | k | g vectors fit the one 128-row tile a step transposes
@@ -225,7 +239,7 @@ KERNELS = {
         "module": "dynamo_tpu.ops.pallas.latent_cache_dma",
         "placeholder": False,
     },
-    "mla_masked_prefill": {
+    "mla_sparse_prefill_masked": {
         "module": "dynamo_tpu.ops.pallas.mla_masked_prefill",
         "placeholder": False,
     },
@@ -580,13 +594,33 @@ def mla_sparse_cost(n: int, h: int, w: int, k: int) -> dict:
 
 
 def mla_masked_cost(s: int, c: int, h: int, dq: int, dv: int,
-                    tq: int = MLA_MASKED_TOKENS_PER_TILE) -> dict:
-    """Masked latent prefill: every (query row, key) pair is scored over
-    Dq and summed over Dv; the context is read once a query tile."""
-    pairs = s * h * c
+                    tq: int = MLA_MASKED_TOKENS_PER_TILE,
+                    tk: int = MLA_MASKED_KEYS_PER_TILE,
+                    live: int | None = None, ctx: int | None = None) -> dict:
+    """Masked latent prefill of S tokens over C positions of which ``live``
+    and ``ctx`` exist (None: all): in the live tiles every (query row, key)
+    pair is scored over Dq and summed over Dv; the live part of the context
+    and of the bias is read once a live query tile, every output row is
+    written."""
+    sl = tq * _cdiv(s if live is None else live, tq)
+    cl = tk * _cdiv(c if ctx is None else ctx, tk)
+    pairs = sl * h * cl
     return _cost_dict(
-        dma=_cdiv(s, tq) * c * dq * 2 + s * c * 4 + s * h * (dq * 2 + dv * 4),
+        dma=(sl // tq) * cl * dq * 2 + sl * cl * 4 + sl * h * dq * 2
+        + s * h * dv * 4,
         flops=2 * pairs * (dq + dv), trans=pairs)
+
+
+def masked_prefill_is_cheaper(context: int, topk: int) -> bool:
+    """Whether a prefill chunk of one sequence over a static context of
+    ``context`` positions, each query selecting at most ``topk`` of them,
+    attends faster masked than by gathering, a token: the masked kernel
+    walks ``context`` / tk key tiles for the tq tokens of a query tile, the
+    gather issues one DMA a selected row.  At 7.3 us a tile and 26 ns a row
+    the forms cross near 60 k positions for 2,048 selected."""
+    masked = (_cdiv(context, MLA_MASKED_KEYS_PER_TILE) * MLA_MASKED_TILE_NS
+              / MLA_MASKED_TOKENS_PER_TILE)
+    return masked < min(topk, context) * MLA_SPARSE_ROW_NS
 
 
 def linear_state_heads_per_step(heads: int) -> int | None:
@@ -1280,24 +1314,29 @@ def _mla_sparse_case() -> dict:
 
 
 def _mla_masked_case() -> dict:
-    """32 tokens of four heads over 256 keys in tiles of (8, 128): causal
-    masks with a few more holes, one query with nothing selected in its
-    first key tile, one with nothing at all.  The poisoned run makes the
-    keys no query selects huge: a masked key weighs exactly nothing, but a
-    cache row is an activation and never NaN, and 0 x NaN is what no
-    matrix unit can mask."""
+    """48 tokens of four heads over 384 keys in tiles of (8, 128), of which
+    29 tokens and 200 keys exist: two dead query tiles, a dead key tile, a
+    context and a chunk that end inside a tile; causal masks with a few more
+    holes, one query with nothing selected in its first key tile, one with
+    nothing at all.  The poisoned run makes the keys no query selects huge —
+    a masked key weighs exactly nothing, but a cache row is an activation
+    and never NaN, and 0 x NaN is what no matrix unit can mask — and the
+    dead key tile, which no step reads, NaN."""
     import jax.numpy as jnp
 
     np = _np()
-    s_, c, h, dq, dv = 32, 256, 4, 128, 128
+    s_, c, h, dq, dv = 48, 384, 4, 128, 128
+    live, ctx_len = 29, 200
 
     def build():
         rng = np.random.default_rng(700)
-        mask = np.tril(np.ones((s_, c), bool), k=c - s_)
+        mask = np.tril(np.ones((s_, c), bool), k=ctx_len - live)
         mask &= rng.random((s_, c)) < 0.6
         mask[5, :128] = False
         mask[9] = False
-        mask[:, 200:208] = False                 # keys nobody selects
+        mask[:, 180:188] = False                 # keys nobody selects
+        mask[live:] = False
+        mask[:, ctx_len:] = False
         return {"q": jnp.asarray(rng.normal(size=(s_ * h, dq)) * 0.3,
                                  jnp.bfloat16),
                 "ctx": rng.normal(size=(c, dq)).astype(np.float32),
@@ -1306,18 +1345,20 @@ def _mla_masked_case() -> dict:
     def _ctx(inp, poisoned):
         ctx = inp["ctx"].copy()
         if poisoned:
-            ctx[200:208] = 1e6
+            ctx[180:188] = 1e6
+            ctx[256:] = np.nan
         return jnp.asarray(ctx, jnp.bfloat16)
 
     def run(inp, poisoned: bool):
         from dynamo_tpu.ops.pallas.mla_masked_prefill import (
-            mla_masked_prefill,
+            mla_sparse_prefill_masked,
         )
 
-        return mla_masked_prefill.__wrapped__(
+        return mla_sparse_prefill_masked.__wrapped__(
             inp["q"], _ctx(inp, poisoned),
             jnp.where(jnp.asarray(inp["mask"]), 0.0, -1e30).astype(
                 jnp.float32),
+            jnp.asarray([live, ctx_len], jnp.int32),
             heads=h, dv=dv, sm_scale=0.2, tokens_per_tile=8,
             keys_per_tile=128, interpret=True)
 
@@ -1330,16 +1371,18 @@ def _mla_masked_case() -> dict:
             p = np.exp(sc - sc.max(axis=-1, keepdims=True))
             p = np.nan_to_num(p / p.sum(axis=-1, keepdims=True))
         ref = np.einsum("shc,cd->shd", p, ctx[:, :dv]).reshape(s_ * h, dv)
-        live = np.ones(ref.shape, bool)
+        alive = np.ones(ref.shape, bool)
         zero = np.zeros(ref.shape, bool)
         zero[9 * h:10 * h] = True
-        return ref.astype(np.float32), live, zero
+        zero[live * h:] = True
+        return ref.astype(np.float32), alive, zero
 
     def pricing():
-        return mla_masked_cost(s_, c, h, dq, dv, tq=8)
+        return mla_masked_cost(s_, c, h, dq, dv, tq=8, tk=128, live=live,
+                               ctx=ctx_len)
 
     return {
-        "name": "masked-latent", "kernel": "mla_masked_prefill",
+        "name": "masked-latent", "kernel": "mla_sparse_prefill_masked",
         "mode": "interpret", "atol": 2e-2,
         "build": build, "run": run, "oracle": oracle, "pricing": pricing,
     }
@@ -2030,17 +2073,23 @@ def probe_latent_dma_inputs(rows_total, width, t):
     return cache, rows, jnp.asarray(slots)
 
 
-def probe_mla_masked_inputs(s, c, h, dq):
-    """q [S·H, Dq], ctx [C, Dq], bias [S, C] (causal, every other key)."""
+def probe_mla_masked_inputs(s, c, h, dq, live=None, ctx=None):
+    """q [S·H, Dq], ctx [C, Dq], bias [S, C], lens [2]: the first ``live``
+    of the S tokens end a context of ``ctx`` of the C positions (None: all
+    of them); causal, every other key at random."""
     import jax.numpy as jnp
 
     np = _np()
     rng = np.random.default_rng(0)
-    mask = np.tril(np.ones((s, c), bool), k=c - s)
+    live, ctx = s if live is None else live, c if ctx is None else ctx
+    mask = np.tril(np.ones((s, c), bool), k=ctx - live)
     mask[:, 1::2] &= rng.random((s, c // 2)) < 0.5
+    mask[live:] = False
+    mask[:, ctx:] = False
     return (jnp.asarray(rng.normal(size=(s * h, dq)) * 0.1, jnp.bfloat16),
             jnp.asarray(rng.normal(size=(c, dq)), jnp.bfloat16),
-            jnp.where(jnp.asarray(mask), 0.0, -1e30).astype(jnp.float32))
+            jnp.where(jnp.asarray(mask), 0.0, -1e30).astype(jnp.float32),
+            jnp.asarray([live, ctx], jnp.int32))
 
 
 def probe_linear_state_inputs(layers, slots, heads, d):
@@ -2144,7 +2193,7 @@ _PROBE_BUILDERS = {
     "ssm_state_update": probe_ssm_state_inputs,
     "selective_state_update": probe_selective_step_inputs,
     "selective_state_scan": probe_selective_scan_inputs,
-    "mla_masked_prefill": probe_mla_masked_inputs,
+    "mla_sparse_prefill_masked": probe_mla_masked_inputs,
     "mla_sparse_attention": probe_mla_sparse_inputs,
     "latent_cache_dma": probe_latent_dma_inputs,
     "paged_decode_attention_mq": probe_decode_inputs,

@@ -18,10 +18,13 @@ with the cell's ``serve`` block (a smaller pool, to leave the float32
 reference room), and for each context length L:
 
   A  a document of L tokens + a 256-token question, 64 greedy tokens: chunked
-     prefill (the dense masked form), the question chunk and the decode steps
-     (the gather kernel);
+     prefill and the question chunk (the masked form: every chunk of one
+     sequence under ~60 k positions since PR 65), the decode steps (the
+     gather kernel);
   B  the same document + another question: a prefix hit of L tokens, the
-     question by the gather kernel over that past, the decode steps.
+     question by the masked kernel over that past with its true lengths (a
+     bucket of 256 tokens and a power-of-two prefix, of which the question
+     and L + 256 positions exist), the decode steps.
 
 (64 tokens a run and not 8: the configuration's ``check`` rule is a share,
 because where the router's near tie picks another expert in bf16 than in
@@ -300,7 +303,7 @@ def one_context(model, core, ref, config, length, seed, a) -> dict:
 
         def _attention(self, lp, li, fi, h_in, positions, cache,
                        block_tables, seq_lens, slot_idx, sel, ctx_blocks,
-                       sparse, full):
+                       sparse, full, groups=None):
             if not full:
                 slots, nvalid = sel[:2]
                 key = jax.random.fold_in(jax.random.PRNGKey(0), li)
@@ -314,7 +317,7 @@ def one_context(model, core, ref, config, length, seed, a) -> dict:
                 sel = (rnd, nvalid, *sel[2:])
             return super()._attention(
                 lp, li, fi, h_in, positions, cache, block_tables, seq_lens,
-                slot_idx, sel, ctx_blocks, sparse, full)
+                slot_idx, sel, ctx_blocks, sparse, full, groups)
 
     _, logp_rnd = probe(Unshared(model.config), core, seq, ans["blocks"], d_rows)
     ctl["unshared_random"] = {"logprobs": logprob_verdict(

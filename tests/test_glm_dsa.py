@@ -111,8 +111,10 @@ def _close(got, want, *, every=None, share=None):
 
 @pytest.fixture
 def dense_chunks(monkeypatch):
-    """Chunks over 8 tokens take the dense masked path (256 in serving)."""
-    monkeypatch.setattr(glm, "SPARSE_MAX_QUERIES", 8)
+    """A prefill chunk takes the masked form, as at serving's contexts (at
+    the toy's 16 selected of under 100 positions the rule says gather)."""
+    monkeypatch.setattr(glm, "masked_prefill_is_cheaper",
+                        lambda context, topk: True)
 
 
 # ------------------------------------------------ (a) program vs reference --
@@ -120,6 +122,7 @@ def dense_chunks(monkeypatch):
     [(0, 80)], [(0, 32), (32, 64), (64, 80)]], ids=["whole", "chunked"])
 def test_gather_prefill_matches_reference(chunks):
     model, params = _model(ONE_INDEX)
+    assert not model.attends_masked(1, 80, 11, BS, 0)
     toks = _tokens(80)
     got, _ = _prefill(model, params, model.init_kv_cache(NB, BS), toks,
                       _table(1, 80), chunks)

@@ -986,7 +986,10 @@ def test_indexer_lists_its_selection_without_an_element_gather(
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, cache, *args).compile()
     hlo = compiled.as_text()
-    assert f"mla_sparse_{'prefill' if chunk else 'decode'}" in hlo
+    # a question after a prefix hit attends masked, a decode step gathers
+    assert ("mla_sparse_prefill_masked" if chunk else "mla_sparse_decode") \
+        in hlo
+    assert ("mla_sparse_prefill" in hlo) == bool(chunk)
     gathered = set()
     for line in hlo.splitlines():
         m = _HLO_INSTR.match(line)
@@ -1001,16 +1004,25 @@ def test_indexer_lists_its_selection_without_an_element_gather(
         assert temp <= GLM_CHUNK_TEMP, temp
 
 
-def test_masked_latent_prefill_compiles_on_one_chip(glm_sds):
-    from dynamo_tpu.ops.pallas.mla_masked_prefill import mla_masked_prefill
+@pytest.mark.parametrize("s,c", [(2048, 34816), (256, 33280), (64, 16896)],
+                         ids=["chunk", "question-256", "question-64"])
+def test_masked_latent_prefill_compiles_on_one_chip(glm_sds, s, c):
+    """The masked form at a document chunk's and at two questions' shapes,
+    the context padded to whole 512-key tiles as ``masked_attention`` pads
+    it; the call carries the operation's name, which cellbench's
+    ``kernel.prefill_attn_roofline`` reads (``^mla_sparse_prefill``)."""
+    from dynamo_tpu.ops.pallas.mla_masked_prefill import (
+        mla_sparse_prefill_masked,
+    )
 
-    s, c, h = 2048, 34816, GLM["h"]
+    h = GLM["h"]
     compiled = jax.jit(functools.partial(
-        mla_masked_prefill, heads=h, dv=512, sm_scale=1 / 16)).lower(
+        mla_sparse_prefill_masked, heads=h, dv=512, sm_scale=1 / 16)).lower(
             glm_sds((s * h, 640), jnp.bfloat16),
             glm_sds((c, 640), jnp.bfloat16),
-            glm_sds((s, c), jnp.float32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+            glm_sds((s, c), jnp.float32), glm_sds((2,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mla_sparse_prefill_masked" in text
 
 
 def test_latent_cache_movers_compile_and_copy_no_cache(glm_sds, tpu_gate):
