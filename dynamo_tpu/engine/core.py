@@ -3434,8 +3434,11 @@ class EngineCore:
 
     def _pool(self):
         """The part of the cache that is held by block: all of it, but for
-        a model that keeps a state per slot beside its ``kv``."""
-        return self.cache["kv"] if self._recurrent else self.cache
+        a model that keeps a state per slot beside its ``kv`` (or the leaf
+        its ``pool_leaf`` names: a latent cache's ``latent``)."""
+        if not self._recurrent:
+            return self.cache
+        return self.cache[getattr(self.model, "pool_leaf", "kv")]
 
     def kv_bytes_per_block(self) -> int:
         """Host-staged wire bytes one KV block occupies (all layers, both

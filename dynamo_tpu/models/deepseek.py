@@ -141,13 +141,6 @@ class DeepseekConfig:
             raise NotImplementedError(
                 f"scoring_func {g('scoring_func')!r}"
             )
-        if method == "noaux_tc" and int(g("n_group", 1) or 1) != 1:
-            # noaux_tc with groups ranks a group by the sum of its two
-            # best biased scores: not computed here
-            raise NotImplementedError(
-                "topk_method 'noaux_tc' with n_group > 1 (group-limited "
-                "choice over biased scores)"
-            )
         if bool(g("attention_bias", False)):
             raise NotImplementedError(
                 "attention_bias=True (biases would be silently dropped)"
@@ -196,16 +189,31 @@ def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
     return out.reshape(b, s, h, d).astype(x.dtype)
 
 
+def routing_groups(cfg: dict, total: int) -> dict:
+    """``n_group`` / ``topk_group`` of the published keys ``cfg`` as the
+    fields of a configuration, checked against the router's ``total``
+    outputs."""
+    groups = int(cfg.get("n_group", 1) or 1)
+    kept = int(cfg.get("topk_group", 1) or 1)
+    if total % groups or not 1 <= kept <= groups:
+        raise ValueError(
+            f"{total} router outputs in n_group {groups}, topk_group {kept}")
+    return {"n_group": groups, "topk_group": kept}
+
+
 def moe_route(cfg, router: jax.Array, xf: jax.Array, bias=None):
     """The DeepSeek family's routing.  ``xf`` [T, Dm] -> (weights [T, k]
     f32, expert ids [T, k]).  Scores are a softmax or, per expert, a
     sigmoid of the router's logits, computed in f32 (inputs AND weights
     cast before the matmul, as HF does: near-tie logits must resolve to the
     same experts).  The k experts are those of largest score — within the
-    ``topk_group`` best groups for ``group_limited_greedy``; of largest
-    score + ``bias`` for ``noaux_tc`` (the correction bias steers the
-    choice only, the weight is the unbiased score).  ``norm_topk_prob``
-    divides the chosen weights by their sum; all are scaled by
+    ``topk_group`` best groups for ``group_limited_greedy`` (V2's: a group's
+    score is its largest); of largest score + ``bias`` for ``noaux_tc`` (the
+    correction bias steers the choice only, the weight is the unbiased
+    score), and with ``n_group`` > 1 within the ``topk_group`` groups whose
+    two largest score + bias sum highest, the others' set to 0 (V3's
+    ``get_topk_indices``, letter for letter).  ``norm_topk_prob`` divides
+    the chosen weights by their sum; all are scaled by
     ``routed_scaling_factor``."""
     t = xf.shape[0]
     logits = xf.astype(jnp.float32) @ router.astype(jnp.float32)  # [T,E]
@@ -223,8 +231,18 @@ def moe_route(cfg, router: jax.Array, xf: jax.Array, bias=None):
         ].set(1.0)
         choice = scores = scores * jnp.repeat(
             gmask, e // cfg.n_group, axis=-1)
-    elif cfg.topk_method == "noaux_tc" and bias is not None:
-        choice = scores + bias.astype(jnp.float32)
+    elif cfg.topk_method == "noaux_tc":
+        if bias is not None:
+            choice = scores + bias.astype(jnp.float32)
+        groups = cfg.n_group
+        if groups > 1:
+            per_group = choice.reshape(t, groups, -1)
+            best_two, _ = jax.lax.top_k(per_group, 2)
+            _, gidx = jax.lax.top_k(best_two.sum(axis=-1), cfg.topk_group)
+            kept = jnp.zeros((t, groups), bool).at[
+                jnp.arange(t)[:, None], gidx].set(True)
+            choice = jnp.where(kept[:, :, None], per_group, 0.0).reshape(
+                choice.shape)
     _, topi = jax.lax.top_k(choice, cfg.num_experts_per_tok)  # [T,k]
     weights = jnp.take_along_axis(scores, topi, axis=-1)
     if cfg.norm_topk_prob:

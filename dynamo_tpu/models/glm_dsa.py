@@ -57,7 +57,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.models.deepseek import apply_rope_interleaved, moe_route
+from dynamo_tpu.models.deepseek import (
+    apply_rope_interleaved,
+    moe_route,
+    routing_groups,
+)
 from dynamo_tpu.models.llama import (
     EXPERT_COUNT_KEYS,
     EXPERT_COUNTS,
@@ -82,7 +86,9 @@ from dynamo_tpu.ops.pallas.registry import (
 Params = Any
 
 __all__ = ["GlmDsaConfig", "GlmDsaModel", "ROUTER_BIAS_STD",
-           "kth_largest", "index_scores", "select_mask", "selected_slots"]
+           "kth_largest", "index_scores", "select_mask", "selected_slots",
+           "latent_params", "latent_projections", "latent_values",
+           "dense_attention_impls"]
 
 # queries scored at a time by the indexer ([tile, heads, context] in f32)
 INDEX_QUERY_TILE = 64
@@ -118,7 +124,7 @@ class GlmDsaConfig:
     qk_rope_head_dim: int
     v_head_dim: int
     kv_lora_rank: int
-    q_lora_rank: int
+    q_lora_rank: int | None        # None: W_q direct, no bottleneck, no norm
     intermediate_size: int
     moe_intermediate_size: int
     n_routed_experts: int          # experts held HERE
@@ -135,6 +141,8 @@ class GlmDsaConfig:
     mlp_layer_types: tuple         # per layer: "dense" | "sparse"
     scoring_func: str = "sigmoid"
     topk_method: str = "noaux_tc"
+    n_group: int = 1               # routing groups (``noaux_tc``: V3's)
+    topk_group: int = 1
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     # YaRN: {"factor", "beta_fast", "beta_slow", "mscale_all_dim",
@@ -213,8 +221,15 @@ class GlmDsaConfig:
             raise NotImplementedError(f"scoring_func {scoring!r}")
         if method not in ("noaux_tc", "greedy"):
             raise NotImplementedError(f"topk_method {method!r}")
-        if int(g("n_group", 1) or 1) != 1 or int(g("topk_group", 1) or 1) != 1:
-            raise NotImplementedError("group-limited expert choice")
+        if int(g("n_group", 1) or 1) > 1 and method != "noaux_tc":
+            raise NotImplementedError(
+                f"group-limited expert choice under topk_method {method!r} "
+                "(noaux_tc's is computed)")
+        q_lora = g("q_lora_rank")
+        if q_lora is None and "full" in types:
+            raise NotImplementedError(
+                "q_lora_rank null with an indexer (its queries are made "
+                "from the bottleneck's output)")
         if bool(g("attention_bias", False)):
             raise NotImplementedError("attention_bias=True")
         if bool(g("mlp_bias", False)):
@@ -249,7 +264,7 @@ class GlmDsaConfig:
             qk_rope_head_dim=int(g("qk_rope_head_dim")),
             v_head_dim=int(g("v_head_dim")),
             kv_lora_rank=int(g("kv_lora_rank")),
-            q_lora_rank=int(g("q_lora_rank")),
+            q_lora_rank=None if q_lora is None else int(q_lora),
             intermediate_size=int(g("intermediate_size")),
             moe_intermediate_size=int(g("moe_intermediate_size")),
             n_routed_experts=held, router_experts=total, expert_first=first,
@@ -262,6 +277,7 @@ class GlmDsaConfig:
             index_topk=int(g("index_topk", 0)),
             indexer_types=types, mlp_layer_types=mlps,
             scoring_func=scoring, topk_method=method,
+            **routing_groups(cfg, total),
             rms_norm_eps=float(g("rms_norm_eps", 1e-5)),
             rope_theta=float(rope.get("rope_theta", g("rope_theta", 10000.0))),
             yarn=yarn, query_scale_beta=beta,
@@ -466,6 +482,70 @@ def _rope_head(x, positions, inv_freq, rope_dim):
          x[..., rope_dim:]], axis=-1)
 
 
+def latent_params(cfg, n: int, dense, dt) -> dict:
+    """The projections of ``n`` stacked latent-attention layers but W_o,
+    drawn in this order (``dense(shape, fan_in)`` draws one): the query's —
+    through a bottleneck of ``q_lora_rank`` with its norm, or W_q direct
+    where that is None — then kv_a, its norm, kv_b.  ``cfg`` is any
+    configuration with the latent keys (GlmDsaConfig, HybridLinearConfig)."""
+    dm, h = cfg.hidden_size, cfg.num_heads
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    r, ql = cfg.kv_lora_rank, cfg.q_lora_rank
+    if ql is None:
+        p = {"wq": dense((n, dm, h * (nope + rope)), dm)}
+    else:
+        p = {"q_a": dense((n, dm, ql), dm),
+             "q_a_norm": jnp.ones((n, ql), dt),
+             "q_b": dense((n, ql, h * (nope + rope)), ql)}
+    p.update(kv_a=dense((n, dm, r + rope), dm),
+             kv_a_norm=jnp.ones((n, r), dt),
+             kv_b=dense((n, r, h * (nope + vd)), r))
+    return p
+
+
+def latent_projections(cfg, lp, x, positions, inv_freq):
+    """Latent attention's projections of the normed input ``x`` [B, S, Dm]
+    in absorbed form: (the H queries in latent space ‖ their roped part
+    [B, S, H, r + rope], the row to cache ĉ ‖ rope(k_pe) [B, S, r + rope],
+    kv_b as [r, H, nope + v], the query bottleneck's normed output or None).
+    q_nope[h]·(Wk[h]ᵀ c) = (Wk[h] q_nope[h])·c: the queries go through
+    kv_b's K half once, and no key is ever expanded."""
+    nh, nope, vd, r = (cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim,
+                       cfg.kv_lora_rank)
+    if "wq" in lp:
+        c_q, q = None, split_heads(x @ lp["wq"], nh)
+    else:
+        c_q = rms_norm(x @ lp["q_a"], lp["q_a_norm"], cfg.rms_norm_eps)
+        q = split_heads(c_q @ lp["q_b"], nh)
+    q_pe = apply_rope_interleaved(q[..., nope:], positions, inv_freq)
+    ckv = x @ lp["kv_a"]
+    c_hat = rms_norm(ckv[..., :r], lp["kv_a_norm"], cfg.rms_norm_eps)
+    k_pe = apply_rope_interleaved(
+        ckv[:, :, None, r:], positions, inv_freq)[:, :, 0]
+    kv_b = lp["kv_b"].reshape(r, nh, nope + vd)
+    q_lat = jnp.concatenate(
+        [jnp.einsum("bshn,rhn->bshr", q[..., :nope], kv_b[..., :nope]),
+         q_pe], axis=-1)
+    return q_lat, jnp.concatenate([c_hat, k_pe], axis=-1), kv_b, c_q
+
+
+def latent_values(cfg, out, kv_b, dtype):
+    """The attended latent ``out`` [B, S, H, >= r] back through kv_b's V
+    half: [B, S, H, v]."""
+    return jnp.einsum(
+        "bshr,rhv->bshv", out[..., :cfg.kv_lora_rank].astype(dtype),
+        kv_b[..., cfg.qk_nope_head_dim:])
+
+
+def dense_attention_impls() -> dict[str, tuple[str, str]]:
+    """phase -> ("pallas" | "xla", why) for the engine's start-up line,
+    where every cached row is attended: the dense kernels follow one rule
+    (``latent_cache.kernels_on``)."""
+    impl, why = sparse_attention_impl("prefill")
+    return {"decode": (impl, f"{why}; mla_dense_decode"),
+            "prefill": (impl, f"{why}; mla_dense_prefill")}
+
+
 @dataclass(frozen=True)
 class _Run:
     """Consecutive layers of one kind: a scan."""
@@ -538,8 +618,7 @@ class GlmDsaModel:
         cfg = self.config
         dt = cfg.jax_dtype
         dm, h = cfg.hidden_size, cfg.num_heads
-        qk, rope, vd = cfg.qk_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        r, ql = cfg.kv_lora_rank, cfg.q_lora_rank
+        vd, ql = cfg.v_head_dim, cfg.q_lora_rank
         hi, di = cfg.index_n_heads, cfg.index_head_dim
         keys = iter(jax.random.split(rng, 128))
 
@@ -552,12 +631,7 @@ class GlmDsaModel:
             p = {
                 "attn_norm": jnp.ones((n, dm), dt),
                 "mlp_norm": jnp.ones((n, dm), dt),
-                "q_a": dense((n, dm, ql), dm),
-                "q_a_norm": jnp.ones((n, ql), dt),
-                "q_b": dense((n, ql, h * qk), ql),
-                "kv_a": dense((n, dm, r + rope), dm),
-                "kv_a_norm": jnp.ones((n, r), dt),
-                "kv_b": dense((n, r, h * (cfg.qk_nope_head_dim + vd)), r),
+                **latent_params(cfg, n, dense, dt),
                 "wo": dense((n, h * vd, dm), h * vd),
             }
             if idx == "full":
@@ -643,10 +717,7 @@ class GlmDsaModel:
     def attention_impls(self) -> dict[str, tuple[str, str]]:
         """phase -> ("pallas" | "xla", why) for the engine's start-up line."""
         if not self.config.indexed:
-            # the dense kernels follow one rule (latent_cache.kernels_on)
-            impl, why = sparse_attention_impl("prefill")
-            return {"decode": (impl, f"{why}; mla_dense_decode"),
-                    "prefill": (impl, f"{why}; mla_dense_prefill")}
+            return dense_attention_impls()
         from dynamo_tpu.ops.pallas.mla_masked_prefill import KERNEL_NAME
 
         out = {p: sparse_attention_impl(p) for p in ("decode", "prefill")}
@@ -752,24 +823,12 @@ class GlmDsaModel:
                    groups=None):
         cfg = self.config
         b, s, _ = h_in.shape
-        nh, nope, vd, r = (cfg.num_heads, cfg.qk_nope_head_dim,
-                           cfg.v_head_dim, cfg.kv_lora_rank)
+        nh, vd, r = cfg.num_heads, cfg.v_head_dim, cfg.kv_lora_rank
         with jax.named_scope("attn_proj"):
             x = rms_norm(h_in, lp["attn_norm"], cfg.rms_norm_eps)
-            c_q = rms_norm(x @ lp["q_a"], lp["q_a_norm"], cfg.rms_norm_eps)
-            q = split_heads(c_q @ lp["q_b"], nh)
-            q_pe = apply_rope_interleaved(q[..., nope:], positions,
-                                          self.inv_freq)
-            ckv = x @ lp["kv_a"]
-            c_hat = rms_norm(ckv[..., :r], lp["kv_a_norm"], cfg.rms_norm_eps)
-            k_pe = apply_rope_interleaved(
-                ckv[:, :, None, r:], positions, self.inv_freq)[:, :, 0]
-            kv_b = lp["kv_b"].reshape(r, nh, nope + vd)
-            # absorption: q_nope[h]·(Wk[h]ᵀ c) = (Wk[h] q_nope[h])·c
-            q_lat = jnp.concatenate(
-                [jnp.einsum("bshn,rhn->bshr", q[..., :nope], kv_b[..., :nope]),
-                 q_pe], axis=-1)
-            row = jnp.concatenate([c_hat, k_pe], axis=-1).reshape(b * s, -1)
+            q_lat, row, kv_b, c_q = latent_projections(
+                cfg, lp, x, positions, self.inv_freq)
+            row = row.reshape(b * s, -1)
             if cfg.indexed:
                 latent = latent_cache.write_latent(
                     cache["latent"], li, latent_cache.pack_rows(row),
@@ -805,8 +864,7 @@ class GlmDsaModel:
                     block_tables[:, :ctx_blocks], sel, self.sm_scale, dv=r,
                     live=jnp.sum(slot_idx >= 0, axis=1), seq_lens=seq_lens)
         with jax.named_scope("attn_out"):
-            o = jnp.einsum("bshr,rhv->bshv", out[..., :r].astype(h_in.dtype),
-                           kv_b[..., nope:])
+            o = latent_values(cfg, out, kv_b, h_in.dtype)
             h = h_in + o.reshape(b, s, nh * vd) @ lp["wo"]
         return h, cache, sel
 

@@ -1,13 +1,17 @@
 """A decoder whose layers are of two kinds: most carry a recurrent state of
-fixed size behind a short causal convolution, every few attend over the paged
-K/V cache (grouped-query attention with no positional term).  Every layer
-ends in routed experts, of which this chip holds a share, plus a shared
-expert — or, where the model has no experts, in one gated MLP with no router.
+fixed size behind a short causal convolution, every few attend over a paged
+cache — grouped-query attention with no positional term over the K/V pool
+(``attention`` "gqa"), or latent attention in absorbed form over one cached
+row a token (``attention`` "mla": the cache's ``kv`` leaf gives way to a
+``latent`` leaf in ops/latent_cache.py's dense layout).  Every layer ends in
+routed experts, of which this chip holds a share, plus a shared expert — or,
+in the ``dense_layers`` leading layers and where the model has no experts, in
+one gated MLP with no router.
 The recurrence is the configuration's (``recurrence``): the gated delta rule
-with a decay per key channel (``solar_open2``, ops/linear_state.py), the
-state-space recurrence with a scalar decay a head (``granitemoehybrid``'s
-Mamba-2 layers, ops/ssm_state.py) or the selective scan with a decay a
-channel and state index (``jamba``'s Mamba-1 layers,
+with a decay per key channel (``solar_open2``, ``ling_hybrid_mla``:
+ops/linear_state.py), the state-space recurrence with a scalar decay a head
+(``granitemoehybrid``'s Mamba-2 layers, ops/ssm_state.py) or the selective
+scan with a decay a channel and state index (``jamba``'s Mamba-1 layers,
 ops/selective_state.py).  docs/linear_state.md has the equations of all
 three.
 
@@ -16,10 +20,14 @@ times ``residual_multiplier``):
 
   * **Delta-rule layer.**  q̂, k̂, v̂ = W x; a depth-wise convolution of
     ``short_conv_kernel_size`` over time on each, then SiLU; q and k L2-normed
-    a head (q also by d^-1/2); decay g = -exp(A_log) · softplus(W_f↑ W_f↓ x +
-    b_dt) a key channel, step beta = 2 · sigmoid(W_β x); the recurrence
+    a head (q also by d^-1/2); decay g = -exp(A_log) · softplus(a + b_dt) a
+    key channel or, in its lower-bound form (``decay_lower_bound`` b < 0),
+    g = b · sigmoid(exp(A_log) · (a + b_dt)) in (b, 0); a = W_f↑ W_f↓ x, or
+    W_f x full-rank where ``gate_rank`` is 0; step beta = ``beta_scale`` ·
+    sigmoid(W_β x), in (0, 2) or (0, 1); the recurrence
     (``delta_rule_step`` for one token a row, ``delta_rule_scan`` for a
-    prefill chunk); RMSNorm a head and a low-rank sigmoid gate; W_o.
+    prefill chunk); RMSNorm a head and a sigmoid gate of the decay's rank;
+    W_o.
   * **State-space layer.**  z ‖ xBC ‖ dt = W_in x; one depth-wise convolution
     with a bias over x‖B‖C, then SiLU; Δ = softplus(dt + b_dt), decay
     exp(Δ·A) a head; the recurrence (``ssd_step`` / ``ssd_scan``); RMSNorm
@@ -34,17 +42,31 @@ times ``residual_multiplier``):
     Pallas kernels every dense model here uses (ops/paged_attention.py), no
     rope, no q/k norm; scale d^-1/2 or ``attention_multiplier``;
     o ⊙ sigmoid(W_gate x) where the model has the gate; W_o.
+  * **MLA layer.**  The projections and the absorbed form of
+    models/glm_dsa.py (``latent_projections``: W_q direct where
+    ``q_lora_rank`` is None, ĉ = RMSNorm(c), rope on q_pe and k_pe), the row
+    ĉ ‖ rope(k_pe) written once into ``latent``, causal softmax over every
+    cached row at scale (nope + rope)^-1/2 (``latent_cache.dense_attention``:
+    ``mla_dense_decode`` / ``mla_dense_prefill`` on the TPU), the attended
+    latent back through kv_b's V half, o_h ⊙ sigmoid(W_gate x)_h a head
+    where ``head_gate``; W_o.
   * **Experts**: as models/glm_dsa.py — the router scores all
     ``router_experts``, this chip computes the part its own give.  With
     ``n_routed_experts`` 0 the layer's feed-forward is W_down(SiLU(W_gate u)
-    ⊙ W_up u) and nothing else: no router, no shared expert.
+    ⊙ W_up u) and nothing else: no router, no shared expert; so is that of
+    the first ``dense_layers`` layers of a model with experts, at
+    ``intermediate_size``.  With ``n_group`` > 1 the choice is V3's
+    group-limited one (``moe_route``).
 
-Parameters are stacked per kind, and each run of consecutive layers of one
-kind is one ``lax.scan``.
+Parameters are stacked per kind — the mixer's, and ``_dense`` behind it where
+the layer ends in the MLP: parameters of another shape, a group and a scan of
+their own — and each run of consecutive layers of one kind is one
+``lax.scan``.
 
 **The state is held per engine slot**, not per block: the cache is a dict of
-``kv`` (the pool, over the attending layers only), ``state``, ``conv``,
-``state_pos`` and ``moe_counts``.  ``forward`` is told which slot each row of
+``kv`` or ``latent`` (the pool, over the attending layers only; the engine
+reads the leaf's name from ``pool_leaf``), ``state``, ``conv``,
+``state_pos`` and ``moe_counts``, all under the one block table.  ``forward`` is told which slot each row of
 the dispatch sits in (``seq_slots``; None: row i is slot i, a decode over the
 slot array) and owns these rules: a row whose first position is 0 starts from
 zeros; a token with ``slot_idx < 0`` is an identity step; a row with no real
@@ -67,17 +89,29 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from dynamo_tpu.models.deepseek import moe_route
-from dynamo_tpu.models.glm_dsa import ROUTER_BIAS_STD
+from dynamo_tpu.models.deepseek import moe_route, routing_groups
+from dynamo_tpu.models.glm_dsa import (
+    ROUTER_BIAS_STD,
+    dense_attention_impls,
+    latent_params,
+    latent_projections,
+    latent_values,
+)
 from dynamo_tpu.models.llama import (
     EXPERT_COUNT_KEYS,
     EXPERT_COUNTS,
     experts_touched,
     grouped_expert_dispatch,
     rms_norm,
+    rope_inv_freq,
     split_heads,
 )
-from dynamo_tpu.ops import linear_state, selective_state, ssm_state
+from dynamo_tpu.ops import (
+    latent_cache,
+    linear_state,
+    selective_state,
+    ssm_state,
+)
 from dynamo_tpu.ops.pallas.linear_state import state_update
 from dynamo_tpu.ops.pallas.selective_state import (
     state_scan as selective_state_scan,
@@ -157,6 +191,33 @@ class HybridLinearConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # the delta rule's switches: the decay's lower bound b < 0 (None: the
+    # softplus form), what multiplies sigmoid(W_β x); ``gate_rank`` 0 makes
+    # both of its gates full-rank
+    decay_lower_bound: float | None = None
+    beta_scale: float = 2.0
+    # the leading layers that end in a dense MLP, and its width
+    dense_layers: int = 0
+    intermediate_size: int = 0
+    n_group: int = 1               # routing groups (``moe_route``)
+    topk_group: int = 1
+    # what the attending layers are: "gqa" over the K/V pool, or "mla" over
+    # one latent row a token (``head_dim`` is then the row, r + rope, and
+    # ``num_kv_heads`` 1: what the engine sizes a token's cache by)
+    attention: str = "gqa"
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    kv_lora_rank: int = 0
+    q_lora_rank: int | None = None
+    rope_theta: float = 10000.0
+    head_gate: bool = False        # MLA: o_h ⊙ sigmoid(W_gate x)_h
+    # how ``init_params`` draws a delta-rule layer's value path: with the
+    # mean SiLU gives every v kept out of the residual stream (its docstring).
+    # The rule of every delta-rule reader from PR 66 on; ``solar_open2``
+    # keeps the draw its accepted cell was measured with.  A property of the
+    # draw, fixed before a cell is measured: not a knob to meet a spread
+    seed_without_common_mode: bool = False
 
     @property
     def jax_dtype(self):
@@ -199,8 +260,8 @@ class HybridLinearConfig:
     @classmethod
     def from_hf_config(cls, cfg: dict, dtype: str = "bfloat16"
                        ) -> "HybridLinearConfig":
-        """The published ``solar_open2``, ``granitemoehybrid`` or ``jamba``
-        keys -> HybridLinearConfig.  Raises, by name, on what this port does not
+        """The published ``solar_open2``, ``granitemoehybrid``, ``jamba`` or
+        ``ling_hybrid_mla`` keys -> HybridLinearConfig.  Raises, by name, on what this port does not
         compute.  ``expert_parallel`` (not a published key) says which share
         of a layer's experts this chip holds, as for models/glm_dsa.py."""
         g = cfg.get
@@ -208,6 +269,8 @@ class HybridLinearConfig:
             return cls._from_granite(cfg, dtype)
         if g("model_type") == "jamba":
             return cls._from_jamba(cfg, dtype)
+        if g("model_type") == "ling_hybrid_mla":
+            return cls._from_ling(cfg, dtype)
         if g("model_type") != "solar_open2":
             raise NotImplementedError(f"model_type {g('model_type')!r}")
         lin = g("linear_attn_config") or {}
@@ -263,6 +326,106 @@ class HybridLinearConfig:
             max_position_embeddings=int(g("max_position_embeddings", 4096)),
             dtype=dtype,
             tie_word_embeddings=bool(g("tie_word_embeddings", False)),
+        )
+
+    @classmethod
+    def _from_ling(cls, cfg: dict, dtype: str) -> "HybridLinearConfig":
+        """``ling_hybrid_mla`` (Ling-3.0-flash's language model; the name is
+        this repository's, the catalog's row carries none): published layer
+        L attends — MLA — iff (L + 1) % ``layer_group_size`` == 0 and is a
+        delta-rule (KDA) layer otherwise, with full-rank gates and the
+        decay's lower-bound form; layers below ``first_k_dense_replace`` end
+        in a dense MLP of ``intermediate_size``, the others in ``num_experts``
+        sigmoid-routed experts chosen inside the ``topk_group`` best of
+        ``n_group`` groups.  ``published_layers`` (not a published key) names
+        the published index of each of the file's layers where the file is a
+        cut of the stack; the per-layer lists are read by it."""
+        g = cfg.get
+        for key in ("use_nGPT", "scale_router_input", "value_norm",
+                    "up_proj_norm", "mtp_use_kda", "use_mla_nope",
+                    "use_kda_lora"):
+            if bool(g(key, False)):
+                raise NotImplementedError(f"{key}=True")
+        for key in ("no_kda_lora", "kda_safe_gate", "use_qk_norm",
+                    "linear_silu", "moe_router_enable_expert_bias"):
+            if not bool(g(key, True)):
+                raise NotImplementedError(f"{key}=False")
+        if g("score_function", "sigmoid") != "sigmoid":
+            raise NotImplementedError(
+                f"score_function {g('score_function')!r}")
+        if g("gated_attention_proj_granularity_type",
+             "head_wise") != "head_wise":
+            raise NotImplementedError(
+                "gated_attention_proj_granularity_type "
+                f"{g('gated_attention_proj_granularity_type')!r}")
+        if g("rope_scaling") is not None:
+            raise NotImplementedError(f"rope_scaling {g('rope_scaling')!r}")
+        if int(g("group_norm_size", 1)) != 1:
+            raise NotImplementedError(
+                f"group_norm_size {g('group_norm_size')} (several heads a "
+                "norm group)")
+        hq, d = int(g("num_attention_heads")), int(g("head_dim"))
+        if int(g("num_kv_heads_for_linear_attn", 0) or 0) not in (0, hq):
+            raise NotImplementedError(
+                "num_kv_heads_for_linear_attn "
+                f"{g('num_kv_heads_for_linear_attn')} (grouped linear heads)")
+        rope = int(g("qk_rope_head_dim"))
+        if int(g("rotary_dim", rope)) != rope or round(
+                float(g("partial_rotary_factor", rope / d)) * d) != rope:
+            raise NotImplementedError(
+                f"rotary_dim {g('rotary_dim')} / partial_rotary_factor "
+                f"{g('partial_rotary_factor')} beside qk_rope_head_dim {rope} "
+                "(a rotary term outside the MLA layers' rope part)")
+        bound = float(g("kda_lower_bound", -5.0))
+        if not bound < 0:
+            raise ValueError(f"kda_lower_bound {bound} is not below 0")
+        n, period = int(g("num_hidden_layers")), int(g("layer_group_size"))
+        published = [int(i) for i in g("published_layers") or range(n)]
+        if len(published) != n or sorted(set(published)) != published:
+            raise ValueError(
+                f"published_layers {published} do not name {n} layers in "
+                "ascending order")
+        for key in ("expert_swiglu_limit_list",
+                    "share_expert_swiglu_limit_list"):
+            limits = list(g(key) or ())
+            on = [i for i in published if i < len(limits) and limits[i]]
+            if on:
+                raise NotImplementedError(
+                    f"{key} is non-zero in layers {on} (a clamp inside the "
+                    "experts' SwiGLU)")
+        dense = sum(i < int(g("first_k_dense_replace", 0)) for i in published)
+        held, total, first = _held_experts(cfg, "num_experts")
+        return cls(
+            vocab_size=int(g("vocab_size")), hidden_size=int(g("hidden_size")),
+            num_layers=n, num_heads=hq, num_kv_heads=1,
+            head_dim=int(g("kv_lora_rank")) + rope,
+            linear_heads=hq, linear_head_dim=d,
+            conv_kernel=int(g("short_conv_kernel_size")), gate_rank=0,
+            gqa_layers=tuple(at for at, i in enumerate(published)
+                             if (i + 1) % period == 0),
+            moe_intermediate_size=int(g("moe_intermediate_size")),
+            n_routed_experts=held, router_experts=total, expert_first=first,
+            num_experts_per_tok=int(g("num_experts_per_tok")),
+            n_shared_experts=1,
+            routed_scaling_factor=float(g("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(g("norm_topk_prob", True)),
+            rms_norm_eps=float(g("rms_norm_eps", 1e-6)),
+            max_position_embeddings=int(g("max_position_embeddings", 4096)),
+            dtype=dtype, gqa_gate=False,
+            shared_intermediate_size=int(
+                g("moe_shared_expert_intermediate_size")),
+            tie_word_embeddings=bool(g("tie_word_embeddings", False)),
+            decay_lower_bound=bound, beta_scale=1.0,
+            dense_layers=dense, intermediate_size=int(g("intermediate_size")),
+            attention="mla",
+            qk_nope_head_dim=int(g("qk_nope_head_dim")),
+            qk_rope_head_dim=rope, v_head_dim=int(g("v_head_dim")),
+            kv_lora_rank=int(g("kv_lora_rank")),
+            q_lora_rank=(None if g("q_lora_rank") is None
+                         else int(g("q_lora_rank"))),
+            rope_theta=float(g("rope_theta", 10000.0)), head_gate=True,
+            seed_without_common_mode=True,
+            **routing_groups(cfg, total),
         )
 
     @classmethod
@@ -457,9 +620,10 @@ def paged_gqa(q, k, v, kv, ci, positions, block_tables, seq_lens, slot_idx,
 @dataclass(frozen=True)
 class _Run:
     """Consecutive layers of one kind: a scan."""
-    kind: str        # "gqa" | "linear"
-    start: int       # first index within the kind's stacks (= its row of
-    count: int       # ``kv`` or of ``state`` / ``conv``)
+    kind: str        # the mixer, "gqa" | "mla" | "linear", and "_dense"
+                     # behind it where the layers end in the dense MLP
+    start: int       # first index within the kind's stacks
+    count: int
     layer0: int      # first layer index (row of ``moe_counts``)
 
 
@@ -471,6 +635,7 @@ class HybridLinearModel:
     # the slots of a prefill dispatch's rows, keeps prefix reuse off, and
     # refuses what packs several sequences into one row axis
     recurrent_state = True
+    pool_leaf = "kv"
     moe_count_keys = EXPERT_COUNT_KEYS + STATE_COUNT_KEYS
     supports_ragged_prefill = False
     supports_unified_dispatch = False
@@ -485,20 +650,46 @@ class HybridLinearModel:
         (scripts/hybrid_linear_longctx_check.py)."""
         self.config = config
         self.state_dtype = state_dtype
-        self.sm_scale = float(config.head_dim ** -0.5
-                              if config.attention_multiplier is None
-                              else config.attention_multiplier)
-        runs, seen = [], {"gqa": 0, "linear": 0}
+        if config.attention == "mla":
+            self.sm_scale = float(
+                (config.qk_nope_head_dim + config.qk_rope_head_dim) ** -0.5)
+            self.inv_freq = rope_inv_freq(config.qk_rope_head_dim,
+                                          config.rope_theta)
+            # what the engine asks a model with attention kernels and a
+            # cache leaf of its own (as GlmDsaModel): the leaf held by
+            # block, the start-up line's kernels, the rows a decode fetches,
+            # and a context gather sized by ``prefix_blocks``
+            from dynamo_tpu.ops.pallas.mla_dense_attention import (
+                decode_rows_fetched,
+            )
+            self.pool_leaf = "latent"
+            self.attention_impls = dense_attention_impls
+            self.decode_rows_fetched = decode_rows_fetched
+            self.prefix_blocks_sizes_forward = True
+        else:
+            self.sm_scale = float(config.head_dim ** -0.5
+                                  if config.attention_multiplier is None
+                                  else config.attention_multiplier)
+        runs, seen = [], {}
         for li in range(config.num_layers):
-            kind = "gqa" if li in config.gqa_layers else "linear"
+            kind = config.attention if li in config.gqa_layers else "linear"
+            if config.n_routed_experts and li < config.dense_layers:
+                kind += "_dense"
+            at = seen.get(kind, 0)
             if runs and runs[-1].kind == kind:
                 last = runs[-1]
                 runs[-1] = _Run(kind, last.start, last.count + 1, last.layer0)
             else:
-                runs.append(_Run(kind, seen[kind], 1, li))
-            seen[kind] += 1
+                runs.append(_Run(kind, at, 1, li))
+            seen[kind] = at + 1
         self.runs = tuple(runs)
-        self.group_sizes = {k: n for k, n in seen.items() if n}
+        self.group_sizes = seen
+        # a layer's row of its mixer's part of the cache (``kv`` / ``latent``,
+        # or ``state`` / ``conv``) is its index within its kind, behind the
+        # rows of the leading dense layers with the same mixer
+        self.cache_shift = {
+            kind: 0 if kind.endswith("_dense") else seen.get(kind + "_dense", 0)
+            for kind in seen}
         self._draw = jax.jit(self._draw_params)
 
     # ------------------------------------------------------------------ init
@@ -507,7 +698,19 @@ class HybridLinearModel:
         own (A_log = ln U(1, 16) a head; b_dt the inverse softplus of a
         log-uniform step in [0.001, 0.1] a channel — a head for the
         state-space layers — the family's initialisation; what the input
-        adds to b_dt scaled by ``DECAY_PROJ_STD``; the selective layers'
+        adds to b_dt scaled by ``DECAY_PROJ_STD``; under the delta rule's
+        lower-bound gate b_dt = logit(step / |b|) / exp(A_log) and the
+        projection scaled by ``DECAY_PROJ_STD`` / exp(A_log) a head, so that
+        the decay's median is the same step's and moves with the token by
+        the same factor; with ``seed_without_common_mode`` the value path's
+        convolution taps have unit norm a channel and W_o zero mean over a
+        head's channels — SiLU gives every v the same positive mean, which
+        the state sums coherently and W_o would hand every token as one
+        common vector: 30% of a normed hidden state at these widths, so
+        every token's router favoured the same experts, 64 rows touched a
+        third of the experts held where an even router touches 63%, and by
+        how much (±11% a layer) was the seed's (PERF.md §6, PR 66); the
+        selective layers'
         A_log = ln(1..N) a channel and W_dt uniform in ±rank^-1/2, Mamba's
         published initialisation).  Keys are drawn in a
         fixed order: a new parameter goes after the ones that are there.  One program: made
@@ -523,17 +726,19 @@ class HybridLinearModel:
         lh, ld, r = cfg.linear_heads, cfg.linear_head_dim, cfg.gate_rank
         keys = iter(jax.random.split(rng, 64))
 
-        def dense(shape, fan_in, scale=1.0):
+        def dense(shape, fan_in, scale=1.0, dtype=dt):
             return (scale * jax.random.normal(next(keys), shape, jnp.float32)
-                    / math.sqrt(fan_in)).astype(dt)
+                    / math.sqrt(fan_in)).astype(dtype)
 
-        def decay_step(shape):
-            """The inverse softplus of a step log-uniform in [0.001, 0.1]:
-            softplus^-1(s) = ln(e^s - 1)."""
-            step = jnp.exp(jax.random.uniform(
+        def step_size(shape):
+            """A decay step log-uniform in [0.001, 0.1]."""
+            return jnp.exp(jax.random.uniform(
                 next(keys), shape, jnp.float32,
                 math.log(0.001), math.log(0.1)))
-            return jnp.log(jnp.expm1(step))
+
+        def decay_step(shape):
+            """Its inverse softplus: softplus^-1(s) = ln(e^s - 1)."""
+            return jnp.log(jnp.expm1(step_size(shape)))
 
         def a_log(n: int):
             return jnp.log(jax.random.uniform(
@@ -556,15 +761,20 @@ class HybridLinearModel:
                 shared_down=dense((n, fs, dm), fs))
             return out
 
-        def mlp(n: int) -> dict:
-            """The feed-forward of a model without experts: one gated MLP."""
-            f = cfg.moe_intermediate_size
+        def mlp(n: int, f: int) -> dict:
+            """One gated MLP: the feed-forward of a model without experts,
+            and of the leading dense layers of one with them."""
             return {"mlp_norm": jnp.ones((n, dm), dt),
                     "mlp_gate": dense((n, dm, f), dm),
                     "mlp_up": dense((n, dm, f), dm),
                     "mlp_down": dense((n, f, dm), f)}
 
-        feed_forward = experts if cfg.n_routed_experts else mlp
+        def feed_forward(n: int, leading: bool = False) -> dict:
+            if leading:
+                return mlp(n, cfg.intermediate_size)
+            if cfg.n_routed_experts:
+                return experts(n)
+            return mlp(n, cfg.moe_intermediate_size)
 
         def gqa(n: int) -> dict:
             out = {"attn_norm": jnp.ones((n, dm), dt),
@@ -574,29 +784,68 @@ class HybridLinearModel:
             if cfg.gqa_gate:
                 out["w_gate_attn"] = dense((n, dm, h * dh), dm)
             out["wo"] = dense((n, h * dh, dm), h * dh)
-            out.update(feed_forward(n))
+            return out
+
+        def mla(n: int) -> dict:
+            out = {"attn_norm": jnp.ones((n, dm), dt),
+                   **latent_params(cfg, n, dense, dt)}
+            if cfg.head_gate:
+                out["w_gate_heads"] = dense((n, dm, h), dm)
+            out["wo"] = dense((n, h * cfg.v_head_dim, dm), h * cfg.v_head_dim)
             return out
 
         def linear(n: int) -> dict:
-            dt_bias = decay_step((n, lh * ld))
-            return {
-                "attn_norm": jnp.ones((n, dm), dt),
-                "wq": dense((n, dm, lh * ld), dm),
-                "wk": dense((n, dm, lh * ld), dm),
-                "wv": dense((n, dm, lh * ld), dm),
-                "conv_w": dense((n, 3 * lh * ld, cfg.conv_kernel),
-                                cfg.conv_kernel),
-                "decay_down": dense((n, dm, r), dm),
-                "decay_up": dense((n, r, lh * ld), r, DECAY_PROJ_STD),
-                "a_log": a_log(n),
-                "dt_bias": dt_bias,
-                "w_beta": dense((n, dm, lh), dm),
-                "out_norm": jnp.ones((n, ld), dt),
-                "gate_down": dense((n, dm, r), dm),
-                "gate_up": dense((n, r, lh * ld), r),
-                "wo": dense((n, lh * ld, dm), lh * ld),
-                **experts(n),
-            }
+            width, bound = lh * ld, cfg.decay_lower_bound
+            step = step_size((n, width))
+            out = {"attn_norm": jnp.ones((n, dm), dt),
+                   "wq": dense((n, dm, width), dm),
+                   "wk": dense((n, dm, width), dm),
+                   "wv": dense((n, dm, width), dm),
+                   "conv_w": dense((n, 3 * width, cfg.conv_kernel),
+                                   cfg.conv_kernel, dtype=jnp.float32)}
+            if cfg.seed_without_common_mode:
+                # every value channel's taps at unit norm: one E[SiLU] for all
+                taps = out["conv_w"][:, 2 * width:]
+                out["conv_w"] = out["conv_w"].at[:, 2 * width:].set(
+                    taps * jax.lax.rsqrt(
+                        jnp.sum(taps * taps, axis=-1, keepdims=True)))
+            out["conv_w"] = out["conv_w"].astype(dt)
+            # what the decay's projection adds to b_dt, drawn at standard
+            # deviation 1 here and scaled below, once A is drawn
+            if r:
+                out["decay_down"] = dense((n, dm, r), dm)
+                last = "decay_up"
+                out[last] = dense((n, r, width), r, dtype=jnp.float32)
+            else:
+                last = "w_decay"
+                out[last] = dense((n, dm, width), dm, dtype=jnp.float32)
+            out["a_log"] = a_log(n)
+            if bound is None:
+                scale = jnp.float32(DECAY_PROJ_STD)
+                out["dt_bias"] = jnp.log(jnp.expm1(step))
+            else:
+                # g = b·sigmoid(A·(a + b_dt)): b_dt puts the median decay at
+                # the drawn step, and A multiplies what the token adds
+                a_head = jnp.repeat(jnp.exp(out["a_log"]), ld, axis=-1)
+                scale = (DECAY_PROJ_STD / a_head)[:, None, :]
+                ratio = step / abs(bound)
+                out["dt_bias"] = jnp.log(ratio / (1.0 - ratio)) / a_head
+            out[last] = (out[last] * scale).astype(dt)
+            out["w_beta"] = dense((n, dm, lh), dm)
+            out["out_norm"] = jnp.ones((n, ld), dt)
+            if r:
+                out["gate_down"] = dense((n, dm, r), dm)
+                out["gate_up"] = dense((n, r, width), r)
+            else:
+                out["w_out_gate"] = dense((n, dm, width), dm)
+            out["wo"] = dense((n, width, dm), width, dtype=jnp.float32)
+            if cfg.seed_without_common_mode:
+                # ... and W_o blind to what is constant over a head's channels
+                heads = out["wo"].reshape(n, lh, ld, dm)
+                out["wo"] = (heads - heads.mean(axis=2, keepdims=True)
+                             ).reshape(n, width, dm)
+            out["wo"] = out["wo"].astype(dt)
+            return out
 
         def conv_init(shape):
             bound = cfg.conv_kernel ** -0.5
@@ -624,7 +873,6 @@ class HybridLinearModel:
                 "d_skip": jnp.ones((n, lh), jnp.float32),
                 "out_norm": jnp.ones((n, inner), dt),
                 "wo": dense((n, inner, dm), inner),
-                **experts(n),
             }
 
         def selective(n: int) -> dict:
@@ -649,15 +897,19 @@ class HybridLinearModel:
                     (n, ns, inner)),
                 "d_skip": jnp.ones((n, inner), jnp.float32),
                 "wo": dense((n, inner, dm), inner),
-                **feed_forward(n),
             }
 
-        make = {"gqa": gqa,
+        make = {"gqa": gqa, "mla": mla,
                 "linear": {"ssd": ssd, "selective": selective,
                            "delta": linear}[cfg.recurrence]}
+
+        def group(kind: str, n: int) -> dict:
+            mixer, _, leading = kind.partition("_")
+            return {**make[mixer](n), **feed_forward(n, bool(leading))}
+
         out = {
             "embed": dense((cfg.vocab_size, dm), dm),
-            "groups": {kind: make[kind](n)
+            "groups": {kind: group(kind, n)
                        for kind, n in sorted(self.group_sizes.items())},
             "final_norm": jnp.ones((dm,), dt),
         }
@@ -675,15 +927,17 @@ class HybridLinearModel:
     def cache_spec(self, quant: bool = False):
         if quant:
             raise NotImplementedError("int8 K/V beside a recurrent state")
-        return {"kv": P(), "state": P(), "conv": P(), "state_pos": P(),
-                "moe_counts": P()}
+        return {self.pool_leaf: P(), "state": P(), "conv": P(),
+                "state_pos": P(), "moe_counts": P()}
 
     # --------------------------------------------------------------- kv cache
     def init_kv_cache(self, num_blocks: int, block_size: int, dtype=None,
                       slots: int | None = None):
         """``kv``: the K/V pool in LlamaModel's layout over the attending
-        layers only, [L_gqa, N, 2, Bs, Hk·D], first in the pytree's order of
-        what the engine counts a token's cache bytes by; ``state``
+        layers only, [L_gqa, N, 2, Bs, Hk·D], what the engine counts a
+        token's cache bytes by — or, where those layers are latent ones,
+        ``latent`` [L_mla, N, Bs, Wd]: the row ĉ ‖ rope(k_pe) in
+        ops/latent_cache.py's dense layout; ``state``
         [L_lin, slots, *state_shape] float32 (H, d, d of the delta rule;
         H, P, N of the state-space layers; N, rows, lanes of the selective
         ones), ``conv`` [L_lin, slots, K-1, conv_width] and
@@ -699,15 +953,21 @@ class HybridLinearModel:
             raise ValueError(
                 "a recurrent state is held per engine slot: init_kv_cache "
                 "needs slots= (EngineCore passes max_batch_size)")
-        return {
-            "kv": jnp.zeros(
+        if cfg.attention == "mla":
+            pool = latent_cache.init_dense_cache(
+                len(cfg.gqa_layers), num_blocks, block_size, cfg.head_dim,
+                cfg.jax_dtype)
+        else:
+            pool = {"kv": jnp.zeros(
                 (len(cfg.gqa_layers), num_blocks, 2, block_size,
-                 cfg.num_kv_heads * cfg.head_dim), cfg.jax_dtype),
+                 cfg.num_kv_heads * cfg.head_dim), cfg.jax_dtype)}
+        return {
+            **pool,
             **linear_state.init_state(
                 cfg.linear_layers, slots, *cfg.state_shape, cfg.conv_width,
                 cfg.conv_kernel, cfg.jax_dtype, self.state_dtype),
             "moe_counts": jnp.zeros(
-                (cfg.num_layers, 1, EXPERT_COUNTS + STATE_COUNTS), jnp.int32),
+                (cfg.num_layers, 1, len(self.moe_count_keys)), jnp.int32),
         }
 
     def state_bytes_per_slot(self) -> int:
@@ -785,10 +1045,12 @@ class HybridLinearModel:
 
     def _mlp(self, lp: dict, h):
         """h + W_down(SiLU(W_gate u) ⊙ W_up u), u = RMSNorm(h): the
-        feed-forward of a model without experts."""
-        x = rms_norm(h, lp["mlp_norm"], self.config.rms_norm_eps)
-        return self._add(h, (jax.nn.silu(x @ lp["mlp_gate"])
-                             * (x @ lp["mlp_up"])) @ lp["mlp_down"])
+        feed-forward of a model without experts, and of the leading dense
+        layers of one with them."""
+        with jax.named_scope("dense_mlp"):
+            x = rms_norm(h, lp["mlp_norm"], self.config.rms_norm_eps)
+            return self._add(h, (jax.nn.silu(x @ lp["mlp_gate"])
+                                 * (x @ lp["mlp_up"])) @ lp["mlp_down"])
 
     def _gqa(self, lp, ci, h, kv, positions, block_tables, seq_lens,
              slot_idx, prefix_blocks, by_length):
@@ -812,6 +1074,42 @@ class HybridLinearModel:
                 o = o.astype(jnp.float32) * gate
             h = self._add(h, o.astype(h.dtype) @ lp["wo"])
         return h, kv
+
+    def _mla(self, lp, ci, h, latent, positions, block_tables, seq_lens,
+             slot_idx, groups):
+        """One latent-attention layer: the shared projections
+        (``latent_projections``), the row written into row ``ci`` of
+        ``latent``, every cached row attended (``block_tables`` cut to the
+        context the call reads), the gate a head.  The whole mixer is under
+        ``latent_attn``."""
+        cfg = self.config
+        b, s, _ = h.shape
+        f32 = jnp.float32
+        with jax.named_scope("latent_attn"):
+            with jax.named_scope("attn_proj"):
+                x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
+                q_lat, row, kv_b, _ = latent_projections(
+                    cfg, lp, x, positions, self.inv_freq)
+                latent = latent_cache.write_dense(
+                    latent, ci, row.reshape(b * s, -1),
+                    slot_idx.reshape(b * s))
+                # the softmax scale rides on the query: the dense kernels
+                # know none
+                q_lat = (q_lat.astype(f32) * self.sm_scale).astype(
+                    q_lat.dtype)
+                if cfg.head_gate:
+                    gate = jax.nn.sigmoid(
+                        (x @ lp["w_gate_heads"]).astype(f32))[..., None]
+            with jax.named_scope("attn"):
+                out = latent_cache.dense_attention(
+                    q_lat, latent, ci, block_tables, positions, seq_lens,
+                    dv=cfg.kv_lora_rank, groups=groups)
+            with jax.named_scope("attn_out"):
+                o = latent_values(cfg, out, kv_b, h.dtype)
+                if cfg.head_gate:
+                    o = (o.astype(f32) * gate).astype(h.dtype)
+                h = self._add(h, o.reshape(b, s, -1) @ lp["wo"])
+        return h, latent
 
     def _ssd(self, lp, si, h, state, conv, rows):
         """One state-space layer; arguments as ``_linear``.  The state's own
@@ -877,6 +1175,11 @@ class HybridLinearModel:
         return h, state, conv
 
     def _linear(self, lp, si, h, state, conv, rows):
+        """One delta-rule layer, the whole mixer under ``delta_rule``."""
+        with jax.named_scope("delta_rule"):
+            return self._delta_rule(lp, si, h, state, conv, rows)
+
+    def _delta_rule(self, lp, si, h, state, conv, rows):
         """One linear layer on ``h`` [B, S, Dm]; ``state`` / ``conv`` are the
         whole leaves, ``si`` this layer's row of them.  ``rows`` = (slots or
         None, fresh [B], alive [B], n_real [B], valid [B, S])."""
@@ -889,9 +1192,12 @@ class HybridLinearModel:
             x = rms_norm(h, lp["attn_norm"], cfg.rms_norm_eps)
             qkv = jnp.concatenate(
                 [x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]], axis=-1)
-            a = (x @ lp["decay_down"]) @ lp["decay_up"]
+            full = not cfg.gate_rank
+            a = (x @ lp["w_decay"] if full
+                 else (x @ lp["decay_down"]) @ lp["decay_up"])
             beta_logit = x @ lp["w_beta"]
-            out_gate = (x @ lp["gate_down"]) @ lp["gate_up"]
+            out_gate = (x @ lp["w_out_gate"] if full
+                        else (x @ lp["gate_down"]) @ lp["gate_up"])
         in_place = self._updates_in_place(s, slots)
         with jax.named_scope("attn"), jax.named_scope("linear"):
             at = si if slots is None else (si, slots)    # row i is slot i
@@ -909,10 +1215,16 @@ class HybridLinearModel:
                     jnp.sum(t * t, axis=-1, keepdims=True) + QK_NORM_EPS)
 
             q, k = unit(q) * ld ** -0.5, unit(k)
-            g = -jnp.exp(lp["a_log"].astype(f32))[:, None] * jax.nn.softplus(
-                a.astype(f32).reshape(b, s, lh, ld)
-                + lp["dt_bias"].astype(f32).reshape(lh, ld))
-            beta = 2.0 * jax.nn.sigmoid(beta_logit.astype(f32))
+            a_head = jnp.exp(lp["a_log"].astype(f32))[:, None]
+            if cfg.decay_lower_bound is None:
+                g = -a_head * jax.nn.softplus(
+                    a.astype(f32).reshape(b, s, lh, ld)
+                    + lp["dt_bias"].astype(f32).reshape(lh, ld))
+            else:
+                g = cfg.decay_lower_bound * jax.nn.sigmoid(a_head * (
+                    a.astype(f32).reshape(b, s, lh, ld)
+                    + lp["dt_bias"].astype(f32).reshape(lh, ld)))
+            beta = cfg.beta_scale * jax.nn.sigmoid(beta_logit.astype(f32))
             # padding: an identity step
             g = jnp.where(valid[..., None, None], g, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
@@ -1051,15 +1363,29 @@ class HybridLinearModel:
             cache["state_pos"], positions, slot_idx, seq_slots,
             cfg.linear_layers)
         valid = rows[-1]
-        by_length = (decode_rows_by_length(block_tables, seq_lens, positions)
-                     if s == 1 else None)
+        latent = cfg.attention == "mla"
+        by_length = groups = None
+        if latent:
+            # the context the call reads: the static ``prefix_blocks``
+            # cached blocks and its own (None: the table)
+            bs = cache["latent"].shape[2]
+            if prefix_blocks is not None:
+                block_tables = block_tables[
+                    :, :prefix_blocks + -(-s // bs)]
+            if s == 1:
+                groups = latent_cache.dense_decode_groups(
+                    block_tables, positions, seq_lens, bs)
+        elif s == 1:
+            by_length = decode_rows_by_length(block_tables, seq_lens,
+                                              positions)
         with jax.named_scope("embed"):
             hidden = params["embed"][tokens].astype(cfg.jax_dtype)
             if cfg.embedding_multiplier != 1.0:
                 hidden = hidden * jnp.asarray(cfg.embedding_multiplier,
                                               hidden.dtype)
 
-        kv, state, conv = cache["kv"], cache["state"], cache["conv"]
+        kv, state, conv = (cache[self.pool_leaf], cache["state"],
+                           cache["conv"])
         counts = cache["moe_counts"].at[0, 0, EXPERT_COUNTS:].add(counted)
         expert_keys = ("w_gate", "w_up", "w_down")
         recur = {"ssd": self._ssd, "selective": self._selective,
@@ -1068,19 +1394,26 @@ class HybridLinearModel:
         def layer_step(kind: str):
             group = params["groups"][kind]
             sliced = {k: v for k, v in group.items() if k not in expert_keys}
+            mixer, _, leading = kind.partition("_")
+            routed = bool(cfg.n_routed_experts) and not leading
+            shift = self.cache_shift[kind]
 
             def step(carry, at):
                 h, kv, state, conv, counts = carry
                 i, li = at
+                ci = i + shift if shift else i
                 lp = jax.tree.map(lambda a: a[i], sliced)
-                if kind == "gqa":
-                    h, kv = self._gqa(lp, i, h, kv, positions, block_tables,
+                if mixer == "gqa":
+                    h, kv = self._gqa(lp, ci, h, kv, positions, block_tables,
                                       seq_lens, slot_idx, prefix_blocks,
                                       by_length)
+                elif mixer == "mla":
+                    h, kv = self._mla(lp, ci, h, kv, positions, block_tables,
+                                      seq_lens, slot_idx, groups)
                 else:
-                    h, state, conv = recur(lp, i, h, state, conv, rows)
+                    h, state, conv = recur(lp, ci, h, state, conv, rows)
                 with jax.named_scope("mlp"):
-                    if cfg.n_routed_experts:
+                    if routed:
                         h, picked = self._experts(group, lp, i, h, valid)
                         counts = counts.at[li, 0, :EXPERT_COUNTS].add(picked)
                     else:
@@ -1099,7 +1432,7 @@ class HybridLinearModel:
                 steps[run.kind], (hidden, kv, state, conv, counts),
                 (run.start + n, run.layer0 + n))
         hidden = rms_norm(hidden, params["final_norm"], cfg.rms_norm_eps)
-        return hidden, {"kv": kv, "state": state, "conv": conv,
+        return hidden, {self.pool_leaf: kv, "state": state, "conv": conv,
                         "state_pos": state_pos, "moe_counts": counts}
 
     def compute_logits(self, params, hidden):

@@ -1,6 +1,8 @@
 """The agreement of a served recurrent-state configuration (``--config``:
 solar-open2-ep16, the delta rule, by default; granite-4.0-h-small-ep2, the
-state-space recurrence; jamba2-3b, the selective scan) with its reference over a long answer, which the benchmark's ``correct`` cannot reach: it sees 8
+state-space recurrence; jamba2-3b, the selective scan; ling-3.0-flash-ep4, the
+delta rule's lower-bound gate beside a latent cache) with its reference over
+a long answer, which the benchmark's ``correct`` cannot reach: it sees 8
 greedy tokens behind at most 700, and the question a recurrent state raises is
 what a thousand updates do to it.
 
@@ -16,8 +18,11 @@ float32's next precision down — which the rule **cannot** tell from float32
 at these widths: 6 linear layers' bf16 rows round more than a bf16 state
 does (PERF.md section 6, PR 48).  ``--cache-one-precision-down``: everything
 a sequence keeps between dispatches one precision below what the
-configuration states — the state in bf16, the K/V rows and the convolution's
-tail in float8 (e4m3) — which the rule rejects.)
+configuration states — the state in bf16, the K/V rows (or the latent rows)
+and the convolution's tail in float8 (e4m3) — which the rule rejects; for
+ling-3.0-flash-ep4 only over the long answer of the first form, not over the
+check's 8 tokens: PERF.md section 6, PR 66, and scripts/
+ling_router_witness.py.)
 
 One process, on the chip (``--tiny``: a toy size on the CPU, to rehearse the
 script).  It builds cellbench/configs/<config>.json at its published
@@ -113,9 +118,35 @@ TINY_JAMBA = dict(
     serve=TINY["serve"],
     check={"abs_tol": 0.06, "share_within": 1.0, "median_tol": 1e-4})
 
+TINY_LING = dict(
+    model_type="ling_hybrid_mla", vocab_size=512, hidden_size=64,
+    num_hidden_layers=4, published_layers=[0, 3, 4, 5], attention_layers=1,
+    layer_group_size=3, first_k_dense_replace=1, num_attention_heads=4,
+    num_key_value_heads=4, head_dim=16, intermediate_size=96,
+    moe_intermediate_size=32, moe_shared_expert_intermediate_size=32,
+    q_lora_rank=None, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, rope_theta=6000000,
+    partial_rotary_factor=0.5, rotary_dim=8, use_mla_nope=False,
+    num_kv_heads_for_linear_attn=0, group_norm_size=1, linear_silu=True,
+    short_conv_kernel_size=4, use_qk_norm=True, no_kda_lora=True,
+    use_kda_lora=False, kda_safe_gate=True, kda_lower_bound=-5,
+    gated_attention_proj_granularity_type="head_wise", num_experts=4,
+    num_experts_per_tok=2, n_group=4, topk_group=2, score_function="sigmoid",
+    moe_router_enable_expert_bias=True, norm_topk_prob=True,
+    routed_scaling_factor=2.5, rms_norm_eps=1e-6,
+    max_position_embeddings=4096, tie_word_embeddings=False,
+    expert_parallel={"chips": 4, "router_experts": 16, "first_expert": 4},
+    dtype="float32", reference="ling_hybrid_mla",
+    model_class=TINY["model_class"], config_class=TINY["config_class"],
+    serve=TINY["serve"],
+    # the XLA form of dense latent attention rounds its queries and
+    # probabilities to bf16 whatever the cache holds: the float32 toy reads
+    # a median of 8e-5, its bf16 state 1e-2, its cache one precision down 0.13
+    check={"abs_tol": 0.06, "share_within": 0.98, "median_tol": 0.001})
+
 # --config: the benchmark's file and the toy that rehearses it
 CONFIGS = {"solar-open2-ep16": TINY, "granite-4.0-h-small-ep2": TINY_GRANITE,
-           "jamba2-3b": TINY_JAMBA}
+           "jamba2-3b": TINY_JAMBA, "ling-3.0-flash-ep4": TINY_LING}
 
 
 def bf16_state() -> None:
@@ -137,12 +168,13 @@ def cache_one_precision_down() -> None:
     """The negative control of the check's rule: what a sequence keeps
     between dispatches, each in the nearest precision below the one the
     configuration states — the state in bf16 (``bf16_state``), the K/V rows
-    and the convolution's tail rounded to float8 (e4m3) before they are
-    kept.  The arithmetic and the reference are not touched."""
+    (the latent rows of a model that keeps those) and the convolution's tail
+    rounded to float8 (e4m3) before they are kept.  The arithmetic and the
+    reference are not touched."""
     import jax
 
     from dynamo_tpu.models import hybrid_linear
-    from dynamo_tpu.ops import linear_state
+    from dynamo_tpu.ops import latent_cache, linear_state
 
     bf16_state()
     # float8 e4m3's 4 exponent and 3 mantissa bits, as an operation of its
@@ -153,6 +185,10 @@ def cache_one_precision_down() -> None:
     hybrid_linear.write_kv_cache_layer = (
         lambda cache, layer, k, v, *rest, **kw:
         write(cache, layer, f8(k), f8(v), *rest, **kw))
+    write_dense = latent_cache.write_dense
+    latent_cache.write_dense = (
+        lambda latent, layer, rows, slots:
+        write_dense(latent, layer, f8(rows), slots))
     conv = linear_state.short_conv
 
     def short_conv(x, w, tail, n_real, bias=None):
@@ -165,7 +201,7 @@ def cache_one_precision_down() -> None:
 # tolerances at which --check-seeds also prints the share of pairs within
 # (the small ones for a model whose logits are divided by logits_scaling)
 SHARES_AT = (0.003, 0.004, 0.005, 0.006, 0.008, 0.01, 0.015,
-             0.15, 0.2, 0.25, 0.3, 0.35, 0.4)
+             0.15, 0.2, 0.25, 0.3, 0.35, 0.4, 0.5, 0.6, 0.8)
 
 CONTROLS = {"bf16_state": bf16_state,
             "cache_one_precision_down": cache_one_precision_down}

@@ -206,17 +206,16 @@ def test_deepseek_engine_int8_kv():
 
 def test_from_hf_rejects_unsupported_configs():
     """Anything this port would get silently wrong must raise loudly:
-    yarn rope_scaling (needs mscale softmax correction), a group-limited
-    choice over biased scores, an unknown score or choice.  V3's routing
-    (sigmoid scores, ``noaux_tc`` with one group, normalised top-k) is
-    computed (``moe_route``) and accepted."""
+    yarn rope_scaling (needs mscale softmax correction), an unknown score
+    or choice.  V3's routing (sigmoid scores, ``noaux_tc`` over one group or
+    group-limited over biased scores, normalised top-k) is computed
+    (``moe_route``) and accepted."""
     base = dict(vocab_size=96, hidden_size=64, num_hidden_layers=2,
                 num_attention_heads=4, qk_nope_head_dim=32,
                 qk_rope_head_dim=16, v_head_dim=32, kv_lora_rank=16,
                 q_lora_rank=None, intermediate_size=96)
     for bad in (
         {"rope_scaling": {"type": "yarn", "factor": 40}},
-        {"topk_method": "noaux_tc", "n_group": 8},
         {"topk_method": "aux_free_v9"},
         {"scoring_func": "tanh"},
         {"moe_layer_freq": 2},
@@ -228,6 +227,9 @@ def test_from_hf_rejects_unsupported_configs():
         "scoring_func": "sigmoid"})
     assert (v3.topk_method, v3.scoring_func, v3.norm_topk_prob) == (
         "noaux_tc", "sigmoid", True)
+    grouped = DeepseekConfig.from_hf({
+        **base, "topk_method": "noaux_tc", "n_group": 8, "topk_group": 4})
+    assert (grouped.n_group, grouped.topk_group) == (8, 4)
     assert DeepseekConfig.from_hf(base).qk_head_dim == 48
 
 

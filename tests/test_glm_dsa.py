@@ -509,7 +509,8 @@ def test_from_hf_config_accepts_the_published_keys_and_refuses_the_rest():
     assert (cfg.index_topk, cfg.kv_lora_rank, cfg.head_dim) == (2048, 512, 576)
     assert cfg.rope_theta == 8_000_000 and cfg.full_layers == 2
     for bad in (
-        {"n_group": 8}, {"scoring_func": "tanh"}, {"topk_method": "x"},
+        {"n_group": 8, "topk_method": "greedy"}, {"q_lora_rank": None},
+        {"scoring_func": "tanh"}, {"topk_method": "x"},
         {"attention_bias": True}, {"hidden_act": "gelu"},
         {"rope_interleave": False}, {"index_topk_pattern": [1]},
         {"rope_parameters": {"rope_type": "yarn", "rope_theta": 1.0}},
@@ -517,8 +518,12 @@ def test_from_hf_config_accepts_the_published_keys_and_refuses_the_rest():
     ):
         with pytest.raises(NotImplementedError):
             GlmDsaConfig.from_hf_config({**published, **bad})
+    grouped = GlmDsaConfig.from_hf_config(
+        {**published, "n_group": 8, "topk_group": 4})
+    assert (grouped.n_group, grouped.topk_group) == (8, 4)
     for bad in ({"indexer_types": ["full"]},
                 {"indexer_types": ["shared"] + ["full"] * 4},
+                {"n_group": 8, "topk_group": 9}, {"n_group": 7},
                 {"expert_parallel": {"router_experts": 256,
                                      "first_expert": 250}}):
         with pytest.raises(ValueError):

@@ -1232,6 +1232,109 @@ def test_hybrid_linear_cell_programs_write_the_state_in_place(
     assert 0.6 * V5E_HBM < total < 0.75 * V5E_HBM, total
 
 
+@pytest.fixture(scope="module", params=[
+    "decode", "prefill",
+    # ~350 CPU-seconds of XLA (128 experts a layer under a scan, the
+    # token-by-token recurrence): outside tier-1
+    pytest.param("reference", marks=pytest.mark.slow)])
+def ling_program(request, topo):
+    """(program, hf, cfg, model, params, cache, compiled):
+    ling-3.0-flash-ep4's decode program, its 512-token chunk or the check's
+    float32 reference over 768 tokens, whole, compiled for the described v5e
+    once a module.  The chunk alone is ~54 CPU-seconds of XLA: paid here, in
+    the set-up, it is not charged to the test that reads it
+    (tests/conftest.py budgets a test's call)."""
+    program = request.param
+    with pytest.MonkeyPatch.context() as patch:     # as ``tpu_gate``
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        hf, cfg, model, params, cache, sds = _abstract_model(
+            "ling-3.0-flash-ep4.json",
+            lambda spec: SingleDeviceSharding(topo.devices[0]))
+        if program == "reference":
+            from cellbench import spec
+
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            ref = spec.load_module(root, "reference", hf["reference"])
+            compiled = jax.jit(ref.make_forward(hf)).lower(
+                params, sds((768,)), sds((8,))).compile()
+        else:
+            fn, args = _step_program(program, model, dict(hf["serve"]), sds,
+                                     prefix_blocks=16)
+            if program == "prefill":    # the engine names the row's slot
+                from dynamo_tpu.engine.core import unified_step
+
+                fn = lambda p, c, *a: unified_step(
+                    model, p, c, *a[:-1], prefix_blocks=16, seq_slots=a[-1])
+                args = (*args, sds((1,)))
+            compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+                params, cache, *args).compile()
+    return program, hf, cfg, model, params, cache, compiled
+
+
+def test_ling_cell_programs_hold_a_latent_cache_beside_the_state(
+        ling_program, tpu_gate):
+    """ling-3.0-flash-ep4's decode program (64 rows = the slot array), a
+    512-token chunk with 16 blocks of the prompt cached, and the check's
+    float32 reference over its longest sequence (700 + 8 tokens, padded to
+    768), whole (layer 0 K+dense | K x5 | M, 128 experts of 512, the cell's
+    latent pool and its 64 slots of state): the MLA layer through the dense
+    latent kernels by name at the new geometry (rows of 640 lanes, 32 heads,
+    value 512), the decode's state update by the delta rule's kernel inside
+    the scopes the cell's metrics read, the experts through the grouped
+    matmul at 1 row an expert (K 2,560 / N 768), one scan a run of layers,
+    every leaf of the cache donated and written in place, and weights +
+    state + latent rows inside the chip (~11.6 GB) with room for the
+    reference's temporaries beside them."""
+    program, hf, cfg, model, params, cache, compiled = ling_program
+    nbytes = lambda a: a.size * a.dtype.itemsize
+    weights = sum(nbytes(a) for a in jax.tree.leaves(params))
+    held = sum(nbytes(a) for a in jax.tree.leaves(cache))
+    assert 10.45e9 < weights < 10.47e9
+    state, pool = nbytes(cache["state"]), nbytes(cache["latent"])
+    assert state == 6 * 64 * 32 * 128 * 128 * 4 and pool == 6272 * 32 * 1280
+    assert hf["attention_layers"] == len(cfg.gqa_layers) == 1
+    mem = compiled.memory_analysis()
+    if program == "reference":
+        print(f"# reference: temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB")
+        # beside the served model: its weights are the reference's
+        # arguments, the cache is the engine's; a layer's 128 experts are
+        # sliced and upcast one at a time (whole they are 2.8 GB)
+        assert mem.temp_size_in_bytes < 1.6e9, mem.temp_size_in_bytes
+        assert (weights + held + mem.temp_size_in_bytes
+                + mem.output_size_in_bytes) < 0.93 * V5E_HBM
+        return
+    hlo = compiled.as_text()
+    assert f"mla_dense_{program}" in hlo
+    # the expert layers' grouped matmul, two calls (gate + up, down) a run of
+    # expert layers (K x5 | M), and no ragged-dot
+    assert len(_grouped_matmul_calls(hlo)) == 2 * 2
+    assert "ragged-dot" not in hlo
+    # K+dense | K K K K K | M
+    assert hlo.count(" while(") == 3
+    assert not re.search(r"f32\[6,64,32,128,128\]\S* copy\(", hlo)
+    for line in hlo.splitlines():
+        if "custom-call(" in line and f"mla_dense_{program}" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "latent_attn" in op_name.split("/"), op_name
+    if program == "decode":
+        calls = [line for line in hlo.splitlines()
+                 if "custom-call(" in line and "linear_state_update" in line]
+        assert len(calls) == 2          # the dense layer's run, and K x5
+        for line in calls:
+            scopes = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+            assert "delta_rule" in scopes and "linear_state" in scopes, scopes
+        assert "f32[64,32,128,128]" not in hlo
+        assert model.state_update_impl() == ("pallas", "tpu")
+    assert mem.alias_size_in_bytes >= held                   # all donated
+    assert mem.temp_size_in_bytes < state // 4, mem.temp_size_in_bytes
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"# {program}: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+          f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"total {total / 1e9:.3f} GB")
+    assert 0.6 * V5E_HBM < total < 0.75 * V5E_HBM, total
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_granite_hybrid_cell_programs_write_the_state_in_place(
         topo, tpu_gate, program):
