@@ -18,6 +18,7 @@ Usage:  python benchmarks/probe_kernels.py [bf16|int8|all] [8b|1b|probe]
         python benchmarks/probe_kernels.py state [out.json]     # the two recurrent states' decode steps
         python benchmarks/probe_kernels.py dense [out.json]     # dense latent decode by sharers a document
         python benchmarks/probe_kernels.py question [out.json]  # a prefill chunk's two attention forms over a selection
+        python benchmarks/probe_kernels.py indexer [out.json]   # a decode step's index scores: the keys gathered, and scored in place
 """
 
 from __future__ import annotations
@@ -657,8 +658,94 @@ def time_question(out_path: str | None) -> None:
             json.dump(table, f, indent=1)
 
 
+def time_indexer(out_path: str | None) -> None:
+    """µs a call of the indexer's decode scores at ``glm-5.2-ep16``'s shapes —
+    32 rows of 32 heads of 128, blocks of 32 keys, a table of 1,152 blocks —
+    in both forms: the keys of every row's whole table gathered and scored by
+    XLA (``index_scores`` over ``keys[tables]``, jitted alone: the program's
+    time on the ``XLA Modules`` line), and ``dsa_index_scores`` (the custom
+    call's own time) by chunk size C and group cap G.  Cases: the cell's mix
+    (32 rows on 12 documents, four each of 16,384 / 24,576 / 32,768 keys, row
+    k on document k mod 12, 160-300 keys of its own), every row alone on a
+    document of its own, and 2 / 3 / 4 rows a document of 24,576.  Beside the
+    times: keys fetched over the table's positions, the largest |Δ| from the
+    XLA form over the positions a row sees, and whether a row's scores are
+    the same bits as at G = 1."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dynamo_tpu.models.glm_dsa import index_scores
+    from dynamo_tpu.ops.pallas import dsa_index_scores as dsa
+    from dynamo_tpu.ops.pallas.registry import probe_dsa_index_inputs
+
+    rows, h, d, bs, m, calls = 32, 32, 128, 32, 1152, 10
+    print(f"# device {jax.devices()[0].device_kind}")
+    rng = np.random.default_rng(67)
+    own = rng.integers(160, 301, rows)
+    cases = {"the cell: 12 documents": (
+        np.repeat([16384, 24576, 32768], 4), np.arange(rows) % 12, own)}
+    for k in (1, 2, 3, 4):
+        cases[f"{k} a document of 24,576"] = (
+            np.full(-(-rows // k), 24576), np.arange(rows) // k, own)
+
+    @jax.jit
+    def gathered(q, w, keys, bt, lens):
+        return index_scores(q[:, None], w[:, None],
+                            keys[bt].reshape(rows, m * bs, d))[:, 0]
+
+    table = []
+    for case, (docs, of, own) in cases.items():
+        args = probe_dsa_index_inputs(rows, h, d, bs, m, docs, of, own,
+                                      seed=67)
+        bt, lens = (np.asarray(a) for a in args[3:])
+        seen = np.arange(m * bs)[None, :] < lens[:, None]
+        ref = np.where(seen, np.asarray(gathered(*args)), 0.0)
+        took = [ns for _, ns in
+                profiled_device_ns(gathered, args, calls)["XLA Modules"]]
+        row = {"case": case, "form": "gather + index_scores (XLA)",
+               "us": round(float(np.median(took)) / 1e3, 1),
+               "context_keys": int(lens.sum()),
+               "table_keys": rows * m * bs}
+        table.append(row)
+        print(json.dumps(row), flush=True)
+        alone = {}
+        for c, g in ((16, 1), (16, 8), (32, 1), (32, 2), (32, 4), (32, 8),
+                     (64, 1), (64, 8)):
+            fn = jax.jit(functools.partial(
+                dsa.dsa_index_scores, blocks_per_chunk=c, group_rows=g))
+            out = np.where(seen, np.asarray(fn(*args)), 0.0)
+            took = [ns for name, ns in
+                    profiled_device_ns(fn, args, calls)["XLA Ops"]
+                    if name.startswith(f"%{dsa.KERNEL_NAME}")]
+            assert len(took) == calls, (len(took), calls)
+            us = float(np.median(took)) / 1e3
+            if g == 1:
+                alone[c] = out
+            fetched = dsa.index_keys_read(bt, lens, bs, c, g)
+            row = {"case": case, "form": dsa.KERNEL_NAME, "c": c, "g": g,
+                   "us": round(us, 1), "fetched_keys": fetched,
+                   "fetched_pct_of_table": round(
+                       100.0 * fetched / (rows * m * bs), 2),
+                   "fetched_gb_s": round(fetched * d * 2 / us / 1e3, 1),
+                   "ns_a_block": round(us * 1e3 / (fetched / bs), 1),
+                   "max_abs_delta_from_xla": float(np.abs(out - ref).max()),
+                   "same_bits_as_alone": bool((out == alone[c]).all())}
+            table.append(row)
+            print(json.dumps(row), flush=True)
+    if out_path:
+        os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+        with open(out_path, "w") as f:
+            json.dump(table, f, indent=1)
+
+
 def main() -> None:
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
+    if which == "indexer":
+        time_indexer(sys.argv[2] if len(sys.argv) > 2 else None)
+        return
     if which == "question":
         time_question(sys.argv[2] if len(sys.argv) > 2 else None)
         return
@@ -796,6 +883,15 @@ def main() -> None:
                 *probe_mla_masked_inputs(shape[0], shape[1], 64, 640,
                                          *shape[2:]),
                 heads=64, dv=512, sm_scale=1 / 16)))
+    from dynamo_tpu.ops.pallas.dsa_index_scores import dsa_index_scores
+    from dynamo_tpu.ops.pallas.registry import probe_dsa_index_inputs
+
+    # the indexer's decode scores: 32 rows on 12 documents of 16-32 k keys
+    variants.append((
+        "dsa_index_scores/decode",
+        lambda: dsa_index_scores(*probe_dsa_index_inputs(
+            32, 32, 128, 32, 1152, np.repeat([16384, 24576, 32768], 4),
+            np.arange(32) % 12, np.full(32, 300)))))
     variants.append((
         "latent_cache/write_rows",
         lambda: write_rows(*probe_latent_dma_inputs(1 << 16, 576, 2048))))

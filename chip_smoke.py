@@ -321,6 +321,24 @@ def _child_kernels(arg: dict) -> None:
         ref = jnp.where(jnp.arange(32)[:, None, None] < 27, ref, 0.0)
         return got, ref.reshape(32 * h, 128)
 
+    def index_scores(_quant):
+        from dynamo_tpu.models.glm_dsa import index_scores as xla_form
+        from dynamo_tpu.ops.pallas.dsa_index_scores import dsa_index_scores
+
+        # seven rows on three documents of 256 / 512 / 544 keys (a group of
+        # three, two of two) and an empty slot, chunks of eight blocks
+        q, w, keys, bt, sl = reg.probe_dsa_index_inputs(
+            8, 8, 128, 32, 20, [256, 512, 544], [0, 1, 2, 0, -1, 1, 2, 0],
+            [40, 7, 33, 1, 0, 70, 64, 90])
+        got = dsa_index_scores(q, w, keys, bt, sl, blocks_per_chunk=8,
+                               interpret=interpret)
+        with jax.default_matmul_precision("highest"):
+            ref = xla_form(q[:, None], w[:, None],
+                           keys[bt].reshape(8, -1, 128))[:, 0]
+        # what a row does not see is whatever the buffers held
+        seen = jnp.arange(ref.shape[1])[None, :] < sl[:, None]
+        return jnp.where(seen, got, 0.0), jnp.where(seen, ref, 0.0)
+
     def latent_dma(_quant):
         from dynamo_tpu.ops.pallas.latent_cache_dma import write_rows
 
@@ -386,6 +404,7 @@ def _child_kernels(arg: dict) -> None:
         "int8_matmul": [("int8_matmul", matmul)],
         "mla_sparse_attention": [("sparse_latent", sparse)],
         "mla_sparse_prefill_masked": [("masked_latent", masked)],
+        "dsa_index_scores": [("index_scores", index_scores)],
         "latent_cache_dma": [("latent_write_rows", latent_dma)],
         "linear_state_update": [("state_step", state_step)],
         "ssm_state_update": [("ssm_step", ssm_step)],
@@ -400,7 +419,8 @@ def _child_kernels(arg: dict) -> None:
         for label, fn in cases[kernel]:
             for quant in ([False] if kernel in (
                     "int8_matmul", "mla_sparse_attention",
-                    "mla_sparse_prefill_masked", "latent_cache_dma",
+                    "mla_sparse_prefill_masked", "dsa_index_scores",
+                    "latent_cache_dma",
                     "linear_state_update", "ssm_state_update",
                     "selective_state_update", "selective_state_scan",
                     "grouped_expert_matmul")

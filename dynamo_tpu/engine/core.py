@@ -684,6 +684,8 @@ class EngineCore:
         # (block tables, lengths, block size) -> cached rows the model's
         # decode attention fetches a layer, where the model can say
         self._decode_rows_fetched = getattr(model, "decode_rows_fetched", None)
+        # the same -> index keys the model's decode indexer fetches a layer
+        self._index_keys_read = getattr(model, "index_keys_read", None)
         # passes of the layer stack a token runs: ut_steps for a looped decoder
         self._ut_steps = int(getattr(model.config, "ut_steps", 1) or 1)
         self._decode_tiling = self._flash_decode_tiling()
@@ -2805,6 +2807,10 @@ class EngineCore:
             if self._decode_rows_fetched is not None:
                 self.counts.attn_fetched_tokens_total += \
                     self._decode_rows_fetched(bt, seq_lens, cfg.block_size)
+            if self._index_keys_read is not None:
+                self.counts.index_keys_table_total += bt.size * cfg.block_size
+                self.counts.index_keys_read_total += \
+                    self._index_keys_read(bt, seq_lens, cfg.block_size)
 
         def finish(out):
             sampled, lps, cids, clps = (a[0] for a in out)

@@ -13,7 +13,14 @@ Per layer (pre-norm residual blocks, docs/glm_dsa.md has the equations):
     through its V half.
   * **Indexer**, in layers whose ``indexer_types`` entry is ``full``: a small
     multi-head scorer over a cache of its own keys picks, for every query
-    token, the ``index_topk`` positions it attends to.  A ``shared`` layer
+    token, the ``index_topk`` positions it attends to.  The scores take one
+    of two forms, by what the call can observe: a decode step on the TPU
+    (one query a row) reads the keys where they lie, through the block
+    table, a document's keys once for the rows that ask it
+    (``ops/pallas/dsa_index_scores.py``: no copy of the table's keys); a
+    prefill chunk, and every call where the kernels are off, gathers its
+    context's keys and scores them in XLA (``index_scores``, the kernel's
+    oracle).  A ``shared`` layer
     has no indexer and attends to the selection of the nearest ``full``
     layer before it.  A ``none`` layer has no indexer either and attends to
     every cached row (``latent_cache.dense_attention``: two Pallas kernels
@@ -578,11 +585,18 @@ class GlmDsaModel:
         # what ``mla_dense_decode`` fetches, and with an indexer nothing to
         # say (the selection is counted as it is)
         self.decode_rows_fetched = None
+        # the same -> index-key rows a decode dispatch's indexer fetches a
+        # ``full`` layer where it scores them in place (what
+        # ``dsa_index_scores`` fetches); None without an indexer
+        self.index_keys_read = None
         if not config.indexed:
             from dynamo_tpu.ops.pallas.mla_dense_attention import (
                 decode_rows_fetched,
             )
             self.decode_rows_fetched = decode_rows_fetched
+        else:
+            from dynamo_tpu.ops.pallas.dsa_index_scores import index_keys_read
+            self.index_keys_read = index_keys_read
         self.sm_scale = float(config.qk_head_dim ** -0.5)
         yarn = config.yarn
         if yarn is None:
@@ -758,11 +772,13 @@ class GlmDsaModel:
 
     # ---------------------------------------------------------------- forward
     def _select(self, lp, fi, x, c_q, positions, cache, block_tables,
-                seq_lens, slot_idx, ctx_blocks, sparse: bool):
+                seq_lens, slot_idx, ctx_blocks, sparse: bool, groups=None):
         """The indexer of a ``full`` layer: writes this call's keys, scores
         every cached position and returns the selection — (slots [N, K],
         nvalid [N]) for the gather, or a mask [B, S, C] for a dense chunk —
-        with the updated cache."""
+        with the updated cache.  ``groups`` (a decode step on the TPU:
+        ``latent_cache.index_decode_groups``) has the keys scored where they
+        lie; None gathers them for ``index_scores``."""
         cfg = self.config
         b, s, _ = x.shape
         hi, di, rope = (cfg.index_n_heads, cfg.index_head_dim,
@@ -779,9 +795,16 @@ class GlmDsaModel:
             cache["index_k"], fi, k.reshape(b * s, di),
             slot_idx.reshape(b * s))
         cache = {**cache, "index_k": index_k}
-        keys = index_k[fi, block_tables[:, :ctx_blocks]].reshape(
-            b, ctx_blocks * bs, di)
-        scores = index_scores(q, w, keys)                # [B, S, C] f32
+        if groups is None:
+            keys = index_k[fi, block_tables[:, :ctx_blocks]].reshape(
+                b, ctx_blocks * bs, di)
+            scores = index_scores(q, w, keys)            # [B, S, C] f32
+        else:
+            # what no row of a group sees is left as it was: ``seen`` below
+            # is the one place a position's visibility is decided
+            scores = latent_cache.decode_index_scores(
+                q[:, 0], w[:, 0], index_k, fi, block_tables[:, :ctx_blocks],
+                positions, seq_lens, groups)[:, None]
         at = jnp.arange(ctx_blocks * bs, dtype=jnp.int32)
         seen = ((at[None, None, :] <= positions[:, :, None])
                 & (at[None, None, :] < seq_lens[:, None, None]))
@@ -846,7 +869,7 @@ class GlmDsaModel:
                 with jax.named_scope("indexer"):
                     sel, cache = self._select(
                         lp, fi, x, c_q, positions, cache, block_tables,
-                        seq_lens, slot_idx, ctx_blocks, sparse)
+                        seq_lens, slot_idx, ctx_blocks, sparse, groups)
             if not cfg.indexed:
                 out = latent_cache.dense_attention(
                     q_lat, cache["latent"], li, block_tables[:, :ctx_blocks],
@@ -932,6 +955,12 @@ class GlmDsaModel:
                 groups = latent_cache.dense_decode_groups(
                     block_tables[:, :ctx_blocks], positions, seq_lens, bs)
         elif sparse:
+            if s == 1:
+                # the rows that ask the same document, found once for every
+                # ``full`` layer's indexer (which then reads its keys once)
+                groups = latent_cache.index_decode_groups(
+                    cache["index_k"], cfg.index_n_heads,
+                    block_tables[:, :ctx_blocks], positions, seq_lens)
             k_sel = min(cfg.index_topk, ctx_blocks * bs)
             sel = (jnp.zeros((b * s, k_sel), jnp.int32),
                    jnp.zeros((b * s,), jnp.int32),

@@ -319,6 +319,16 @@ REQUEST_FAMILY = (
            "kernel fetches a layer for those rows, what a group of rows that "
            "ask one document shares counted once (over context, cellbench's "
            "attn.fetched_pct)"),
+    _count("dynamo_tpu_engine_index_keys_table_total", "counter",
+           "latent attention with an indexer: rows x positions of the block "
+           "tables the decode dispatches carried, summed (what a gather of "
+           "every row's whole table copies a full layer)"),
+    _count("dynamo_tpu_engine_index_keys_read_total", "counter",
+           "of those, the index keys the decode indexer fetches a full layer "
+           "where it scores them in place (the model's index_keys_read, the "
+           "kernel's own arithmetic: whole blocks up to each row's length, "
+           "what a group of rows that ask one document shares counted once): "
+           "over the table, cellbench's attn.index_keys_read_pct"),
     _count("dynamo_tpu_engine_prefill_masked_tokens_total", "counter",
            "latent attention with an indexer: prompt tokens computed whose "
            "chunk attended in masked form, every key of the context scored "

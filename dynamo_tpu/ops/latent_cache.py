@@ -14,7 +14,10 @@ parts below), a model that attends to every cached row fetches whole blocks
              row is a legal DMA (ops/pallas/mla_sparse_attention.py).
              GLM-5.2: width 576 -> W 384, 1,536 B a token and layer.
 ``index_k``  bf16 [Lf, N, Bs, Di]: the indexer's key of a token, in the
-             layers that compute an index (``Lf`` of the ``L``).
+             layers that compute an index (``Lf`` of the ``L``).  A block of
+             one layer is one contiguous [Bs, Di] DMA for the kernel that
+             scores a decode step's keys where they lie
+             (ops/pallas/dsa_index_scores.py).
 
 Both index blocks on axis 1 and share the engine's block table, so a block
 id is one block of every layer in both parts, and prefix reuse carries both.
@@ -45,6 +48,7 @@ __all__ = [
     "masked_attention",
     "dense_row_width", "init_dense_cache", "write_dense", "dense_attention",
     "dense_decode_groups",
+    "index_decode_groups", "decode_index_scores",
     "kernels_on",
 ]
 
@@ -294,6 +298,47 @@ def dense_decode_groups(block_tables: jax.Array, positions: jax.Array,
 
     return decode_groups(jnp, block_tables, _decode_lens(positions, seq_lens),
                          block_size)
+
+
+def index_decode_groups(index_k: jax.Array, heads: int,
+                        block_tables: jax.Array, positions: jax.Array,
+                        seq_lens: jax.Array):
+    """What ``decode_index_scores`` takes as ``groups`` for one query a row:
+    the rows of a decode step that hold the same leading blocks, worked out
+    once a step for every ``full`` layer's ``dsa_index_scores``; None where
+    the indexer gathers its keys and XLA scores them (no kernels, or a step
+    whose scores the kernel cannot hold)."""
+    if not kernels_on():
+        return None
+    from dynamo_tpu.ops.pallas import dsa_index_scores as dsa
+    from dynamo_tpu.ops.pallas.mla_dense_attention import decode_groups
+    from dynamo_tpu.ops.pallas.registry import (
+        DSA_INDEX_BLOCKS_PER_CHUNK,
+        DSA_INDEX_GROUP_ROWS,
+    )
+
+    (b, m), (_, _, bs, di) = block_tables.shape, index_k.shape
+    if not dsa.fits(b, m, bs, heads, di):
+        return None
+    return decode_groups(jnp, block_tables, _decode_lens(positions, seq_lens),
+                         bs, DSA_INDEX_BLOCKS_PER_CHUNK, DSA_INDEX_GROUP_ROWS)
+
+
+def decode_index_scores(q: jax.Array, w: jax.Array, index_k: jax.Array,
+                        layer, block_tables: jax.Array, positions: jax.Array,
+                        seq_lens: jax.Array, groups: jax.Array) -> jax.Array:
+    """The index scores of a decode step by ``dsa_index_scores``: q
+    [B, Hi, Di] and w [B, Hi], one query a row, over the keys of row
+    ``layer`` of ``index_k`` that ``block_tables`` [B, M] names, with the
+    step's ``groups`` (``index_decode_groups``: not None).  f32 [B, M·Bs],
+    a row's scores at the positions it sees (c <= its position, c <
+    ``seq_lens``) and anything elsewhere."""
+    from dynamo_tpu.ops.pallas.dsa_index_scores import dsa_index_scores
+
+    lf, n, bs, di = index_k.shape
+    return dsa_index_scores(
+        q, w, index_k.reshape(lf * n, bs, di), block_tables + layer * n,
+        _decode_lens(positions, seq_lens), groups)
 
 
 def dense_attention(q: jax.Array, latent: jax.Array, layer,

@@ -53,6 +53,11 @@ __all__ = [
     "MLA_MASKED_KEYS_PER_TILE",
     "MLA_MASKED_TILE_NS",
     "MLA_SPARSE_ROW_NS",
+    "DSA_INDEX_BLOCKS_PER_CHUNK",
+    "DSA_INDEX_GROUP_ROWS",
+    "DSA_INDEX_VMEM_BYTES",
+    "dsa_index_vmem_bytes",
+    "dsa_index_cost",
     "LINEAR_STATE_HEADS_PER_STEP",
     "linear_state_heads_per_step",
     "SSM_STATE_BLOCK_BYTES",
@@ -154,6 +159,16 @@ MLA_MASKED_KEYS_PER_TILE = 512
 # and builds the bias, 0.58 ms at 33 k positions: ~8% of the kernel there
 MLA_MASKED_TILE_NS = 7300
 MLA_SPARSE_ROW_NS = 26
+# the indexer's decode scores (ops/pallas/dsa_index_scores.py): blocks of
+# index keys fetched and scored together (32 x 32 = 1,024 keys, 256 KiB a
+# buffer at 128 bf16 lanes: a block is an 8 KB DMA, and the kernel is bound by
+# issuing them), the rows that may share one fetch of a document's keys, and
+# the VMEM the call may take: every row's scores stay resident (32 rows x
+# 36,864 positions in f32 are 4.5 MiB, twice for the pipeline's two buffers),
+# which is past the compiler's default of 16 MiB with the buffers beside them
+DSA_INDEX_BLOCKS_PER_CHUNK = 32
+DSA_INDEX_GROUP_ROWS = 8
+DSA_INDEX_VMEM_BYTES = 48 * 1024 * 1024
 # recurrent state update: heads of one slot a grid step.  16 matrices of
 # 128 x 128 float32 are 1 MiB a buffer, 4 MiB double buffered in and out,
 # and their 48 q | k | g vectors fit the one 128-row tile a step transposes
@@ -241,6 +256,11 @@ KERNELS = {
     },
     "mla_sparse_prefill_masked": {
         "module": "dynamo_tpu.ops.pallas.mla_masked_prefill",
+        "placeholder": False,
+    },
+    # the indexer's scores of a decode step, from the keys where they lie
+    "dsa_index_scores": {
+        "module": "dynamo_tpu.ops.pallas.dsa_index_scores",
         "placeholder": False,
     },
     # the recurrent state's decode step, one read and one write a matrix
@@ -609,6 +629,30 @@ def mla_masked_cost(s: int, c: int, h: int, dq: int, dv: int,
         dma=(sl // tq) * cl * dq * 2 + sl * cl * 4 + sl * h * dq * 2
         + s * h * dv * 4,
         flops=2 * pairs * (dq + dv), trans=pairs)
+
+
+def dsa_index_vmem_bytes(b: int, cols: int, h: int, d: int, t: int,
+                         g: int) -> int:
+    """VMEM of one ``dsa_index_scores`` call: the rows' scores [B, cols],
+    queries [B, H, D] and lane-broadcast head weights [B, H, 128] as whole
+    blocks (two buffers each), the stacked members' copies, two key chunks of
+    T keys, and a group's products [G·H, T] in f32 with as much again for
+    what is made of them."""
+    blocks = b * cols * 4 + b * h * d * 2 + b * h * 128 * 4
+    scratch = g * h * d * 2 + g * h * 128 * 4 + DOUBLE_BUFFER * t * d * 2
+    return DOUBLE_BUFFER * blocks + scratch + 2 * g * h * t * 4
+
+
+def dsa_index_cost(b: int, h: int, d: int, cols: int, fetched: int,
+                   scored: int) -> dict:
+    """Index scores of a decode step of B rows over ``cols`` table
+    positions: ``fetched`` keys of D bf16 elements move (a group's shared
+    keys once), ``scored`` (row, key) pairs take H dot products of D and a
+    relu, a weight and a sum each; queries and weights are read and every
+    row's scores written once."""
+    return _cost_dict(
+        dma=fetched * d * 2 + b * cols * 4 + b * h * d * 2 + b * h * 128 * 4,
+        flops=scored * h * (2 * d + 3), trans=0)
 
 
 def masked_prefill_is_cheaper(context: int, topk: int) -> bool:
@@ -1388,6 +1432,81 @@ def _mla_masked_case() -> dict:
     }
 
 
+def _dsa_index_case() -> dict:
+    """Seven rows of four heads over tables of twelve blocks of 32 keys,
+    eight blocks a chunk (the table is not whole chunks): three rows on one
+    document whose lengths end in different blocks, one of them the whole
+    table, two on another that share ten blocks but one whole chunk, a row
+    alone, an empty slot.  Only what a row sees is compared: the rest is
+    whatever the buffers held.  The poisoned run makes every block no row
+    owns NaN, and every key past a row's length in the blocks only it
+    holds."""
+    import jax.numpy as jnp
+
+    np = _np()
+    b, h, d, bs, m, n, c = 7, 4, 128, 32, 12, 64, 8
+    doc_a, doc_b = list(range(3, 35, 4)), list(range(5, 45, 4))
+    tables = np.zeros((b, m), np.int32)
+    tables[0, :10] = doc_a + [44, 45]
+    tables[1] = doc_a + [46, 47, 48, 49]
+    tables[2, :9] = doc_a + [50]
+    tables[3, :6] = range(52, 58)
+    tables[5, :11] = doc_b + [58]
+    tables[6] = doc_b + [59, 60]
+    lens = np.array([298, 384, 258, 170, 0, 325, 382], np.int32)
+
+    def build():
+        rng = np.random.default_rng(900)
+        return {"q": jnp.asarray(rng.normal(size=(b, h, d)), jnp.bfloat16),
+                "w": jnp.asarray(rng.normal(size=(b, h)), jnp.bfloat16),
+                "keys": rng.normal(size=(n, bs, d)).astype(np.float32)}
+
+    def _keys(inp, poisoned):
+        keys = inp["keys"].copy()
+        if poisoned:
+            owned = np.zeros(n, bool)
+            for r in range(b):
+                owned[tables[r, :-(-lens[r] // bs)]] = True
+            keys[~owned] = np.nan
+            for r in (0, 2, 3, 5, 6):          # a last block of its own
+                keys[tables[r, lens[r] // bs], lens[r] % bs:] = np.nan
+        return jnp.asarray(keys, jnp.bfloat16)
+
+    def run(inp, poisoned: bool):
+        from dynamo_tpu.ops.pallas.dsa_index_scores import dsa_index_scores
+
+        out = dsa_index_scores.__wrapped__(
+            inp["q"], inp["w"], _keys(inp, poisoned), jnp.asarray(tables),
+            jnp.asarray(lens), blocks_per_chunk=c, group_rows=4,
+            interpret=True)
+        seen = np.arange(m * bs)[None, :] < lens[:, None]
+        return jnp.where(seen, out, 0.0)
+
+    def oracle(inp):
+        q = np.asarray(inp["q"], np.float32)
+        w = np.asarray(inp["w"], np.float32)
+        keys = np.asarray(_keys(inp, False), np.float32)[tables].reshape(
+            b, m * bs, d)
+        dots = np.einsum("bhd,bcd->bhc", q, keys)
+        ref = np.einsum("bhc,bh->bc", np.maximum(dots, 0), w) * (h * d) ** -0.5
+        seen = np.arange(m * bs)[None, :] < lens[:, None]
+        return np.where(seen, ref, 0).astype(np.float32), seen, ~seen
+
+    def pricing():
+        from dynamo_tpu.ops.pallas.dsa_index_scores import index_keys_read
+
+        return dsa_index_cost(
+            b, h, d, 16 * bs,
+            fetched=index_keys_read(tables, lens, bs, c, 4),
+            scored=int((-(-lens // bs) * bs).sum()))
+
+    return {
+        "name": "index-scores", "kernel": "dsa_index_scores",
+        "mode": "interpret", "atol": 2e-2,
+        "build": build, "run": run, "oracle": oracle, "pricing": pricing,
+    }
+
+
 def _latent_dma_case(kind: str) -> dict:
     """``write``: seven rows of which two have no slot; ``gather``: five
     blocks, one twice.  Pure copies: the oracle is exact."""
@@ -1869,6 +1988,7 @@ def audit_cases() -> list[dict]:
         _int8_matmul_case(),
         _mla_sparse_case(),
         _mla_masked_case(),
+        _dsa_index_case(),
         _latent_dma_case("write"),
         _latent_dma_case("gather"),
         _linear_state_case(),
@@ -2092,6 +2212,35 @@ def probe_mla_masked_inputs(s, c, h, dq, live=None, ctx=None):
             jnp.asarray([live, ctx], jnp.int32))
 
 
+def probe_dsa_index_inputs(rows, h, d, bs, m, docs, of, own, seed=0):
+    """q [B, H, D], w [B, H], keys [R, Bs, D], tables [B, M], lens [B]: row r
+    asks document ``of[r]`` (``docs``: their lengths in keys, whole blocks;
+    the same block ids for every row that asks one) and adds ``own[r]`` keys
+    of its own; ``of[r]`` < 0 is an empty slot."""
+    import jax
+    import jax.numpy as jnp
+
+    np = _np()
+    rng = np.random.default_rng(seed)
+    docs, of, own = (np.asarray(a, np.int64) for a in (docs, of, own))
+    n = int((docs // bs).sum() + (-(-own // bs)).sum()) + 1
+    free = iter(rng.permutation(n - 1) + 1)
+    held = [[next(free) for _ in range(x // bs)] for x in docs]
+    tables = np.zeros((rows, m), np.int32)
+    lens = np.zeros(rows, np.int32)
+    for r in range(rows):
+        if of[r] < 0:
+            continue
+        ids = held[of[r]] + [next(free) for _ in range(-(-own[r] // bs))]
+        tables[r, :len(ids)] = ids
+        lens[r] = docs[of[r]] // bs * bs + own[r]
+    kq, kw, kk = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(kq, (rows, h, d), jnp.bfloat16),
+            jax.random.normal(kw, (rows, h), jnp.bfloat16),
+            jax.random.normal(kk, (n, bs, d), jnp.bfloat16),
+            jnp.asarray(tables), jnp.asarray(lens))
+
+
 def probe_linear_state_inputs(layers, slots, heads, d):
     """state [L,B,H,d,d] f32, layer, q, k, v, g [B,H,d], beta [B,H], fresh,
     alive [B] (every eighth slot idle, one starting afresh)."""
@@ -2194,6 +2343,7 @@ _PROBE_BUILDERS = {
     "selective_state_update": probe_selective_step_inputs,
     "selective_state_scan": probe_selective_scan_inputs,
     "mla_sparse_prefill_masked": probe_mla_masked_inputs,
+    "dsa_index_scores": probe_dsa_index_inputs,
     "mla_sparse_attention": probe_mla_sparse_inputs,
     "latent_cache_dma": probe_latent_dma_inputs,
     "paged_decode_attention_mq": probe_decode_inputs,

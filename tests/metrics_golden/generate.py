@@ -145,6 +145,8 @@ def seed_http_metrics():
     ec.attn_context_tokens_total = 48000
     ec.attn_selected_tokens_total = 4096
     ec.attn_fetched_tokens_total = 21000
+    ec.index_keys_table_total = 2359296
+    ec.index_keys_read_total = 614400
     ec.prefill_masked_tokens_total = 232
     ec.moe_router_picks_total = 4608
     ec.moe_held_picks_total = 576

@@ -63,10 +63,11 @@ def test_tiny_reports_what_the_server_ran(tiny):
     assert warm and " 0 hits" not in warm[0]
     kernels = [l for l in tiny.lines if l.startswith("  kernel ")]
     # nine lines of the GQA kernels and the int8 matmul, then the sparse
-    # and the masked latent-attention kernels, the latent cache's writes,
+    # and the masked latent-attention kernels, the indexer's decode scores,
+    # the latent cache's writes,
     # the three recurrent states' decode steps, the selective scan of a
     # prefill chunk and the experts' grouped matmul
-    assert len(kernels) == 17 and all(
+    assert len(kernels) == 18 and all(
         l.endswith("PASS") and "interpret=True" in l for l in kernels)
     assert any("sparse_latent" in l for l in kernels)
     assert any("masked_latent" in l for l in kernels)

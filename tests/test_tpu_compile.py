@@ -962,22 +962,29 @@ def test_indexer_lists_its_selection_without_an_element_gather(
     """From the selection mask to the slot list attention reads, the indexer
     gathers nothing by the element (three ``take_along_axis`` of 65,536 -
     524,288 scalars a ``full`` layer were 12% of a decode program and a
-    quarter of a question program: PERF.md, PR 44).  What is left under the
-    scope is the gather of the keys, whole blocks of ``index_k``; and a
-    256-query question holds no more temporaries than a 2,048-token chunk,
-    the program that sizes the cell's memory (0.11 GB: 0.15 with the
-    gathers).  A stack with an indexer traces no grouping of its decode
-    rows: that is the dense kernel's (PR 59), and GLM's programs are their
-    parent's."""
+    quarter of a question program: PERF.md, PR 44).  A question gathers its
+    context's keys, whole blocks of ``index_k``, and holds no more
+    temporaries than a 2,048-token chunk, the program that sizes the cell's
+    memory (0.11 GB: 0.15 with the gathers).  A decode step gathers nothing
+    under the scope at all: ``dsa_index_scores`` reads the keys where they
+    lie, once a ``full`` layer, and the rows that ask one document are
+    grouped once a step, outside the layer scans, for both (PR 67: the copy
+    of the whole table's keys, ``bf16[32, 36864, 128]``, was 302 MB a layer
+    every step)."""
     from dynamo_tpu.ops import latent_cache
 
-    def never(*a, **kw):
-        raise AssertionError("an indexed stack asked for decode groups")
-
-    monkeypatch.setattr(latent_cache, "dense_decode_groups", never)
+    grouped = []
+    real = latent_cache.index_decode_groups
+    monkeypatch.setattr(
+        latent_cache, "index_decode_groups",
+        lambda *a, **kw: grouped.append(1) or real(*a, **kw))
+    # a decode step: the two ``full`` layers of the cell's five; a question:
+    # one ``full`` layer and one that shares its selection
+    kinds = _GLM_KINDS if chunk else dict(
+        _GLM_KINDS, indexer_types=["full", "full"])
     hf, cfg, model, params, cache, sds = _abstract_model(
         "glm-5.2-ep16.json",
-        lambda spec: SingleDeviceSharding(topo.devices[0]), **_GLM_KINDS)
+        lambda spec: SingleDeviceSharding(topo.devices[0]), **kinds)
     # the cell's decode program, or a question behind the longest prefix
     fn, args = _step_program(
         "prefill" if chunk else "decode", model,
@@ -998,10 +1005,53 @@ def test_indexer_lists_its_selection_without_an_element_gather(
                 and _FROM_A_GATHER.search(line)):
             dims = tuple(int(d) for d in m.group(3).split(","))
             gathered.add((m.group(2), dims[-2:]))
-    assert gathered == {("bf16", (BS, 128))}, gathered      # [.., Bs, Di]
+    scored = [line for line in hlo.splitlines()
+              if "custom-call(" in line and "dsa_index_scores" in line]
     if chunk:
+        assert gathered == {("bf16", (BS, 128))}, gathered  # [.., Bs, Di]
+        assert not scored and not grouped
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp <= GLM_CHUNK_TEMP, temp
+        return
+    assert not gathered, gathered
+    # the kernel, once a ``full`` layer, under the scope the benchmark reads
+    # the indexer's time by; the groups once for both
+    assert len(scored) == 2 and all("/indexer/" in line for line in scored)
+    assert len(grouped) == 1
+    # no copy of the table's keys: no array of keys a position of the table
+    # is written (the cache itself is [2, 14400, 32, 128], in place)
+    copies = [(name, dims, op) for name, dtype, dims, op
+              in _unfused_instructions(hlo)
+              if dtype == "bf16" and dims[-1:] == (128,)
+              and math.prod(dims) >= 32 * 36864 * 128
+              and dims != tuple(cache["index_k"].shape)
+              and op not in _PASS_THROUGH]
+    assert not copies, copies
+    index_bytes = cache["index_k"].size * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < index_bytes
+
+
+@pytest.mark.parametrize("rows", [32, 5], ids=["the-cell", "five-rows"])
+def test_index_scores_kernel_compiles_on_one_chip(glm_sds, rows):
+    """``dsa_index_scores`` at the cell's shapes — 32 rows of 32 heads of
+    128 over a table of 1,152 blocks of the two ``full`` layers' keys, every
+    row's scores [32, 36864] resident in VMEM — and at a batch that is no
+    power of two (the groups' cap follows it).  The call carries the kernel's
+    own name, which matches neither pattern the cell's attention rooflines
+    read (``^mla_sparse_decode``, ``^mla_sparse_prefill``); the keys are read
+    where they lie: no temporary but the output's own."""
+    from dynamo_tpu.ops.pallas import dsa_index_scores as dsa
+
+    assert dsa.fits(rows, 1152, BS, 32, 128)
+    compiled = jax.jit(dsa.dsa_index_scores).lower(
+        glm_sds((rows, 32, 128), jnp.bfloat16),
+        glm_sds((rows, 32), jnp.bfloat16),
+        glm_sds((2 * GLM["blocks"], BS, 128), jnp.bfloat16),
+        glm_sds((rows, 1152), jnp.int32), glm_sds((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and dsa.KERNEL_NAME in text
+    assert not re.match(r"mla_sparse_(decode|prefill)", dsa.KERNEL_NAME)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
 @pytest.mark.parametrize("s,c", [(2048, 34816), (256, 33280), (64, 16896)],
@@ -1084,12 +1134,20 @@ def test_dense_latent_prefill_compiles_on_one_chip(glm_sds, s, blocks):
     ("decode", None, 1), ("prefill", 256, 1024), ("prefill", 2048, 1024)],
     ids=["decode", "question-256", "chunk-2048"])
 def test_mistral4_cell_programs_keep_one_cache_and_name_their_kernels(
-        topo, tpu_gate, program, chunk, prefix):
+        topo, tpu_gate, program, chunk, prefix, monkeypatch):
     """The cell's decode program, a question behind the longest document and
     a document's last chunk, whole (9 layers, 16 experts, the cell's cache):
     the dense kernel by name, one layer scan, the cache donated and written
     in place by XLA's own scatter (no second copy, no re-layout), and
-    weights + cache + temporaries inside the chip (11.7-11.8 GB)."""
+    weights + cache + temporaries inside the chip (11.7-11.8 GB).  A stack
+    without an indexer asks for none of the indexer's groups: its programs
+    are their parent's (PR 67)."""
+    from dynamo_tpu.ops import latent_cache
+
+    def never(*a, **kw):
+        raise AssertionError("a stack with no indexer asked for its groups")
+
+    monkeypatch.setattr(latent_cache, "index_decode_groups", never)
     hf, cfg, model, params, cache, sds = _abstract_model(
         "mistral-small-4-ep8.json",
         lambda spec: SingleDeviceSharding(topo.devices[0]))
